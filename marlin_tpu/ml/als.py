@@ -29,7 +29,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..utils.compat import pcast, shard_map, vma_of
 
 __all__ = ["als_run", "ALSModel"]
 
@@ -92,9 +91,9 @@ def _chunked_segment_stats(factors_other, seg_ids, other_ids, ratings,
     )
     # inside shard_map the data is varying over the mesh axes; the scan carry
     # init must carry the same varying-manual-axes type
-    vma = tuple(vma_of(ratings))
+    vma = tuple(jax.typeof(ratings).vma)
     if vma:
-        init = tuple(pcast(x, vma, to="varying") for x in init)
+        init = tuple(jax.lax.pcast(x, vma, to="varying") for x in init)
     idxs = jnp.arange(n_chunks * chunk).reshape(n_chunks, chunk)
     (xtx, xty, counts), _ = jax.lax.scan(body, init, idxs)
     return xtx[:num_segments], xty[:num_segments], counts[:num_segments]
@@ -201,7 +200,7 @@ def _solve_side_sharded(factors_other, blk_sid, blk_oid, blk_val, lam, alpha,
     spec_b = P(axes, None)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(), spec_b, spec_b, spec_b, P(), P()),
         out_specs=spec_b,
     )

@@ -24,7 +24,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..utils.compat import shard_map
 
 __all__ = ["pagerank", "build_transition_matrix", "build_transition_operator",
            "TransitionOperator"]
@@ -147,7 +146,7 @@ def _pagerank_edges(src, dst, weight, inv_deg, dangling, damping, n: int,
     axes = tuple(mesh.axis_names)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(axes), P(axes), P(axes), P(), P(), P()),
         out_specs=P(),
     )

@@ -991,7 +991,7 @@ def _lm_prefill_paged_jit(params, pages, table, chunk, chunk_start, length,
     # pages at the END (not interleaved with the per-layer math), with an
     # optimization barrier pinning the gathers' output layout: without it
     # the attention einsum's preferred operand layout propagates THROUGH
-    # the gather to the slab parameter and XLA relayouts (copies) every
+    # the gather to the slab parameter and XLA copies (re-lays-out) every
     # (num_pages, ...) buffer per call — a cost scaling with the POOL, not
     # the chunk (measured ~2.5x per chunk on the bench pool; the barrier
     # moves the transpose onto the small gathered context instead)
@@ -1260,8 +1260,7 @@ def _kv_page_copy_jit(pages, src, dst):
 
 
 # forward the private jit cache-size probe through the un-jitted shims (the
-# no-recompile tests/benches read it; getattr-guarded everywhere, so its
-# absence on a future JAX merely skips those checks)
+# no-recompile tests/benches read it)
 for _pub, _jit in ((lm_generate, _lm_generate_jit),
                    (lm_generate_batch, _lm_generate_batch_jit),
                    (lm_prefill_slot, _lm_prefill_slot_jit),
@@ -1269,8 +1268,7 @@ for _pub, _jit in ((lm_generate, _lm_generate_jit),
                    (lm_prefill_paged, _lm_prefill_paged_jit),
                    (lm_decode_paged, _lm_decode_paged_jit),
                    (kv_page_copy, _kv_page_copy_jit)):
-    if hasattr(_jit, "_cache_size"):
-        _pub._cache_size = _jit._cache_size
+    _pub._cache_size = _jit._cache_size
 del _pub, _jit
 
 

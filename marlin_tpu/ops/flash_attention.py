@@ -29,7 +29,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .pallas_kernels import _interpret
-from ..utils.compat import shape_dtype_struct, vma_of
 
 __all__ = ["flash_attention_panel", "flash_attention_panel_bwd",
            "flash_attention_single_panel", "block_divisor"]
@@ -39,7 +38,7 @@ _NEG = -1e30
 # Every kernel dot pins an EXPLICIT precision: left to the backend default,
 # the f32 matmuls silently ran single-pass bf16 after a runtime update
 # changed Mosaic's default — rel err 3.03e-3 (2^-8 mantissa) against the
-# pinned-precision oracle, caught by tools/tpu_smoke.py (rounds 2-4 rode the
+# pinned-precision oracle, caught by an on-chip smoke run (rounds 2-4 rode the
 # OLD default, which extended f32 operands to true-f32 MXU passes — the
 # class every prior measurement of this kernel had). The pin is HIGHEST:
 # Mosaic lowers exactly DEFAULT and HIGHEST (HIGH/bf16_3x is rejected:
@@ -299,7 +298,7 @@ def flash_attention_panel_bwd(q, k, v, do, lse, delta, q_offset, k_offset,
     scalars = jnp.stack([jnp.asarray(q_offset, jnp.int32),
                          jnp.asarray(k_offset, jnp.int32),
                          jnp.asarray(valid_len, jnp.int32)])
-    vma = vma_of(q)
+    vma = jax.typeof(q).vma
     f32 = jnp.float32
     g = bq // 128
     lse2 = lse.reshape(sq // 128, 128)
@@ -330,8 +329,8 @@ def flash_attention_panel_bwd(q, k, v, do, lse, delta, q_offset, k_offset,
             ],
         ),
         out_shape=[
-            shape_dtype_struct((skv, d), f32, vma=vma),
-            shape_dtype_struct((skv, d), f32, vma=vma),
+            jax.ShapeDtypeStruct((skv, d), f32, vma=vma),
+            jax.ShapeDtypeStruct((skv, d), f32, vma=vma),
         ],
         interpret=interpret,
     )(scalars, q, do, lse2, delta2, k, v)
@@ -354,7 +353,7 @@ def flash_attention_panel_bwd(q, k, v, do, lse, delta, q_offset, k_offset,
             out_specs=pl.BlockSpec((bq, d), lambda i, j, *_: (i, 0)),
             scratch_shapes=[pltpu.VMEM((bq, d), f32)],
         ),
-        out_shape=shape_dtype_struct((sq, d), f32, vma=vma),
+        out_shape=jax.ShapeDtypeStruct((sq, d), f32, vma=vma),
         interpret=interpret,
     )(scalars, q, do, lse2, delta2, k, v)
     return dq, dk, dv
@@ -428,14 +427,14 @@ def flash_attention_panel(q, k, v, m, l, acc, q_offset, k_offset, valid_len,
                              bq=bq, bkv=bkv)
     # under shard_map the inputs carry varying-manual-axes types; the outputs
     # must declare the same so the vma checker can see through pallas_call
-    vma = vma_of(q)
+    vma = jax.typeof(q).vma
     m2, l2, a2 = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=[
-            shape_dtype_struct((sq // 128, 128), jnp.float32, vma=vma),
-            shape_dtype_struct((sq // 128, 128), jnp.float32, vma=vma),
-            shape_dtype_struct((sq, d), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((sq // 128, 128), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((sq // 128, 128), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((sq, d), jnp.float32, vma=vma),
         ],
         interpret=interpret,
     )(scalars, q, k, v, m.reshape(sq // 128, 128),

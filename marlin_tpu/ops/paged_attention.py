@@ -156,7 +156,7 @@ def _paged_decode_attention_call(q, k_pages, v_pages, tables, lengths,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, kvh, group, dh), q.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(tables, lengths, q, k_pages, v_pages)

@@ -314,7 +314,7 @@ def bsr_spmm_pallas(bsr: BsrMatrix, b, interpret: bool | None = None) -> jax.Arr
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((n_block_rows, bs, pp), f32),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(brows, bcols, copy_of, slot_of.astype(jnp.int32),

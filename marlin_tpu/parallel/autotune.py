@@ -12,10 +12,9 @@ empirical dispatch that beats any static heuristic wherever the heuristic's
 model of the machine is wrong (e.g. dispatch-latency-bound mid sizes, or
 meshes where resharding costs dominate).
 
-Timing discipline: dispatch is async (and the relay environment adds a fixed
-sync cost), so each candidate is compiled first, then ``reps`` calls are
-enqueued back-to-back and forced once with a scalar fetch — the same
-``MTUtils.evaluate`` discipline the benchmarks use.
+Timing discipline: dispatch is async, so each candidate is compiled first,
+then ``reps`` calls are enqueued back-to-back and forced once with a scalar
+fetch — the same ``MTUtils.evaluate`` discipline the benchmarks use.
 """
 
 from __future__ import annotations

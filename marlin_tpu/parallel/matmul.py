@@ -41,7 +41,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..config import get_config
 from ..mesh import default_mesh, pad_to_multiple
-from ..utils.compat import shard_map
 from .carma import split_method
 
 _M, _K, _N = "m", "k", "n"
@@ -97,7 +96,7 @@ def _rmm_fn(mesh3: Mesh, precision: str, accum_dtype):
 
     @jax.jit
     def f(a, b):
-        return shard_map(
+        return jax.shard_map(
             local,
             mesh=mesh3,
             in_specs=(P(_M, _K), P(_K, _N)),
@@ -300,7 +299,7 @@ def _fused_fn(
             b = jnp.pad(b_pad[:k, :n], ((0, kp_r - k), (0, np_r - n)))
             a = jax.lax.with_sharding_constraint(a, sh_a)
             b = jax.lax.with_sharding_constraint(b, sh_b)
-            c = shard_map(
+            c = jax.shard_map(
                 local, mesh=mesh3,
                 in_specs=(P(_M, _K), P(_K, _N)), out_specs=P(_M, _N),
             )(a, b)

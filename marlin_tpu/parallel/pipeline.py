@@ -42,7 +42,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..mesh import ROWS, default_mesh
-from ..utils.compat import pcast, shard_map
 
 __all__ = ["pipeline_apply", "stack_stage_params", "split_microbatches"]
 
@@ -116,7 +115,7 @@ def pipeline_apply(stage_params, stage_fn, x, mesh: Mesh | None = None,
     pspecs = jax.tree.map(spec, stage_params)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(pspecs, P(*(None,) * xm.ndim)),
         out_specs=P(*(None,) * xm.ndim),
         # manualize ONLY the pipeline axis: every other mesh axis stays Auto
@@ -162,7 +161,7 @@ def pipeline_apply(stage_params, stage_fn, x, mesh: Mesh | None = None,
         # the tick output is device-varying (axis_index / ppermute); the
         # zero init must carry the same varying-manual-axes type
         init = jax.tree.map(
-            lambda a: pcast(a, (axis,), to="varying"), init)
+            lambda a: jax.lax.pcast(a, (axis,), to="varying"), init)
         (_, out), _ = jax.lax.scan(
             tick, init, jnp.arange(n_micro + n_stages - 1))
         # every device but the last holds zeros: psum collects AND replicates

@@ -26,7 +26,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..config import get_config
 from ..mesh import ROWS, default_mesh, pad_to_multiple
-from ..utils.compat import pcast, shard_map
 
 __all__ = ["ring_matmul"]
 
@@ -55,7 +54,7 @@ def _ring_fn(mesh: Mesh, axis: str, precision: str, accum_dtype):
             )
             return b_next, acc
 
-        acc0 = pcast(
+        acc0 = jax.lax.pcast(
             jnp.zeros((a_blk.shape[0], b_blk.shape[1]), accum_dtype),
             (axis,), to="varying",
         )
@@ -64,7 +63,7 @@ def _ring_fn(mesh: Mesh, axis: str, precision: str, accum_dtype):
 
     @jax.jit
     def f(a, b):
-        return shard_map(
+        return jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(P(axis, None), P(axis, None)),

@@ -32,7 +32,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..mesh import ROWS, default_mesh, pad_to_multiple
-from ..utils.compat import shard_map
 
 __all__ = ["ulysses_attention"]
 
@@ -99,7 +98,7 @@ def _ulysses_fn(mesh: Mesh, axis: str, causal: bool, scale: float):
     def f(q, k, v, valid_len):
         # check_vma off: the pallas interpreter's block slicing mixes varying
         # and invariant operands (same caveat as the ring flash path)
-        return shard_map(
+        return jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(P(None, axis, None),) * 3 + (P(),),

@@ -434,7 +434,7 @@ def aot_compile_buckets(params: dict, heads: int, buckets: Sequence[Bucket],
     :func:`~marlin_tpu.models.planner.kv_page_bytes` IS its steady-state
     cache footprint, whatever the bucket set (docs/serving.md, bucket
     tuning). Requires libtpu
-    (:func:`~marlin_tpu.utils.aot.supports_aot_tpu`). Peak accounting
+    (:func:`~marlin_tpu.utils.aot.tpu_topology`). Peak accounting
     degrades to the temp+argument+output lower bound on PJRT builds whose
     stats object lacks ``peak_memory_in_bytes`` (:func:`_peak_bytes`)."""
     import jax

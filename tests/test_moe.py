@@ -17,17 +17,6 @@ from marlin_tpu.models.moe import (init_moe, moe_capacity, moe_decode_ffn,
 from marlin_tpu.models.transformer import (TransformerLM, init_transformer,
                                            lm_loss)
 
-import jax as _jax_mod
-
-# jax-0.4.37-era gate: these cases exercise behaviour that only works in
-# the top-level jax.shard_map / jax.typeof era (partial-auto shard_map,
-# scan-carry replication checks) -- same class as tests/test_aot_tpu.py.
-needs_modern_jax = pytest.mark.skipif(
-    getattr(_jax_mod, "shard_map", None) is None
-    or not hasattr(_jax_mod, "typeof"),
-    reason="needs modern jax (top-level shard_map / typeof era)")
-
-
 
 @pytest.fixture
 def mesh():
@@ -160,7 +149,6 @@ def test_moe_init_interleaving():
     assert p["l1"]["moe"]["w1"].shape == (4, 16, 64)
 
 
-@needs_modern_jax
 def test_moe_lm_trains(mesh):
     toks = mt.models.transformer.synthetic_stream(257, vocab=32, seed=0)
     lm = TransformerLM(vocab=32, d_model=16, heads=2, layers=2,
@@ -171,7 +159,6 @@ def test_moe_lm_trains(mesh):
     assert "moe" in params["l0"]
 
 
-@needs_modern_jax
 def test_moe_grads_reach_router(mesh):
     # the load-balance aux and the combine weights both feed wg's gradient
     # (jitted, like lm_train_step — eager grad through the ring's internal
@@ -184,7 +171,6 @@ def test_moe_grads_reach_router(mesh):
     assert np.isfinite(gw).all() and np.abs(gw).max() > 0
 
 
-@needs_modern_jax
 def test_moe_decode_matches_forward(mesh):
     # greedy decode through the MoE decode path continues the argmax of the
     # training forward (capacity high enough that prefill routing is exact)
@@ -240,7 +226,6 @@ def test_moe_decode_compute_dtype():
     assert out.dtype == jnp.bfloat16
 
 
-@needs_modern_jax
 def test_moe_bf16_training(mesh):
     # mixed precision composes with MoE: bf16 activations route through f32
     # gating and bf16 expert matmuls; the step learns and params stay f32

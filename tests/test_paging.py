@@ -388,9 +388,8 @@ def test_paged_compiles_bounded_three_per_bucket(params, compile_count):
     eos, mixed sampling knobs, multi-chunk prompts — adds ZERO."""
     from marlin_tpu.models.transformer import kv_page_copy
 
-    probes = [getattr(f, "_cache_size", None)
+    probes = [f._cache_size
               for f in (lm_prefill_paged, lm_decode_paged, kv_page_copy)]
-    probes = [p for p in probes if p is not None]
     before = sum(p() for p in probes)
     with _engine(params, prefill_chunk=2 * PAGE_LEN) as eng:
         assert eng.warmup() == len(BUCKETS)

@@ -14,17 +14,6 @@ import marlin_tpu as mt
 from marlin_tpu.parallel.pipeline import (pipeline_apply, split_microbatches,
                                           stack_stage_params)
 
-import jax as _jax_mod
-
-# jax-0.4.37-era gate: these cases exercise behaviour that only works in
-# the top-level jax.shard_map / jax.typeof era (partial-auto shard_map,
-# scan-carry replication checks) -- same class as tests/test_aot_tpu.py.
-needs_modern_jax = pytest.mark.skipif(
-    getattr(_jax_mod, "shard_map", None) is None
-    or not hasattr(_jax_mod, "typeof"),
-    reason="needs modern jax (top-level shard_map / typeof era)")
-
-
 
 def _mlp_stage(p, x):
     return jnp.tanh(x @ p["w"] + p["b"])
@@ -50,7 +39,6 @@ def mesh4():
     return mt.create_mesh((4, 2))
 
 
-@needs_modern_jax
 def test_pipeline_matches_sequential(mesh4):
     rng = np.random.default_rng(0)
     d, batch = 16, 24
@@ -63,7 +51,6 @@ def test_pipeline_matches_sequential(mesh4):
                                rtol=1e-5, atol=1e-6)
 
 
-@needs_modern_jax
 def test_pipeline_default_microbatch(mesh4):
     # default microbatch = batch // n_stages: still exact
     rng = np.random.default_rng(1)
@@ -77,7 +64,6 @@ def test_pipeline_default_microbatch(mesh4):
                                rtol=1e-5, atol=1e-6)
 
 
-@needs_modern_jax
 def test_pipeline_single_microbatch_many(mesh4):
     # M > S and M = batch (microbatch=1): deepest schedule, still exact
     rng = np.random.default_rng(2)
@@ -91,7 +77,6 @@ def test_pipeline_single_microbatch_many(mesh4):
                                rtol=1e-5, atol=1e-6)
 
 
-@needs_modern_jax
 def test_pipeline_grad_matches_sequential(mesh4):
     rng = np.random.default_rng(3)
     d, batch = 8, 16
@@ -121,7 +106,6 @@ def test_pipeline_grad_matches_sequential(mesh4):
                                    rtol=1e-4, atol=1e-6)
 
 
-@needs_modern_jax
 def test_pipeline_jit_train_step(mesh4):
     # one jitted SGD step through the pipeline drops the loss
     rng = np.random.default_rng(4)
@@ -153,7 +137,6 @@ def test_pipeline_validation(mesh4):
         split_microbatches(jnp.zeros((10, 4)), 3)
 
 
-@needs_modern_jax
 def test_pipeline_tensor_parallel_stage_matches_sequential(mesh4):
     # pp x tp numerically: stage weights additionally sharded over "cols"
     # (column-split w0, row-split w1 — XLA's activation psum runs inside the

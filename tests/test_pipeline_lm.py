@@ -17,17 +17,6 @@ from marlin_tpu.models.transformer import (_head_logits, _n_layers,
                                            _rmsnorm, init_transformer,
                                            synthetic_stream)
 
-import jax as _jax_mod
-
-# jax-0.4.37-era gate: these cases exercise behaviour that only works in
-# the top-level jax.shard_map / jax.typeof era (partial-auto shard_map,
-# scan-carry replication checks) -- same class as tests/test_aot_tpu.py.
-needs_modern_jax = pytest.mark.skipif(
-    getattr(_jax_mod, "shard_map", None) is None
-    or not hasattr(_jax_mod, "typeof"),
-    reason="needs modern jax (top-level shard_map / typeof era)")
-
-
 
 @pytest.fixture
 def mesh4():
@@ -52,7 +41,6 @@ def _token_batch(b, t, vocab=32):
                      for i in range(b)])
 
 
-@needs_modern_jax
 def test_pp_lm_loss_matches_sequential(mesh4):
     p = init_transformer(jax.random.key(0), 32, 32, 2, 4)
     toks = _token_batch(8, 17)
@@ -62,7 +50,6 @@ def test_pp_lm_loss_matches_sequential(mesh4):
     np.testing.assert_allclose(got, want, rtol=1e-5)
 
 
-@needs_modern_jax
 def test_pp_lm_trains(mesh4):
     p = init_transformer(jax.random.key(1), 32, 32, 2, 4)
     sp, outer = pp_stage_params(p, mesh4)
@@ -89,7 +76,6 @@ def test_pp_stage_params_validation(mesh4):
         pp_stage_params(pm, mesh4)
 
 
-@needs_modern_jax
 def test_pp_lm_gqa(mesh4):
     # GQA params flow through the stage blocks (kv broadcast inside)
     p = init_transformer(jax.random.key(4), 32, 32, 4, 4, kv_heads=2)
@@ -100,7 +86,6 @@ def test_pp_lm_gqa(mesh4):
     np.testing.assert_allclose(got, want, rtol=1e-5)
 
 
-@needs_modern_jax
 def test_pp_lm_matches_flagship_lm_loss(mesh4):
     # cross-MODEL parity: the pipelined stack must compute the same function
     # as TransformerLM's lm_loss on the same params (pins _pp_block to the
@@ -116,7 +101,6 @@ def test_pp_lm_matches_flagship_lm_loss(mesh4):
     np.testing.assert_allclose(got, want, rtol=2e-4)
 
 
-@needs_modern_jax
 def test_pp_lm_grad_matches_sequential(mesh4):
     # gradient parity through the reversed pipeline (incl. the masked-psum
     # output collection), stage-by-stage against the sequential stack

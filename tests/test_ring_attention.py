@@ -7,17 +7,6 @@ import jax.numpy as jnp
 
 from marlin_tpu.parallel.ring_attention import attention_reference, ring_attention
 
-import jax as _jax_mod
-
-# jax-0.4.37-era gate: these cases exercise behaviour that only works in
-# the top-level jax.shard_map / jax.typeof era (partial-auto shard_map,
-# scan-carry replication checks) -- same class as tests/test_aot_tpu.py.
-needs_modern_jax = pytest.mark.skipif(
-    getattr(_jax_mod, "shard_map", None) is None
-    or not hasattr(_jax_mod, "typeof"),
-    reason="needs modern jax (top-level shard_map / typeof era)")
-
-
 
 def _qkv(seq, d, seed, heads=None):
     rng = np.random.default_rng(seed)
@@ -142,7 +131,7 @@ def test_ring_attention_bad_precision(mesh):
 
 
 @pytest.mark.parametrize(
-    "backend", [pytest.param("xla", marks=needs_modern_jax), "flash"])
+    "backend", ["xla", "flash"])
 def test_ring_attention_grad(mesh, backend):
     # long-context TRAINING: gradients must flow through both backends (the
     # flash path's custom VJP runs the two-pass Pallas recompute kernels,

@@ -198,14 +198,13 @@ def test_acceptance_continuous_batching(params, paged):
                for _ in range(4)]
 
     if paged:
-        probes = [getattr(f, "_cache_size", None)
+        probes = [f._cache_size
                   for f in (lm_prefill_paged, lm_decode_paged)]
         per_bucket = 2  # + the shared page-copy program, counted below
     else:
-        probes = [getattr(f, "_cache_size", None)
+        probes = [f._cache_size
                   for f in (lm_prefill_slot, lm_decode_rows)]
         per_bucket = 2
-    probes = [p for p in probes if p is not None]
     before = sum(p() for p in probes)
 
     eng = _engine(params, clock=clock, paged=paged)
@@ -738,21 +737,6 @@ def test_percentile_helper():
     assert percentile([7.0], 99) == 7.0
     with pytest.raises(ValueError):
         percentile([], 50)
-
-
-def test_aot_compile_buckets_reports_hbm(params):
-    """Compile-only TPU evidence for bucket sizing (needs libtpu)."""
-    from marlin_tpu.serving import aot_compile_buckets
-    from marlin_tpu.utils.aot import supports_aot_tpu
-
-    if not supports_aot_tpu():
-        pytest.skip("no libtpu: compile-only TPU topology unavailable")
-    # this tiny model's compiler peak (weights + workspace) dwarfs its KV
-    # slab arithmetic, so the planner-honesty warning MUST fire here — the
-    # same signal that catches a real under-budgeted serve_max_batch
-    with pytest.warns(RuntimeWarning, match="measured peak"):
-        peaks = aot_compile_buckets(params, HEADS, [(8, 4)], max_batch=2)
-    assert set(peaks) == {(8, 4)} and peaks[(8, 4)] > 0
 
 
 @pytest.mark.slow

@@ -8,20 +8,8 @@ import jax.numpy as jnp
 from marlin_tpu.models import TransformerLM, lm_generate, lm_loss, transformer_forward
 from marlin_tpu.models.transformer import synthetic_stream as _tokens
 
-import jax as _jax_mod
 
-# jax-0.4.37-era gate: these cases exercise behaviour that only works in
-# the top-level jax.shard_map / jax.typeof era (partial-auto shard_map,
-# scan-carry replication checks) -- same class as tests/test_aot_tpu.py.
-needs_modern_jax = pytest.mark.skipif(
-    getattr(_jax_mod, "shard_map", None) is None
-    or not hasattr(_jax_mod, "typeof"),
-    reason="needs modern jax (top-level shard_map / typeof era)")
-
-
-
-@pytest.mark.parametrize(
-    "attn", [pytest.param("ring", marks=needs_modern_jax), "ulysses"])
+@pytest.mark.parametrize("attn", ["ring", "ulysses"])
 def test_transformer_trains(mesh, attn):
     lm = TransformerLM(vocab=64, d_model=32, heads=4, layers=1,
                        learning_rate=5e-3, attn=attn, seed=0)
@@ -50,7 +38,6 @@ def test_transformer_forward_shape(mesh):
     assert logits.shape == (50, 32)
 
 
-@needs_modern_jax
 def test_transformer_checkpointing(mesh, tmp_path):
     from marlin_tpu.io.checkpoint import load_checkpoint
 
@@ -125,7 +112,6 @@ def test_lm_generate_bf16_params(mesh):
     assert out.shape == (6,) and np.all((out >= 0) & (out < 16))
 
 
-@needs_modern_jax
 def test_lm_generate_reproduces_trained_pattern(mesh):
     """After training on a noise-free periodic stream, greedy decode from one
     period must continue the period — the end-to-end train->generate loop."""
@@ -144,7 +130,6 @@ def test_lm_generate_reproduces_trained_pattern(mesh):
                      noise=0.0)[: len(out)]
     assert out.tolist() == expect.tolist()
 
-@needs_modern_jax
 def test_chunked_loss_matches_dense(mesh):
     """loss_chunk changes memory, not math — value AND gradients, on a
     sequence length that is not a multiple of the chunk (mask path runs)."""
@@ -171,7 +156,6 @@ def test_chunked_loss_matches_dense(mesh):
                                    rtol=2e-4, atol=1e-6, err_msg=str(ka))
 
 
-@needs_modern_jax
 def test_chunked_loss_trains(mesh):
     lm = TransformerLM(vocab=64, d_model=32, heads=4, layers=1,
                        learning_rate=5e-3, remat=True, loss_chunk=64, seed=0)
@@ -179,7 +163,6 @@ def test_chunked_loss_trains(mesh):
     assert losses[-1] < losses[0] * 0.8, (losses[0], losses[-1])
 
 
-@needs_modern_jax
 def test_transformer_trains_through_flash(mesh):
     """End-to-end LM training with the ring FLASH backend pinned: the Pallas
     forward + two-pass Pallas backward (interpret mode on the CPU mesh) carry
@@ -206,9 +189,7 @@ def test_lm_generate_no_recompile_across_temperatures(mesh):
     prompt = np.array([1, 2, 3], np.int32)
     lm_generate(p, prompt, jax.random.key(0), heads=2, max_len=16, steps=4,
                 temperature=0.0)
-    cache_size = getattr(lm_generate, "_cache_size", None)
-    if cache_size is None:  # private jitted-fn API; absent on newer JAX
-        pytest.skip("jit cache-size probe unavailable on this JAX")
+    cache_size = lm_generate._cache_size
     n0 = cache_size()
     outs = [np.asarray(lm_generate(p, prompt, jax.random.key(0), heads=2,
                                    max_len=16, steps=4, temperature=t))
@@ -226,7 +207,6 @@ def test_transformer_generate_facade(mesh):
     assert out.shape == (7,) and np.all((out >= 0) & (out < 16))
 
 
-@needs_modern_jax
 def test_compute_dtype_bf16_trains(mesh):
     """Mixed precision (bf16 activations, f32 params/Adam): training must
     still converge on the periodic stream, and the loss must track the f32
@@ -248,7 +228,6 @@ def test_compute_dtype_bf16_trains(mesh):
     assert x.dtype == jnp.bfloat16
 
 
-@needs_modern_jax
 def test_compute_dtype_flash_backend(mesh):
     """bf16 activations through the Pallas flash path (interpret on CPU):
     gradients stay finite and the loss matches the xla backend run."""
@@ -263,7 +242,6 @@ def test_compute_dtype_flash_backend(mesh):
     np.testing.assert_allclose(lfl, lxl, rtol=0.08)
 
 
-@needs_modern_jax
 def test_generate_compute_dtype_bf16(mesh):
     """Decode honors compute_dtype: bf16 KV caches, finite f32 logits, valid
     tokens; greedy decode still tracks the trained pattern."""
@@ -288,7 +266,6 @@ def test_generate_compute_dtype_bf16(mesh):
     assert all(c.dtype == jnp.bfloat16 for kv in caches.values() for c in kv)
 
 
-@needs_modern_jax
 def test_mlp_chunk_matches_dense(mesh):
     """mlp_chunk changes memory, not math — value AND gradients, on a length
     that is not a multiple of the chunk (remainder path runs)."""
@@ -315,7 +292,6 @@ def test_mlp_chunk_matches_dense(mesh):
                                    rtol=2e-4, atol=1e-6, err_msg=str(ka))
 
 
-@needs_modern_jax
 def test_mlp_chunk_trains(mesh):
     lm = TransformerLM(vocab=64, d_model=32, heads=4, layers=1,
                        learning_rate=5e-3, remat=True, loss_chunk=64,
@@ -380,7 +356,6 @@ def test_flash_prefill_generates(mesh, monkeypatch):
     assert out.tolist() == cur
 
 
-@needs_modern_jax
 def test_offload_residuals_matches(mesh):
     """offload_residuals parks the remat checkpoints in host RAM between
     forward and backward — memory placement, not math: jitted loss and grads
@@ -408,7 +383,6 @@ def test_offload_residuals_matches(mesh):
                                    rtol=1e-5, atol=1e-7, err_msg=str(ka))
 
 
-@needs_modern_jax
 def test_offload_residuals_trains(mesh):
     lm = TransformerLM(vocab=64, d_model=32, heads=4, layers=1,
                        learning_rate=5e-3, remat=True, loss_chunk=64,
@@ -690,12 +664,11 @@ def test_topk_topp_sampling(mesh):
     # top_p legitimately compiles the with-nucleus variant — top_p=None is
     # a statically different, sort-free program — so warm it before counting)
     gen(temperature=1.0, top_p=0.5)
-    cache_size = getattr(lm_generate, "_cache_size", None)
-    if cache_size is not None:
-        n0 = cache_size()
-        for tp in (0.3, 0.6, 0.95):
-            gen(temperature=1.0, top_p=tp)
-        assert cache_size() == n0, "top_p sweep recompiled"
+    cache_size = lm_generate._cache_size
+    n0 = cache_size()
+    for tp in (0.3, 0.6, 0.95):
+        gen(temperature=1.0, top_p=tp)
+    assert cache_size() == n0, "top_p sweep recompiled"
     # batched path honors the same contract
     from marlin_tpu.models import lm_generate_batch
 
@@ -742,7 +715,6 @@ def test_gqa_shapes_and_mha_equivalence(mesh):
                           kv_heads=bad).init_params()
 
 
-@needs_modern_jax
 def test_gqa_trains_and_decodes(mesh):
     """GQA end to end: training converges through the ring (K/V broadcast to
     query heads), and greedy cached decode equals the full-forward argmax

@@ -1,0 +1,11 @@
+"""layer: scheduler. Share of the traced window in which no device operation
+ran and the engine's worker (``serving/engine.py`` ``_run_paged``) was
+inside ``serve.decode``: the buckets' dispatches, syncs and retirements.
+The four ``idle_pct.*`` add up to ``device_idle_pct.serve``.
+Source: device trace, cut by the program's spans."""
+
+from benchmarks import engine_spans
+
+
+def read(ctx):
+    return engine_spans.idle_pct(ctx, "decode")

@@ -102,6 +102,7 @@ from ..obs.exposition import (register_health_provider,
                               unregister_slo_provider)
 from ..obs.metrics import get_registry
 from ..utils import faults
+from ..utils.tracing import annotate
 from .batcher import (BatchFormer, bucket_program_key, capture_bucket_costs,
                       normalize_buckets, warmup_buckets)
 from .kvpool import (PagedGroup, PagedKVPool, PagePoolExhausted,
@@ -114,6 +115,11 @@ from .request import (SHED_REASON_PREFIX, STATUS_ERROR, STATUS_EXPIRED,
                       AdmissionQueue, Request, Result, ResultHandle)
 
 __all__ = ["ServeEngine", "MigrationError"]
+
+
+def _bucket_tag(bucket) -> str:
+    """A bucket as a span field: ``256x128`` (``als x 8`` joins alike)."""
+    return "x".join(str(b) for b in bucket)
 
 
 class MigrationError(RuntimeError):
@@ -772,104 +778,108 @@ class ServeEngine:
             return self._submit(request, ctx)
 
     def _submit(self, request: Request, ctx) -> ResultHandle:
-        faults.fire("serve.enqueue", path=str(request.rid))
-        handle = ResultHandle(request)
-        now = self._clock()
-        prog = self._programs.get(request.program)
-        if prog is None:
-            return self._refuse(handle, STATUS_REJECTED, (
-                f"unknown program {request.program!r} (this engine serves "
-                f"{sorted(self._programs)})"))
-        why = prog.validate(request)
-        if why is not None:
-            return self._refuse(handle, STATUS_REJECTED, why)
-        pbucket = prog.pick_bucket(request)
-        if pbucket is None:
-            return self._refuse(handle, STATUS_REJECTED,
-                                prog.refuse_no_bucket(request))
-        # former/pool key: LM keeps its bare (prompt, steps) tuple (the
-        # pre-refactor keys — events, pools, and migration manifests are
-        # unchanged); other programs namespace theirs under their name
-        bucket = (pbucket if request.program == "lm"
-                  else (prog.name,) + tuple(pbucket))
-        # resolve the relative/default deadline to an absolute engine-clock
-        # one, ONCE — a router failover or worker restart must not hand the
-        # request a fresh budget
-        if request.deadline is None:
-            rel = request.deadline_s
-            if rel is None:
-                rel = get_config().serve_default_deadline_s
-            if rel is not None:
-                request.deadline = now + float(rel)
-                request.deadline_s = None
-        if request.deadline is not None and request.deadline <= now:
-            return self._refuse(handle, STATUS_EXPIRED, (
-                f"deadline {request.deadline} already passed at submission "
-                f"(now {now})"))
-        # deadline-aware admission: with service history (EWMA of ok
-        # per-request seconds), a request whose projected completion behind
-        # the current queue already overshoots its deadline is refused NOW —
-        # cheaper for everyone than decoding it into a guaranteed expiry
-        if request.deadline is not None and self._service_ewma > 0.0:
-            projected = now + self._service_ewma * (
-                1.0 + self._queue.count / self.max_batch)
-            if projected > request.deadline:
+        with annotate("serve.submit", rid=request.rid) as span:
+            faults.fire("serve.enqueue", path=str(request.rid))
+            handle = ResultHandle(request)
+            now = self._clock()
+            prog = self._programs.get(request.program)
+            if prog is None:
                 return self._refuse(handle, STATUS_REJECTED, (
-                    f"deadline unmeetable: projected completion {projected:.3f}"
-                    f" > deadline {request.deadline:.3f} at queue depth "
-                    f"{self._queue.count} (service est "
-                    f"{self._service_ewma:.3f}s)"))
-        # the program prices its own resource units (LM: actual KV pages or
-        # the slab worst case; one-shot programs: their padded device row)
-        # against the one shared HBM admission budget; a capacity refusal
-        # (e.g. more pages than the pool holds) raises the reason
-        try:
-            cost = prog.admission_cost(request, pbucket)
-        except ValueError as exc:
-            return self._refuse(handle, STATUS_REJECTED, str(exc))
-        if get_config().serve_admission_calibration:
-            cost = self._calibrate_cost(request, pbucket, cost)
-        reason = self._queue.try_admit(
-            cost, priority=request.priority,
-            deadline_slack_s=(request.deadline - now
-                              if request.deadline is not None else None))
-        if reason is not None:
-            # a drain/close-shut gate is a deterministic shutting_down
-            # Result (the caller can failover/retry elsewhere); overload
-            # stays a rejection with the backpressure reason. Matching the
-            # RETURNED reason (the close reason never changes once set)
-            # keeps a "queue full" verdict that raced a concurrent drain
-            # labeled as the backpressure it was
-            if reason == self._queue.closed_reason:
-                return self._refuse(handle, STATUS_SHUTTING_DOWN, reason)
-            if (self._slo is not None
-                    and reason.startswith(SHED_REASON_PREFIX)):
-                self._slo.record_shed()
-            return self._refuse(handle, STATUS_REJECTED, reason)
-        entry = _Entry(request, handle, bucket, cost, now, trace=ctx)
-        with self._cond:
-            if self._state != "running":
-                admitted = False
-            else:
-                self._former.add(entry)
-                if self._idle:
-                    # an IDLE worker's heartbeat is legitimately old (it
-                    # blocks in cond.wait): restart the watchdog window at
-                    # admission so the wakeup isn't a false positive. A
-                    # busy (possibly wedged) worker is NOT idle — traffic
-                    # must never keep refreshing a dead worker's pulse
-                    self._heartbeat = time.monotonic()
-                self._cond.notify_all()
-                admitted = True
-        if not admitted:  # raced with drain()/close(): resolve, don't strand
-            self._queue.release(cost)
-            return self._refuse(handle, STATUS_SHUTTING_DOWN,
-                                "engine is shutting down")
-        self.metrics.record_enqueue(request.rid, bucket, self._queue.count,
-                                    program=request.program)
-        self.metrics.record_queue(self._queue.count,
-                                  self._queue.bytes_in_flight)
-        return handle
+                    f"unknown program {request.program!r} (this engine serves "
+                    f"{sorted(self._programs)})"))
+            why = prog.validate(request)
+            if why is not None:
+                return self._refuse(handle, STATUS_REJECTED, why)
+            pbucket = prog.pick_bucket(request)
+            if pbucket is None:
+                return self._refuse(handle, STATUS_REJECTED,
+                                    prog.refuse_no_bucket(request))
+            # former/pool key: LM keeps its bare (prompt, steps) tuple (the
+            # pre-refactor keys — events, pools, and migration manifests are
+            # unchanged); other programs namespace theirs under their name
+            bucket = (pbucket if request.program == "lm"
+                      else (prog.name,) + tuple(pbucket))
+            span.set_metadata(bucket=_bucket_tag(bucket))
+            # resolve the relative/default deadline to an absolute
+            # engine-clock one, ONCE — a router failover or worker restart
+            # must not hand the request a fresh budget
+            if request.deadline is None:
+                rel = request.deadline_s
+                if rel is None:
+                    rel = get_config().serve_default_deadline_s
+                if rel is not None:
+                    request.deadline = now + float(rel)
+                    request.deadline_s = None
+            if request.deadline is not None and request.deadline <= now:
+                return self._refuse(handle, STATUS_EXPIRED, (
+                    f"deadline {request.deadline} already passed at "
+                    f"submission (now {now})"))
+            # deadline-aware admission: with service history (EWMA of ok
+            # per-request seconds), a request whose projected completion
+            # behind the current queue already overshoots its deadline is
+            # refused NOW — cheaper for everyone than decoding it into a
+            # guaranteed expiry
+            if request.deadline is not None and self._service_ewma > 0.0:
+                projected = now + self._service_ewma * (
+                    1.0 + self._queue.count / self.max_batch)
+                if projected > request.deadline:
+                    return self._refuse(handle, STATUS_REJECTED, (
+                        f"deadline unmeetable: projected completion "
+                        f"{projected:.3f} > deadline "
+                        f"{request.deadline:.3f} at queue depth "
+                        f"{self._queue.count} (service est "
+                        f"{self._service_ewma:.3f}s)"))
+            # the program prices its own resource units (LM: actual KV pages or
+            # the slab worst case; one-shot programs: their padded device row)
+            # against the one shared HBM admission budget; a capacity refusal
+            # (e.g. more pages than the pool holds) raises the reason
+            try:
+                cost = prog.admission_cost(request, pbucket)
+            except ValueError as exc:
+                return self._refuse(handle, STATUS_REJECTED, str(exc))
+            if get_config().serve_admission_calibration:
+                cost = self._calibrate_cost(request, pbucket, cost)
+            reason = self._queue.try_admit(
+                cost, priority=request.priority,
+                deadline_slack_s=(request.deadline - now
+                                  if request.deadline is not None else None))
+            if reason is not None:
+                # a drain/close-shut gate is a deterministic shutting_down
+                # Result (the caller can failover/retry elsewhere); overload
+                # stays a rejection with the backpressure reason. Matching the
+                # RETURNED reason (the close reason never changes once set)
+                # keeps a "queue full" verdict that raced a concurrent drain
+                # labeled as the backpressure it was
+                if reason == self._queue.closed_reason:
+                    return self._refuse(handle, STATUS_SHUTTING_DOWN, reason)
+                if (self._slo is not None
+                        and reason.startswith(SHED_REASON_PREFIX)):
+                    self._slo.record_shed()
+                return self._refuse(handle, STATUS_REJECTED, reason)
+            entry = _Entry(request, handle, bucket, cost, now, trace=ctx)
+            with self._cond:
+                if self._state != "running":
+                    admitted = False
+                else:
+                    self._former.add(entry)
+                    if self._idle:
+                        # an IDLE worker's heartbeat is legitimately old (it
+                        # blocks in cond.wait): restart the watchdog window at
+                        # admission so the wakeup isn't a false positive. A
+                        # busy (possibly wedged) worker is NOT idle — traffic
+                        # must never keep refreshing a dead worker's pulse
+                        self._heartbeat = time.monotonic()
+                    self._cond.notify_all()
+                    admitted = True
+            if not admitted:  # raced with drain()/close(): don't strand
+                self._queue.release(cost)
+                return self._refuse(handle, STATUS_SHUTTING_DOWN,
+                                    "engine is shutting down")
+            self.metrics.record_enqueue(request.rid, bucket, self._queue.count,
+                                        program=request.program)
+            self.metrics.record_queue(self._queue.count,
+                                      self._queue.bytes_in_flight)
+            return handle
 
     def submit_many(self, requests) -> list[ResultHandle]:
         return [self.submit(r) for r in requests]
@@ -1329,6 +1339,7 @@ class ServeEngine:
                 continue
             pool.caches, pool.tokens = caches, tokens
             launched.append((bucket, pool, live, t0, nxt))
+        synced = 0.0  # perf_counter at the previous bucket's landing
         for bucket, pool, live, t0, nxt in launched:
             try:
                 # analyze: ignore[host-sync] — THE one intentional sync per
@@ -1338,7 +1349,10 @@ class ServeEngine:
             except Exception as exc:
                 self._fail_pool(pools, bucket, exc)
                 continue
-            wall = time.perf_counter() - t0
+            # this bucket's own interval (see _step_paged): the time before
+            # the previous bucket landed belongs to that bucket
+            t0, synced = max(t0, synced), time.perf_counter()
+            wall = synced - t0
             self.metrics.record_step(bucket, len(live), self.max_batch, wall,
                                      program_key=self._prog_key(bucket))
             self.flight.record(
@@ -2051,66 +2065,104 @@ class ServeEngine:
                 claimed = []
                 with self._cond:
                     while True:
-                        if self._gen != gen:
-                            return  # superseded by a recovery
-                        if self._state == "freezing":
-                            # migration pause: park WITHOUT touching the
-                            # pools — freeze_rows() joins this thread and
-                            # takes over every resident row
-                            return
-                        busy = any(p.occupied_slots()
-                                   for p in pools.values())
-                        if self._mig_inbox:
-                            break  # service migration ops outside the lock
-                        if self._state == "closing":
-                            # resident rows (live AND mid-prefill) are the
-                            # work in flight: finish them (close() already
-                            # emptied the former)
-                            if not busy:
+                        with annotate("serve.claim") as span:
+                            if self._gen != gen:
+                                return  # superseded by a recovery
+                            if self._state == "freezing":
+                                # migration pause: park WITHOUT touching
+                                # the pools — freeze_rows() joins this
+                                # thread and takes over every resident row
                                 return
-                            break
-                        draining = self._state == "draining"
-                        claimed = self._claim_rowlevel(pools)
-                        if claimed or busy:
-                            break
-                        if draining:
-                            return  # nothing queued, nothing resident
+                            busy = any(p.occupied_slots()
+                                       for p in pools.values())
+                            if self._mig_inbox:
+                                break  # service migration ops unlocked
+                            if self._state == "closing":
+                                # resident rows (live AND mid-prefill) are
+                                # the work in flight: finish them (close()
+                                # already emptied the former)
+                                if not busy:
+                                    return
+                                break
+                            draining = self._state == "draining"
+                            claimed = self._claim_rowlevel(pools)
+                            span.set_metadata(claimed=len(claimed))
+                            if claimed or busy:
+                                break
+                            if draining:
+                                return  # nothing queued, nothing resident
                         self._idle = True
-                        self._cond.wait(None if self._real_clock
-                                        else _POLL_CAP_S)
+                        with annotate("serve.wait"):
+                            self._cond.wait(None if self._real_clock
+                                            else _POLL_CAP_S)
                         self._idle = False
                         if self._gen == gen:
                             self._heartbeat = time.monotonic()
                     self._claimed = claimed
-                with self._cond:
-                    if self._gen == gen and pool is not self._kvpool:
-                        # this generation dropped its pool (slab consumed
-                        # by a failed donated call): rebind to the rebuilt
-                        # one — the old object's arrays are deleted. Under
-                        # the lock + gen check: a stale generation must
-                        # never build (or adopt) the live generation's
-                        # pool
-                        pool = self._ensure_kvpool()
-                self._service_migrations(pool, pools, pf_queue)
-                prog_claimed = [e for e in claimed
-                                if self._is_program_bucket(e.bucket)]
-                lm_claimed = [e for e in claimed
-                              if not self._is_program_bucket(e.bucket)]
-                self._admit_paged(pool, pools, lm_claimed, pf_queue)
-                self._admit_program_rows(pools, prog_claimed)
-                claimed = []
-                with self._cond:
-                    if self._gen == gen:  # never clobber a successor's
-                        self._claimed = []  # claimed mirror
-                self._prefill_paged_chunk(pool, pools, pf_queue)
-                self._step_paged(pool, pools)
-                self._step_program_rows(pools)
+                # the iteration that does the work: opened once the claim
+                # (and any wait) has returned, so its counts are this
+                # iteration's and an idle engine holds no open span. The
+                # counts cost a pass over the resident rows: taken only
+                # while a trace is
+                with annotate("serve.iter") as span:
+                    if span.is_enabled():
+                        span.set_metadata(**self._paged_counts(pool, pools))
+                    with self._cond:
+                        if self._gen == gen and pool is not self._kvpool:
+                            # this generation dropped its pool (slab
+                            # consumed by a failed donated call): rebind to
+                            # the rebuilt one — the old object's arrays are
+                            # deleted. Under the lock + gen check: a stale
+                            # generation must never build (or adopt) the
+                            # live generation's pool
+                            pool = self._ensure_kvpool()
+                    self._service_migrations(pool, pools, pf_queue)
+                    prog_claimed = [e for e in claimed
+                                    if self._is_program_bucket(e.bucket)]
+                    lm_claimed = [e for e in claimed
+                                  if not self._is_program_bucket(e.bucket)]
+                    self._admit_paged(pool, pools, lm_claimed, pf_queue)
+                    self._admit_program_rows(pools, prog_claimed)
+                    claimed = []
+                    with self._cond:
+                        if self._gen == gen:  # never clobber a successor's
+                            self._claimed = []  # claimed mirror
+                    self._prefill_paged_chunk(pool, pools, pf_queue)
+                    self._step_paged(pool, pools)
+                    self._step_program_rows(pools)
         except BaseException as exc:  # worker death: recover or fail held
             held = [p.entries[i] for p in pools.values()
                     for i in p.occupied_slots()]
             if self._crash_handler(exc, claimed + held, gen):
                 return
             raise
+
+    def _paged_counts(self, pool, pools) -> dict:
+        """The ``serve.iter`` span's fields: the queue and the KV pool as
+        an iteration begins, its claim made (``queue_depth`` as the step
+        records have it: requests pending or in flight). ``row_pages`` are
+        the pages resident LM rows hold (reserved up front for prompt +
+        steps), ``kv_tokens`` the positions of them that are written (a row
+        mid-prefill has its prefilled prompt), ``pages_used`` adds what the
+        prefix cache keeps."""
+        resident = live = row_pages = kv_tokens = 0
+        for g in pools.values():
+            if isinstance(g, ProgramRowSet):
+                continue
+            for i in g.occupied_slots():
+                resident += 1
+                row_pages += len(g.row_pages[i] or ())
+                if g.pf_next[i] < 0:
+                    live += 1
+                    # analyze: ignore[host-sync] — host numpy bookkeeping
+                    kv_tokens += int(g.positions[i])
+                else:
+                    # analyze: ignore[host-sync] — host numpy bookkeeping
+                    kv_tokens += int(min(g.pf_next[i], g.lengths[i]))
+        return {"queue_depth": self._queue.count, "resident_rows": resident,
+                "live_rows": live, "row_pages": row_pages,
+                "pages_used": pool.used_count(),
+                "pages_total": pool.capacity, "kv_tokens": kv_tokens}
 
     def _admit_paged(self, pool, pools, claimed, pf_queue) -> None:
         """Bind each claimed entry to a free row of its bucket's group:
@@ -2130,7 +2182,9 @@ class ServeEngine:
         claimed = sorted(claimed,
                          key=lambda e: (-e.request.priority, e.request.rid))
         for e in claimed:
-            with obs_trace.use(e.trace):
+            with obs_trace.use(e.trace), annotate(
+                    "serve.admit", rid=e.request.rid,
+                    bucket=_bucket_tag(e.bucket)) as span:
                 now = self._clock()
                 r = e.request
                 if r.deadline is not None and r.deadline <= now:
@@ -2185,6 +2239,9 @@ class ServeEngine:
                     "alloc", rid=r.rid, pages=len(spages) + len(owned),
                     shared=len(spages), used=pool.used_count(),
                     total=pool.capacity)
+                span.set_metadata(queue_wait_ms=1e3 * e.queue_s,
+                                  pages=len(spages) + len(owned),
+                                  shared_pages=len(spages))
         self._record_pages(pool)
 
     def _prefill_paged_chunk(self, pool, pools, pf_queue) -> None:
@@ -2199,8 +2256,14 @@ class ServeEngine:
         TTFT — caches the completed prompt pages for prefix sharing, and
         flips the row decode-ready."""
         budget = self._prefill_chunk
-        while budget > 0 and pf_queue:
-            budget -= self._prefill_one_chunk(pool, pools, pf_queue)
+        chunks = 0
+        with annotate("serve.prefill") as span:
+            while budget > 0 and pf_queue:
+                used = self._prefill_one_chunk(pool, pools, pf_queue)
+                budget -= used
+                chunks += used > 0
+            span.set_metadata(chunks=chunks,
+                              tokens=self._prefill_chunk - budget)
         self._live_rows = sum(len(g.live_slots()) for g in pools.values())
 
     def _prefill_one_chunk(self, pool, pools, pf_queue) -> int:
@@ -2228,33 +2291,40 @@ class ServeEngine:
             C = group.chunk
             tokens = min(C, n - cs)
             final = cs + C >= n
-            chunk = group.prompts[slot][cs:cs + C]
-            if chunk.shape[0] < C:
-                # a prefix hit whose shared_len is page- but not CHUNK-
-                # aligned leaves a short tail slice; pad it back to the
-                # compiled width — a narrower array would compile a fresh
-                # program per width and break the <=3-per-bucket bound
-                chunk = np.concatenate(
-                    [chunk, np.zeros(C - chunk.shape[0], np.int32)])
             try:
-                # copy-on-write gate on every page the chunk will scatter
-                # into (a no-op in steady state: writes target owned pages
-                # by construction — kvpool.PagedKVPool.ensure_writable)
-                for j in range(cs // self._page_len,
-                               min((cs + C) // self._page_len,
-                                   group.pages_per_row)):
-                    self._cow(pool, group, slot, j, rid=r.rid)
-                from ..models.transformer import lm_prefill_paged
+                with annotate("serve.prefill.dispatch", rid=r.rid,
+                              bucket=_bucket_tag(bucket), start=cs,
+                              tokens=tokens, final=int(final)):
+                    chunk = group.prompts[slot][cs:cs + C]
+                    if chunk.shape[0] < C:
+                        # a prefix hit whose shared_len is page- but not
+                        # CHUNK-aligned leaves a short tail slice; pad it
+                        # back to the compiled width — a narrower array
+                        # would compile a fresh program per width and
+                        # break the <=3-per-bucket bound
+                        chunk = np.concatenate(
+                            [chunk, np.zeros(C - chunk.shape[0], np.int32)])
+                    # copy-on-write gate on every page the chunk will
+                    # scatter into (a no-op in steady state: writes target
+                    # owned pages by construction —
+                    # kvpool.PagedKVPool.ensure_writable)
+                    for j in range(cs // self._page_len,
+                                   min((cs + C) // self._page_len,
+                                       group.pages_per_row)):
+                        self._cow(pool, group, slot, j, rid=r.rid)
+                    from ..models.transformer import lm_prefill_paged
 
-                faults.fire("serve.prefill", path=f"bucket-{p}x{s}")
-                t0 = time.perf_counter()
-                pages, first = lm_prefill_paged(
-                    self.params, pool.pages, group.tables[slot], chunk, cs,
-                    n, heads=self.heads, page_len=self._page_len,
-                    seed=r.seed, temperature=r.temperature, top_p=r.top_p,
-                    top_k=r.top_k, compute_dtype=self.compute_dtype,
-                    moe=self.moe)
-                first = int(first)  # device sync: the chunk landed
+                    faults.fire("serve.prefill", path=f"bucket-{p}x{s}")
+                    t0 = time.perf_counter()
+                    pages, first = lm_prefill_paged(
+                        self.params, pool.pages, group.tables[slot], chunk,
+                        cs, n, heads=self.heads, page_len=self._page_len,
+                        seed=r.seed, temperature=r.temperature,
+                        top_p=r.top_p, top_k=r.top_k,
+                        compute_dtype=self.compute_dtype, moe=self.moe)
+                with annotate("serve.prefill.sync", rid=r.rid,
+                              final=int(final)):
+                    first = int(first)  # device sync: the chunk landed
                 wall = time.perf_counter() - t0
             except Exception as exc:
                 pf_queue.popleft()
@@ -2308,80 +2378,124 @@ class ServeEngine:
         any result is awaited (async dispatch overlap, as in the slab
         loop); non-live rows run the masked-harmless dummy against page 0
         so a prefilling neighbor's pages are never scribbled."""
+        launched = []
+        with annotate("serve.decode") as span:
+            for bucket, group in list(pools.items()):
+                if isinstance(group, ProgramRowSet):
+                    continue  # the program lane: _step_program_rows
+                with annotate("serve.decode.dispatch",
+                              bucket=_bucket_tag(bucket)) as dispatch:
+                    launch = self._dispatch_paged(pool, pools, bucket,
+                                                  group, dispatch)
+                if launch is not None:
+                    launched.append(launch)
+            span.set_metadata(buckets=len(launched))
+            synced = 0.0  # perf_counter at the previous bucket's landing
+            for bucket, group, live, t0, nxt in launched:
+                tag = _bucket_tag(bucket)
+                try:
+                    with annotate("serve.decode.sync", bucket=tag):
+                        # analyze: ignore[host-sync] — THE one intentional
+                        # sync per decode step: the host must see the
+                        # emitted tokens to retire rows (all dispatches
+                        # above launched async first)
+                        nxt = np.asarray(nxt)  # sync; the emitted tokens
+                except Exception as exc:
+                    self._fail_paged_bucket(pool, pools, bucket, exc)
+                    continue
+                # the interval that is this bucket's alone: the device ran
+                # the earlier buckets' steps first, so time before their
+                # landing is theirs (summing whole dispatch-to-landing
+                # walls counted device time two and three times)
+                t0, synced = max(t0, synced), time.perf_counter()
+                with annotate("serve.decode.retire", bucket=tag) as retire:
+                    retire.set_metadata(retired=self._land_paged(
+                        pool, pools, bucket, group, live, nxt, synced - t0))
+        self._live_rows = sum(len(g.live_slots()) for g in pools.values())
+
+    def _dispatch_paged(self, pool, pools, bucket, group, span):
+        """One bucket's half of :meth:`_step_paged` before the landing:
+        deadline sweep, copy-on-write gate, decode inputs, the async
+        ``lm_decode_paged`` call. Returns what the landing needs, or None
+        where nothing was dispatched. ``span`` (``serve.decode.dispatch``)
+        gets the work the call was given beside the work that is useful:
+        ``padded_rows`` x ``table_width`` pages against ``kv_tokens``, the
+        positions the live rows attend (each row's cache plus the entry
+        this step writes)."""
         from ..models.transformer import lm_decode_paged
 
-        launched = []
-        for bucket, group in list(pools.items()):
-            if isinstance(group, ProgramRowSet):
-                continue  # the program lane steps in _step_program_rows
-            now = self._clock()
-            for i in group.occupied_slots():
-                dl = group.entries[i].request.deadline
-                if dl is not None and dl <= now:
-                    self._retire_row_paged(
-                        pool, pools, bucket, i, STATUS_EXPIRED, now,
-                        reason=f"deadline {dl} passed mid-decode "
-                               f"(now {now})")
-            live = group.live_slots()
-            if not live:
-                continue
-            p, s = bucket
-            try:
-                for i in live:  # COW gate on each row's write page
-                    self._cow(pool, group, slot=i,
-                              # analyze: ignore[host-sync] — host numpy
-                              # block-table bookkeeping, not device data
-                              table_idx=int(group.positions[i])
-                              // self._page_len,
-                              rid=group.entries[i].request.rid)
-                faults.fire("serve.decode_step", path=f"bucket-{p}x{s}")
-                t0 = time.perf_counter()
-                tables, positions, cur = group.decode_inputs()
-                pages, nxt = lm_decode_paged(
-                    self.params, pool.pages, tables, positions, cur,
-                    group.steps_done, group.seeds, group.temperature,
-                    group.top_p, group.top_k, heads=self.heads,
-                    page_len=self._page_len,
-                    compute_dtype=self.compute_dtype, moe=self.moe,
-                    kernel=self._decode_kernel)
-            except Exception as exc:
-                self._fail_paged_bucket(pool, pools, bucket, exc)
-                continue
-            pool.pages = pages
-            launched.append((bucket, group, live, t0, nxt))
-        for bucket, group, live, t0, nxt in launched:
-            try:
-                # analyze: ignore[host-sync] — THE one intentional sync per
-                # decode step: the host must see the emitted tokens to
-                # retire rows (all dispatches above launched async first)
-                nxt = np.asarray(nxt)  # sync; the per-row emitted tokens
-            except Exception as exc:
-                self._fail_paged_bucket(pool, pools, bucket, exc)
-                continue
-            wall = time.perf_counter() - t0
-            self.metrics.record_step(bucket, len(live), self.max_batch,
-                                     wall, program_key=self._prog_key(bucket),
-                                     program="lm_decode_paged")
-            self.flight.record(
-                "step", bucket=list(bucket), rows=len(live),
-                seconds=wall, queue_depth=self._queue.count,
-                compiles=_compile_count(), pages_used=pool.used_count())
-            now = self._clock()
-            for i in live:
-                if group.entries[i] is None:
-                    continue  # expired between dispatch and landing
-                group.positions[i] += 1
-                group.steps_done[i] += 1
-                tok = int(nxt[i])
-                group.cur_tok[i] = tok
-                group.emitted[i].append(tok)
-                r = group.entries[i].request
-                if ((r.eos is not None and tok == r.eos)
-                        # analyze: ignore[host-sync] — host numpy bookkeeping
-                        or int(group.steps_done[i]) >= r.steps):
-                    self._retire_row_paged(pool, pools, bucket, i,
-                                           STATUS_OK, now)
-        self._live_rows = sum(len(g.live_slots()) for g in pools.values())
+        now = self._clock()
+        for i in group.occupied_slots():
+            dl = group.entries[i].request.deadline
+            if dl is not None and dl <= now:
+                self._retire_row_paged(
+                    pool, pools, bucket, i, STATUS_EXPIRED, now,
+                    reason=f"deadline {dl} passed mid-decode "
+                           f"(now {now})")
+        live = group.live_slots()
+        span.set_metadata(rows=len(live))
+        if not live:
+            return None
+        p, s = bucket
+        try:
+            for i in live:  # COW gate on each row's write page
+                self._cow(pool, group, slot=i,
+                          # analyze: ignore[host-sync] — host numpy
+                          # block-table bookkeeping, not device data
+                          table_idx=int(group.positions[i])
+                          // self._page_len,
+                          rid=group.entries[i].request.rid)
+            faults.fire("serve.decode_step", path=f"bucket-{p}x{s}")
+            t0 = time.perf_counter()
+            tables, positions, cur = group.decode_inputs()
+            pages, nxt = lm_decode_paged(
+                self.params, pool.pages, tables, positions, cur,
+                group.steps_done, group.seeds, group.temperature,
+                group.top_p, group.top_k, heads=self.heads,
+                page_len=self._page_len,
+                compute_dtype=self.compute_dtype, moe=self.moe,
+                kernel=self._decode_kernel)
+        except Exception as exc:
+            self._fail_paged_bucket(pool, pools, bucket, exc)
+            return None
+        pool.pages = pages
+        span.set_metadata(padded_rows=self.max_batch,
+                          table_width=tables.shape[1],
+                          # analyze: ignore[host-sync] — host numpy
+                          kv_tokens=int(positions.sum()) + len(live))
+        return bucket, group, live, t0, nxt
+
+    def _land_paged(self, pool, pools, bucket, group, live, nxt,
+                    seconds: float) -> int:
+        """One bucket's half of :meth:`_step_paged` after its landing: the
+        step's records, the per-row bookkeeping, retirement. ``seconds`` is
+        the interval that belongs to this bucket alone. Returns the rows
+        retired."""
+        self.metrics.record_step(bucket, len(live), self.max_batch, seconds,
+                                 program_key=self._prog_key(bucket),
+                                 program="lm_decode_paged")
+        self.flight.record(
+            "step", bucket=list(bucket), rows=len(live), seconds=seconds,
+            queue_depth=self._queue.count, compiles=_compile_count(),
+            pages_used=pool.used_count())
+        now = self._clock()
+        retired = 0
+        for i in live:
+            if group.entries[i] is None:
+                continue  # expired between dispatch and landing
+            group.positions[i] += 1
+            group.steps_done[i] += 1
+            tok = int(nxt[i])
+            group.cur_tok[i] = tok
+            group.emitted[i].append(tok)
+            r = group.entries[i].request
+            if ((r.eos is not None and tok == r.eos)
+                    # analyze: ignore[host-sync] — host numpy bookkeeping
+                    or int(group.steps_done[i]) >= r.steps):
+                self._retire_row_paged(pool, pools, bucket, i,
+                                       STATUS_OK, now)
+                retired += 1
+        return retired
 
     def _retire_row_paged(self, pool, pools, bucket, slot: int,
                           status: str, now: float, reason: str = "") -> None:

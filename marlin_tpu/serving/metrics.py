@@ -138,7 +138,6 @@ class ServeMetrics:
         self.errors = 0
         self.shut_down = 0
         self.retries = 0
-        self.batches = 0  # legacy (gang scheduler, retired PR 8): always 0
         self.prefix_hits = 0
         self.prefix_misses = 0
         self.pages_total = 0
@@ -153,7 +152,6 @@ class ServeMetrics:
         self.program_steps = 0  # one-shot program batch dispatches
         self.program_rows = 0   # rows those dispatches served
         self.swaps = 0          # atomic model hot-updates (record_swap)
-        self._occupancy_sum = 0.0
         self._step_occupancy_sum = 0.0
         self._total_s = Reservoir(keep_latencies, rng)
         self._queue_s = Reservoir(keep_latencies, rng)
@@ -508,14 +506,12 @@ class ServeMetrics:
             qs = self._queue_s.values()
             tt = self._ttft_s.values()
             ss = self._step_s.values()
-            dispatches = self.batches + self.steps
-            occ = self._occupancy_sum + self._step_occupancy_sum
             out = {
                 "submitted": self.submitted, "rejected": self.rejected,
                 "expired": self.expired, "completed": self.completed,
                 "errors": self.errors, "shut_down": self.shut_down,
                 "retries": self.retries,
-                "batches": self.batches, "steps": self.steps,
+                "steps": self.steps,
                 "prefix_hits": self.prefix_hits,
                 "prefix_misses": self.prefix_misses,
                 "pages_total": self.pages_total,
@@ -529,8 +525,9 @@ class ServeMetrics:
                 "swaps": self.swaps,
                 "new_tokens": self.new_tokens,
                 "busy_s": round(self.busy_s, 6),
-                "occupancy_mean": (round(occ / dispatches, 4)
-                                   if dispatches else None),
+                "occupancy_mean": (
+                    round(self._step_occupancy_sum / self.steps, 4)
+                    if self.steps else None),
                 "tok_s": (round(self.new_tokens / self.busy_s, 2)
                           if self.busy_s > 0 else None),
             }

@@ -123,7 +123,7 @@ _FAILOVER = (STATUS_REJECTED, STATUS_SHUTTING_DOWN)
 #: prefix hit/miss record exactly this way). Gauges (pages_*) stay
 #: current-replicas-only: a dead pool holds no pages.
 _COUNTER_KEYS = ("submitted", "rejected", "expired", "completed", "errors",
-                 "shut_down", "retries", "batches", "steps", "new_tokens",
+                 "shut_down", "retries", "steps", "new_tokens",
                  "prefix_hits", "prefix_misses", "migrated_out",
                  "migrated_in", "migrate_fallback", "busy_s",
                  "program_steps", "program_rows", "swaps")
@@ -850,7 +850,7 @@ class Router:
         snaps = [(rep.idx, rep.engine.metrics.snapshot()) for rep in reps]
         agg: dict = {"replicas": {i: s for i, s in snaps}}
         for key in ("submitted", "rejected", "expired", "completed",
-                    "errors", "shut_down", "retries", "batches", "steps",
+                    "errors", "shut_down", "retries", "steps",
                     "new_tokens", "prefix_hits", "prefix_misses",
                     "migrated_out", "migrated_in", "migrate_fallback",
                     "program_steps", "program_rows", "swaps"):

@@ -4,8 +4,9 @@ The reference's observability is `System.currentTimeMillis` deltas and raw
 printlns (SURVEY.md §5.1/§5.5 — flagged as a gap worth exceeding). This module
 adds:
 
-- :func:`annotate` — names a region so it shows up in `jax.profiler` traces
-  (XProf/TensorBoard) as a labeled span.
+- :func:`annotate` — a timed span with fields, written into the
+  `jax.profiler` trace (and nowhere else) as ``marlin:<name>``: the engine's
+  phases on the device trace's clock.
 - :class:`EventLog` — append-only JSON-lines event log (step timings, bytes
   moved, custom counters) for post-hoc analysis without a profiler UI. Every
   record automatically carries the active span context
@@ -35,11 +36,15 @@ __all__ = ["annotate", "EventLog", "matmul_flops", "effective_gflops",
            "set_default_event_log", "get_default_event_log"]
 
 
-@contextlib.contextmanager
-def annotate(name: str):
-    """Label a region in profiler traces; no-ops cheaply outside tracing."""
-    with jax.profiler.TraceAnnotation(name):
-        yield
+def annotate(name: str, **fields: Any) -> jax.profiler.TraceAnnotation:
+    """A timed span ``marlin:<name>`` on the profiler's own timeline, so on
+    the device trace's clock: ``with annotate("serve.iter", rows=5): ...``.
+    ``fields`` (ints, floats, strings) arrive as the event's stats. The
+    profiler session is the only store: a span exists exactly while a trace
+    is being taken (``jax.profiler.start_trace``, ``obs.perf.capture_profile``)
+    and costs about a microsecond otherwise. The program's one way to record
+    a span; read them back with ``benchmarks/engine_spans.py``."""
+    return jax.profiler.TraceAnnotation("marlin:" + name, **fields)
 
 
 def matmul_flops(m: int, k: int, n: int) -> float:

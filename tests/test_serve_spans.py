@@ -1,0 +1,367 @@
+"""Timed spans of ``ServeEngine``'s paged loop, and the step time they sit on.
+
+``utils.tracing.annotate`` writes ``marlin:<name>`` events with fields into
+the ``jax.profiler`` session and nowhere else. Under a CPU capture a small
+paged engine must emit every span of ``docs/observability.md``'s table, nested
+as the table says, with counts that agree with the engine's own; with no
+capture it serves the same tokens and announces the same records as before.
+The last part holds the repaired step time: with three live buckets
+``ServeMetrics.busy_s`` is no more than the wall time.
+"""
+
+import collections
+import time
+import tracemalloc
+
+import numpy as np
+import pytest
+
+import jax
+
+from benchmarks import engine_spans
+from benchmarks.trace_reduce import find_xplane
+from marlin_tpu.models import TransformerLM
+from marlin_tpu.models.planner import request_pages
+from marlin_tpu.models.transformer import lm_generate
+from marlin_tpu.serving import STATUS_OK, Request, ServeEngine
+from marlin_tpu.utils.tracing import annotate
+
+HEADS = 2
+PAGE_LEN = 4
+BUCKETS = ((8, 4), (16, 4), (32, 8))
+#: (prompt length, steps): two rows in each bucket, one prompt of two chunks
+#: and one of four, so non-final chunks exist
+SCHEDULE = ((3, 4), (7, 3), (12, 4), (14, 2), (20, 8), (30, 5))
+
+#: span -> (fields it must carry, its parent on the worker's thread)
+SPANS = {
+    "serve.submit": ({"rid", "bucket"}, None),
+    "serve.wait": (set(), None),
+    "serve.claim": ({"claimed"}, None),
+    "serve.iter": ({"queue_depth", "resident_rows", "live_rows", "row_pages",
+                    "pages_used", "pages_total", "kv_tokens"}, None),
+    "serve.admit": ({"rid", "bucket", "queue_wait_ms", "pages",
+                     "shared_pages"}, "serve.iter"),
+    "serve.prefill": ({"chunks", "tokens"}, "serve.iter"),
+    "serve.prefill.dispatch": ({"rid", "bucket", "start", "tokens", "final"},
+                               "serve.prefill"),
+    "serve.prefill.sync": ({"rid", "final"}, "serve.prefill"),
+    "serve.decode": ({"buckets"}, "serve.iter"),
+    "serve.decode.dispatch": ({"bucket", "rows"}, "serve.decode"),
+    "serve.decode.sync": ({"bucket"}, "serve.decode"),
+    "serve.decode.retire": ({"bucket", "retired"}, "serve.decode"),
+}
+
+
+class ListSink:
+    """What ``ServeEngine(log=...)`` writes to, kept in order."""
+
+    def __init__(self):
+        self.records = []
+
+    def event(self, kind, **fields):
+        self.records.append(fields)
+
+
+@pytest.fixture(scope="module")
+def params():
+    return TransformerLM(vocab=32, d_model=16, heads=HEADS, layers=2,
+                         seed=9).init_params()
+
+
+def _engine(params, **kw):
+    kw.setdefault("buckets", BUCKETS)
+    kw.setdefault("max_batch", 4)
+    kw.setdefault("queue_depth", 64)
+    kw.setdefault("page_len", PAGE_LEN)
+    kw.setdefault("prefill_chunk", 8)
+    return ServeEngine(params, HEADS, **kw)
+
+
+def _requests(schedule=SCHEDULE):
+    return [Request(prompt=list(range(1, 1 + n)), steps=steps)
+            for n, steps in schedule]
+
+
+def _parked(eng, timeout=120.0):
+    """Return once the worker is in its wait, every span of its last
+    iteration closed. The worker clears ``_idle`` as it wakes and sets it
+    under the lock just before it waits again, so after a result has come
+    back this cannot see the wait before that request."""
+    deadline = time.monotonic() + timeout
+    while True:
+        with eng._cond:
+            if eng._idle:
+                return
+        assert time.monotonic() < deadline, "the worker never parked"
+        time.sleep(0.001)
+
+
+def _serve(params, schedule=SCHEDULE, capture_dir=None, **kw):
+    """Queue ``schedule`` on a paused engine, then let the worker run: the
+    iterations are the same in every run. With ``capture_dir`` the whole of
+    it happens under a profiler capture, which also sees one late request
+    wake the worker from its wait. Returns results, records, spans and the
+    engine's last snapshot."""
+    sink = ListSink()
+    eng = _engine(params, log=sink, start=False, **kw)
+    eng.warmup()
+    if capture_dir is not None:
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(str(capture_dir), profiler_options=opts)
+    try:
+        handles = eng.submit_many(_requests(schedule))
+        t0 = time.perf_counter()
+        eng.start()
+        results = [h.result(timeout=120) for h in handles]
+        wall = time.perf_counter() - t0
+        n_records = len(sink.records)
+        if capture_dir is not None:
+            _parked(eng)
+            late = eng.submit(Request(prompt=[1, 2, 3], steps=2))
+            assert late.result(timeout=120).status == STATUS_OK
+            # a result arrives from inside serve.decode.retire: the spans
+            # around it close only as the worker goes back into its wait
+            _parked(eng)
+    finally:
+        if capture_dir is not None:
+            jax.profiler.stop_trace()
+        snap = eng.metrics.snapshot()
+        eng.close()
+    spans = (engine_spans.load(find_xplane(str(capture_dir)))["spans"]
+             if capture_dir is not None else None)
+    return {"results": results, "records": sink.records[:n_records],
+            "spans": spans, "snapshot": snap, "wall": wall}
+
+
+@pytest.fixture(scope="module")
+def traced(params, tmp_path_factory):
+    return _serve(params, capture_dir=tmp_path_factory.mktemp("capture"))
+
+
+@pytest.fixture(scope="module")
+def untraced(params):
+    return _serve(params)
+
+
+def _parents(spans):
+    """``{id(span): parent span or None}`` on each thread, by nesting."""
+    out, stacks = {}, collections.defaultdict(list)
+    for s in spans:  # sorted by (start, -end)
+        stack = stacks[s.line]
+        while stack and stack[-1].end <= s.start:
+            stack.pop()
+        out[id(s)] = stack[-1] if stack else None
+        stack.append(s)
+    return out
+
+
+@pytest.mark.parametrize("name", sorted(SPANS))
+def test_every_span_is_emitted_with_its_fields(traced, name):
+    fields, _ = SPANS[name]
+    found = [s for s in traced["spans"] if s.name == name]
+    assert found, f"no marlin:{name} span in the capture"
+    best = max(found, key=lambda s: len(s.fields))
+    assert fields <= set(best.fields), (name, best.fields)
+
+
+def test_dispatched_decode_spans_carry_the_work_they_were_given(traced):
+    calls = [s for s in traced["spans"] if s.name == "serve.decode.dispatch"
+             and s.fields["rows"]]
+    assert calls
+    width = {f"{p}x{s}": -(-(p + s) // PAGE_LEN) for p, s in BUCKETS}
+    for s in calls:
+        assert s.fields["padded_rows"] == 4
+        assert s.fields["table_width"] == width[s.fields["bucket"]]
+        assert 0 < s.fields["kv_tokens"] <= (s.fields["rows"]
+                                             * s.fields["table_width"]
+                                             * PAGE_LEN)
+
+
+def test_spans_nest_as_the_table_says(traced):
+    spans = traced["spans"]
+    parents = _parents(spans)
+    worker = engine_spans.worker_line(spans)
+    assert worker is not None
+    for s in spans:
+        _, parent = SPANS[s.name]
+        got = parents[id(s)]
+        if s.name == "serve.submit":
+            assert s.line != worker and got is None
+            continue
+        assert s.line == worker, s
+        assert (got.name if got is not None else None) == parent, (s, got)
+
+
+def test_decode_dispatch_rows_are_the_new_tokens_less_first_tokens(traced):
+    spans, snap = traced["spans"], traced["snapshot"]
+    rows = sum(s.fields["rows"] for s in spans
+               if s.name == "serve.decode.dispatch")
+    firsts = sum(1 for s in spans if s.name == "serve.prefill.sync"
+                 and s.fields["final"])
+    assert firsts == len(SCHEDULE) + 1  # the late request too
+    assert rows == snap["new_tokens"] - firsts
+    steps = [s for s in spans if s.name == "serve.decode.dispatch"
+             and s.fields["rows"]]
+    assert len(steps) == snap["steps"]
+    retired = sum(s.fields["retired"] for s in spans
+                  if s.name == "serve.decode.retire")
+    assert retired == len(SCHEDULE) + 1
+
+
+def test_queue_wait_ms_is_the_results_queue_s(traced):
+    waits = {s.fields["rid"]: s.fields["queue_wait_ms"]
+             for s in traced["spans"] if s.name == "serve.admit"}
+    for r in traced["results"]:
+        assert waits[r.rid] == pytest.approx(1e3 * r.metrics["queue_s"],
+                                             rel=1e-9)
+
+
+def test_prefill_chunks_of_one_request_join_by_rid(traced):
+    by_rid = collections.defaultdict(list)
+    for s in traced["spans"]:
+        if s.name == "serve.prefill.dispatch":
+            by_rid[s.fields["rid"]].append(s.fields)
+    for req, (n, _) in zip(traced["results"], SCHEDULE):
+        chunks = by_rid[req.rid]
+        assert [c["start"] for c in chunks] == list(range(0, n, 8))
+        assert sum(c["tokens"] for c in chunks) == n
+        assert [c["final"] for c in chunks] == [0] * (len(chunks) - 1) + [1]
+
+
+def test_row_pages_and_kv_tokens_match_the_pool_by_hand(params, tmp_path):
+    """Two requests in one bucket, prompts 5 and 7, three tokens each: both
+    prefill in the first iteration (one chunk each), decode twice, retire."""
+    got = _serve(params, schedule=((5, 3), (7, 3)), capture_dir=tmp_path)
+    pages = request_pages(5, 3, PAGE_LEN) + request_pages(7, 3, PAGE_LEN)
+    assert pages == 2 + 3
+    iters = [s.fields for s in got["spans"] if s.name == "serve.iter"]
+    total = iters[0]["pages_total"]
+    empty = {"resident_rows": 0, "live_rows": 0, "row_pages": 0,
+             "pages_used": 0, "kv_tokens": 0}
+    # as each iteration begins: nothing resident; both rows live with their
+    # prompts (5 + 7) and the first decode's entry each in the cache
+    assert iters[0] == {"queue_depth": 2, "pages_total": total, **empty}
+    assert iters[1] == {"queue_depth": 2, "pages_total": total,
+                        "resident_rows": 2, "live_rows": 2,
+                        "row_pages": pages, "pages_used": pages,
+                        "kv_tokens": 5 + 7 + 2}
+    assert iters[2]["resident_rows"] == 0 and iters[2]["row_pages"] == 0
+    admits = [s.fields for s in got["spans"] if s.name == "serve.admit"]
+    assert [a["pages"] for a in admits[:2]] == [2, 3]
+    calls = [s.fields for s in got["spans"]
+             if s.name == "serve.decode.dispatch" and s.fields["rows"]]
+    # a call attends each live row's cache plus the entry it writes
+    assert [c["kv_tokens"] for c in calls[:2]] == [5 + 7 + 2, 6 + 8 + 2]
+    assert [c["rows"] for c in calls[:2]] == [2, 2]
+
+
+def test_a_capture_begun_while_the_worker_waits_holds_the_iteration_whole(
+        params, tmp_path):
+    """``serve.iter`` opens once the claim has returned, so a parked worker
+    holds no open iteration: the request that wakes it is captured from its
+    claim on, with counts taken after the wake-up."""
+    eng = _engine(params)
+    eng.warmup()
+    try:
+        _parked(eng)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            res = eng.submit(Request(prompt=[1, 2, 3], steps=2)).result(120)
+            _parked(eng)
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        eng.close()
+    assert res.status == STATUS_OK
+    spans = engine_spans.load(find_xplane(str(tmp_path)))["spans"]
+    worker = [s for s in engine_spans.worker_spans(spans)
+              if s.name in ("serve.claim", "serve.wait", "serve.iter")]
+    # the wait it was parked in began before the capture and is not in it
+    assert [s.name for s in worker[:2]] == ["serve.claim", "serve.iter"]
+    assert worker[0].fields == {"claimed": 1}
+    assert worker[1].fields["queue_depth"] == 1
+    assert worker[0].end <= worker[1].start
+    admits = [s for s in spans if s.name == "serve.admit"]
+    assert [s.fields["rid"] for s in admits] == [res.rid]
+    assert worker[1].start <= admits[0].start <= admits[0].end <= worker[1].end
+
+
+def test_tokens_are_the_reference_with_and_without_a_capture(
+        params, traced, untraced):
+    for run in (traced, untraced):
+        for res, (n, steps) in zip(run["results"], SCHEDULE):
+            assert res.status == STATUS_OK
+            prompt = np.arange(1, 1 + n, dtype=np.int32)
+            want = np.asarray(lm_generate(
+                params, prompt, jax.random.key(0), heads=HEADS,
+                max_len=n + steps, steps=steps))
+            assert res.tokens.tolist() == want.tolist()
+
+
+#: the keys of the records the benchmark's TokenSink reads, as the parent
+#: of this change announced them
+RECORD_KEYS = {
+    "step": {"ev", "bucket", "rows", "occupancy", "seconds", "new_tokens",
+             "tok_s"},
+    "prefill": {"ev", "bucket", "new_tokens", "seconds", "chunk", "rid"},
+}
+
+
+@pytest.mark.parametrize("ev", sorted(RECORD_KEYS))
+def test_records_keep_keys_count_and_order(traced, untraced, ev):
+    def stream(run):
+        rid0 = run["results"][0].rid  # rids count on through the process
+        return [(r["ev"], tuple(r["bucket"]), r.get("rows"), r["new_tokens"],
+                 r["rid"] - rid0 if "rid" in r else None,
+                 tuple(r.get("chunk", ())))
+                for r in run["records"] if r.get("ev") == ev]
+
+    assert stream(traced) == stream(untraced)
+    records = [r for r in untraced["records"] if r.get("ev") == ev]
+    for r in records:
+        assert set(r) - {"trace_id", "span_id", "parent_id"} == RECORD_KEYS[ev]
+    if ev == "step":  # one record per dispatched bucket, a token per row
+        assert len(records) == untraced["snapshot"]["steps"]
+        assert (sum(r["new_tokens"] for r in records) + len(SCHEDULE)
+                == untraced["snapshot"]["new_tokens"])
+    else:             # one per chunk; the final one is the first token
+        assert len(records) == sum(-(-n // 8) for n, _ in SCHEDULE)
+        assert sum(r["new_tokens"] for r in records) == len(SCHEDULE)
+
+
+def test_annotate_keeps_nothing_in_memory():
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    with annotate("warm", rows=1) as span:
+        span.set_metadata(retired=0)
+    tracemalloc.start()
+    try:
+        before = tracemalloc.take_snapshot()
+        for i in range(20000):
+            with annotate("serve.iter", rows=i, bucket="8x4") as span:
+                span.set_metadata(retired=i)
+        after = tracemalloc.take_snapshot()
+    finally:
+        tracemalloc.stop()
+    grown = sum(d.size_diff for d in after.compare_to(before, "filename")
+                if d.size_diff > 0)
+    assert grown < 64 * 1024, f"{grown} bytes kept after 20000 spans"
+
+
+def test_busy_s_is_no_more_than_wall_time_with_three_live_buckets(params):
+    """Every bucket has live rows in every iteration and decode dominates:
+    dispatch-to-landing walls summed over the buckets count the device two
+    and three times; the per-bucket intervals cannot pass the clock."""
+    got = _serve(params, buckets=((8, 24), (16, 24), (32, 24)),
+                 schedule=((4, 24), (5, 24), (12, 24), (13, 24), (24, 24),
+                           (25, 24)))
+    assert all(r.status == STATUS_OK for r in got["results"])
+    steps = [r for r in got["records"] if r.get("ev") == "step"]
+    assert {tuple(r["bucket"]) for r in steps} == {(8, 24), (16, 24), (32, 24)}
+    assert got["snapshot"]["steps"] == len(steps) >= 3 * 23
+    assert 0 < got["snapshot"]["busy_s"] <= got["wall"]

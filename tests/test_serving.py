@@ -575,7 +575,7 @@ def test_rowlevel_mixed_sampling_knobs_share_steps(params, compile_count):
             assert r.tokens.size == 2 + 3
             assert np.all(r.tokens < 32) and np.all(r.tokens >= 0)
         snap = eng.metrics.snapshot()
-        assert snap["batches"] == 0  # nothing gang-dispatched
+        assert snap["steps"] >= 2  # row-level decode steps served them
 
 
 def test_rowlevel_sampled_replay_is_composition_independent(params):

@@ -748,7 +748,11 @@ def _pick_token_row(temperature, top_p, top_k, logits, sub):
     by rank (exactly k survivors; value ties at the k-th logit break by sort
     order instead of all surviving), and the sort always exists in the
     program — per-row knobs cannot statically elide it. The greedy branch is
-    the same argmax, so greedy rows are unaffected by either."""
+    the same argmax, so a greedy row's TOKEN is unaffected by either. Its
+    COST is spared only where the temperature is one scalar (the prefill
+    programs: a real conditional); under ``vmap`` the ``cond`` becomes a
+    select and every row pays for the sort, which is why the decode programs
+    go through :func:`_pick_token_rows`."""
 
     def sample():
         l = logits / jnp.maximum(temperature, 1e-6)
@@ -775,6 +779,25 @@ def _row_key(seed, step):
     row's seed key. Depends only on (seed, step) — never on slot index or
     co-resident rows — so sampled replay is composition-independent."""
     return jax.random.fold_in(jax.random.key(seed), step)
+
+
+def _pick_token_rows(temperature, top_p, top_k, logits, seeds, steps_done):
+    """:func:`_pick_token_row` for every row of a decode batch — (B,) knobs,
+    seeds and emitted-token counts, (B, V) logits — with the sort behind ONE
+    conditional on a scalar: a batch with no sampled row takes the argmax
+    and never sorts the vocabulary (on the serving cell the two argsorts
+    were 16 of a decode step's 42 ms, for rows that were all greedy). A
+    batch with any sampled row runs the per-row function on each row's
+    :func:`_row_key` stream for all of its rows, its select included, so
+    every row's token is what the per-row function gives it. Hand it
+    temperature 0 for rows whose token is discarded (free, prefilling), or
+    one of them switches the sort on for a batch of greedy rows."""
+    return jax.lax.cond(
+        jnp.any(temperature > 0.0),
+        lambda: jax.vmap(_pick_token_row)(
+            temperature, top_p, top_k, logits,
+            jax.vmap(_row_key)(seeds, steps_done)),
+        lambda: jnp.argmax(logits, axis=-1).astype(jnp.int32))
 
 
 def lm_prefill_slot(params, caches, tokens, slot, prompt, length, heads: int,
@@ -874,8 +897,8 @@ def _lm_decode_rows_jit(params, caches, tokens, positions, steps_done, seeds,
     logits, caches = jax.vmap(
         lambda xb, cb, pb: _decode_step(params, xb, cb, pb, heads, moe)
     )(x, caches, pos)
-    subs = jax.vmap(_row_key)(seeds, steps_done)
-    nxt = jax.vmap(_pick_token_row)(temperature, top_p, top_k, logits, subs)
+    nxt = _pick_token_rows(temperature, top_p, top_k, logits, seeds,
+                           steps_done)
     tokens = tokens.at[rows, pos + 1].set(nxt)
     return caches, tokens, nxt
 
@@ -1210,9 +1233,8 @@ def _lm_decode_paged_jit(params, pages, tables, positions, cur_tokens,
     if kernel == "pallas":
         logits, new_pages = _decode_paged_pallas(
             params, pages, tables, pos, x, heads, page_len, moe)
-        subs = jax.vmap(_row_key)(seeds, steps_done)
-        nxt = jax.vmap(_pick_token_row)(temperature, top_p, top_k, logits,
-                                        subs)
+        nxt = _pick_token_rows(temperature, top_p, top_k, logits, seeds,
+                               steps_done)
         return new_pages, nxt
     # gather each row's context in block-table order: position t of the
     # gathered view IS absolute position t, so _decode_step's positional
@@ -1223,8 +1245,8 @@ def _lm_decode_paged_jit(params, pages, tables, positions, cur_tokens,
     logits, new_ctx = jax.vmap(
         lambda xb, cb, pb: _decode_step(params, xb, cb, pb, heads, moe)
     )(x, ctx, pos)
-    subs = jax.vmap(_row_key)(seeds, steps_done)
-    nxt = jax.vmap(_pick_token_row)(temperature, top_p, top_k, logits, subs)
+    nxt = _pick_token_rows(temperature, top_p, top_k, logits, seeds,
+                           steps_done)
     # write back the ONE cache entry each row produced — sliced at pos out
     # of the updated per-row context, which lets XLA fold the update-then-
     # slice into the entry itself instead of materializing a whole updated

@@ -2421,7 +2421,9 @@ class ServeEngine:
         gets the work the call was given beside the work that is useful:
         ``padded_rows`` x ``table_width`` pages against ``kv_tokens``, the
         positions the live rows attend (each row's cache plus the entry
-        this step writes)."""
+        this step writes); and ``sampled_rows``, the live rows with a
+        temperature above 0 — at 0 the program took its argmax branch and
+        never sorted the vocabulary."""
         from ..models.transformer import lm_decode_paged
 
         now = self._clock()
@@ -2447,10 +2449,10 @@ class ServeEngine:
                           rid=group.entries[i].request.rid)
             faults.fire("serve.decode_step", path=f"bucket-{p}x{s}")
             t0 = time.perf_counter()
-            tables, positions, cur = group.decode_inputs()
+            tables, positions, cur, temperature = group.decode_inputs()
             pages, nxt = lm_decode_paged(
                 self.params, pool.pages, tables, positions, cur,
-                group.steps_done, group.seeds, group.temperature,
+                group.steps_done, group.seeds, temperature,
                 group.top_p, group.top_k, heads=self.heads,
                 page_len=self._page_len,
                 compute_dtype=self.compute_dtype, moe=self.moe,
@@ -2462,7 +2464,8 @@ class ServeEngine:
         span.set_metadata(padded_rows=self.max_batch,
                           table_width=tables.shape[1],
                           # analyze: ignore[host-sync] — host numpy
-                          kv_tokens=int(positions.sum()) + len(live))
+                          kv_tokens=int(positions.sum()) + len(live),
+                          sampled_rows=int((temperature > 0).sum()))
         return bucket, group, live, t0, nxt
 
     def _land_paged(self, pool, pools, bucket, group, live, nxt,

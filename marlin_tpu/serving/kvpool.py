@@ -854,16 +854,21 @@ class PagedGroup:
     # -------------------------------------------------------- decode inputs
 
     def decode_inputs(self):
-        """(tables, positions, cur_tokens) with every non-live row masked
-        to the dummy table/position — a prefilling row's REAL pages must
-        never be scribbled by its dummy decode write."""
+        """(tables, positions, cur_tokens, temperature) with every non-live
+        row masked to the dummy table/position — a prefilling row's REAL
+        pages must never be scribbled by its dummy decode write — and to
+        temperature 0: its token is discarded, and the decode program sorts
+        the vocabulary for the whole bucket when any row it is handed
+        samples, so a sampled request still in prefill must not switch that
+        on for live rows that are all greedy."""
         live = np.zeros(self.width, bool)
         live[self.live_slots()] = True
         tables = np.where(live[:, None],
                           self.tables[:, :self.pages_per_row], 0)
         positions = np.where(live, self.positions, 0)
         cur = np.where(live, self.cur_tok, 0)
-        return tables, positions, cur
+        temperature = np.where(live, self.temperature, np.float32(0.0))
+        return tables, positions, cur, temperature
 
 
 # ---------------------------------------------------------------- programs

@@ -616,8 +616,16 @@ def test_lm_decode_paged_pallas_compiles_for_v5e(one_chip):
             st((B,)), st((B,), jnp.uint32), st((B,), jnp.float32),
             st((B,), jnp.float32), st((B,)), heads=8, page_len=_PAGE_LEN,
             compute_dtype=None, moe=None, kernel="pallas").lower().compile()
-    assert "tpu_custom_call" in c.as_text()
+    text = c.as_text()
+    assert "tpu_custom_call" in text
     assert c.memory_analysis().peak_memory_in_bytes < 1024**3
+    # the sampling tail survives the TPU compiler as ONE real conditional
+    # (not flattened into a select), with the vocabulary sorts in a branch
+    # computation and none in the entry computation greedy batches run
+    assert text.count(" conditional(") == 1
+    entry = text[text.index("\nENTRY "):]
+    assert " conditional(" in entry and " sort(" not in entry
+    assert " sort(" in text
 
 
 def test_lm_prefill_paged_compiles_for_v5e(one_chip):

@@ -97,9 +97,10 @@ def _parked(eng, timeout=120.0):
         time.sleep(0.001)
 
 
-def _serve(params, schedule=SCHEDULE, capture_dir=None, **kw):
-    """Queue ``schedule`` on a paused engine, then let the worker run: the
-    iterations are the same in every run. With ``capture_dir`` the whole of
+def _serve(params, schedule=SCHEDULE, capture_dir=None, requests=None, **kw):
+    """Queue ``schedule`` (or ``requests``, where greedy ones will not do)
+    on a paused engine, then let the worker run: the iterations are the
+    same in every run. With ``capture_dir`` the whole of
     it happens under a profiler capture, which also sees one late request
     wake the worker from its wait. Returns results, records, spans and the
     engine's last snapshot."""
@@ -112,7 +113,7 @@ def _serve(params, schedule=SCHEDULE, capture_dir=None, **kw):
         opts.host_tracer_level = 2
         jax.profiler.start_trace(str(capture_dir), profiler_options=opts)
     try:
-        handles = eng.submit_many(_requests(schedule))
+        handles = eng.submit_many(requests or _requests(schedule))
         t0 = time.perf_counter()
         eng.start()
         results = [h.result(timeout=120) for h in handles]
@@ -174,10 +175,44 @@ def test_dispatched_decode_spans_carry_the_work_they_were_given(traced):
     width = {f"{p}x{s}": -(-(p + s) // PAGE_LEN) for p, s in BUCKETS}
     for s in calls:
         assert s.fields["padded_rows"] == 4
+        assert s.fields["sampled_rows"] == 0  # every request is greedy
         assert s.fields["table_width"] == width[s.fields["bucket"]]
         assert 0 < s.fields["kv_tokens"] <= (s.fields["rows"]
                                              * s.fields["table_width"]
                                              * PAGE_LEN)
+
+
+def test_sampled_rows_counts_live_sampled_rows_only(params, tmp_path,
+                                                    monkeypatch):
+    """A greedy request decodes while a sampled one of the same bucket is
+    still prefilling (30 tokens at 8 an iteration): until its final chunk
+    the bucket is handed temperature 0 in every row, so the decode program
+    takes its argmax branch, and ``sampled_rows`` is what it was handed."""
+    from marlin_tpu.models import transformer
+
+    handed = []
+    decode = transformer.lm_decode_paged
+
+    def spy(*args, **kw):
+        handed.append(np.asarray(args[7]).copy())  # temperature
+        return decode(*args, **kw)
+
+    monkeypatch.setattr(transformer, "lm_decode_paged", spy)
+    got = _serve(params, buckets=((32, 8),), capture_dir=tmp_path, requests=[
+        Request(prompt=[1, 2, 3], steps=8),
+        Request(prompt=list(range(1, 31)), steps=6, temperature=0.9,
+                seed=11)])
+    assert all(r.status == STATUS_OK for r in got["results"])
+    calls = [(s.fields["rows"], s.fields["sampled_rows"])
+             for s in got["spans"]
+             if s.name == "serve.decode.dispatch" and s.fields["rows"]]
+    # three decodes of the greedy row alone, four of both, one of the
+    # sampled row alone (it has one token more to go); then _serve's late
+    # greedy request
+    assert calls == [(1, 0)] * 3 + [(2, 1)] * 4 + [(1, 1), (1, 0)]
+    handed = handed[-len(calls):]  # warm-up's calls came first
+    assert [int((t > 0).sum()) for t in handed] == [n for _, n in calls]
+    assert all(t.dtype == np.float32 and t.shape == (4,) for t in handed)
 
 
 def test_spans_nest_as_the_table_says(traced):

@@ -34,8 +34,8 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..mesh import ROWS
 
-__all__ = ["init_moe", "moe_ffn", "moe_decode_ffn", "moe_capacity",
-           "shard_moe_params"]
+__all__ = ["init_moe", "moe_ffn", "moe_decode_ffn", "moe_experts_ffn",
+           "moe_capacity", "shard_moe_params"]
 
 
 def init_moe(key, d_model: int, d_ff: int, n_experts: int,
@@ -213,3 +213,93 @@ def moe_decode_ffn(mp: dict, h, top_k: int = 2):
     hh = jax.nn.gelu(jnp.einsum("d,kdf->kf", h, w1)).astype(cd)
     out = jnp.einsum("kf,kfd->kd", hh, w2)
     return jnp.sum(out * topv[:, None].astype(out.dtype), axis=0).astype(cd)
+
+
+#: rows of a grouped matmul's tile on the chip, and its weight tile (k, n)
+_GMM_TILING = (128, 1024, 1024)
+
+
+def _grouped_matmul(xs, w, sizes, out_dtype):
+    """``xs[rows of group e] @ w[e]`` for rows sorted by group, ``sizes`` rows
+    a group; rows past the last group belong to none and come back
+    UNDEFINED (the caller never reads them). On the chip this is the Pallas
+    grouped matmul that ships with JAX (``megablox.gmm``) with 1024 x 1024
+    weight tiles: XLA's own lowering of ``jax.lax.ragged_dot`` picks 512 x
+    512, which at a prefill chunk's ~40 rows a group is bound by its grid
+    steps (1.3 ms a call where the weights' bytes take 0.5; ``PERF.md``
+    section 6, PR 31). Both visit only the groups that have rows. Elsewhere
+    (the CPU tests) ``ragged_dot``'s reference lowering."""
+    from ..ops.pallas_kernels import _interpret
+
+    if _interpret():
+        return jax.lax.ragged_dot(xs, w, sizes,
+                                  preferred_element_type=out_dtype)
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    tm = _GMM_TILING[0]
+    pad = -xs.shape[0] % tm
+    out = gmm(jnp.pad(xs, ((0, pad), (0, 0))) if pad else xs, w, sizes,
+              preferred_element_type=out_dtype, tiling=_GMM_TILING)
+    return out[:xs.shape[0]]
+
+
+def moe_experts_ffn(mp: dict, h, valid, *, top_k: int, first_expert: int = 0,
+                    routed_scale: float = 1.0, compute_dtype=None):
+    """The dropless expert layer of the serving path, for a ``(T, d)`` batch
+    of tokens — a prefill chunk or a decode bucket's rows alike, so both
+    route identically: float32 softmax router over ALL the model's experts
+    (``mp["router"]``: (d, E)), the ``top_k`` largest renormalised over
+    themselves, SwiGLU experts, the routed sum times ``routed_scale``, plus
+    the shared expert (ungated). The layer holds the experts
+    ``[first_expert, first_expert + held)`` (``mp["e_gate"]``/``e_up``:
+    (held, d, f), ``e_down``: (held, f, d)) and computes THEIR part of the
+    result: what the absent experts would add is left out. There is no
+    capacity and no dropped token: the assignments are sorted by held expert
+    and the experts run as grouped matmuls (:func:`_grouped_matmul`, which
+    visits only the groups that have rows, so an expert no token picked is
+    never read). Rows where ``valid``
+    is false (a bucket's dummy rows, a chunk's padding) are routed nowhere.
+
+    The router reads ``h`` as it comes (float32 from the caller's norm: a
+    rounded input flips near-ties among the picks); the experts' matmuls run
+    on ``h`` in ``compute_dtype`` (default: ``h``'s own).
+
+    Returns ``(out, counts)``: ``out`` (T, d) in ``h``'s dtype; ``counts``
+    int32 ``(assignments, assignments that fell on held experts, distinct
+    held experts touched)``."""
+    T, d = h.shape
+    cd = jnp.dtype(compute_dtype or h.dtype)
+    held = mp["e_gate"].shape[0]
+    gates = jax.nn.softmax(jnp.matmul(
+        h.astype(jnp.float32), mp["router"].astype(jnp.float32),
+        precision="highest"), axis=-1)
+    topv, topi = jax.lax.top_k(gates, top_k)                      # (T, k)
+    topv = topv / jnp.sum(topv, axis=-1, keepdims=True)
+    local = ((topi >= first_expert) & (topi < first_expert + held)
+             & valid[:, None])
+    # one row per assignment, sorted by held expert; what is not held here
+    # (or not a token) sorts behind every group and belongs to none
+    lid = jnp.where(local, topi - first_expert, held).reshape(T * top_k)
+    order = jnp.argsort(lid, stable=True)
+    sizes = jnp.sum(lid[:, None] == jnp.arange(held)[None, :], axis=0,
+                    dtype=jnp.int32)
+    hc = h.astype(cd)
+    xs = hc[order // top_k]                                       # (A, d)
+    a = (jax.nn.silu(_grouped_matmul(xs, mp["e_gate"].astype(cd), sizes, cd))
+         * _grouped_matmul(xs, mp["e_up"].astype(cd), sizes, cd))
+    ys = _grouped_matmul(a, mp["e_down"].astype(cd), sizes,
+                         jnp.float32)                             # (A, d)
+    n_local = jnp.sum(sizes)
+    # back to the tokens, a pick at a time: a local pick's row lies inside
+    # its group; every other pick's row is undefined and is not read
+    at = jnp.argsort(order).reshape(T, top_k)
+    routed = sum(jnp.where(local[:, j, None], ys[at[:, j]], 0.0)
+                 * topv[:, j, None] for j in range(top_k))
+    shared = jnp.matmul(jax.nn.silu(hc @ mp["s_gate"].astype(cd))
+                        * (hc @ mp["s_up"].astype(cd)),
+                        mp["s_down"].astype(cd),
+                        preferred_element_type=jnp.float32)
+    out = routed_scale * routed + shared
+    counts = jnp.stack([jnp.sum(valid) * top_k, n_local,
+                        jnp.sum(sizes > 0)]).astype(jnp.int32)
+    return out.astype(h.dtype), counts

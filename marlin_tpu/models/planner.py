@@ -89,16 +89,25 @@ def usable_hbm_bytes(total_bytes: int = 16 * GIB,
     return total_bytes - DEFAULT_RESERVE_BYTES
 
 
-def kv_page_bytes(params: dict, heads: int, page_len: int,
-                  compute_dtype=None) -> int:
+def kv_page_bytes(params: dict, heads, page_len: int,
+                  compute_dtype=None, kind: str = "full") -> int:
     """Bytes of ONE KV page across every layer: layers x {k,v} x page_len x
     kv_heads x dh in the compute dtype. The paged serving engine's admission
     unit — a request is charged :func:`request_pages` x this, the *actual*
     memory its cache rows can ever pin, instead of the dense-slab era's
-    bucket worst case (docs/serving.md)."""
+    bucket worst case (docs/serving.md). For a
+    :class:`~marlin_tpu.models.hybrid.ModelSpec` a page id names a page in
+    the layers of one ``kind`` only (``full``: the global class;
+    ``sliding``: the window class), so each class has its own page bytes."""
     import jax.numpy as jnp
 
+    from .hybrid import ModelSpec
     from .transformer import _n_layers
+
+    if isinstance(heads, ModelSpec):
+        dt = jnp.dtype(compute_dtype or heads.compute_dtype)
+        return (len(heads.layer_names(kind)) * 2 * page_len * heads.kv_heads
+                * heads.head_dim * dt.itemsize)
 
     d = params["emb"].shape[1]
     dh = d // heads
@@ -108,17 +117,21 @@ def kv_page_bytes(params: dict, heads: int, page_len: int,
         * dt.itemsize
 
 
-def request_pages(prompt_len: int, steps: int, page_len: int) -> int:
+def request_pages(prompt_len: int, steps: int, page_len: int,
+                  ring: int | None = None) -> int:
     """KV pages one request can ever write: cache positions run
     ``[0, prompt_len + steps - 1)`` (the final emitted token is never
     decoded from, so its K/V is never stored), rounded up to whole pages.
     This is the paged admission charge AND the allocation size — charging
     what will be written is what guarantees page allocation can never fail
-    under an admission-bounded load (serving/kvpool.py)."""
+    under an admission-bounded load (serving/kvpool.py). With ``ring`` (the
+    window class of a model with sliding layers) the request pins at most
+    that many pages: positions behind the window are overwritten in place."""
     if prompt_len < 1 or steps < 1 or page_len < 1:
         raise ValueError(f"prompt_len/steps/page_len must be >= 1, got "
                          f"{(prompt_len, steps, page_len)}")
-    return -(-(prompt_len + steps - 1) // page_len)
+    pages = -(-(prompt_len + steps - 1) // page_len)
+    return pages if ring is None else min(pages, ring)
 
 
 @dataclasses.dataclass(frozen=True)

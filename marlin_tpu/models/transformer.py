@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .hybrid import ModelSpec, require_int_heads
+
 __all__ = ["TransformerLM", "init_transformer", "transformer_forward",
            "lm_loss", "lm_train_step", "lm_generate", "lm_generate_batch",
            "init_kv_slab", "lm_prefill_slot", "lm_decode_rows",
@@ -239,6 +241,7 @@ def _trunk(params, tokens, mesh, heads, attn, remat, precision,
     L·S·d term from device HBM entirely: the knob that carries training past
     the single-chip context cliff (docs/parallelism.md; SURVEY §7
     "matrices bigger than HBM")."""
+    require_int_heads(heads, "the trainer and transformer_forward")
     from ..mesh import default_mesh
 
     mesh = mesh or default_mesh()
@@ -586,6 +589,7 @@ def lm_generate(params, prompt, key, heads: int, max_len: int, steps: int,
     caches in that dtype — at decode the caches ARE the memory, so this
     halves cache HBM; logits/softmax stay f32. Defaults to the params
     dtype."""
+    require_int_heads(heads, "lm_generate")
     return _lm_generate_jit(
         params, jnp.asarray(prompt, jnp.int32), key, heads=heads,
         max_len=max_len, steps=steps,
@@ -653,6 +657,7 @@ def lm_generate_batch(params, prompts, lengths, key, heads: int,
     (``temperature``/``top_p`` traced, ``top_k`` static, ``top_p=None``
     statically sort-free).
     """
+    require_int_heads(heads, "lm_generate_batch")
     return _lm_generate_batch_jit(
         params, jnp.asarray(prompts, jnp.int32),
         jnp.asarray(lengths, jnp.int32), key, heads=heads, max_len=max_len,
@@ -731,6 +736,7 @@ def init_kv_slab(params, rows: int, max_len: int, heads: int,
     bucket (max_len = P_bucket + steps_bucket). The slab is allocated once
     per (bucket, engine) and then only ever updated in place through the
     donated prefill/decode programs below."""
+    require_int_heads(heads, "the slab programs (init_kv_slab)")
     d = params["emb"].shape[1]
     dh = d // heads
     kvh = params["l0"]["wk"].shape[1] // dh  # kv_heads <= heads under GQA
@@ -815,6 +821,7 @@ def lm_prefill_slot(params, caches, tokens, slot, prompt, length, heads: int,
     ``prompt + first_token`` into the slot's token row, and returns
     ``(caches, tokens, first_token)``. One compile per (P, max_len) bucket
     shape — ``slot``/``length``/sampling knobs are all traced."""
+    require_int_heads(heads, "the slab programs (lm_prefill_slot)")
     return _lm_prefill_slot_jit(
         params, caches, tokens, jnp.asarray(slot, jnp.int32),
         jnp.asarray(prompt, jnp.int32), jnp.asarray(length, jnp.int32),
@@ -870,6 +877,7 @@ def lm_decode_rows(params, caches, tokens, positions, steps_done, seeds,
     (the caller guarantees ``positions + 1 < max_len`` for live rows) and
     returns ``(caches, tokens, next_tokens)``. One compile per bucket —
     the second of the two row-level programs."""
+    require_int_heads(heads, "the slab programs (lm_decode_rows)")
     as_i32 = lambda v: jnp.asarray(v, jnp.int32)  # noqa: E731
     return _lm_decode_rows_jit(
         params, caches, tokens, as_i32(positions), as_i32(steps_done),
@@ -931,14 +939,21 @@ def _lm_decode_rows_jit(params, caches, tokens, positions, steps_done, seeds,
 # out-of-extent scatters scribble where nothing valid ever lives.
 
 
-def init_kv_pages(params, num_pages: int, page_len: int, heads: int,
-                  compute_dtype: str | None = None):
+def init_kv_pages(params, num_pages: int, page_len: int, heads,
+                  compute_dtype: str | None = None, window_pages: int = 0):
     """Zeroed page slab: layer -> (k, v), each (num_pages, page_len,
     kv_heads, dh) in the compute dtype. One slab per engine — buckets share
     it; only block tables are bucket-shaped. Keep ``page_len`` a multiple
     of 8 (16 default) so pages stay sublane-aligned on TPU and the decode
     gather stays on the fast path (PAPERS.md 2202.05868: block geometry
-    must track the MXU/lane grid)."""
+    must track the MXU/lane grid). For a :class:`~.hybrid.ModelSpec` the
+    sliding layers' slabs hold ``window_pages`` pages instead
+    (:func:`.hybrid.init_kv_pages`)."""
+    if isinstance(heads, ModelSpec):
+        from . import hybrid
+
+        return hybrid.init_kv_pages(heads, num_pages, window_pages, page_len,
+                                    compute_dtype)
     if num_pages < 2:
         raise ValueError(f"num_pages must be >= 2 (page 0 is the dummy), "
                          f"got {num_pages}")
@@ -978,7 +993,18 @@ def lm_prefill_paged(params, pages, table, chunk, chunk_start, length,
     token — meaningful only on the final chunk (the one containing position
     ``length - 1``); earlier chunks return a garbage sample the scheduler
     ignores. One compile per (C, W_t) shape — ``chunk_start``, ``length``,
-    the table, and every sampling knob are traced."""
+    the table, and every sampling knob are traced.
+
+    With a :class:`~.hybrid.ModelSpec` for ``heads``, ``table`` is the row's
+    ``(global table, window ring)`` and the result is
+    :func:`.hybrid.prefill_paged`'s ``(pages, first, counts, logits)``."""
+    if isinstance(heads, ModelSpec):
+        from . import hybrid
+
+        return hybrid.prefill_paged(
+            params, pages, table, chunk, chunk_start, length, heads,
+            page_len, seed=seed, temperature=temperature, top_p=top_p,
+            top_k=top_k)
     return _lm_prefill_paged_jit(
         params, pages, jnp.asarray(table, jnp.int32),
         jnp.asarray(chunk, jnp.int32), jnp.asarray(chunk_start, jnp.int32),
@@ -1137,7 +1163,17 @@ def lm_decode_paged(params, pages, tables, positions, cur_tokens,
       path (logits agree to ~ulp — online softmax reassociates).
 
     Returns ``(pages, next_tokens)``. One compile per (B, W) bucket shape
-    per backend."""
+    per backend. With a :class:`~.hybrid.ModelSpec` for ``heads``,
+    ``tables`` is ``(global tables, window rings)`` and the result is
+    :func:`.hybrid.decode_paged`'s ``(pages, next_tokens, counts,
+    logits)``."""
+    if isinstance(heads, ModelSpec):
+        from . import hybrid
+
+        return hybrid.decode_paged(
+            params, pages, tables, positions, cur_tokens, steps_done, seeds,
+            temperature, top_p, top_k, heads, page_len,
+            resolve_decode_kernel(kernel))
     as_i32 = lambda v: jnp.asarray(v, jnp.int32)  # noqa: E731
     return _lm_decode_paged_jit(
         params, pages, as_i32(tables), as_i32(positions),

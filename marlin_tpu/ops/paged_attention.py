@@ -88,12 +88,23 @@ def paged_attention_cost(batch: int, table_width: int, page_len: int,
     return {"flops": flops, "bytes accessed": kv_bytes + qo_bytes}
 
 
-def _paged_attn_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
-                       acc_ref, m_ref, l_ref, *, page_len: int):
+def _paged_attn_kernel(*refs, page_len: int, windowed: bool = False):
     """Grid (B, W), W innermost ("arbitrary": pages run sequentially per
     row). Scalar-prefetched ``tables`` select the K/V block — the in-place
     read; q/out blocks index by row only, so they stay resident across a
-    row's pages while the online-softmax state accumulates in scratch."""
+    row's pages while the online-softmax state accumulates in scratch.
+    ``windowed`` adds two scalar-prefetched vectors: grid step ``w`` of row
+    ``b`` is the page of positions ``(first_page[b] + w) * page_len ...``
+    (its block picked by :func:`_paged_decode_attention_window_call`'s index
+    map) and positions below ``lower[b]`` are masked like those at or past
+    the length. The first page visited holds ``lower[b]``, a live position,
+    so the running max is finite before any wholly masked page."""
+    if windowed:
+        (tables_ref, lengths_ref, first_ref, lower_ref, q_ref, k_ref, v_ref,
+         o_ref, acc_ref, m_ref, l_ref) = refs
+    else:
+        (tables_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
+         l_ref) = refs
     b = pl.program_id(0)
     w = pl.program_id(1)
 
@@ -112,7 +123,12 @@ def _paged_attn_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
                    preferred_element_type=jnp.float32) / math.sqrt(dh)
     # absolute position of column t is w*page_len + t; live iff < length
     t = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-    s = jnp.where(w * page_len + t < lengths_ref[b], s, _MASKED)
+    if windowed:
+        at = (first_ref[b] + w) * page_len + t
+        s = jnp.where((at >= lower_ref[b]) & (at < lengths_ref[b]), s,
+                      _MASKED)
+    else:
+        s = jnp.where(w * page_len + t < lengths_ref[b], s, _MASKED)
     # online-softmax update: new running max, rescale the old accumulator
     m_prev = m_ref[:]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=2))
@@ -162,8 +178,47 @@ def _paged_decode_attention_call(q, k_pages, v_pages, tables, lengths,
     )(tables, lengths, q, k_pages, v_pages)
 
 
+@functools.partial(jax.jit, static_argnames=("page_len", "interpret"))
+def _paged_decode_attention_window_call(q, k_pages, v_pages, tables, lengths,
+                                        first_page, lower, page_len: int,
+                                        interpret: bool):
+    """The kernel over a row's window: ``tables`` is the ring of W pages in
+    which position ``p`` lives in slot ``(p // page_len) % W``; the grid
+    visits the W pages from ``first_page[b]`` on, in position order. Its own
+    jitted name, so a trace tells the two attention kinds apart."""
+    B, kvh, group, dh = q.shape
+    W = tables.shape[1]
+    kernel = functools.partial(_paged_attn_kernel, page_len=page_len,
+                               windowed=True)
+    row_spec = pl.BlockSpec((1, kvh, group, dh),
+                            lambda b, w, tbl, lens, first, low: (b, 0, 0, 0))
+    page_spec = pl.BlockSpec(
+        (1, page_len, kvh, dh),
+        lambda b, w, tbl, lens, first, low: (
+            tbl[b, jax.lax.rem(first[b] + w, W)], 0, 0, 0))
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(B, W),
+            in_specs=[row_spec, page_spec, page_spec],
+            out_specs=row_spec,
+            scratch_shapes=[
+                pltpu.VMEM((kvh, group, dh), jnp.float32),
+                pltpu.VMEM((kvh, group), jnp.float32),
+                pltpu.VMEM((kvh, group), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, kvh, group, dh), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+    )(tables, lengths, first_page, lower, q, k_pages, v_pages)
+
+
 def paged_decode_attention(q, k_pages, v_pages, tables, lengths,
-                           interpret: bool | None = None) -> jax.Array:
+                           interpret: bool | None = None,
+                           first_page=None, lower=None) -> jax.Array:
     """Decode attention for a batch of rows directly over the page slab.
 
     ``q`` is ``(B, kv_heads, group, dh)`` (the grouped decode-query form;
@@ -177,6 +232,12 @@ def paged_decode_attention(q, k_pages, v_pages, tables, lengths,
     The row's pages are read IN PLACE through the block table (no gathered
     context array); masking, GQA mapping, and softmax numerics follow
     :func:`~marlin_tpu.models.transformer._decode_step` (module docstring).
+
+    With ``first_page`` and ``lower`` (both (B,)) the row attends a window:
+    only positions in ``[lower[b], lengths[b])``, and ``tables`` is the
+    row's ring of W pages (position ``p`` in slot ``(p // page_len) % W``),
+    visited from page ``first_page[b]`` (= ``lower[b] // page_len``) on, so
+    W pages are read whatever the row's length.
     """
     q = jnp.asarray(q)
     if q.ndim != 4:
@@ -199,11 +260,23 @@ def paged_decode_attention(q, k_pages, v_pages, tables, lengths,
         raise ValueError(f"tables must be (B, W) with B={q.shape[0]}, got "
                          f"{tables.shape}")
     W = tables.shape[1]
-    # clamp as the decode path clamps positions: every row attends at least
-    # position 0 (length 1), never past its table extent
-    lengths = jnp.clip(jnp.asarray(lengths, jnp.int32), 1, W * page_len)
     if interpret is None:
         interpret = _interpret()
+    if (first_page is None) != (lower is None):
+        raise ValueError("a window needs both first_page and lower")
+    lengths = jnp.asarray(lengths, jnp.int32)
+    if lower is not None:
+        # the same clamp on the ring's own extent: at least one position,
+        # never past the W pages that start at first_page
+        first_page = jnp.asarray(first_page, jnp.int32)
+        lower = jnp.asarray(lower, jnp.int32)
+        lengths = jnp.clip(lengths, lower + 1, (first_page + W) * page_len)
+        return _paged_decode_attention_window_call(
+            q, k_pages, v_pages, tables, lengths, first_page, lower,
+            page_len=page_len, interpret=bool(interpret))
+    # clamp as the decode path clamps positions: every row attends at least
+    # position 0 (length 1), never past its table extent
+    lengths = jnp.clip(lengths, 1, W * page_len)
     return _paged_decode_attention_call(q, k_pages, v_pages, tables, lengths,
                                         page_len=page_len,
                                         interpret=bool(interpret))

@@ -92,6 +92,7 @@ import weakref
 import numpy as np
 
 from ..config import get_config
+from ..models.hybrid import ModelSpec
 from ..obs import memledger, perf, trace as obs_trace
 from ..obs.collectors import compile_count as _compile_count
 from ..obs.exposition import (register_health_provider,
@@ -106,7 +107,8 @@ from ..utils.tracing import annotate
 from .batcher import (BatchFormer, bucket_program_key, capture_bucket_costs,
                       normalize_buckets, warmup_buckets)
 from .kvpool import (PagedGroup, PagedKVPool, PagePoolExhausted,
-                     auto_num_pages, capture_paged_costs, paged_program_key,
+                     auto_num_pages, auto_window_pages,
+                     capture_paged_costs, group_chunk, paged_program_key,
                      warmup_paged)
 from .metrics import ServeMetrics
 from .programs import PagedLMProgram, ProgramRowSet
@@ -115,6 +117,13 @@ from .request import (SHED_REASON_PREFIX, STATUS_ERROR, STATUS_EXPIRED,
                       AdmissionQueue, Request, Result, ResultHandle)
 
 __all__ = ["ServeEngine", "MigrationError"]
+
+
+def _program_result(out) -> tuple:
+    """``(pages, tokens, expert counts or None)`` of a paged program's
+    result: the dense block's programs return the first two, a ModelSpec's
+    ``(pages, tokens, counts, logits)``."""
+    return out[0], out[1], out[2] if len(out) > 2 else None
 
 
 def _bucket_tag(bucket) -> str:
@@ -210,7 +219,7 @@ class ServeEngine:
     Usable as a context manager (``close()`` on exit); ``start=False`` defers
     the worker thread so tests can stage a queue before any dispatch."""
 
-    def __init__(self, params: dict, heads: int, *, buckets=None,
+    def __init__(self, params: dict, heads, *, buckets=None,
                  max_batch: int | None = None,
                  max_wait_ms: float | None = None,
                  queue_depth: int | None = None,
@@ -221,11 +230,27 @@ class ServeEngine:
                  prefill_chunk: int | None = None,
                  prefix_cache: bool | None = None,
                  decode_kernel: str | None = None,
+                 window_pages: int | None = None,
                  programs=None,
                  clock=time.monotonic, log=None, start: bool = True):
         cfg = get_config()
         self.params = params
         self.heads = heads
+        #: the model's description where ``heads`` is one
+        #: (:class:`~marlin_tpu.models.hybrid.ModelSpec`), else None: the
+        #: dense block that an integer head count names
+        self._spec = heads if isinstance(heads, ModelSpec) else None
+        if self._spec is not None:
+            if paged is False or (paged is None and not cfg.serve_paged):
+                raise ValueError("a ModelSpec is served by the paged path "
+                                 "alone: ServeEngine(params, spec) needs "
+                                 "paged=True")
+            if moe is not None:
+                raise ValueError("`moe` sets the routing of the dense "
+                                 "block's GShard layer; a ModelSpec carries "
+                                 "its own")
+            if compute_dtype is None:
+                compute_dtype = self._spec.compute_dtype
         self.compute_dtype = compute_dtype
         self.moe = moe
         if rowlevel is not None:
@@ -269,11 +294,36 @@ class ServeEngine:
                                     self._page_len)
         self._num_pages = npages
         self._kvpool: PagedKVPool | None = None  # built lazily / on warmup
+        #: the window class (a ModelSpec with sliding layers): the ring of
+        #: pages a row holds there, the class's size, one page's bytes.
+        #: ``_ring`` is None for the dense block, 0 for a spec without a
+        #: sliding layer
+        self._ring = None if self._spec is None else 0
+        self._window_pages = 0
+        self._window_page_bytes = 0
         if self.paged:
             from ..models.planner import kv_page_bytes
 
             self._page_bytes = kv_page_bytes(params, heads, self._page_len,
                                              compute_dtype)
+            if self._spec is not None and self._spec.has_window:
+                from ..models.hybrid import window_ring_pages
+
+                if prefix_cache:
+                    raise ValueError(
+                        "prefix_cache=True with a model that has "
+                        "sliding-window layers: sharing a window layer's "
+                        "pages is not built; leave it unset (off)")
+                self._prefix_cache = False
+                self._ring = window_ring_pages(
+                    self._spec.window,
+                    max(group_chunk(b, self._page_len, self._prefill_chunk)
+                        for b in self.buckets), self._page_len)
+                self._window_pages = int(window_pages or auto_window_pages(
+                    self.buckets, self.max_batch, self._ring))
+                self._window_page_bytes = kv_page_bytes(
+                    params, heads, self._page_len, compute_dtype,
+                    kind="sliding")
         if hbm_budget_bytes is None:
             from ..models.planner import usable_hbm_bytes
 
@@ -560,8 +610,8 @@ class ServeEngine:
         price their actual padded device row; the ratio resolves once per
         bucket (live ProgramCosts first, the AOT table second, 1.0 when
         neither measured this exact program) and is cached."""
-        if request.program != "lm":
-            return cost
+        if request.program != "lm" or self._spec is not None:
+            return cost  # a spec's programs have no captured cost model
         ratio = self._calib_ratios.get(pbucket)
         if ratio is None:
             from .batcher import bucket_kv_bytes
@@ -587,7 +637,8 @@ class ServeEngine:
             # worker they superseded has stopped dispatching
             pool = self._kvpool = PagedKVPool(
                 self.params, self.heads, self._num_pages, self._page_len,
-                self.compute_dtype, self._prefix_cache)
+                self.compute_dtype, self._prefix_cache,
+                window_pages=self._window_pages, ring=self._ring or 0)
             self.metrics.record_pages(pool.capacity, 0, 0)
             # account the slab in the process memory ledger: the free rides
             # every drop path (recovery, slab loss, terminal close), so a
@@ -595,8 +646,9 @@ class ServeEngine:
             led = memledger.get_ledger()
             led.free(f"kvpool:{self._name}", strict=False)
             led.register(f"kvpool:{self._name}",
-                         self._num_pages * self._page_bytes, "kvpool",
-                         owner=self._name)
+                         self._num_pages * self._page_bytes
+                         + self._window_pages * self._window_page_bytes,
+                         "kvpool", owner=self._name)
         return pool
 
     def _record_pages(self, pool) -> None:
@@ -1612,6 +1664,7 @@ class ServeEngine:
         worker has exited and the router closes the engine next."""
         if not self.paged:
             return None
+        self._refuse_window("freeze_rows")
         self._queue.close("engine freezing for migration")
         with self._cond:
             if self._state not in ("running", "draining"):
@@ -1754,6 +1807,7 @@ class ServeEngine:
         if not self.paged:
             raise MigrationError(
                 f"adopt target {self._name} is not a paged engine")
+        self._refuse_window("adopt_rows")
         if timeout is None:
             timeout = get_config().serve_migrate_timeout_s
         box: dict = {"bound": [], "cancelled": False}
@@ -1833,6 +1887,7 @@ class ServeEngine:
         cache warming must never fail a restart)."""
         if not self.paged or n <= 0:
             return None
+        self._refuse_window("export_prefixes")
         if timeout is None:
             timeout = get_config().serve_migrate_timeout_s
         try:
@@ -1846,12 +1901,23 @@ class ServeEngine:
         (worker-mediated; best-effort). Returns entries inserted."""
         if not self.paged or not blob:
             return 0
+        self._refuse_window("import_prefixes")
         if timeout is None:
             timeout = get_config().serve_migrate_timeout_s
         try:
             return int(self._mig_post("import_prefixes", blob, timeout) or 0)
         except MigrationError:
             return 0
+
+    def _refuse_window(self, what: str) -> None:
+        """Migration and prefix sharing move or share a row's pages; a
+        window layer's ring is neither shared nor serialized yet, so for
+        such a model these entry points raise rather than move half a row."""
+        if self._ring:
+            raise MigrationError(
+                f"{what}: {self._name} serves a model with sliding-window "
+                f"layers; migrating or sharing a window layer's pages is "
+                f"not built")
 
     def _mig_post(self, kind: str, payload, timeout: float):
         """Post one op to the worker's migration mailbox and wait."""
@@ -1928,7 +1994,7 @@ class ServeEngine:
             if group is None and bucket in self.buckets:
                 group = pools[bucket] = PagedGroup(
                     bucket, self.max_batch, self._page_len,
-                    self._prefill_chunk)
+                    self._prefill_chunk, ring=self._ring)
                 capture_paged_costs(
                     self.params, self.heads, bucket, self.max_batch,
                     pool, self._prefill_chunk, self.compute_dtype,
@@ -2145,13 +2211,14 @@ class ServeEngine:
         steps), ``kv_tokens`` the positions of them that are written (a row
         mid-prefill has its prefilled prompt), ``pages_used`` adds what the
         prefix cache keeps."""
-        resident = live = row_pages = kv_tokens = 0
+        resident = live = row_pages = window_pages = kv_tokens = 0
         for g in pools.values():
             if isinstance(g, ProgramRowSet):
                 continue
             for i in g.occupied_slots():
                 resident += 1
                 row_pages += len(g.row_pages[i] or ())
+                window_pages += len(g.window_row_pages[i] or ())
                 if g.pf_next[i] < 0:
                     live += 1
                     # analyze: ignore[host-sync] — host numpy bookkeeping
@@ -2159,10 +2226,16 @@ class ServeEngine:
                 else:
                     # analyze: ignore[host-sync] — host numpy bookkeeping
                     kv_tokens += int(min(g.pf_next[i], g.lengths[i]))
-        return {"queue_depth": self._queue.count, "resident_rows": resident,
-                "live_rows": live, "row_pages": row_pages,
-                "pages_used": pool.used_count(),
-                "pages_total": pool.capacity, "kv_tokens": kv_tokens}
+        out = {"queue_depth": self._queue.count, "resident_rows": resident,
+               "live_rows": live, "row_pages": row_pages,
+               "pages_used": pool.used_count(),
+               "pages_total": pool.capacity, "kv_tokens": kv_tokens}
+        if self._spec is not None:
+            # both classes beside their sum: a row's global table covers
+            # every position, its window ring a bounded few
+            out.update(row_pages=row_pages + window_pages,
+                       global_pages=row_pages, window_pages=window_pages)
+        return out
 
     def _admit_paged(self, pool, pools, claimed, pf_queue) -> None:
         """Bind each claimed entry to a free row of its bucket's group:
@@ -2201,7 +2274,7 @@ class ServeEngine:
                 if group is None:
                     group = pools[e.bucket] = PagedGroup(
                         e.bucket, self.max_batch, self._page_len,
-                        self._prefill_chunk)
+                        self._prefill_chunk, ring=self._ring)
                     # no-warmup path: the bucket's cost model still lands
                     # with its first (lazy) compile
                     capture_paged_costs(
@@ -2213,10 +2286,15 @@ class ServeEngine:
                 n = r.prompt.shape[0]
                 shared_len, spages = pool.match_prefix(r.prompt)
                 need = request_pages(n, r.steps, self._page_len)
+                wpages = []
                 try:
+                    if self._ring:
+                        wpages = pool.alloc_window(request_pages(
+                            n, r.steps, self._page_len, ring=self._ring))
                     owned = pool.alloc(need - len(spages))
                 except PagePoolExhausted as exc:
                     pool.release(spages)  # drop the refs the match took
+                    pool.release_window(wpages)
                     # the OOM post-mortem lands BEFORE the retry path runs
                     # (the retry rebuilds state and destroys the evidence)
                     memledger.dump_oom_forensics(
@@ -2232,7 +2310,7 @@ class ServeEngine:
                                      "total_s": now - e.enq_t}))
                     continue
                 group.assign(slot, e, spages + owned, shared_len,
-                             len(spages))
+                             len(spages), wpages)
                 pf_queue.append((e.bucket, slot, r.rid))
                 self.metrics.record_prefix(hit=bool(spages))
                 self.metrics.record_page_event(
@@ -2316,15 +2394,18 @@ class ServeEngine:
 
                     faults.fire("serve.prefill", path=f"bucket-{p}x{s}")
                     t0 = time.perf_counter()
-                    pages, first = lm_prefill_paged(
-                        self.params, pool.pages, group.tables[slot], chunk,
-                        cs, n, heads=self.heads, page_len=self._page_len,
+                    out = lm_prefill_paged(
+                        self.params, pool.pages, group.prefill_tables(slot),
+                        chunk, cs, n, heads=self.heads,
+                        page_len=self._page_len,
                         seed=r.seed, temperature=r.temperature,
                         top_p=r.top_p, top_k=r.top_k,
                         compute_dtype=self.compute_dtype, moe=self.moe)
+                    pages, first, counts = _program_result(out)
                 with annotate("serve.prefill.sync", rid=r.rid,
-                              final=int(final)):
+                              final=int(final)) as sync:
                     first = int(first)  # device sync: the chunk landed
+                    self._moe_counts(sync, counts)
                 wall = time.perf_counter() - t0
             except Exception as exc:
                 pf_queue.popleft()
@@ -2394,12 +2475,13 @@ class ServeEngine:
             for bucket, group, live, t0, nxt in launched:
                 tag = _bucket_tag(bucket)
                 try:
-                    with annotate("serve.decode.sync", bucket=tag):
+                    with annotate("serve.decode.sync", bucket=tag) as sync:
                         # analyze: ignore[host-sync] — THE one intentional
                         # sync per decode step: the host must see the
                         # emitted tokens to retire rows (all dispatches
                         # above launched async first)
-                        nxt = np.asarray(nxt)  # sync; the emitted tokens
+                        nxt, counts = np.asarray(nxt[0]), nxt[1]  # sync
+                        self._moe_counts(sync, counts)
                 except Exception as exc:
                     self._fail_paged_bucket(pool, pools, bucket, exc)
                     continue
@@ -2450,23 +2532,56 @@ class ServeEngine:
             faults.fire("serve.decode_step", path=f"bucket-{p}x{s}")
             t0 = time.perf_counter()
             tables, positions, cur, temperature = group.decode_inputs()
-            pages, nxt = lm_decode_paged(
+            out = lm_decode_paged(
                 self.params, pool.pages, tables, positions, cur,
                 group.steps_done, group.seeds, temperature,
                 group.top_p, group.top_k, heads=self.heads,
                 page_len=self._page_len,
                 compute_dtype=self.compute_dtype, moe=self.moe,
                 kernel=self._decode_kernel)
+            pages, *nxt = _program_result(out)  # (tokens, expert counts)
         except Exception as exc:
             self._fail_paged_bucket(pool, pools, bucket, exc)
             return None
         pool.pages = pages
+        if self._spec is not None:
+            # the pages each attention kind's kernel is given a row
+            tables, ring = tables
+            span.set_metadata(global_table_width=tables.shape[1],
+                              window_table_width=ring.shape[1])
+            if span.is_enabled():
+                # the pages that hold what each kind of layer attends this
+                # step: every position so far; the window's
+                at = group.positions[live] // self._page_len
+                low = (np.maximum(group.positions[live]
+                                  - self._spec.window + 1, 0)
+                       // self._page_len)
+                span.set_metadata(
+                    # analyze: ignore[host-sync] — host numpy bookkeeping
+                    global_kv_pages=int((at + 1).sum()),
+                    window_kv_pages=int((at - low + 1).sum())
+                    if self._ring else 0)
         span.set_metadata(padded_rows=self.max_batch,
                           table_width=tables.shape[1],
                           # analyze: ignore[host-sync] — host numpy
                           kv_tokens=int(positions.sum()) + len(live),
                           sampled_rows=int((temperature > 0).sum()))
         return bucket, group, live, t0, nxt
+
+    @staticmethod
+    def _moe_counts(span, counts) -> None:
+        """A ModelSpec's programs return, after the tokens, what their
+        expert layers counted (summed over layers): set on the span in which
+        the result landed — the dispatch's own span has closed by then, and
+        a span's fields cannot be set afterwards. Read only while a trace is
+        taken; the arrays have landed with the tokens."""
+        if counts is not None and span.is_enabled():
+            # analyze: ignore[host-sync] — three ints that rode back with
+            # the tokens the caller has just synced on
+            assigned, local, touched = (int(c) for c in np.asarray(counts))
+            span.set_metadata(moe_assignments=assigned,
+                              moe_local_assignments=local,
+                              moe_experts_touched=touched)
 
     def _land_paged(self, pool, pools, bucket, group, live, nxt,
                     seconds: float) -> int:
@@ -2523,9 +2638,11 @@ class ServeEngine:
         else:
             result = Result(e.request.rid, status, reason=reason,
                             metrics=metrics)
+        ring = group.window_row_pages[slot]
         pages = group.release(slot)
         if pool is not None:
             pool.release(pages)
+            pool.release_window(ring)
             # inside the request's span: the free record must join the
             # request's trace whichever step retires it
             with obs_trace.use(e.trace):
@@ -2605,6 +2722,7 @@ class ServeEngine:
             for i in group.live_slots():
                 e = group.entries[i]
                 if e.attempts_left():
+                    pool.release_window(group.window_row_pages[i])
                     pool.release(group.release(i))
                     self._requeue(e, reason)
                 else:
@@ -2634,6 +2752,7 @@ class ServeEngine:
                                   f"pool lost to a failed prefill: {reason}")
         else:
             now = self._clock()
+            pool.release_window(group.window_row_pages[slot])
             pool.release(group.release(slot))
             if e.attempts_left():
                 self._requeue(e, reason)

@@ -43,6 +43,19 @@ slack) always has room, whatever the queue depth. A hand-set smaller
 the call either way (a failed alloc retries/errors one request, never the
 worker).
 
+**Two classes of page** (a :class:`~marlin_tpu.models.hybrid.ModelSpec`
+with sliding-window layers): the full-attention layers' slabs are indexed by
+the page ids above (a row's *global* table covers every position); the
+sliding layers' slabs are a second, smaller id space with its own free list
+and refcounts, of which a row holds a fixed **ring** of
+:func:`~marlin_tpu.models.hybrid.window_ring_pages` pages (position ``p``
+lives in ring slot ``(p // page_len) % ring``: a page that falls behind the
+window is overwritten in place, so a row pins O(window + chunk) positions
+there whatever its length). Admission charges each class for what the row
+can pin; :meth:`PagedKVPool.audit` balances both. Window pages are never
+shared: with such a model the prefix cache is off and the migration entry
+points raise.
+
 Everything here is host-side numpy/stdlib except the three compiled
 programs it drives; single-threaded by contract (only the engine worker
 touches a pool, like :class:`~.batcher.SlotPool`).
@@ -132,6 +145,26 @@ def _round_up(n: int, m: int) -> int:
     return -(-n // m) * m
 
 
+def group_chunk(bucket, page_len: int, prefill_chunk: int) -> int:
+    """The compiled prefill-chunk width of one bucket, in tokens: whole
+    pages, never wider than the prompt extent (a narrow bucket compiles the
+    smaller program), and CAPPED below the per-iteration token budget
+    (serve_prefill_chunk) — the program's cost is fixed at its width
+    whatever the real token count, so a wide program makes a prefix-hit
+    row's short tail (the prefix-cache win) as expensive as a full prefill;
+    the engine instead runs several small chunks per iteration up to the
+    budget."""
+    cap = max(64, 4 * page_len)
+    return min(_round_up(max(1, prefill_chunk), page_len),
+               _round_up(bucket[0], page_len), _round_up(cap, page_len))
+
+
+def auto_window_pages(buckets, max_batch: int, ring: int) -> int:
+    """The default size of the window class: every slot of every bucket
+    holds one ring, plus the dummy."""
+    return 1 + len(list(buckets)) * max_batch * ring
+
+
 def auto_num_pages(buckets, max_batch: int, page_len: int) -> int:
     """The default pool size (``serve_num_pages=0``): every bucket's full
     slot width at its full extent — the dense-slab steady state, so a
@@ -161,16 +194,30 @@ class PagedKVPool:
     donated program call. Counters (``hits``/``misses``/``cow_copies``/
     ``evictions``) feed the serving metrics."""
 
-    def __init__(self, params: dict, heads: int, num_pages: int,
+    def __init__(self, params: dict, heads, num_pages: int,
                  page_len: int, compute_dtype: str | None = None,
-                 prefix_cache: bool = True):
+                 prefix_cache: bool = True, window_pages: int = 0,
+                 ring: int = 0):
+        from ..models.hybrid import ModelSpec
         from ..models.transformer import init_kv_pages
 
         self.page_len = int(page_len)
         self.num_pages = int(num_pages)
         self.compute_dtype = compute_dtype
+        windowed = isinstance(heads, ModelSpec) and heads.has_window
+        #: pages of the window class (0: the model has no sliding layer)
+        #: and the ring a row holds of them
+        self.window_pages = int(window_pages) if windowed else 0
+        self.ring = int(ring) if windowed else 0
+        if windowed and self.ring < 1:
+            raise ValueError("a model with sliding layers needs ring >= 1")
         self.pages = init_kv_pages(params, num_pages, page_len, heads,
-                                   compute_dtype)
+                                   compute_dtype, self.window_pages)
+        self._wfree = list(range(self.window_pages - 1, 0, -1))
+        self._wref = np.zeros(self.window_pages, np.int32)
+        if windowed:
+            self._wref[0] = 1  # the window class's own dummy
+            prefix_cache = False  # a window page is never shared
         # pop() hands out ascending ids; page 0 never enters the list
         self._free = list(range(num_pages - 1, 0, -1))
         self._ref = np.zeros(num_pages, np.int32)
@@ -204,11 +251,47 @@ class PagedKVPool:
         return len(self._cache)
 
     def stats(self) -> dict:
-        return {"total": self.capacity, "used": self.used_count(),
-                "shared": self.shared_count(),
-                "cached": self.cached_count(), "hits": self.hits,
-                "misses": self.misses, "cow_copies": self.cow_copies,
-                "evictions": self.evictions}
+        out = {"total": self.capacity, "used": self.used_count(),
+               "shared": self.shared_count(),
+               "cached": self.cached_count(), "hits": self.hits,
+               "misses": self.misses, "cow_copies": self.cow_copies,
+               "evictions": self.evictions}
+        if self.window_pages:
+            out.update(window_total=self.window_pages - 1,
+                       window_used=self.window_used_count())
+        return out
+
+    # ------------------------------------------------------ the window class
+
+    def window_used_count(self) -> int:
+        return max(self.window_pages - 1, 0) - len(self._wfree)
+
+    def alloc_window(self, n: int) -> list[int]:
+        """``n`` fresh pages of the window class (a row's ring). Nothing is
+        evictable there: :class:`PagePoolExhausted` when too few are free."""
+        if len(self._wfree) < n:
+            raise PagePoolExhausted(
+                f"need {n} window pages, {len(self._wfree)} free "
+                f"({self.window_used_count()}/{self.window_pages - 1} used)")
+        out = [self._wfree.pop() for _ in range(n)]
+        for p in out:
+            self._wref[p] = 1
+        return out
+
+    def release_window(self, pages) -> None:
+        """A retiring row's ring goes back to the window class's free list
+        (the window twin of :meth:`release`; never shared, so one referent)."""
+        for p in pages or ():
+            self._wref[p] -= 1
+            assert self._wref[p] == 0, f"window page {p} released twice"
+            self._wfree.append(int(p))
+
+    def _refuse_window(self, what: str) -> None:
+        if self.window_pages:
+            raise NotImplementedError(
+                f"{what} is not built for a model with sliding-window "
+                f"layers: a window layer's pages are a per-row ring that is "
+                f"neither shared nor serialized")
 
     # ----------------------------------------------------- alloc / refcount
 
@@ -415,6 +498,7 @@ class PagedKVPool:
         gathered device→host once for the whole set. The blob is
         self-contained: :meth:`import_rows` on any pool with matching
         geometry rebuilds the rows without reference to this pool."""
+        self._refuse_window("row migration (export_rows)")
         host = self._host_pages()
         names = self._layer_names()
         meta = {"version": 1, "kind": "rows", **self._geometry(),
@@ -443,6 +527,7 @@ class PagedKVPool:
         failure every page this call allocated is released (pages already
         content-written stay valid for the cache entries that reference
         them), so a failed import leaks nothing."""
+        self._refuse_window("row migration (import_rows)")
         chunks = _mig_chunks(blob)
         if not chunks or chunks[0][0] != _MIG_META:
             raise MigrationCorruptError("blob does not start with a meta "
@@ -517,6 +602,7 @@ class PagedKVPool:
         never be matched), as a CRC-framed blob for warming a peer's cache.
         Keys are the content hashes themselves — no prompt tokens travel.
         Returns None when there is nothing to export."""
+        self._refuse_window("prefix export")
         if not self.prefix_cache_enabled or not self._cache:
             return None
         selected: set[bytes] = set()
@@ -559,6 +645,7 @@ class PagedKVPool:
         failing it), takes the cache-owned reference, and links into the
         parent chain. Entries already cached (or whose parent did not make
         the cut) are skipped. Returns entries inserted."""
+        self._refuse_window("prefix import")
         if not self.prefix_cache_enabled:
             return 0
         chunks = _mig_chunks(blob)
@@ -628,6 +715,7 @@ class PagedKVPool:
         errors: list[str] = []
         expect = np.zeros(self.num_pages, np.int64)
         expect[0] = 1  # the dummy pin
+        errors += self._audit_window(groups)
         for g in groups:
             for slot in g.occupied_slots():
                 for p in (g.row_pages[slot] or []):
@@ -689,6 +777,45 @@ class PagedKVPool:
         return {"ok": not errors, "errors": errors, **self.stats()}
 
 
+    def _audit_window(self, groups) -> list[str]:
+        """The window class's half of :meth:`audit`: every ring within its
+        bound, every page either free or held by exactly one row."""
+        if not self.window_pages:
+            return []
+        errors: list[str] = []
+        expect = np.zeros(self.window_pages, np.int64)
+        expect[0] = 1
+        for g in groups:
+            for slot in g.occupied_slots():
+                ring = g.window_row_pages[slot] or []
+                if len(ring) > self.ring:
+                    errors.append(f"a row's window table holds {len(ring)} "
+                                  f"pages, over its bound {self.ring}")
+                for p in ring:
+                    if not 0 < int(p) < self.window_pages:
+                        errors.append(f"window table references "
+                                      f"out-of-range page {int(p)}")
+                    else:
+                        expect[int(p)] += 1
+        fs = set(self._wfree)
+        if len(fs) != len(self._wfree):
+            errors.append("window free list contains duplicate pages")
+        if 0 in fs or int(self._wref[0]) < 1:
+            errors.append("window dummy page 0 is free or unpinned")
+        for p in range(1, self.window_pages):
+            ref = int(self._wref[p])
+            if p in fs:
+                if ref or expect[p]:
+                    errors.append(f"free window page {p} is still referenced")
+            elif ref == 0:
+                errors.append(f"window page {p} leaked: refcount 0 but not "
+                              f"on the free list")
+            elif groups and ref != int(expect[p]):
+                errors.append(f"window page {p}: refcount {ref} != "
+                              f"{int(expect[p])} referents")
+        return errors
+
+
 class PagedGroup:
     """Per-bucket row bookkeeping over a shared :class:`PagedKVPool` — the
     paged analog of :class:`~.batcher.SlotPool`. Owns the per-row vectors
@@ -699,26 +826,23 @@ class PagedGroup:
     the engine worker touches a group."""
 
     def __init__(self, bucket, width: int, page_len: int,
-                 prefill_chunk: int):
+                 prefill_chunk: int, ring: int | None = None):
         p, s = bucket
         self.bucket = bucket
         self.width = width
         self.page_len = page_len
         #: block-table width for DECODE: pages covering the bucket extent
         self.pages_per_row = -(-(p + s) // page_len)
-        #: compiled chunk width in tokens: whole pages, never wider than
-        #: the prompt extent (a narrow bucket compiles the smaller
-        #: program), and CAPPED below the per-iteration token budget
-        #: (serve_prefill_chunk) — the program's cost is fixed at its
-        #: width whatever the real token count, so a wide program makes a
-        #: prefix-hit row's short tail (the prefix-cache win) as expensive
-        #: as a full prefill; the engine instead runs several small chunks
-        #: per iteration up to the budget
-        cap = max(64, 4 * page_len)
-        self.chunk = min(_round_up(max(1, prefill_chunk), page_len),
-                         _round_up(p, page_len),
-                         _round_up(cap, page_len))
+        #: compiled chunk width in tokens (:func:`group_chunk`)
+        self.chunk = group_chunk(bucket, page_len, prefill_chunk)
         self.chunk_pages = self.chunk // page_len
+        #: pages of a row's window ring: the row's whole window table, for
+        #: prefill and decode alike. None: the dense block's programs, which
+        #: take one table; a ModelSpec's take ``(table, ring)``, the ring 0
+        #: wide where no layer slides
+        self.ring = ring
+        self.window_tables = np.zeros((width, ring or 0), np.int32)
+        self.window_row_pages: list = [None] * width
         #: stored table width: decode extent + chunk spill (a final chunk
         #: starting near the extent scatters into these dummy-page slots)
         self.table_width = self.pages_per_row + self.chunk_pages
@@ -763,10 +887,12 @@ class PagedGroup:
     # ---------------------------------------------------------- transitions
 
     def assign(self, slot: int, entry, pages: list[int], shared_len: int,
-               n_shared: int) -> None:
+               n_shared: int, window_pages=()) -> None:
         """Bind an admitted entry: ``pages`` is the row's full block table
         in position order (``n_shared`` prefix-cache pages first, then the
-        freshly allocated remainder); prefill resumes at ``shared_len``."""
+        freshly allocated remainder); prefill resumes at ``shared_len``.
+        ``window_pages`` is the row's ring of the window class (at most
+        ``ring``; a short request holds only the slots it can reach)."""
         r = entry.request
         n = r.prompt.shape[0]
         self.entries[slot] = entry
@@ -774,6 +900,9 @@ class PagedGroup:
         self.tables[slot, :] = 0
         self.tables[slot, :len(pages)] = pages
         self.row_pages[slot] = list(pages)
+        self.window_tables[slot, :] = 0
+        self.window_tables[slot, :len(window_pages)] = window_pages
+        self.window_row_pages[slot] = list(window_pages)
         self.shared_pages[slot] = n_shared
         self.pf_next[slot] = shared_len
         padded = np.zeros(_round_up(n, self.chunk), np.int32)
@@ -837,6 +966,8 @@ class PagedGroup:
         self.entries[slot] = None
         self.tables[slot, :] = 0
         self.row_pages[slot] = None
+        self.window_tables[slot, :] = 0
+        self.window_row_pages[slot] = None
         self.shared_pages[slot] = 0
         self.pf_next[slot] = -1
         self.positions[slot] = 0
@@ -850,6 +981,13 @@ class PagedGroup:
         self.emitted[slot] = None
         self.ttft_s[slot] = None
         return pages
+
+    def prefill_tables(self, slot: int):
+        """What the prefill program takes for ``slot``: the row's table,
+        and its window ring beside it where the model has sliding layers."""
+        if self.ring is not None:
+            return self.tables[slot], self.window_tables[slot]
+        return self.tables[slot]
 
     # -------------------------------------------------------- decode inputs
 
@@ -865,6 +1003,8 @@ class PagedGroup:
         live[self.live_slots()] = True
         tables = np.where(live[:, None],
                           self.tables[:, :self.pages_per_row], 0)
+        if self.ring is not None:  # a spec's programs take both classes
+            tables = (tables, np.where(live[:, None], self.window_tables, 0))
         positions = np.where(live, self.positions, 0)
         cur = np.where(live, self.cur_tok, 0)
         temperature = np.where(live, self.temperature, np.float32(0.0))
@@ -918,6 +1058,13 @@ def capture_paged_costs(params: dict, heads: int, bucket, max_batch: int,
                                 compute_dtype, kernel)
     programs = ("lm_prefill_paged", "lm_decode_paged")
     if all(costs.tried(name, key) for name in programs):
+        return
+    if not isinstance(heads, int):
+        # a ModelSpec's programs have their own signatures; their cost
+        # models are not captured (the attempt is marked, as after a failed
+        # trace), so the roofline gauges skip them
+        for name in programs:
+            costs.capture(name, key)
         return
     from ..models.transformer import (_lm_decode_paged_jit,
                                       _lm_prefill_paged_jit, _n_layers,
@@ -988,23 +1135,39 @@ def warmup_paged(params: dict, heads: int, buckets, max_batch: int,
     from .batcher import normalize_buckets
 
     buckets = normalize_buckets(buckets)
-    for bucket in buckets:
-        g = PagedGroup(bucket, max_batch, pool.page_len, prefill_chunk)
+    groups = [PagedGroup(bucket, max_batch, pool.page_len, prefill_chunk,
+                         ring=None if isinstance(heads, int) else pool.ring)
+              for bucket in buckets]
+    w = max_batch
+    rows = (np.zeros(w, np.int32), np.zeros(w, np.int32),
+            np.zeros(w, np.int32), np.zeros(w, np.uint32),
+            np.zeros(w, np.float32), np.ones(w, np.float32),
+            np.zeros(w, np.int32))
+    if not isinstance(heads, int):
+        # a spec's programs take the compiler half a minute each and leave
+        # most cores idle: all buckets' at once, then the calls below run
+        # what is compiled
+        from ..models import hybrid
+        from ..models.transformer import resolve_decode_kernel
+
+        hybrid.precompile_paged(
+            [(params, pool.pages, g.prefill_tables(0),
+              np.zeros(g.chunk, np.int32), 0, 1, heads, pool.page_len)
+             for g in groups],
+            [(params, pool.pages, g.decode_inputs()[0], *rows, heads,
+              pool.page_len, resolve_decode_kernel(kernel)) for g in groups])
+    for bucket, g in zip(buckets, groups):
         capture_paged_costs(params, heads, bucket, max_batch, pool,
                             prefill_chunk, compute_dtype, moe,
                             kernel=kernel)
-        pool.pages, _ = lm_prefill_paged(
-            params, pool.pages, np.zeros(g.table_width, np.int32),
+        pool.pages = lm_prefill_paged(
+            params, pool.pages, g.prefill_tables(0),
             np.zeros(g.chunk, np.int32), 0, 1, heads=heads,
-            page_len=pool.page_len, compute_dtype=compute_dtype, moe=moe)
-        w = max_batch
+            page_len=pool.page_len, compute_dtype=compute_dtype, moe=moe)[0]
         pool.pages, nxt = lm_decode_paged(
-            params, pool.pages, np.zeros((w, g.pages_per_row), np.int32),
-            np.zeros(w, np.int32), np.zeros(w, np.int32),
-            np.zeros(w, np.int32), np.zeros(w, np.uint32),
-            np.zeros(w, np.float32), np.ones(w, np.float32),
-            np.zeros(w, np.int32), heads=heads, page_len=pool.page_len,
-            compute_dtype=compute_dtype, moe=moe, kernel=kernel)
+            params, pool.pages, g.decode_inputs()[0], *rows, heads=heads,
+            page_len=pool.page_len, compute_dtype=compute_dtype, moe=moe,
+            kernel=kernel)[:2]
         jax.block_until_ready(nxt)
     pool.pages = kv_page_copy(pool.pages, 0, 0)  # the third program
     jax.block_until_ready(pool.pages["l0"][0])

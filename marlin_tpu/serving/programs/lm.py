@@ -74,7 +74,11 @@ class PagedLMProgram(BucketProgram):
                 raise ValueError(
                     f"request needs {pages} KV pages but the pool holds "
                     f"{eng._num_pages - 1} (serve_num_pages)")
-            return pages * eng._page_bytes
+            # each class of page is charged for what the row can pin in it:
+            # every position in the global class, a ring in the window class
+            ring = request_pages(request.prompt.shape[0], request.steps,
+                                 eng._page_len, ring=eng._ring or 0)
+            return pages * eng._page_bytes + ring * eng._window_page_bytes
         return bucket_kv_bytes(eng.params, eng.heads, bucket,
                                eng.compute_dtype)
 

@@ -659,3 +659,54 @@ def test_rmm_20000_compiles_for_2x2_v5e(topo_mesh):
         precision="high")).trace(a, a).lower().compile()
     assert "all-reduce" in c.as_text()
     assert c.memory_analysis().peak_memory_in_bytes < 15 * 1024**3
+
+
+@pytest.mark.parametrize("group", [6, 9], ids=["full-g6", "sliding-g9"])
+def test_paged_window_kernel_mosaic_compiles_at_laguna_widths(one_chip,
+                                                              group):
+    """The decode kernel at Laguna-S-2.1's widths through real Mosaic: 8 KV
+    heads x 128, page_len 128, 32 rows; query groups 6 (full layers, the
+    plain call over 64 pages) and 9 (sliding layers, the window call over a
+    ring of 5 pages)."""
+    from marlin_tpu.ops.paged_attention import (
+        _paged_decode_attention_call, _paged_decode_attention_window_call)
+
+    def st(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    B, kvh, dh, pl = 32, 8, 128, 128
+    q = st((B, kvh, group, dh), jnp.bfloat16)
+    if group == 6:
+        slab = st((1537, pl, kvh, dh), jnp.bfloat16)
+        c = _paged_decode_attention_call.trace(
+            q, slab, slab, st((B, 64)), st((B,)), page_len=pl,
+            interpret=False).lower().compile()
+    else:
+        slab = st((201, pl, kvh, dh), jnp.bfloat16)
+        c = _paged_decode_attention_window_call.trace(
+            q, slab, slab, st((B, 5)), st((B,)), st((B,)), st((B,)),
+            page_len=pl, interpret=False).lower().compile()
+    assert "tpu_custom_call" in c.as_text()
+
+
+def test_dropless_expert_layer_compiles_for_v5e_as_a_grouped_matmul(one_chip):
+    """``moe_experts_ffn`` at the published widths (hidden 3072, 64 held
+    experts of width 1024, router 256, top-10) for a decode bucket's 32
+    rows: on the chip its experts run through the Pallas grouped matmul
+    (``megablox.gmm``, a ``tpu_custom_call``), not a dense dot over every
+    expert and not XLA's own ``ragged-dot`` lowering."""
+    from marlin_tpu.models.moe import moe_experts_ffn
+
+    def st(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    d, e, f = 3072, 64, 1024
+    mp = {"router": st((d, 256), jnp.float32), "e_gate": st((e, d, f)),
+          "e_up": st((e, d, f)), "e_down": st((e, f, d)),
+          "s_gate": st((d, f)), "s_up": st((d, f)), "s_down": st((f, d))}
+    with mt.config_context(pallas_interpret=False):
+        c = jax.jit(lambda mp, h, valid: moe_experts_ffn(
+            mp, h, valid, top_k=10, first_expert=64, routed_scale=2.5)).trace(
+                mp, st((32, d)), st((32,), jnp.bool_)).lower().compile()
+    text = c.as_text()
+    assert text.count("tpu_custom_call") >= 3 and "ragged-dot" not in text

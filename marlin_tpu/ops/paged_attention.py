@@ -26,13 +26,21 @@ therefore differs from the dense softmax by float associativity (logits
 agree to ~ulp); greedy argmax is unaffected, which is the serving
 bit-identity contract (tests/test_paged_attention.py drives it).
 
-Per-row ``lengths`` masks the tail: positions ``>= lengths[b]`` score −1e30
-exactly as the gather path masks them, and pages wholly past a row's
-length contribute ``exp(−1e30 − m) = 0`` (the row's first page always has
-at least one live position — lengths are clamped ≥ 1, mirroring the decode
-path's position clamp). Dummy rows (all-zero block tables, the free/
-prefilling-slot contract) attend one masked-harmless position of the
-sacrificial page 0.
+Per-row ``lengths`` masks the tail: inside a row's last live page,
+positions ``>= lengths[b]`` score −1e30 exactly as the gather path masks
+them. A page wholly past a row's length is SKIPPED: its grid step runs
+neither einsum nor the softmax update (``pl.when``), and the index map names
+the row's last live page again, so the pipeline starts no copy for it and
+never reads the table entry. The numbers are those of masking it (it would
+contribute ``exp(−1e30 − m) = 0`` and rescale by ``exp(0) = 1``), except
+that the output no longer depends on what such a page holds: a NaN or Inf
+there used to turn ``0 × Inf`` into NaN. The first page a row visits always
+has a live position (lengths are clamped ≥ 1, mirroring the decode path's
+position clamp), so the running max is finite at the flush. The grid stays
+(B, W): an engine that pads to ``max_batch`` rows over a bucket's whole
+table pays one scalar compare for each step it skips. Dummy rows (all-zero
+block tables, the free/prefilling-slot contract) attend one masked-harmless
+position of the sacrificial page 0, in one live step.
 
 ``interpret=`` defaults through :func:`~.pallas_kernels._interpret` —
 interpreter everywhere but real TPU — so the tier-1 CPU suites exercise
@@ -80,7 +88,11 @@ def paged_attention_cost(batch: int, table_width: int, page_len: int,
     pallas_call is opaque to XLA's analysis; interpret-mode lowerings are
     analyzed as ordinary XLA ops and don't need this). FLOPs are the two
     (group·dh × page_len) contractions per (row, page, kv-head); bytes are
-    one in-place pass over each row's table extent of the slab plus q/out."""
+    one in-place pass over each row's table extent of the slab plus q/out.
+    It prices the TABLE HANDED IN, ``batch`` x ``table_width`` pages, not
+    the pages visited: the kernel skips every page past a row's length
+    (the engine counts the visited ones as ``kv_pages`` on its
+    ``serve.decode.dispatch`` span)."""
     t = batch * table_width * kv_heads
     flops = 2.0 * 2.0 * t * group * dh * page_len
     kv_bytes = 2.0 * t * page_len * dh * itemsize
@@ -97,8 +109,10 @@ def _paged_attn_kernel(*refs, page_len: int, windowed: bool = False):
     ``b`` is the page of positions ``(first_page[b] + w) * page_len ...``
     (its block picked by :func:`_paged_decode_attention_window_call`'s index
     map) and positions below ``lower[b]`` are masked like those at or past
-    the length. The first page visited holds ``lower[b]``, a live position,
-    so the running max is finite before any wholly masked page."""
+    the length. A step whose page starts at or past ``lengths[b]`` skips the
+    body; the first page visited holds a live position (position 0, or
+    ``lower[b]``), so the running max is finite and ``l`` positive at the
+    flush."""
     if windowed:
         (tables_ref, lengths_ref, first_ref, lower_ref, q_ref, k_ref, v_ref,
          o_ref, acc_ref, m_ref, l_ref) = refs
@@ -114,33 +128,40 @@ def _paged_attn_kernel(*refs, page_len: int, windowed: bool = False):
         m_ref[:] = jnp.full_like(m_ref, _MASKED)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0]  # (kvh, group, dh) — compute dtype
-    k = k_ref[0]  # (page_len, kvh, dh)
-    v = v_ref[0]
-    dh = q.shape[-1]
-    # the _decode_step score einsum, f32 scores, same 1/sqrt(dh) scaling
-    s = jnp.einsum("kgd,tkd->kgt", q, k,
-                   preferred_element_type=jnp.float32) / math.sqrt(dh)
-    # absolute position of column t is w*page_len + t; live iff < length
-    t = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-    if windowed:
-        at = (first_ref[b] + w) * page_len + t
-        s = jnp.where((at >= lower_ref[b]) & (at < lengths_ref[b]), s,
-                      _MASKED)
-    else:
-        s = jnp.where(w * page_len + t < lengths_ref[b], s, _MASKED)
-    # online-softmax update: new running max, rescale the old accumulator
-    m_prev = m_ref[:]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=2))
-    m_ref[:] = m_new
-    alpha = jnp.exp(m_prev - m_new)  # 0.0 on the w==0 init (m_prev=-1e30)
-    p = jnp.exp(s - m_new[:, :, None])  # masked cols underflow to exact 0
-    l_ref[:] = alpha * l_ref[:] + jnp.sum(p, axis=2)
-    # probabilities meet V in the compute dtype — q's dtype, the same cast
-    # _decode_step applies (p.astype(cd)); the accumulator stays f32
-    pv = jnp.einsum("kgt,tkd->kgd", p.astype(q.dtype), v,
-                    preferred_element_type=jnp.float32)
-    acc_ref[:] = acc_ref[:] * alpha[:, :, None] + pv
+    # a page wholly past the row's length holds nothing the row attends:
+    # the step does no compute (and, its block index clamped to the row's
+    # last live page by the index map, starts no copy). Skipping it leaves
+    # m, l and acc exactly as the masked formulation did (alpha = 1, p = 0).
+    page = first_ref[b] + w if windowed else w
+
+    @pl.when(page * page_len < lengths_ref[b])
+    def _live_page():
+        q = q_ref[0]  # (kvh, group, dh) — compute dtype
+        k = k_ref[0]  # (page_len, kvh, dh)
+        v = v_ref[0]
+        dh = q.shape[-1]
+        # the _decode_step score einsum, f32 scores, same 1/sqrt(dh) scaling
+        s = jnp.einsum("kgd,tkd->kgt", q, k,
+                       preferred_element_type=jnp.float32) / math.sqrt(dh)
+        # absolute position of column t is page*page_len + t; live iff
+        # < length (and, for a window, >= lower)
+        at = page * page_len + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        keep = at < lengths_ref[b]
+        if windowed:
+            keep &= at >= lower_ref[b]
+        s = jnp.where(keep, s, _MASKED)
+        # online-softmax update: new running max, rescale the old accumulator
+        m_prev = m_ref[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2))
+        m_ref[:] = m_new
+        alpha = jnp.exp(m_prev - m_new)  # 0.0 on the w==0 init (m_prev=-1e30)
+        p = jnp.exp(s - m_new[:, :, None])  # masked cols underflow to exact 0
+        l_ref[:] = alpha * l_ref[:] + jnp.sum(p, axis=2)
+        # probabilities meet V in the compute dtype — q's dtype, the same cast
+        # _decode_step applies (p.astype(cd)); the accumulator stays f32
+        pv = jnp.einsum("kgt,tkd->kgd", p.astype(q.dtype), v,
+                        preferred_element_type=jnp.float32)
+        acc_ref[:] = acc_ref[:] * alpha[:, :, None] + pv
 
     @pl.when(w == pl.num_programs(1) - 1)
     def _flush():
@@ -155,9 +176,13 @@ def _paged_decode_attention_call(q, k_pages, v_pages, tables, lengths,
     kernel = functools.partial(_paged_attn_kernel, page_len=page_len)
     row_spec = pl.BlockSpec((1, kvh, group, dh),
                             lambda b, w, tbl, lens: (b, 0, 0, 0))
-    # THE in-place read: the block table entry is the K/V block index
-    page_spec = pl.BlockSpec((1, page_len, kvh, dh),
-                             lambda b, w, tbl, lens: (tbl[b, w], 0, 0, 0))
+    # THE in-place read: the block table entry is the K/V block index. A
+    # step past the row's last live page names that page again: the block
+    # index does not change, so the pipeline copies nothing for it
+    page_spec = pl.BlockSpec(
+        (1, page_len, kvh, dh),
+        lambda b, w, tbl, lens: (
+            tbl[b, jnp.minimum(w, (lens[b] - 1) // page_len)], 0, 0, 0))
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -195,7 +220,9 @@ def _paged_decode_attention_window_call(q, k_pages, v_pages, tables, lengths,
     page_spec = pl.BlockSpec(
         (1, page_len, kvh, dh),
         lambda b, w, tbl, lens, first, low: (
-            tbl[b, jax.lax.rem(first[b] + w, W)], 0, 0, 0))
+            tbl[b, jax.lax.rem(jnp.minimum(first[b] + w,
+                                           (lens[b] - 1) // page_len), W)],
+            0, 0, 0))
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -232,12 +259,16 @@ def paged_decode_attention(q, k_pages, v_pages, tables, lengths,
     The row's pages are read IN PLACE through the block table (no gathered
     context array); masking, GQA mapping, and softmax numerics follow
     :func:`~marlin_tpu.models.transformer._decode_step` (module docstring).
+    Only the pages that hold positions below ``lengths[b]`` are read and
+    computed on; the grid steps of the others do nothing, and what those
+    pages (or table entries) hold cannot reach the output.
 
     With ``first_page`` and ``lower`` (both (B,)) the row attends a window:
     only positions in ``[lower[b], lengths[b])``, and ``tables`` is the
     row's ring of W pages (position ``p`` in slot ``(p // page_len) % W``),
-    visited from page ``first_page[b]`` (= ``lower[b] // page_len``) on, so
-    W pages are read whatever the row's length.
+    visited from page ``first_page[b]`` (= ``lower[b] // page_len``) on, up
+    to the page of position ``lengths[b] - 1``: a ring slot past it (stale,
+    or not yet written) is skipped like a page past the length.
     """
     q = jnp.asarray(q)
     if q.ndim != 4:

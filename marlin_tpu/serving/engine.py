@@ -2503,9 +2503,11 @@ class ServeEngine:
         gets the work the call was given beside the work that is useful:
         ``padded_rows`` x ``table_width`` pages against ``kv_tokens``, the
         positions the live rows attend (each row's cache plus the entry
-        this step writes); and ``sampled_rows``, the live rows with a
-        temperature above 0 — at 0 the program took its argmax branch and
-        never sorted the vocabulary."""
+        this step writes) and ``kv_pages``, the pages that hold them (the
+        grid steps of the decode kernel that compute; a spec's dispatch
+        has them per attention kind); and ``sampled_rows``, the live rows
+        with a temperature above 0 — at 0 the program took its argmax
+        branch and never sorted the vocabulary."""
         from ..models.transformer import lm_decode_paged
 
         now = self._clock()
@@ -2549,10 +2551,16 @@ class ServeEngine:
             tables, ring = tables
             span.set_metadata(global_table_width=tables.shape[1],
                               window_table_width=ring.shape[1])
-            if span.is_enabled():
-                # the pages that hold what each kind of layer attends this
-                # step: every position so far; the window's
-                at = group.positions[live] // self._page_len
+        if span.is_enabled():
+            # the pages that hold what the live rows attend this step (the
+            # decode kernel's grid steps that compute, of the padded_rows x
+            # table width it is given): every position so far; for a
+            # spec's sliding layers, the window's
+            at = group.positions[live] // self._page_len
+            if self._spec is None:
+                # analyze: ignore[host-sync] — host numpy bookkeeping
+                span.set_metadata(kv_pages=int((at + 1).sum()))
+            else:
                 low = (np.maximum(group.positions[live]
                                   - self._spec.window + 1, 0)
                        // self._page_len)

@@ -661,6 +661,31 @@ def test_rmm_20000_compiles_for_2x2_v5e(topo_mesh):
     assert c.memory_analysis().peak_memory_in_bytes < 15 * 1024**3
 
 
+def _compile_laguna_decode_kernel(one_chip, group, page_len, pages, width):
+    """Laguna-S-2.1's decode kernel through real Mosaic: 32 rows, 8 KV heads
+    x 128, bf16, a slab of ``pages`` pages and a table ``width`` wide; query
+    group 6 is a full layer (the plain call), 9 a sliding layer (the window
+    call, ``width`` its ring)."""
+    from marlin_tpu.ops.paged_attention import (
+        _paged_decode_attention_call, _paged_decode_attention_window_call)
+
+    def st(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    B, kvh, dh = 32, 8, 128
+    q = st((B, kvh, group, dh), jnp.bfloat16)
+    slab = st((pages, page_len, kvh, dh), jnp.bfloat16)
+    if group == 6:
+        c = _paged_decode_attention_call.trace(
+            q, slab, slab, st((B, width)), st((B,)), page_len=page_len,
+            interpret=False).lower().compile()
+    else:
+        c = _paged_decode_attention_window_call.trace(
+            q, slab, slab, st((B, width)), st((B,)), st((B,)), st((B,)),
+            page_len=page_len, interpret=False).lower().compile()
+    assert "tpu_custom_call" in c.as_text()
+
+
 @pytest.mark.parametrize("group", [6, 9], ids=["full-g6", "sliding-g9"])
 def test_paged_window_kernel_mosaic_compiles_at_laguna_widths(one_chip,
                                                               group):
@@ -668,25 +693,20 @@ def test_paged_window_kernel_mosaic_compiles_at_laguna_widths(one_chip,
     heads x 128, page_len 128, 32 rows; query groups 6 (full layers, the
     plain call over 64 pages) and 9 (sliding layers, the window call over a
     ring of 5 pages)."""
-    from marlin_tpu.ops.paged_attention import (
-        _paged_decode_attention_call, _paged_decode_attention_window_call)
+    pages, width = (1537, 64) if group == 6 else (201, 5)
+    _compile_laguna_decode_kernel(one_chip, group, 128, pages, width)
 
-    def st(shape, dt=jnp.int32):
-        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
-    B, kvh, dh, pl = 32, 8, 128, 128
-    q = st((B, kvh, group, dh), jnp.bfloat16)
-    if group == 6:
-        slab = st((1537, pl, kvh, dh), jnp.bfloat16)
-        c = _paged_decode_attention_call.trace(
-            q, slab, slab, st((B, 64)), st((B,)), page_len=pl,
-            interpret=False).lower().compile()
-    else:
-        slab = st((201, pl, kvh, dh), jnp.bfloat16)
-        c = _paged_decode_attention_window_call.trace(
-            q, slab, slab, st((B, 5)), st((B,)), st((B,)), st((B,)),
-            page_len=pl, interpret=False).lower().compile()
-    assert "tpu_custom_call" in c.as_text()
+@pytest.mark.parametrize("group", [6, 9], ids=["full-g6", "sliding-g9"])
+def test_paged_kernels_mosaic_compile_at_the_laguna_cells_shapes(one_chip,
+                                                                 group):
+    """Both variants as ``serve.laguna-longtail32`` runs them (page_len
+    256): the plain call over the widest bucket's 32 pages of the 769-page
+    slab, the window call over a ring of 4 pages of the 145-page slab. Their
+    index maps clamp a scalar-prefetched length, which is what Mosaic has to
+    accept."""
+    pages, width = (769, 32) if group == 6 else (145, 4)
+    _compile_laguna_decode_kernel(one_chip, group, 256, pages, width)
 
 
 def test_dropless_expert_layer_compiles_for_v5e_as_a_grouped_matmul(one_chip):

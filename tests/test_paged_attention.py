@@ -163,6 +163,103 @@ def test_cost_model_shape():
     assert c1["flops"] > 0 and c1["bytes accessed"] > 0
 
 
+# ------------------------------------------------ pages no row holds
+
+
+def _ref_window_attention(q, k_pages, v_pages, tables, lengths, lower):
+    """The window's obvious formulation: row ``b`` attends positions
+    ``[lower[b], lengths[b])``, position ``p`` read from ring slot
+    ``(p // page_len) % W``, one position at a time."""
+    q = np.asarray(q, np.float32)
+    kp = np.asarray(k_pages, np.float32)
+    vp = np.asarray(v_pages, np.float32)
+    W = tables.shape[1]
+    out = np.zeros_like(q)
+    for b in range(q.shape[0]):
+        at = np.arange(lower[b], lengths[b])
+        pids = tables[b, (at // PAGE_LEN) % W]
+        k, v = kp[pids, at % PAGE_LEN], vp[pids, at % PAGE_LEN]
+        s = np.einsum("kgd,tkd->kgt", q[b], k) / np.sqrt(q.shape[-1])
+        p = np.exp(s - s.max(axis=2, keepdims=True))
+        out[b] = np.einsum("kgt,tkd->kgd", p / p.sum(axis=2, keepdims=True), v)
+    return out
+
+
+_WINDOW = 2 * PAGE_LEN  # over a ring of W = 3 pages, as the engine sizes it
+_FULL = 3 * PAGE_LEN    # _random_case's table extent
+
+# last attended position of each of the four rows
+_DEAD_PAGE_CASES = {
+    "global": {
+        "one": [0, 0, 0, 0],
+        "page": [PAGE_LEN - 1] * 4,
+        "page+1": [PAGE_LEN] * 4,
+        "ragged": [0, 8, 16, 23],
+        "full-table": [_FULL - 1] * 4,
+    },
+    "window": {
+        "one": [0, 0, 0, 0],
+        "page": [PAGE_LEN - 1] * 4,
+        "page+1": [PAGE_LEN] * 4,
+        "ragged": [3, 12, 20, 29],
+        "full-ring": [_WINDOW + 6, _WINDOW + 14, 2 * _WINDOW + 6, 30],
+        # lower = pos - window + 1 lands on a page's first position
+        "lower-on-boundary": [_WINDOW - 1, _WINDOW + 7, _WINDOW + 15, 39],
+        # the live pages straddle the ring's end: slots 2, 0 / 1, 2, 0 / ...
+        "wrapped-ring": [26, 33, 40, 47],
+    },
+}
+
+
+@pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize(
+    "variant,case",
+    [(v, c) for v, cases in _DEAD_PAGE_CASES.items() for c in cases])
+def test_pages_past_a_rows_length_are_never_read(variant, case, dtype):
+    """Both variants, on lengths around every page edge: (a) the kernel
+    matches the obvious formulation, and (b) its output does not depend on
+    what a page wholly past a row's length (or the pool's dummy page 0)
+    holds — bit-equal with those pages full of NaN and of Inf. A kernel that
+    only masks such a page computes ``0 x Inf`` on it and fails (b)."""
+    rng = np.random.default_rng(7)
+    q, kp, vp, tables = _random_case(rng)
+    B, W = tables.shape
+    pos = np.array(_DEAD_PAGE_CASES[variant][case], np.int32)
+    lengths = pos + 1
+    window_size = _WINDOW if variant == "window" else _FULL
+    lower = np.maximum(pos - window_size + 1, 0).astype(np.int32)
+    first = lower // PAGE_LEN  # all zero for the global variant
+    window = (dict(first_page=first, lower=lower) if variant == "window"
+              else {})
+    want = _ref_window_attention(q, kp, vp, tables, lengths, lower)
+    # the slots of each row's table that hold no attended position: a row
+    # visits pages first .. first + W - 1, in slot page % W
+    visited = first[:, None] + np.arange(W)[None, :]
+    dead = visited * PAGE_LEN >= lengths[:, None]
+    dead_slots = np.zeros_like(dead)
+    np.put_along_axis(dead_slots, visited % W, dead, axis=1)
+    dead_pages = tables[dead_slots]
+    # even rows name the dummy page there, as the engine's tables do; odd
+    # rows keep a page of their own (a ring's stale slot)
+    tables = np.where(dead_slots & (np.arange(B) % 2 == 0)[:, None], 0,
+                      tables).astype(np.int32)
+    cast = lambda a: jnp.asarray(a, dtype)
+    got = paged_decode_attention(cast(q), cast(kp), cast(vp), tables, lengths,
+                                 interpret=True, **window)
+    tol = 2e-6 if dtype is np.float32 else 0.05
+    np.testing.assert_allclose(np.asarray(got, np.float32), want, atol=tol,
+                               rtol=tol)
+    for poison in (np.nan, np.inf):
+        kx, vx = kp.copy(), vp.copy()
+        kx[0] = vx[0] = poison
+        kx[dead_pages] = vx[dead_pages] = poison
+        again = paged_decode_attention(cast(q), cast(kx), cast(vx), tables,
+                                       lengths, interpret=True, **window)
+        np.testing.assert_array_equal(np.asarray(again, np.float32),
+                                      np.asarray(got, np.float32))
+
+
 # ------------------------------------------- backend bit-identity grid
 
 

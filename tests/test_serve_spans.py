@@ -180,6 +180,9 @@ def test_dispatched_decode_spans_carry_the_work_they_were_given(traced):
         assert 0 < s.fields["kv_tokens"] <= (s.fields["rows"]
                                              * s.fields["table_width"]
                                              * PAGE_LEN)
+        # the pages that hold them: at least one a row, none past the table
+        assert (-(-s.fields["kv_tokens"] // PAGE_LEN) <= s.fields["kv_pages"]
+                <= s.fields["rows"] * s.fields["table_width"])
 
 
 def test_sampled_rows_counts_live_sampled_rows_only(params, tmp_path,
@@ -213,6 +216,32 @@ def test_sampled_rows_counts_live_sampled_rows_only(params, tmp_path,
     handed = handed[-len(calls):]  # warm-up's calls came first
     assert [int((t > 0).sum()) for t in handed] == [n for _, n in calls]
     assert all(t.dtype == np.float32 and t.shape == (4,) for t in handed)
+
+
+def test_kv_pages_is_the_pages_the_live_rows_positions_fill(params, tmp_path,
+                                                            monkeypatch):
+    """``kv_pages`` of every dense dispatch against what the program was
+    handed: ``positions // page_len + 1`` summed over the rows whose table
+    names a page (a free or prefilling slot's is all dummy)."""
+    from marlin_tpu.models import transformer
+
+    handed = []
+    decode = transformer.lm_decode_paged
+
+    def spy(*args, **kw):
+        tables, positions = np.asarray(args[2]), np.asarray(args[3])
+        live = tables[:, 0] != 0
+        handed.append((int(live.sum()),
+                       int((positions[live] // PAGE_LEN + 1).sum())))
+        return decode(*args, **kw)
+
+    monkeypatch.setattr(transformer, "lm_decode_paged", spy)
+    got = _serve(params, capture_dir=tmp_path)
+    calls = [(s.fields["rows"], s.fields["kv_pages"]) for s in got["spans"]
+             if s.name == "serve.decode.dispatch" and s.fields["rows"]]
+    assert len(calls) > len(SCHEDULE)
+    assert calls == handed[-len(calls):]  # warm-up's calls came first
+    assert any(pages > rows for rows, pages in calls)  # rows past one page
 
 
 def test_spans_nest_as_the_table_says(traced):
@@ -291,6 +320,11 @@ def test_row_pages_and_kv_tokens_match_the_pool_by_hand(params, tmp_path):
     # a call attends each live row's cache plus the entry it writes
     assert [c["kv_tokens"] for c in calls[:2]] == [5 + 7 + 2, 6 + 8 + 2]
     assert [c["rows"] for c in calls[:2]] == [2, 2]
+    # ... held in positions // page_len + 1 pages a row (positions 5 and 7,
+    # then 6 and 8): the decode kernel's grid steps that compute
+    assert [c["kv_pages"] for c in calls[:2]] == [
+        5 // PAGE_LEN + 1 + 7 // PAGE_LEN + 1,
+        6 // PAGE_LEN + 1 + 8 // PAGE_LEN + 1]
 
 
 def test_a_capture_begun_while_the_worker_waits_holds_the_iteration_whole(

@@ -728,14 +728,10 @@ def config_serve(d_model=128, heads=8, layers=4, vocab=256):
     rows retire at their requested steps),
     MARLIN_BENCH_SERVE_WARMUP=0 skips the per-bucket pre-compile (the
     first-request-pays-the-compile A/B),
-    MARLIN_BENCH_SERVE_PAGED=0 is the dense-slab control for the paged
-    KV-pool A/B (records get a `_slab` suffix; docs/performance.md records
-    the pair),
     MARLIN_BENCH_SERVE_PREFIX_LEN=N (0 = off, the default) prepends a
     shared N-token system prompt to every request — the prefix-cache
     workload (records get a `_prefix` suffix; the acceptance bar is
-    prefix-cache hits > 0 and TTFT p99 down vs the `_prefix_slab` control,
-    ISSUE 8); the per-rate detail carries the hit counts,
+    prefix-cache hits > 0); the per-rate detail carries the hit counts,
     MARLIN_BENCH_SERVE_ROUTER=N (0 = off, the default) serves each rate
     through a Router over N supervised engine replicas instead of one bare
     engine — the resilience-layer A/B (records get a `_router` suffix;
@@ -751,8 +747,7 @@ def config_serve(d_model=128, heads=8, layers=4, vocab=256):
     regressions that slid with it to warnings. The model
     (d_model=128, heads=8, layers=4) is sized so decode COMPUTE is
     non-trivial relative to dispatch — the serving regime; at toy sizes the
-    sweep measures Python/dispatch overhead, which flatters whichever
-    backend does the least host-side bookkeeping.
+    sweep measures Python/dispatch overhead.
 
     Observability ride-along (docs/observability.md): a /metrics endpoint
     (MARLIN_BENCH_OBS_PORT, default ephemeral) is scraped DURING the first
@@ -776,7 +771,6 @@ def config_serve(d_model=128, heads=8, layers=4, vocab=256):
     n_req = int(os.environ.get("MARLIN_BENCH_SERVE_N", 64))
     max_batch = int(os.environ.get("MARLIN_BENCH_SERVE_BATCH", 8))
     warmup = os.environ.get("MARLIN_BENCH_SERVE_WARMUP", "1") != "0"
-    paged = os.environ.get("MARLIN_BENCH_SERVE_PAGED", "1") != "0"
     # decode-kernel A/B control: "" = the config default ('auto'),
     # "gather"/"pallas" force a backend and tag every record key with _k…
     # so both legs coexist in BENCH_ALL.json
@@ -791,7 +785,6 @@ def config_serve(d_model=128, heads=8, layers=4, vocab=256):
         prefix_len = 240
     router_n = int(os.environ.get("MARLIN_BENCH_SERVE_ROUTER", "0"))
     suffix = (("_prefix" if prefix_len else "")
-              + ("" if paged else "_slab")
               + ("_router" if router_n else "")
               + (f"_k{decode_kernel}" if decode_kernel else ""))
     steps_lo, steps_hi = (int(v) for v in os.environ.get(
@@ -821,7 +814,7 @@ def config_serve(d_model=128, heads=8, layers=4, vocab=256):
     def make_engine():
         return ServeEngine(params, heads, buckets=buckets,
                            max_batch=max_batch, max_wait_ms=5.0,
-                           queue_depth=4 * n_req, paged=paged,
+                           queue_depth=4 * n_req,
                            decode_kernel=decode_kernel or None)
 
     def run_rate(rate):
@@ -898,14 +891,12 @@ def config_serve(d_model=128, heads=8, layers=4, vocab=256):
         # faults) is a degraded data point, not a sweep abort
         ms = lambda xs, q: (  # noqa: E731
             f"{percentile(xs, q) * 1e3:.0f}" if xs else "n/a")
-        sched = (f"paged, {snap['steps']} decode steps"
-                 if paged else f"dense slab, {snap['steps']} decode steps")
-        if paged:
-            hits, misses = snap.get("prefix_hits", 0), \
-                snap.get("prefix_misses", 0)
-            sched += (f", prefix-cache {hits} hit / {misses} miss, "
-                      f"cache-resident pages {snap.get('pages_used', 0)}"
-                      f"/{snap.get('pages_total', 0)}")
+        hits, misses = snap.get("prefix_hits", 0), \
+            snap.get("prefix_misses", 0)
+        sched = (f"paged, {snap['steps']} decode steps, "
+                 f"prefix-cache {hits} hit / {misses} miss, "
+                 f"cache-resident pages {snap.get('pages_used', 0)}"
+                 f"/{snap.get('pages_total', 0)}")
         extra = None
         if router_n:
             # the router observability satellite (ISSUE 12): the merged
@@ -955,9 +946,8 @@ def config_serve(d_model=128, heads=8, layers=4, vocab=256):
             val, detail, extra = runs[len(runs) // 2]
             if bench_reps > 1:
                 detail += f"; median of {bench_reps} reps"
-            # the slab/prefix/router controls keep their own record keys so
-            # the A/B tuple coexists in BENCH_ALL.json (merge keyed by
-            # config)
+            # the prefix/router/kernel legs keep their own record keys so
+            # they coexist in BENCH_ALL.json (merge keyed by config)
             record(f"serve_load{rate:g}" + suffix, val, "tok/s", detail,
                    extra=extra)
         ctrl = sorted(run_control() for _ in range(bench_reps))
@@ -974,9 +964,7 @@ def config_serve(d_model=128, heads=8, layers=4, vocab=256):
         # from the engines' live decode steps across all rates.
         from marlin_tpu.obs import perf as obs_perf
 
-        # the slab control runs lm_decode_rows; the paged default decodes
-        # through the block-table gather program
-        decode_prog = "lm_decode_paged" if paged else "lm_decode_rows"
+        decode_prog = "lm_decode_paged"
         decode_rows = [r for r in obs_perf.get_program_costs().rows()
                        if r["program"] == decode_prog and r["calls"]
                        and r["roofline_frac"] is not None]
@@ -1011,11 +999,9 @@ def config_serve(d_model=128, heads=8, layers=4, vocab=256):
             # the same scrape: TYPE lines render even before any backend
             # sample lands, so the check holds on CPU too
             "marlin_mem_registered_bytes", "marlin_mem_live_bytes",
-            "marlin_mem_unattributed_bytes")
-    if paged:
-        # the paging families ride only when the paged pool served
-        want += ("marlin_serve_kv_pages_total", "marlin_serve_kv_pages_used",
-                 "marlin_serve_prefix_cache_total")
+            "marlin_mem_unattributed_bytes",
+            "marlin_serve_kv_pages_total", "marlin_serve_kv_pages_used",
+            "marlin_serve_prefix_cache_total")
     if router_n:
         # the resilience families ride only when the router/supervisors ran
         want += ("marlin_serve_retries_total", "marlin_serve_restarts_total",

@@ -282,8 +282,8 @@ def phase_lm_serve(lm, params, on_tpu):
 
     eng = ServeEngine(params, lm.heads)  # every knob at its default
     want_kernel = "pallas" if on_tpu else "gather"
-    check(eng.paged and eng._decode_kernel == want_kernel,
-          f"engine resolved paged={eng.paged} kernel={eng._decode_kernel}")
+    check(eng._decode_kernel == want_kernel,
+          f"engine resolved kernel={eng._decode_kernel}")
     sup = Supervisor(eng)
     reqs, repeats = serve_requests(lm)
     handles = [None] * len(reqs)

@@ -125,23 +125,21 @@ class MarlinConfig:
     # k rounds UP to the smallest fitting depth (results slice back down).
     # Depths beyond the resident model's item/node count are dropped.
     serve_program_topk: tuple = (8,)
-    # Paged KV cache (default): the engine owns ONE device-resident page
-    # slab (serve_num_pages x serve_page_len KV rows per layer) shared by
-    # every bucket, rows hold block tables of pages, admission charges the
+    # The paged KV cache: the engine owns ONE device-resident page slab
+    # (serve_num_pages x serve_page_len KV rows per layer) shared by every
+    # bucket, rows hold block tables of pages, admission charges the
     # request's ACTUAL pages (models/planner.request_pages) instead of the
     # bucket worst case, full prompt pages are prefix-shared copy-on-write
     # across requests, and long prompts prefill in serve_prefill_chunk-token
-    # chunks interleaved with decode steps. False = the dense per-slot slab
-    # scheduler (the PR 4 control; docs/serving.md compares them).
-    serve_paged: bool = True
+    # chunks interleaved with decode steps.
     # Tokens per KV page. Keep it a multiple of 8 (sublane-aligned pages —
     # the decode gather stays on the fast path); larger pages cut block-
     # table overhead but waste more of the last page per request and share
     # prefixes at coarser granularity.
     serve_page_len: int = 16
     # Total pages in the pool (page 0 is a sacrificial dummy). 0 = auto:
-    # enough for every bucket's slab extent at full width plus slack — the
-    # dense-slab steady state, so paged-vs-slab A/Bs hold capacity equal.
+    # enough for every bucket's full extent at full width plus slack, so a
+    # full slot set always fits (kvpool.auto_num_pages).
     serve_num_pages: int = 0
     # Prefill at most this many prompt tokens per worker iteration (rounded
     # up to a whole number of pages); decode steps interleave between

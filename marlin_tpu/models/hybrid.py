@@ -28,7 +28,7 @@ between the two paged programs is only how a query meets the cache, handed in
 as ``attend``. Only the paged serving path runs such a model
 (``ServeEngine(params, spec)`` -> ``lm_prefill_paged`` / ``lm_decode_paged``,
 which hand a spec to :func:`prefill_paged` / :func:`decode_paged` here):
-``lm_generate``, the slab programs and the trainer raise for a spec
+``lm_generate`` and the trainer raise for a spec
 (:func:`require_int_heads`).
 
 Two classes of KV page (``serving/kvpool.py``): a full layer's slab is

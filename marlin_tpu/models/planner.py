@@ -94,8 +94,8 @@ def kv_page_bytes(params: dict, heads, page_len: int,
     """Bytes of ONE KV page across every layer: layers x {k,v} x page_len x
     kv_heads x dh in the compute dtype. The paged serving engine's admission
     unit — a request is charged :func:`request_pages` x this, the *actual*
-    memory its cache rows can ever pin, instead of the dense-slab era's
-    bucket worst case (docs/serving.md). For a
+    memory its cache rows can ever pin, not the bucket's worst case
+    (docs/serving.md). For a
     :class:`~marlin_tpu.models.hybrid.ModelSpec` a page id names a page in
     the layers of one ``kind`` only (``full``: the global class;
     ``sliding``: the window class), so each class has its own page bytes."""

@@ -28,7 +28,6 @@ from .hybrid import ModelSpec, require_int_heads
 
 __all__ = ["TransformerLM", "init_transformer", "transformer_forward",
            "lm_loss", "lm_train_step", "lm_generate", "lm_generate_batch",
-           "init_kv_slab", "lm_prefill_slot", "lm_decode_rows",
            "init_kv_pages", "lm_prefill_paged", "lm_decode_paged",
            "kv_page_copy", "synthetic_stream"]
 
@@ -715,35 +714,16 @@ def _lm_generate_batch_jit(params, prompts, lengths, key, heads: int,
 
 
 # --------------------------------------------------------------------------
-# Row-level serving, dense-slab backend: a persistent slot-resident KV slab
-# + two small programs (slot-targeted prefill, batched single-token decode)
-# that the serving engine's step scheduler composes. Unlike the fused
+# Row-level sampling for the serving programs below. Unlike the fused
 # lm_generate_batch (one program runs a batch to completion — the
-# batch-of-prompts eval shape), the slab lives on device ACROSS steps —
-# rows enter via prefill into a free slot and leave individually, so batch
-# composition can change every step. Greedy decode is composition-
-# independent (each vmapped row is the same math as lm_generate's), which
-# is what makes per-row results bit-identical to lm_generate on the same
-# prompt; sampled rows draw a per-row stream fold_in(key(seed), step) that
-# is ALSO composition-independent, so a sampled output replays from
-# (seed, prompt) alone. The paged backend below shares both guarantees.
-
-
-def init_kv_slab(params, rows: int, max_len: int, heads: int,
-                 compute_dtype: str | None = None):
-    """Zeroed persistent KV pool: layer -> (k, v), each (rows, max_len,
-    kv_heads, dh) in the compute dtype — one slot per row, sized for one
-    bucket (max_len = P_bucket + steps_bucket). The slab is allocated once
-    per (bucket, engine) and then only ever updated in place through the
-    donated prefill/decode programs below."""
-    require_int_heads(heads, "the slab programs (init_kv_slab)")
-    d = params["emb"].shape[1]
-    dh = d // heads
-    kvh = params["l0"]["wk"].shape[1] // dh  # kv_heads <= heads under GQA
-    dt = jnp.dtype(compute_dtype) if compute_dtype else params["emb"].dtype
-    return {f"l{i}": tuple(jnp.zeros((rows, max_len, kvh, dh), dt)
-                           for _ in range(2))
-            for i in range(_n_layers(params))}
+# batch-of-prompts eval shape), a serving batch changes composition every
+# step: rows enter through prefill and leave individually. Greedy decode
+# is composition-independent (each row is the same math as lm_generate's),
+# which is what makes per-row results bit-identical to lm_generate on the
+# same prompt; sampled rows draw a per-row stream fold_in(key(seed), step)
+# that is ALSO composition-independent, so a sampled output replays from
+# (seed, prompt) alone. Every knob is a traced per-row value, so one
+# program serves any mix of them.
 
 
 def _pick_token_row(temperature, top_p, top_k, logits, sub):
@@ -806,111 +786,6 @@ def _pick_token_rows(temperature, top_p, top_k, logits, seeds, steps_done):
         lambda: jnp.argmax(logits, axis=-1).astype(jnp.int32))
 
 
-def lm_prefill_slot(params, caches, tokens, slot, prompt, length, heads: int,
-                    max_len: int, seed=0, temperature=0.0, top_p=None,
-                    top_k=None, compute_dtype: str | None = None,
-                    moe: tuple | None = None):
-    """Prefill one prompt into slot ``slot`` of a persistent KV slab.
-
-    ``caches``/``tokens`` are the slab state from :func:`init_kv_slab` /
-    a (rows, max_len) int32 token buffer — both are DONATED (the update is
-    in place; the caller must replace its references with the returned
-    arrays). ``prompt`` is (P,) int32 padded to the bucket width, ``length``
-    its true length; the program writes the slot's full cache row (stale
-    K/V from a previous occupant is fully overwritten), stores
-    ``prompt + first_token`` into the slot's token row, and returns
-    ``(caches, tokens, first_token)``. One compile per (P, max_len) bucket
-    shape — ``slot``/``length``/sampling knobs are all traced."""
-    require_int_heads(heads, "the slab programs (lm_prefill_slot)")
-    return _lm_prefill_slot_jit(
-        params, caches, tokens, jnp.asarray(slot, jnp.int32),
-        jnp.asarray(prompt, jnp.int32), jnp.asarray(length, jnp.int32),
-        jnp.asarray(seed, jnp.uint32),
-        jnp.asarray(temperature, jnp.float32),
-        jnp.asarray(1.0 if top_p is None else top_p, jnp.float32),
-        jnp.asarray(0 if top_k is None else top_k, jnp.int32),
-        heads=heads, max_len=max_len, compute_dtype=compute_dtype, moe=moe)
-
-
-@functools.partial(jax.jit, static_argnames=("heads", "max_len",
-                                             "compute_dtype", "moe"),
-                   donate_argnums=(1, 2))
-def _lm_prefill_slot_jit(params, caches, tokens, slot, prompt, length,
-                         seed, temperature, top_p, top_k, heads: int,
-                         max_len: int, compute_dtype, moe=None):
-    P = prompt.shape[0]
-    if P + 1 > max_len:
-        raise ValueError(f"bucket prompt width {P} leaves no room for a "
-                         f"generated token within max_len {max_len}")
-    cdtype = jnp.dtype(compute_dtype) if compute_dtype else params["emb"].dtype
-    x, row_caches = _prefill_hidden(params, prompt, heads, max_len, cdtype,
-                                    moe)
-    # causal attention: positions < length never see the pad tail, so the
-    # hidden state at length-1 equals the unpadded prompt's last position
-    logits0 = _head_logits(x[length - 1], params["emb"])
-    first = _pick_token_row(temperature, top_p, top_k, logits0,
-                            _row_key(seed, 0))
-    row_tokens = (jnp.zeros((max_len,), jnp.int32)
-                  .at[:P].set(prompt).at[length].set(first))
-    new_caches = {
-        name: tuple(jax.lax.dynamic_update_index_in_dim(slab, row, slot, 0)
-                    for slab, row in zip(caches[name], row_caches[name]))
-        for name in caches}
-    tokens = jax.lax.dynamic_update_index_in_dim(tokens, row_tokens, slot, 0)
-    return new_caches, tokens, first
-
-
-def lm_decode_rows(params, caches, tokens, positions, steps_done, seeds,
-                   temperature, top_p, top_k, heads: int, max_len: int,
-                   compute_dtype: str | None = None,
-                   moe: tuple | None = None):
-    """One decode step for EVERY slot of a persistent KV slab.
-
-    ``caches``/``tokens`` are the slab state (DONATED — replace your
-    references with the returned arrays). Per-row vectors, all (rows,):
-    ``positions`` the index of each row's last written token (free slots
-    pass 0 — they compute a masked-harmless dummy step whose outputs the
-    scheduler ignores), ``steps_done`` the emitted-token count feeding the
-    per-row sampling stream, ``seeds``/``temperature``/``top_p``/``top_k``
-    the per-row sampling knobs (0 temperature = greedy; ``top_p`` 1.0 /
-    ``top_k`` 0 = off). Writes each row's next token at ``positions + 1``
-    (the caller guarantees ``positions + 1 < max_len`` for live rows) and
-    returns ``(caches, tokens, next_tokens)``. One compile per bucket —
-    the second of the two row-level programs."""
-    require_int_heads(heads, "the slab programs (lm_decode_rows)")
-    as_i32 = lambda v: jnp.asarray(v, jnp.int32)  # noqa: E731
-    return _lm_decode_rows_jit(
-        params, caches, tokens, as_i32(positions), as_i32(steps_done),
-        jnp.asarray(seeds, jnp.uint32),
-        jnp.asarray(temperature, jnp.float32),
-        jnp.asarray(top_p, jnp.float32), as_i32(top_k),
-        heads=heads, max_len=max_len, compute_dtype=compute_dtype, moe=moe)
-
-
-@functools.partial(jax.jit, static_argnames=("heads", "max_len",
-                                             "compute_dtype", "moe"),
-                   donate_argnums=(1, 2))
-def _lm_decode_rows_jit(params, caches, tokens, positions, steps_done, seeds,
-                        temperature, top_p, top_k, heads: int, max_len: int,
-                        compute_dtype, moe=None):
-    B = tokens.shape[0]
-    rows = jnp.arange(B)
-    cdtype = jnp.dtype(compute_dtype) if compute_dtype else params["emb"].dtype
-    # clamp the write index so a free slot (positions 0) scribbles inside
-    # its own row instead of clipping out of bounds; its cache write at
-    # position 0 is equally harmless — prefill rewrites the whole cache row
-    # when the slot is next assigned
-    pos = jnp.minimum(positions, max_len - 2)
-    x = params["emb"][tokens[rows, pos]].astype(cdtype)
-    logits, caches = jax.vmap(
-        lambda xb, cb, pb: _decode_step(params, xb, cb, pb, heads, moe)
-    )(x, caches, pos)
-    nxt = _pick_token_rows(temperature, top_p, top_k, logits, seeds,
-                           steps_done)
-    tokens = tokens.at[rows, pos + 1].set(nxt)
-    return caches, tokens, nxt
-
-
 # --------------------------------------------------------------------------
 # Paged serving: the KV pool is a single device-resident page slab
 # (num_pages, page_len, kv_heads, dh) per layer shared by EVERY bucket, and
@@ -927,9 +802,9 @@ def _lm_decode_rows_jit(params, caches, tokens, positions, steps_done, seeds,
 #                     long any one iteration is away from decode.
 #   lm_decode_paged   one token for every row of a bucket: per-row block-
 #                     table gather of the paged context, the SAME
-#                     _decode_step math as the dense-slab scheduler (greedy
-#                     stays bit-identical to lm_generate), and a scatter of
-#                     the one page each row wrote.
+#                     _decode_step math as lm_generate (greedy stays
+#                     bit-identical to it), and a scatter of the one cache
+#                     entry each row wrote.
 #   kv_page_copy      dst <- src for one page across all layers — the
 #                     copy-on-write half of prefix sharing.
 #
@@ -1144,18 +1019,23 @@ def lm_decode_paged(params, pages, tables, positions, cur_tokens,
     ``pages`` is the pool slab (DONATED). ``tables`` is (B, W) int32 block
     tables — pass an all-dummy (zero) row for every slot that is free or
     still prefilling: it computes a masked-harmless step against page 0
-    whose outputs the scheduler ignores, exactly the dense-slab dummy-row
-    contract. ``cur_tokens`` is each row's last emitted token (the engine
-    keeps the token stream host-side; the result is built from it), the
-    remaining per-row vectors are as :func:`lm_decode_rows`.
+    whose outputs the scheduler ignores. ``cur_tokens`` is each row's last
+    emitted token (the engine keeps the token stream host-side; the result
+    is built from it). The remaining per-row vectors, all (B,):
+    ``positions`` the index of each row's last written token (the caller
+    guarantees ``positions + 1 < W * page_len`` for live rows),
+    ``steps_done`` the emitted-token count feeding the per-row sampling
+    stream, ``seeds``/``temperature``/``top_p``/``top_k`` the per-row
+    sampling knobs (0 temperature = greedy; ``top_p`` 1.0 / ``top_k`` 0 =
+    off; hand a row whose token is discarded temperature 0).
 
     ``kernel`` selects the attention backend (default: the config's
     ``serve_decode_kernel``, resolved via :func:`resolve_decode_kernel`):
 
     - ``'gather'`` — the reference path: each row gathers its context by
-      block table and runs the SAME :func:`_decode_step` math as the slab
-      scheduler (greedy rows stay bit-identical to :func:`lm_generate`),
-      then writes back the single cache entry it produced.
+      block table and runs the SAME :func:`_decode_step` math as
+      :func:`lm_generate` (greedy rows stay bit-identical to it), then
+      writes back the single cache entry it produced.
     - ``'pallas'`` — the fused :func:`~marlin_tpu.ops.paged_attention
       .paged_decode_attention` kernel attends over the page slab IN PLACE
       through the block table (no materialized context; requires
@@ -1274,8 +1154,8 @@ def _lm_decode_paged_jit(params, pages, tables, positions, cur_tokens,
         return new_pages, nxt
     # gather each row's context in block-table order: position t of the
     # gathered view IS absolute position t, so _decode_step's positional
-    # masking applies unchanged — the decode math is literally the slab
-    # scheduler's (bit-identity by construction, not by re-derivation)
+    # masking applies unchanged — the decode math is literally
+    # lm_generate's (bit-identity by construction, not by re-derivation)
     ctx = {name: tuple(t[tables].reshape(B, L, *t.shape[2:]) for t in kv)
            for name, kv in pages.items()}
     logits, new_ctx = jax.vmap(
@@ -1321,8 +1201,6 @@ def _kv_page_copy_jit(pages, src, dst):
 # no-recompile tests/benches read it)
 for _pub, _jit in ((lm_generate, _lm_generate_jit),
                    (lm_generate_batch, _lm_generate_batch_jit),
-                   (lm_prefill_slot, _lm_prefill_slot_jit),
-                   (lm_decode_rows, _lm_decode_rows_jit),
                    (lm_prefill_paged, _lm_prefill_paged_jit),
                    (lm_decode_paged, _lm_decode_paged_jit),
                    (kv_page_copy, _kv_page_copy_jit)):

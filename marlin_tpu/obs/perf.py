@@ -9,8 +9,8 @@ never fail the program it watches):
   registry of per-compiled-program cost models, captured from XLA's own
   accounting (``lowered.cost_analysis()`` / ``compiled.cost_analysis()`` +
   ``memory_analysis()``) at the existing compile sites (serving
-  ``warmup_buckets``/``aot_compile_buckets``, the streamed-op and matmul
-  jits, autotune candidates) and *joined* with measured wall times
+  ``warmup_paged`` and group creation, the streamed-op and matmul jits,
+  autotune candidates) and *joined* with measured wall times
   (:meth:`ProgramCosts.observe` from the serving worker, streamed ops,
   autotune timings). The join is rendered as roofline numbers — the
   fraction-of-peak reporting "Large Scale Distributed Linear Algebra With

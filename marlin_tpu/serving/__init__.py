@@ -32,13 +32,9 @@ Quick start::
 
 from .batcher import (  # noqa: F401
     BatchFormer,
-    SlotPool,
-    aot_compile_buckets,
     bucket_kv_bytes,
     normalize_buckets,
     pick_bucket,
-    planner_ratio_warning,
-    warmup_buckets,
 )
 from .engine import ServeEngine  # noqa: F401
 from .kvpool import (  # noqa: F401

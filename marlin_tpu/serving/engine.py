@@ -5,17 +5,15 @@ library's compiled decode programs: concurrent callers ``submit`` requests;
 an admission gate (queue depth + in-flight KV-cache HBM budget, request.py)
 rejects overload with a reason; a batch former (batcher.py) buckets prompts
 onto a small static shape set so compiles stay bounded; and a single worker
-thread keeps the device fed. Scheduling is row-level (the unit is the
-slot-step, not the batch — the gang scheduler of PR 3 is retired and its
-``rowlevel`` escape hatch removed). Two KV-cache backends share the
-row-level skeleton:
+thread keeps the device fed. Scheduling is row-level: the unit is the
+slot-step, not the batch.
 
-**Paged** (``serve_paged``, the default): ONE device-resident page slab
+**The KV cache is paged**: ONE device-resident page slab
 (:mod:`.kvpool` over :func:`~marlin_tpu.models.transformer.init_kv_pages`)
 shared by every bucket; each row holds a host-side block table of pages.
 Admission charges the request's ACTUAL pages
 (:func:`~marlin_tpu.models.planner.request_pages` — a short request in a
-long bucket no longer reserves the bucket's worst case), completed full
+long bucket does not reserve the bucket's worst case), completed full
 prompt pages are prefix-shared copy-on-write across requests (a common
 system prompt is prefilled once — :class:`~.kvpool.PagedKVPool`), and long
 prompts prefill in bounded ``serve_prefill_chunk``-token chunks. Every
@@ -35,19 +33,11 @@ interleave between its chunks, bounding TTFT for everyone else. ≤ 3
 compiled programs per bucket (chunked prefill + decode step, plus one
 pool-wide page-copy program), for ANY per-row mix of sampling knobs.
 
-**Dense slab** (``serve_paged=False``, the PR 4 control): each bucket owns
-a persistent ``(max_batch, max_len, kvh, dh)`` slab
-(:class:`~.batcher.SlotPool`), whole-prompt prefill on admit
-(:func:`~marlin_tpu.models.transformer.lm_prefill_slot`), decode via
-:func:`~marlin_tpu.models.transformer.lm_decode_rows` — 2 programs per
-bucket, admission charged at the bucket worst case. The paged-vs-slab A/B
-in ``bench_all.py serve`` runs this side.
-
-Both backends keep the invariants PR 3/4 established: exactly one Result
-per request, per-row greedy output bit-identical to
-:func:`~marlin_tpu.models.transformer.lm_generate` on the unpadded prompt
-(the paged decode literally reuses ``_decode_step``), and sampled rows on
-composition-independent ``fold_in(key(seed), step)`` streams.
+The invariants: exactly one Result per request, per-row greedy output
+bit-identical to :func:`~marlin_tpu.models.transformer.lm_generate` on the
+unpadded prompt (the gather decode literally reuses ``_decode_step``), and
+sampled rows on composition-independent ``fold_in(key(seed), step)``
+streams.
 
 **Pluggable programs** (serving/programs/): LM decode is one
 :class:`~.programs.BucketProgram` among several — ``ServeEngine(...,
@@ -68,17 +58,16 @@ and mid-prefill rows), and retires everything still queued with a clean
 ``shutting_down`` Result. Both are terminal and idempotent; the worker
 thread (named ``marlin-serve-*`` — the conftest leak fixture watches the
 prefix) is joined before either returns. Chaos hooks (utils/faults.py):
-``serve.enqueue`` fires in ``submit``; ``serve.step`` fires before each
-slab prefill; ``serve.prefill`` fires before each paged prefill CHUNK — a
-fault fails/retries that one request and the pool stays consistent (the
-chunk cursor makes prefill resumable, so a retry re-runs the prompt from
-its shared prefix); ``serve.decode_step`` fires before each decode step —
-a fault fails/retries only that step's live rows. The engine keeps serving
-after any of them; if a failed donated call consumed the page slab, every
-resident row fails/retries and the pool is rebuilt zeroed — the same
-contract worker-crash recovery gives it (supervisor.py: pools dropped,
-live rows requeued, page-unit admission reservations carried across
-attempts).
+``serve.enqueue`` fires in ``submit``; ``serve.prefill`` fires before each
+prefill CHUNK — a fault fails/retries that one request and the pool stays
+consistent (the chunk cursor makes prefill resumable, so a retry re-runs
+the prompt from its shared prefix); ``serve.decode_step`` fires before
+each decode step — a fault fails/retries only that step's live rows. The
+engine keeps serving after any of them; if a failed donated call consumed
+the page slab, every resident row fails/retries and the pool is rebuilt
+zeroed — the same contract worker-crash recovery gives it (supervisor.py:
+pools dropped, live rows requeued, page-unit admission reservations
+carried across attempts).
 """
 
 from __future__ import annotations
@@ -104,8 +93,7 @@ from ..obs.exposition import (register_health_provider,
 from ..obs.metrics import get_registry
 from ..utils import faults
 from ..utils.tracing import annotate
-from .batcher import (BatchFormer, bucket_program_key, capture_bucket_costs,
-                      normalize_buckets, warmup_buckets)
+from .batcher import BatchFormer, bucket_kv_bytes, normalize_buckets
 from .kvpool import (PagedGroup, PagedKVPool, PagePoolExhausted,
                      auto_num_pages, auto_window_pages,
                      capture_paged_costs, group_chunk, paged_program_key,
@@ -132,8 +120,9 @@ def _bucket_tag(bucket) -> str:
 
 
 class MigrationError(RuntimeError):
-    """A freeze/adopt handoff could not run (wrong backend or lifecycle
-    state, or the target worker did not service the request in time). The
+    """A freeze/adopt handoff could not run (a model with window layers,
+    the wrong lifecycle state, or the target worker did not service the
+    request in time). The
     router falls back to the PR 7 retry path on it."""
 
 _engine_ids = itertools.count()
@@ -203,14 +192,10 @@ class ServeEngine:
     tests; wall throughput is always measured on the real clock. ``log``
     overrides the default EventLog for ``serve`` records.
 
-    ``paged`` picks the KV backend (``serve_paged`` by default): True = the
-    paged pool (block tables over one shared page slab, prefix caching,
-    chunked prefill; ``page_len``/``num_pages``/``prefill_chunk``/
-    ``prefix_cache`` override the ``serve_*`` knobs); False = the dense
-    per-bucket slot slab (the PR 4 control). The long-deprecated
-    ``rowlevel`` kwarg is REMOVED (the gang scheduler it disabled retired
-    in PR 8) — passing it raises; use ``serve_paged``/``paged`` to pick
-    the KV backend.
+    The KV cache is the paged pool (block tables over one shared page
+    slab, prefix caching, chunked prefill); ``page_len``/``num_pages``/
+    ``prefill_chunk``/``prefix_cache``/``decode_kernel`` override the
+    ``serve_*`` knobs.
 
     ``programs`` registers additional :class:`~.programs.BucketProgram`
     instances (ALS scoring, PageRank queries, classification, ...) served
@@ -225,7 +210,6 @@ class ServeEngine:
                  queue_depth: int | None = None,
                  hbm_budget_bytes: int | None = None,
                  compute_dtype: str | None = None, moe: tuple | None = None,
-                 rowlevel: bool | None = None, paged: bool | None = None,
                  page_len: int | None = None, num_pages: int | None = None,
                  prefill_chunk: int | None = None,
                  prefix_cache: bool | None = None,
@@ -241,10 +225,6 @@ class ServeEngine:
         #: dense block that an integer head count names
         self._spec = heads if isinstance(heads, ModelSpec) else None
         if self._spec is not None:
-            if paged is False or (paged is None and not cfg.serve_paged):
-                raise ValueError("a ModelSpec is served by the paged path "
-                                 "alone: ServeEngine(params, spec) needs "
-                                 "paged=True")
             if moe is not None:
                 raise ValueError("`moe` sets the routing of the dense "
                                  "block's GShard layer; a ModelSpec carries "
@@ -253,13 +233,6 @@ class ServeEngine:
                 compute_dtype = self._spec.compute_dtype
         self.compute_dtype = compute_dtype
         self.moe = moe
-        if rowlevel is not None:
-            raise ValueError(
-                "ServeEngine(rowlevel=...) was removed: the gang scheduler "
-                "it selected retired in PR 8 and scheduling is always "
-                "row-level — use serve_paged/paged to pick the KV backend")
-        self.rowlevel = True  # legacy attribute: always row-level now
-        self.paged = bool(cfg.serve_paged if paged is None else paged)
         self.buckets = normalize_buckets(
             cfg.serve_buckets if buckets is None else buckets)
         self.max_batch = int(cfg.serve_max_batch if max_batch is None
@@ -278,7 +251,7 @@ class ServeEngine:
             else decode_kernel)
         self._page_len = int(cfg.serve_page_len if page_len is None
                              else page_len)
-        if self.paged and self._decode_kernel == "pallas":
+        if self._decode_kernel == "pallas":
             # the fused kernel streams whole pages as sublane-aligned
             # blocks; round the page size up rather than fall back
             from ..ops.paged_attention import align_page_len
@@ -301,29 +274,28 @@ class ServeEngine:
         self._ring = None if self._spec is None else 0
         self._window_pages = 0
         self._window_page_bytes = 0
-        if self.paged:
-            from ..models.planner import kv_page_bytes
+        from ..models.planner import kv_page_bytes
 
-            self._page_bytes = kv_page_bytes(params, heads, self._page_len,
-                                             compute_dtype)
-            if self._spec is not None and self._spec.has_window:
-                from ..models.hybrid import window_ring_pages
+        self._page_bytes = kv_page_bytes(params, heads, self._page_len,
+                                         compute_dtype)
+        if self._spec is not None and self._spec.has_window:
+            from ..models.hybrid import window_ring_pages
 
-                if prefix_cache:
-                    raise ValueError(
-                        "prefix_cache=True with a model that has "
-                        "sliding-window layers: sharing a window layer's "
-                        "pages is not built; leave it unset (off)")
-                self._prefix_cache = False
-                self._ring = window_ring_pages(
-                    self._spec.window,
-                    max(group_chunk(b, self._page_len, self._prefill_chunk)
-                        for b in self.buckets), self._page_len)
-                self._window_pages = int(window_pages or auto_window_pages(
-                    self.buckets, self.max_batch, self._ring))
-                self._window_page_bytes = kv_page_bytes(
-                    params, heads, self._page_len, compute_dtype,
-                    kind="sliding")
+            if prefix_cache:
+                raise ValueError(
+                    "prefix_cache=True with a model that has "
+                    "sliding-window layers: sharing a window layer's "
+                    "pages is not built; leave it unset (off)")
+            self._prefix_cache = False
+            self._ring = window_ring_pages(
+                self._spec.window,
+                max(group_chunk(b, self._page_len, self._prefill_chunk)
+                    for b in self.buckets), self._page_len)
+            self._window_pages = int(window_pages or auto_window_pages(
+                self.buckets, self.max_batch, self._ring))
+            self._window_page_bytes = kv_page_bytes(
+                params, heads, self._page_len, compute_dtype,
+                kind="sliding")
         if hbm_budget_bytes is None:
             from ..models.planner import usable_hbm_bytes
 
@@ -335,7 +307,7 @@ class ServeEngine:
         self._cond = threading.Condition()
         self._former = BatchFormer(self.buckets, self.max_batch,
                                    max_wait=float(wait_ms) / 1e3)
-        # Request.program routing table: LM (this engine's paged/slab path,
+        # Request.program routing table: LM (this engine's paged path,
         # wrapped as the first BucketProgram) plus whatever the caller
         # registered. Former/pool keys for non-LM buckets are namespaced
         # (name, *bucket) tuples — a str head can never collide with LM's
@@ -410,15 +382,15 @@ class ServeEngine:
             return eng._health_info()
 
         register_health_provider(name, _health)
-        if self.paged:
-            def _kvpool_report():
-                eng = ref()
-                if eng is None:
-                    unregister_kvpool_provider(name)
-                    return None
-                return eng.kvpool_audit()
 
-            register_kvpool_provider(name, _kvpool_report)
+        def _kvpool_report():
+            eng = ref()
+            if eng is None:
+                unregister_kvpool_provider(name)
+                return None
+            return eng.kvpool_audit()
+
+        register_kvpool_provider(name, _kvpool_report)
         # --- serving SLOs (obs/slo.py + obs/timeseries.py) -----------------
         # built only when objectives are configured (serve_slo) — otherwise
         # the hot path carries literally nothing (one None check per worker
@@ -481,8 +453,8 @@ class ServeEngine:
         the ``marlin-serve`` prefix (the conftest leak fixture and the
         flight recorder key on it) with a ``-r<gen>`` suffix."""
         name = self._name if gen == 0 else f"{self._name}-r{gen}"
-        return threading.Thread(target=self._run, args=(gen,), daemon=True,
-                                name=name)
+        return threading.Thread(target=self._run_paged, args=(gen,),
+                                daemon=True, name=name)
 
     def start(self) -> None:
         """Start the worker thread (idempotent; no-op once shutting down)."""
@@ -495,23 +467,17 @@ class ServeEngine:
     def warmup(self) -> int:
         """Compile every bucket's programs before traffic: the chunked
         prefill + decode pair per bucket plus the shared page-copy program
-        in paged mode (kvpool.warmup_paged, against THIS engine's pool —
-        program identity includes the slab shape), the slot prefill +
-        decode pair in slab mode (batcher.warmup_buckets). Call before the
-        first submit — warmup drives the live pool."""
+        (kvpool.warmup_paged, against THIS engine's pool — program identity
+        includes the slab shape). Call before the first submit — warmup
+        drives the live pool."""
         self._warming = True
         try:
-            if self.paged:
-                with self._cond:  # never race a worker's lazy pool creation
-                    pool = self._ensure_kvpool()
-                n = warmup_paged(self.params, self.heads, self.buckets,
-                                 self.max_batch, pool,
-                                 self._prefill_chunk, self.compute_dtype,
-                                 self.moe, kernel=self._decode_kernel)
-            else:
-                n = warmup_buckets(self.params, self.heads, self.buckets,
-                                   self.max_batch, self.compute_dtype,
-                                   self.moe)
+            with self._cond:  # never race a worker's lazy pool creation
+                pool = self._ensure_kvpool()
+            n = warmup_paged(self.params, self.heads, self.buckets,
+                             self.max_batch, pool, self._prefill_chunk,
+                             self.compute_dtype, self.moe,
+                             kernel=self._decode_kernel)
             for name, prog in self._programs.items():
                 if name != "lm":  # LM compiled above against the live pool
                     n += prog.warmup()
@@ -587,25 +553,20 @@ class ServeEngine:
 
     def _prog_key(self, bucket) -> str:
         """The roofline-accounting key for this engine's programs at one
-        bucket (cached — it sits on the per-step path). Paged programs key
-        the page geometry in too (kvpool.paged_program_key)."""
+        bucket (cached — it sits on the per-step path): the page geometry
+        and the decode kernel are part of it (kvpool.paged_program_key)."""
         key = self._prog_keys.get(bucket)
         if key is None:
-            if self.paged:
-                key = paged_program_key(self.params, bucket, self.max_batch,
-                                        self._page_len, self.compute_dtype,
-                                        self._decode_kernel)
-            else:
-                key = bucket_program_key(self.params, bucket, self.max_batch,
-                                         self.compute_dtype)
-            self._prog_keys[bucket] = key
+            key = self._prog_keys[bucket] = paged_program_key(
+                self.params, bucket, self.max_batch, self._page_len,
+                self.compute_dtype, self._decode_kernel)
         return key
 
     def _calibrate_cost(self, request, pbucket, cost: int) -> int:
         """Measured-peak admission calibration (obs/memledger.py): scale
         the planner's per-bucket charge by the compiler-measured
         peak/planner ratio for this bucket's program key, so admission
-        stops over-admitting by the 4-5x the slab arithmetic under-counts
+        stops over-admitting by what the KV arithmetic under-counts
         (AOT_MEMORY.json serve_buckets). LM only — one-shot programs
         price their actual padded device row; the ratio resolves once per
         bucket (live ProgramCosts first, the AOT table second, 1.0 when
@@ -614,16 +575,12 @@ class ServeEngine:
             return cost  # a spec's programs have no captured cost model
         ratio = self._calib_ratios.get(pbucket)
         if ratio is None:
-            from .batcher import bucket_kv_bytes
-
             planner = bucket_kv_bytes(self.params, self.heads, pbucket,
                                       self.compute_dtype,
                                       batch=self.max_batch)
-            programs = (("lm_prefill_paged", "lm_decode_paged")
-                        if self.paged
-                        else ("lm_prefill_slot", "lm_decode_rows"))
-            ratio = memledger.admission_ratio(planner, programs,
-                                              self._prog_key(pbucket))
+            ratio = memledger.admission_ratio(
+                planner, ("lm_prefill_paged", "lm_decode_paged"),
+                self._prog_key(pbucket))
             self._calib_ratios[pbucket] = ratio
         return int(cost * ratio) if ratio != 1.0 else cost
 
@@ -673,8 +630,7 @@ class ServeEngine:
         self._finalized = True
         self._flight_dump("close")
         try:
-            families = ["lm_decode_paged", "lm_prefill_paged",
-                        "lm_decode_rows", "lm_prefill_slot"]
+            families = ["lm_decode_paged", "lm_prefill_paged"]
             families += [p.cost_program for n, p in self._programs.items()
                          if n != "lm" and p.cost_program]
             for prog in dict.fromkeys(families):
@@ -881,8 +837,8 @@ class ServeEngine:
                         f"{request.deadline:.3f} at queue depth "
                         f"{self._queue.count} (service est "
                         f"{self._service_ewma:.3f}s)"))
-            # the program prices its own resource units (LM: actual KV pages or
-            # the slab worst case; one-shot programs: their padded device row)
+            # the program prices its own resource units (LM: actual KV pages;
+            # one-shot programs: their padded device row)
             # against the one shared HBM admission budget; a capacity refusal
             # (e.g. more pages than the pool holds) raises the reason
             try:
@@ -947,12 +903,6 @@ class ServeEngine:
         return handle
 
     # ----------------------------------------------------------- worker loop
-
-    def _run(self, gen: int = 0) -> None:
-        if self.paged:
-            self._run_paged(gen)
-        else:
-            self._run_rowlevel(gen)
 
     def _crash_handler(self, exc: BaseException, held: list,
                        gen: int) -> bool:
@@ -1052,85 +1002,6 @@ class ServeEngine:
         self.metrics.record_queue(self._queue.count,
                                   self._queue.bytes_in_flight)
 
-    # ------------------------------------------------- row-level scheduler
-
-    def _run_rowlevel(self, gen: int) -> None:
-        """The slot-step loop: each iteration refills freed slots from the
-        queue (prefill-on-admit), retires finished/expired rows, and runs
-        one decode step per bucket with live rows. ``pools`` maps bucket ->
-        SlotPool and persists across iterations — the KV slab never leaves
-        the device between steps. ``self._pools``/``self._claimed`` mirror
-        the worker's hands so a supervisor recovering a STUCK generation
-        (watchdog timeout — the thread is alive but unreachable) can still
-        find every in-flight entry to requeue."""
-        pools: dict[tuple, object] = {}
-        with self._cond:
-            if self._gen != gen:
-                return  # superseded before the first iteration: a late-
-                # starting thread must not clobber its successor's mirrors
-            self._pools = pools
-        claimed: list[_Entry] = []
-        try:
-            while True:
-                if self._gen == gen:  # a superseded straggler must never
-                    # analyze: single-writer — generation-guarded monotonic
-                    # stamp; floats assign atomically under the GIL and the
-                    # watchdog tolerates any interleaving
-                    self._heartbeat = time.monotonic()  # fake a live pulse
-                if self._slo is not None:
-                    # rate-limited internally (serve_slo_eval_interval_s):
-                    # per-iteration cost is one float compare
-                    self._slo.tick(self._clock())
-                faults.fire("serve.worker_crash",
-                            path=threading.current_thread().name)
-                claimed = []
-                with self._cond:
-                    while True:
-                        if self._gen != gen:
-                            return  # superseded by a recovery
-                        if self._state == "closing":
-                            # the live slots are the work in flight: finish
-                            # them (close() already emptied the former)
-                            if not any(p.live_slots()
-                                       for p in pools.values()):
-                                return
-                            break
-                        draining = self._state == "draining"
-                        claimed = self._claim_rowlevel(pools)
-                        if claimed or any(p.live_slots()
-                                          for p in pools.values()):
-                            break
-                        if draining:
-                            return  # nothing queued, nothing live
-                        # no max_wait ripening in row-level mode: wait for
-                        # a submit/drain/close notify (poll-capped under an
-                        # injected clock, as in the gang loop)
-                        self._idle = True
-                        self._cond.wait(None if self._real_clock
-                                        else _POLL_CAP_S)
-                        self._idle = False
-                        if self._gen == gen:
-                            self._heartbeat = time.monotonic()
-                    self._claimed = claimed
-                prog_claimed = [e for e in claimed
-                                if self._is_program_bucket(e.bucket)]
-                lm_claimed = [e for e in claimed
-                              if not self._is_program_bucket(e.bucket)]
-                self._admit_rowlevel(pools, lm_claimed)
-                self._admit_program_rows(pools, prog_claimed)
-                claimed = []
-                with self._cond:
-                    if self._gen == gen:  # never clobber a successor's
-                        self._claimed = []  # claimed mirror
-                self._step_rowlevel(pools)
-                self._step_program_rows(pools)
-        except BaseException as exc:  # worker death: recover or fail held
-            live = [p.entries[i] for p in pools.values()
-                    for i in p.live_slots()]
-            if self._crash_handler(exc, claimed + live, gen):
-                return
-            raise
-
     @staticmethod
     def _is_program_bucket(bucket) -> bool:
         """True for a namespaced (name, *bucket) program key — the one
@@ -1139,7 +1010,7 @@ class ServeEngine:
         return (isinstance(bucket, tuple) and bool(bucket)
                 and isinstance(bucket[0], str))
 
-    def _claim_rowlevel(self, pools) -> list[_Entry]:
+    def _claim(self, pools) -> list[_Entry]:
         """Claim queued entries for free slots, per bucket (called under the
         engine lock; prefill happens outside it). Program buckets claim up
         to their program's padded width instead of the LM max_batch."""
@@ -1285,176 +1156,6 @@ class ServeEngine:
                                          reason=reason)
         self._flight_dump("program-step-failed")
 
-    def _admit_rowlevel(self, pools, claimed) -> None:
-        """Prefill each claimed entry into a free slot of its bucket's pool
-        (created lazily). The first token lands here — the row's TTFT."""
-        from .batcher import SlotPool
-        from ..models.transformer import lm_prefill_slot
-
-        for e in claimed:
-            # the worker runs every request's admission inside that
-            # request's span: its prefill record — and any compile the
-            # bridge observes during it — joins the request's trace
-            with obs_trace.use(e.trace):
-                now = self._clock()
-                r = e.request
-                dl = r.deadline
-                p, s = e.bucket
-                if dl is not None and dl <= now:
-                    self._retire(e, Result(
-                        r.rid, STATUS_EXPIRED,
-                        reason=f"deadline {dl} passed before dispatch "
-                               f"(dispatched at {now})",
-                        metrics={"bucket": e.bucket,
-                                 "queue_s": now - e.enq_t,
-                                 "total_s": now - e.enq_t}))
-                    continue
-                e.queue_s = now - e.enq_t
-                try:
-                    faults.fire("serve.step", path=f"bucket-{p}x{s}")
-                    pool = pools.get(e.bucket)
-                    if pool is None:
-                        pool = pools[e.bucket] = SlotPool(
-                            self.params, self.heads, e.bucket,
-                            self.max_batch, self.compute_dtype)
-                        # no-warmup path: the bucket's cost model still
-                        # lands with its first (lazy) compile
-                        capture_bucket_costs(
-                            self.params, self.heads, e.bucket,
-                            self.max_batch, self.compute_dtype, self.moe,
-                            key=self._prog_key(e.bucket))
-                    slot = pool.free_slots()[0]
-                    prompt = np.zeros((p,), np.int32)
-                    n = r.prompt.shape[0]
-                    prompt[:n] = r.prompt
-                    t0 = time.perf_counter()
-                    caches, tokens, first = lm_prefill_slot(
-                        self.params, pool.caches, pool.tokens, slot, prompt,
-                        n, heads=self.heads, max_len=p + s, seed=r.seed,
-                        temperature=r.temperature, top_p=r.top_p,
-                        top_k=r.top_k, compute_dtype=self.compute_dtype,
-                        moe=self.moe)
-                    first = int(first)  # device sync: the first token exists
-                    wall = time.perf_counter() - t0
-                except Exception as exc:
-                    self._admit_failure(pools, e, exc)
-                    continue
-                pool.caches, pool.tokens = caches, tokens
-                pool.assign(slot, e)
-                pool.ttft_s[slot] = self._clock() - e.enq_t
-                self.metrics.record_prefill(
-                    e.bucket, wall, rid=r.rid,
-                    program_key=self._prog_key(e.bucket))
-                self.flight.record(
-                    "prefill", bucket=[p, s], slot=slot, rid=r.rid,
-                    seconds=wall, queue_depth=self._queue.count,
-                    compiles=_compile_count())
-                if r.steps == 1 or (r.eos is not None and first == r.eos):
-                    self._retire_row(pool, slot, STATUS_OK, self._clock())
-        self._live_rows = sum(len(p.live_slots()) for p in pools.values())
-
-    def _step_rowlevel(self, pools) -> None:
-        """Retire expired live rows, then run ONE decode step per bucket
-        with live rows and retire rows that finished on it. All buckets'
-        step programs are DISPATCHED before any result is awaited — JAX
-        dispatch is async, so bucket B's device work overlaps the host
-        round-trip for bucket A instead of serializing behind it."""
-        from ..models.transformer import lm_decode_rows
-
-        launched = []
-        for bucket, pool in list(pools.items()):
-            if isinstance(pool, ProgramRowSet):
-                continue  # the program lane steps in _step_program_rows
-            now = self._clock()
-            for i in pool.live_slots():
-                dl = pool.entries[i].request.deadline
-                if dl is not None and dl <= now:
-                    self._retire_row(
-                        pool, i, STATUS_EXPIRED, now,
-                        reason=f"deadline {dl} passed mid-decode "
-                               f"(now {now})")
-            live = pool.live_slots()
-            if not live:
-                continue
-            p, s = bucket
-            try:
-                faults.fire("serve.decode_step", path=f"bucket-{p}x{s}")
-                t0 = time.perf_counter()
-                caches, tokens, nxt = lm_decode_rows(
-                    self.params, pool.caches, pool.tokens, pool.positions,
-                    pool.steps_done, pool.seeds, pool.temperature,
-                    pool.top_p, pool.top_k, heads=self.heads,
-                    max_len=pool.max_len, compute_dtype=self.compute_dtype,
-                    moe=self.moe)
-            except Exception as exc:
-                self._fail_pool(pools, bucket, exc)
-                continue
-            pool.caches, pool.tokens = caches, tokens
-            launched.append((bucket, pool, live, t0, nxt))
-        synced = 0.0  # perf_counter at the previous bucket's landing
-        for bucket, pool, live, t0, nxt in launched:
-            try:
-                # analyze: ignore[host-sync] — THE one intentional sync per
-                # decode step: the host must see the emitted tokens to
-                # retire rows (all dispatches above launched async first)
-                nxt = np.asarray(nxt)  # sync; the per-row emitted tokens
-            except Exception as exc:
-                self._fail_pool(pools, bucket, exc)
-                continue
-            # this bucket's own interval (see _step_paged): the time before
-            # the previous bucket landed belongs to that bucket
-            t0, synced = max(t0, synced), time.perf_counter()
-            wall = synced - t0
-            self.metrics.record_step(bucket, len(live), self.max_batch, wall,
-                                     program_key=self._prog_key(bucket))
-            self.flight.record(
-                "step", bucket=list(bucket), rows=len(live),
-                seconds=wall, queue_depth=self._queue.count,
-                compiles=_compile_count())
-            now = self._clock()
-            host_tokens = None  # one slab fetch shared by this step's retirees
-            for i in live:
-                pool.positions[i] += 1
-                pool.steps_done[i] += 1
-                r = pool.entries[i].request
-                if ((r.eos is not None and int(nxt[i]) == r.eos)
-                        # analyze: ignore[host-sync] — host numpy bookkeeping
-                        or int(pool.steps_done[i]) >= r.steps):
-                    if host_tokens is None:
-                        # analyze: ignore[host-sync] — one slab fetch
-                        # amortized over every row this step retires
-                        host_tokens = np.asarray(pool.tokens)
-                    self._retire_row(pool, i, STATUS_OK, now,
-                                     host_tokens=host_tokens)
-        self._live_rows = sum(len(p.live_slots()) for p in pools.values())
-
-    def _retire_row(self, pool, slot: int, status: str, now: float,
-                    reason: str = "", host_tokens=None) -> None:
-        """Retire one slot's row and free the slot — the ONLY path a live
-        slot leaves the pool by, so every terminal status releases the
-        admission budget exactly once. ``host_tokens`` lets a step that
-        retires several rows share ONE slab fetch (the transfer is whole-slab
-        either way: a per-slot device gather would compile one tiny
-        executable per static slot index and break the
-        zero-compiles-under-traffic guarantee)."""
-        e = pool.entries[slot]
-        metrics = {"bucket": pool.bucket, "slot": slot,
-                   "queue_s": e.queue_s, "ttft_s": pool.ttft_s[slot],
-                   "total_s": now - e.enq_t}
-        if status == STATUS_OK:
-            n = int(pool.lengths[slot])
-            emitted = int(pool.steps_done[slot])
-            if host_tokens is None:
-                host_tokens = np.asarray(pool.tokens)
-            toks = host_tokens[slot, : n + emitted].copy()
-            result = Result(e.request.rid, STATUS_OK, tokens=toks,
-                            metrics=metrics)
-        else:
-            result = Result(e.request.rid, status, reason=reason,
-                            metrics=metrics)
-        pool.release(slot)
-        self._retire(e, result)
-
     def _requeue(self, entry: _Entry, reason: str) -> None:
         """Park a failed attempt back in the former for its next attempt
         (the caller checked ``attempts_left``). The admission reservation
@@ -1477,69 +1178,6 @@ class ServeEngine:
             self.metrics.record_retry(entry.request.rid, twin.attempt,
                                       entry.request.max_attempts, reason)
 
-    def _fail_pool(self, pools, bucket, exc: Exception) -> None:
-        """A decode step died: rows with attempt budget left requeue for a
-        transparent retry; the rest fail with error Results. Either way
-        ONLY that step's live rows are touched and the slot pool stays
-        consistent (slots freed, budget accounted exactly once). If the
-        failed call consumed the donated slab (a genuine post-dispatch
-        failure, not an injected fault raised before launch), drop the pool
-        — it is rebuilt zeroed on the next admission."""
-        pool = pools[bucket]
-        reason = f"decode step failed: {type(exc).__name__}: {exc}"
-        if memledger.is_oom_error(exc):
-            memledger.dump_oom_forensics(reason)
-        self.flight.record("decode_fault", bucket=list(bucket),
-                           rows=len(pool.live_slots()), error=reason,
-                           queue_depth=self._queue.count,
-                           compiles=_compile_count())
-        now = self._clock()
-        for i in pool.live_slots():
-            e = pool.entries[i]
-            if e.attempts_left():
-                pool.release(i)
-                self._requeue(e, reason)
-            else:
-                self._retire_row(pool, i, STATUS_ERROR, now, reason=reason)
-        if self._slab_lost(pool):
-            pools.pop(bucket)
-        # the black box lands NOW, while the final iterations are still in
-        # the ring — the post-mortem for exactly this failure class
-        self._flight_dump("decode-step-failed")
-
-    def _admit_failure(self, pools, entry: _Entry, exc: Exception) -> None:
-        """A prefill died: the entry being admitted retries within its
-        attempt budget, else gets an error Result; co-resident live rows
-        survive unless the failed call consumed the donated slab, in which
-        case they fail/retry too and the pool is dropped."""
-        now = self._clock()
-        reason = f"prefill failed: {type(exc).__name__}: {exc}"
-        if memledger.is_oom_error(exc):
-            memledger.dump_oom_forensics(reason)
-        if entry.attempts_left():
-            self._requeue(entry, reason)
-        else:
-            self._retire(entry, Result(
-                entry.request.rid, STATUS_ERROR, reason=reason,
-                metrics={"bucket": entry.bucket, "queue_s": entry.queue_s,
-                         "total_s": now - entry.enq_t}))
-        self.flight.record("prefill_fault", bucket=list(entry.bucket),
-                           rid=entry.request.rid, error=reason,
-                           queue_depth=self._queue.count,
-                           compiles=_compile_count())
-        pool = pools.get(entry.bucket)
-        if pool is not None and self._slab_lost(pool):
-            lost = f"slab lost to a failed prefill: {reason}"
-            for i in pool.live_slots():
-                e = pool.entries[i]
-                if e.attempts_left():
-                    pool.release(i)
-                    self._requeue(e, lost)
-                else:
-                    self._retire_row(pool, i, STATUS_ERROR, now, reason=lost)
-            pools.pop(entry.bucket)
-        self._flight_dump("prefill-failed")
-
     # ------------------------------------------------- supervised recovery
 
     def attach_supervisor(self, on_crash) -> None:
@@ -1556,9 +1194,9 @@ class ServeEngine:
         (``_gen`` bump — a stale thread exits at its next check and can
         never retire a superseded entry), requeue every undone in-flight
         entry within its attempt budget (the rest fail with ``error``),
-        drop the slot pools — the slab state died with the worker; pools
-        rebuild zeroed on the next admission, the PR 4 ``is_deleted``
-        pool-rebuild path generalized — and spawn a fresh worker thread.
+        drop the row groups and the page pool — their state died with the
+        worker; both rebuild zeroed on the next admission — and spawn a
+        fresh worker thread.
         Queued (former) entries are untouched: they were never in flight.
         ``respawn=False`` is the breaker's terminal path: supersede and
         fail everything held, mark the old thread abandoned (it may be
@@ -1634,14 +1272,6 @@ class ServeEngine:
             self._thread.start()
         return {"gen": gen, "requeued": len(twins), "failed": len(failed)}
 
-    @staticmethod
-    def _slab_lost(pool) -> bool:
-        """True when a failed donated call consumed the pool's arrays (the
-        backends that implement donation delete the inputs on dispatch;
-        injected faults raise before the call and never trip this)."""
-        deleted = getattr(pool.tokens, "is_deleted", None)
-        return bool(deleted and deleted())
-
     # ------------------------------------------------ cross-engine migration
 
     def freeze_rows(self) -> dict | None:
@@ -1659,11 +1289,9 @@ class ServeEngine:
         rows that could not export (a ``serve.migrate`` export fault, or a
         worker crash mid-freeze — the pool is not trusted after one) and
         must ride the PR 7 retry path. Returns None when the engine cannot
-        freeze (not paged, or already terminal) — the caller falls back to
-        a plain drain. Terminal either way once it returns a dict: the
-        worker has exited and the router closes the engine next."""
-        if not self.paged:
-            return None
+        freeze (already terminal) — the caller falls back to a plain drain.
+        Terminal either way once it returns a dict: the worker has exited
+        and the router closes the engine next."""
         self._refuse_window("freeze_rows")
         self._queue.close("engine freezing for migration")
         with self._cond:
@@ -1804,9 +1432,6 @@ class ServeEngine:
         blob = frozen.get("blob")
         if blob is None or not entries:
             return {"adopted": [], "fallback": list(entries.values())}
-        if not self.paged:
-            raise MigrationError(
-                f"adopt target {self._name} is not a paged engine")
         self._refuse_window("adopt_rows")
         if timeout is None:
             timeout = get_config().serve_migrate_timeout_s
@@ -1885,7 +1510,7 @@ class ServeEngine:
         """The pool's N hottest prefix-cache chains as a migration blob
         (worker-mediated; best-effort — returns None instead of raising:
         cache warming must never fail a restart)."""
-        if not self.paged or n <= 0:
+        if n <= 0:
             return None
         self._refuse_window("export_prefixes")
         if timeout is None:
@@ -1899,7 +1524,7 @@ class ServeEngine:
                         timeout: float | None = None) -> int:
         """Warm this pool's prefix cache from a peer's exported chains
         (worker-mediated; best-effort). Returns entries inserted."""
-        if not self.paged or not blob:
+        if not blob:
             return 0
         self._refuse_window("import_prefixes")
         if timeout is None:
@@ -2070,8 +1695,6 @@ class ServeEngine:
         engine's live groups — exact on a quiesced engine (closed, drained,
         frozen); advisory under a running worker (the probe snapshot races
         row transitions). Never raises — rides ``GET /debug/kvpool``."""
-        if not self.paged:
-            return {"ok": True, "errors": [], "note": "engine is not paged"}
         with self._cond:
             pool = self._kvpool
             groups = [g for g in self._pools.values()
@@ -2097,8 +1720,11 @@ class ServeEngine:
         over the engine's
         one shared :class:`PagedKVPool`; ``pf_queue`` is the FIFO of rows
         mid-prefill ((bucket, slot, rid) — rid guards against a retired
-        slot's re-occupant inheriting a stale cursor). Mirrors for
-        supervisor recovery as in the slab loop."""
+        slot's re-occupant inheriting a stale cursor).
+        ``self._pools``/``self._claimed`` mirror the worker's hands so a
+        supervisor recovering a STUCK generation (watchdog timeout — the
+        thread is alive but unreachable) can still find every in-flight
+        entry to requeue."""
         pools: dict[tuple, PagedGroup] = {}
         with self._cond:
             if self._gen != gen:
@@ -2151,7 +1777,7 @@ class ServeEngine:
                                     return
                                 break
                             draining = self._state == "draining"
-                            claimed = self._claim_rowlevel(pools)
+                            claimed = self._claim(pools)
                             span.set_metadata(claimed=len(claimed))
                             if claimed or busy:
                                 break
@@ -2248,7 +1874,7 @@ class ServeEngine:
             return
         from ..models.planner import request_pages
 
-        # dispatch order ACROSS buckets: _claim_rowlevel walks an unordered
+        # dispatch order ACROSS buckets: _claim walks an unordered
         # bucket set, but the prefill queue is the TTFT ledger — higher
         # priority first, then arrival (rid is monotonic per process), so a
         # short early request never waits out a later long prompt's chunks
@@ -2456,9 +2082,11 @@ class ServeEngine:
     def _step_paged(self, pool, pools) -> None:
         """Retire expired resident rows, then run ONE decode step per
         bucket over its live rows. All buckets' steps are dispatched before
-        any result is awaited (async dispatch overlap, as in the slab
-        loop); non-live rows run the masked-harmless dummy against page 0
-        so a prefilling neighbor's pages are never scribbled."""
+        any result is awaited — JAX dispatch is async, so bucket B's device
+        work overlaps the host round-trip for bucket A instead of
+        serializing behind it; non-live rows run the masked-harmless dummy
+        against page 0 so a prefilling neighbor's pages are never
+        scribbled."""
         launched = []
         with annotate("serve.decode") as span:
             for bucket, group in list(pools.items()):
@@ -2662,7 +2290,8 @@ class ServeEngine:
 
     def _paged_pool_lost(self, pool) -> bool:
         """True when a failed donated call consumed the page slab (the
-        paged analog of :meth:`_slab_lost`)."""
+        backends that implement donation delete the inputs on dispatch;
+        injected faults raise before the call and never trip this)."""
         if pool is None:
             return False
         leaf = pool.pages["l0"][0]

@@ -1,10 +1,9 @@
 """Paged KV-cache pool: block-table paging + copy-on-write prefix sharing.
 
-The dense-slab scheduler (PR 4, ``serve_paged=False``) gives every slot a
-``(max_len, kvh, dh)`` cache row sized for its bucket's worst case — a
-short request in a long bucket wastes HBM linearly and admission must
-budget by bucket. This module replaces the slab with the classic paged
-design: ONE device-resident page slab per engine
+A cache row sized for its bucket's worst case wastes HBM linearly for a
+short request in a long bucket, and admission would have to budget by
+bucket. So the cache is the classic paged design: ONE device-resident page
+slab per engine
 (:func:`~marlin_tpu.models.transformer.init_kv_pages` — ``(num_pages,
 page_len, kvh, dh)`` per layer, shared by every bucket) plus host-side
 bookkeeping per row:
@@ -58,7 +57,7 @@ points raise.
 
 Everything here is host-side numpy/stdlib except the three compiled
 programs it drives; single-threaded by contract (only the engine worker
-touches a pool, like :class:`~.batcher.SlotPool`).
+touches a pool).
 """
 
 from __future__ import annotations
@@ -167,11 +166,10 @@ def auto_window_pages(buckets, max_batch: int, ring: int) -> int:
 
 def auto_num_pages(buckets, max_batch: int, page_len: int) -> int:
     """The default pool size (``serve_num_pages=0``): every bucket's full
-    slot width at its full extent — the dense-slab steady state, so a
-    paged-vs-slab A/B holds device capacity equal — plus one slack page
-    per slot (chunk scatter spill) and the dummy page 0. Short requests
-    use fewer pages than this budget assumes; the surplus is what the
-    prefix cache lives in."""
+    slot width at its full extent, so a full slot set always fits, plus
+    one slack page per slot (chunk scatter spill) and the dummy page 0.
+    Short requests use fewer pages than this budget assumes; the surplus is
+    what the prefix cache lives in."""
     pages = 1  # the dummy
     for p, s in buckets:
         pages += max_batch * (-(-(p + s) // page_len) + 1)
@@ -817,13 +815,12 @@ class PagedKVPool:
 
 
 class PagedGroup:
-    """Per-bucket row bookkeeping over a shared :class:`PagedKVPool` — the
-    paged analog of :class:`~.batcher.SlotPool`. Owns the per-row vectors
-    the decode program takes, each row's block table and prefill cursor,
-    and the host-side emitted-token stream (tokens never live on device in
-    paged mode: the decode program takes ``cur_tokens`` and returns the
-    next ones, so results are assembled host-side). Single-threaded — only
-    the engine worker touches a group."""
+    """Per-bucket row bookkeeping over a shared :class:`PagedKVPool`. Owns
+    the per-row vectors the decode program takes, each row's block table
+    and prefill cursor, and the host-side emitted-token stream (tokens
+    never live on the device: the decode program takes ``cur_tokens`` and
+    returns the next ones, so results are assembled host-side).
+    Single-threaded — only the engine worker touches a group."""
 
     def __init__(self, bucket, width: int, page_len: int,
                  prefill_chunk: int, ring: int | None = None):
@@ -920,7 +917,7 @@ class PagedGroup:
 
     def finish_prefill(self, slot: int, first: int) -> None:
         """The final chunk landed: the row becomes decode-ready with its
-        first emitted token in hand (= the slab path's prefill contract)."""
+        first emitted token in hand."""
         self.pf_next[slot] = -1
         self.positions[slot] = self.lengths[slot]
         self.steps_done[slot] = 1
@@ -1037,9 +1034,14 @@ def capture_paged_costs(params: dict, heads: int, bucket, max_batch: int,
                         key: str | None = None,
                         kernel: str = "gather") -> None:
     """Capture the XLA cost models of a bucket's paged program pair into
-    the process ProgramCosts registry — trace + lower only, gated per
-    (program, key) like :func:`~.batcher.capture_bucket_costs`. Never
-    raises (observability must not fail warmup or a dispatch).
+    the process ProgramCosts registry — trace + lower only (no backend
+    compile; the bucket's real compile already happened or is about to
+    through the jit cache). Gated per (program, key) on ATTEMPTED, not
+    succeeded, so repeated calls — the engine invokes this on every group
+    creation, with its cached ``key`` — cost two dict lookups after the
+    first, and a backend without ``cost_analysis()`` does not re-pay the
+    trace on every dispatch. Never raises (observability must not fail
+    warmup or a dispatch).
 
     With ``kernel='pallas'`` on a Mosaic (non-interpret) lowering, the
     pallas_call is a custom call XLA's cost analysis scores at zero — the

@@ -291,7 +291,7 @@ class ServeMetrics:
     def record_prefill(self, bucket, seconds: float,
                        rid: int | None = None,
                        program_key: str | None = None,
-                       program: str = "lm_prefill_slot",
+                       program: str = "lm_prefill_paged",
                        chunk=None, final: bool = True) -> None:
         """One prefill dispatch. The row's FIRST token is emitted by the
         COMPLETING dispatch (real TTFT), so that one counts toward
@@ -322,7 +322,7 @@ class ServeMetrics:
     def record_step(self, bucket, rows: int, max_batch: int,
                     seconds: float,
                     program_key: str | None = None,
-                    program: str = "lm_decode_rows",
+                    program: str = "lm_decode_paged",
                     label: str | None = None) -> None:
         """One decode step over a bucket's rows: ``rows`` live slots each
         emitted one token (``new_tokens`` == ``rows``). ``program_key``

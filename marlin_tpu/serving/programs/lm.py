@@ -1,24 +1,22 @@
-"""The paged/slab LM decode path as the first BucketProgram.
+"""The paged LM decode path as the first BucketProgram.
 
-This is the extraction end of the refactor: the *policy* the engine's
-``_submit`` used to hardcode for LM traffic — bucket rounding
+The *policy* of LM traffic — bucket rounding
 (:func:`~marlin_tpu.serving.batcher.pick_bucket`), page-unit admission
-pricing (:func:`~marlin_tpu.models.planner.request_pages` × page bytes, or
-the slab worst case), the pool-capacity refusal, and the ProgramCosts keys
-— now answers through the same :class:`~.base.BucketProgram` surface every
-other program uses. The *mechanism* (chunked prefill, the decode step, KV
-page bookkeeping) stays in the engine's paged/slab loops untouched: LM rows
-execute exactly the pre-refactor code path, which is what keeps greedy
-output bit-identical to ``lm_generate`` — the acceptance bar for this
-seam. :meth:`PagedLMProgram.step` is therefore deliberately unreachable;
-the freeze/adopt hooks are likewise the engine's KV-blob export, not ours.
+pricing (:func:`~marlin_tpu.models.planner.request_pages` × page bytes),
+the pool-capacity refusal, and the ProgramCosts keys — answers through the
+same :class:`~.base.BucketProgram` surface every other program uses. The
+*mechanism* (chunked prefill, the decode step, KV page bookkeeping) is the
+engine's paged loop: that one code path is what keeps greedy output
+bit-identical to ``lm_generate``. :meth:`PagedLMProgram.step` is therefore
+deliberately unreachable; the freeze/adopt hooks are likewise the engine's
+KV-blob export, not ours.
 """
 
 from __future__ import annotations
 
 import threading
 
-from ..batcher import bucket_kv_bytes, pick_bucket
+from ..batcher import pick_bucket
 from . import register_program
 from .base import BucketProgram
 
@@ -27,12 +25,11 @@ __all__ = ["PagedLMProgram"]
 
 @register_program
 class PagedLMProgram(BucketProgram):
-    """token prompt → generated tokens via the engine's paged/slab loops."""
+    """token prompt → generated tokens via the engine's paged loop."""
 
     name = "lm"
     cost_program = "lm_decode_paged"
-    resource_unit = ("actual KV pages x page bytes (paged) / "
-                     "bucket slab bytes (slab)")
+    resource_unit = "actual KV pages x page bytes"
 
     def __init__(self, engine):
         # no super().__init__: LM's batch axis is the engine's max_batch,
@@ -61,26 +58,23 @@ class PagedLMProgram(BucketProgram):
 
     def admission_cost(self, request, bucket):
         eng = self._eng
-        if eng.paged:
-            # admission charges the request's ACTUAL pages (the memory its
-            # cache rows can ever write — planner.request_pages), not the
-            # bucket worst case: short requests in long buckets stop
-            # reserving capacity they never use
-            from ...models.planner import request_pages
+        # admission charges the request's ACTUAL pages (the memory its
+        # cache rows can ever write — planner.request_pages), not the
+        # bucket worst case: a short request in a long bucket reserves
+        # only what it can use
+        from ...models.planner import request_pages
 
-            pages = request_pages(request.prompt.shape[0], request.steps,
-                                  eng._page_len)
-            if pages > eng._num_pages - 1:
-                raise ValueError(
-                    f"request needs {pages} KV pages but the pool holds "
-                    f"{eng._num_pages - 1} (serve_num_pages)")
-            # each class of page is charged for what the row can pin in it:
-            # every position in the global class, a ring in the window class
-            ring = request_pages(request.prompt.shape[0], request.steps,
-                                 eng._page_len, ring=eng._ring or 0)
-            return pages * eng._page_bytes + ring * eng._window_page_bytes
-        return bucket_kv_bytes(eng.params, eng.heads, bucket,
-                               eng.compute_dtype)
+        pages = request_pages(request.prompt.shape[0], request.steps,
+                              eng._page_len)
+        if pages > eng._num_pages - 1:
+            raise ValueError(
+                f"request needs {pages} KV pages but the pool holds "
+                f"{eng._num_pages - 1} (serve_num_pages)")
+        # each class of page is charged for what the row can pin in it:
+        # every position in the global class, a ring in the window class
+        ring = request_pages(request.prompt.shape[0], request.steps,
+                             eng._page_len, ring=eng._ring or 0)
+        return pages * eng._page_bytes + ring * eng._window_page_bytes
 
     def program_key(self, bucket, width=None):
         return self._eng._prog_key(bucket)
@@ -93,5 +87,5 @@ class PagedLMProgram(BucketProgram):
 
     def step(self, bucket, requests):  # pragma: no cover - engine-executed
         raise RuntimeError(
-            "LM rows execute in the engine's paged/slab loops, not via "
+            "LM rows execute in the engine's paged loop, not via "
             "BucketProgram.step")

@@ -45,7 +45,7 @@ One engine is one worker loop on (implicitly) one device set; the ROADMAP's
   fails (the ``serve.migrate`` fault point simulates each) degrades that
   row to the PR 7 retry path — a fresh-attempt twin on a healthy replica,
   reservation carried exactly once, nothing double-delivers — and a
-  replica that cannot freeze at all (slab engine) falls back to the old
+  replica that cannot freeze at all (already terminal) falls back to the
   drain-in-place rotation.
 - **One scrape target**: the router registers a single aggregated health
   provider (each adopted engine's individual provider is unregistered —
@@ -135,7 +135,7 @@ def _prefix_route_key(request, ready) -> bytes | None:
     deliberately ONLY the first page, so requests sharing a system prompt
     map together whatever their tails do. None when nothing is shareable
     (prompt must be strictly longer than a page: the cache never shares
-    the last-token page) or no ready replica is paged. Non-LM BucketProgram
+    the last-token page) or no replica is ready. Non-LM BucketProgram
     requests have no KV prefix to be affine to, so they deterministically
     fall back to power-of-two-choices placement — mixed traffic load-
     balances instead of piling onto whichever replica owns a hot prompt."""
@@ -144,8 +144,7 @@ def _prefix_route_key(request, ready) -> bytes | None:
     if not get_config().serve_prefix_affinity:
         return None
     prompt = getattr(request, "prompt", None)
-    page_len = next((r.engine._page_len for r in ready
-                     if getattr(r.engine, "paged", False)), 0)
+    page_len = next((r.engine._page_len for r in ready), 0)
     if prompt is None or not page_len or len(prompt) <= page_len:
         return None
     head = np.ascontiguousarray(np.asarray(prompt[:page_len], np.int32))
@@ -387,7 +386,7 @@ class Router:
         backlog moves wholesale, and only then is the engine closed,
         rebuilt via the factory, its prefix cache warmed from a peer, and
         rejoined before the next replica leaves — peers absorb traffic
-        throughout. A replica that cannot freeze (slab engine) falls back
+        throughout. A replica that cannot freeze (already terminal) falls back
         to the PR 7 drain-in-place rotation; a migration leg that fails
         degrades those rows to retry twins — zero dropped requests either
         way. Returns per-replica timings. Requires a factory; serialized
@@ -416,7 +415,7 @@ class Router:
                 # into the retry fallback) and the supervisor idles on the
                 # freezing/frozen states rather than respawning under us
                 if not self._migrate_out(rep):
-                    # can't freeze (slab engine / already terminal): the
+                    # can't freeze (already terminal): the
                     # PR 7 path — drain FIRST, supervisor attached, so a
                     # crash mid-drain recovers and accepted work completes
                     self._emit(ev="replica_rotate", router=self._name,
@@ -590,7 +589,7 @@ class Router:
 
     def _migrate_out(self, rep: _Replica) -> bool:
         """Freeze ``rep`` and move everything it holds: live rows adopt
-        onto the least-loaded ready paged peer (KV travels, decode resumes
+        onto the least-loaded ready peer (KV travels, decode resumes
         mid-stream), the queued backlog moves as-is (same entries — they
         never started, no twin needed), and rows any leg failed on degrade
         to fresh-attempt retry twins. Admission reservations move exactly
@@ -644,11 +643,10 @@ class Router:
         return True
 
     def _pick_target(self, exclude: _Replica) -> _Replica | None:
-        """Least-loaded ready PAGED peer — the adoption target."""
+        """Least-loaded ready peer — the adoption target."""
         with self._lock:
             cands = [r for r in self._replicas
-                     if r is not exclude and r.ready()
-                     and getattr(r.engine, "paged", False)]
+                     if r is not exclude and r.ready()]
         return min(cands, key=lambda r: r.load(), default=None)
 
     def _place_entries(self, src: _Replica, entries, retry: bool) -> int:
@@ -707,9 +705,8 @@ class Router:
         n = get_config().serve_cache_warm_prefixes
         with self._lock:
             peers = [r for r in self._replicas
-                     if r is not fresh and r.ready()
-                     and getattr(r.engine, "paged", False)]
-        if n <= 0 or not getattr(fresh.engine, "paged", False) or not peers:
+                     if r is not fresh and r.ready()]
+        if n <= 0 or not peers:
             return
         # warmest peer first: the one whose cache has answered the most —
         # affinity concentrates a shared prefix there

@@ -22,9 +22,9 @@ loop:
   freshly scaled-out replica must not be "recovered" mid-warmup — crash
   detection stays on throughout.
 - **Recovery** (``ServeEngine._recover``) rebuilds from the admission
-  contract outward: slot pools are dropped (the KV slab state died with
-  the worker; pools rebuild zeroed on the next admission — the PR 4
-  ``is_deleted``→pool-rebuild path generalized), live rows that never
+  contract outward: the row groups and the page pool are dropped (their
+  state died with the worker; both rebuild zeroed on the next admission),
+  live rows that never
   emitted a Result re-queue within their per-request ``max_attempts``
   budget (exactly-once is preserved by attempt accounting: a superseded
   entry can never set the handle, and the admission reservation is carried

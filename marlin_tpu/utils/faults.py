@@ -31,21 +31,16 @@ point               fires from
 ``serve.enqueue``   :meth:`~marlin_tpu.serving.engine.ServeEngine.submit`
                     entry (ctx carries ``path=<rid>``) — a raise here
                     surfaces to the submitting caller
-``serve.step``      the serving worker loop, just before each gang batch
-                    launch / each row-level slot prefill (ctx carries
-                    ``path="bucket-<P>x<steps>"``) — a raise fails that
-                    batch's / that admission's requests with ``error``
-                    Results; the engine keeps serving
 ``serve.prefill``   the paged scheduler, just before each bounded prefill
                     CHUNK (ctx carries ``path="bucket-<P>x<steps>"``) — a
                     raise fails only the rows prefilling in that chunk;
                     already-decoded rows and queued requests keep serving
 ``serve.decode_step``
-                    the row-level scheduler, just before each single-token
-                    decode step over a bucket's KV slab (ctx carries
+                    the paged scheduler, just before each single-token
+                    decode step over a bucket's live rows (ctx carries
                     ``path="bucket-<P>x<steps>"``) — a raise fails only
                     that step's live rows with ``error`` Results and leaves
-                    the slot pool consistent; queued requests keep serving
+                    the page pool consistent; queued requests keep serving
 ``serve.worker_crash``
                     the serving worker loop, once per iteration OUTSIDE the
                     per-batch/per-step failure envelopes (ctx carries
@@ -100,7 +95,7 @@ __all__ = [
 KNOWN_POINTS = frozenset({
     "ckpt.write", "ckpt.manifest", "fs.open", "fs.list", "step.run",
     "device.probe", "prefetch.produce", "dataplane.read", "serve.enqueue",
-    "serve.step", "serve.prefill", "serve.decode_step", "serve.worker_crash",
+    "serve.prefill", "serve.decode_step", "serve.worker_crash",
     "serve.router_route", "serve.migrate", "serve.fleet",
     "serve.program_step",
 })

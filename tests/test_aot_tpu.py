@@ -532,21 +532,6 @@ def test_pp_lm_train_step_compiles_for_4chip_v5e(topo_mesh):
     assert c.memory_analysis().peak_memory_in_bytes > 0
 
 
-def test_aot_compile_buckets_reports_hbm(topo):
-    """Compile-only TPU evidence for bucket sizing (needs libtpu)."""
-    from marlin_tpu.models.transformer import TransformerLM
-    from marlin_tpu.serving import aot_compile_buckets
-
-    params = TransformerLM(vocab=32, d_model=16, heads=2, layers=2,
-                           seed=9).init_params()
-    # this tiny model's compiler peak (weights + workspace) dwarfs its KV
-    # slab arithmetic, so the planner-honesty warning MUST fire here — the
-    # same signal that catches a real under-budgeted serve_max_batch
-    with pytest.warns(RuntimeWarning, match="measured peak"):
-        peaks = aot_compile_buckets(params, 2, [(8, 4)], max_batch=2)
-    assert set(peaks) == {(8, 4)} and peaks[(8, 4)] > 0
-
-
 # ---------------------------------------------------------------------------
 # chip_smoke.py's programs at chip_smoke.py's widths: the d512/h8/L4/v4096 LM
 # under ServeEngine's default geometry (buckets (64,32),(256,64), max_batch

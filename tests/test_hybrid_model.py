@@ -390,16 +390,13 @@ def test_prefix_cache_and_migration_refuse_a_window_model(model):
                 call()
 
 
-@pytest.mark.parametrize("what", ["lm_generate", "slab engine", "moe knob"])
+@pytest.mark.parametrize("what", ["lm_generate", "moe knob"])
 def test_the_paths_that_cannot_run_a_spec_say_so(model, what):
     _, spec, params = model
     if what == "lm_generate":
         with pytest.raises(TypeError, match="heads: int"):
             lm_generate(params, np.zeros(4, np.int32), jax.random.key(0),
                         heads=spec, max_len=8, steps=2)
-    elif what == "slab engine":
-        with pytest.raises(ValueError, match="paged"):
-            ServeEngine(params, spec, paged=False, start=False)
     else:
         with pytest.raises(ValueError, match="moe"):
             ServeEngine(params, spec, moe=(2, 1.25, 64), start=False)

@@ -71,7 +71,6 @@ def _engine(params, **kw):
     kw.setdefault("queue_depth", 64)
     kw.setdefault("page_len", PAGE_LEN)
     kw.setdefault("num_pages", 256)
-    kw.setdefault("paged", True)
     return ServeEngine(params, HEADS, **kw)
 
 

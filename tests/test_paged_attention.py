@@ -96,7 +96,7 @@ def test_kernel_page_boundary_lengths():
 
 def test_kernel_dummy_rows_are_harmless():
     """Rows still prefilling ride the batch with an all-dummy (zero) table
-    and length 1 — the dense-slab dummy-row contract. Their outputs must be
+    and length 1 — the dummy-row contract. Their outputs must be
     finite (the scheduler discards them) and must not perturb live rows."""
     rng = np.random.default_rng(2)
     q, kp, vp, tables = _random_case(rng)

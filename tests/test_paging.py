@@ -9,7 +9,7 @@ identical to unshared runs), chunked-prefill interleaving and resumability
 across injected ``serve.prefill`` faults, the ≤ 3-compiles-per-bucket
 bound via the shared ``compile_count`` fixture, page-unit admission, and a
 slow chaos soak with worker kills over a paged pool. The generic engine
-contracts (exactly-once, drain/close, both backends' acceptance) live in
+contracts (exactly-once, drain/close, the acceptance scenario) live in
 tests/test_serving.py.
 """
 
@@ -22,6 +22,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import marlin_tpu as mt
 from marlin_tpu.models import TransformerLM
 from marlin_tpu.models.planner import kv_page_bytes, request_pages
 from marlin_tpu.models.transformer import (init_kv_pages, lm_decode_paged,
@@ -45,6 +46,9 @@ from marlin_tpu.utils.faults import RaiseFault, Schedule
 HEADS = 2
 BUCKETS = ((8, 4), (16, 4))
 PAGE_LEN = 4
+# the fork the platform chooses: the gather program (CPU) and the Pallas
+# kernel (the chip; interpret mode here)
+KERNELS = pytest.mark.parametrize("kernel", ["gather", "pallas"])
 
 
 @pytest.fixture(scope="module")
@@ -276,13 +280,18 @@ def test_report_paging_line(params, tmp_path):
     assert "page occupancy peak" in text
 
 
-def test_rowlevel_kwarg_is_removed(params):
-    """Satellite (ISSUE 18): the deprecated ``rowlevel`` escape hatch is
-    gone — passing it (either value) raises a ValueError that points at
-    serve_paged, the knob that actually picks a backend now."""
-    for val in (False, True):
-        with pytest.raises(ValueError, match="serve_paged"):
-            _engine(params, rowlevel=val)
+@pytest.mark.parametrize("selector", ["paged", "rowlevel", "serve_paged"])
+def test_backend_selectors_are_removed(params, selector):
+    """There is one scheduler and one KV backend, and nothing selects
+    another: the two constructor kwargs are gone from the signature and the
+    config field is unknown."""
+    if selector == "serve_paged":
+        with pytest.raises(AttributeError, match="serve_paged"):
+            with mt.config_context(serve_paged=False):
+                pass
+    else:
+        with pytest.raises(TypeError, match=selector):
+            _engine(params, **{selector: False})
 
 
 # ------------------------------------------------- engine: chunked prefill
@@ -318,14 +327,16 @@ def test_chunked_prefill_interleaves_decode(params, tmp_path):
         "no decode step interleaved with the long prompt's chunks")
 
 
-def test_prefill_fault_retries_resumably(params):
+@KERNELS
+def test_prefill_fault_retries_resumably(params, kernel):
     """Satellite: chunked prefill is resumable across injected
     serve.prefill faults — a mid-prefill fault frees the row's pages, the
     retry re-runs from its (re-matched) shared prefix, and the output
     stays bit-identical. Without attempt budget the request errors and
     the engine keeps serving."""
     prompt = (np.arange(16) % 32).astype(np.int32)
-    eng = _engine(params, start=False, prefill_chunk=2 * PAGE_LEN)
+    eng = _engine(params, start=False, prefill_chunk=2 * PAGE_LEN,
+                  decode_kernel=kernel)
     try:
         eng.warmup()
         with faults.injected("serve.prefill", RaiseFault(times=1)):
@@ -355,8 +366,7 @@ def test_prefill_fault_retries_resumably(params):
 
 def test_fault_mid_chunk_stream_keeps_neighbors(params):
     """A serve.prefill fault on one row's LATER chunk leaves co-resident
-    decoding rows untouched (the paged analog of the slab's
-    fault-blast-radius contract)."""
+    decoding rows untouched (the fault's blast radius is one row)."""
     eng = _engine(params, start=False, prefill_chunk=PAGE_LEN, max_batch=2)
     try:
         eng.warmup()
@@ -510,16 +520,18 @@ def test_page_unit_admission(params):
     assert eng._queue.bytes_in_flight == 0
 
 
-def test_every_retirement_path_frees_pages(params):
+@KERNELS
+def test_every_retirement_path_frees_pages(params, kernel):
     """Satellite regression: eos / steps / submit-expiry / dispatch-expiry
     / prefill-fault / decode-fault / drain each release the row's pages
     exactly once — afterwards the pool holds only prefix-cache pages and
-    the admission gate is fully drained (the page-unit mirror of PR 4's
-    expiring-burst test, per path)."""
+    the admission gate is fully drained (test_serving.py's expiring-burst
+    test, per path)."""
     from tests.test_serving import FakeClock
 
     clock = FakeClock()
-    eng = _engine(params, clock=clock, start=False, max_batch=2)
+    eng = _engine(params, clock=clock, start=False, max_batch=2,
+                  decode_kernel=kernel)
     try:
         eng.warmup()
         gen = _ref(params, [5, 3], 4)[2:]

@@ -176,33 +176,48 @@ def test_program_costs_capture_never_raises():
     assert not costs.tried("p", "other")
 
 
-def test_capture_sites_do_not_retry_failed_traces(lm_params):
+def test_capture_sites_do_not_retry_failed_traces(monkeypatch):
     """A bucket whose trace fails must not be re-traced on every dispatch:
-    capture_bucket_costs marks the attempt even when the lowering path
-    raises (simulated via params the slab derivation chokes on)."""
-    from marlin_tpu.serving.batcher import (bucket_program_key,
-                                            capture_bucket_costs)
+    capture_paged_costs marks the attempt for BOTH paged programs even when
+    the lowering path raises (simulated via params the page-slab derivation
+    chokes on), and the next call stops at the gate."""
+    import types
 
+    from marlin_tpu.serving import kvpool
+
+    built = []
+
+    class CountedGroup(kvpool.PagedGroup):
+        def __init__(self, *a, **kw):
+            built.append(a)
+            super().__init__(*a, **kw)
+
+    monkeypatch.setattr(kvpool, "PagedGroup", CountedGroup)
     bad_params = {"emb": np.zeros((4, 4), np.float32)}  # no l0: trace dies
-    capture_bucket_costs(bad_params, HEADS, (8, 4), 4)
-    key = bucket_program_key(bad_params, (8, 4), 4)
+    pool = types.SimpleNamespace(page_len=4, num_pages=8)
+    kvpool.capture_paged_costs(bad_params, HEADS, (8, 4), 4, pool, 8)
+    assert len(built) == 1  # it got as far as the trace
+    key = kvpool.paged_program_key(bad_params, (8, 4), 4, 4)
     costs = perf.get_program_costs()
-    assert costs.tried("lm_decode_rows", key)
-    assert not costs.has("lm_decode_rows", key)
+    for prog in ("lm_prefill_paged", "lm_decode_paged"):
+        assert costs.tried(prog, key)
+        assert not costs.has(prog, key)
+    kvpool.capture_paged_costs(bad_params, HEADS, (8, 4), 4, pool, 8)
+    assert len(built) == 1  # gated on attempted: nothing traced again
 
 
 def test_program_emit_and_report_table(default_log):
     costs = ProgramCosts()
     key = program_key(bucket="8x4")
-    costs.capture("lm_decode_rows", key,
+    costs.capture("lm_decode_paged", key,
                   cost={"flops": 4000.0, "bytes accessed": 1000.0},
                   log=default_log)
-    costs.observe("lm_decode_rows", key, seconds=0.004, calls=8)
+    costs.observe("lm_decode_paged", key, seconds=0.004, calls=8)
     with config_context(obs_peak_flops=1e7, obs_peak_bw=1e9):
         assert costs.emit(log=default_log) == 1
     out = analyze(default_log.read())
     assert "== program utilization ==" in out
-    assert "lm_decode_rows" in out
+    assert "lm_decode_paged" in out
     # achieved = 4000 / 0.0005 = 8 MFLOP/s = 0.01 GFLOP/s; frac = 0.8
     assert "80.00%" in out
 

@@ -37,7 +37,6 @@ from marlin_tpu.serving import (
     ServeEngine,
     Supervisor,
     available_programs,
-    planner_ratio_warning,
 )
 from marlin_tpu.serving.router import _prefix_route_key
 from marlin_tpu.utils import EventLog, faults
@@ -228,19 +227,6 @@ def test_pagerank_refresh_changes_rankings(params):
             np.isin(np.arange(5), [1, 2]), ranks, -np.inf)))}
     finally:
         eng.close()
-
-
-def test_planner_ratio_warning_threshold():
-    # honest planner → silent
-    assert planner_ratio_warning((8, 4), 100, 100) is None
-    assert planner_ratio_warning((8, 4), 200, 100) is None   # exactly 2.0x
-    # degenerate planner numbers never divide-by-zero into a warning
-    assert planner_ratio_warning((8, 4), 100, 0) is None
-    msg = planner_ratio_warning((16, 8), 500, 100)
-    assert msg is not None
-    assert "5.0x" in msg and "(16, 8)" in msg and "measured peak" in msg
-    # the factor is a knob
-    assert planner_ratio_warning((8, 4), 500, 100, factor=6.0) is None
 
 
 # ------------------------------------------------------------- mixed traffic

@@ -90,17 +90,17 @@ def _ref(params, prompt, steps, heads=HEADS):
 # --------------------------------------------------------------- supervisor
 
 
-@pytest.mark.parametrize("paged", [False, True], ids=["slab", "paged"])
-def test_supervisor_recovers_worker_crash(params, paged, tmp_path):
-    """The crash-recovery invariant: a serve.worker_crash mid-stream kills
-    the worker thread; the supervisor restarts it within the backoff
-    budget, live rows re-queue within their attempt budget (page-unit
-    reservations carried across attempts on the paged backend; the pool is
-    dropped and rebuilt zeroed), every request reaches exactly one
+@pytest.mark.parametrize("kernel", ["gather", "pallas"])
+def test_supervisor_recovers_worker_crash(params, kernel, tmp_path):
+    """The crash-recovery invariant, under both decode kernels: a
+    serve.worker_crash mid-stream kills the worker thread; the supervisor
+    restarts it within the backoff budget, live rows re-queue within their
+    attempt budget (page-unit reservations carried across attempts; the
+    pool is dropped and rebuilt zeroed), every request reaches exactly one
     terminal ok Result, and greedy outputs are bit-identical to
     uninterrupted lm_generate."""
     log = EventLog(str(tmp_path / "serve.jsonl"))
-    eng = _engine(params, paged=paged, log=log)
+    eng = _engine(params, decode_kernel=kernel, log=log)
     eng.warmup()
     sup = Supervisor(eng, backoff_s=0.005, poll_s=0.02, log=log)
     try:

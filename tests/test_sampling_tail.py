@@ -2,7 +2,7 @@
 
 ``_pick_token_rows`` chooses between "sort the vocabulary and sample" and
 "argmax" once per dispatch, on a scalar the program computes from the
-temperatures it is handed. For every mix of greedy and sampled rows the three
+temperatures it is handed. For every mix of greedy and sampled rows both
 decode programs must give, bit for bit, the tokens of the per-row function
 called row by row on unbatched logits; and in their jaxprs no ``sort`` may
 stand outside a branch of a ``cond`` with a scalar predicate (under ``vmap``
@@ -30,7 +30,7 @@ MIXES = {
     "all-sampled": ((0.7, 1.0, 1.3, 0.9), (1.0, 1.0, 1.0, 1.0), (0, 0, 0, 0)),
     "mixed-knobs": ((0.0, 0.8, 0.0, 1.1), (1.0, 0.6, 0.5, 1.0), (0, 0, 3, 5)),
 }
-PROGRAMS = ("paged-gather", "paged-pallas", "rows")
+PROGRAMS = ("paged-gather", "paged-pallas")
 
 
 @pytest.fixture(scope="module")
@@ -84,48 +84,27 @@ def _paged_tokens(step, params, mix):
     return out
 
 
-def _rows_tokens(step, params, mix):
-    caches = tf.init_kv_slab(params, B, P + STEPS + 1, HEADS)
-    tokens = jnp.zeros((B, P + STEPS + 1), jnp.int32)
-    for b in range(B):
-        caches, tokens, _ = tf.lm_prefill_slot(
-            params, caches, tokens, b, _prompt(b), P, heads=HEADS,
-            max_len=P + STEPS + 1)
-    pos, done, out = np.full(B, P, np.int32), np.ones(B, np.int32), []
-    for _ in range(STEPS):
-        caches, tokens, nxt = step(params, caches, tokens, pos, done,
-                                   *_knobs(mix))
-        out.append(np.asarray(nxt).tolist())
-        pos, done = pos + 1, done + 1
-    return out
-
-
 def _program(name):
-    """(the jitted program's Python body with its static arguments bound,
-    the driver that steps it)."""
-    if name == "rows":
-        return functools.partial(
-            tf._lm_decode_rows_jit.__wrapped__, heads=HEADS,
-            max_len=P + STEPS + 1, compute_dtype=None), _rows_tokens
+    """The jitted program's Python body with its static arguments bound."""
     return functools.partial(
         tf._lm_decode_paged_jit.__wrapped__, heads=HEADS, page_len=PAGE_LEN,
-        compute_dtype=None, kernel=name.split("-")[1]), _paged_tokens
+        compute_dtype=None, kernel=name.split("-")[1])
 
 
 @pytest.mark.parametrize("mix", sorted(MIXES))
 @pytest.mark.parametrize("program", PROGRAMS)
 def test_decode_tokens_equal_the_per_row_oracle(params, monkeypatch,
                                                 program, mix):
-    body, drive = _program(program)
-    got = drive(jax.jit(body), params, mix)
+    body = _program(program)
+    got = _paged_tokens(jax.jit(body), params, mix)
     # the same program with the tail replaced by the row-by-row oracle,
     # traced anew so that the replacement is what gets compiled
     monkeypatch.setattr(tf, "_pick_token_rows", _row_by_row)
-    want = drive(jax.jit(lambda *a: body(*a)), params, mix)
+    want = _paged_tokens(jax.jit(lambda *a: body(*a)), params, mix)
     assert got == want
     if mix == "all-sampled":  # and the draw is not the argmax in disguise
         monkeypatch.undo()
-        assert got != drive(jax.jit(body), params, "all-greedy")
+        assert got != _paged_tokens(jax.jit(body), params, "all-greedy")
 
 
 def _sorts(jaxpr, under_scalar_cond=False):
@@ -146,14 +125,10 @@ def _sorts(jaxpr, under_scalar_cond=False):
 
 @pytest.mark.parametrize("program", PROGRAMS)
 def test_every_sort_lies_behind_a_scalar_cond(params, program):
-    body, _ = _program(program)
+    body = _program(program)
     i32 = jnp.zeros(B, jnp.int32)
-    if program == "rows":
-        state = (tf.init_kv_slab(params, B, P + STEPS + 1, HEADS),
-                 jnp.zeros((B, P + STEPS + 1), jnp.int32), i32, i32)
-    else:
-        state = (tf.init_kv_pages(params, 1 + B * W, PAGE_LEN, HEADS),
-                 jnp.zeros((B, W), jnp.int32), i32, i32, i32)
+    state = (tf.init_kv_pages(params, 1 + B * W, PAGE_LEN, HEADS),
+             jnp.zeros((B, W), jnp.int32), i32, i32, i32)
     jaxpr = jax.make_jaxpr(body)(params, *state, *_knobs("mixed-knobs"))
     found = list(_sorts(jaxpr.jaxpr))
     assert len(found) == 2, "the sampler's two argsorts"

@@ -2,13 +2,14 @@
 
 The acceptance scenario (test_acceptance_continuous_batching) drives 36
 concurrent requests across two shape buckets through :class:`ServeEngine` on
-an injectable clock — under BOTH KV backends (the paged pool, the default,
-and the dense slot slab, the PR 4 control) — and asserts the subsystem's
-contracts: exactly one Result per request, per-row outputs bit-identical to
-the direct :func:`lm_generate` call on the unpadded prompt (greedy decode
-is composition-independent), deadline expiry surfaced (never silently
-dropped), and a bounded compile count (≤ 2 programs per bucket slab, ≤ 3
-paged — prefill-chunk + decode + the shared page-copy; the conftest
+an injectable clock — under BOTH decode-attention kernels (the gather
+program the CPU resolves to, and the Pallas kernel the chip resolves to,
+here in interpret mode) — and asserts the subsystem's contracts: exactly
+one Result per request, per-row outputs bit-identical to the direct
+:func:`lm_generate` call on the unpadded prompt (greedy decode is
+composition-independent), deadline expiry surfaced (never silently
+dropped), and a bounded compile count (≤ 3 programs per bucket:
+prefill-chunk + decode + the shared page-copy; the conftest
 ``compile_count`` fixture). Everything runs greedy/seeded on the CPU mesh,
 so it is fully deterministic. Paged-pool internals (alloc/refcount/COW/
 prefix cache/chunked-prefill resumability) live in tests/test_paging.py.
@@ -23,9 +24,8 @@ import pytest
 import jax
 
 from marlin_tpu.models import TransformerLM
-from marlin_tpu.models.transformer import (lm_decode_paged, lm_decode_rows,
-                                           lm_generate, lm_prefill_paged,
-                                           lm_prefill_slot)
+from marlin_tpu.models.transformer import (lm_decode_paged, lm_generate,
+                                           lm_prefill_paged)
 from marlin_tpu.serving import (
     STATUS_ERROR,
     STATUS_EXPIRED,
@@ -47,6 +47,9 @@ from marlin_tpu.utils.faults import FaultInjected, RaiseFault, Schedule
 HEADS = 2
 BUCKETS = ((8, 4), (16, 4))
 PAGE_LEN = 4  # small pages so every bucket is genuinely multi-page
+# the one fork the platform chooses (resolve_decode_kernel): the engine-level
+# contracts hold under the kernel the chip runs as under the CPU's
+KERNELS = pytest.mark.parametrize("kernel", ["gather", "pallas"])
 
 
 class FakeClock:
@@ -86,7 +89,7 @@ def _engine(params, **kw):
 def _reference_single(params, prompt, steps_req, heads=HEADS):
     """The row-level acceptance bar: lm_generate on the UNPADDED prompt at
     its own max_len — per-row greedy output must be bit-identical to it
-    regardless of bucket padding, slab width, or co-resident rows."""
+    regardless of bucket padding, slot width, or co-resident rows."""
     prompt = np.asarray(prompt, np.int32)
     return np.asarray(lm_generate(
         params, prompt, jax.random.key(0), heads=heads,
@@ -177,14 +180,13 @@ def test_batch_former_fifo_among_equal_priority():
 # ------------------------------------------------------------- engine layer
 
 
-@pytest.mark.parametrize("paged", [False, True], ids=["slab", "paged"])
-def test_acceptance_continuous_batching(params, paged):
+@KERNELS
+def test_acceptance_continuous_batching(params, kernel):
     """The tentpole acceptance: >= 32 concurrent requests, >= 2 buckets,
     deterministic clock — exactly one Result each, per-row bit-identical to
-    the direct lm_generate call on the unpadded prompt (both KV backends:
-    the paged pool and the dense-slab control), expired deadlines surfaced,
-    drain() completes in-flight work, and a bounded compile count (<= 2
-    programs per bucket slab; <= 2 per bucket paged plus the one shared
+    the direct lm_generate call on the unpadded prompt (both decode
+    kernels), expired deadlines surfaced, drain() completes in-flight work,
+    and a bounded compile count (<= 2 per bucket plus the one shared
     page-copy program — <= 3 total per bucket, for any knob mix)."""
     clock = FakeClock()
     rng = np.random.default_rng(4)
@@ -197,17 +199,12 @@ def test_acceptance_continuous_batching(params, paged):
     expired = [Request(prompt=[1, 2], steps=2, deadline=-1.0)
                for _ in range(4)]
 
-    if paged:
-        probes = [f._cache_size
-                  for f in (lm_prefill_paged, lm_decode_paged)]
-        per_bucket = 2  # + the shared page-copy program, counted below
-    else:
-        probes = [f._cache_size
-                  for f in (lm_prefill_slot, lm_decode_rows)]
-        per_bucket = 2
+    probes = [f._cache_size for f in (lm_prefill_paged, lm_decode_paged)]
+    per_bucket = 2  # the shared page-copy program is not bucket-shaped
     before = sum(p() for p in probes)
 
-    eng = _engine(params, clock=clock, paged=paged)
+    eng = _engine(params, clock=clock, decode_kernel=kernel)
+    assert eng._decode_kernel == kernel
     try:
         handles = {}
         lock = threading.Lock()
@@ -239,10 +236,9 @@ def test_acceptance_continuous_batching(params, paged):
 
         # compile count: bounded by the bucket set (measured BEFORE the
         # direct-call references below add their own programs)
-        if probes:
-            grew = sum(p() for p in probes) - before
-            assert grew <= per_bucket * len(BUCKETS), \
-                f"recompiled: {grew} programs for {BUCKETS}"
+        grew = sum(p() for p in probes) - before
+        assert grew <= per_bucket * len(BUCKETS), \
+            f"recompiled: {grew} programs for {BUCKETS}"
 
         # per-row bit-identical to the direct call: lm_generate on the
         # unpadded prompt itself — regardless of bucket padding, page
@@ -340,22 +336,6 @@ def test_close_retires_queued_with_shutting_down(params):
     assert r.status == STATUS_SHUTTING_DOWN
 
 
-def test_serve_step_fault_fails_request_and_engine_recovers(params):
-    """Chaos: a serve.step fault kills one slab prefill mid-flight — the
-    request gets an error Result (never dropped), and the engine keeps
-    serving (the paged analog, serve.prefill, lives in test_paging.py)."""
-    with _engine(params, paged=False) as eng:
-        with faults.injected("serve.step", RaiseFault(times=1)):
-            bad = eng.submit(Request(prompt=[1, 2], steps=2))
-            r = bad.result(timeout=60)
-            assert r.status == STATUS_ERROR and "FaultInjected" in r.reason
-        good = eng.submit(Request(prompt=[1, 2], steps=2))
-        assert good.result(timeout=60).status == STATUS_OK
-        snap = eng.metrics.snapshot()
-        assert snap["errors"] == 1 and snap["completed"] == 1
-    assert eng.pending() == 0
-
-
 def test_serve_enqueue_fault_propagates_to_caller(params):
     with _engine(params, start=False) as eng:
         with faults.injected("serve.enqueue", RaiseFault(times=1)):
@@ -421,13 +401,13 @@ def test_priority_orders_dispatch(params, tmp_path):
     assert set(order[:4]) == high_rids, order
 
 
-@pytest.mark.parametrize("paged", [False, True], ids=["slab", "paged"])
-def test_warmup_then_traffic_compiles_nothing(params, compile_count, paged):
+@KERNELS
+def test_warmup_then_traffic_compiles_nothing(params, compile_count, kernel):
     """warmup() pays every bucket's compile up front — the prefill/decode
-    pair per bucket (plus the shared page-copy program paged) — and
-    traffic afterwards adds ZERO XLA compiles (the promoted compile-bound
-    guard from tests/conftest.py)."""
-    with _engine(params, paged=paged) as eng:
+    pair per bucket plus the shared page-copy program — and traffic
+    afterwards adds ZERO XLA compiles (the promoted compile-bound guard
+    from tests/conftest.py)."""
+    with _engine(params, decode_kernel=kernel) as eng:
         assert eng.warmup() == len(BUCKETS)
         with compile_count() as c:
             hs = [eng.submit(Request(prompt=[1] * n, steps=2))
@@ -486,17 +466,19 @@ def test_drain_vs_concurrent_submit_race(params):
     assert eng._queue.bytes_in_flight == 0
 
 
-# ------------------------- row-level scheduling (paged default backend)
+# ------------------------------------------------- row-level scheduling
 
 
-def test_rowlevel_step_events_and_slot_refill(params, tmp_path):
-    """The row-level guarantee the gang loop cannot give: a finished row's
-    slot is refilled ON THE NEXT STEP. Asserted via the per-step occupancy
+@KERNELS
+def test_rowlevel_step_events_and_slot_refill(params, tmp_path, kernel):
+    """The row-level guarantee: a finished row's slot is refilled ON THE
+    NEXT STEP. Asserted via the per-step occupancy
     event stream (no sleeps): 3 requests through a 2-slot pool complete in
     2 full-occupancy decode steps — only possible if the slot freed by the
     short row hosts the queued row immediately."""
     log = EventLog(str(tmp_path / "serve.jsonl"))
-    eng = _engine(params, max_batch=2, log=log, start=False)
+    eng = _engine(params, max_batch=2, log=log, start=False,
+                  decode_kernel=kernel)
     try:
         a = eng.submit(Request(prompt=[1, 2, 3], steps=3))
         b = eng.submit(Request(prompt=[4, 5], steps=2))
@@ -597,7 +579,7 @@ def test_rowlevel_sampled_replay_is_composition_independent(params):
 
 
 def test_rowlevel_gqa_bit_identical(params):
-    """GQA (kv_heads < heads) through the row-level engine: the slab shape
+    """GQA (kv_heads < heads) through the row-level engine: the page shape
     derives kv_heads from the params, ragged lengths decode from their own
     positions, and per-row output stays bit-identical to lm_generate."""
     gqa = TransformerLM(vocab=32, d_model=16, heads=4, layers=2, kv_heads=2,
@@ -616,11 +598,12 @@ def test_rowlevel_gqa_bit_identical(params):
             assert res.tokens.tolist() == ref.tolist()
 
 
-def test_rowlevel_decode_step_fault_fails_only_live_rows(params):
+@KERNELS
+def test_rowlevel_decode_step_fault_fails_only_live_rows(params, kernel):
     """Chaos: a serve.decode_step fault fails ONLY that step's live rows
-    with error Results; queued requests still serve afterwards and the slot
+    with error Results; queued requests still serve afterwards and the page
     pool stays consistent (all slots free, budget fully released)."""
-    eng = _engine(params, max_batch=2, start=False)
+    eng = _engine(params, max_batch=2, start=False, decode_kernel=kernel)
     try:
         live = [eng.submit(Request(prompt=[1, 2], steps=3))
                 for _ in range(2)]
@@ -642,29 +625,29 @@ def test_rowlevel_decode_step_fault_fails_only_live_rows(params):
     assert eng._queue.bytes_in_flight == 0
 
 
-@pytest.mark.parametrize("paged", [False, True], ids=["slab", "paged"])
-def test_expiring_burst_releases_admission_budget(params, paged):
+@KERNELS
+def test_expiring_burst_releases_admission_budget(params, kernel):
     """Regression (admission accounting): a burst of requests that all
     expire — some at submit, some at dispatch — must release every byte of
     the in-flight KV budget on retirement, or admission wedges forever.
-    The paged leg charges in PAGE units (request_pages x kv_page_bytes) —
-    the page-reservation mirror of the PR 4 byte-unit regression."""
-    clock = FakeClock()
-    if paged:
-        from marlin_tpu.models.planner import kv_page_bytes, request_pages
+    The charge is in PAGE units (request_pages x kv_page_bytes), at the page
+    length the kernel's block shape rounds the engine's to."""
+    from marlin_tpu.models.planner import kv_page_bytes, request_pages
+    from marlin_tpu.ops.paged_attention import align_page_len
 
-        unit = (request_pages(2, 2, PAGE_LEN)
-                * kv_page_bytes(params, HEADS, PAGE_LEN))
-    else:
-        unit = bucket_kv_bytes(params, HEADS, (8, 4))
-    eng = _engine(params, clock=clock, start=False, paged=paged,
+    clock = FakeClock()
+    page_len = align_page_len(PAGE_LEN) if kernel == "pallas" else PAGE_LEN
+    unit = (request_pages(2, 2, page_len)
+            * kv_page_bytes(params, HEADS, page_len))
+    eng = _engine(params, clock=clock, start=False, decode_kernel=kernel,
                   hbm_budget_bytes=10 * unit)
     try:
         at_submit = [eng.submit(Request(prompt=[1, 2], steps=2,
                                         deadline=-1.0)) for _ in range(3)]
         at_dispatch = [eng.submit(Request(prompt=[1, 2], steps=2,
                                           deadline=5.0)) for _ in range(6)]
-        assert eng._queue.bytes_in_flight > 0
+        assert eng._page_len == page_len
+        assert eng._queue.bytes_in_flight == 6 * unit
         clock.advance(10.0)
         eng.start()
         for h in at_submit + at_dispatch:
@@ -692,8 +675,8 @@ def test_crash_retry_releases_admission_budget_exactly_once(params):
     its one admission reservation — never double-charged by the re-queue,
     and fully released on its final retirement whichever attempt serves
     it. Covers the decode-fault retry and the exhausted-budget error.
-    Runs the (default) paged backend, so the reservation under test is the
-    page-unit charge carried across attempts."""
+    The reservation under test is the page-unit charge carried across
+    attempts."""
     from marlin_tpu.models.planner import kv_page_bytes, request_pages
 
     cost = (request_pages(2, 3, PAGE_LEN)

@@ -515,78 +515,6 @@ def test_gqa_batched_decode_ragged(mesh):
         assert got == single(r).tolist(), (b, got, single(r).tolist())
 
 
-def test_gqa_slab_prefill_decode_rows(mesh):
-    """The row-level serving programs under GQA: the slab derives kv_heads
-    from the params shapes, ragged rows prefill into arbitrary slots, decode
-    from their own positions, and a mid-stream refill (retire one row,
-    prefill a new prompt into its slot while neighbors keep decoding) stays
-    bit-identical to lm_generate per row."""
-    import jax
-    import jax.numpy as jnp
-
-    from marlin_tpu.models.transformer import (init_kv_slab, lm_decode_rows,
-                                               lm_prefill_slot)
-
-    lm = TransformerLM(vocab=32, d_model=16, heads=4, layers=2, kv_heads=2,
-                       seed=21)
-    p = lm.init_params()
-    P, S, B = 8, 4, 3
-    max_len = P + S
-    caches = init_kv_slab(p, B, max_len, 4)
-    assert caches["l0"][0].shape == (B, max_len, 2, 4)  # kv_heads=2, dh=4
-    tokens = jnp.zeros((B, max_len), jnp.int32)
-
-    def single(prompt, steps):
-        return np.asarray(lm_generate(p, np.asarray(prompt, np.int32),
-                                      jax.random.key(0), heads=4,
-                                      max_len=len(prompt) + steps,
-                                      steps=steps))
-
-    def pad(pr):
-        out = np.zeros(P, np.int32)
-        out[: len(pr)] = pr
-        return out
-
-    pos = np.zeros(B, np.int32)
-    done = np.zeros(B, np.int32)
-    zeros = np.zeros(B, np.int32)
-    knobs = dict(heads=4, max_len=max_len)
-    sample_off = (np.zeros(B, np.uint32), np.zeros(B, np.float32),
-                  np.ones(B, np.float32), zeros)
-    # rows A (slot 0, 2 steps) and Bp (slot 2, 4 steps); slot 1 stays free
-    prA, prB, prC = [3, 1, 4], [2, 7, 1, 8, 2, 8], [9, 9, 5, 1, 2]
-    outs = {0: [], 2: []}
-    for slot, pr in ((0, prA), (2, prB)):
-        caches, tokens, first = lm_prefill_slot(
-            p, caches, tokens, slot, pad(pr), len(pr), **knobs)
-        outs[slot].append(int(first))
-        pos[slot], done[slot] = len(pr), 1
-    for _ in range(1):
-        caches, tokens, nxt = lm_decode_rows(
-            p, caches, tokens, pos, done, *sample_off, **knobs)
-        nxt = np.asarray(nxt)
-        for slot in (0, 2):
-            outs[slot].append(int(nxt[slot]))
-            pos[slot] += 1
-            done[slot] += 1
-    # retire A (2 emitted), refill its slot with C mid-stream for Bp
-    assert outs[0] == single(prA, 2)[len(prA):].tolist()
-    caches, tokens, first = lm_prefill_slot(
-        p, caches, tokens, 0, pad(prC), len(prC), **knobs)
-    outC = [int(first)]
-    pos[0], done[0] = len(prC), 1
-    for _ in range(2):
-        caches, tokens, nxt = lm_decode_rows(
-            p, caches, tokens, pos, done, *sample_off, **knobs)
-        nxt = np.asarray(nxt)
-        outC.append(int(nxt[0]))
-        outs[2].append(int(nxt[2]))
-        pos[[0, 2]] += 1
-        done[[0, 2]] += 1
-    assert outs[2] == single(prB, 4)[len(prB):].tolist()
-    assert outC == single(prC, 3)[len(prC):].tolist()
-
-
 def test_batched_decode_overflow_raises(mesh):
     """P + steps > max_len is a hard error (a silent clamp would corrupt the
     cache-position contract), mirroring the single-sequence path."""

@@ -4,7 +4,7 @@ the call.
 ``donate_argnums`` hands the argument's buffer to XLA — after the call the
 Python reference is a deleted array, and touching it raises (or, worse,
 on some paths silently aliases freed memory). The repo's donated programs
-are the serving KV-slab updaters (``_lm_prefill_slot_jit`` etc.,
+are the serving KV-slab updaters (``_lm_prefill_paged_jit`` etc.,
 models/transformer.py); the safe idiom is ``pool.pages =
 _lm_decode_paged_jit(params, pool.pages, ...)`` — the donated reference is
 overwritten by the very statement that consumes it.

@@ -97,74 +97,6 @@ def moe_train_step(seq: int, mesh) -> dict:
     return lct_train_step(seq, mesh, n_experts=8)
 
 
-def serve_bucket_report() -> dict:
-    """Predicted vs planner-estimated serving memory, one table: for each
-    bucket of the bench-serve model (bench_all config_serve: d128/h8/L4/
-    v256, row-level), the TPU compiler's own ``memory_analysis()`` peak
-    (``aot_compile_buckets`` — the real prefill + decode-step programs on a
-    compile-only v5e topology) next to the planner's slab arithmetic
-    (``bucket_kv_bytes * max_batch`` — what the admission gate charges) and
-    the usable-HBM budget both are sized against. Where the two columns
-    disagree, the compiler wins (round-4 verdict #2); the planner's number
-    is what admission will *enforce*, so a planner underestimate here is an
-    OOM waiting for traffic. Each row also records the measured
-    ``peak_planner_ratio`` — planner honesty in one number; past 2x,
-    ``aot_compile_buckets`` itself warns (serving.planner_ratio_warning).
-
-    The ``calibration`` column is that ratio clamped to the admission
-    multiplier range ([1, 32]): with ``serve_admission_calibration`` on,
-    the engine multiplies its per-bucket admission charge by exactly this
-    number (obs/memledger.admission_ratio reads it back through
-    models/planner.bucket_calibration, keyed on ``program_key`` /
-    ``program_key_slab`` so only the program it was measured for can
-    inherit it), which is what brings the calibrated estimate within the
-    acceptance band of the compiler-measured peak instead of 4-5x under."""
-    from marlin_tpu import get_config
-    from marlin_tpu.serving import aot_compile_buckets, bucket_kv_bytes
-    from marlin_tpu.serving.batcher import bucket_program_key
-    from marlin_tpu.serving.kvpool import paged_program_key
-
-    heads, max_batch = 8, 8
-    buckets = ((64, 32), (256, 32))
-    lm = TransformerLM(vocab=256, d_model=128, heads=heads, layers=4, seed=0)
-    params = lm.init_params()
-    t0 = time.time()
-    compiled = aot_compile_buckets(params, heads, buckets, max_batch)
-    budget = _usable_budget()
-    page_len = get_config().serve_page_len
-    out = {"model": "d128/h8/L4/v256 (bench_all config_serve)",
-           "max_batch": max_batch, "usable_hbm_budget_bytes": budget,
-           "compile_s": round(time.time() - t0, 1), "buckets": {}}
-    # steady-state residency sums over buckets (the engine never frees a
-    # slab); program peak is per dispatched bucket
-    slab_total = 0
-    print(f"  {'bucket':>10} {'compiler peak':>14} {'planner slab':>13} "
-          f"{'peak/plan':>10} {'calib':>6} {'of budget':>10}")
-    for b in buckets:
-        slab = bucket_kv_bytes(params, heads, b, batch=max_batch)
-        slab_total += slab
-        peak = compiled[b]
-        ratio = round(peak / slab, 3) if slab else None
-        calib = min(max(ratio, 1.0), 32.0) if ratio else None
-        out["buckets"][f"{b[0]}x{b[1]}"] = {
-            "compiler_peak_bytes": int(peak),
-            "planner_slab_bytes": int(slab),
-            "peak_planner_ratio": ratio,
-            "calibration": calib,
-            "calibrated_bytes": int(slab * calib) if calib else None,
-            "program_key": paged_program_key(params, b, max_batch,
-                                             page_len),
-            "program_key_slab": bucket_program_key(params, b, max_batch),
-            "peak_frac_of_budget": round(peak / budget, 4),
-        }
-        print(f"  {b[0]:>7}x{b[1]:<2} {peak:>14} {slab:>13} "
-              f"{peak / slab if slab else 0:>10.2f} {calib or 0:>6.2f} "
-              f"{peak / budget:>9.2%}")
-    out["planner_slab_total_bytes"] = int(slab_total)
-    out["fits_usable_hbm"] = slab_total + max(compiled.values()) < budget
-    return out
-
-
 def attn_forward(seq: int, mesh) -> dict:
     """AOT-compile the attn_long flash forward (d=128 head)."""
     rep = NamedSharding(mesh, P())
@@ -252,17 +184,6 @@ def main(seqs):
         report["lct_long_4chip"][label] = r = _try(
             lambda s, m: lct_train_step(s, m, compute_dtype=cd), seq, mesh4)
         print(f"  {_fmt(r)} (per chip)", flush=True)
-
-    # serving buckets: compiler-predicted peak vs the planner's admission
-    # arithmetic, next to the same usable-HBM budget (one table)
-    print("[aot] serve_buckets (bench-serve model) ...", flush=True)
-    try:
-        report["serve_buckets"] = serve_bucket_report()
-    except Exception as e:
-        report["serve_buckets"] = {
-            "error": str(e).split("\n")[0][:200]}
-        print(f"  serve_buckets failed: {report['serve_buckets']['error']}",
-              flush=True)
 
     with open(_REPORT_PATH, "w") as f:
         json.dump(report, f, indent=2)

@@ -800,7 +800,7 @@ def _pick_token_rows(temperature, top_p, top_k, logits, seeds, steps_done):
 #                     the C/page_len pages it covers. Resumable — a long
 #                     prompt prefills across worker iterations, bounding how
 #                     long any one iteration is away from decode.
-#   lm_decode_paged   one token for every row of a bucket: per-row block-
+#   lm_decode_paged   one token for every row of a call: per-row block-
 #                     table gather of the paged context, the SAME
 #                     _decode_step math as lm_generate (greedy stays
 #                     bit-identical to it), and a scatter of the one cache
@@ -1014,7 +1014,7 @@ def lm_decode_paged(params, pages, tables, positions, cur_tokens,
                     heads: int, page_len: int,
                     compute_dtype: str | None = None,
                     moe: tuple | None = None, kernel: str | None = None):
-    """One decode step for every row of a bucket over the paged pool.
+    """One decode step for every row of a call over the paged pool.
 
     ``pages`` is the pool slab (DONATED). ``tables`` is (B, W) int32 block
     tables — pass an all-dummy (zero) row for every slot that is free or
@@ -1042,7 +1042,7 @@ def lm_decode_paged(params, pages, tables, positions, cur_tokens,
       ``page_len`` a multiple of 8). Greedy token streams match the gather
       path (logits agree to ~ulp — online softmax reassociates).
 
-    Returns ``(pages, next_tokens)``. One compile per (B, W) bucket shape
+    Returns ``(pages, next_tokens)``. One compile per (B, W) table shape
     per backend. With a :class:`~.hybrid.ModelSpec` for ``heads``,
     ``tables`` is ``(global tables, window rings)`` and the result is
     :func:`.hybrid.decode_paged`'s ``(pages, next_tokens, counts,

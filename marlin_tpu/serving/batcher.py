@@ -10,8 +10,10 @@ compile. The discipline here:
   retire at their *requested* steps (the bucket only sizes the cache
   extent).
 - **Fixed slot width** — every bucket's row set is exactly ``max_batch``
-  wide (free rows run masked-harmless dummies), so B never varies and the
-  compile count is bounded by the bucket set, not the traffic pattern.
+  wide, and so is the one decode call the live rows of all buckets share
+  (the rows it does not fill run masked-harmless dummies), so B never
+  varies and the compile count is bounded by the bucket set (a prefill
+  program each, ONE decode program), not the traffic pattern.
 - **Claim queues** — :class:`BatchFormer` keeps one priority-ordered FIFO
   per bucket; :meth:`BatchFormer.take_for_bucket` hands freed rows the best
   pending request immediately (prefill-on-admit — higher ``priority``
@@ -22,8 +24,9 @@ compile. The discipline here:
   fallback.
 
 A bucket's row state (block tables, cursors, sampling vectors) is
-:class:`~.kvpool.PagedGroup`; warm-up and cost capture of a bucket's
-programs are :func:`~.kvpool.warmup_paged` and
+:class:`~.kvpool.PagedGroup`; :func:`~.kvpool.decode_inputs` packs the live
+rows of every bucket's group into one decode call; warm-up and cost capture
+of the programs are :func:`~.kvpool.warmup_paged` and
 :func:`~.kvpool.capture_paged_costs`. The pool sizes by page arithmetic:
 ``models/planner.kv_page_bytes`` × ``serve_num_pages`` is its steady-state
 footprint, whatever the bucket set.

@@ -25,13 +25,17 @@ worker iteration:
     may share the budget; a long prompt takes one chunk and resumes next
     iteration; a row's final chunk emits its first token — real TTFT)
     →  retire rows that emitted ``eos``, hit their step budget, or
-    expired  →  run ONE decode step per bucket over its live rows  →
-    repeat
+    expired  →  run ONE decode step over the live rows of EVERY bucket,
+    packed into one call of the one decode program  →  repeat
 
 so one long prompt can never monopolize an iteration — decode steps
-interleave between its chunks, bounding TTFT for everyone else. ≤ 3
-compiled programs per bucket (chunked prefill + decode step, plus one
-pool-wide page-copy program), for ANY per-row mix of sampling knobs.
+interleave between its chunks, bounding TTFT for everyone else. Rows are
+kept per bucket (admission, pages, chunked prefill), but a decode step
+reads the weights once: the live rows of all buckets share a call of
+``max_batch`` rows over the widest bucket's table (more calls of the same
+program only where more than ``max_batch`` rows are live). One compiled
+chunked-prefill program per bucket, plus one decode program and one
+pool-wide page-copy program, for ANY per-row mix of sampling knobs.
 
 The invariants: exactly one Result per request, per-row greedy output
 bit-identical to :func:`~marlin_tpu.models.transformer.lm_generate` on the
@@ -62,7 +66,7 @@ prefix) is joined before either returns. Chaos hooks (utils/faults.py):
 prefill CHUNK — a fault fails/retries that one request and the pool stays
 consistent (the chunk cursor makes prefill resumable, so a retry re-runs
 the prompt from its shared prefix); ``serve.decode_step`` fires before
-each decode step — a fault fails/retries only that step's live rows. The
+each decode call — a fault fails/retries only the rows that call carried. The
 engine keeps serving after any of them; if a failed donated call consumed
 the page slab, every resident row fails/retries and the pool is rebuilt
 zeroed — the same contract worker-crash recovery gives it (supervisor.py:
@@ -96,8 +100,8 @@ from ..utils.tracing import annotate
 from .batcher import BatchFormer, bucket_kv_bytes, normalize_buckets
 from .kvpool import (PagedGroup, PagedKVPool, PagePoolExhausted,
                      auto_num_pages, auto_window_pages,
-                     capture_paged_costs, group_chunk, paged_program_key,
-                     warmup_paged)
+                     capture_paged_costs, decode_inputs, decode_pages,
+                     group_chunk, paged_program_key, warmup_paged)
 from .metrics import ServeMetrics
 from .programs import PagedLMProgram, ProgramRowSet
 from .request import (SHED_REASON_PREFIX, STATUS_ERROR, STATUS_EXPIRED,
@@ -117,6 +121,19 @@ def _program_result(out) -> tuple:
 def _bucket_tag(bucket) -> str:
     """A bucket as a span field: ``256x128`` (``als x 8`` joins alike)."""
     return "x".join(str(b) for b in bucket)
+
+
+def _call_tag(call) -> str:
+    """The buckets one decode call carries (a list of ``(group, slots)``),
+    as a span field: ``256x128+768x256`` (the profiler ends a field's value
+    at a comma)."""
+    return "+".join(_bucket_tag(group.bucket) for group, _ in call)
+
+
+def _call_rows(call) -> list:
+    """``(group, slot)`` of every row a decode call carries, in the call's
+    row order."""
+    return [(group, i) for group, slots in call for i in slots]
 
 
 class MigrationError(RuntimeError):
@@ -257,6 +274,10 @@ class ServeEngine:
             from ..ops.paged_attention import align_page_len
 
             self._page_len = align_page_len(self._page_len)
+        #: the one decode program has the widest bucket's shape: its table
+        #: width, and the bucket under whose key its calls are timed
+        self._decode_pages = decode_pages(self.buckets, self._page_len)
+        self._decode_bucket = max(self.buckets, key=sum)
         self._prefill_chunk = int(cfg.serve_prefill_chunk
                                   if prefill_chunk is None else prefill_chunk)
         self._prefix_cache = bool(cfg.serve_prefix_cache
@@ -465,10 +486,10 @@ class ServeEngine:
         self._thread.start()
 
     def warmup(self) -> int:
-        """Compile every bucket's programs before traffic: the chunked
-        prefill + decode pair per bucket plus the shared page-copy program
-        (kvpool.warmup_paged, against THIS engine's pool — program identity
-        includes the slab shape). Call before the first submit — warmup
+        """Compile the engine's programs before traffic: every bucket's
+        chunked prefill, the one decode program and the shared page-copy
+        program (kvpool.warmup_paged, against THIS engine's pool — program
+        identity includes the slab shape). Call before the first submit — warmup
         drives the live pool."""
         self._warming = True
         try:
@@ -1714,9 +1735,10 @@ class ServeEngine:
         """The paged slot-step loop: each iteration refills freed rows from
         the queue (page allocation + prefix match — host-side), runs
         prefill chunks up to the ``serve_prefill_chunk`` TOKEN budget
-        (oldest rows first), then one decode step per bucket over its live
-        rows — chunked prefill interleaves with decode, so a long prompt
-        never monopolizes an iteration. ``pools`` maps bucket -> PagedGroup
+        (oldest rows first), then one decode step over the live rows of
+        every bucket, packed into one call (:meth:`_step_paged`) — chunked
+        prefill interleaves with decode, so a long prompt never monopolizes
+        an iteration. ``pools`` maps bucket -> PagedGroup
         over the engine's
         one shared :class:`PagedKVPool`; ``pf_queue`` is the FIFO of rows
         mid-prefill ((bucket, slot, rid) — rid guards against a retired
@@ -1901,13 +1923,17 @@ class ServeEngine:
                     group = pools[e.bucket] = PagedGroup(
                         e.bucket, self.max_batch, self._page_len,
                         self._prefill_chunk, ring=self._ring)
-                    # no-warmup path: the bucket's cost model still lands
-                    # with its first (lazy) compile
-                    capture_paged_costs(
-                        self.params, self.heads, e.bucket, self.max_batch,
-                        pool, self._prefill_chunk, self.compute_dtype,
-                        self.moe, key=self._prog_key(e.bucket),
-                        kernel=self._decode_kernel)
+                    # no-warmup path: the cost models of the bucket's
+                    # prefill and of the one decode program (the widest
+                    # bucket's shape, under its key) still land with their
+                    # first (lazy) compile
+                    for bucket in (e.bucket, self._decode_bucket):
+                        capture_paged_costs(
+                            self.params, self.heads, bucket, self.max_batch,
+                            pool, self._prefill_chunk, self.compute_dtype,
+                            self.moe, key=self._prog_key(bucket),
+                            kernel=self._decode_kernel,
+                            table_pages=self._decode_pages)
                 slot = group.free_slots()[0]
                 n = r.prompt.shape[0]
                 shared_len, spages = pool.match_prefix(r.prompt)
@@ -2080,98 +2106,142 @@ class ServeEngine:
                                pages_used=pool.used_count())
 
     def _step_paged(self, pool, pools) -> None:
-        """Retire expired resident rows, then run ONE decode step per
-        bucket over its live rows. All buckets' steps are dispatched before
-        any result is awaited — JAX dispatch is async, so bucket B's device
-        work overlaps the host round-trip for bucket A instead of
-        serializing behind it; non-live rows run the masked-harmless dummy
-        against page 0 so a prefilling neighbor's pages are never
-        scribbled."""
-        launched = []
+        """Retire expired resident rows, then run ONE decode step over the
+        live rows of EVERY bucket, packed into one call of the one decode
+        program (``max_batch`` rows, the widest bucket's table): the
+        weights are read once an iteration, whatever the number of buckets
+        that hold rows. Where the buckets together hold more live rows
+        than a call has (each may hold ``max_batch``), the step is
+        ``ceil(live / max_batch)`` calls of the same program, all
+        dispatched before any result is awaited — JAX dispatch is async,
+        so a later call's device work overlaps the host round-trip for an
+        earlier one instead of serializing behind it. A row that is not
+        live (free, or still prefilling) is in no call: the rows a call
+        does not fill run the masked-harmless dummy against page 0, so a
+        prefilling neighbor's pages are never scribbled."""
         with annotate("serve.decode") as span:
-            for bucket, group in list(pools.items()):
-                if isinstance(group, ProgramRowSet):
-                    continue  # the program lane: _step_program_rows
+            calls = self._pack_paged(pool, pools)
+            launched = []
+            for call in calls:
                 with annotate("serve.decode.dispatch",
-                              bucket=_bucket_tag(bucket)) as dispatch:
-                    launch = self._dispatch_paged(pool, pools, bucket,
-                                                  group, dispatch)
+                              bucket=_call_tag(call)) as dispatch:
+                    launch = self._dispatch_paged(pool, pools, call,
+                                                  dispatch)
                 if launch is not None:
                     launched.append(launch)
-            span.set_metadata(buckets=len(launched))
-            synced = 0.0  # perf_counter at the previous bucket's landing
-            for bucket, group, live, t0, nxt in launched:
-                tag = _bucket_tag(bucket)
+            span.set_metadata(
+                buckets=len({group.bucket for call in calls
+                             for group, _ in call}),
+                dispatches=len(launched))
+            synced = 0.0  # perf_counter at the previous call's landing
+            steps: dict = {}  # bucket -> [rows, seconds] landed, unannounced
+            for n, (call, t0, nxt) in enumerate(launched):
+                tag = _call_tag(call)
+                # the buckets whose last rows this call carries: their step
+                # records are due at its landing (a bucket's rows go on
+                # into the next call where a call is full)
+                later = {group.bucket for c, _, _ in launched[n + 1:]
+                         for group, _ in c}
+                due = [group.bucket for group, _ in call
+                       if group.bucket not in later]
                 try:
                     with annotate("serve.decode.sync", bucket=tag) as sync:
                         # analyze: ignore[host-sync] — THE one intentional
-                        # sync per decode step: the host must see the
-                        # emitted tokens to retire rows (all dispatches
-                        # above launched async first)
+                        # sync per decode call: the host must see the
+                        # emitted tokens to retire rows (every call above
+                        # launched async first)
                         nxt, counts = np.asarray(nxt[0]), nxt[1]  # sync
                         self._moe_counts(sync, counts)
                 except Exception as exc:
-                    self._fail_paged_bucket(pool, pools, bucket, exc)
+                    self._fail_paged_call(pool, pools, call, exc)
+                    self._record_steps(pool, steps, due)
                     continue
-                # the interval that is this bucket's alone: the device ran
-                # the earlier buckets' steps first, so time before their
-                # landing is theirs (summing whole dispatch-to-landing
-                # walls counted device time two and three times)
+                # the interval that is this call's alone: the device ran
+                # the earlier calls first, so time before their landing is
+                # theirs (summing whole dispatch-to-landing walls counted
+                # device time twice)
                 t0, synced = max(t0, synced), time.perf_counter()
                 with annotate("serve.decode.retire", bucket=tag) as retire:
                     retire.set_metadata(retired=self._land_paged(
-                        pool, pools, bucket, group, live, nxt, synced - t0))
+                        pool, pools, call, nxt, synced - t0, steps, due))
         self._live_rows = sum(len(g.live_slots()) for g in pools.values())
 
-    def _dispatch_paged(self, pool, pools, bucket, group, span):
-        """One bucket's half of :meth:`_step_paged` before the landing:
-        deadline sweep, copy-on-write gate, decode inputs, the async
-        ``lm_decode_paged`` call. Returns what the landing needs, or None
-        where nothing was dispatched. ``span`` (``serve.decode.dispatch``)
-        gets the work the call was given beside the work that is useful:
-        ``padded_rows`` x ``table_width`` pages against ``kv_tokens``, the
-        positions the live rows attend (each row's cache plus the entry
-        this step writes) and ``kv_pages``, the pages that hold them (the
-        grid steps of the decode kernel that compute; a spec's dispatch
-        has them per attention kind); and ``sampled_rows``, the live rows
-        with a temperature above 0 — at 0 the program took its argmax
-        branch and never sorted the vocabulary."""
+    def _pack_paged(self, pool, pools) -> list:
+        """The deadline sweep over every LM group, then this iteration's
+        decode calls: the live rows of all groups in bucket order, cut into
+        runs of at most ``max_batch``. A call is a list of ``(group,
+        slots)``; a bucket whose rows do not fit the call they begin in
+        goes on in the next."""
+        now = self._clock()
+        calls, room = [], 0
+        # (a ProgramRowSet is the program lane's: _step_program_rows)
+        for bucket in sorted(b for b, g in pools.items()
+                             if not isinstance(g, ProgramRowSet)):
+            group = pools[bucket]
+            for i in group.occupied_slots():
+                dl = group.entries[i].request.deadline
+                if dl is not None and dl <= now:
+                    self._retire_row_paged(
+                        pool, pools, bucket, i, STATUS_EXPIRED, now,
+                        reason=f"deadline {dl} passed mid-decode "
+                               f"(now {now})")
+            live = group.live_slots()
+            while live:
+                if not room:
+                    calls.append([])
+                    room = self.max_batch
+                take, live = live[:room], live[room:]
+                calls[-1].append((group, take))
+                room -= len(take)
+        return calls
+
+    def _dispatch_paged(self, pool, pools, call, span):
+        """One call's half of :meth:`_step_paged` before the landing: the
+        copy-on-write gate, the packed decode inputs
+        (:func:`.kvpool.decode_inputs`), the async ``lm_decode_paged``
+        call. Returns what the landing needs, or None where nothing was
+        dispatched. ``span`` (``serve.decode.dispatch``) gets the work the
+        call was given beside the work that is useful, over all the rows it
+        carries: ``padded_rows`` x ``table_width`` pages against
+        ``kv_tokens``, the positions the live rows attend (each row's cache
+        plus the entry this step writes) and ``kv_pages``, the pages that
+        hold them (the grid steps of the decode kernel that compute; a
+        spec's dispatch has them per attention kind); and ``sampled_rows``,
+        the live rows with a temperature above 0 — at 0 the program took
+        its argmax branch and never sorted the vocabulary."""
         from ..models.transformer import lm_decode_paged
 
-        now = self._clock()
-        for i in group.occupied_slots():
-            dl = group.entries[i].request.deadline
-            if dl is not None and dl <= now:
-                self._retire_row_paged(
-                    pool, pools, bucket, i, STATUS_EXPIRED, now,
-                    reason=f"deadline {dl} passed mid-decode "
-                           f"(now {now})")
-        live = group.live_slots()
-        span.set_metadata(rows=len(live))
-        if not live:
+        # an earlier call's failure may have consumed the slab, and every
+        # resident row went with the pool (_drop_paged_pool)
+        call = [(group, kept) for group, slots in call
+                if (kept := [i for i in slots
+                             if group.entries[i] is not None])]
+        rows = len(_call_rows(call))
+        span.set_metadata(rows=rows)
+        if not rows:
             return None
-        p, s = bucket
         try:
-            for i in live:  # COW gate on each row's write page
+            for group, i in _call_rows(call):
+                # COW gate on each row's write page
                 self._cow(pool, group, slot=i,
                           # analyze: ignore[host-sync] — host numpy
                           # block-table bookkeeping, not device data
                           table_idx=int(group.positions[i])
                           // self._page_len,
                           rid=group.entries[i].request.rid)
-            faults.fire("serve.decode_step", path=f"bucket-{p}x{s}")
+            faults.fire("serve.decode_step", path=f"bucket-{_call_tag(call)}")
             t0 = time.perf_counter()
-            tables, positions, cur, temperature = group.decode_inputs()
+            (tables, positions, cur, steps_done, seeds, temperature, top_p,
+             top_k) = decode_inputs(call, self.max_batch,
+                                    self._decode_pages, self._ring)
             out = lm_decode_paged(
-                self.params, pool.pages, tables, positions, cur,
-                group.steps_done, group.seeds, temperature,
-                group.top_p, group.top_k, heads=self.heads,
-                page_len=self._page_len,
-                compute_dtype=self.compute_dtype, moe=self.moe,
-                kernel=self._decode_kernel)
+                self.params, pool.pages, tables, positions, cur, steps_done,
+                seeds, temperature, top_p, top_k, heads=self.heads,
+                page_len=self._page_len, compute_dtype=self.compute_dtype,
+                moe=self.moe, kernel=self._decode_kernel)
             pages, *nxt = _program_result(out)  # (tokens, expert counts)
         except Exception as exc:
-            self._fail_paged_bucket(pool, pools, bucket, exc)
+            self._fail_paged_call(pool, pools, call, exc)
             return None
         pool.pages = pages
         if self._spec is not None:
@@ -2184,12 +2254,12 @@ class ServeEngine:
             # decode kernel's grid steps that compute, of the padded_rows x
             # table width it is given): every position so far; for a
             # spec's sliding layers, the window's
-            at = group.positions[live] // self._page_len
+            at = positions[:rows] // self._page_len
             if self._spec is None:
                 # analyze: ignore[host-sync] — host numpy bookkeeping
                 span.set_metadata(kv_pages=int((at + 1).sum()))
             else:
-                low = (np.maximum(group.positions[live]
+                low = (np.maximum(positions[:rows]
                                   - self._spec.window + 1, 0)
                        // self._page_len)
                 span.set_metadata(
@@ -2200,9 +2270,9 @@ class ServeEngine:
         span.set_metadata(padded_rows=self.max_batch,
                           table_width=tables.shape[1],
                           # analyze: ignore[host-sync] — host numpy
-                          kv_tokens=int(positions.sum()) + len(live),
+                          kv_tokens=int(positions.sum()) + rows,
                           sampled_rows=int((temperature > 0).sum()))
-        return bucket, group, live, t0, nxt
+        return call, t0, nxt
 
     @staticmethod
     def _moe_counts(span, counts) -> None:
@@ -2219,37 +2289,61 @@ class ServeEngine:
                               moe_local_assignments=local,
                               moe_experts_touched=touched)
 
-    def _land_paged(self, pool, pools, bucket, group, live, nxt,
-                    seconds: float) -> int:
-        """One bucket's half of :meth:`_step_paged` after its landing: the
-        step's records, the per-row bookkeeping, retirement. ``seconds`` is
-        the interval that belongs to this bucket alone. Returns the rows
-        retired."""
-        self.metrics.record_step(bucket, len(live), self.max_batch, seconds,
-                                 program_key=self._prog_key(bucket),
-                                 program="lm_decode_paged")
-        self.flight.record(
-            "step", bucket=list(bucket), rows=len(live), seconds=seconds,
-            queue_depth=self._queue.count, compiles=_compile_count(),
-            pages_used=pool.used_count())
+    def _land_paged(self, pool, pools, call, nxt, seconds: float,
+                    steps: dict, due) -> int:
+        """One call's half of :meth:`_step_paged` after its landing: its
+        rows and seconds go to their buckets' step records (the seconds,
+        the interval that belongs to this call alone, divided among the
+        buckets it carried by rows), the records that are ``due`` are
+        announced, then the per-row bookkeeping and retirement: row ``n``
+        of the call's tokens ``nxt`` is the ``n``-th row it carried.
+        Returns the rows retired."""
+        carried = _call_rows(call)
+        for group, slots in call:
+            got = steps.setdefault(group.bucket, [0, 0.0])
+            got[0] += len(slots)
+            got[1] += seconds * len(slots) / len(carried)
+        # the call's wall time joins the one decode program's cost model,
+        # which stands under the widest bucket's key (its shape)
+        perf.get_program_costs().observe(
+            "lm_decode_paged", self._prog_key(self._decode_bucket), seconds)
+        self._record_steps(pool, steps, due)
         now = self._clock()
         retired = 0
-        for i in live:
+        for n, (group, i) in enumerate(carried):
             if group.entries[i] is None:
-                continue  # expired between dispatch and landing
+                continue  # its pool was dropped under an earlier call
             group.positions[i] += 1
             group.steps_done[i] += 1
-            tok = int(nxt[i])
+            tok = int(nxt[n])
             group.cur_tok[i] = tok
             group.emitted[i].append(tok)
             r = group.entries[i].request
             if ((r.eos is not None and tok == r.eos)
                     # analyze: ignore[host-sync] — host numpy bookkeeping
                     or int(group.steps_done[i]) >= r.steps):
-                self._retire_row_paged(pool, pools, bucket, i,
+                self._retire_row_paged(pool, pools, group.bucket, i,
                                        STATUS_OK, now)
                 retired += 1
         return retired
+
+    def _record_steps(self, pool, steps: dict, due) -> None:
+        """Announce the ``due`` buckets' step records and drop them from
+        ``steps``: ONE ``step`` record per bucket that had live rows an
+        iteration, ``rows`` a token each — a caller that rebuilds a
+        request's token times from the records of its bucket (the
+        benchmark's sink) counts on it, so a bucket whose rows two calls
+        carried is announced once, at the later landing. A bucket none of
+        whose rows landed has no record."""
+        for bucket in due:
+            if bucket not in steps:
+                continue
+            rows, seconds = steps.pop(bucket)
+            self.metrics.record_step(bucket, rows, self.max_batch, seconds)
+            self.flight.record(
+                "step", bucket=list(bucket), rows=rows, seconds=seconds,
+                queue_depth=self._queue.count, compiles=_compile_count(),
+                pages_used=pool.used_count())
 
     def _retire_row_paged(self, pool, pools, bucket, slot: int,
                           status: str, now: float, reason: str = "") -> None:
@@ -2331,24 +2425,25 @@ class ServeEngine:
                                            total=self._num_pages - 1)
             self.metrics.record_pages(self._num_pages - 1, 0, 0)
 
-    def _fail_paged_bucket(self, pool, pools, bucket,
-                           exc: Exception) -> None:
-        """A paged decode step died: with the pool intact (an injected
-        fault raised before launch) only that step's live rows
-        fail/retry and their pages free; a consumed slab escalates to
-        :meth:`_drop_paged_pool`."""
-        group = pools.get(bucket)
-        if group is None or pool is not self._kvpool:
-            # an earlier bucket's failure in this same landing loop already
-            # escalated to _drop_paged_pool: every resident row (including
-            # this bucket's) was requeued/failed there — a second handling
-            # pass would KeyError on the cleared pools map
+    def _fail_paged_call(self, pool, pools, call, exc: Exception) -> None:
+        """A paged decode call died: with the pool intact (an injected
+        fault raised before launch) only the rows that call carried
+        fail/retry, in every bucket it carried, each once, and their pages
+        free; a consumed slab escalates to :meth:`_drop_paged_pool`."""
+        if pool is not self._kvpool:
+            # an earlier call's failure in this same step already escalated
+            # to _drop_paged_pool: every resident row (this call's too) was
+            # requeued/failed there — a second handling pass would find
+            # nothing of it on the cleared pools map
             return
+        carried = [(group, i) for group, i in _call_rows(call)
+                   if group.entries[i] is not None]
         reason = f"decode step failed: {type(exc).__name__}: {exc}"
         if memledger.is_oom_error(exc):
             memledger.dump_oom_forensics(reason)
-        self.flight.record("decode_fault", bucket=list(bucket),
-                           rows=len(group.live_slots()), error=reason,
+        self.flight.record("decode_fault",
+                           bucket=[list(group.bucket) for group, _ in call],
+                           rows=len(carried), error=reason,
                            queue_depth=self._queue.count,
                            compiles=_compile_count(),
                            pages_used=pool.used_count() if pool else 0)
@@ -2356,14 +2451,14 @@ class ServeEngine:
             self._drop_paged_pool(pool, pools, reason)
         else:
             now = self._clock()
-            for i in group.live_slots():
+            for group, i in carried:
                 e = group.entries[i]
                 if e.attempts_left():
                     pool.release_window(group.window_row_pages[i])
                     pool.release(group.release(i))
                     self._requeue(e, reason)
                 else:
-                    self._retire_row_paged(pool, pools, bucket, i,
+                    self._retire_row_paged(pool, pools, group.bucket, i,
                                            STATUS_ERROR, now, reason=reason)
             self._record_pages(pool)
         self._flight_dump("decode-step-failed")

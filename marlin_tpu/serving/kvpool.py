@@ -12,7 +12,10 @@ bookkeeping per row:
   covering its positions; the decode program gathers by table, the chunked
   prefill program scatters by table
   (:func:`~marlin_tpu.models.transformer.lm_decode_paged` /
-  :func:`~marlin_tpu.models.transformer.lm_prefill_paged`).
+  :func:`~marlin_tpu.models.transformer.lm_prefill_paged`). Rows are kept
+  per bucket (:class:`PagedGroup`), but ONE decode call carries the live
+  rows of every bucket (:func:`decode_inputs`): each row's table is laid
+  into the widest bucket's width, the rest of it the dummy page.
 - **Free-list allocation + refcounts** — a request allocates exactly
   :func:`~marlin_tpu.models.planner.request_pages` pages (what it can ever
   write); every retirement path releases them exactly once; page 0 is a
@@ -72,7 +75,8 @@ import numpy as np
 
 __all__ = ["PagedKVPool", "PagedGroup", "PagePoolExhausted",
            "MigrationCorruptError", "auto_num_pages", "paged_program_key",
-           "warmup_paged", "capture_paged_costs"]
+           "warmup_paged", "capture_paged_costs", "decode_inputs",
+           "decode_pages"]
 
 
 class PagePoolExhausted(RuntimeError):
@@ -816,7 +820,8 @@ class PagedKVPool:
 
 class PagedGroup:
     """Per-bucket row bookkeeping over a shared :class:`PagedKVPool`. Owns
-    the per-row vectors the decode program takes, each row's block table
+    the per-row vectors the decode program takes (:func:`decode_inputs`
+    gathers the live rows of every bucket's group), each row's block table
     and prefill cursor, and the host-side emitted-token stream (tokens
     never live on the device: the decode program takes ``cur_tokens`` and
     returns the next ones, so results are assembled host-side).
@@ -986,26 +991,54 @@ class PagedGroup:
             return self.tables[slot], self.window_tables[slot]
         return self.tables[slot]
 
-    # -------------------------------------------------------- decode inputs
 
-    def decode_inputs(self):
-        """(tables, positions, cur_tokens, temperature) with every non-live
-        row masked to the dummy table/position — a prefilling row's REAL
-        pages must never be scribbled by its dummy decode write — and to
-        temperature 0: its token is discarded, and the decode program sorts
-        the vocabulary for the whole bucket when any row it is handed
-        samples, so a sampled request still in prefill must not switch that
-        on for live rows that are all greedy."""
-        live = np.zeros(self.width, bool)
-        live[self.live_slots()] = True
-        tables = np.where(live[:, None],
-                          self.tables[:, :self.pages_per_row], 0)
-        if self.ring is not None:  # a spec's programs take both classes
-            tables = (tables, np.where(live[:, None], self.window_tables, 0))
-        positions = np.where(live, self.positions, 0)
-        cur = np.where(live, self.cur_tok, 0)
-        temperature = np.where(live, self.temperature, np.float32(0.0))
-        return tables, positions, cur, temperature
+def decode_pages(buckets, page_len: int) -> int:
+    """The table width of an engine's one decode program: the pages that
+    cover the WIDEST bucket's extent. Rows of a narrower bucket ride the
+    same call, their tables ending in the dummy page (the decode kernel
+    neither fetches nor computes a page past a row's length)."""
+    return max(-(-(p + s) // page_len) for p, s in buckets)
+
+
+def decode_inputs(rows, width: int, pages_per_row: int,
+                  ring: int | None = None):
+    """What one call of the decode program takes after the slab, for the
+    LIVE rows ``rows`` — ``(group, slots)`` runs in call order, of any
+    buckets' groups, at most ``width`` rows in all, packed from row 0:
+    ``(tables, positions, cur_tokens, steps_done, seeds, temperature, top_p,
+    top_k)``, each ``width`` rows. A row's block table is laid into
+    ``pages_per_row`` columns (:func:`decode_pages`); what lies past its own
+    bucket's extent is the dummy page 0. The rows no live row fills are the
+    dummy row: table and position 0 — a prefilling row's REAL pages must
+    never be scribbled by a dummy decode write, so such a row is simply not
+    handed in — and temperature 0: the decode program sorts the vocabulary
+    for the whole call when any row it is handed samples. With ``ring`` (a
+    ModelSpec's programs take both classes of page) ``tables`` is
+    ``(tables, window rings)``."""
+    tables = np.zeros((width, pages_per_row), np.int32)
+    rings = np.zeros((width, ring or 0), np.int32)
+    positions = np.zeros(width, np.int32)
+    cur = np.zeros(width, np.int32)
+    steps_done = np.zeros(width, np.int32)
+    seeds = np.zeros(width, np.uint32)
+    temperature = np.zeros(width, np.float32)
+    top_p = np.ones(width, np.float32)
+    top_k = np.zeros(width, np.int32)
+    at = 0
+    for g, slots in rows:
+        to = slice(at, at + len(slots))
+        at += len(slots)
+        tables[to, :g.pages_per_row] = g.tables[slots, :g.pages_per_row]
+        rings[to] = g.window_tables[slots]
+        positions[to] = g.positions[slots]
+        cur[to] = g.cur_tok[slots]
+        steps_done[to] = g.steps_done[slots]
+        seeds[to] = g.seeds[slots]
+        temperature[to] = g.temperature[slots]
+        top_p[to] = g.top_p[slots]
+        top_k[to] = g.top_k[slots]
+    return ((tables, rings) if ring is not None else tables, positions, cur,
+            steps_done, seeds, temperature, top_p, top_k)
 
 
 # ---------------------------------------------------------------- programs
@@ -1032,11 +1065,17 @@ def capture_paged_costs(params: dict, heads: int, bucket, max_batch: int,
                         compute_dtype: str | None = None,
                         moe: tuple | None = None,
                         key: str | None = None,
-                        kernel: str = "gather") -> None:
-    """Capture the XLA cost models of a bucket's paged program pair into
-    the process ProgramCosts registry — trace + lower only (no backend
-    compile; the bucket's real compile already happened or is about to
-    through the jit cache). Gated per (program, key) on ATTEMPTED, not
+                        kernel: str = "gather",
+                        table_pages: int | None = None) -> None:
+    """Capture the XLA cost models of a bucket's paged programs into the
+    process ProgramCosts registry under the bucket's key: its chunked
+    prefill, and — where the bucket is as wide as the engine's ONE decode
+    program, whose table has ``table_pages`` columns (:func:`decode_pages`;
+    None: this bucket's own extent, an engine of one bucket) — that decode
+    program, whose shape is the widest bucket's and whose calls are timed
+    under its key. Trace + lower only (no backend compile; the real compile
+    already happened or is about to through the jit cache). Gated per
+    (program, key) on ATTEMPTED, not
     succeeded, so repeated calls — the engine invokes this on every group
     creation, with its cached ``key`` — cost two dict lookups after the
     first, and a backend without ``cost_analysis()`` does not re-pay the
@@ -1058,7 +1097,10 @@ def capture_paged_costs(params: dict, heads: int, bucket, max_batch: int,
     if key is None:
         key = paged_program_key(params, bucket, max_batch, pool.page_len,
                                 compute_dtype, kernel)
-    programs = ("lm_prefill_paged", "lm_decode_paged")
+    own_pages = decode_pages([bucket], pool.page_len)
+    table_pages = table_pages or own_pages
+    programs = ("lm_prefill_paged",) + (
+        ("lm_decode_paged",) if own_pages == table_pages else ())
     if all(costs.tried(name, key) for name in programs):
         return
     if not isinstance(heads, int):
@@ -1088,14 +1130,16 @@ def capture_paged_costs(params: dict, heads: int, bucket, max_batch: int,
             st((), jnp.float32), st(()), heads=heads,
             page_len=pool.page_len, compute_dtype=compute_dtype,
             moe=moe).lower()
+        costs.capture("lm_prefill_paged", key, lowered=pre)
+        if "lm_decode_paged" not in programs:
+            return
         dec = _lm_decode_paged_jit.trace(
-            sds(params), pages, st((max_batch, g.pages_per_row)),
+            sds(params), pages, st((max_batch, table_pages)),
             st((max_batch,)), st((max_batch,)), st((max_batch,)),
             st((max_batch,), jnp.uint32), st((max_batch,), jnp.float32),
             st((max_batch,), jnp.float32), st((max_batch,)), heads=heads,
             page_len=pool.page_len, compute_dtype=compute_dtype,
             moe=moe, kernel=kernel).lower()
-        costs.capture("lm_prefill_paged", key, lowered=pre)
         dec_cost = None
         if kernel == "pallas":
             from ..ops.pallas_kernels import _interpret
@@ -1107,7 +1151,7 @@ def capture_paged_costs(params: dict, heads: int, bucket, max_batch: int,
                 kvh = params["l0"]["wk"].shape[1] // dh
                 slab = pages["l0"][0]
                 kc = paged_attention_cost(
-                    max_batch, g.pages_per_row, pool.page_len, kvh,
+                    max_batch, table_pages, pool.page_len, kvh,
                     heads // kvh, dh, jnp.dtype(slab.dtype).itemsize)
                 dec_cost = dict(dec.cost_analysis() or {})
                 n = _n_layers(params)
@@ -1125,11 +1169,13 @@ def warmup_paged(params: dict, heads: int, buckets, max_batch: int,
                  compute_dtype: str | None = None,
                  moe: tuple | None = None, kernel: str = "gather") -> int:
     """Compile (and execute once, against dummy page 0) every bucket's
-    paged program pair plus the one shared page-copy program — ≤ 3
-    programs per bucket, the whole paged compile story. Runs against the
-    engine's REAL pool (program identity includes the slab shape, so a
-    throwaway pool would compile programs traffic never hits); all dummy
-    writes land in page 0. Returns the buckets warmed."""
+    chunked-prefill program, the ONE decode program every bucket's rows ride
+    (``max_batch`` rows, the widest bucket's table: :func:`decode_pages`)
+    and the one shared page-copy program — a program per bucket and two,
+    the whole paged compile story. Runs against the engine's REAL pool
+    (program identity includes the slab shape, so a throwaway pool would
+    compile programs traffic never hits); all dummy writes land in page 0.
+    Returns the buckets warmed."""
     import jax
 
     from ..models.transformer import (kv_page_copy, lm_decode_paged,
@@ -1137,17 +1183,14 @@ def warmup_paged(params: dict, heads: int, buckets, max_batch: int,
     from .batcher import normalize_buckets
 
     buckets = normalize_buckets(buckets)
+    ring = None if isinstance(heads, int) else pool.ring
     groups = [PagedGroup(bucket, max_batch, pool.page_len, prefill_chunk,
-                         ring=None if isinstance(heads, int) else pool.ring)
-              for bucket in buckets]
-    w = max_batch
-    rows = (np.zeros(w, np.int32), np.zeros(w, np.int32),
-            np.zeros(w, np.int32), np.zeros(w, np.uint32),
-            np.zeros(w, np.float32), np.ones(w, np.float32),
-            np.zeros(w, np.int32))
+                         ring=ring) for bucket in buckets]
+    table_pages = decode_pages(buckets, pool.page_len)
+    dummy = decode_inputs((), max_batch, table_pages, ring)
     if not isinstance(heads, int):
         # a spec's programs take the compiler half a minute each and leave
-        # most cores idle: all buckets' at once, then the calls below run
+        # most cores idle: all of them at once, then the calls below run
         # what is compiled
         from ..models import hybrid
         from ..models.transformer import resolve_decode_kernel
@@ -1156,21 +1199,20 @@ def warmup_paged(params: dict, heads: int, buckets, max_batch: int,
             [(params, pool.pages, g.prefill_tables(0),
               np.zeros(g.chunk, np.int32), 0, 1, heads, pool.page_len)
              for g in groups],
-            [(params, pool.pages, g.decode_inputs()[0], *rows, heads,
-              pool.page_len, resolve_decode_kernel(kernel)) for g in groups])
+            [(params, pool.pages, *dummy, heads, pool.page_len,
+              resolve_decode_kernel(kernel))])
     for bucket, g in zip(buckets, groups):
         capture_paged_costs(params, heads, bucket, max_batch, pool,
                             prefill_chunk, compute_dtype, moe,
-                            kernel=kernel)
+                            kernel=kernel, table_pages=table_pages)
         pool.pages = lm_prefill_paged(
             params, pool.pages, g.prefill_tables(0),
             np.zeros(g.chunk, np.int32), 0, 1, heads=heads,
             page_len=pool.page_len, compute_dtype=compute_dtype, moe=moe)[0]
-        pool.pages, nxt = lm_decode_paged(
-            params, pool.pages, g.decode_inputs()[0], *rows, heads=heads,
-            page_len=pool.page_len, compute_dtype=compute_dtype, moe=moe,
-            kernel=kernel)[:2]
-        jax.block_until_ready(nxt)
-    pool.pages = kv_page_copy(pool.pages, 0, 0)  # the third program
+    pool.pages, nxt = lm_decode_paged(
+        params, pool.pages, *dummy, heads=heads, page_len=pool.page_len,
+        compute_dtype=compute_dtype, moe=moe, kernel=kernel)[:2]
+    jax.block_until_ready(nxt)
+    pool.pages = kv_page_copy(pool.pages, 0, 0)  # the last program
     jax.block_until_ready(pool.pages["l0"][0])
     return len(buckets)

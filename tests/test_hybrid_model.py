@@ -20,7 +20,8 @@ from marlin_tpu.models.transformer import (init_kv_pages, lm_decode_paged,
                                            lm_generate, lm_prefill_paged)
 from marlin_tpu.serving import Request, ServeEngine
 from marlin_tpu.serving.engine import MigrationError
-from marlin_tpu.serving.kvpool import PagedGroup, PagedKVPool
+from marlin_tpu.serving.kvpool import (PagedGroup, PagedKVPool,
+                                       decode_inputs)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PAGE, CHUNK = 8, 16
@@ -277,13 +278,62 @@ def test_the_engine_serves_a_share_and_both_page_classes_balance(kernel):
         assert gaps.max() < 1e-4
 
 
+@pytest.mark.parametrize("kernel", ["gather", "pallas"])
+def test_rows_with_rings_in_two_buckets_share_one_decode_call(
+        model, monkeypatch, kernel):
+    """One row in the small bucket and two in the large one, live together:
+    every decode call carries the rows of both (their global tables laid
+    into the wide bucket's width, their rings side by side), one call a
+    step, and every served token is the reference's best."""
+    from marlin_tpu.models import transformer
+
+    cfg, spec, params = model
+    handed = []
+    decode = transformer.lm_decode_paged
+
+    def spy(*args, **kw):
+        gtables, rings = (np.asarray(t) for t in args[2])
+        live = gtables[:, 0] != 0
+        handed.append((gtables.shape, rings.shape, int(live.sum()),
+                       bool((rings[live] != 0).any(axis=1).all())))
+        return decode(*args, **kw)
+
+    rng = np.random.default_rng(5)
+    sizes = [(9, 8), (40, 12), (14, 20)]  # buckets (24, 8), (72, 24) twice
+    reqs = [Request(prompt=rng.integers(0, 96, n).astype(np.int32), steps=s)
+            for n, s in sizes]
+    with _engine(spec, params, decode_kernel=kernel, prefill_chunk=64,
+                 start=False) as eng:
+        eng.warmup()
+        monkeypatch.setattr(transformer, "lm_decode_paged", spy)
+        handles = eng.submit_many(reqs)
+        eng.start()
+        results = [h.result(timeout=120) for h in handles]
+        audit = eng.kvpool_audit()
+        ring, width = eng._ring, eng._decode_pages
+    assert [r.status for r in results] == ["ok"] * 3
+    assert width == -(-(72 + 24) // PAGE)
+    # 19 steps of the longest request, a call each; the first seven carry
+    # all three rows, which no one bucket holds
+    assert len(handed) == 19
+    assert {h[:2] for h in handed} == {((3, width), (3, ring))}
+    assert [h[2] for h in handed[:7]] == [3] * 7
+    assert all(h[3] for h in handed)  # every live row came with its ring
+    assert audit["ok"], audit["errors"]
+    for r, q in zip(results, reqs):
+        gaps = reference.served_gaps(params, cfg, r.tokens, len(q.prompt),
+                                     96, 24)["gaps"]
+        assert gaps.max() < 1e-4
+
+
 def test_warmup_compiles_each_bucket_program_once_side_by_side(
         model, monkeypatch):
-    """``warmup`` lowers and compiles all buckets' programs at once
-    (``hybrid.precompile_paged``) and then runs them: the runs must find
-    those executables, so each of the 2 x 2 programs is compiled exactly
-    once (a second compile would double a cold start instead of cutting it),
-    and traffic after the warm-up compiles nothing."""
+    """``warmup`` lowers and compiles the engine's programs at once
+    (``hybrid.precompile_paged``: a prefill program for each of the two
+    buckets and the ONE decode program their rows share) and then runs them:
+    the runs must find those executables, so each of the three is compiled
+    exactly once (a second compile would double a cold start instead of
+    cutting it), and traffic after the warm-up compiles nothing."""
     _, spec, params = model
     compiled, at_end = [], []
     precompile = hybrid.precompile_paged
@@ -318,13 +368,13 @@ def test_warmup_compiles_each_bucket_program_once_side_by_side(
     finally:
         jax.monitoring.unregister_event_duration_listener(on)
     assert res.status == "ok"
-    assert mine == [2, 2]
+    assert mine == [2, 1]
     assert len(compiled) == n_warm  # nothing compiled under traffic
-    # the four programs side by side; their runs then compile nothing: what
-    # is left is the page copy (four more would mean that the runs did not
+    # the three programs side by side; their runs then compile nothing: what
+    # is left is the page copy (three more would mean that the runs did not
     # find the lowered programs)
-    assert len(at_end) == 1 and at_end[0] >= 4
-    assert n_warm - at_end[0] < 4, (at_end, n_warm)
+    assert len(at_end) == 1 and at_end[0] >= 3
+    assert n_warm - at_end[0] < 3, (at_end, n_warm)
 
 
 def test_the_audit_catches_a_leaked_and_an_overlong_ring(model):
@@ -409,7 +459,11 @@ def test_the_dense_block_keeps_its_tables_and_its_kernel():
     from marlin_tpu.ops.paged_attention import paged_decode_attention
 
     g = PagedGroup((24, 8), 2, PAGE, CHUNK)
-    assert g.ring is None and isinstance(g.decode_inputs()[0], np.ndarray)
+    tables = decode_inputs([(g, [])], 2, g.pages_per_row, g.ring)[0]
+    assert g.ring is None and isinstance(tables, np.ndarray)
+    ringed = PagedGroup((24, 8), 2, PAGE, CHUNK, ring=3)
+    both = decode_inputs([(ringed, [])], 2, g.pages_per_row, 3)[0]
+    assert [t.shape for t in both] == [(2, g.pages_per_row), (2, 3)]
     assert g.prefill_tables(0) is not None and g.prefill_tables(0).ndim == 1
     key = jax.random.key(3)
     q = jax.random.normal(key, (2, 2, 3, 16), jnp.float32)

@@ -459,8 +459,8 @@ def test_unaligned_prefix_tail_compiles_nothing(params, compile_count,
 
 
 def test_second_bucket_failure_after_pool_drop_is_inert(params):
-    """Regression (review): when one bucket's decode failure consumes the
-    shared slab and drops the pool, a second bucket's failure landing in
+    """Regression (review): when one decode call's failure consumes the
+    shared slab and drops the pool, a second call's failure landing in
     the same step loop must be a no-op — not a KeyError on the cleared
     pools map that masquerades as a worker crash."""
     eng = _engine(params, start=False)
@@ -472,11 +472,11 @@ def test_second_bucket_failure_after_pool_drop_is_inert(params):
         for bucket in BUCKETS:
             pools[bucket] = PagedGroup(bucket, eng.max_batch, PAGE_LEN,
                                        eng._prefill_chunk)
+        late = [(g, [0]) for g in pools.values()]
         eng._drop_paged_pool(pool, pools, "slab consumed (simulated)")
         assert pools == {} and eng._kvpool is None
-        # the second bucket's handler observes the drop and returns
-        eng._fail_paged_bucket(pool, pools, BUCKETS[1],
-                               RuntimeError("late"))
+        # the second call's handler observes the drop and returns
+        eng._fail_paged_call(pool, pools, late, RuntimeError("late"))
         # a STALE generation's drop must not clear the live pool: rebind,
         # then drop with the old (dead) pool object — the live reference
         # survives

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 
 from marlin_tpu.models import TransformerLM
 from marlin_tpu.models import transformer as tf
-from marlin_tpu.serving.kvpool import PagedGroup
+from marlin_tpu.serving.kvpool import PagedGroup, decode_inputs
 
 HEADS, PAGE_LEN, B, P, STEPS = 2, 8, 4, 8, 4
 W = (P + 8) // PAGE_LEN
@@ -136,9 +136,11 @@ def test_every_sort_lies_behind_a_scalar_cond(params, program):
 
 
 def test_a_prefilling_row_is_handed_to_decode_as_greedy():
-    """``decode_inputs()`` masks a non-live row's temperature with its table
-    and position: the row's token is discarded, and its temperature would
-    switch the vocabulary sort on for the whole bucket."""
+    """A decode call is packed from LIVE rows only: a row still prefilling
+    stays out of it with its table, its position and its temperature (its
+    token would be discarded, and its temperature would switch the
+    vocabulary sort on for every row of the call), and the rows no live row
+    fills are greedy dummies on page 0."""
     class Entry:
         def __init__(self, **kw):
             self.request = type("R", (), dict(
@@ -150,9 +152,19 @@ def test_a_prefilling_row_is_handed_to_decode_as_greedy():
                  [2, 3, 4, 5], 0, 0)
     group.finish_prefill(0, first=7)
     assert group.live_slots() == [0] and group.prefilling_slots() == [1]
-    *_, temperature = group.decode_inputs()
+
+    def packed():
+        return decode_inputs([(group, group.live_slots())], B,
+                             group.pages_per_row)
+
+    tables, positions, cur, *_, temperature, top_p, top_k = packed()
     assert temperature.dtype == np.float32
     assert temperature.tolist() == [0.0, 0.0, 0.0, 0.0]
+    assert tables[:, 0].tolist() == [1, 0, 0, 0]  # row 1's pages stay out
+    assert positions.tolist() == [3, 0, 0, 0] and cur.tolist() == [7, 0, 0, 0]
+    assert top_p.tolist() == [1.0] * 4 and top_k.tolist() == [0] * 4
     group.finish_prefill(1, first=9)
-    *_, temperature = group.decode_inputs()
+    tables, *_, temperature, _, _ = packed()
     assert temperature.tolist() == [0.0, np.float32(0.9), 0.0, 0.0]
+    assert tables[:, :4].tolist() == [[1, 0, 0, 0], [2, 3, 4, 5],
+                                      [0] * 4, [0] * 4]

@@ -5,8 +5,10 @@ the ``jax.profiler`` session and nowhere else. Under a CPU capture a small
 paged engine must emit every span of ``docs/observability.md``'s table, nested
 as the table says, with counts that agree with the engine's own; with no
 capture it serves the same tokens and announces the same records as before.
-The last part holds the repaired step time: with three live buckets
-``ServeMetrics.busy_s`` is no more than the wall time.
+One decode call carries the live rows of every bucket: ``serve.decode``
+says how many buckets and how many calls. The last part holds the repaired
+step time: with three live buckets ``ServeMetrics.busy_s`` is no more than
+the wall time.
 """
 
 import collections
@@ -23,7 +25,8 @@ from benchmarks.trace_reduce import find_xplane
 from marlin_tpu.models import TransformerLM
 from marlin_tpu.models.planner import request_pages
 from marlin_tpu.models.transformer import lm_generate
-from marlin_tpu.serving import STATUS_OK, Request, ServeEngine
+from marlin_tpu.serving import (STATUS_OK, Request, ServeEngine,
+                                pick_bucket)
 from marlin_tpu.utils.tracing import annotate
 
 HEADS = 2
@@ -46,7 +49,7 @@ SPANS = {
     "serve.prefill.dispatch": ({"rid", "bucket", "start", "tokens", "final"},
                                "serve.prefill"),
     "serve.prefill.sync": ({"rid", "final"}, "serve.prefill"),
-    "serve.decode": ({"buckets"}, "serve.iter"),
+    "serve.decode": ({"buckets", "dispatches"}, "serve.iter"),
     "serve.decode.dispatch": ({"bucket", "rows"}, "serve.decode"),
     "serve.decode.sync": ({"bucket"}, "serve.decode"),
     "serve.decode.retire": ({"bucket", "retired"}, "serve.decode"),
@@ -134,6 +137,7 @@ def _serve(params, schedule=SCHEDULE, capture_dir=None, requests=None, **kw):
     spans = (engine_spans.load(find_xplane(str(capture_dir)))["spans"]
              if capture_dir is not None else None)
     return {"results": results, "records": sink.records[:n_records],
+            "late_records": sink.records[n_records:],
             "spans": spans, "snapshot": snap, "wall": wall}
 
 
@@ -172,17 +176,70 @@ def test_dispatched_decode_spans_carry_the_work_they_were_given(traced):
     calls = [s for s in traced["spans"] if s.name == "serve.decode.dispatch"
              and s.fields["rows"]]
     assert calls
-    width = {f"{p}x{s}": -(-(p + s) // PAGE_LEN) for p, s in BUCKETS}
+    tags = [f"{p}x{s}" for p, s in BUCKETS]
+    widest = max(-(-(p + s) // PAGE_LEN) for p, s in BUCKETS)
+    assert any("+" in s.fields["bucket"] for s in calls)
     for s in calls:
+        # the buckets the call carries, in bucket order; its table is the
+        # widest bucket's whichever they are
+        carried = s.fields["bucket"].split("+")
+        assert carried == [t for t in tags if t in carried]
+        assert len(carried) <= s.fields["rows"] <= 4
         assert s.fields["padded_rows"] == 4
         assert s.fields["sampled_rows"] == 0  # every request is greedy
-        assert s.fields["table_width"] == width[s.fields["bucket"]]
+        assert s.fields["table_width"] == widest
         assert 0 < s.fields["kv_tokens"] <= (s.fields["rows"]
                                              * s.fields["table_width"]
                                              * PAGE_LEN)
         # the pages that hold them: at least one a row, none past the table
         assert (-(-s.fields["kv_tokens"] // PAGE_LEN) <= s.fields["kv_pages"]
                 <= s.fields["rows"] * s.fields["table_width"])
+
+
+#: (prompt length, steps) that are live together from the first decode step:
+#: one chunk each, all inside one iteration's prefill budget of 64 tokens
+TOGETHER = {2: ((3, 4), (5, 3), (12, 4), (14, 3)),
+            3: ((3, 4), (12, 4), (20, 6), (3, 8))}
+
+
+@pytest.mark.parametrize("live_buckets", sorted(TOGETHER))
+def test_serve_decode_counts_the_buckets_and_the_one_call(params, tmp_path,
+                                                          live_buckets):
+    """Rows live in two and in three buckets: ``serve.decode`` has
+    ``buckets`` 2 / 3 beside ``dispatches`` 1, its one dispatch span names
+    the buckets it carries and counts all their rows, and the sink gets one
+    ``step`` record for each of them."""
+    schedule = TOGETHER[live_buckets]
+    got = _serve(params, schedule=schedule, capture_dir=tmp_path,
+                 prefill_chunk=64)
+    assert all(r.status == STATUS_OK for r in got["results"])
+    decodes = [s.fields for s in got["spans"] if s.name == "serve.decode"
+               and s.fields["buckets"]]
+    calls = [s.fields for s in got["spans"]
+             if s.name == "serve.decode.dispatch" and s.fields["rows"]]
+    assert all(d["dispatches"] == 1 for d in decodes)
+    assert len(calls) == len(decodes)
+    assert decodes[0]["buckets"] == live_buckets
+    tags = sorted({"x".join(map(str, _bucket(n, s))) for n, s in schedule},
+                  key=lambda t: int(t.split("x")[0]))
+    assert calls[0]["bucket"] == "+".join(tags)
+    assert calls[0]["rows"] == len(schedule)
+    # every landing names the same buckets as its dispatch
+    for name in ("serve.decode.sync", "serve.decode.retire"):
+        named = [s.fields["bucket"] for s in got["spans"] if s.name == name]
+        assert named == [c["bucket"] for c in calls]
+    steps = [r for r in got["records"] + got["late_records"]
+             if r.get("ev") == "step"]
+    assert len(steps) == sum(d["buckets"] for d in decodes)
+    assert ([(tuple(r["bucket"]), r["rows"])
+             for r in steps[:live_buckets]]
+            == [(b, [_bucket(n, s) for n, s in schedule].count(b))
+                for b in sorted({_bucket(n, s) for n, s in schedule})])
+    assert sum(r["rows"] for r in steps) == sum(c["rows"] for c in calls)
+
+
+def _bucket(n, steps):
+    return pick_bucket(n, steps, BUCKETS)
 
 
 def test_sampled_rows_counts_live_sampled_rows_only(params, tmp_path,
@@ -267,12 +324,27 @@ def test_decode_dispatch_rows_are_the_new_tokens_less_first_tokens(traced):
                  and s.fields["final"])
     assert firsts == len(SCHEDULE) + 1  # the late request too
     assert rows == snap["new_tokens"] - firsts
-    steps = [s for s in spans if s.name == "serve.decode.dispatch"
+    # a device call per dispatch span that carried rows, a ``step`` record
+    # (``snap["steps"]``) per bucket that held live rows
+    decodes = [s for s in spans if s.name == "serve.decode"]
+    calls = [s for s in spans if s.name == "serve.decode.dispatch"
              and s.fields["rows"]]
-    assert len(steps) == snap["steps"]
+    assert len(calls) == sum(s.fields["dispatches"] for s in decodes)
+    assert sum(s.fields["buckets"] for s in decodes) == snap["steps"]
+    assert all(s.fields["dispatches"] == -(-rows // 4)
+               for s, rows in zip(decodes, _rows_by_decode(spans)))
     retired = sum(s.fields["retired"] for s in spans
                   if s.name == "serve.decode.retire")
     assert retired == len(SCHEDULE) + 1
+
+
+def _rows_by_decode(spans):
+    """Live rows dispatched inside each ``serve.decode`` span, in order."""
+    decodes = [s for s in spans if s.name == "serve.decode"]
+    return [sum(c.fields["rows"] for c in spans
+                if c.name == "serve.decode.dispatch"
+                and d.start <= c.start and c.end <= d.end)
+            for d in decodes]
 
 
 def test_queue_wait_ms_is_the_results_queue_s(traced):

@@ -7,10 +7,10 @@ hashable :class:`ModelSpec` (static under ``jit``) read from a Hugging Face
 style configuration dict (:meth:`ModelSpec.from_config`):
 
 - a head size independent of the width, and query heads **per layer**;
-- layers of two attention kinds, ``full`` (every earlier position) and
+- layers of three attention kinds, ``full`` (every earlier position),
   ``sliding`` (the last ``window`` positions: key ``j`` is visible to query
   ``i`` iff ``0 <= i - j < window``), each with its own rotary embedding
-  (plain, or YaRN on part of each head);
+  (plain, or YaRN on part of each head), and ``latent`` (below);
 - a per-head sigmoid gate on the attention output, before ``wo``;
 - a SwiGLU FFN, dense or a mixture of experts with a shared expert
   (:func:`~marlin_tpu.models.moe.moe_experts_ffn`: the layer is told which
@@ -35,6 +35,33 @@ Two classes of KV page (``serving/kvpool.py``): a full layer's slab is
 indexed by the row's *global* table (every position); a sliding layer's slab
 by the row's *window* table, a ring of :func:`window_ring_pages` pages in
 which position ``p`` lives in slot ``(p // page_len) % ring``.
+
+**Latent attention** (:class:`LatentSpec`; the DeepSeek-V3 configuration
+family's keys). A token's cache entry is not a (K, V) pair per KV head but
+ONE vector: the normed down-projection ``c_kv`` (``kv_rank`` values) and a
+rotary key ``k_pe`` (``rope_dim`` values) that all heads share. A latent
+layer's slab is one array a layer, ``(num_pages, page_len, entry_width)``
+(the entry padded with zeros to whole lane tiles,
+:attr:`LatentSpec.entry_width`), of the global class (every position, one
+table, shareable through the prefix cache like any global page). The two
+programs meet it differently, and give the same numbers:
+
+- *prefill* up-projects the gathered latents, ``[k_nope_h | v_h] = c_kv
+  W_kvb[h]``, and attends per head: a chunk's queries meet each key once,
+  so the fewer operations win (2 x (128 + 128) a head and pair against the
+  absorbed form's 2 x (320 + 256)). A chunk of whole lane tiles (a
+  multiple of 128 tokens: every engine on the chip) goes through the flash
+  kernel (:func:`_attend_latent_flash`); a narrower one through
+  :func:`_attend_latent_blocks`, the same arithmetic in plain ``jax.numpy``
+  a key block at a time;
+- *decode* is absorbed: ``W_kvb`` is never applied to the cache. The query
+  takes the key half (``qt_h = q_nope_h W_kvb[h, :, :nope]^T``), meets the
+  latent page itself as a key of ``kv_rank + rope_dim`` columns shared by
+  all heads, the page's first ``kv_rank`` columns are also the value, and
+  the value half of ``W_kvb`` is applied to the attended latent
+  (:func:`~marlin_tpu.ops.paged_attention.paged_decode_attention_latent`
+  reads each page once, in place; :func:`_attend_latent_gather` is the same
+  arithmetic on gathered pages).
 """
 
 from __future__ import annotations
@@ -47,7 +74,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["RopeSpec", "LayerSpec", "ModelSpec", "init_params",
+__all__ = ["RopeSpec", "LatentSpec", "LayerSpec", "ModelSpec", "init_params",
            "init_layer_params", "init_kv_pages", "window_ring_pages",
            "layer_forward", "prefill_paged", "decode_paged",
            "require_int_heads"]
@@ -69,7 +96,9 @@ class RopeSpec:
     """One rotary embedding: ``kind`` ``default`` (``inv_freq_i =
     theta^(-2i/D)``) or ``yarn`` (the interpolated/extrapolated blend below,
     cos and sin scaled by ``attention_factor``), over the first
-    ``rotary_dim`` (= D) dimensions of each head, rotate-half form."""
+    ``rotary_dim`` (= D) dimensions of each head, rotate-half form (dimension
+    ``i`` turns with ``i + D/2``) or, with ``interleave``, adjacent pairs
+    (``2i`` with ``2i + 1``)."""
 
     theta: float
     rotary_dim: int
@@ -79,6 +108,7 @@ class RopeSpec:
     beta_fast: float = 32.0
     beta_slow: float = 1.0
     attention_factor: float = 1.0
+    interleave: bool = False
 
     def inv_freq(self) -> np.ndarray:
         D = self.rotary_dim
@@ -105,10 +135,63 @@ class RopeSpec:
 
 
 @dataclasses.dataclass(frozen=True)
+class LatentSpec:
+    """The sizes of a latent-attention layer and its softmax scale:
+    ``softmax_scale`` multiplies every score (``(nope_dim + rope_dim)^-1/2``
+    times the square of YaRN's ``m``), and query ``i`` is scaled once more by
+    ``tau(i) = 1 + scaling_beta * ln(1 + floor(i / scaling_original_max))``
+    (1 everywhere where ``scaling_beta`` is 0)."""
+
+    q_rank: int
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    softmax_scale: float
+    scaling_beta: float = 0.0
+    scaling_original_max: int = 0
+
+    @property
+    def entry_dim(self) -> int:
+        """Values a token leaves in a layer's cache."""
+        return self.kv_rank + self.rope_dim
+
+    @property
+    def entry_width(self) -> int:
+        """Columns of a cache entry as the slab stores it: ``entry_dim``
+        rounded up to whole lane tiles of 128, the rest zeros. The chip
+        tiles an array's minor dimension by 128: handed a slab of 320
+        columns, the compiler copies the WHOLE slab into a padded one before
+        every kernel call (0.5 GB a layer at the published sizes, compiled
+        for a described v5e); stored padded, the kernel reads it in place.
+        A zero column adds nothing to a score (the query's are zero too)
+        and lies past the value's ``kv_rank`` columns."""
+        return -(-self.entry_dim // 128) * 128
+
+    def query_scale(self, positions):
+        """``softmax_scale * tau(position)``, float32, per query."""
+        scale = jnp.full(positions.shape, self.softmax_scale, jnp.float32)
+        if self.scaling_beta:
+            scale = scale * (1.0 + self.scaling_beta * jnp.log1p(jnp.floor(
+                positions.astype(jnp.float32) / self.scaling_original_max)))
+        return scale
+
+
+@dataclasses.dataclass(frozen=True)
 class LayerSpec:
-    attn: str      # "full" | "sliding"
+    attn: str      # "full" | "sliding" | "latent"
     q_heads: int
     ffn: str       # "dense" | "moe"
+
+
+def _experts_total(held: int, experts_total, first_expert: int) -> int:
+    """The router's width for a share of ``held`` experts from
+    ``first_expert`` on (``experts_total`` None: all are held)."""
+    total = held if experts_total is None else int(experts_total)
+    if not 0 <= first_expert <= total - held:
+        raise ValueError(f"experts [{first_expert}, "
+                         f"{first_expert + held}) are not among {total}")
+    return total
 
 
 @dataclasses.dataclass(frozen=True)
@@ -135,6 +218,11 @@ class ModelSpec:
     norm_eps: float = 1e-6
     param_dtype: str = "bfloat16"
     compute_dtype: str = "bfloat16"
+    #: the expert layers' scoring: ``softmax`` over the router's outputs, or
+    #: ``sigmoid`` of each with a per-expert bias that only selects
+    scoring: str = "softmax"
+    #: the latent layers' sizes (their rotary embedding is ``rope_full``)
+    latent: LatentSpec | None = None
 
     @classmethod
     def from_config(cls, cfg: dict, experts_total: int | None = None,
@@ -146,7 +234,10 @@ class ModelSpec:
         ``num_experts`` and ``vocab_size`` are what is held here.
         ``experts_total`` is the router's width where the configuration holds
         a share of the experts (default: all are held), ``first_expert`` the
-        first one of the share."""
+        first one of the share. A configuration with ``kv_lora_rank`` is of
+        the latent-attention family (:meth:`_from_latent_config`)."""
+        if "kv_lora_rank" in cfg:
+            return cls._from_latent_config(cfg, experts_total, first_expert)
         n = int(cfg["num_hidden_layers"])
         kinds = {"full_attention": "full", "sliding_attention": "sliding"}
         heads = cfg.get("num_attention_heads_per_layer") or (
@@ -173,10 +264,7 @@ class ModelSpec:
                 attention_factor=float(p.get("attention_factor", 1.0)))
 
         held = int(cfg["num_experts"])
-        total = held if experts_total is None else int(experts_total)
-        if not 0 <= first_expert <= total - held:
-            raise ValueError(f"experts [{first_expert}, "
-                             f"{first_expert + held}) are not among {total}")
+        total = _experts_total(held, experts_total, first_expert)
         return cls(
             d_model=int(cfg["hidden_size"]), head_dim=dh,
             kv_heads=int(cfg["num_key_value_heads"]), layers=layers,
@@ -195,6 +283,73 @@ class ModelSpec:
             param_dtype=str(cfg.get("param_dtype", "bfloat16")),
             compute_dtype=str(cfg.get("compute_dtype", "bfloat16")))
 
+    @classmethod
+    def _from_latent_config(cls, cfg: dict, experts_total, first_expert):
+        """The DeepSeek-V3 family's keys: every layer ``latent``
+        (``q_lora_rank``, ``kv_lora_rank``, ``qk_nope_head_dim``,
+        ``qk_rope_head_dim``, ``v_head_dim``), dense before
+        ``first_k_dense_replace`` and an expert layer from it on
+        (``n_routed_experts`` held here, ``n_shared_experts`` x
+        ``moe_intermediate_size`` shared, sigmoid scoring), YaRN on the
+        rotary dimensions with ``mscale`` / ``mscale_all_dim``, and
+        ``llama_4_scaling_beta`` for the position-dependent query scale."""
+        if (int(cfg.get("n_group", 1)), int(cfg.get("topk_group", 1))) \
+                != (1, 1):
+            raise ValueError("group-limited routing (n_group / topk_group "
+                             "> 1) is not built")
+        if not cfg.get("norm_topk_prob", True):
+            raise ValueError("picks that are not renormalised "
+                             "(norm_topk_prob false) are not built")
+        n = int(cfg["num_hidden_layers"])
+        dense_first = int(cfg.get("first_k_dense_replace", 0))
+        layers = tuple(
+            LayerSpec("latent", int(cfg["num_attention_heads"]),
+                      "dense" if i < dense_first else "moe")
+            for i in range(n))
+        rp = cfg["rope_parameters"]
+        factor = float(rp.get("factor", 1.0))
+        yarn = rp.get("rope_type", "default") == "yarn"
+
+        def mscale(m: float) -> float:
+            return 0.1 * m * math.log(factor) + 1.0 if yarn and factor > 1 \
+                else 1.0
+
+        nope, rdim = int(cfg["qk_nope_head_dim"]), int(cfg["qk_rope_head_dim"])
+        m_all = mscale(float(rp.get("mscale_all_dim", 0.0)))
+        rope = RopeSpec(
+            theta=float(rp["rope_theta"]), rotary_dim=rdim,
+            kind="yarn" if yarn else "default", factor=factor,
+            original_max=int(rp.get("original_max_position_embeddings", 0)),
+            beta_fast=float(rp.get("beta_fast", 32.0)),
+            beta_slow=float(rp.get("beta_slow", 1.0)),
+            attention_factor=mscale(float(rp.get("mscale", 1.0))) / m_all,
+            interleave=bool(cfg.get("rope_interleave", False)))
+        latent = LatentSpec(
+            q_rank=int(cfg["q_lora_rank"]), kv_rank=int(cfg["kv_lora_rank"]),
+            nope_dim=nope, rope_dim=rdim, v_dim=int(cfg["v_head_dim"]),
+            softmax_scale=(nope + rdim) ** -0.5 * m_all * m_all,
+            scaling_beta=float(rp.get("llama_4_scaling_beta", 0.0)),
+            scaling_original_max=int(
+                rp.get("original_max_position_embeddings", 0)))
+        held = int(cfg["n_routed_experts"])
+        total = _experts_total(held, experts_total, first_expert)
+        width = int(cfg["moe_intermediate_size"])
+        return cls(
+            d_model=int(cfg["hidden_size"]), head_dim=nope + rdim,
+            kv_heads=int(cfg["num_key_value_heads"]), layers=layers,
+            window=0, rope_full=rope, rope_sliding=rope,
+            dense_width=int(cfg["intermediate_size"]), expert_width=width,
+            shared_width=width * int(cfg.get("n_shared_experts", 1)),
+            n_experts=total, experts_held=held,
+            first_expert=int(first_expert),
+            top_k=int(cfg["num_experts_per_tok"]),
+            routed_scale=float(cfg.get("routed_scaling_factor", 1.0)),
+            vocab_held=int(cfg["vocab_size"]),
+            norm_eps=float(cfg.get("rms_norm_eps", 1e-6)),
+            param_dtype=str(cfg.get("param_dtype", "bfloat16")),
+            compute_dtype=str(cfg.get("compute_dtype", "bfloat16")),
+            scoring="sigmoid", latent=latent)
+
     @property
     def n_layers(self) -> int:
         return len(self.layers)
@@ -206,6 +361,17 @@ class ModelSpec:
     @property
     def has_window(self) -> bool:
         return any(ly.attn == "sliding" for ly in self.layers)
+
+    def page_values(self, kind: str, page_len: int) -> int:
+        """Cache values ONE page id of class ``kind`` holds over all layers
+        (``full``: the global class, the full and the latent layers;
+        ``sliding``: the window class): K and V per KV head for a full or a
+        sliding layer, one latent entry for a latent one."""
+        kinds = ("sliding",) if kind == "sliding" else ("full", "latent")
+        return page_len * sum(
+            self.latent.entry_width if ly.attn == "latent"
+            else 2 * self.kv_heads * self.head_dim
+            for ly in self.layers if ly.attn in kinds)
 
 
 def window_ring_pages(window: int, chunk: int, page_len: int) -> int:
@@ -258,12 +424,24 @@ def init_layer_params(spec: ModelSpec, ly: LayerSpec, key) -> dict:
     s = d ** -0.5
     hq, hk = ly.q_heads * dh, spec.kv_heads * dh
     lp = {"ln1": jnp.ones((d,), jnp.float32),
-          "ln2": jnp.ones((d,), jnp.float32),
-          "wq": _normal(ks[0], (d, hq), s, dt),
-          "wk": _normal(ks[1], (d, hk), s, dt),
-          "wv": _normal(ks[2], (d, hk), s, dt),
-          "wgate": _normal(ks[3], (d, ly.q_heads), s, dt),
-          "wo": _normal(ks[4], (hq, d), hq ** -0.5, dt)}
+          "ln2": jnp.ones((d,), jnp.float32)}
+    if ly.attn == "latent":
+        la, H = spec.latent, ly.q_heads
+        lp.update(
+            wq_a=_normal(ks[0], (d, la.q_rank), s, dt),
+            q_norm=jnp.ones((la.q_rank,), jnp.float32),
+            wq_b=_normal(ks[1], (la.q_rank, H * dh), la.q_rank ** -0.5, dt),
+            wkv_a=_normal(ks[2], (d, la.entry_dim), s, dt),
+            kv_norm=jnp.ones((la.kv_rank,), jnp.float32),
+            wkv_b=_normal(ks[3], (la.kv_rank, H * (la.nope_dim + la.v_dim)),
+                          la.kv_rank ** -0.5, dt),
+            wo=_normal(ks[4], (H * la.v_dim, d), (H * la.v_dim) ** -0.5, dt))
+    else:
+        lp.update(wq=_normal(ks[0], (d, hq), s, dt),
+                  wk=_normal(ks[1], (d, hk), s, dt),
+                  wv=_normal(ks[2], (d, hk), s, dt),
+                  wgate=_normal(ks[3], (d, ly.q_heads), s, dt),
+                  wo=_normal(ks[4], (hq, d), hq ** -0.5, dt))
     if ly.ffn == "dense":
         f = spec.dense_width
         lp.update(w_gate=_normal(ks[5], (d, f), s, dt),
@@ -280,6 +458,15 @@ def init_layer_params(spec: ModelSpec, ly: LayerSpec, key) -> dict:
             "s_gate": _normal(ks[12], (d, fs), s, dt),
             "s_up": _normal(ks[13], (d, fs), s, dt),
             "s_down": _normal(ks[14], (fs, d), fs ** -0.5, dt)}
+        if spec.scoring == "sigmoid":
+            # the family's load-balancing term: small beside the spacing of
+            # the top scores (0.013 at 128 experts), so that it changes
+            # which expert a near-tie picks and leaves the experts' loads
+            # even. At 0.05 it made an expert 2.5 x as popular as its
+            # neighbour, and the held experts a step touches differed by 8 %
+            # from seed to seed (PERF.md section 6, PR 35)
+            lp["moe"]["e_bias"] = 0.005 * jax.random.normal(
+                ks[15], (spec.n_experts,), jnp.float32)
     return lp
 
 
@@ -302,17 +489,26 @@ def init_params(spec: ModelSpec, key) -> dict:
 
 def init_kv_pages(spec: ModelSpec, num_pages: int, window_pages: int,
                   page_len: int, compute_dtype: str | None = None) -> dict:
-    """Zeroed slabs, layer -> (k, v): ``(num_pages, page_len, kv_heads,
-    head_dim)`` for a full layer, ``(window_pages, ...)`` for a sliding one.
-    Page 0 of each class is its dummy."""
+    """Zeroed slabs, layer -> a tuple of arrays: ``(k, v)``, each
+    ``(num_pages, page_len, kv_heads, head_dim)``, for a full layer
+    (``(window_pages, ...)`` for a sliding one); ONE array ``(num_pages,
+    page_len, entry_width)`` for a latent layer
+    (:attr:`LatentSpec.entry_width`). Page 0 of each class is its dummy."""
     if num_pages < 2 or (spec.has_window and window_pages < 2):
         raise ValueError(f"each page class needs >= 2 pages (page 0 is the "
                          f"dummy), got {num_pages} and {window_pages}")
     dt = jnp.dtype(compute_dtype or spec.compute_dtype)
-    return {f"l{i}": tuple(
-        jnp.zeros((num_pages if ly.attn == "full" else window_pages,
-                   page_len, spec.kv_heads, spec.head_dim), dt)
-        for _ in range(2)) for i, ly in enumerate(spec.layers)}
+
+    def slabs(ly):
+        if ly.attn == "latent":
+            return (jnp.zeros((num_pages, page_len,
+                               spec.latent.entry_width), dt),)
+        return tuple(
+            jnp.zeros((num_pages if ly.attn == "full" else window_pages,
+                       page_len, spec.kv_heads, spec.head_dim), dt)
+            for _ in range(2))
+
+    return {f"l{i}": slabs(ly) for i, ly in enumerate(spec.layers)}
 
 
 # ------------------------------------------------------------ the block, once
@@ -342,6 +538,13 @@ def _rope(x, positions, rope: RopeSpec):
     cos = (jnp.cos(ang) * rope.attention_factor)[:, None, :]
     sin = (jnp.sin(ang) * rope.attention_factor)[:, None, :]
     xf = x.astype(jnp.float32)
+    if rope.interleave:
+        pairs = xf[..., :D].reshape(*xf.shape[:-1], D // 2, 2)
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+        turned = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).reshape(*xf.shape[:-1], D)
+        return jnp.concatenate([turned, xf[..., D:]],
+                               axis=-1).astype(x.dtype)
     x1, x2 = xf[..., :D // 2], xf[..., D // 2:D]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin,
                            xf[..., D:]], axis=-1)
@@ -362,7 +565,8 @@ def layer_forward(spec: ModelSpec, i: int, lp: dict, x, positions, valid,
     written. ``attend(q, k, v)``
     takes the rotated ``q`` (T, kv_heads, group, head_dim) and this layer's
     new ``k``, ``v`` (T, kv_heads, head_dim), stores them where the program
-    keeps its cache, and returns the attention output in ``q``'s shape.
+    keeps its cache, and returns the attention output in ``q``'s shape (a
+    latent layer's ``attend`` takes what :func:`_latent_attention` says).
     ``valid`` (T,) marks the rows that are real tokens: the others are routed
     to no expert. Returns ``(x, counts)``; ``counts`` is the expert layer's
     ``(assignments, local assignments, held experts touched)``, zeros for a
@@ -370,6 +574,10 @@ def layer_forward(spec: ModelSpec, i: int, lp: dict, x, positions, valid,
     ly = spec.layers[i]
     T, cd = x.shape[0], jnp.dtype(spec.compute_dtype)
     H, kvh, dh = ly.q_heads, spec.kv_heads, spec.head_dim
+    if ly.attn == "latent":
+        with jax.named_scope("attn_latent"):
+            x = _latent_attention(spec, ly, lp, x, positions, attend)
+        return _ffn_half(spec, ly, lp, x, valid)
     rope = spec.rope_full if ly.attn == "full" else spec.rope_sliding
     with jax.named_scope(f"attn_{ly.attn}"):
         h = _rmsnorm(x, lp["ln1"], spec.norm_eps).astype(cd)
@@ -380,6 +588,42 @@ def layer_forward(spec: ModelSpec, i: int, lp: dict, x, positions, valid,
         o = attend(q.reshape(T, kvh, H // kvh, dh), k, v).reshape(T, H, dh)
         o = (o.astype(jnp.float32) * gate[:, :, None]).astype(cd)
         x = x + _mm(o.reshape(T, H * dh), lp["wo"], jnp.float32)
+    return _ffn_half(spec, ly, lp, x, valid)
+
+
+def _latent_attention(spec: ModelSpec, ly: LayerSpec, lp: dict, x, positions,
+                      attend):
+    """The attention half of a latent layer: queries through their own
+    latent (``wq_a``, norm, ``wq_b``), the cache entry ``(c_kv, k_pe)`` from
+    ``wkv_a`` (the norm on ``c_kv`` only), the rotary embedding on the
+    ``rope_dim`` columns of each, then ``attend(q_nope (T, H, nope), q_pe
+    (T, H, rope), entry (T, entry_width), scale (T,) float32, wkv_b
+    (kv_rank, H, nope + v))``, which stores ``entry`` where the program keeps
+    its cache and returns the heads' outputs (T, H, v): every score is
+    ``scale[i] * (q_nope . k_nope + q_pe . k_pe)``."""
+    la, cd = spec.latent, jnp.dtype(spec.compute_dtype)
+    T, H = x.shape[0], ly.q_heads
+    h = _rmsnorm(x, lp["ln1"], spec.norm_eps).astype(cd)
+    c_q = _rmsnorm(_mm(h, lp["wq_a"], jnp.float32), lp["q_norm"],
+                   spec.norm_eps).astype(cd)
+    q = _mm(c_q, lp["wq_b"]).reshape(T, H, la.nope_dim + la.rope_dim)
+    q_pe = _rope(q[..., la.nope_dim:], positions, spec.rope_full)
+    kv = _mm(h, lp["wkv_a"], jnp.float32)
+    c_kv = _rmsnorm(kv[:, :la.kv_rank], lp["kv_norm"], spec.norm_eps)
+    k_pe = _rope(kv[:, None, la.kv_rank:], positions, spec.rope_full)[:, 0]
+    entry = jnp.concatenate(
+        [c_kv, k_pe, jnp.zeros((T, la.entry_width - la.entry_dim))],
+        axis=-1).astype(cd)
+    o = attend(q[..., :la.nope_dim], q_pe, entry,
+               la.query_scale(positions),
+               lp["wkv_b"].reshape(la.kv_rank, H, la.nope_dim + la.v_dim))
+    return x + _mm(o.reshape(T, H * la.v_dim).astype(cd), lp["wo"],
+                   jnp.float32)
+
+
+def _ffn_half(spec: ModelSpec, ly: LayerSpec, lp: dict, x, valid):
+    """The FFN half of :func:`layer_forward`, every attention kind's."""
+    cd = jnp.dtype(spec.compute_dtype)
     h = _rmsnorm(x, lp["ln2"], spec.norm_eps)
     if ly.ffn == "dense":
         with jax.named_scope("ffn_dense"):
@@ -392,7 +636,7 @@ def layer_forward(spec: ModelSpec, i: int, lp: dict, x, positions, valid,
         out, counts = moe_experts_ffn(
             lp["moe"], h, valid, top_k=spec.top_k,
             first_expert=spec.first_expert, routed_scale=spec.routed_scale,
-            compute_dtype=cd)
+            compute_dtype=cd, scoring=spec.scoring)
     return x + out, counts
 
 
@@ -476,6 +720,172 @@ def _attend_dense(q, k, v, q_pos, k_pos, k_live, window, block=None):
     return o.transpose(1, 0, 2, 3)
 
 
+def _attend_latent_blocks(q_nope, q_pe, ctx, wkv_b, scale, q_pos,
+                          la: LatentSpec, block: int):
+    """Prefill's latent attention, unabsorbed: the queries ``q_nope`` (T, H,
+    nope), ``q_pe`` (T, H, rope) at ``q_pos`` against the gathered entries
+    ``ctx`` (L, entry_width), entry ``j`` at position ``j``, visible to
+    query ``i`` iff ``j <= q_pos[i]``. The entries are met ``block`` at a
+    time with a running softmax, all heads at once: each block's latents
+    are up-projected to per-head keys and values there (``wkv_b`` (kv_rank,
+    H, nope + v)), so the whole table's keys and values never exist, and a
+    block that begins after the last query is skipped whole. Returns (T, H,
+    v) in the queries' dtype."""
+    T, H, n = q_nope.shape
+    cd = q_nope.dtype
+    nb = -(-ctx.shape[0] // block)
+    blocks = jnp.pad(ctx, [(0, nb * block - ctx.shape[0]), (0, 0)]).reshape(
+        nb, block, ctx.shape[1])
+    w = wkv_b.astype(cd)
+
+    def step(carry, blk):
+        cb, b0 = blk
+
+        def meet(carry):
+            m, l, acc = carry
+            kv = jnp.einsum("sc,chd->shd", cb[:, :la.kv_rank], w,
+                            preferred_element_type=jnp.float32).astype(cd)
+            s = (jnp.einsum("thd,shd->hts", q_nope, kv[..., :n],
+                            preferred_element_type=jnp.float32)
+                 + jnp.einsum("thr,sr->hts", q_pe,
+                              cb[:, la.kv_rank:la.entry_dim],
+                              preferred_element_type=jnp.float32))
+            seen = (b0 + jnp.arange(block))[None, :] <= q_pos[:, None]
+            s = jnp.where(seen[None], s * scale[None, :, None], _MASKED)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new[..., None])
+            pv = jnp.einsum("hts,shd->htd", p.astype(cd), kv[..., n:],
+                            preferred_element_type=jnp.float32)
+            return (m_new, alpha * l + jnp.sum(p, axis=-1),
+                    acc * alpha[..., None] + pv)
+
+        return jax.lax.cond(b0 <= q_pos[-1], meet, lambda c: c, carry), None
+
+    init = (jnp.full((H, T), _MASKED, jnp.float32),
+            jnp.zeros((H, T), jnp.float32),
+            jnp.zeros((H, T, la.v_dim), jnp.float32))
+    (_, l, acc), _ = jax.lax.scan(
+        step, init, (blocks, jnp.arange(nb) * block))
+    return (acc / l[..., None]).transpose(1, 0, 2).astype(cd)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "bq", "bkv"))
+def _latent_prefill_flash_head(qh, kh, vh, q_offset, scale: float, bq: int,
+                               bkv: int):
+    """One head of :func:`_latent_prefill_flash_call`: the flash panel
+    kernel from an empty state, normalised. Jitted by itself because the
+    kernel's operation in a trace takes the innermost jitted name."""
+    from ..ops.flash_attention import flash_attention_panel
+
+    T, d = qh.shape
+    m, l, acc = flash_attention_panel(
+        qh, kh, vh, jnp.full((T,), _MASKED, jnp.float32),
+        jnp.zeros((T,), jnp.float32), jnp.zeros((T, d), jnp.float32),
+        q_offset, 0, q_offset + T, causal=True, scale=scale, bq=bq, bkv=bkv)
+    return acc / jnp.maximum(l, 1e-30)[:, None]
+
+
+@functools.partial(jax.jit, static_argnames=("scale",))
+def _latent_prefill_flash_call(q, k, v, q_offset, scale: float):
+    """Causal attention of a chunk's queries ``q`` (H, T, d), query ``i`` at
+    position ``q_offset + i``, over per-head keys and values ``k``, ``v`` (H,
+    L, d) at positions 0..L-1: the flash panel kernel
+    (:func:`~marlin_tpu.ops.flash_attention.flash_attention_panel`: score
+    tiles stay in VMEM, a key block wholly after the chunk is skipped)
+    vmapped over heads."""
+    from ..ops.flash_attention import block_divisor
+
+    head = functools.partial(_latent_prefill_flash_head, scale=scale,
+                             bq=block_divisor(q.shape[1]),
+                             bkv=min(1024, k.shape[1]))
+    return jax.vmap(head, in_axes=(0, 0, 0, None))(q, k, v, q_offset)
+
+
+def _attend_latent_flash(q_nope, q_pe, ctx, wkv_b, scale, chunk_start,
+                         la: LatentSpec):
+    """Prefill's latent attention on the chip, unabsorbed, through the flash
+    kernel: the gathered entries ``ctx`` (L, entry_width) are up-projected to
+    per-head keys ``[k_nope_h | k_pe]`` and values once (0.3 GB a layer at
+    19k positions, transient), the position-dependent part of ``scale``
+    goes into the queries, and the chunk (T a multiple of 128, its first
+    query at ``chunk_start``) attends causally. What
+    :func:`_attend_latent_blocks` computes with its scores in HBM (ten
+    passes over 32 x 1024 x 1024 floats a key block: 17 ms a layer at 17k
+    positions where the matmuls are 1.5) this keeps in VMEM."""
+    T, H, n = q_nope.shape
+    cd = q_nope.dtype
+    L = ctx.shape[0]
+    bkv = 1024 if L >= 1024 else -(-L // 128) * 128
+    # a no-op where the caller gathered whole key blocks (flash_table_pages)
+    ctx = jnp.pad(ctx, [(0, -(-L // bkv) * bkv - L), (0, 0)])
+    w = wkv_b.astype(cd)
+    # the keys [k_nope_h | k_pe] as ONE product of the stored entry: the
+    # latent columns through the key half of W_kvb, the rotary columns
+    # through an identity, the pad columns through zeros. Slicing and
+    # joining the per-head arrays instead moved 0.5 GB a layer
+    r = la.rope_dim
+    w_key = jnp.zeros((ctx.shape[1], H, n + r), cd)
+    w_key = w_key.at[:la.kv_rank, :, :n].set(w[..., :n])
+    w_key = w_key.at[la.kv_rank:la.entry_dim, :, n:].set(
+        jnp.broadcast_to(jnp.eye(r, dtype=cd)[:, None, :], (r, H, r)))
+    k = jnp.einsum("se,ehd->hsd", ctx, w_key,
+                   preferred_element_type=jnp.float32).astype(cd)
+    v = jnp.einsum("sc,chd->hsd", ctx[:, :la.kv_rank], w[..., n:],
+                   preferred_element_type=jnp.float32).astype(cd)
+    tau = (scale / la.softmax_scale)[:, None, None]
+    q = (jnp.concatenate([q_nope, q_pe], axis=-1).astype(jnp.float32)
+         * tau).astype(cd).transpose(1, 0, 2)
+    d = max(k.shape[-1], la.v_dim)   # the kernel has one head size
+
+    def widen(x):
+        return jnp.pad(x, [(0, 0), (0, 0), (0, d - x.shape[-1])])
+
+    o = _latent_prefill_flash_call(widen(q), widen(k), widen(v),
+                                   chunk_start, scale=la.softmax_scale)
+    return o[..., :la.v_dim].transpose(1, 0, 2).astype(cd)
+
+
+def flash_table_pages(pages: int, page_len: int) -> int:
+    """How many pages of a row's table prefill gathers for a latent layer:
+    the table's own, rounded up to whole key blocks of the flash kernel
+    (1024 positions) where it spans one; the extra entries name the dummy
+    page, whose positions lie after every query."""
+    positions = pages * page_len
+    if positions < 1024 or 1024 % page_len:
+        return pages
+    return -(-positions // 1024) * 1024 // page_len
+
+
+def _attend_latent_gather(q, slab, tables, lengths, value_dim: int,
+                          page_len: int):
+    """The reference formulation of the latent decode kernel: each row's
+    pages gathered, the absorbed query ``q`` (B, H, entry_width, scaled)
+    against every live entry, the entry's first ``value_dim`` columns the
+    value. Returns the attended latents (B, H, value_dim)."""
+    B, W = tables.shape
+    e = slab[tables].reshape(B, W * page_len, slab.shape[-1])
+    live = jnp.arange(W * page_len)[None, :] < lengths[:, None]
+    s = jnp.einsum("bhe,bte->bht", q, e, preferred_element_type=jnp.float32)
+    p = jax.nn.softmax(jnp.where(live[:, None, :], s, _MASKED), axis=-1)
+    return jnp.einsum("bht,btc->bhc", p.astype(q.dtype), e[..., :value_dim])
+
+
+def _absorbed_query(q_nope, q_pe, scale, wkv_b, la: LatentSpec):
+    """The decode query that meets a latent entry itself: per head ``[q_nope
+    W_kvb[:, :nope]^T | q_pe | 0]`` to the entry's stored width, times the
+    row's softmax scale (folded in here, in float32, so the kernel needs no
+    scale), in the compute dtype."""
+    cd = q_nope.dtype
+    qt = jnp.einsum("bhn,chn->bhc", q_nope,
+                    wkv_b[..., :la.nope_dim].astype(cd),
+                    preferred_element_type=jnp.float32)
+    q = jnp.concatenate(
+        [qt, q_pe.astype(jnp.float32),
+         jnp.zeros((*qt.shape[:2], la.entry_width - la.entry_dim))], axis=-1)
+    return (q * scale[:, None, None]).astype(cd)
+
+
 # ------------------------------------------------------------- paged prefill
 
 
@@ -491,7 +901,6 @@ def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
         raise ValueError(f"chunk width {C} must be a multiple of page_len "
                          f"{page_len}")
     cp = C // page_len
-    kvh, dh = spec.kv_heads, spec.head_dim
     s_page = chunk_start // page_len
     q_pos = chunk_start + jnp.arange(C)
     valid = q_pos < length
@@ -504,9 +913,12 @@ def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
     # window/page_len pages before the chunk, in position order
     Lg = gtable.shape[0] * page_len
     w_slots = wtable[jnp.mod(s_page - wp + jnp.arange(wp), max(ring, 1))]
+    ltable = jnp.pad(gtable, (0, flash_table_pages(
+        gtable.shape[0], page_len) - gtable.shape[0]))
+    by_kind = {"full": gtable, "sliding": w_slots, "latent": ltable}
     ctx = jax.lax.optimization_barrier({
-        name: tuple(t[gtable if ly.attn == "full" else w_slots]
-                    .reshape(-1, kvh, dh) for t in pages[name])
+        name: tuple(t[by_kind[ly.attn]].reshape(-1, *t.shape[2:])
+                    for t in pages[name])
         for name, ly in ((f"l{i}", ly) for i, ly in enumerate(spec.layers))})
     g_pos = jnp.arange(Lg)
     w_pos = jnp.concatenate([(s_page - wp) * page_len
@@ -515,6 +927,17 @@ def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
     new_kv, counts = {}, jnp.zeros((3,), jnp.int32)
     for i, ly in enumerate(spec.layers):
         name = f"l{i}"
+
+        def attend_latent(q_nope, q_pe, entry, scale, wkv_b, name=name):
+            new_kv[name] = (entry,)
+            (ce,) = ctx[name]
+            ce = jax.lax.dynamic_update_slice(ce, entry.astype(ce.dtype),
+                                              (chunk_start, 0))
+            if C % 128 == 0:   # the flash kernel's rows come in lane tiles
+                return _attend_latent_flash(q_nope, q_pe, ce, wkv_b, scale,
+                                            chunk_start, spec.latent)
+            return _attend_latent_blocks(q_nope, q_pe, ce, wkv_b, scale,
+                                         q_pos, spec.latent, block=C)
 
         def attend(q, k, v, name=name, ly=ly):
             new_kv[name] = (k, v)
@@ -540,7 +963,8 @@ def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
                     w_pos[keys], w_pos[keys] >= 0, spec.window))
             return jnp.concatenate(outs)
 
-        x, c = layer_forward(spec, i, params[name], x, q_pos, valid, attend)
+        x, c = layer_forward(spec, i, params[name], x, q_pos, valid,
+                             attend_latent if ly.attn == "latent" else attend)
         counts = counts + c
     # write the chunk's pages, one dynamic update a page (transformer.py has
     # the reason). A page wholly past the prompt goes to the dummy: in a
@@ -548,17 +972,17 @@ def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
     new_pages = {}
     for i, ly in enumerate(spec.layers):
         name = f"l{i}"
-        pk, pv = pages[name]
-        k, v = new_kv[name]
-        pgk = k.astype(pk.dtype).reshape(cp, page_len, kvh, dh)
-        pgv = v.astype(pv.dtype).reshape(cp, page_len, kvh, dh)
+        slabs = pages[name]
+        fresh = [new.astype(t.dtype).reshape(cp, page_len, *t.shape[2:])
+                 for new, t in zip(new_kv[name], slabs)]
         for j in range(cp):
-            pid = (gtable[s_page + j] if ly.attn == "full"
-                   else wtable[jnp.mod(s_page + j, ring)])
+            pid = (wtable[jnp.mod(s_page + j, ring)] if ly.attn == "sliding"
+                   else gtable[s_page + j])
             pid = jnp.where(chunk_start + j * page_len < length, pid, 0)
-            pk = jax.lax.dynamic_update_index_in_dim(pk, pgk[j], pid, 0)
-            pv = jax.lax.dynamic_update_index_in_dim(pv, pgv[j], pid, 0)
-        new_pages[name] = (pk, pv)
+            slabs = tuple(
+                jax.lax.dynamic_update_index_in_dim(t, pg[j], pid, 0)
+                for t, pg in zip(slabs, fresh))
+        new_pages[name] = slabs
     idx = jnp.clip(length - 1 - chunk_start, 0, C - 1)
     logits = _head_logits(spec, params, x[idx])
     first = _pick_token_row(temperature, top_p, top_k, logits,
@@ -621,7 +1045,8 @@ def _lm_decode_paged_spec_jit(params, pages, gtables, wtables, positions,
                               cur_tokens, steps_done, seeds, temperature,
                               top_p, top_k, spec: ModelSpec, page_len: int,
                               kernel: str):
-    from ..ops.paged_attention import paged_decode_attention
+    from ..ops.paged_attention import (paged_decode_attention,
+                                       paged_decode_attention_latent)
     from .transformer import _pick_token_rows, _scatter_kv_entries
 
     B, Wg = gtables.shape
@@ -637,6 +1062,7 @@ def _lm_decode_paged_spec_jit(params, pages, gtables, wtables, positions,
     # per class: (tables, the page id this step writes, first page the
     # kernel visits, lowest visible position)
     per_class = {"full": (gtables, gtables[rows, page], zero, zero)}
+    per_class["latent"] = per_class["full"]  # one table, every position
     if spec.has_window:
         per_class["sliding"] = (wtables, wtables[rows, jnp.mod(page, ring)],
                                 lower // page_len, lower)
@@ -660,7 +1086,28 @@ def _lm_decode_paged_spec_jit(params, pages, gtables, wtables, positions,
             return paged_decode_attention(q, pk, pv, tables, lengths,
                                           first_page=first_page, lower=low)
 
-        x, c = layer_forward(spec, i, params[name], x, pos, live, attend)
+        def attend_latent(q_nope, q_pe, entry, scale, wkv_b, name=name,
+                          tables=tables, pids=pids):
+            (slab,) = pages[name]
+            for b in range(B):  # as _scatter_kv_entries: in-place updates
+                slab = jax.lax.dynamic_update_slice(
+                    slab, entry[b].astype(slab.dtype)[None, None],
+                    (pids[b], off[b], 0))
+            new_pages[name] = (slab,)
+            la = spec.latent
+            q = _absorbed_query(q_nope, q_pe, scale, wkv_b, la)
+            if kernel == "pallas":
+                ot = paged_decode_attention_latent(q, slab, tables, lengths,
+                                                   value_dim=la.kv_rank)
+            else:
+                ot = _attend_latent_gather(q, slab, tables, lengths,
+                                           la.kv_rank, page_len)
+            return jnp.einsum("bhc,chv->bhv", ot,
+                              wkv_b[..., la.nope_dim:].astype(ot.dtype),
+                              preferred_element_type=jnp.float32)
+
+        x, c = layer_forward(spec, i, params[name], x, pos, live,
+                             attend_latent if ly.attn == "latent" else attend)
         counts = counts + c
     logits = _head_logits(spec, params, x)
     nxt = _pick_token_rows(temperature, top_p, top_k, logits, seeds,

@@ -244,7 +244,8 @@ def _grouped_matmul(xs, w, sizes, out_dtype):
 
 
 def moe_experts_ffn(mp: dict, h, valid, *, top_k: int, first_expert: int = 0,
-                    routed_scale: float = 1.0, compute_dtype=None):
+                    routed_scale: float = 1.0, compute_dtype=None,
+                    scoring: str = "softmax"):
     """The dropless expert layer of the serving path, for a ``(T, d)`` batch
     of tokens — a prefill chunk or a decode bucket's rows alike, so both
     route identically: float32 softmax router over ALL the model's experts
@@ -260,6 +261,11 @@ def moe_experts_ffn(mp: dict, h, valid, *, top_k: int, first_expert: int = 0,
     never read). Rows where ``valid``
     is false (a bucket's dummy rows, a chunk's padding) are routed nowhere.
 
+    ``scoring`` (static) ``sigmoid``: each router output through a sigmoid;
+    the picks are the ``top_k`` largest of score + ``mp["e_bias"]`` (E,), a
+    per-expert bias that only selects; the weights are the picks' own scores
+    renormalised over themselves.
+
     The router reads ``h`` as it comes (float32 from the caller's norm: a
     rounded input flips near-ties among the picks); the experts' matmuls run
     on ``h`` in ``compute_dtype`` (default: ``h``'s own).
@@ -270,11 +276,17 @@ def moe_experts_ffn(mp: dict, h, valid, *, top_k: int, first_expert: int = 0,
     T, d = h.shape
     cd = jnp.dtype(compute_dtype or h.dtype)
     held = mp["e_gate"].shape[0]
-    gates = jax.nn.softmax(jnp.matmul(
-        h.astype(jnp.float32), mp["router"].astype(jnp.float32),
-        precision="highest"), axis=-1)
-    topv, topi = jax.lax.top_k(gates, top_k)                      # (T, k)
-    topv = topv / jnp.sum(topv, axis=-1, keepdims=True)
+    logits = jnp.matmul(h.astype(jnp.float32),
+                        mp["router"].astype(jnp.float32), precision="highest")
+    if scoring == "softmax":
+        topv, topi = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    elif scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        _, topi = jax.lax.top_k(scores + mp["e_bias"], top_k)
+        topv = jnp.take_along_axis(scores, topi, axis=-1)
+    else:
+        raise ValueError(f"unknown expert scoring {scoring!r}")
+    topv = topv / jnp.sum(topv, axis=-1, keepdims=True)            # (T, k)
     local = ((topi >= first_expert) & (topi < first_expert + held)
              & valid[:, None])
     # one row per assignment, sorted by held expert; what is not held here

@@ -97,8 +97,11 @@ def kv_page_bytes(params: dict, heads, page_len: int,
     memory its cache rows can ever pin, not the bucket's worst case
     (docs/serving.md). For a
     :class:`~marlin_tpu.models.hybrid.ModelSpec` a page id names a page in
-    the layers of one ``kind`` only (``full``: the global class;
-    ``sliding``: the window class), so each class has its own page bytes."""
+    the layers of one ``kind`` only (``full``: the global class, whose
+    latent layers hold ONE array of ``entry_width`` values a position where
+    the others hold K and V per KV head; ``sliding``: the window class), so
+    each class has its own page bytes
+    (:meth:`~marlin_tpu.models.hybrid.ModelSpec.page_values`)."""
     import jax.numpy as jnp
 
     from .hybrid import ModelSpec
@@ -106,8 +109,7 @@ def kv_page_bytes(params: dict, heads, page_len: int,
 
     if isinstance(heads, ModelSpec):
         dt = jnp.dtype(compute_dtype or heads.compute_dtype)
-        return (len(heads.layer_names(kind)) * 2 * page_len * heads.kv_heads
-                * heads.head_dim * dt.itemsize)
+        return heads.page_values(kind, page_len) * dt.itemsize
 
     d = params["emb"].shape[1]
     dh = d // heads

@@ -42,6 +42,15 @@ table pays one scalar compare for each step it skips. Dummy rows (all-zero
 block tables, the free/prefilling-slot contract) attend one masked-harmless
 position of the sacrificial page 0, in one live step.
 
+**The latent variant** (:func:`paged_decode_attention_latent`): a latent
+attention layer's page is ONE array ``(num_pages, page_len, entry)`` whose
+entry is shared by every head, and the absorbed decode query (``entry``
+wide, its softmax scale folded in) meets it directly: the page is the key,
+and its first ``value_dim`` columns are the value. The page is fetched once
+a grid step and serves as both; everything else (the scalar-prefetched
+table, the skip past a row's length, the clamped block index, the online
+softmax in scratch) is the kernel above with the KV-head axis gone.
+
 ``interpret=`` defaults through :func:`~.pallas_kernels._interpret` —
 interpreter everywhere but real TPU — so the tier-1 CPU suites exercise
 the real kernel body, not a stand-in.
@@ -59,8 +68,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .pallas_kernels import _interpret
 
-__all__ = ["paged_decode_attention", "align_page_len", "paged_attention_cost",
-           "PAGE_SUBLANE"]
+__all__ = ["paged_decode_attention", "paged_decode_attention_latent",
+           "align_page_len", "paged_attention_cost", "PAGE_SUBLANE"]
 
 # TPU sublane multiple: the kernel's K/V block second-to-minor dimension is
 # page_len, so pages must stay a multiple of this for an unpadded block
@@ -311,3 +320,120 @@ def paged_decode_attention(q, k_pages, v_pages, tables, lengths,
     return _paged_decode_attention_call(q, k_pages, v_pages, tables, lengths,
                                         page_len=page_len,
                                         interpret=bool(interpret))
+
+
+def _latent_attn_kernel(tables_ref, lengths_ref, q_ref, e_ref, o_ref, acc_ref,
+                        m_ref, l_ref, *, page_len: int, value_dim: int):
+    """Grid (B, W) as :func:`_paged_attn_kernel`. ``q_ref`` (1, H, entry) is
+    the row's absorbed, scaled query; ``e_ref`` (1, page_len, entry) the
+    page, read once: all ``entry`` columns are the key, the first
+    ``value_dim`` the value. The score is split at ``value_dim`` (a lane-tile
+    boundary at the published sizes) so that neither contraction crosses a
+    partial tile."""
+    b = pl.program_id(0)
+    w = pl.program_id(1)
+
+    @pl.when(w == 0)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[:] = jnp.full_like(m_ref, _MASKED)
+        l_ref[:] = jnp.zeros_like(l_ref)
+
+    @pl.when(w * page_len < lengths_ref[b])
+    def _live_page():
+        q = q_ref[0]                       # (H, entry)
+        e = e_ref[0]                       # (page_len, entry)
+        c = e[:, :value_dim]
+        nt = (((1,), (1,)), ((), ()))      # contract both minor dimensions
+        s = (jax.lax.dot_general(q[:, :value_dim], c, nt,
+                                 preferred_element_type=jnp.float32)
+             + jax.lax.dot_general(q[:, value_dim:], e[:, value_dim:], nt,
+                                   preferred_element_type=jnp.float32))
+        at = w * page_len + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(at < lengths_ref[b], s, _MASKED)
+        m_prev = m_ref[:]                  # (H, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        m_ref[:] = m_new
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_ref[:] = alpha * l_ref[:] + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * alpha + jnp.dot(
+            p.astype(q.dtype), c, preferred_element_type=jnp.float32)
+
+    @pl.when(w == pl.num_programs(1) - 1)
+    def _flush():
+        o_ref[0] = (acc_ref[:] / l_ref[:]).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("value_dim", "interpret"))
+def _paged_decode_attention_latent_call(q, slab, tables, lengths,
+                                        value_dim: int, interpret: bool):
+    """Its own jitted name, so a trace tells the kernel from the (K, V)
+    variants."""
+    B, H, E = q.shape
+    W = tables.shape[1]
+    page_len = slab.shape[1]
+    kernel = functools.partial(_latent_attn_kernel, page_len=page_len,
+                               value_dim=value_dim)
+    page_spec = pl.BlockSpec(
+        (1, page_len, E),
+        lambda b, w, tbl, lens: (
+            tbl[b, jnp.minimum(w, (lens[b] - 1) // page_len)], 0, 0))
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, W),
+            in_specs=[pl.BlockSpec((1, H, E),
+                                   lambda b, w, tbl, lens: (b, 0, 0)),
+                      page_spec],
+            out_specs=pl.BlockSpec((1, H, value_dim),
+                                   lambda b, w, tbl, lens: (b, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((H, value_dim), jnp.float32),  # accumulator
+                pltpu.VMEM((H, 1), jnp.float32),          # running max m
+                pltpu.VMEM((H, 1), jnp.float32),          # normalizer l
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, H, value_dim), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+    )(tables, lengths, q, slab)
+
+
+def paged_decode_attention_latent(q, slab, tables, lengths, value_dim: int,
+                                  interpret: bool | None = None) -> jax.Array:
+    """Absorbed decode attention of a latent layer, directly over its page
+    slab. ``q`` (B, H, entry): each row's per-head query against a cache
+    ENTRY (the softmax scale already folded in); ``slab`` ``(num_pages,
+    page_len, entry)``; ``tables`` (B, W) and ``lengths`` (B,) as
+    :func:`paged_decode_attention`. Row ``b`` attends the entries at
+    positions below ``lengths[b]``: score ``q . entry``, value the entry's
+    first ``value_dim`` columns. Returns the attended values (B, H,
+    value_dim) in ``q``'s dtype; the caller applies the value half of the
+    up-projection. Each page a row holds is read once, in place, and a page
+    past the row's length not at all."""
+    q = jnp.asarray(q)
+    if q.ndim != 3 or slab.ndim != 3 or q.shape[2] != slab.shape[2]:
+        raise ValueError(f"q must be (B, H, entry) and the slab (num_pages, "
+                         f"page_len, entry), got {q.shape} and {slab.shape}")
+    if not 0 < value_dim <= slab.shape[2]:
+        raise ValueError(f"value_dim {value_dim} is not within the entry's "
+                         f"{slab.shape[2]} columns")
+    page_len = int(slab.shape[1])
+    if page_len % PAGE_SUBLANE:
+        raise ValueError(
+            f"page_len {page_len} is not a multiple of {PAGE_SUBLANE}; size "
+            f"pages through align_page_len()")
+    tables = jnp.asarray(tables, jnp.int32)
+    if tables.ndim != 2 or tables.shape[0] != q.shape[0]:
+        raise ValueError(f"tables must be (B, W) with B={q.shape[0]}, got "
+                         f"{tables.shape}")
+    if interpret is None:
+        interpret = _interpret()
+    lengths = jnp.clip(jnp.asarray(lengths, jnp.int32), 1,
+                       tables.shape[1] * page_len)
+    return _paged_decode_attention_latent_call(
+        q, slab, tables, lengths, value_dim=int(value_dim),
+        interpret=bool(interpret))

@@ -1858,7 +1858,9 @@ class ServeEngine:
         the pages resident LM rows hold (reserved up front for prompt +
         steps), ``kv_tokens`` the positions of them that are written (a row
         mid-prefill has its prefilled prompt), ``pages_used`` adds what the
-        prefix cache keeps."""
+        prefix cache keeps, ``shared_pages`` are the pages with more than one
+        referent (rows and the cache) and ``cached_pages`` the cache's
+        entries."""
         resident = live = row_pages = window_pages = kv_tokens = 0
         for g in pools.values():
             if isinstance(g, ProgramRowSet):
@@ -1877,7 +1879,9 @@ class ServeEngine:
         out = {"queue_depth": self._queue.count, "resident_rows": resident,
                "live_rows": live, "row_pages": row_pages,
                "pages_used": pool.used_count(),
-               "pages_total": pool.capacity, "kv_tokens": kv_tokens}
+               "pages_total": pool.capacity, "kv_tokens": kv_tokens,
+               "shared_pages": pool.shared_count(),
+               "cached_pages": pool.cached_count()}
         if self._spec is not None:
             # both classes beside their sum: a row's global table covers
             # every position, its window ring a bounded few
@@ -1971,7 +1975,8 @@ class ServeEngine:
                     total=pool.capacity)
                 span.set_metadata(queue_wait_ms=1e3 * e.queue_s,
                                   pages=len(spages) + len(owned),
-                                  shared_pages=len(spages))
+                                  shared_pages=len(spages),
+                                  prompt_tokens=n, shared_tokens=shared_len)
         self._record_pages(pool)
 
     def _prefill_paged_chunk(self, pool, pools, pf_queue) -> None:
@@ -2258,6 +2263,11 @@ class ServeEngine:
             if self._spec is None:
                 # analyze: ignore[host-sync] — host numpy bookkeeping
                 span.set_metadata(kv_pages=int((at + 1).sum()))
+            elif self._spec.latent is not None:
+                span.set_metadata(
+                    latent_table_width=tables.shape[1],
+                    # analyze: ignore[host-sync] — host numpy bookkeeping
+                    latent_kv_pages=int((at + 1).sum()))
             else:
                 low = (np.maximum(positions[:rows]
                                   - self._spec.window + 1, 0)

@@ -58,6 +58,16 @@ can pin; :meth:`PagedKVPool.audit` balances both. Window pages are never
 shared: with such a model the prefix cache is off and the migration entry
 points raise.
 
+**A page that is one array** (a ``ModelSpec``'s latent-attention layers): a
+layer's slab is a tuple of arrays indexed by the same page id, ``(k, v)``
+for the layers above and ONE array ``(num_pages, page_len, entry_width)``
+for a latent layer (:func:`~marlin_tpu.models.hybrid.init_kv_pages`).
+Nothing here assumes the pair: the page-copy program, the host fetch and
+flush, and the migration blobs walk each layer's arrays in order, and
+:func:`~marlin_tpu.models.planner.kv_page_bytes` prices what a page id
+holds. Latent pages are of the global class, so they are shared through the
+prefix cache and migrate like any other.
+
 Everything here is host-side numpy/stdlib except the three compiled
 programs it drives; single-threaded by contract (only the engine worker
 touches a pool).
@@ -97,7 +107,8 @@ class MigrationCorruptError(RuntimeError):
 _MIG_MAGIC = b"MGRT"
 _MIG_HDR = struct.Struct("<4sIIQ12x")  # 32 bytes
 _MIG_META = 1      # JSON metadata (geometry + per-row/per-entry manifest)
-_MIG_ROW = 2       # one row's page contents, layers in order, k then v
+_MIG_ROW = 2       # one row's page contents, layers in order, each layer's
+#                    arrays in order (k then v; a latent layer's one)
 _MIG_PREFIX = 3    # prefix-cache pages (one body for the whole entry set)
 
 
@@ -487,10 +498,11 @@ class PagedKVPool:
     def _row_nbytes(self, n_pages: int) -> int:
         geo = self._geometry()
         item = np.dtype(geo["dtype"]).itemsize
-        per_page = sum(int(np.prod([self.page_len] + shape[1:]))
-                       for shape in geo["shapes"])
-        # shapes[i] is (page_len, kvh, dh); k and v slabs per layer
-        return 2 * n_pages * per_page * item
+        # shapes[i] is one page of layer i's arrays ((page_len, kvh, dh) for
+        # K and for V; (page_len, entry) for a latent layer's one array)
+        per_page = sum(int(np.prod(shape)) * len(self.pages[name])
+                       for name, shape in zip(geo["layers"], geo["shapes"]))
+        return n_pages * per_page * item
 
     def export_rows(self, rows) -> bytes:
         """Serialize a row set into a CRC-framed host blob. Each element of
@@ -511,8 +523,8 @@ class PagedKVPool:
         for r in meta["rows"]:
             pids = np.asarray(r["pages"], np.int64)
             body = b"".join(
-                np.ascontiguousarray(host[name][half][pids]).tobytes()
-                for name in names for half in (0, 1))
+                np.ascontiguousarray(arr[pids]).tobytes()
+                for name in names for arr in host[name])
             blob.append(_mig_frame(_MIG_ROW, body))
         return b"".join(blob)
 
@@ -574,11 +586,11 @@ class PagedKVPool:
                     for name, shape in zip(names, meta["shapes"]):
                         cnt = n_pages * int(np.prod(shape))
                         nb = cnt * dtype.itemsize
-                        for half in (0, 1):
+                        for slab in host[name]:
                             arr = np.frombuffer(
                                 body, dtype, cnt, off).reshape(
                                     [n_pages] + shape)
-                            host[name][half][owned] = arr[len(spages):]
+                            slab[owned] = arr[len(spages):]
                             off += nb
                 row = dict(row, pages=pages, n_shared=len(spages),
                            shared_len=shared_len)
@@ -633,8 +645,8 @@ class PagedKVPool:
                 "parent": None if e.parent is None else e.parent.hex()})
             pid = np.asarray([e.page], np.int64)
             body.append(b"".join(
-                np.ascontiguousarray(host[name][half][pid]).tobytes()
-                for name in names for half in (0, 1)))
+                np.ascontiguousarray(arr[pid]).tobytes()
+                for name in names for arr in host[name]))
         meta = {"version": 1, "kind": "prefixes", **self._geometry(),
                 "entries": entries}
         return (_mig_frame(_MIG_META, json.dumps(meta).encode())
@@ -693,8 +705,8 @@ class PagedKVPool:
             for name, shape in zip(names, meta["shapes"]):
                 cnt = int(np.prod(shape))
                 nb = cnt * dtype.itemsize
-                for half in (0, 1):
-                    host[name][half][page] = np.frombuffer(
+                for slab in host[name]:
+                    slab[page] = np.frombuffer(
                         body, dtype, cnt, off).reshape(shape)
                     off += nb
             self._cache[key] = _CacheEntry(page, parent)

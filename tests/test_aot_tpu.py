@@ -715,3 +715,54 @@ def test_dropless_expert_layer_compiles_for_v5e_as_a_grouped_matmul(one_chip):
                 mp, st((32, d)), st((32,), jnp.bool_)).lower().compile()
     text = c.as_text()
     assert text.count("tpu_custom_call") >= 3 and "ragged-dot" not in text
+
+
+def test_latent_decode_kernel_mosaic_compiles_in_place_at_published_widths(
+        one_chip):
+    """The latent decode kernel at Mistral-Small-4's widths through real
+    Mosaic: 32 heads against an entry of 320 values stored in 384 columns,
+    the value its first 256, pages of 256, 32 rows over 70 pages. The slab
+    is read IN PLACE: the compiled call holds no temporary (handed a slab of
+    320 columns the compiler copies all of it into a padded one first, 0.5
+    GB, which is why ``LatentSpec.entry_width`` pads)."""
+    from marlin_tpu.ops.paged_attention import \
+        _paged_decode_attention_latent_call
+
+    def st(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def compiled(entry):
+        return _paged_decode_attention_latent_call.trace(
+            st((32, 32, entry), jnp.bfloat16),
+            st((2561, 256, entry), jnp.bfloat16), st((32, 70)), st((32,)),
+            value_dim=256, interpret=False).lower().compile()
+
+    c = compiled(384)
+    assert "tpu_custom_call" in c.as_text()
+    assert c.memory_analysis().temp_size_in_bytes < 1 << 20
+    assert compiled(320).memory_analysis().temp_size_in_bytes \
+        >= 2561 * 256 * 384 * 2
+
+
+def test_sigmoid_expert_layer_compiles_for_v5e_at_published_widths(one_chip):
+    """``moe_experts_ffn`` under sigmoid scoring at Mistral-Small-4's widths
+    (hidden 4096, 32 held experts of width 2048, router 128, top-4) for a
+    decode call's 32 rows: the experts run through the grouped matmul
+    kernel."""
+    from marlin_tpu.models.moe import moe_experts_ffn
+
+    def st(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    d, e, f = 4096, 32, 2048
+    mp = {"router": st((d, 128), jnp.float32),
+          "e_bias": st((128,), jnp.float32), "e_gate": st((e, d, f)),
+          "e_up": st((e, d, f)), "e_down": st((e, f, d)),
+          "s_gate": st((d, f)), "s_up": st((d, f)), "s_down": st((f, d))}
+    with mt.config_context(pallas_interpret=False):
+        c = jax.jit(lambda mp, h, valid: moe_experts_ffn(
+            mp, h, valid, top_k=4, first_expert=0,
+            scoring="sigmoid")).trace(
+                mp, st((32, d)), st((32,), jnp.bool_)).lower().compile()
+    text = c.as_text()
+    assert text.count("tpu_custom_call") >= 3 and "ragged-dot" not in text

@@ -376,14 +376,20 @@ def test_row_pages_and_kv_tokens_match_the_pool_by_hand(params, tmp_path):
     iters = [s.fields for s in got["spans"] if s.name == "serve.iter"]
     total = iters[0]["pages_total"]
     empty = {"resident_rows": 0, "live_rows": 0, "row_pages": 0,
-             "pages_used": 0, "kv_tokens": 0}
+             "pages_used": 0, "kv_tokens": 0, "shared_pages": 0,
+             "cached_pages": 0}
     # as each iteration begins: nothing resident; both rows live with their
-    # prompts (5 + 7) and the first decode's entry each in the cache
+    # prompts (5 + 7) and the first decode's entry each in the cache; each
+    # prompt has one whole page before its last token's, and the prompts
+    # begin alike, so the prefix cache holds it once, for the row that
+    # finished first: one entry, one page with two referents
+    cached = 1
     assert iters[0] == {"queue_depth": 2, "pages_total": total, **empty}
     assert iters[1] == {"queue_depth": 2, "pages_total": total,
                         "resident_rows": 2, "live_rows": 2,
                         "row_pages": pages, "pages_used": pages,
-                        "kv_tokens": 5 + 7 + 2}
+                        "kv_tokens": 5 + 7 + 2, "shared_pages": cached,
+                        "cached_pages": cached}
     assert iters[2]["resident_rows"] == 0 and iters[2]["row_pages"] == 0
     admits = [s.fields for s in got["spans"] if s.name == "serve.admit"]
     assert [a["pages"] for a in admits[:2]] == [2, 3]

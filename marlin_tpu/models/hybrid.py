@@ -1044,11 +1044,13 @@ def _attend_gather(q, pk, pv, tables, lengths, first_page, lower,
 def _lm_decode_paged_spec_jit(params, pages, gtables, wtables, positions,
                               cur_tokens, steps_done, seeds, temperature,
                               top_p, top_k, spec: ModelSpec, page_len: int,
-                              kernel: str):
+                              kernel: str, prev_tokens=None, prev_index=None):
     from ..ops.paged_attention import (paged_decode_attention,
                                        paged_decode_attention_latent)
-    from .transformer import _pick_token_rows, _scatter_kv_entries
+    from .transformer import (_pick_token_rows, _scatter_kv_entries,
+                              _select_tokens)
 
+    cur_tokens = _select_tokens(cur_tokens, prev_tokens, prev_index)
     B, Wg = gtables.shape
     ring = wtables.shape[1]
     rows = jnp.arange(B)
@@ -1117,7 +1119,10 @@ def _lm_decode_paged_spec_jit(params, pages, gtables, wtables, positions,
 
 def _decode_args(params, pages, tables, positions, cur_tokens, steps_done,
                  seeds, temperature, top_p, top_k, spec: ModelSpec,
-                 page_len: int, kernel: str):
+                 page_len: int, kernel: str, prev_tokens=None,
+                 prev_index=None):
+    from .transformer import _fed_tokens
+
     gtables, wtables = tables
     as_i32 = lambda v: jnp.asarray(v, jnp.int32)  # noqa: E731
     return (params, pages, as_i32(gtables), as_i32(wtables),
@@ -1125,12 +1130,14 @@ def _decode_args(params, pages, tables, positions, cur_tokens, steps_done,
             jnp.asarray(seeds, jnp.uint32),
             jnp.asarray(temperature, jnp.float32),
             jnp.asarray(top_p, jnp.float32), as_i32(top_k)), {
-                "spec": spec, "page_len": page_len, "kernel": kernel}
+                "spec": spec, "page_len": page_len, "kernel": kernel,
+                **_fed_tokens(prev_tokens, prev_index)}
 
 
 def decode_paged(params, pages, tables, positions, cur_tokens, steps_done,
                  seeds, temperature, top_p, top_k, spec: ModelSpec,
-                 page_len: int, kernel: str):
+                 page_len: int, kernel: str, prev_tokens=None,
+                 prev_index=None):
     """:func:`~marlin_tpu.models.transformer.lm_decode_paged` for a spec:
     ``tables`` is ``(global tables (B, W), window rings (B, ring))``; a row
     whose global table starts at the dummy page is a dummy row and is routed
@@ -1138,7 +1145,8 @@ def decode_paged(params, pages, tables, positions, cur_tokens, steps_done,
     :func:`prefill_paged`."""
     args, static = _decode_args(params, pages, tables, positions, cur_tokens,
                                 steps_done, seeds, temperature, top_p, top_k,
-                                spec, page_len, kernel)
+                                spec, page_len, kernel, prev_tokens,
+                                prev_index)
     return _lm_decode_paged_spec_jit(*args, **static)
 
 

@@ -1013,7 +1013,8 @@ def lm_decode_paged(params, pages, tables, positions, cur_tokens,
                     steps_done, seeds, temperature, top_p, top_k,
                     heads: int, page_len: int,
                     compute_dtype: str | None = None,
-                    moe: tuple | None = None, kernel: str | None = None):
+                    moe: tuple | None = None, kernel: str | None = None,
+                    prev_tokens=None, prev_index=None):
     """One decode step for every row of a call over the paged pool.
 
     ``pages`` is the pool slab (DONATED). ``tables`` is (B, W) int32 block
@@ -1021,7 +1022,12 @@ def lm_decode_paged(params, pages, tables, positions, cur_tokens,
     still prefilling: it computes a masked-harmless step against page 0
     whose outputs the scheduler ignores. ``cur_tokens`` is each row's last
     emitted token (the engine keeps the token stream host-side; the result
-    is built from it). The remaining per-row vectors, all (B,):
+    is built from it). ``prev_tokens``/``prev_index`` feed a row its token
+    from the DEVICE instead: where ``prev_index[b] >= 0`` row ``b``'s token
+    is ``prev_tokens[prev_index[b]]`` (an earlier call's ``next_tokens``,
+    which the host need not have seen: the engine dispatches a step before
+    the last one has landed), elsewhere ``cur_tokens[b]``. The remaining
+    per-row vectors, all (B,):
     ``positions`` the index of each row's last written token (the caller
     guarantees ``positions + 1 < W * page_len`` for live rows),
     ``steps_done`` the emitted-token count feeding the per-row sampling
@@ -1053,7 +1059,7 @@ def lm_decode_paged(params, pages, tables, positions, cur_tokens,
         return hybrid.decode_paged(
             params, pages, tables, positions, cur_tokens, steps_done, seeds,
             temperature, top_p, top_k, heads, page_len,
-            resolve_decode_kernel(kernel))
+            resolve_decode_kernel(kernel), prev_tokens, prev_index)
     as_i32 = lambda v: jnp.asarray(v, jnp.int32)  # noqa: E731
     return _lm_decode_paged_jit(
         params, pages, as_i32(tables), as_i32(positions),
@@ -1062,7 +1068,42 @@ def lm_decode_paged(params, pages, tables, positions, cur_tokens,
         jnp.asarray(temperature, jnp.float32),
         jnp.asarray(top_p, jnp.float32), as_i32(top_k),
         heads=heads, page_len=page_len, compute_dtype=compute_dtype, moe=moe,
-        kernel=resolve_decode_kernel(kernel))
+        kernel=resolve_decode_kernel(kernel),
+        **_fed_tokens(prev_tokens, prev_index))
+
+
+def _fed_tokens(prev_tokens, prev_index) -> dict:
+    """The decode programs' two optional inputs as int32 arrays (both or
+    neither: without them a program is traced without the select)."""
+    if prev_tokens is None:
+        return {}
+    return {"prev_tokens": jnp.asarray(prev_tokens, jnp.int32),
+            "prev_index": jnp.asarray(prev_index, jnp.int32)}
+
+
+def _select_tokens(cur_tokens, prev_tokens, prev_index):
+    """Each row's current token: ``prev_tokens[prev_index]`` where the index
+    is not negative (a token the host has not seen), else ``cur_tokens``."""
+    if prev_tokens is None:
+        return cur_tokens
+    return jnp.where(prev_index >= 0,
+                     prev_tokens[jnp.maximum(prev_index, 0)], cur_tokens)
+
+
+def feed_token(feed, index, token):
+    """``feed`` (a decode call's ``next_tokens``, or zeros before the first
+    call) with ``token`` written at ``index``: how a final prefill chunk's
+    first token joins the tokens the next decode call is fed from the
+    device (``prev_tokens``) without the host seeing it. All traced: ONE
+    compiled program per feed width."""
+    return _feed_token_jit(jnp.asarray(feed, jnp.int32),
+                           jnp.asarray(index, jnp.int32),
+                           jnp.asarray(token, jnp.int32))
+
+
+@jax.jit
+def _feed_token_jit(feed, index, token):
+    return feed.at[index].set(token)
 
 
 def _scatter_kv_entries(pk, pv, k_new, v_new, pids, off):
@@ -1136,8 +1177,10 @@ def _decode_paged_pallas(params, pages, tables, pos, x, heads: int,
 def _lm_decode_paged_jit(params, pages, tables, positions, cur_tokens,
                          steps_done, seeds, temperature, top_p, top_k,
                          heads: int, page_len: int, compute_dtype,
-                         moe=None, kernel: str = "gather"):
+                         moe=None, kernel: str = "gather",
+                         prev_tokens=None, prev_index=None):
     B, W = tables.shape
+    cur_tokens = _select_tokens(cur_tokens, prev_tokens, prev_index)
     L = W * page_len
     rows = jnp.arange(B)
     cdtype = jnp.dtype(compute_dtype) if compute_dtype else params["emb"].dtype

@@ -16,17 +16,23 @@ Admission charges the request's ACTUAL pages
 long bucket does not reserve the bucket's worst case), completed full
 prompt pages are prefix-shared copy-on-write across requests (a common
 system prompt is prefilled once — :class:`~.kvpool.PagedKVPool`), and long
-prompts prefill in bounded ``serve_prefill_chunk``-token chunks. Every
+prompts prefill in bounded ``serve_prefill_chunk``-token chunks. The worker
+lands a result only after it has dispatched the work that follows it (a
+pipeline one call deep: the chip always holds the next program). Every
 worker iteration:
 
     refill freed rows from the queue (page allocation + prefix match —
-    host-side, cheap)  →  prefill at most ``serve_prefill_chunk`` prompt
-    TOKENS, oldest row first, in page-aligned chunks (several short rows
-    may share the budget; a long prompt takes one chunk and resumes next
-    iteration; a row's final chunk emits its first token — real TTFT)
-    →  retire rows that emitted ``eos``, hit their step budget, or
-    expired  →  run ONE decode step over the live rows of EVERY bucket,
-    packed into one call of the one decode program  →  repeat
+    host-side, cheap)  →  dispatch at most ``serve_prefill_chunk`` prompt
+    TOKENS of prefill, oldest row first, in page-aligned chunks (several
+    short rows may share the budget; a long prompt takes one chunk and
+    resumes next iteration; a row's final chunk makes it decode-ready, its
+    first token still on the device)  →  retire rows that expired  →
+    dispatch ONE decode step over the live rows of EVERY bucket, packed
+    into one call of the one decode program and fed from the device with
+    the tokens the host has not seen  →  land the PREVIOUS decode call
+    (announce its tokens, retire rows that emitted ``eos`` or hit their
+    step budget), then the first tokens of this iteration's final chunks —
+    real TTFT  →  repeat
 
 so one long prompt can never monopolize an iteration — decode steps
 interleave between its chunks, bounding TTFT for everyone else. Rows are
@@ -80,6 +86,7 @@ import collections
 import itertools
 import threading
 import time
+import typing
 import weakref
 
 import numpy as np
@@ -134,6 +141,117 @@ def _call_rows(call) -> list:
     """``(group, slot)`` of every row a decode call carries, in the call's
     row order."""
     return [(group, i) for group, slots in call for i in slots]
+
+
+class _Launch(typing.NamedTuple):
+    """A decode call the device has and the host has not landed: the rows
+    it carried as ``(group, slot, entry)`` (the landing matches by ENTRY: a
+    slot may have been retired and refilled meanwhile), its tokens and
+    expert counts still on the device, and the step-record accounting of
+    the step it belongs to (``steps`` shared by the step's calls, ``due``
+    the buckets announced at this call's landing). ``seq`` is its place
+    among the programs dispatched (:meth:`_Pipeline.dispatched`);
+    ``leaving`` holds, by index into ``rows``, the rows that have left
+    their slots with this call in flight (:class:`_Leaving`)."""
+
+    rows: list
+    tag: str
+    t0: float
+    nxt: object
+    counts: object
+    steps: dict
+    due: list
+    seq: int
+    leaving: dict
+
+
+class _First(typing.NamedTuple):
+    """A final prefill chunk whose first token has not landed."""
+
+    group: PagedGroup
+    slot: int
+    entry: "_Entry"
+    first: object
+    counts: object
+    t0: float
+    chunk: list
+    seq: int
+
+
+class _Leaving(typing.NamedTuple):
+    """A row that has left its slot: what its Result is built from. A row
+    whose step budget ends with the decode call in flight leaves as soon
+    as that call is all it waits for, so the slot and the pages are free
+    for the next claim (every later program is behind the call in the
+    device's stream); the call's landing appends the last token to
+    ``emitted`` and answers the request."""
+
+    entry: "_Entry"
+    emitted: list
+    metrics: dict  # all of the Result's but ``total_s``
+
+
+class _Pipeline:
+    """What one worker generation has dispatched and not landed. The loop
+    is a pipeline ONE call deep: a decode call lands only after the call
+    that follows it has been dispatched, so the device always holds the
+    next program while the host packs, lands and retires.
+
+    ``call`` is the decode call in flight, ``firsts`` the final chunks
+    whose first token is still on the device. ``feed`` is the device vector
+    (``max_batch`` tokens) the next decode call is fed from: the last
+    call's ``next_tokens``, with the first tokens of final chunks written
+    over the entries in ``free`` (those no continuing row reads). Every
+    decode dispatch replaces it and bumps ``serial``; a row knows its
+    entry by ``(fed_serial, fed_index)``, so an entry of a replaced feed is
+    never read (by then the row's token has landed: depth is one).
+
+    ``seq`` numbers the programs dispatched (prefill chunks and decode
+    calls, in the device's order) and ``landed_seq`` is the last of them
+    the host has seen end: a landing's interval (``landed_t`` to now) is
+    one program's wall time only where the program before it had landed
+    (:meth:`landed`)."""
+
+    def __init__(self, width: int):
+        self.width = width
+        self.call: _Launch | None = None
+        self.landing: _Launch | None = None  # the call being landed
+        self.firsts: list[_First] = []
+        self.landed_t = 0.0  # perf_counter at the last landing of either
+        self.seq = self.landed_seq = 0
+        self.serial = 0
+        self.reset_feed()
+
+    def busy(self) -> bool:
+        return self.call is not None or bool(self.firsts)
+
+    def leaving(self) -> list:
+        """The entries that have left their slots and await their last
+        token: in no group, so whoever gathers a generation's rows (the
+        crash handler, a recovery) asks here too. Safe from another
+        thread: a snapshot."""
+        return [left.entry for call in (self.landing, self.call)
+                if call is not None for left in list(call.leaving.values())]
+
+    def dispatched(self) -> int:
+        self.seq += 1
+        return self.seq
+
+    def landed(self, seq: int) -> bool:
+        """Program ``seq`` has ended. True where the one before it had been
+        seen to end: the interval since the last landing is then this
+        program's alone (a chunk that is not final is never landed, so its
+        device time lies in the next landing's interval)."""
+        alone = self.landed_seq >= seq - 1
+        self.landed_seq = max(self.landed_seq, seq)
+        return alone
+
+    def reset_feed(self) -> None:
+        """Forget the device's tokens (a rebuilt pool: the old stream's
+        arrays may be poisoned, and every row that read them is gone)."""
+        self.feed = np.zeros(self.width, np.int32)
+        self.serial += 1
+        self.free = list(range(self.width))
 
 
 class MigrationError(RuntimeError):
@@ -364,6 +482,7 @@ class ServeEngine:
         # without touching shared state (its entries are superseded)
         self._gen = 0
         self._pools: dict[tuple, object] = {}   # current worker's slot pools
+        self._pipe: _Pipeline | None = None     # ... and what it has in flight
         self._claimed: list = []                # claimed-but-unslotted rows
         self._crash: tuple | None = None        # (exc, undone entries)
         self._on_crash = None                   # supervisor's prompt-wake cb
@@ -1243,7 +1362,12 @@ class ServeEngine:
                 stash = [e for p in self._pools.values()
                          for e in list(p.entries) if e is not None]
                 stash += list(self._claimed)
+                if self._pipe is not None:
+                    # rows that left their slots with their last call in
+                    # flight are in no group
+                    stash += self._pipe.leaving()
             self._pools = {}
+            self._pipe = None
             self._claimed = []
             # the paged pool's slab/block-table/prefix-cache state died
             # with the worker: drop it wholesale; it rebuilds zeroed on
@@ -1732,26 +1856,40 @@ class ServeEngine:
     # --------------------------------------------------- paged scheduler
 
     def _run_paged(self, gen: int) -> None:
-        """The paged slot-step loop: each iteration refills freed rows from
-        the queue (page allocation + prefix match — host-side), runs
-        prefill chunks up to the ``serve_prefill_chunk`` TOKEN budget
-        (oldest rows first), then one decode step over the live rows of
-        every bucket, packed into one call (:meth:`_step_paged`) — chunked
-        prefill interleaves with decode, so a long prompt never monopolizes
-        an iteration. ``pools`` maps bucket -> PagedGroup
+        """The paged slot-step loop, a pipeline ONE call deep: the worker
+        lands a result only after it has dispatched the work that follows
+        it. Each iteration refills freed rows from the queue (page
+        allocation + prefix match — host-side), dispatches prefill chunks
+        up to the ``serve_prefill_chunk`` TOKEN budget (oldest rows first;
+        no chunk is waited for here), dispatches one decode step over the
+        live rows of every bucket, packed into one call
+        (:meth:`_step_paged`), and only then lands the PREVIOUS decode call
+        (its tokens announced, its rows retired) and the first tokens of
+        this iteration's final chunks — while the chip runs what was just
+        dispatched. The tokens of a call in flight stay on the device: the
+        next call is fed from them (:class:`_Pipeline`), and the host's
+        cursors (``positions``, ``steps_done``) advance at dispatch; a row
+        whose step budget ends with the call in flight has left its slot for
+        the next claim to refill (:meth:`_leave_paged`).
+        Chunked prefill interleaves with decode, so a long prompt never
+        monopolizes an iteration. ``pools`` maps bucket -> PagedGroup
         over the engine's
         one shared :class:`PagedKVPool`; ``pf_queue`` is the FIFO of rows
         mid-prefill ((bucket, slot, rid) — rid guards against a retired
-        slot's re-occupant inheriting a stale cursor).
-        ``self._pools``/``self._claimed`` mirror the worker's hands so a
-        supervisor recovering a STUCK generation (watchdog timeout — the
+        slot's re-occupant inheriting a stale cursor); ``pipe`` is what is
+        in flight. Every way out lands it first (:meth:`_drain_paged`), but
+        a superseded generation's, whose rows the recovery already requeued.
+        ``self._pools``/``self._claimed``/``self._pipe`` mirror the worker's
+        hands so a supervisor recovering a STUCK generation (watchdog timeout — the
         thread is alive but unreachable) can still find every in-flight
         entry to requeue."""
         pools: dict[tuple, PagedGroup] = {}
+        pipe = _Pipeline(self.max_batch)
         with self._cond:
             if self._gen != gen:
                 return  # superseded before the first iteration
             self._pools = pools
+            self._pipe = pipe
             # the GENERATION-LOCAL pool binding: every helper below takes
             # this pool, never self._kvpool — a stuck-but-alive superseded
             # worker resuming mid-iteration must mutate only its own dead
@@ -1777,6 +1915,7 @@ class ServeEngine:
                 faults.fire("serve.worker_crash",
                             path=threading.current_thread().name)
                 claimed = []
+                freezing = False
                 with self._cond:
                     while True:
                         with annotate("serve.claim") as span:
@@ -1786,9 +1925,16 @@ class ServeEngine:
                                 # migration pause: park WITHOUT touching
                                 # the pools — freeze_rows() joins this
                                 # thread and takes over every resident row
-                                return
-                            busy = any(p.occupied_slots()
-                                       for p in pools.values())
+                                # (what is in flight lands first, below,
+                                # outside the lock)
+                                freezing = True
+                                break
+                            # a call in flight still has to land, though
+                            # no row of it may hold a slot any more (they
+                            # left with their last step out, or were
+                            # retired under it)
+                            busy = pipe.busy() or any(
+                                p.occupied_slots() for p in pools.values())
                             if self._mig_inbox:
                                 break  # service migration ops unlocked
                             if self._state == "closing":
@@ -1813,6 +1959,11 @@ class ServeEngine:
                         if self._gen == gen:
                             self._heartbeat = time.monotonic()
                     self._claimed = claimed
+                if freezing:
+                    # freeze_rows exports positions / cur_tok / emitted:
+                    # they must be the landed ones
+                    self._drain_paged(pool, pools, pipe)
+                    return
                 # the iteration that does the work: opened once the claim
                 # (and any wait) has returned, so its counts are this
                 # iteration's and an idle engine holds no open span. The
@@ -1830,6 +1981,7 @@ class ServeEngine:
                             # generation must never build (or adopt) the
                             # live generation's pool
                             pool = self._ensure_kvpool()
+                            pipe.reset_feed()
                     self._service_migrations(pool, pools, pf_queue)
                     prog_claimed = [e for e in claimed
                                     if self._is_program_bucket(e.bucket)]
@@ -1841,13 +1993,17 @@ class ServeEngine:
                     with self._cond:
                         if self._gen == gen:  # never clobber a successor's
                             self._claimed = []  # claimed mirror
-                    self._prefill_paged_chunk(pool, pools, pf_queue)
-                    self._step_paged(pool, pools)
+                    self._prefill_paged_chunk(pool, pools, pf_queue, pipe)
+                    self._step_paged(pool, pools, pipe)
                     self._step_program_rows(pools)
         except BaseException as exc:  # worker death: recover or fail held
+            # (what was in flight is dropped with the generation: its rows
+            # are still in their slots, or in the flight's ``leaving``, so
+            # the handler finds every one)
             held = [p.entries[i] for p in pools.values()
                     for i in p.occupied_slots()]
-            if self._crash_handler(exc, claimed + held, gen):
+            if self._crash_handler(exc, claimed + held + pipe.leaving(),
+                                   gen):
                 return
             raise
 
@@ -1979,32 +2135,38 @@ class ServeEngine:
                                   prompt_tokens=n, shared_tokens=shared_len)
         self._record_pages(pool)
 
-    def _prefill_paged_chunk(self, pool, pools, pf_queue) -> None:
-        """Run bounded prefill for this iteration — the chunked-prefill
+    def _prefill_paged_chunk(self, pool, pools, pf_queue, pipe) -> None:
+        """Dispatch bounded prefill for this iteration — the chunked-prefill
         scheduling contract: at most ``serve_prefill_chunk`` prompt TOKENS
         of prefill per worker iteration (several short prompts may share
         the budget; one long prompt consumes it in a single chunk and
         resumes next iteration), decode steps interleaving in between so a
         long prompt never monopolizes the worker. Rows prefill oldest
-        first — FIFO TTFT fairness. A row's final chunk (the one
-        containing the prompt's last token) emits its first token — real
-        TTFT — caches the completed prompt pages for prefix sharing, and
-        flips the row decode-ready."""
+        first — FIFO TTFT fairness. No chunk is waited for here: a chunk's
+        pages reach the next program by data dependence, and a row's final
+        chunk (the one containing the prompt's last token) flips the row
+        decode-ready with its first token still on the device —
+        :meth:`_land_first` brings it, after this iteration's decode
+        dispatch."""
         budget = self._prefill_chunk
         chunks = 0
         with annotate("serve.prefill") as span:
             while budget > 0 and pf_queue:
-                used = self._prefill_one_chunk(pool, pools, pf_queue)
+                used = self._prefill_one_chunk(pool, pools, pf_queue, pipe)
                 budget -= used
                 chunks += used > 0
             span.set_metadata(chunks=chunks,
                               tokens=self._prefill_chunk - budget)
         self._live_rows = sum(len(g.live_slots()) for g in pools.values())
 
-    def _prefill_one_chunk(self, pool, pools, pf_queue) -> int:
-        """One chunk for the head of the prefill queue; returns the real
-        prompt tokens it consumed (0 ends the caller's budget loop —
-        nothing left to prefill, or the head row just failed)."""
+    def _prefill_one_chunk(self, pool, pools, pf_queue, pipe) -> int:
+        """Dispatch one chunk for the head of the prefill queue; returns the
+        real prompt tokens it consumed (0 ends the caller's budget loop —
+        nothing left to prefill, or the head row just failed). A chunk that
+        is not final is never synced on (an error in it surfaces at the
+        next landing, which finds the slab unusable and requeues every
+        resident row); a final one joins ``pipe.firsts`` and its token the
+        device feed, so the row rides this iteration's decode call."""
         while pf_queue:
             bucket, slot, rid = pf_queue[0]
             group = pools.get(bucket)
@@ -2059,38 +2221,106 @@ class ServeEngine:
                         top_p=r.top_p, top_k=r.top_k,
                         compute_dtype=self.compute_dtype, moe=self.moe)
                     pages, first, counts = _program_result(out)
-                with annotate("serve.prefill.sync", rid=r.rid,
-                              final=int(final)) as sync:
-                    first = int(first)  # device sync: the chunk landed
-                    self._moe_counts(sync, counts)
-                wall = time.perf_counter() - t0
             except Exception as exc:
                 pf_queue.popleft()
                 self._paged_prefill_failure(pool, pools, bucket, slot, exc)
                 return 0  # end this iteration's budget loop
             pool.pages = pages
+            seq = pipe.dispatched()
             group.pf_next[slot] = cs + C
-            self.metrics.record_prefill(
-                e.bucket, wall, rid=r.rid,
-                program_key=self._prog_key(e.bucket),
-                program="lm_prefill_paged", chunk=[cs, tokens], final=final)
             self.flight.record(
                 "prefill", bucket=[p, s], slot=slot, rid=r.rid,
-                seconds=wall, chunk=[cs, tokens],
+                seconds=time.perf_counter() - t0, chunk=[cs, tokens],
                 queue_depth=self._queue.count, compiles=_compile_count(),
                 pages_used=pool.used_count())
-            if final:
-                pf_queue.popleft()
-                group.finish_prefill(slot, first)
-                group.ttft_s[slot] = self._clock() - e.enq_t
-                # the prompt's full pages are final now — publish them for
-                # copy-on-write reuse by later identical prefixes
-                pool.insert_prefix(r.prompt, group.row_pages[slot])
-                self._record_pages(pool)
-                if r.steps == 1 or (r.eos is not None and first == r.eos):
-                    self._retire_row_paged(pool, pools, bucket, slot,
-                                           STATUS_OK, self._clock())
+            if not final:
+                # nothing waits for this chunk: it has no interval of its
+                # own (its device time is inside the next landing's)
+                self.metrics.record_prefill(
+                    e.bucket, 0.0, rid=r.rid, program="lm_prefill_paged",
+                    chunk=[cs, tokens], final=False)
+                return tokens
+            pf_queue.popleft()
+            group.begin_decode(slot)
+            pipe.firsts.append(_First(group, slot, e, first, counts, t0,
+                                      [cs, tokens], seq))
+            if r.steps > 1:
+                self._feed_first(pool, pools, pipe, group, slot, first)
         return tokens
+
+    def _feed_first(self, pool, pools, pipe, group, slot: int,
+                    first) -> None:
+        """Write a final chunk's token (a device scalar) into the feed, over
+        an entry no continuing row reads, and point the row at it: the row
+        rides the next decode call without the host having seen its token.
+        Where every entry is taken (the call in flight is full of rows that
+        go on) the pipeline drains instead and the token lands now, after
+        the in-flight call's: a request's first-token record never precedes
+        a step record its row was not in."""
+        from ..models.transformer import feed_token
+
+        if not pipe.free:
+            self._drain_paged(pool, pools, pipe)
+            return
+        index = pipe.free.pop(0)
+        pipe.feed = feed_token(pipe.feed, index, first)
+        group.fed_serial[slot] = pipe.serial
+        group.fed_index[slot] = index
+
+    def _land_first(self, pool, pools, pipe, item: _First) -> None:
+        """Bring a final chunk's first token to the host (the one place the
+        worker waits for a prefill program): the ``prefill`` record with the
+        token (``new_tokens == 1``, written now that it is here: TTFT is a
+        real arrival time; its seconds are the interval since the landing
+        before it, which join the prefill program's cost model where they
+        are this chunk's alone: :meth:`_Pipeline.landed`), the
+        prompt's pages published for prefix sharing, and the row retired
+        where that token ends it. A row retired with its chunk in flight
+        (deadline, a failed neighbour) is matched by entry and skipped."""
+        group, slot, e = item.group, item.slot, item.entry
+        r = e.request
+        with obs_trace.use(e.trace):
+            try:
+                with annotate("serve.prefill.sync", rid=r.rid,
+                              final=1) as sync:
+                    first = int(item.first)  # device sync: the chunk landed
+                    self._moe_counts(sync, item.counts)
+            except Exception as exc:
+                if group.entries[slot] is e:
+                    self._paged_prefill_failure(pool, pools, group.bucket,
+                                                slot, exc)
+                return
+            t = time.perf_counter()
+            seconds, pipe.landed_t = t - max(item.t0, pipe.landed_t), t
+            alone = pipe.landed(item.seq)
+            if group.entries[slot] is not e:
+                return
+            self.metrics.record_prefill(
+                e.bucket, seconds, rid=r.rid,
+                program_key=self._prog_key(e.bucket) if alone else None,
+                program="lm_prefill_paged", chunk=item.chunk, final=True)
+            group.land_first(slot, first)
+            group.ttft_s[slot] = self._clock() - e.enq_t
+            # the prompt's full pages are final now — publish them for
+            # copy-on-write reuse by later identical prefixes
+            pool.insert_prefix(r.prompt, group.row_pages[slot])
+            self._record_pages(pool)
+            if r.steps == 1 or (r.eos is not None and first == r.eos):
+                self._retire_row_paged(pool, pools, group.bucket, slot,
+                                       STATUS_OK, self._clock())
+
+    def _drain_paged(self, pool, pools, pipe, then=None) -> None:
+        """Land everything in flight, in the device's order: the decode
+        call, then the first tokens of the chunks dispatched after it.
+        ``then`` is the call just dispatched behind them, in flight from
+        here on."""
+        pipe.landing, pipe.call = pipe.call, then
+        if pipe.landing is not None:
+            self._land_paged(pool, pools, pipe, pipe.landing)
+            pipe.landing = None
+        firsts, pipe.firsts = pipe.firsts, []
+        for item in firsts:
+            self._land_first(pool, pools, pipe, item)
 
     def _cow(self, pool, group, slot: int, table_idx: int,
              rid: int | None = None) -> None:
@@ -2110,70 +2340,73 @@ class ServeEngine:
                                fresh=rp[table_idx],
                                pages_used=pool.used_count())
 
-    def _step_paged(self, pool, pools) -> None:
-        """Retire expired resident rows, then run ONE decode step over the
-        live rows of EVERY bucket, packed into one call of the one decode
-        program (``max_batch`` rows, the widest bucket's table): the
+    def _step_paged(self, pool, pools, pipe) -> None:
+        """Retire expired resident rows, then DISPATCH one decode step over
+        the live rows of EVERY bucket, packed into one call of the one
+        decode program (``max_batch`` rows, the widest bucket's table): the
         weights are read once an iteration, whatever the number of buckets
-        that hold rows. Where the buckets together hold more live rows
-        than a call has (each may hold ``max_batch``), the step is
-        ``ceil(live / max_batch)`` calls of the same program, all
-        dispatched before any result is awaited — JAX dispatch is async,
-        so a later call's device work overlaps the host round-trip for an
-        earlier one instead of serializing behind it. A row that is not
-        live (free, or still prefilling) is in no call: the rows a call
-        does not fill run the masked-harmless dummy against page 0, so a
-        prefilling neighbor's pages are never scribbled."""
+        that hold rows. Only then land what was in flight before it: the
+        previous call's tokens (``serve.decode.sync`` of call t follows
+        ``serve.decode.dispatch`` of call t+1), then the first tokens of
+        this iteration's final chunks — the chip runs the new call
+        meanwhile — and free the slots of the rows that end with the new
+        call (:meth:`_leave_paged`). Where nothing was dispatched (every row in flight ends
+        with its step, nothing else is live) what is in flight lands at
+        once.
+
+        Where the buckets together hold more live rows than a call has
+        (each may hold ``max_batch``), the step is ``ceil(live /
+        max_batch)`` calls of the same program, and the rule holds call by
+        call: each is dispatched, then the one before it lands, so a call
+        is fed from the device by its immediate predecessor alone and every
+        other row's token is on the host by then. A row that is not live
+        (free, or still prefilling) is in no call: the rows a call does not
+        fill run the masked-harmless dummy against page 0, so a prefilling
+        neighbor's pages are never scribbled."""
         with annotate("serve.decode") as span:
             calls = self._pack_paged(pool, pools)
-            launched = []
-            for call in calls:
-                with annotate("serve.decode.dispatch",
-                              bucket=_call_tag(call)) as dispatch:
-                    launch = self._dispatch_paged(pool, pools, call,
-                                                  dispatch)
-                if launch is not None:
-                    launched.append(launch)
-            span.set_metadata(
-                buckets=len({group.bucket for call in calls
-                             for group, _ in call}),
-                dispatches=len(launched))
-            synced = 0.0  # perf_counter at the previous call's landing
             steps: dict = {}  # bucket -> [rows, seconds] landed, unannounced
-            for n, (call, t0, nxt) in enumerate(launched):
-                tag = _call_tag(call)
+            dispatched = 0
+            for n, call in enumerate(calls):
                 # the buckets whose last rows this call carries: their step
                 # records are due at its landing (a bucket's rows go on
                 # into the next call where a call is full)
-                later = {group.bucket for c, _, _ in launched[n + 1:]
+                later = {group.bucket for c in calls[n + 1:]
                          for group, _ in c}
                 due = [group.bucket for group, _ in call
                        if group.bucket not in later]
-                try:
-                    with annotate("serve.decode.sync", bucket=tag) as sync:
-                        # analyze: ignore[host-sync] — THE one intentional
-                        # sync per decode call: the host must see the
-                        # emitted tokens to retire rows (every call above
-                        # launched async first)
-                        nxt, counts = np.asarray(nxt[0]), nxt[1]  # sync
-                        self._moe_counts(sync, counts)
-                except Exception as exc:
-                    self._fail_paged_call(pool, pools, call, exc)
-                    self._record_steps(pool, steps, due)
+                with annotate("serve.decode.dispatch",
+                              bucket=_call_tag(call)) as dispatch:
+                    launch = self._dispatch_paged(pool, pools, call,
+                                                  dispatch, pipe, steps, due)
+                if launch is None:
+                    # nothing went out: what these buckets landed in this
+                    # step's earlier calls is announced with the last of
+                    # them (in flight), or now
+                    if pipe.call is not None and pipe.call.steps is steps:
+                        pipe.call.due.extend(due)
+                    else:
+                        self._record_steps(pool, steps, due)
                     continue
-                # the interval that is this call's alone: the device ran
-                # the earlier calls first, so time before their landing is
-                # theirs (summing whole dispatch-to-landing walls counted
-                # device time twice)
-                t0, synced = max(t0, synced), time.perf_counter()
-                with annotate("serve.decode.retire", bucket=tag) as retire:
-                    retire.set_metadata(retired=self._land_paged(
-                        pool, pools, call, nxt, synced - t0, steps, due))
+                dispatched += 1
+                # (the first tokens land here too: their chunks ran before
+                # the call just dispatched, and a row of them in a LATER
+                # call of this step is fed from the host)
+                self._drain_paged(pool, pools, pipe, then=launch)
+                self._leave_paged(pool, pools, launch)
+            if not dispatched:
+                self._drain_paged(pool, pools, pipe)
+            span.set_metadata(
+                buckets=len({group.bucket for call in calls
+                             for group, _ in call}),
+                dispatches=dispatched)
         self._live_rows = sum(len(g.live_slots()) for g in pools.values())
 
     def _pack_paged(self, pool, pools) -> list:
         """The deadline sweep over every LM group, then this iteration's
-        decode calls: the live rows of all groups in bucket order, cut into
+        decode calls: the live rows of all groups that have a step left to
+        run (the cursors count what is dispatched: a row whose last step is
+        in flight only waits for its landing), in bucket order, cut into
         runs of at most ``max_batch``. A call is a list of ``(group,
         slots)``; a bucket whose rows do not fit the call they begin in
         goes on in the next."""
@@ -2190,7 +2423,8 @@ class ServeEngine:
                         pool, pools, bucket, i, STATUS_EXPIRED, now,
                         reason=f"deadline {dl} passed mid-decode "
                                f"(now {now})")
-            live = group.live_slots()
+            live = [i for i in group.live_slots()
+                    if group.steps_done[i] < group.entries[i].request.steps]
             while live:
                 if not room:
                     calls.append([])
@@ -2200,12 +2434,20 @@ class ServeEngine:
                 room -= len(take)
         return calls
 
-    def _dispatch_paged(self, pool, pools, call, span):
-        """One call's half of :meth:`_step_paged` before the landing: the
+    def _dispatch_paged(self, pool, pools, call, span, pipe, steps: dict,
+                        due):
+        """One call's half of :meth:`_step_paged` before its landing: the
         copy-on-write gate, the packed decode inputs
         (:func:`.kvpool.decode_inputs`), the async ``lm_decode_paged``
-        call. Returns what the landing needs, or None where nothing was
-        dispatched. ``span`` (``serve.decode.dispatch``) gets the work the
+        call, fed from the device (``pipe.feed``) for every row whose token
+        the host has not seen, and then the host's bookkeeping for the step:
+        ``positions`` and ``steps_done`` advance NOW, the call's tokens
+        become the feed and each row is pointed at its entry. Returns the
+        :class:`_Launch` the landing needs, or None where nothing was
+        dispatched. ``span`` (``serve.decode.dispatch``) gets ``ahead`` (1
+        where an earlier decode call had not landed at this dispatch: the
+        step was pipelined), ``fed_rows`` (rows whose token came from the
+        device) and the work the
         call was given beside the work that is useful, over all the rows it
         carries: ``padded_rows`` x ``table_width`` pages against
         ``kv_tokens``, the positions the live rows attend (each row's cache
@@ -2216,17 +2458,19 @@ class ServeEngine:
         its argmax branch and never sorted the vocabulary."""
         from ..models.transformer import lm_decode_paged
 
-        # an earlier call's failure may have consumed the slab, and every
-        # resident row went with the pool (_drop_paged_pool)
+        # an earlier landing may have retired a row packed for this call
+        # (its eos, a failed call's rows), or dropped the pool with every
+        # resident row (_drop_paged_pool)
         call = [(group, kept) for group, slots in call
                 if (kept := [i for i in slots
                              if group.entries[i] is not None])]
-        rows = len(_call_rows(call))
-        span.set_metadata(rows=rows)
+        carried = _call_rows(call)
+        rows = len(carried)
+        span.set_metadata(rows=rows, ahead=int(pipe.call is not None))
         if not rows:
             return None
         try:
-            for group, i in _call_rows(call):
+            for group, i in carried:
                 # COW gate on each row's write page
                 self._cow(pool, group, slot=i,
                           # analyze: ignore[host-sync] — host numpy
@@ -2237,18 +2481,38 @@ class ServeEngine:
             faults.fire("serve.decode_step", path=f"bucket-{_call_tag(call)}")
             t0 = time.perf_counter()
             (tables, positions, cur, steps_done, seeds, temperature, top_p,
-             top_k) = decode_inputs(call, self.max_batch,
-                                    self._decode_pages, self._ring)
+             top_k, prev_index) = decode_inputs(
+                 call, self.max_batch, self._decode_pages, self._ring,
+                 pipe.serial)
             out = lm_decode_paged(
                 self.params, pool.pages, tables, positions, cur, steps_done,
                 seeds, temperature, top_p, top_k, heads=self.heads,
                 page_len=self._page_len, compute_dtype=self.compute_dtype,
-                moe=self.moe, kernel=self._decode_kernel)
-            pages, *nxt = _program_result(out)  # (tokens, expert counts)
+                moe=self.moe, kernel=self._decode_kernel,
+                prev_tokens=pipe.feed, prev_index=prev_index)
+            pages, nxt, counts = _program_result(out)
         except Exception as exc:
-            self._fail_paged_call(pool, pools, call, exc)
+            self._fail_paged_call(
+                pool, pools, [(group, i, group.entries[i])
+                              for group, i in carried], exc)
             return None
         pool.pages = pages
+        # the step is the device's now: the cursors move on, the call's
+        # tokens are the feed, and its entries past the live rows, and those
+        # of rows that end with this step, are free for first tokens
+        pipe.feed = nxt
+        pipe.serial += 1
+        pipe.free = list(range(rows, pipe.width))
+        row = 0
+        for group, slots in call:
+            group.positions[slots] += 1
+            group.steps_done[slots] += 1
+            group.fed_serial[slots] = pipe.serial
+            group.fed_index[slots] = np.arange(row, row + len(slots))
+            pipe.free += [row + k for k, i in enumerate(slots)
+                          if group.steps_done[i]
+                          >= group.entries[i].request.steps]
+            row += len(slots)
         if self._spec is not None:
             # the pages each attention kind's kernel is given a row
             tables, ring = tables
@@ -2281,8 +2545,11 @@ class ServeEngine:
                           table_width=tables.shape[1],
                           # analyze: ignore[host-sync] — host numpy
                           kv_tokens=int(positions.sum()) + rows,
-                          sampled_rows=int((temperature > 0).sum()))
-        return call, t0, nxt
+                          sampled_rows=int((temperature > 0).sum()),
+                          fed_rows=int((prev_index >= 0).sum()))
+        return _Launch([(group, i, group.entries[i]) for group, i in carried],
+                       _call_tag(call), t0, nxt, counts, steps, due,
+                       pipe.dispatched(), {})
 
     @staticmethod
     def _moe_counts(span, counts) -> None:
@@ -2299,43 +2566,86 @@ class ServeEngine:
                               moe_local_assignments=local,
                               moe_experts_touched=touched)
 
-    def _land_paged(self, pool, pools, call, nxt, seconds: float,
-                    steps: dict, due) -> int:
-        """One call's half of :meth:`_step_paged` after its landing: its
-        rows and seconds go to their buckets' step records (the seconds,
-        the interval that belongs to this call alone, divided among the
-        buckets it carried by rows), the records that are ``due`` are
-        announced, then the per-row bookkeeping and retirement: row ``n``
-        of the call's tokens ``nxt`` is the ``n``-th row it carried.
-        Returns the rows retired."""
-        carried = _call_rows(call)
-        for group, slots in call:
-            got = steps.setdefault(group.bucket, [0, 0.0])
-            got[0] += len(slots)
-            got[1] += seconds * len(slots) / len(carried)
-        # the call's wall time joins the one decode program's cost model,
-        # which stands under the widest bucket's key (its shape)
-        perf.get_program_costs().observe(
-            "lm_decode_paged", self._prog_key(self._decode_bucket), seconds)
-        self._record_steps(pool, steps, due)
-        now = self._clock()
-        retired = 0
-        for n, (group, i) in enumerate(carried):
-            if group.entries[i] is None:
-                continue  # its pool was dropped under an earlier call
-            group.positions[i] += 1
-            group.steps_done[i] += 1
-            tok = int(nxt[n])
-            group.cur_tok[i] = tok
-            group.emitted[i].append(tok)
-            r = group.entries[i].request
-            if ((r.eos is not None and tok == r.eos)
-                    # analyze: ignore[host-sync] — host numpy bookkeeping
-                    or int(group.steps_done[i]) >= r.steps):
-                self._retire_row_paged(pool, pools, group.bucket, i,
-                                       STATUS_OK, now)
-                retired += 1
-        return retired
+    def _leave_paged(self, pool, pools, launch: _Launch) -> None:
+        """Free the slots of the rows whose step budget ends with ``launch``,
+        the call just dispatched and by now all that is in flight: the host
+        knows they end without seeing the token, so the next claim refills
+        their slots (and may reuse their pages: every later program is
+        behind this call in the device's stream) and only the landing
+        record is kept (:class:`_Leaving`). A row that ends by ``eos`` is
+        found at its landing, as before."""
+        for n, (group, i, e) in enumerate(launch.rows):
+            if (group.entries[i] is e
+                    and group.steps_done[i] >= e.request.steps):
+                launch.leaving[n] = self._release_row_paged(
+                    pool, pools, group.bucket, i)
+
+    def _land_paged(self, pool, pools, pipe, launch: _Launch) -> None:
+        """A call's half of :meth:`_step_paged` after its landing — the one
+        place the worker waits for a decode program (``serve.decode.sync``),
+        with the next call already on the device. Rows are matched by ENTRY:
+        a row retired while the call was in flight (its ``eos`` found one
+        step late, a deadline, a failed neighbour) has its token discarded,
+        never handed to the slot's next occupant. Then, in
+        ``serve.decode.retire`` (``retired``, ``discarded``): the landed
+        rows and the call's seconds (landing to landing: the interval that
+        belongs to this call alone) go to their buckets' step records,
+        divided among the buckets by rows; the records that are due are
+        announced; then the tokens reach the rows' streams, a row that
+        ``eos`` or its step budget ends is retired, and a row that had left
+        its slot is answered."""
+        try:
+            with annotate("serve.decode.sync", bucket=launch.tag) as sync:
+                # analyze: ignore[host-sync] — THE one intentional sync per
+                # decode call: the host must see the emitted tokens to
+                # retire rows (the next call was launched first)
+                toks = np.asarray(launch.nxt)  # sync
+                self._moe_counts(sync, launch.counts)
+        except Exception as exc:
+            self._fail_paged_call(pool, pools, launch.rows, exc,
+                                  leaving=launch.leaving)
+            self._record_steps(pool, launch.steps, launch.due)
+            return
+        t = time.perf_counter()
+        seconds, pipe.landed_t = t - max(launch.t0, pipe.landed_t), t
+        alone = pipe.landed(launch.seq)
+        with annotate("serve.decode.retire", bucket=launch.tag) as retire:
+            landed = [(n, group, i)
+                      for n, (group, i, e) in enumerate(launch.rows)
+                      if n in launch.leaving or group.entries[i] is e]
+            for _, group, _ in landed:
+                got = launch.steps.setdefault(group.bucket, [0, 0.0])
+                got[0] += 1
+                got[1] += seconds / len(landed)
+            if alone:
+                # the call's wall time joins the one decode program's cost
+                # model, which stands under the widest bucket's key (its
+                # shape); an interval that holds a chunk's device time too
+                # is left out (the mean is over the calls observed)
+                perf.get_program_costs().observe(
+                    "lm_decode_paged", self._prog_key(self._decode_bucket),
+                    seconds)
+            self._record_steps(pool, launch.steps, launch.due)
+            now = self._clock()
+            retired = 0
+            for n, group, i in landed:
+                tok = int(toks[n])
+                left = launch.leaving.pop(n, None)
+                if left is not None:
+                    left.emitted.append(tok)
+                    self._finish_row_paged(left, STATUS_OK, now)
+                    retired += 1
+                    continue
+                group.cur_tok[i] = tok
+                group.emitted[i].append(tok)
+                r = group.entries[i].request
+                if ((r.eos is not None and tok == r.eos)
+                        or len(group.emitted[i]) >= r.steps):
+                    self._retire_row_paged(pool, pools, group.bucket, i,
+                                           STATUS_OK, now)
+                    retired += 1
+            retire.set_metadata(retired=retired,
+                                discarded=len(launch.rows) - len(landed))
 
     def _record_steps(self, pool, steps: dict, due) -> None:
         """Announce the ``due`` buckets' step records and drop them from
@@ -2357,27 +2667,25 @@ class ServeEngine:
 
     def _retire_row_paged(self, pool, pools, bucket, slot: int,
                           status: str, now: float, reason: str = "") -> None:
-        """Retire one paged row and free its slot — the ONLY path a
-        resident row leaves a group by, so every terminal status releases
-        the row's pages AND its page-unit admission reservation exactly
-        once (pages here via the pool refcount, the reservation in
-        :meth:`_retire` by whoever wins the handle)."""
+        """Retire one resident row: free its slot and pages and answer its
+        request."""
+        self._finish_row_paged(
+            self._release_row_paged(pool, pools, bucket, slot), status, now,
+            reason)
+
+    def _release_row_paged(self, pool, pools, bucket, slot: int) -> _Leaving:
+        """Free one paged row's slot — the ONLY path a resident row leaves
+        a group by with a Result to come, so every terminal status releases
+        the row's pages exactly once (here, via the pool refcount; its
+        page-unit admission reservation in :meth:`_retire`, by whoever wins
+        the handle). Returns what the Result is built from."""
         group = pools[bucket]
         e = group.entries[slot]
-        n_pages = len(group.row_pages[slot] or [])
-        metrics = {"bucket": bucket, "slot": slot, "queue_s": e.queue_s,
-                   "ttft_s": group.ttft_s[slot],
-                   "total_s": now - e.enq_t, "pages": n_pages,
-                   "shared_pages": int(group.shared_pages[slot])}
-        if status == STATUS_OK:
-            toks = np.concatenate([
-                np.asarray(e.request.prompt, np.int32),
-                np.asarray(group.emitted[slot], np.int32)])
-            result = Result(e.request.rid, STATUS_OK, tokens=toks,
-                            metrics=metrics)
-        else:
-            result = Result(e.request.rid, status, reason=reason,
-                            metrics=metrics)
+        left = _Leaving(e, group.emitted[slot], {
+            "bucket": bucket, "slot": slot, "queue_s": e.queue_s,
+            "ttft_s": group.ttft_s[slot],
+            "pages": len(group.row_pages[slot] or []),
+            "shared_pages": int(group.shared_pages[slot])})
         ring = group.window_row_pages[slot]
         pages = group.release(slot)
         if pool is not None:
@@ -2390,17 +2698,43 @@ class ServeEngine:
                     "free", rid=e.request.rid, pages=len(pages),
                     used=pool.used_count(), total=pool.capacity)
             self._record_pages(pool)
+        return left
+
+    def _finish_row_paged(self, left: _Leaving, status: str, now: float,
+                          reason: str = "") -> None:
+        """Answer the request of a row that has left its slot."""
+        e = left.entry
+        metrics = {**left.metrics, "total_s": now - e.enq_t}
+        if status == STATUS_OK:
+            toks = np.concatenate([
+                np.asarray(e.request.prompt, np.int32),
+                np.asarray(left.emitted, np.int32)])
+            result = Result(e.request.rid, STATUS_OK, tokens=toks,
+                            metrics=metrics)
+        else:
+            result = Result(e.request.rid, status, reason=reason,
+                            metrics=metrics)
         self._retire(e, result)
 
     def _paged_pool_lost(self, pool) -> bool:
-        """True when a failed donated call consumed the page slab (the
-        backends that implement donation delete the inputs on dispatch;
-        injected faults raise before the call and never trip this)."""
+        """True when the page slab is gone: a failed donated call consumed
+        it (the backends that implement donation delete the inputs on
+        dispatch), or it is the output of a program that failed on the
+        device — or of one dispatched on such an output, which is what the
+        slab is when a call fails with its successor in flight. Waits for
+        what is in flight (a failure path). Injected faults raise before
+        the call and never trip this."""
         if pool is None:
             return False
         leaf = pool.pages["l0"][0]
         deleted = getattr(leaf, "is_deleted", None)
-        return bool(deleted and deleted())
+        if deleted and deleted():
+            return True
+        try:
+            leaf.block_until_ready()
+        except Exception:
+            return True
+        return False
 
     def _drop_paged_pool(self, pool, pools, reason: str) -> None:
         """The calling generation's slab died under a failed donated
@@ -2435,32 +2769,52 @@ class ServeEngine:
                                            total=self._num_pages - 1)
             self.metrics.record_pages(self._num_pages - 1, 0, 0)
 
-    def _fail_paged_call(self, pool, pools, call, exc: Exception) -> None:
-        """A paged decode call died: with the pool intact (an injected
-        fault raised before launch) only the rows that call carried
-        fail/retry, in every bucket it carried, each once, and their pages
-        free; a consumed slab escalates to :meth:`_drop_paged_pool`."""
-        if pool is not self._kvpool:
-            # an earlier call's failure in this same step already escalated
-            # to _drop_paged_pool: every resident row (this call's too) was
-            # requeued/failed there — a second handling pass would find
-            # nothing of it on the cleared pools map
-            return
-        carried = [(group, i) for group, i in _call_rows(call)
-                   if group.entries[i] is not None]
+    def _fail_paged_call(self, pool, pools, rows, exc: Exception,
+                         leaving=None) -> None:
+        """A paged decode call died — at its dispatch, or at its landing
+        with the next call already dispatched. ``rows`` are the ``(group,
+        slot, entry)`` it carried. With the slab usable (an injected fault
+        raised before launch) only those rows fail/retry, in every bucket
+        the call carried, each once (one the next call carries too is
+        released here and discarded at that landing), and their pages free;
+        a slab that is consumed or poisoned (the failed program's output,
+        which the next call was dispatched on) escalates to
+        :meth:`_drop_paged_pool`, once: the other call's landing finds the
+        pool gone and returns. ``leaving`` are the rows of it that had left
+        their slots (:meth:`_leave_paged`): in no group whatever became of
+        the pool, they retry or fail here, by entry."""
         reason = f"decode step failed: {type(exc).__name__}: {exc}"
+        left = []
+        while leaving:
+            left.append(leaving.popitem()[1])
+        # (a pool that is not the engine's any more: an earlier failure
+        # already escalated to _drop_paged_pool, and every resident row,
+        # this call's too, was requeued/failed there — a second handling
+        # pass would find nothing of it on the cleared pools map)
+        carried = ([(group, i) for group, i, e in rows
+                    if group.entries[i] is e]
+                   if pool is self._kvpool else [])
+        if not carried and not left:
+            return  # every row it carried is gone already (the call before
+            # it failed and took them): nothing of this call is left to fail
         if memledger.is_oom_error(exc):
             memledger.dump_oom_forensics(reason)
         self.flight.record("decode_fault",
-                           bucket=[list(group.bucket) for group, _ in call],
-                           rows=len(carried), error=reason,
+                           bucket=[list(b) for b in dict.fromkeys(
+                               group.bucket for group, _, _ in rows)],
+                           rows=len(carried) + len(left), error=reason,
                            queue_depth=self._queue.count,
                            compiles=_compile_count(),
                            pages_used=pool.used_count() if pool else 0)
-        if self._paged_pool_lost(pool):
+        now = self._clock()
+        for row in left:
+            if row.entry.attempts_left():
+                self._requeue(row.entry, reason)
+            else:
+                self._finish_row_paged(row, STATUS_ERROR, now, reason)
+        if carried and self._paged_pool_lost(pool):
             self._drop_paged_pool(pool, pools, reason)
-        else:
-            now = self._clock()
+        elif carried:
             for group, i in carried:
                 e = group.entries[i]
                 if e.attempts_left():

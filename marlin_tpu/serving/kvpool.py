@@ -834,10 +834,13 @@ class PagedGroup:
     """Per-bucket row bookkeeping over a shared :class:`PagedKVPool`. Owns
     the per-row vectors the decode program takes (:func:`decode_inputs`
     gathers the live rows of every bucket's group), each row's block table
-    and prefill cursor, and the host-side emitted-token stream (tokens
-    never live on the device: the decode program takes ``cur_tokens`` and
-    returns the next ones, so results are assembled host-side).
-    Single-threaded — only the engine worker touches a group."""
+    and prefill cursor, and the host-side emitted-token stream (results are
+    assembled host-side from the tokens that have LANDED; the engine
+    dispatches a row's next step before its last token has, so the
+    cursors here run ahead of ``emitted`` by what is in flight, and
+    ``fed_serial``/``fed_index`` say where on the device the row's current
+    token lies meanwhile). Single-threaded — only the engine worker touches
+    a group."""
 
     def __init__(self, bucket, width: int, page_len: int,
                  prefill_chunk: int, ring: int | None = None):
@@ -870,6 +873,11 @@ class PagedGroup:
         self.top_p = np.ones(width, np.float32)   # 1.0 = nucleus filter off
         self.top_k = np.zeros(width, np.int32)    # 0 = rank filter off
         self.cur_tok = np.zeros(width, np.int32)
+        #: where the row's current token lies while the host has not seen
+        #: it: entry ``fed_index`` of the engine's device feed number
+        #: ``fed_serial`` (0: nowhere; ``cur_tok`` holds it once landed)
+        self.fed_serial = np.zeros(width, np.int64)
+        self.fed_index = np.zeros(width, np.int32)
         self.ttft_s: list = [None] * width
         #: next chunk_start per row; -1 = not prefilling (free or decoding)
         self.pf_next = np.full(width, -1, np.int64)
@@ -925,6 +933,7 @@ class PagedGroup:
         self.positions[slot] = 0
         self.steps_done[slot] = 0
         self.cur_tok[slot] = 0
+        self.fed_serial[slot] = 0
         self.seeds[slot] = np.uint32(r.seed)
         self.temperature[slot] = r.temperature
         self.top_p[slot] = 1.0 if r.top_p is None else r.top_p
@@ -932,12 +941,15 @@ class PagedGroup:
         self.emitted[slot] = []
         self.ttft_s[slot] = None
 
-    def finish_prefill(self, slot: int, first: int) -> None:
-        """The final chunk landed: the row becomes decode-ready with its
-        first emitted token in hand."""
+    def begin_decode(self, slot: int) -> None:
+        """The final chunk is DISPATCHED: the row is decode-ready, its first
+        token still on the device (:meth:`land_first` brings it)."""
         self.pf_next[slot] = -1
         self.positions[slot] = self.lengths[slot]
         self.steps_done[slot] = 1
+
+    def land_first(self, slot: int, first: int) -> None:
+        """The final chunk's token reached the host."""
         self.cur_tok[slot] = first
         self.emitted[slot] = [int(first)]
 
@@ -965,6 +977,7 @@ class PagedGroup:
         self.positions[slot] = int(row["position"])
         self.steps_done[slot] = int(row["steps_done"])
         self.cur_tok[slot] = int(row["cur_tok"])
+        self.fed_serial[slot] = 0
         self.seeds[slot] = np.uint32(r.seed)
         self.temperature[slot] = r.temperature
         self.top_p[slot] = 1.0 if r.top_p is None else r.top_p
@@ -988,6 +1001,7 @@ class PagedGroup:
         self.steps_done[slot] = 0
         self.lengths[slot] = 0
         self.cur_tok[slot] = 0
+        self.fed_serial[slot] = 0
         self.temperature[slot] = 0.0
         self.top_p[slot] = 1.0
         self.top_k[slot] = 0
@@ -1013,12 +1027,15 @@ def decode_pages(buckets, page_len: int) -> int:
 
 
 def decode_inputs(rows, width: int, pages_per_row: int,
-                  ring: int | None = None):
+                  ring: int | None = None, serial: int = 0):
     """What one call of the decode program takes after the slab, for the
     LIVE rows ``rows`` — ``(group, slots)`` runs in call order, of any
     buckets' groups, at most ``width`` rows in all, packed from row 0:
     ``(tables, positions, cur_tokens, steps_done, seeds, temperature, top_p,
-    top_k)``, each ``width`` rows. A row's block table is laid into
+    top_k, prev_index)``, each ``width`` rows. ``prev_index`` is, for a row
+    whose current token lies in device feed number ``serial`` (the engine's
+    present one), its entry there; -1 for every other row, whose token is
+    the host's ``cur_tokens``. A row's block table is laid into
     ``pages_per_row`` columns (:func:`decode_pages`); what lies past its own
     bucket's extent is the dummy page 0. The rows no live row fills are the
     dummy row: table and position 0 — a prefilling row's REAL pages must
@@ -1036,6 +1053,7 @@ def decode_inputs(rows, width: int, pages_per_row: int,
     temperature = np.zeros(width, np.float32)
     top_p = np.ones(width, np.float32)
     top_k = np.zeros(width, np.int32)
+    prev_index = np.full(width, -1, np.int32)
     at = 0
     for g, slots in rows:
         to = slice(at, at + len(slots))
@@ -1049,8 +1067,10 @@ def decode_inputs(rows, width: int, pages_per_row: int,
         temperature[to] = g.temperature[slots]
         top_p[to] = g.top_p[slots]
         top_k[to] = g.top_k[slots]
+        prev_index[to] = np.where(g.fed_serial[slots] == serial,
+                                  g.fed_index[slots], -1)
     return ((tables, rings) if ring is not None else tables, positions, cur,
-            steps_done, seeds, temperature, top_p, top_k)
+            steps_done, seeds, temperature, top_p, top_k, prev_index)
 
 
 # ---------------------------------------------------------------- programs
@@ -1151,7 +1171,8 @@ def capture_paged_costs(params: dict, heads: int, bucket, max_batch: int,
             st((max_batch,), jnp.uint32), st((max_batch,), jnp.float32),
             st((max_batch,), jnp.float32), st((max_batch,)), heads=heads,
             page_len=pool.page_len, compute_dtype=compute_dtype,
-            moe=moe, kernel=kernel).lower()
+            moe=moe, kernel=kernel, prev_tokens=st((max_batch,)),
+            prev_index=st((max_batch,))).lower()
         dec_cost = None
         if kernel == "pallas":
             from ..ops.pallas_kernels import _interpret
@@ -1182,16 +1203,19 @@ def warmup_paged(params: dict, heads: int, buckets, max_batch: int,
                  moe: tuple | None = None, kernel: str = "gather") -> int:
     """Compile (and execute once, against dummy page 0) every bucket's
     chunked-prefill program, the ONE decode program every bucket's rows ride
-    (``max_batch`` rows, the widest bucket's table: :func:`decode_pages`)
-    and the one shared page-copy program — a program per bucket and two,
-    the whole paged compile story. Runs against the engine's REAL pool
+    (``max_batch`` rows, the widest bucket's table: :func:`decode_pages`),
+    the token feed's write (:func:`~marlin_tpu.models.transformer
+    .feed_token`) and the one shared page-copy program — a program per
+    bucket and three, the whole paged compile story. The decode program runs
+    twice, fed as the engine feeds it: first from a fresh feed, then from its
+    own tokens. Runs against the engine's REAL pool
     (program identity includes the slab shape, so a throwaway pool would
     compile programs traffic never hits); all dummy writes land in page 0.
     Returns the buckets warmed."""
     import jax
 
-    from ..models.transformer import (kv_page_copy, lm_decode_paged,
-                                      lm_prefill_paged)
+    from ..models.transformer import (feed_token, kv_page_copy,
+                                      lm_decode_paged, lm_prefill_paged)
     from .batcher import normalize_buckets
 
     buckets = normalize_buckets(buckets)
@@ -1199,7 +1223,8 @@ def warmup_paged(params: dict, heads: int, buckets, max_batch: int,
     groups = [PagedGroup(bucket, max_batch, pool.page_len, prefill_chunk,
                          ring=ring) for bucket in buckets]
     table_pages = decode_pages(buckets, pool.page_len)
-    dummy = decode_inputs((), max_batch, table_pages, ring)
+    *dummy, unfed = decode_inputs((), max_batch, table_pages, ring)
+    feed = np.zeros(max_batch, np.int32)
     if not isinstance(heads, int):
         # a spec's programs take the compiler half a minute each and leave
         # most cores idle: all of them at once, then the calls below run
@@ -1212,19 +1237,22 @@ def warmup_paged(params: dict, heads: int, buckets, max_batch: int,
               np.zeros(g.chunk, np.int32), 0, 1, heads, pool.page_len)
              for g in groups],
             [(params, pool.pages, *dummy, heads, pool.page_len,
-              resolve_decode_kernel(kernel))])
+              resolve_decode_kernel(kernel), feed, unfed)])
     for bucket, g in zip(buckets, groups):
         capture_paged_costs(params, heads, bucket, max_batch, pool,
                             prefill_chunk, compute_dtype, moe,
                             kernel=kernel, table_pages=table_pages)
-        pool.pages = lm_prefill_paged(
+        pool.pages, first = lm_prefill_paged(
             params, pool.pages, g.prefill_tables(0),
             np.zeros(g.chunk, np.int32), 0, 1, heads=heads,
-            page_len=pool.page_len, compute_dtype=compute_dtype, moe=moe)[0]
-    pool.pages, nxt = lm_decode_paged(
-        params, pool.pages, *dummy, heads=heads, page_len=pool.page_len,
-        compute_dtype=compute_dtype, moe=moe, kernel=kernel)[:2]
-    jax.block_until_ready(nxt)
+            page_len=pool.page_len, compute_dtype=compute_dtype,
+            moe=moe)[:2]
+    for _ in range(2):
+        pool.pages, feed = lm_decode_paged(
+            params, pool.pages, *dummy, heads=heads, page_len=pool.page_len,
+            compute_dtype=compute_dtype, moe=moe, kernel=kernel,
+            prev_tokens=feed_token(feed, 0, first), prev_index=unfed)[:2]
+    jax.block_until_ready(feed)
     pool.pages = kv_page_copy(pool.pages, 0, 0)  # the last program
     jax.block_until_ready(pool.pages["l0"][0])
     return len(buckets)

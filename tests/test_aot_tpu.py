@@ -590,7 +590,8 @@ def test_paged_decode_kernel_mosaic_compiles(one_chip, dtype):
 
 def test_lm_decode_paged_pallas_compiles_for_v5e(one_chip):
     """The default engine's decode program on a TPU — ``lm_decode_paged``
-    with the Pallas kernel inside — whole, for one v5e chip."""
+    with the Pallas kernel inside, fed from the device as the engine feeds
+    it (``prev_tokens`` / ``prev_index``) — whole, for one v5e chip."""
     from marlin_tpu.models.transformer import _lm_decode_paged_jit
 
     params, pages, group, st = _serve_shapes(one_chip)
@@ -600,7 +601,8 @@ def test_lm_decode_paged_pallas_compiles_for_v5e(one_chip):
             params, pages, st((B, group.pages_per_row)), st((B,)), st((B,)),
             st((B,)), st((B,), jnp.uint32), st((B,), jnp.float32),
             st((B,), jnp.float32), st((B,)), heads=8, page_len=_PAGE_LEN,
-            compute_dtype=None, moe=None, kernel="pallas").lower().compile()
+            compute_dtype=None, moe=None, kernel="pallas",
+            prev_tokens=st((B,)), prev_index=st((B,))).lower().compile()
     text = c.as_text()
     assert "tpu_custom_call" in text
     assert c.memory_analysis().peak_memory_in_bytes < 1024**3

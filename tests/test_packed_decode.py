@@ -95,9 +95,9 @@ def _watch(eng, monkeypatch):
                               "shape": tables.shape})
         return decode(*args, **kw)
 
-    def counted(pool, pools):
+    def counted(pool, pools, pipe):
         steps.append([])
-        step(pool, pools)
+        return step(pool, pools, pipe)
 
     monkeypatch.setattr(transformer, "lm_decode_paged", spy)
     monkeypatch.setattr(eng, "_step_paged", counted)
@@ -200,16 +200,23 @@ def test_a_call_is_packed_in_order_over_the_widest_table():
     narrow = PagedGroup((8, 4), 4, PAGE_LEN, 8)
     wide = PagedGroup((32, 8), 4, PAGE_LEN, 8)
     narrow.assign(2, Entry(3), [7], 0, 0)
-    narrow.finish_prefill(2, first=5)
+    narrow.begin_decode(2)
+    narrow.land_first(2, first=5)
     wide.assign(0, Entry(20), [1, 2, 3, 4, 5, 6], 0, 0)
     wide.assign(3, Entry(30, temperature=0.5), list(range(10, 20)), 0, 0)
-    wide.finish_prefill(0, first=8)
-    wide.finish_prefill(3, first=9)
+    wide.begin_decode(0)
+    wide.land_first(0, first=8)
+    wide.begin_decode(3)
+    wide.land_first(3, first=9)
     wide.seeds[3], wide.top_k[3] = 77, 3
     width = decode_pages([narrow.bucket, wide.bucket], PAGE_LEN)
     assert width == wide.pages_per_row == 10 > narrow.pages_per_row == 3
+    wide.fed_serial[3], wide.fed_index[3] = 7, 2  # its token: feed 7's
+    narrow.fed_serial[2], narrow.fed_index[2] = 6, 1  # an older feed's
     (tables, positions, cur, steps_done, seeds, temperature, top_p,
-     top_k) = decode_inputs([(narrow, [2]), (wide, [0, 3])], 4, width)
+     top_k, prev_index) = decode_inputs([(narrow, [2]), (wide, [0, 3])], 4,
+                                        width, serial=7)
+    assert prev_index.tolist() == [-1, -1, 2, -1]
     assert tables.shape == (4, 10) and tables.dtype == np.int32
     assert tables.tolist() == [[7] + [0] * 9, [1, 2, 3, 4, 5, 6, 0, 0, 0, 0],
                                list(range(10, 20)), [0] * 10]

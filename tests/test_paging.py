@@ -472,7 +472,7 @@ def test_second_bucket_failure_after_pool_drop_is_inert(params):
         for bucket in BUCKETS:
             pools[bucket] = PagedGroup(bucket, eng.max_batch, PAGE_LEN,
                                        eng._prefill_chunk)
-        late = [(g, [0]) for g in pools.values()]
+        late = [(g, 0, object()) for g in pools.values()]
         eng._drop_paged_pool(pool, pools, "slab consumed (simulated)")
         assert pools == {} and eng._kvpool is None
         # the second call's handler observes the drop and returns

@@ -150,12 +150,13 @@ def test_a_prefilling_row_is_handed_to_decode_as_greedy():
     group.assign(0, Entry(prompt=np.arange(3), temperature=0.0), [1], 0, 0)
     group.assign(1, Entry(prompt=np.arange(30), temperature=0.9),
                  [2, 3, 4, 5], 0, 0)
-    group.finish_prefill(0, first=7)
+    group.begin_decode(0)
+    group.land_first(0, first=7)
     assert group.live_slots() == [0] and group.prefilling_slots() == [1]
 
     def packed():
         return decode_inputs([(group, group.live_slots())], B,
-                             group.pages_per_row)
+                             group.pages_per_row)[:-1]  # (not prev_index)
 
     tables, positions, cur, *_, temperature, top_p, top_k = packed()
     assert temperature.dtype == np.float32
@@ -163,7 +164,8 @@ def test_a_prefilling_row_is_handed_to_decode_as_greedy():
     assert tables[:, 0].tolist() == [1, 0, 0, 0]  # row 1's pages stay out
     assert positions.tolist() == [3, 0, 0, 0] and cur.tolist() == [7, 0, 0, 0]
     assert top_p.tolist() == [1.0] * 4 and top_k.tolist() == [0] * 4
-    group.finish_prefill(1, first=9)
+    group.begin_decode(1)
+    group.land_first(1, first=9)
     tables, *_, temperature, _, _ = packed()
     assert temperature.tolist() == [0.0, np.float32(0.9), 0.0, 0.0]
     assert tables[:, :4].tolist() == [[1, 0, 0, 0], [2, 3, 4, 5],

@@ -48,11 +48,14 @@ SPANS = {
     "serve.prefill": ({"chunks", "tokens"}, "serve.iter"),
     "serve.prefill.dispatch": ({"rid", "bucket", "start", "tokens", "final"},
                                "serve.prefill"),
-    "serve.prefill.sync": ({"rid", "final"}, "serve.prefill"),
+    # (a first token lands after the decode call its row rides has gone out)
+    "serve.prefill.sync": ({"rid", "final"}, "serve.decode"),
     "serve.decode": ({"buckets", "dispatches"}, "serve.iter"),
-    "serve.decode.dispatch": ({"bucket", "rows"}, "serve.decode"),
+    "serve.decode.dispatch": ({"bucket", "rows", "ahead", "fed_rows"},
+                              "serve.decode"),
     "serve.decode.sync": ({"bucket"}, "serve.decode"),
-    "serve.decode.retire": ({"bucket", "retired"}, "serve.decode"),
+    "serve.decode.retire": ({"bucket", "retired", "discarded"},
+                            "serve.decode"),
 }
 
 
@@ -369,7 +372,10 @@ def test_prefill_chunks_of_one_request_join_by_rid(traced):
 
 def test_row_pages_and_kv_tokens_match_the_pool_by_hand(params, tmp_path):
     """Two requests in one bucket, prompts 5 and 7, three tokens each: both
-    prefill in the first iteration (one chunk each), decode twice, retire."""
+    prefill in the first iteration (one chunk each) and ride its decode
+    call; the second dispatches their last step, lands the first, and the
+    rows leave their slots (their budget ends with the call in flight); the
+    third has nothing to dispatch and lands it."""
     got = _serve(params, schedule=((5, 3), (7, 3)), capture_dir=tmp_path)
     pages = request_pages(5, 3, PAGE_LEN) + request_pages(7, 3, PAGE_LEN)
     assert pages == 2 + 3
@@ -379,7 +385,8 @@ def test_row_pages_and_kv_tokens_match_the_pool_by_hand(params, tmp_path):
              "pages_used": 0, "kv_tokens": 0, "shared_pages": 0,
              "cached_pages": 0}
     # as each iteration begins: nothing resident; both rows live with their
-    # prompts (5 + 7) and the first decode's entry each in the cache; each
+    # prompts (5 + 7) and the first decode's entry each in the cache (the
+    # cursors count what is dispatched); each
     # prompt has one whole page before its last token's, and the prompts
     # begin alike, so the prefix cache holds it once, for the row that
     # finished first: one entry, one page with two referents

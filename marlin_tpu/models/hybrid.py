@@ -11,6 +11,8 @@ style configuration dict (:meth:`ModelSpec.from_config`):
   ``sliding`` (the last ``window`` positions: key ``j`` is visible to query
   ``i`` iff ``0 <= i - j < window``), each with its own rotary embedding
   (plain, or YaRN on part of each head), and ``latent`` (below);
+- a fourth kind of layer (``LayerSpec.ssm``; below): full attention AND a
+  state-space mixer side by side on one normed input, their outputs added;
 - a per-head sigmoid gate on the attention output, before ``wo``;
 - a SwiGLU FFN, dense or a mixture of experts with a shared expert
   (:func:`~marlin_tpu.models.moe.moe_experts_ffn`: the layer is told which
@@ -25,7 +27,8 @@ style configuration dict (:meth:`ModelSpec.from_config`):
 
 The block's arithmetic exists once (:func:`layer_forward`); what differs
 between the two paged programs is only how a query meets the cache, handed in
-as ``attend``. Only the paged serving path runs such a model
+as ``attend`` (and how a mixer meets its state, handed in as ``mix``). Only
+the paged serving path runs such a model
 (``ServeEngine(params, spec)`` -> ``lm_prefill_paged`` / ``lm_decode_paged``,
 which hand a spec to :func:`prefill_paged` / :func:`decode_paged` here):
 ``lm_generate`` and the trainer raise for a spec
@@ -35,6 +38,26 @@ Two classes of KV page (``serving/kvpool.py``): a full layer's slab is
 indexed by the row's *global* table (every position); a sliding layer's slab
 by the row's *window* table, a ring of :func:`window_ring_pages` pages in
 which position ``p`` lives in slot ``(p // page_len) % ring``.
+
+**A state slot beside the pages** (:class:`SsmSpec`, :class:`Multipliers`;
+the ``falcon_h1`` configuration family's keys). A layer with a state-space
+(Mamba-2) mixer keeps two memories of the past: its attention's keys and
+values in pages of the global class, as any full layer, and its mixer's
+**recurrent state**, per row ``(heads, state, head_dim)`` float32, with the
+**tail** of the mixer's causal convolution (its last ``conv - 1`` inputs).
+State and tail are fixed in size, neither paged nor growing nor shareable
+by prefix; they live in two more arrays a layer after its pages
+(:func:`init_kv_pages`), indexed by the row's STATE SLOT: an id of the pool
+(``serving/kvpool.py``), handed to the programs per row beside the block
+table, slot 0 the dummy. Prefill (:func:`~marlin_tpu.ops.ssm
+.ssd_chunk_scan`) enters a chunk with the slot's state and tail and leaves
+them after the chunk's last valid token; a row's first chunk enters with
+zeros whatever the slot's last row left (:func:`_enter_state`). Decode
+(:func:`~marlin_tpu.ops.ssm.ssd_decode_update`) reads and writes each live
+row's slot once, in place. The family's fixed multipliers sit on every
+branch (:func:`_parallel_mixers`, :func:`_ssm_mixer`, :func:`_ffn_half`,
+:func:`_embed`, :func:`_head_logits`); a model without them traces to the
+programs it traced to before.
 
 **Latent attention** (:class:`LatentSpec`; the DeepSeek-V3 configuration
 family's keys). A token's cache entry is not a (K, V) pair per KV head but
@@ -74,7 +97,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["RopeSpec", "LatentSpec", "LayerSpec", "ModelSpec", "init_params",
+__all__ = ["RopeSpec", "LatentSpec", "SsmSpec", "Multipliers", "LayerSpec",
+           "ModelSpec", "init_params",
            "init_layer_params", "init_kv_pages", "window_ring_pages",
            "layer_forward", "prefill_paged", "decode_paged",
            "require_int_heads"]
@@ -178,10 +202,109 @@ class LatentSpec:
 
 
 @dataclasses.dataclass(frozen=True)
+class SsmSpec:
+    """The sizes of a state-space (Mamba-2) mixer (the ``falcon_h1``
+    configuration family's ``mamba_*`` / ``ssm_*`` keys): ``heads`` heads of
+    ``head_dim`` channels, each with a state of ``state`` columns a channel;
+    ``groups`` groups of heads share one ``B`` and ``C``; a causal depthwise
+    convolution of ``conv`` taps before the recurrence; the chunked scan's
+    block ``chunk``. ``in_mult`` scales the mixer's input, ``mup`` the five
+    segments of its input projection (gate, x, B, C, dt), ``out_mult`` its
+    output. The recurrent state is kept in ``state_dtype`` (float32: it is
+    multiplied and added to at every token), the convolution's tail in the
+    compute dtype."""
+
+    heads: int
+    head_dim: int
+    state: int
+    groups: int
+    conv: int
+    chunk: int
+    in_mult: float = 1.0
+    out_mult: float = 1.0
+    mup: tuple = (1.0, 1.0, 1.0, 1.0, 1.0)
+    rms_norm: bool = True
+    norm_before_gate: bool = False
+    state_dtype: str = "float32"
+
+    @property
+    def d_inner(self) -> int:
+        return self.heads * self.head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels the convolution runs over: ``[x | B | C]``."""
+        return self.d_inner + 2 * self.groups * self.state
+
+    @property
+    def segments(self) -> tuple:
+        """Widths of the input projection's five segments, in order."""
+        gn = self.groups * self.state
+        return (self.d_inner, self.d_inner, gn, gn, self.heads)
+
+    def mup_vector(self) -> np.ndarray:
+        """``mup`` laid over the input projection's outputs."""
+        return np.repeat(np.asarray(self.mup, np.float32), self.segments)
+
+    def slot_values(self) -> tuple:
+        """Values ONE row's slot holds in one layer: ``(recurrent state,
+        convolution tail)``."""
+        return (self.heads * self.state * self.head_dim,
+                (self.conv - 1) * self.conv_dim)
+
+
+@dataclasses.dataclass(frozen=True)
+class Multipliers:
+    """The fixed scalars a configuration family puts on its branches (the
+    ``falcon_h1`` family's ``*_multiplier`` keys): on the embedding, on the
+    attention's input, keys and output, inside and after the FFN, on the
+    logits."""
+
+    embedding: float = 1.0
+    attention_in: float = 1.0
+    attention_out: float = 1.0
+    key: float = 1.0
+    mlp_gate: float = 1.0
+    mlp_down: float = 1.0
+    lm_head: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
 class LayerSpec:
     attn: str      # "full" | "sliding" | "latent"
     q_heads: int
     ffn: str       # "dense" | "moe"
+    #: a state-space mixer (:class:`SsmSpec`) beside the attention, both on
+    #: the one normed input, their outputs added
+    ssm: bool = False
+
+
+_LAYER_TYPES_KEYS = (
+    "num_hidden_layers", "layer_types", "head_dim", "hidden_size",
+    "num_key_value_heads", "sliding_window", "rope_parameters",
+    "intermediate_size", "moe_intermediate_size",
+    "shared_expert_intermediate_size", "num_experts", "num_experts_per_tok",
+    "vocab_size")
+_LATENT_KEYS = (
+    "num_hidden_layers", "num_attention_heads", "rope_parameters",
+    "qk_nope_head_dim", "qk_rope_head_dim", "q_lora_rank", "v_head_dim",
+    "n_routed_experts", "moe_intermediate_size", "hidden_size",
+    "num_key_value_heads", "intermediate_size", "num_experts_per_tok",
+    "vocab_size")
+_FALCON_H1_KEYS = (
+    "num_hidden_layers", "hidden_size", "head_dim", "num_attention_heads",
+    "num_key_value_heads", "rope_theta", "intermediate_size", "vocab_size",
+    "mamba_n_heads", "mamba_d_head", "mamba_d_state", "mamba_n_groups",
+    "mamba_d_conv", "mamba_chunk_size")
+
+
+def _require(cfg: dict, keys: tuple, family: str) -> None:
+    """A ``ValueError`` that names every key of ``keys`` ``cfg`` lacks."""
+    missing = [k for k in keys if k not in cfg]
+    if missing:
+        raise ValueError(
+            f"ModelSpec.from_config read this configuration as of the "
+            f"{family} family and could not read its keys {missing}")
 
 
 def _experts_total(held: int, experts_total, first_expert: int) -> int:
@@ -223,6 +346,10 @@ class ModelSpec:
     scoring: str = "softmax"
     #: the latent layers' sizes (their rotary embedding is ``rope_full``)
     latent: LatentSpec | None = None
+    #: the state-space mixers' sizes (the layers with ``ssm``)
+    ssm: SsmSpec | None = None
+    #: the family's fixed branch multipliers (None: there are none)
+    mults: Multipliers | None = None
 
     @classmethod
     def from_config(cls, cfg: dict, experts_total: int | None = None,
@@ -235,9 +362,17 @@ class ModelSpec:
         ``experts_total`` is the router's width where the configuration holds
         a share of the experts (default: all are held), ``first_expert`` the
         first one of the share. A configuration with ``kv_lora_rank`` is of
-        the latent-attention family (:meth:`_from_latent_config`)."""
+        the latent-attention family (:meth:`_from_latent_config`), one with
+        ``mamba_d_ssm`` of the ``falcon_h1`` family
+        (:meth:`_from_falcon_h1_config`). A configuration that lacks keys
+        its family needs raises a ``ValueError`` that names them."""
         if "kv_lora_rank" in cfg:
+            _require(cfg, _LATENT_KEYS, "latent-attention (kv_lora_rank)")
             return cls._from_latent_config(cfg, experts_total, first_expert)
+        if "mamba_d_ssm" in cfg:
+            _require(cfg, _FALCON_H1_KEYS, "falcon_h1 (mamba_d_ssm)")
+            return cls._from_falcon_h1_config(cfg)
+        _require(cfg, _LAYER_TYPES_KEYS, "layer_types")
         n = int(cfg["num_hidden_layers"])
         kinds = {"full_attention": "full", "sliding_attention": "sliding"}
         heads = cfg.get("num_attention_heads_per_layer") or (
@@ -350,6 +485,60 @@ class ModelSpec:
             compute_dtype=str(cfg.get("compute_dtype", "bfloat16")),
             scoring="sigmoid", latent=latent)
 
+    @classmethod
+    def _from_falcon_h1_config(cls, cfg: dict):
+        """The ``falcon_h1`` family's keys: every layer full attention (GQA,
+        the rotary embedding over the whole head) AND a Mamba-2 mixer
+        (``mamba_*``) side by side on one normed input, then a dense SwiGLU
+        FFN; fixed multipliers on every branch (``*_multiplier``,
+        ``ssm_multipliers``, ``mlp_multipliers``); no expert layer, no
+        window. ``ssm_state_dtype`` (not a published key; default float32)
+        is the dtype the recurrent state is kept in."""
+        unbuilt = [k for k in ("attention_bias", "mamba_proj_bias",
+                               "mlp_bias", "projectors_bias") if cfg.get(k)]
+        if unbuilt or not cfg.get("mamba_conv_bias", True) \
+                or cfg.get("rope_scaling"):
+            raise ValueError(f"not built: projection biases {unbuilt}, a "
+                             f"convolution without bias, or rope_scaling")
+        n = int(cfg["num_hidden_layers"])
+        heads, hd = int(cfg["mamba_n_heads"]), int(cfg["mamba_d_head"])
+        if heads * hd != int(cfg["mamba_d_ssm"]):
+            raise ValueError("mamba_n_heads x mamba_d_head != mamba_d_ssm")
+        dh = int(cfg["head_dim"])
+        rope = RopeSpec(theta=float(cfg["rope_theta"]), rotary_dim=dh)
+        gate_mult, down_mult = cfg.get("mlp_multipliers", (1.0, 1.0))
+        ssm = SsmSpec(
+            heads=heads, head_dim=hd, state=int(cfg["mamba_d_state"]),
+            groups=int(cfg["mamba_n_groups"]), conv=int(cfg["mamba_d_conv"]),
+            chunk=int(cfg["mamba_chunk_size"]),
+            in_mult=float(cfg.get("ssm_in_multiplier", 1.0)),
+            out_mult=float(cfg.get("ssm_out_multiplier", 1.0)),
+            mup=tuple(float(v) for v in cfg.get("ssm_multipliers",
+                                                (1.0,) * 5)),
+            rms_norm=bool(cfg.get("mamba_rms_norm", True)),
+            norm_before_gate=bool(cfg.get("mamba_norm_before_gate", False)),
+            state_dtype=str(cfg.get("ssm_state_dtype", "float32")))
+        mults = Multipliers(
+            embedding=float(cfg.get("embedding_multiplier", 1.0)),
+            attention_in=float(cfg.get("attention_in_multiplier", 1.0)),
+            attention_out=float(cfg.get("attention_out_multiplier", 1.0)),
+            key=float(cfg.get("key_multiplier", 1.0)),
+            mlp_gate=float(gate_mult), mlp_down=float(down_mult),
+            lm_head=float(cfg.get("lm_head_multiplier", 1.0)))
+        layer = LayerSpec("full", int(cfg["num_attention_heads"]), "dense",
+                          ssm=True)
+        return cls(
+            d_model=int(cfg["hidden_size"]), head_dim=dh,
+            kv_heads=int(cfg["num_key_value_heads"]), layers=(layer,) * n,
+            window=0, rope_full=rope, rope_sliding=rope,
+            dense_width=int(cfg["intermediate_size"]), expert_width=0,
+            shared_width=0, n_experts=0, experts_held=0, first_expert=0,
+            top_k=0, routed_scale=1.0, vocab_held=int(cfg["vocab_size"]),
+            norm_eps=float(cfg.get("rms_norm_eps", 1e-6)),
+            param_dtype=str(cfg.get("param_dtype", "bfloat16")),
+            compute_dtype=str(cfg.get("compute_dtype", "bfloat16")),
+            ssm=ssm, mults=mults)
+
     @property
     def n_layers(self) -> int:
         return len(self.layers)
@@ -361,6 +550,23 @@ class ModelSpec:
     @property
     def has_window(self) -> bool:
         return any(ly.attn == "sliding" for ly in self.layers)
+
+    @property
+    def has_state(self) -> bool:
+        """Whether a row holds a recurrent-state slot beside its pages."""
+        return any(ly.ssm for ly in self.layers)
+
+    def state_slot_bytes(self, compute_dtype: str | None = None) -> int:
+        """Bytes ONE row's state slot holds over all layers: the recurrent
+        state in its own dtype and the convolution's tail in the compute
+        dtype, for each layer with a mixer (0 for a model without)."""
+        if not self.has_state:
+            return 0
+        state, tail = self.ssm.slot_values()
+        per_layer = (state * np.dtype(self.ssm.state_dtype).itemsize
+                     + tail * jnp.dtype(compute_dtype
+                                        or self.compute_dtype).itemsize)
+        return per_layer * sum(ly.ssm for ly in self.layers)
 
     def page_values(self, kind: str, page_len: int) -> int:
         """Cache values ONE page id of class ``kind`` holds over all layers
@@ -414,6 +620,13 @@ def _normal(key, shape, std, dtype):
     return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
 
 
+#: a whole table in ONE program: drawn op by op, a 261120 x 5120 table held
+#: its float32 draw, the scaled copy and the result at once (13.4 GB beside
+#: the table before it: the process's peak read 16.0 GB where the engine
+#: holds 14.4; my chip runs, PR 37); fused, only the result exists
+_normal_table = jax.jit(_normal, static_argnums=(1, 2, 3))
+
+
 @functools.partial(jax.jit, static_argnames=("spec", "ly"))
 def init_layer_params(spec: ModelSpec, ly: LayerSpec, key) -> dict:
     """One layer's parameters from ``key``, in one jitted draw (a whole
@@ -423,9 +636,21 @@ def init_layer_params(spec: ModelSpec, ly: LayerSpec, key) -> dict:
     ks = jax.random.split(key, 16)
     s = d ** -0.5
     hq, hk = ly.q_heads * dh, spec.kv_heads * dh
+    # a family with fixed multipliers: a projection that a multiplier
+    # follows is drawn that much wider, so that every branch reaches the
+    # residual stream at order 1 (at N(0, 1/fan_in) a branch behind a
+    # multiplier of 0.01-0.09 would vanish under bfloat16 rounding)
+    mu = spec.mults or Multipliers()
     lp = {"ln1": jnp.ones((d,), jnp.float32),
           "ln2": jnp.ones((d,), jnp.float32)}
-    if ly.attn == "latent":
+    if ly.ssm:
+        lp.update(wq=_normal(ks[0], (d, hq), s, dt),
+                  wk=_normal(ks[1], (d, hk), s / mu.key, dt),
+                  wv=_normal(ks[2], (d, hk), s, dt),
+                  wo=_normal(ks[4], (hq, d),
+                             hq ** -0.5 / mu.attention_out, dt),
+                  ssm=_init_ssm_params(spec, ks[8:15]))
+    elif ly.attn == "latent":
         la, H = spec.latent, ly.q_heads
         lp.update(
             wq_a=_normal(ks[0], (d, la.q_rank), s, dt),
@@ -444,9 +669,9 @@ def init_layer_params(spec: ModelSpec, ly: LayerSpec, key) -> dict:
                   wo=_normal(ks[4], (hq, d), hq ** -0.5, dt))
     if ly.ffn == "dense":
         f = spec.dense_width
-        lp.update(w_gate=_normal(ks[5], (d, f), s, dt),
+        lp.update(w_gate=_normal(ks[5], (d, f), s / mu.mlp_gate, dt),
                   w_up=_normal(ks[6], (d, f), s, dt),
-                  w_down=_normal(ks[7], (f, d), f ** -0.5, dt))
+                  w_down=_normal(ks[7], (f, d), f ** -0.5 / mu.mlp_down, dt))
     else:
         fe, fs, e = spec.expert_width, spec.shared_width, spec.experts_held
         lp["moe"] = {
@@ -470,14 +695,45 @@ def init_layer_params(spec: ModelSpec, ly: LayerSpec, key) -> dict:
     return lp
 
 
+def _init_ssm_params(spec: ModelSpec, ks) -> dict:
+    """A mixer's parameters: the input projection's five segments each drawn
+    so that, after ``in_mult`` and its ``mup`` entry, the segment is of
+    order 1; the convolution N(0, 1/taps) with a bias N(0, 0.1^2); ``A``
+    uniform in [1, 16], ``dt`` log-uniform in [1e-3, 1e-1] (``dt_bias`` its
+    inverse softplus) and ``D`` 1, the Mamba-2 defaults: the state then
+    remembers tens to hundreds of tokens."""
+    sm, d, dt = spec.ssm, spec.d_model, jnp.dtype(spec.param_dtype)
+    std_in = d ** -0.5 / (sm.in_mult * jnp.asarray(sm.mup_vector()))
+    w_in = (jax.random.normal(ks[0], (d, std_in.shape[0]), jnp.float32)
+            * std_in[None, :]).astype(dt)
+    step = jnp.exp(jax.random.uniform(
+        ks[3], (sm.heads,), jnp.float32, math.log(1e-3), math.log(1e-1)))
+    return {
+        "w_in": w_in,
+        "conv_w": _normal(ks[1], (sm.conv, sm.conv_dim), sm.conv ** -0.5, dt),
+        "conv_b": _normal(ks[2], (sm.conv_dim,), 0.1, dt),
+        "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+        "A_log": jnp.log(jax.random.uniform(ks[4], (sm.heads,), jnp.float32,
+                                            1.0, 16.0)),
+        "D": jnp.ones((sm.heads,), jnp.float32),
+        "norm": jnp.ones((sm.d_inner,), jnp.float32),
+        "w_out": _normal(ks[5], (sm.d_inner, d),
+                         sm.d_inner ** -0.5 / sm.out_mult, dt)}
+
+
 def init_params(spec: ModelSpec, key) -> dict:
     """Scaled-normal parameters, drawn a layer at a time; embedding and head
-    are separate (untied) and hold ``vocab_held`` rows."""
+    are separate (untied) and hold ``vocab_held`` rows. With the family's
+    multipliers (:class:`Multipliers`) the embedding reaches the stream at
+    order 1 and the logits spread by order 1."""
     dt = jnp.dtype(spec.param_dtype)
+    mu = spec.mults
     ks = jax.random.split(key, spec.n_layers + 2)
-    p = {"emb": _normal(ks[0], (spec.vocab_held, spec.d_model), 0.02, dt),
-         "head": _normal(ks[1], (spec.vocab_held, spec.d_model),
-                         spec.d_model ** -0.5, dt),
+    table = (spec.vocab_held, spec.d_model)
+    p = {"emb": _normal_table(ks[0], table,
+                              0.02 if mu is None else 1.0 / mu.embedding, dt),
+         "head": _normal_table(ks[1], table, spec.d_model ** -0.5
+                               / (1.0 if mu is None else mu.lm_head), dt),
          "ln_f": jnp.ones((spec.d_model,), jnp.float32)}
     if not isinstance(ks, jax.core.Tracer):
         _compile_side_by_side([(init_layer_params, (spec, ly, ks[2]), {})
@@ -488,25 +744,41 @@ def init_params(spec: ModelSpec, key) -> dict:
 
 
 def init_kv_pages(spec: ModelSpec, num_pages: int, window_pages: int,
-                  page_len: int, compute_dtype: str | None = None) -> dict:
+                  page_len: int, compute_dtype: str | None = None,
+                  state_slots: int = 0) -> dict:
     """Zeroed slabs, layer -> a tuple of arrays: ``(k, v)``, each
     ``(num_pages, page_len, kv_heads, head_dim)``, for a full layer
     (``(window_pages, ...)`` for a sliding one); ONE array ``(num_pages,
     page_len, entry_width)`` for a latent layer
-    (:attr:`LatentSpec.entry_width`). Page 0 of each class is its dummy."""
+    (:attr:`LatentSpec.entry_width`). Page 0 of each class is its dummy. A
+    layer with a state-space mixer has two more arrays after its pages,
+    indexed by a row's STATE SLOT and not by a page id: the recurrent states
+    ``(state_slots, heads, state, head_dim)`` in the mixer's ``state_dtype``
+    and the convolution tails ``(state_slots, conv - 1, conv_dim)``; slot 0
+    is the dummy."""
     if num_pages < 2 or (spec.has_window and window_pages < 2):
         raise ValueError(f"each page class needs >= 2 pages (page 0 is the "
                          f"dummy), got {num_pages} and {window_pages}")
+    if spec.has_state and state_slots < 2:
+        raise ValueError(f"a model with state-space mixers needs >= 2 state "
+                         f"slots (slot 0 is the dummy), got {state_slots}")
     dt = jnp.dtype(compute_dtype or spec.compute_dtype)
 
     def slabs(ly):
         if ly.attn == "latent":
             return (jnp.zeros((num_pages, page_len,
                                spec.latent.entry_width), dt),)
-        return tuple(
+        kv = tuple(
             jnp.zeros((num_pages if ly.attn == "full" else window_pages,
                        page_len, spec.kv_heads, spec.head_dim), dt)
             for _ in range(2))
+        if not ly.ssm:
+            return kv
+        sm = spec.ssm
+        return kv + (
+            jnp.zeros((state_slots, sm.heads, sm.state, sm.head_dim),
+                      jnp.dtype(sm.state_dtype)),
+            jnp.zeros((state_slots, sm.conv - 1, sm.conv_dim), dt))
 
     return {f"l{i}": slabs(ly) for i, ly in enumerate(spec.layers)}
 
@@ -551,15 +823,21 @@ def _rope(x, positions, rope: RopeSpec):
     return out.astype(x.dtype)
 
 
-def _swiglu(h, w_gate, w_up, w_down):
+def _swiglu(h, w_gate, w_up, w_down, gate_mult=None, down_mult=None):
     """Operands in ``h``'s dtype, the result in float32 (it joins the
-    residual stream)."""
-    return _mm(jax.nn.silu(_mm(h, w_gate)) * _mm(h, w_up), w_down,
-               jnp.float32)
+    residual stream). With the multipliers of a family that has them, the
+    gate's pre-activation (scaled in float32) and the result carry one
+    each."""
+    if gate_mult is None:
+        return _mm(jax.nn.silu(_mm(h, w_gate)) * _mm(h, w_up), w_down,
+                   jnp.float32)
+    gate = jax.nn.silu(_mm(h, w_gate, jnp.float32) * gate_mult)
+    return _mm(gate.astype(h.dtype) * _mm(h, w_up), w_down,
+               jnp.float32) * down_mult
 
 
 def layer_forward(spec: ModelSpec, i: int, lp: dict, x, positions, valid,
-                  attend):
+                  attend, mix=None):
     """Layer ``i`` over the float32 residual stream ``x`` (T, d) whose rows
     stand at ``positions`` (T,): the one place the block's arithmetic is
     written. ``attend(q, k, v)``
@@ -570,10 +848,15 @@ def layer_forward(spec: ModelSpec, i: int, lp: dict, x, positions, valid,
     ``valid`` (T,) marks the rows that are real tokens: the others are routed
     to no expert. Returns ``(x, counts)``; ``counts`` is the expert layer's
     ``(assignments, local assignments, held experts touched)``, zeros for a
-    dense FFN."""
+    dense FFN. A layer with a state-space mixer takes ``mix`` too
+    (:func:`_parallel_mixers`): the recurrent state is handed in and out
+    there the way ``attend`` hands in the cache."""
     ly = spec.layers[i]
     T, cd = x.shape[0], jnp.dtype(spec.compute_dtype)
     H, kvh, dh = ly.q_heads, spec.kv_heads, spec.head_dim
+    if ly.ssm:
+        x = _parallel_mixers(spec, ly, lp, x, positions, attend, mix)
+        return _ffn_half(spec, ly, lp, x, valid)
     if ly.attn == "latent":
         with jax.named_scope("attn_latent"):
             x = _latent_attention(spec, ly, lp, x, positions, attend)
@@ -621,15 +904,88 @@ def _latent_attention(spec: ModelSpec, ly: LayerSpec, lp: dict, x, positions,
                    jnp.float32)
 
 
+def _parallel_mixers(spec: ModelSpec, ly: LayerSpec, lp: dict, x, positions,
+                     attend, mix):
+    """The first half of a layer with a state-space mixer: ``u =
+    rmsnorm(x)`` feeds BOTH mixers, whose outputs are added to the stream.
+    Attention: ``q``, ``k`` (times ``key``), ``v`` from ``u *
+    attention_in``, the rotary embedding, ``attend`` as a full layer's, ``wo``,
+    times ``attention_out``; no head gate. The mixer (:func:`_ssm_mixer`)
+    under the ``ssm_mixer`` scope."""
+    mu, cd = spec.mults or Multipliers(), jnp.dtype(spec.compute_dtype)
+    T, H, kvh, dh = x.shape[0], ly.q_heads, spec.kv_heads, spec.head_dim
+    u = _rmsnorm(x, lp["ln1"], spec.norm_eps)
+    with jax.named_scope("attn_full"):
+        h = (u * mu.attention_in).astype(cd)
+        q = _rope(_mm(h, lp["wq"]).reshape(T, H, dh), positions,
+                  spec.rope_full)
+        k = (_mm(h, lp["wk"], jnp.float32) * mu.key).astype(cd)
+        k = _rope(k.reshape(T, kvh, dh), positions, spec.rope_full)
+        v = _mm(h, lp["wv"]).reshape(T, kvh, dh)
+        o = attend(q.reshape(T, kvh, H // kvh, dh), k, v)
+        a = _mm(o.reshape(T, H * dh).astype(cd), lp["wo"],
+                jnp.float32) * mu.attention_out
+    with jax.named_scope("ssm_mixer"):
+        m = _ssm_mixer(spec, lp["ssm"], u, mix)
+    return x + a + m
+
+
+def _scan_operands(sm: SsmSpec, conv, cd):
+    """From the convolution's output (T, conv_dim) float32 to the
+    recurrence's operands: SiLU, then ``x`` (T, heads, head_dim), ``B``, ``C``
+    (T, groups, state), in ``cd``."""
+    act = jax.nn.silu(conv).astype(cd)
+    gn, T = sm.groups * sm.state, conv.shape[0]
+    return (act[:, :sm.d_inner].reshape(T, sm.heads, sm.head_dim),
+            act[:, sm.d_inner:sm.d_inner + gn].reshape(T, sm.groups,
+                                                       sm.state),
+            act[:, sm.d_inner + gn:].reshape(T, sm.groups, sm.state))
+
+
+def _ssm_mixer(spec: ModelSpec, sp: dict, u, mix):
+    """The Mamba-2 mixer over the normed input ``u`` (T, d) float32: ``p =
+    (w_in (u * in_mult)) * mup`` cut into gate ``z``, ``[x | B | C]`` and
+    ``dt``; ``dt = softplus(dt + dt_bias)``; ``mix(xbc (T, conv_dim), dt (T,
+    heads) float32, sp)`` runs the causal convolution, SiLU and the
+    recurrence where the program keeps the row's state and returns ``y`` (T,
+    d_inner) float32 (``D x`` included); then the gate and the grouped
+    RMSNorm (gate first unless ``norm_before_gate``), ``w_out``, times
+    ``out_mult``."""
+    sm, cd = spec.ssm, jnp.dtype(spec.compute_dtype)
+    T, di = u.shape[0], sm.d_inner
+    p = _mm((u * sm.in_mult).astype(cd), sp["w_in"], jnp.float32) \
+        * jnp.asarray(sm.mup_vector())
+    z, xbc, dt = p[:, :di], p[:, di:di + sm.conv_dim], p[:, di + sm.conv_dim:]
+    dt = jax.nn.softplus(dt + sp["dt_bias"])
+    y = mix(xbc.astype(cd), dt, sp)
+
+    def norm(v):  # over each of the `groups` groups of d_inner / groups
+        vg = v.reshape(T, sm.groups, di // sm.groups)
+        vg = vg * jax.lax.rsqrt(jnp.mean(vg * vg, axis=-1, keepdims=True)
+                                + spec.norm_eps)
+        return vg.reshape(T, di) * sp["norm"]
+
+    gate = jax.nn.silu(z)
+    if not sm.rms_norm:
+        y = y * gate
+    elif sm.norm_before_gate:
+        y = norm(y) * gate
+    else:
+        y = norm(y * gate)
+    return _mm(y.astype(cd), sp["w_out"], jnp.float32) * sm.out_mult
+
+
 def _ffn_half(spec: ModelSpec, ly: LayerSpec, lp: dict, x, valid):
     """The FFN half of :func:`layer_forward`, every attention kind's."""
     cd = jnp.dtype(spec.compute_dtype)
     h = _rmsnorm(x, lp["ln2"], spec.norm_eps)
     if ly.ffn == "dense":
+        mu = spec.mults
         with jax.named_scope("ffn_dense"):
-            return (x + _swiglu(h.astype(cd), lp["w_gate"], lp["w_up"],
-                                lp["w_down"]),
-                    jnp.zeros((3,), jnp.int32))
+            out = _swiglu(h.astype(cd), lp["w_gate"], lp["w_up"],
+                          lp["w_down"], *(() if mu is None else
+                                          (mu.mlp_gate, mu.mlp_down)))
+            return x + out, jnp.zeros((3,), jnp.int32)
     from .moe import moe_experts_ffn
 
     with jax.named_scope("moe_experts"):
@@ -644,8 +1000,15 @@ def _head_logits(spec: ModelSpec, params: dict, x):
     """Float32 logits over the held rows of the (untied) head."""
     xf = _rmsnorm(x, params["ln_f"], spec.norm_eps).astype(
         spec.compute_dtype)
-    return jnp.matmul(xf, params["head"].astype(xf.dtype).T,
-                      preferred_element_type=jnp.float32)
+    logits = jnp.matmul(xf, params["head"].astype(xf.dtype).T,
+                        preferred_element_type=jnp.float32)
+    return logits if spec.mults is None else logits * spec.mults.lm_head
+
+
+def _embed(spec: ModelSpec, params: dict, tokens):
+    """The residual stream's first value, float32."""
+    x = params["emb"][tokens].astype(jnp.float32)
+    return x if spec.mults is None else x * spec.mults.embedding
 
 
 def _attend_dense(q, k, v, q_pos, k_pos, k_live, window, block=None):
@@ -893,7 +1256,9 @@ def _absorbed_query(q_nope, q_pe, scale, wkv_b, la: LatentSpec):
                    donate_argnums=(1,))
 def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
                                chunk_start, length, seed, temperature, top_p,
-                               top_k, spec: ModelSpec, page_len: int):
+                               top_k, spec: ModelSpec, page_len: int,
+                               state_slot=None):
+    from ..ops import ssm as ssm_ops
     from .transformer import _pick_token_row, _row_key
 
     C = chunk.shape[0]
@@ -918,15 +1283,39 @@ def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
     by_kind = {"full": gtable, "sliding": w_slots, "latent": ltable}
     ctx = jax.lax.optimization_barrier({
         name: tuple(t[by_kind[ly.attn]].reshape(-1, *t.shape[2:])
-                    for t in pages[name])
+                    for t in _kv_slabs(ly, pages[name]))
         for name, ly in ((f"l{i}", ly) for i, ly in enumerate(spec.layers))})
     g_pos = jnp.arange(Lg)
     w_pos = jnp.concatenate([(s_page - wp) * page_len
                              + jnp.arange(wp * page_len), q_pos])
-    x = params["emb"][chunk].astype(jnp.float32)
-    new_kv, counts = {}, jnp.zeros((3,), jnp.int32)
+    x = _embed(spec, params, chunk)
+    new_kv, new_state, counts = {}, {}, jnp.zeros((3,), jnp.int32)
     for i, ly in enumerate(spec.layers):
         name = f"l{i}"
+
+        def mix(xbc, dt, sp, name=name):
+            # the row's state slot, entered and left: a row's FIRST chunk
+            # enters with zeros whatever the slot's last row left in it
+            # (this program is behind every earlier call in the stream);
+            # positions past the prompt move neither state nor tail
+            sm = spec.ssm
+            states, tails = pages[name][2:]
+            s0, t0 = _enter_state(chunk_start == 0, states[state_slot],
+                                  tails[state_slot])
+            conv, t1 = ssm_ops.causal_conv(
+                xbc, t0, sp["conv_w"], sp["conv_b"],
+                jnp.clip(length - chunk_start, 0, C))
+            xs, Bm, Cm = _scan_operands(sm, conv, xbc.dtype)
+            with jax.named_scope("ssm_scan"):
+                y, s1 = ssm_ops.ssd_chunk_scan(
+                    xs, jnp.where(valid[:, None], dt, 0.0),
+                    -jnp.exp(sp["A_log"]), Bm, Cm, sp["D"], s0,
+                    block=min(sm.chunk, C))
+            new_state[name] = (
+                jax.lax.dynamic_update_index_in_dim(states, s1, state_slot,
+                                                    0),
+                jax.lax.dynamic_update_index_in_dim(tails, t1, state_slot, 0))
+            return y.reshape(C, sm.d_inner)
 
         def attend_latent(q_nope, q_pe, entry, scale, wkv_b, name=name):
             new_kv[name] = (entry,)
@@ -964,7 +1353,8 @@ def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
             return jnp.concatenate(outs)
 
         x, c = layer_forward(spec, i, params[name], x, q_pos, valid,
-                             attend_latent if ly.attn == "latent" else attend)
+                             attend_latent if ly.attn == "latent" else attend,
+                             mix)
         counts = counts + c
     # write the chunk's pages, one dynamic update a page (transformer.py has
     # the reason). A page wholly past the prompt goes to the dummy: in a
@@ -972,7 +1362,7 @@ def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
     new_pages = {}
     for i, ly in enumerate(spec.layers):
         name = f"l{i}"
-        slabs = pages[name]
+        slabs = _kv_slabs(ly, pages[name])
         fresh = [new.astype(t.dtype).reshape(cp, page_len, *t.shape[2:])
                  for new, t in zip(new_kv[name], slabs)]
         for j in range(cp):
@@ -982,7 +1372,7 @@ def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
             slabs = tuple(
                 jax.lax.dynamic_update_index_in_dim(t, pg[j], pid, 0)
                 for t, pg in zip(slabs, fresh))
-        new_pages[name] = slabs
+        new_pages[name] = slabs + new_state.get(name, ())
     idx = jnp.clip(length - 1 - chunk_start, 0, C - 1)
     logits = _head_logits(spec, params, x[idx])
     first = _pick_token_row(temperature, top_p, top_k, logits,
@@ -993,7 +1383,7 @@ def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
 def _prefill_args(params, pages, tables, chunk, chunk_start, length,
                   spec: ModelSpec, page_len: int, seed=0, temperature=0.0,
                   top_p=None, top_k=None):
-    gtable, wtable = tables
+    gtable, wtable, *state_slot = tables
     return (params, pages, jnp.asarray(gtable, jnp.int32),
             jnp.asarray(wtable, jnp.int32), jnp.asarray(chunk, jnp.int32),
             jnp.asarray(chunk_start, jnp.int32),
@@ -1001,14 +1391,39 @@ def _prefill_args(params, pages, tables, chunk, chunk_start, length,
             jnp.asarray(temperature, jnp.float32),
             jnp.asarray(1.0 if top_p is None else top_p, jnp.float32),
             jnp.asarray(0 if top_k is None else top_k, jnp.int32)), {
-                "spec": spec, "page_len": page_len}
+                "spec": spec, "page_len": page_len,
+                **_state_slots(spec, "state_slot", state_slot)}
+
+
+def _enter_state(fresh, state, tail):
+    """What a prefill chunk enters with: the slot's state and tail, or
+    zeros where the chunk is the row's first (``fresh``): a slot is not
+    wiped when it changes hands."""
+    return jnp.where(fresh, 0, state), jnp.where(fresh, 0, tail)
+
+
+def _kv_slabs(ly: LayerSpec, arrays: tuple) -> tuple:
+    """A layer's arrays that a page id indexes (what follows them a state
+    slot does: :func:`init_kv_pages`)."""
+    return arrays[:2] if ly.ssm else arrays
+
+
+def _state_slots(spec: ModelSpec, name: str, given) -> dict:
+    """The programs' state-slot argument, from the tail of ``tables``: there
+    for a spec with state, and only for one."""
+    if bool(given) != spec.has_state:
+        raise ValueError(
+            "a model with state-space mixers takes (global tables, window "
+            "rings, state slots); any other (global tables, window rings)")
+    return {name: jnp.asarray(given[0], jnp.int32)} if given else {}
 
 
 def prefill_paged(params, pages, tables, chunk, chunk_start, length,
                   spec: ModelSpec, page_len: int, seed=0, temperature=0.0,
                   top_p=None, top_k=None):
     """:func:`~marlin_tpu.models.transformer.lm_prefill_paged` for a spec:
-    ``tables`` is the row's ``(global table, window ring)``. Returns
+    ``tables`` is the row's ``(global table, window ring)`` and, for a model
+    with state-space mixers, its state slot after them. Returns
     ``(pages, first, counts, logits)``: ``counts`` the expert layers'
     ``(assignments, local assignments, experts touched)`` summed over
     layers, ``logits`` the float32 logits ``first`` was picked from."""
@@ -1044,7 +1459,9 @@ def _attend_gather(q, pk, pv, tables, lengths, first_page, lower,
 def _lm_decode_paged_spec_jit(params, pages, gtables, wtables, positions,
                               cur_tokens, steps_done, seeds, temperature,
                               top_p, top_k, spec: ModelSpec, page_len: int,
-                              kernel: str, prev_tokens=None, prev_index=None):
+                              kernel: str, prev_tokens=None, prev_index=None,
+                              state_slots=None):
+    from ..ops import ssm as ssm_ops
     from ..ops.paged_attention import (paged_decode_attention,
                                        paged_decode_attention_latent)
     from .transformer import (_pick_token_rows, _scatter_kv_entries,
@@ -1068,15 +1485,31 @@ def _lm_decode_paged_spec_jit(params, pages, gtables, wtables, positions,
     if spec.has_window:
         per_class["sliding"] = (wtables, wtables[rows, jnp.mod(page, ring)],
                                 lower // page_len, lower)
-    x = params["emb"][cur_tokens].astype(jnp.float32)
+    x = _embed(spec, params, cur_tokens)
     new_pages, counts = {}, jnp.zeros((3,), jnp.int32)
     for i, ly in enumerate(spec.layers):
         name = f"l{i}"
         tables, pids, first_page, low = per_class[ly.attn]
 
+        def mix(xbc, dt, sp, name=name):
+            # one token a row: each live row's slot read and written once;
+            # the rows no live row fills name the dummy slot 0
+            sm = spec.ssm
+            states, tails = pages[name][2:]
+            with jax.named_scope("ssm_update"):
+                conv, t1 = ssm_ops.conv_step(xbc, tails[state_slots],
+                                             sp["conv_w"], sp["conv_b"])
+                xs, Bm, Cm = _scan_operands(sm, conv, xbc.dtype)
+                states, y = ssm_ops.ssd_decode_update(
+                    states, state_slots, xs, dt, -jnp.exp(sp["A_log"]), Bm,
+                    Cm, sp["D"], kernel=kernel)
+                tails = tails.at[state_slots].set(t1)
+            new_pages[name] += (states, tails)
+            return y.reshape(B, sm.d_inner)
+
         def attend(q, k, v, name=name, ly=ly, tables=tables, pids=pids,
                    first_page=first_page, low=low):
-            pk, pv = pages[name]
+            pk, pv = _kv_slabs(ly, pages[name])
             pk, pv = _scatter_kv_entries(pk, pv, k.astype(pk.dtype),
                                          v.astype(pv.dtype), pids, off)
             new_pages[name] = (pk, pv)
@@ -1109,7 +1542,8 @@ def _lm_decode_paged_spec_jit(params, pages, gtables, wtables, positions,
                               preferred_element_type=jnp.float32)
 
         x, c = layer_forward(spec, i, params[name], x, pos, live,
-                             attend_latent if ly.attn == "latent" else attend)
+                             attend_latent if ly.attn == "latent" else attend,
+                             mix)
         counts = counts + c
     logits = _head_logits(spec, params, x)
     nxt = _pick_token_rows(temperature, top_p, top_k, logits, seeds,
@@ -1123,7 +1557,7 @@ def _decode_args(params, pages, tables, positions, cur_tokens, steps_done,
                  prev_index=None):
     from .transformer import _fed_tokens
 
-    gtables, wtables = tables
+    gtables, wtables, *state_slots = tables
     as_i32 = lambda v: jnp.asarray(v, jnp.int32)  # noqa: E731
     return (params, pages, as_i32(gtables), as_i32(wtables),
             as_i32(positions), as_i32(cur_tokens), as_i32(steps_done),
@@ -1131,7 +1565,8 @@ def _decode_args(params, pages, tables, positions, cur_tokens, steps_done,
             jnp.asarray(temperature, jnp.float32),
             jnp.asarray(top_p, jnp.float32), as_i32(top_k)), {
                 "spec": spec, "page_len": page_len, "kernel": kernel,
-                **_fed_tokens(prev_tokens, prev_index)}
+                **_fed_tokens(prev_tokens, prev_index),
+                **_state_slots(spec, "state_slots", state_slots)}
 
 
 def decode_paged(params, pages, tables, positions, cur_tokens, steps_done,
@@ -1139,9 +1574,10 @@ def decode_paged(params, pages, tables, positions, cur_tokens, steps_done,
                  page_len: int, kernel: str, prev_tokens=None,
                  prev_index=None):
     """:func:`~marlin_tpu.models.transformer.lm_decode_paged` for a spec:
-    ``tables`` is ``(global tables (B, W), window rings (B, ring))``; a row
-    whose global table starts at the dummy page is a dummy row and is routed
-    to no expert. Returns ``(pages, next_tokens, counts, logits)`` as
+    ``tables`` is ``(global tables (B, W), window rings (B, ring))`` and,
+    for a model with state-space mixers, ``state slots (B,)`` after them; a
+    row whose global table starts at the dummy page is a dummy row, is
+    routed to no expert and names the dummy state slot 0. Returns ``(pages, next_tokens, counts, logits)`` as
     :func:`prefill_paged`."""
     args, static = _decode_args(params, pages, tables, positions, cur_tokens,
                                 steps_done, seeds, temperature, top_p, top_k,

@@ -815,20 +815,22 @@ def _pick_token_rows(temperature, top_p, top_k, logits, seeds, steps_done):
 
 
 def init_kv_pages(params, num_pages: int, page_len: int, heads,
-                  compute_dtype: str | None = None, window_pages: int = 0):
+                  compute_dtype: str | None = None, window_pages: int = 0,
+                  state_slots: int = 0):
     """Zeroed page slab: layer -> (k, v), each (num_pages, page_len,
     kv_heads, dh) in the compute dtype. One slab per engine — buckets share
     it; only block tables are bucket-shaped. Keep ``page_len`` a multiple
     of 8 (16 default) so pages stay sublane-aligned on TPU and the decode
     gather stays on the fast path (PAPERS.md 2202.05868: block geometry
     must track the MXU/lane grid). For a :class:`~.hybrid.ModelSpec` the
-    sliding layers' slabs hold ``window_pages`` pages instead
-    (:func:`.hybrid.init_kv_pages`)."""
+    sliding layers' slabs hold ``window_pages`` pages instead, and a layer
+    with a state-space mixer has ``state_slots`` recurrent-state slots
+    beside its pages (:func:`.hybrid.init_kv_pages`)."""
     if isinstance(heads, ModelSpec):
         from . import hybrid
 
         return hybrid.init_kv_pages(heads, num_pages, window_pages, page_len,
-                                    compute_dtype)
+                                    compute_dtype, state_slots)
     if num_pages < 2:
         raise ValueError(f"num_pages must be >= 2 (page 0 is the dummy), "
                          f"got {num_pages}")

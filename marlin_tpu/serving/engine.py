@@ -350,6 +350,7 @@ class ServeEngine:
                  prefix_cache: bool | None = None,
                  decode_kernel: str | None = None,
                  window_pages: int | None = None,
+                 state_slots: int | None = None,
                  programs=None,
                  clock=time.monotonic, log=None, start: bool = True):
         cfg = get_config()
@@ -435,6 +436,32 @@ class ServeEngine:
             self._window_page_bytes = kv_page_bytes(
                 params, heads, self._page_len, compute_dtype,
                 kind="sliding")
+        #: the recurrent-state slots (a ModelSpec with state-space mixers):
+        #: how many the pool holds, the dummy slot 0 among them (default:
+        #: one for every row of every bucket), and one slot's bytes over
+        #: all layers, charged at admission beside the row's pages
+        self._state_slots = 0
+        self._state_slot_bytes = 0
+        if self._spec is not None and self._spec.has_state:
+            if prefix_cache:
+                raise ValueError(
+                    "prefix_cache=True with a model that has state-space "
+                    "mixers: a shared page is no use without the recurrent "
+                    "state at the prefix's end, and snapshotting states is "
+                    "not built; leave it unset (off)")
+            self._prefix_cache = False
+            chunk = self._spec.ssm.chunk
+            for b in self.buckets:
+                width = group_chunk(b, self._page_len, self._prefill_chunk)
+                if width % min(chunk, width):
+                    raise ValueError(
+                        f"bucket {b}'s prefill chunk of {width} tokens is "
+                        f"not whole blocks of the chunked scan "
+                        f"({chunk}: mamba_chunk_size)")
+            self._state_slots = int(
+                state_slots or 1 + len(self.buckets) * self.max_batch)
+            self._state_slot_bytes = self._spec.state_slot_bytes(
+                compute_dtype)
         if hbm_budget_bytes is None:
             from ..models.planner import usable_hbm_bytes
 
@@ -735,7 +762,8 @@ class ServeEngine:
             pool = self._kvpool = PagedKVPool(
                 self.params, self.heads, self._num_pages, self._page_len,
                 self.compute_dtype, self._prefix_cache,
-                window_pages=self._window_pages, ring=self._ring or 0)
+                window_pages=self._window_pages, ring=self._ring or 0,
+                state_slots=self._state_slots)
             self.metrics.record_pages(pool.capacity, 0, 0)
             # account the slab in the process memory ledger: the free rides
             # every drop path (recovery, slab loss, terminal close), so a
@@ -744,9 +772,16 @@ class ServeEngine:
             led.free(f"kvpool:{self._name}", strict=False)
             led.register(f"kvpool:{self._name}",
                          self._num_pages * self._page_bytes
-                         + self._window_pages * self._window_page_bytes,
+                         + self._window_pages * self._window_page_bytes
+                         + self._state_slots * self._state_slot_bytes,
                          "kvpool", owner=self._name)
         return pool
+
+    def _new_group(self, bucket) -> PagedGroup:
+        """A bucket's row bookkeeping, shaped for this engine's programs."""
+        return PagedGroup(bucket, self.max_batch, self._page_len,
+                          self._prefill_chunk, ring=self._ring,
+                          stateful=bool(self._state_slots))
 
     def _record_pages(self, pool) -> None:
         st = pool.stats()
@@ -1437,7 +1472,7 @@ class ServeEngine:
         freeze (already terminal) — the caller falls back to a plain drain.
         Terminal either way once it returns a dict: the worker has exited
         and the router closes the engine next."""
-        self._refuse_window("freeze_rows")
+        self._refuse_private("freeze_rows")
         self._queue.close("engine freezing for migration")
         with self._cond:
             if self._state not in ("running", "draining"):
@@ -1577,7 +1612,7 @@ class ServeEngine:
         blob = frozen.get("blob")
         if blob is None or not entries:
             return {"adopted": [], "fallback": list(entries.values())}
-        self._refuse_window("adopt_rows")
+        self._refuse_private("adopt_rows")
         if timeout is None:
             timeout = get_config().serve_migrate_timeout_s
         box: dict = {"bound": [], "cancelled": False}
@@ -1657,7 +1692,7 @@ class ServeEngine:
         cache warming must never fail a restart)."""
         if n <= 0:
             return None
-        self._refuse_window("export_prefixes")
+        self._refuse_private("export_prefixes")
         if timeout is None:
             timeout = get_config().serve_migrate_timeout_s
         try:
@@ -1671,7 +1706,7 @@ class ServeEngine:
         (worker-mediated; best-effort). Returns entries inserted."""
         if not blob:
             return 0
-        self._refuse_window("import_prefixes")
+        self._refuse_private("import_prefixes")
         if timeout is None:
             timeout = get_config().serve_migrate_timeout_s
         try:
@@ -1679,15 +1714,23 @@ class ServeEngine:
         except MigrationError:
             return 0
 
-    def _refuse_window(self, what: str) -> None:
+    def _refuse_private(self, what: str) -> None:
         """Migration and prefix sharing move or share a row's pages; a
-        window layer's ring is neither shared nor serialized yet, so for
-        such a model these entry points raise rather than move half a row."""
+        window layer's ring is neither shared nor serialized yet, and a
+        row's pages are no use without its recurrent state, which is not
+        either: for such a model these entry points raise rather than move
+        half a row."""
         if self._ring:
             raise MigrationError(
                 f"{what}: {self._name} serves a model with sliding-window "
                 f"layers; migrating or sharing a window layer's pages is "
                 f"not built")
+        if self._state_slots:
+            raise MigrationError(
+                f"{what}: {self._name} serves a model with state-space "
+                f"mixers; a row's pages are no use without the recurrent "
+                f"state at their end, and snapshotting, sharing or "
+                f"serializing a state slot is not built")
 
     def _mig_post(self, kind: str, payload, timeout: float):
         """Post one op to the worker's migration mailbox and wait."""
@@ -1762,9 +1805,7 @@ class ServeEngine:
             bucket = tuple(row["bucket"])
             group = pools.get(bucket)
             if group is None and bucket in self.buckets:
-                group = pools[bucket] = PagedGroup(
-                    bucket, self.max_batch, self._page_len,
-                    self._prefill_chunk, ring=self._ring)
+                group = pools[bucket] = self._new_group(bucket)
                 capture_paged_costs(
                     self.params, self.heads, bucket, self.max_batch,
                     pool, self._prefill_chunk, self.compute_dtype,
@@ -2043,6 +2084,11 @@ class ServeEngine:
             # every position, its window ring a bounded few
             out.update(row_pages=row_pages + window_pages,
                        global_pages=row_pages, window_pages=window_pages)
+        if self._state_slots:
+            # every resident row holds one slot, prefilling or live
+            out.update(state_slots=self._state_slots - 1,
+                       state_rows=resident,
+                       state_bytes=resident * self._state_slot_bytes)
         return out
 
     def _admit_paged(self, pool, pools, claimed, pf_queue) -> None:
@@ -2080,9 +2126,7 @@ class ServeEngine:
                 e.queue_s = now - e.enq_t
                 group = pools.get(e.bucket)
                 if group is None:
-                    group = pools[e.bucket] = PagedGroup(
-                        e.bucket, self.max_batch, self._page_len,
-                        self._prefill_chunk, ring=self._ring)
+                    group = pools[e.bucket] = self._new_group(e.bucket)
                     # no-warmup path: the cost models of the bucket's
                     # prefill and of the one decode program (the widest
                     # bucket's shape, under its key) still land with their
@@ -2098,15 +2142,17 @@ class ServeEngine:
                 n = r.prompt.shape[0]
                 shared_len, spages = pool.match_prefix(r.prompt)
                 need = request_pages(n, r.steps, self._page_len)
-                wpages = []
+                wpages, state_id = [], 0
                 try:
                     if self._ring:
                         wpages = pool.alloc_window(request_pages(
                             n, r.steps, self._page_len, ring=self._ring))
+                    state_id = pool.alloc_state()
                     owned = pool.alloc(need - len(spages))
                 except PagePoolExhausted as exc:
                     pool.release(spages)  # drop the refs the match took
                     pool.release_window(wpages)
+                    pool.release_state(state_id)
                     # the OOM post-mortem lands BEFORE the retry path runs
                     # (the retry rebuilds state and destroys the evidence)
                     memledger.dump_oom_forensics(
@@ -2122,7 +2168,7 @@ class ServeEngine:
                                      "total_s": now - e.enq_t}))
                     continue
                 group.assign(slot, e, spages + owned, shared_len,
-                             len(spages), wpages)
+                             len(spages), wpages, state_id)
                 pf_queue.append((e.bucket, slot, r.rid))
                 self.metrics.record_prefix(hit=bool(spages))
                 self.metrics.record_page_event(
@@ -2133,6 +2179,8 @@ class ServeEngine:
                                   pages=len(spages) + len(owned),
                                   shared_pages=len(spages),
                                   prompt_tokens=n, shared_tokens=shared_len)
+                if state_id:
+                    span.set_metadata(state_bytes=self._state_slot_bytes)
         self._record_pages(pool)
 
     def _prefill_paged_chunk(self, pool, pools, pf_queue, pipe) -> None:
@@ -2191,7 +2239,10 @@ class ServeEngine:
             try:
                 with annotate("serve.prefill.dispatch", rid=r.rid,
                               bucket=_bucket_tag(bucket), start=cs,
-                              tokens=tokens, final=int(final)):
+                              tokens=tokens, final=int(final)) as dispatch:
+                    if self._state_slots:
+                        # the valid tokens the mixers' scan advances over
+                        dispatch.set_metadata(ssm_tokens=tokens)
                     chunk = group.prompts[slot][cs:cs + C]
                     if chunk.shape[0] < C:
                         # a prefix hit whose shared_len is page- but not
@@ -2483,7 +2534,7 @@ class ServeEngine:
             (tables, positions, cur, steps_done, seeds, temperature, top_p,
              top_k, prev_index) = decode_inputs(
                  call, self.max_batch, self._decode_pages, self._ring,
-                 pipe.serial)
+                 pipe.serial, stateful=bool(self._state_slots))
             out = lm_decode_paged(
                 self.params, pool.pages, tables, positions, cur, steps_done,
                 seeds, temperature, top_p, top_k, heads=self.heads,
@@ -2515,9 +2566,12 @@ class ServeEngine:
             row += len(slots)
         if self._spec is not None:
             # the pages each attention kind's kernel is given a row
-            tables, ring = tables
+            tables, ring = tables[:2]
             span.set_metadata(global_table_width=tables.shape[1],
                               window_table_width=ring.shape[1])
+            if self._state_slots:
+                # the live rows whose state slot this call reads and writes
+                span.set_metadata(state_rows=rows)
         if span.is_enabled():
             # the pages that hold what the live rows attend this step (the
             # decode kernel's grid steps that compute, of the padded_rows x
@@ -2686,11 +2740,10 @@ class ServeEngine:
             "ttft_s": group.ttft_s[slot],
             "pages": len(group.row_pages[slot] or []),
             "shared_pages": int(group.shared_pages[slot])})
-        ring = group.window_row_pages[slot]
-        pages = group.release(slot)
-        if pool is not None:
-            pool.release(pages)
-            pool.release_window(ring)
+        if pool is None:
+            group.release(slot)
+        else:
+            pages = pool.release_row(group, slot)
             # inside the request's span: the free record must join the
             # request's trace whichever step retires it
             with obs_trace.use(e.trace):
@@ -2818,8 +2871,7 @@ class ServeEngine:
             for group, i in carried:
                 e = group.entries[i]
                 if e.attempts_left():
-                    pool.release_window(group.window_row_pages[i])
-                    pool.release(group.release(i))
+                    pool.release_row(group, i)
                     self._requeue(e, reason)
                 else:
                     self._retire_row_paged(pool, pools, group.bucket, i,
@@ -2848,8 +2900,7 @@ class ServeEngine:
                                   f"pool lost to a failed prefill: {reason}")
         else:
             now = self._clock()
-            pool.release_window(group.window_row_pages[slot])
-            pool.release(group.release(slot))
+            pool.release_row(group, slot)
             if e.attempts_left():
                 self._requeue(e, reason)
             else:

@@ -68,6 +68,23 @@ flush, and the migration blobs walk each layer's arrays in order, and
 holds. Latent pages are of the global class, so they are shared through the
 prefix cache and migrate like any other.
 
+**A state slot beside the pages** (a ``ModelSpec`` with state-space
+mixers): such a layer's memory of the past is not keys and values in pages
+but, per row, a recurrent state of fixed size and the tail of a short
+convolution (:func:`~marlin_tpu.models.hybrid.init_kv_pages`: two more
+arrays a layer, after its pages). A row holds ONE state slot, an index of
+this pool (:meth:`PagedKVPool.alloc_state`) that serves every such layer,
+taken at admission with the row's pages and freed with them
+(:meth:`PagedKVPool.release_row`); slot 0 is the dummy that padded decode
+rows scribble on. It neither grows nor pages, and it is no use to another
+row: a shared page is worthless without the state at the prefix's end, so
+the prefix cache is off and the migration entry points raise
+(:meth:`PagedKVPool._refuse_private`). Nothing zeroes a slot when it changes
+hands: a row's first prefill chunk enters with a zero state whatever the
+slot holds, and that program is behind every earlier call in the stream.
+A :class:`PagedGroup` keeps a row's state slot as ``state_ids[slot]``: a
+pool-level id, NOT the row's slot (its place) in the group.
+
 Everything here is host-side numpy/stdlib except the three compiled
 programs it drives; single-threaded by contract (only the engine worker
 touches a pool).
@@ -210,7 +227,7 @@ class PagedKVPool:
     def __init__(self, params: dict, heads, num_pages: int,
                  page_len: int, compute_dtype: str | None = None,
                  prefix_cache: bool = True, window_pages: int = 0,
-                 ring: int = 0):
+                 ring: int = 0, state_slots: int = 0):
         from ..models.hybrid import ModelSpec
         from ..models.transformer import init_kv_pages
 
@@ -218,6 +235,11 @@ class PagedKVPool:
         self.num_pages = int(num_pages)
         self.compute_dtype = compute_dtype
         windowed = isinstance(heads, ModelSpec) and heads.has_window
+        stateful = isinstance(heads, ModelSpec) and heads.has_state
+        #: recurrent-state slots, the dummy slot 0 among them (0: the model
+        #: has no state-space mixer)
+        self.state_slots = int(state_slots) if stateful else 0
+        self._sfree = list(range(self.state_slots - 1, 0, -1))
         #: pages of the window class (0: the model has no sliding layer)
         #: and the ring a row holds of them
         self.window_pages = int(window_pages) if windowed else 0
@@ -225,12 +247,15 @@ class PagedKVPool:
         if windowed and self.ring < 1:
             raise ValueError("a model with sliding layers needs ring >= 1")
         self.pages = init_kv_pages(params, num_pages, page_len, heads,
-                                   compute_dtype, self.window_pages)
+                                   compute_dtype, self.window_pages,
+                                   self.state_slots)
         self._wfree = list(range(self.window_pages - 1, 0, -1))
         self._wref = np.zeros(self.window_pages, np.int32)
         if windowed:
             self._wref[0] = 1  # the window class's own dummy
             prefix_cache = False  # a window page is never shared
+        if stateful:
+            prefix_cache = False  # nor a page without the state at its end
         # pop() hands out ascending ids; page 0 never enters the list
         self._free = list(range(num_pages - 1, 0, -1))
         self._ref = np.zeros(num_pages, np.int32)
@@ -272,6 +297,9 @@ class PagedKVPool:
         if self.window_pages:
             out.update(window_total=self.window_pages - 1,
                        window_used=self.window_used_count())
+        if self.state_slots:
+            out.update(state_total=self.state_slots - 1,
+                       state_used=self.state_used_count())
         return out
 
     # ------------------------------------------------------ the window class
@@ -299,12 +327,56 @@ class PagedKVPool:
             assert self._wref[p] == 0, f"window page {p} released twice"
             self._wfree.append(int(p))
 
-    def _refuse_window(self, what: str) -> None:
+    def _refuse_private(self, what: str) -> None:
+        """Sharing and migration move pages; what a row holds besides (a
+        window ring, a recurrent-state slot) is neither shared nor
+        serialized, and the pages are no use without it."""
         if self.window_pages:
             raise NotImplementedError(
                 f"{what} is not built for a model with sliding-window "
                 f"layers: a window layer's pages are a per-row ring that is "
                 f"neither shared nor serialized")
+        if self.state_slots:
+            raise NotImplementedError(
+                f"{what} is not built for a model with state-space mixers: "
+                f"a row's pages are no use without the recurrent state at "
+                f"their end, and a state slot is neither snapshotted, shared "
+                f"nor serialized")
+
+    # ------------------------------------------------------- the state slots
+
+    def state_used_count(self) -> int:
+        return max(self.state_slots - 1, 0) - len(self._sfree)
+
+    def alloc_state(self) -> int:
+        """One free state slot for a row being admitted (0 where the model
+        has none). Whatever its last row left in it stays there: the row's
+        first prefill chunk enters with zeros. :class:`PagePoolExhausted`
+        when every slot is held."""
+        if not self.state_slots:
+            return 0
+        if not self._sfree:
+            raise PagePoolExhausted(
+                f"need a state slot, all {self.state_slots - 1} are held")
+        return self._sfree.pop()
+
+    def release_state(self, state_id: int) -> None:
+        """A retiring row's state slot goes back to the free list."""
+        if state_id:
+            assert state_id not in self._sfree, \
+                f"state slot {state_id} released twice"
+            self._sfree.append(int(state_id))
+
+    def release_row(self, group: "PagedGroup", slot: int) -> list[int]:
+        """Free everything the row in ``slot`` of ``group`` holds, of every
+        kind, and the slot itself: its window ring, its state slot, its
+        pages. The one funnel of every retirement path. Returns the pages
+        it held."""
+        self.release_window(group.window_row_pages[slot])
+        self.release_state(int(group.state_ids[slot]))
+        pages = group.release(slot)
+        self.release(pages)
+        return pages
 
     # ----------------------------------------------------- alloc / refcount
 
@@ -512,7 +584,7 @@ class PagedKVPool:
         gathered device→host once for the whole set. The blob is
         self-contained: :meth:`import_rows` on any pool with matching
         geometry rebuilds the rows without reference to this pool."""
-        self._refuse_window("row migration (export_rows)")
+        self._refuse_private("row migration (export_rows)")
         host = self._host_pages()
         names = self._layer_names()
         meta = {"version": 1, "kind": "rows", **self._geometry(),
@@ -541,7 +613,7 @@ class PagedKVPool:
         failure every page this call allocated is released (pages already
         content-written stay valid for the cache entries that reference
         them), so a failed import leaks nothing."""
-        self._refuse_window("row migration (import_rows)")
+        self._refuse_private("row migration (import_rows)")
         chunks = _mig_chunks(blob)
         if not chunks or chunks[0][0] != _MIG_META:
             raise MigrationCorruptError("blob does not start with a meta "
@@ -616,7 +688,7 @@ class PagedKVPool:
         never be matched), as a CRC-framed blob for warming a peer's cache.
         Keys are the content hashes themselves — no prompt tokens travel.
         Returns None when there is nothing to export."""
-        self._refuse_window("prefix export")
+        self._refuse_private("prefix export")
         if not self.prefix_cache_enabled or not self._cache:
             return None
         selected: set[bytes] = set()
@@ -659,7 +731,7 @@ class PagedKVPool:
         failing it), takes the cache-owned reference, and links into the
         parent chain. Entries already cached (or whose parent did not make
         the cut) are skipped. Returns entries inserted."""
-        self._refuse_window("prefix import")
+        self._refuse_private("prefix import")
         if not self.prefix_cache_enabled:
             return 0
         chunks = _mig_chunks(blob)
@@ -730,6 +802,7 @@ class PagedKVPool:
         expect = np.zeros(self.num_pages, np.int64)
         expect[0] = 1  # the dummy pin
         errors += self._audit_window(groups)
+        errors += self._audit_state(groups)
         for g in groups:
             for slot in g.occupied_slots():
                 for p in (g.row_pages[slot] or []):
@@ -830,6 +903,37 @@ class PagedKVPool:
         return errors
 
 
+    def _audit_state(self, groups) -> list[str]:
+        """The state slots' half of :meth:`audit`: every slot either free or
+        held by exactly one resident row, every resident row holding one."""
+        if not self.state_slots:
+            return []
+        errors: list[str] = []
+        held = np.zeros(self.state_slots, np.int64)
+        for g in groups:
+            for slot in g.occupied_slots():
+                sid = int(g.state_ids[slot])
+                if not 0 < sid < self.state_slots:
+                    errors.append(f"a resident row holds state slot {sid}, "
+                                  f"not one of 1..{self.state_slots - 1}")
+                else:
+                    held[sid] += 1
+        fs = set(self._sfree)
+        if len(fs) != len(self._sfree):
+            errors.append("state free list contains duplicate slots")
+        if 0 in fs:
+            errors.append("dummy state slot 0 is on the free list")
+        for sid in range(1, self.state_slots):
+            if sid in fs:
+                if held[sid]:
+                    errors.append(f"free state slot {sid} is still held by "
+                                  f"a row")
+            elif groups and held[sid] != 1:
+                errors.append(f"state slot {sid}: off the free list with "
+                              f"{int(held[sid])} rows holding it")
+        return errors
+
+
 class PagedGroup:
     """Per-bucket row bookkeeping over a shared :class:`PagedKVPool`. Owns
     the per-row vectors the decode program takes (:func:`decode_inputs`
@@ -843,7 +947,8 @@ class PagedGroup:
     a group."""
 
     def __init__(self, bucket, width: int, page_len: int,
-                 prefill_chunk: int, ring: int | None = None):
+                 prefill_chunk: int, ring: int | None = None,
+                 stateful: bool = False):
         p, s = bucket
         self.bucket = bucket
         self.width = width
@@ -860,6 +965,12 @@ class PagedGroup:
         self.ring = ring
         self.window_tables = np.zeros((width, ring or 0), np.int32)
         self.window_row_pages: list = [None] * width
+        #: whether the programs take a state slot a row (a model with
+        #: state-space mixers), and each row's: an id of the POOL's
+        #: (:meth:`PagedKVPool.alloc_state`; 0: none), not the row's slot
+        #: in this group
+        self.stateful = stateful
+        self.state_ids = np.zeros(width, np.int32)
         #: stored table width: decode extent + chunk spill (a final chunk
         #: starting near the extent scatters into these dummy-page slots)
         self.table_width = self.pages_per_row + self.chunk_pages
@@ -909,12 +1020,13 @@ class PagedGroup:
     # ---------------------------------------------------------- transitions
 
     def assign(self, slot: int, entry, pages: list[int], shared_len: int,
-               n_shared: int, window_pages=()) -> None:
+               n_shared: int, window_pages=(), state_id: int = 0) -> None:
         """Bind an admitted entry: ``pages`` is the row's full block table
         in position order (``n_shared`` prefix-cache pages first, then the
         freshly allocated remainder); prefill resumes at ``shared_len``.
         ``window_pages`` is the row's ring of the window class (at most
-        ``ring``; a short request holds only the slots it can reach)."""
+        ``ring``; a short request holds only the slots it can reach),
+        ``state_id`` its state slot in the pool."""
         r = entry.request
         n = r.prompt.shape[0]
         self.entries[slot] = entry
@@ -925,6 +1037,7 @@ class PagedGroup:
         self.window_tables[slot, :] = 0
         self.window_tables[slot, :len(window_pages)] = window_pages
         self.window_row_pages[slot] = list(window_pages)
+        self.state_ids[slot] = state_id
         self.shared_pages[slot] = n_shared
         self.pf_next[slot] = shared_len
         padded = np.zeros(_round_up(n, self.chunk), np.int32)
@@ -995,6 +1108,7 @@ class PagedGroup:
         self.row_pages[slot] = None
         self.window_tables[slot, :] = 0
         self.window_row_pages[slot] = None
+        self.state_ids[slot] = 0
         self.shared_pages[slot] = 0
         self.pf_next[slot] = -1
         self.positions[slot] = 0
@@ -1012,7 +1126,11 @@ class PagedGroup:
 
     def prefill_tables(self, slot: int):
         """What the prefill program takes for ``slot``: the row's table,
-        and its window ring beside it where the model has sliding layers."""
+        its window ring beside it where the model is a spec, and its state
+        slot after them where the model has state-space mixers."""
+        if self.stateful:
+            return (self.tables[slot], self.window_tables[slot],
+                    self.state_ids[slot])
         if self.ring is not None:
             return self.tables[slot], self.window_tables[slot]
         return self.tables[slot]
@@ -1027,7 +1145,8 @@ def decode_pages(buckets, page_len: int) -> int:
 
 
 def decode_inputs(rows, width: int, pages_per_row: int,
-                  ring: int | None = None, serial: int = 0):
+                  ring: int | None = None, serial: int = 0,
+                  stateful: bool = False):
     """What one call of the decode program takes after the slab, for the
     LIVE rows ``rows`` — ``(group, slots)`` runs in call order, of any
     buckets' groups, at most ``width`` rows in all, packed from row 0:
@@ -1043,9 +1162,14 @@ def decode_inputs(rows, width: int, pages_per_row: int,
     handed in — and temperature 0: the decode program sorts the vocabulary
     for the whole call when any row it is handed samples. With ``ring`` (a
     ModelSpec's programs take both classes of page) ``tables`` is
-    ``(tables, window rings)``."""
+    ``(tables, window rings)``, and with ``stateful`` ``(tables, window
+    rings, state slots)``: a row's place in the call is not its state slot
+    (the call packs its live rows from row 0 anew every iteration), so each
+    row's pool-level slot id rides beside its table, and the rows no live
+    row fills name the dummy state slot 0 as they name the dummy page."""
     tables = np.zeros((width, pages_per_row), np.int32)
     rings = np.zeros((width, ring or 0), np.int32)
+    state_ids = np.zeros(width, np.int32)
     positions = np.zeros(width, np.int32)
     cur = np.zeros(width, np.int32)
     steps_done = np.zeros(width, np.int32)
@@ -1060,6 +1184,7 @@ def decode_inputs(rows, width: int, pages_per_row: int,
         at += len(slots)
         tables[to, :g.pages_per_row] = g.tables[slots, :g.pages_per_row]
         rings[to] = g.window_tables[slots]
+        state_ids[to] = g.state_ids[slots]
         positions[to] = g.positions[slots]
         cur[to] = g.cur_tok[slots]
         steps_done[to] = g.steps_done[slots]
@@ -1069,8 +1194,12 @@ def decode_inputs(rows, width: int, pages_per_row: int,
         top_k[to] = g.top_k[slots]
         prev_index[to] = np.where(g.fed_serial[slots] == serial,
                                   g.fed_index[slots], -1)
-    return ((tables, rings) if ring is not None else tables, positions, cur,
-            steps_done, seeds, temperature, top_p, top_k, prev_index)
+    if stateful:
+        tables = (tables, rings, state_ids)
+    elif ring is not None:
+        tables = (tables, rings)
+    return (tables, positions, cur, steps_done, seeds, temperature, top_p,
+            top_k, prev_index)
 
 
 # ---------------------------------------------------------------- programs
@@ -1220,10 +1349,12 @@ def warmup_paged(params: dict, heads: int, buckets, max_batch: int,
 
     buckets = normalize_buckets(buckets)
     ring = None if isinstance(heads, int) else pool.ring
+    stateful = bool(pool.state_slots)
     groups = [PagedGroup(bucket, max_batch, pool.page_len, prefill_chunk,
-                         ring=ring) for bucket in buckets]
+                         ring=ring, stateful=stateful) for bucket in buckets]
     table_pages = decode_pages(buckets, pool.page_len)
-    *dummy, unfed = decode_inputs((), max_batch, table_pages, ring)
+    *dummy, unfed = decode_inputs((), max_batch, table_pages, ring,
+                                  stateful=stateful)
     feed = np.zeros(max_batch, np.int32)
     if not isinstance(heads, int):
         # a spec's programs take the compiler half a minute each and leave

@@ -74,7 +74,10 @@ class PagedLMProgram(BucketProgram):
         # every position in the global class, a ring in the window class
         ring = request_pages(request.prompt.shape[0], request.steps,
                              eng._page_len, ring=eng._ring or 0)
-        return pages * eng._page_bytes + ring * eng._window_page_bytes
+        # and, for a model with state-space mixers, the one state slot the
+        # row holds whatever its length
+        return (pages * eng._page_bytes + ring * eng._window_page_bytes
+                + eng._state_slot_bytes)
 
     def program_key(self, bucket, width=None):
         return self._eng._prog_key(bucket)

@@ -768,3 +768,34 @@ def test_sigmoid_expert_layer_compiles_for_v5e_at_published_widths(one_chip):
                 mp, st((32, d)), st((32,), jnp.bool_)).lower().compile()
     text = c.as_text()
     assert text.count("tpu_custom_call") >= 3 and "ragged-dot" not in text
+
+
+def test_state_update_kernel_mosaic_compiles_in_place_at_published_widths(
+        one_chip):
+    """The decode state update (``ops/ssm.py``) at the ``falcon_h1`` cell's
+    shapes (64 rows, 32 heads of 128 channels, a state of 256 columns in 2
+    groups, 65 slots) compiles through Mosaic for a v5e, and updates the
+    slab IN PLACE: the 273 MB slab is aliased to the output and no
+    temporary of its size appears (a copy of it would cost a layer 0.67 ms
+    a step, as much as the update itself)."""
+    from marlin_tpu.ops import ssm
+
+    B, H, N, P, G, S = 64, 32, 256, 128, 2, 65
+    slab = jax.ShapeDtypeStruct((S, H, N, P), jnp.float32, sharding=one_chip)
+    slab_bytes = S * H * N * P * 4
+
+    def st(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def step(slab, slots, x, dt, A, Bm, Cm, D):
+        return ssm.ssd_decode_update(slab, slots, x, dt, A, Bm, Cm, D,
+                                     kernel="pallas", interpret=False)
+
+    assert ssm.decode_update_supported(H, G, N, P)
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+        slab, st((B,), jnp.int32), st((B, H, P)), st((B, H)), st((H,)),
+        st((B, G, N)), st((B, G, N)), st((H,))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= slab_bytes
+    assert m.temp_size_in_bytes < slab_bytes // 8

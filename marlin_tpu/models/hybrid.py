@@ -747,10 +747,18 @@ def init_kv_pages(spec: ModelSpec, num_pages: int, window_pages: int,
                   page_len: int, compute_dtype: str | None = None,
                   state_slots: int = 0) -> dict:
     """Zeroed slabs, layer -> a tuple of arrays: ``(k, v)``, each
-    ``(num_pages, page_len, kv_heads, head_dim)``, for a full layer
+    ``(num_pages, page_len, kv_heads * head_dim)``, for a full layer
     (``(window_pages, ...)`` for a sliding one); ONE array ``(num_pages,
     page_len, entry_width)`` for a latent layer
     (:attr:`LatentSpec.entry_width`). Page 0 of each class is its dummy. A
+    token's heads lie side by side in ONE row, so head ``h``'s keys of a page
+    are the lane slice ``[:, h * head_dim:(h + 1) * head_dim]``, a matrix of
+    whole tiles at ``head_dim`` 128 that the decode kernel contracts on the
+    MXU as it is (:func:`~marlin_tpu.ops.paged_attention
+    .paged_decode_attention` picks its body by the slab's rank; held
+    ``(page_len, kv_heads, head_dim)``, as the dense model's 16-token pages
+    are, a 256-token page cost the kernel 8-9 us a step for 0.6-1.3 us of
+    bytes: PERF.md, PR 38). A
     layer with a state-space mixer has two more arrays after its pages,
     indexed by a row's STATE SLOT and not by a page id: the recurrent states
     ``(state_slots, heads, state, head_dim)`` in the mixer's ``state_dtype``
@@ -770,7 +778,7 @@ def init_kv_pages(spec: ModelSpec, num_pages: int, window_pages: int,
                                spec.latent.entry_width), dt),)
         kv = tuple(
             jnp.zeros((num_pages if ly.attn == "full" else window_pages,
-                       page_len, spec.kv_heads, spec.head_dim), dt)
+                       page_len, spec.kv_heads * spec.head_dim), dt)
             for _ in range(2))
         if not ly.ssm:
             return kv
@@ -1330,16 +1338,22 @@ def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
 
         def attend(q, k, v, name=name, ly=ly):
             new_kv[name] = (k, v)
+            # the context as the slab holds it, a token's heads in one row;
+            # the chunk's own entries join it in that form
             ck, cv = ctx[name]
+            k, v = (new.astype(c.dtype).reshape(C, -1)
+                    for new, c in ((k, ck), (v, cv)))
+
+            def heads(c):
+                return c.reshape(c.shape[0], spec.kv_heads, spec.head_dim)
+
             if ly.attn == "full":
-                ck = jax.lax.dynamic_update_slice(
-                    ck, k.astype(ck.dtype), (chunk_start, 0, 0))
-                cv = jax.lax.dynamic_update_slice(
-                    cv, v.astype(cv.dtype), (chunk_start, 0, 0))
-                return _attend_dense(q, ck, cv, q_pos, g_pos,
+                ck = jax.lax.dynamic_update_slice(ck, k, (chunk_start, 0))
+                cv = jax.lax.dynamic_update_slice(cv, v, (chunk_start, 0))
+                return _attend_dense(q, heads(ck), heads(cv), q_pos, g_pos,
                                      jnp.ones((Lg,), bool), None, block=C)
-            ck = jnp.concatenate([ck, k.astype(ck.dtype)])
-            cv = jnp.concatenate([cv, v.astype(cv.dtype)])
+            ck = heads(jnp.concatenate([ck, k]))
+            cv = heads(jnp.concatenate([cv, v]))
             # a band, not a square: the queries a sub-block at a time, each
             # against the window before it and itself (the context holds
             # exactly `window` positions before the chunk, so the slices
@@ -1440,12 +1454,14 @@ def _attend_gather(q, pk, pv, tables, lengths, first_page, lower,
                    page_len: int):
     """The reference formulation of the decode kernel: each row's pages
     gathered in position order (ring slot ``(first_page + w) % W``), dense
-    masked softmax over them. ``q`` (B, kvh, g, dh)."""
+    masked softmax over them. ``q`` (B, kvh, g, dh); the slabs as
+    :func:`init_kv_pages` lays them out."""
     B, W = tables.shape
     slots = jnp.mod(first_page[:, None] + jnp.arange(W)[None, :], W)
     pids = jnp.take_along_axis(tables, slots, axis=1)
-    k = pk[pids].reshape(B, W * page_len, *pk.shape[2:])
-    v = pv[pids].reshape(B, W * page_len, *pv.shape[2:])
+    heads = (B, W * page_len, q.shape[1], q.shape[3])
+    k = pk[pids].reshape(heads)
+    v = pv[pids].reshape(heads)
     pos = first_page[:, None] * page_len + jnp.arange(W * page_len)[None, :]
     live = (pos >= lower[:, None]) & (pos < lengths[:, None])
     s = jnp.einsum("bkgd,btkd->bkgt", q, k,

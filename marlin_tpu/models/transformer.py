@@ -1119,12 +1119,16 @@ def _scatter_kv_entries(pk, pv, k_new, v_new, pids, off):
     unroll is a handful of in-place updates. Dummy rows all target page 0
     offset 0; their duplicate writes are last-writer garbage in a page
     nothing valid ever reads (ordering is irrelevant — every write to a
-    location nothing reads is equally garbage)."""
+    location nothing reads is equally garbage). ``k_new``/``v_new`` are (B,
+    kvh, dh); an entry takes the shape of the slab's own page row: ``(kvh,
+    dh)`` here, one row of ``kvh * dh`` in a spec model's slab
+    (:func:`~marlin_tpu.models.hybrid.init_kv_pages`)."""
     B = pids.shape[0]
+    entry = (1, 1) + pk.shape[2:]   # (kvh, dh), or one row of kvh * dh
     for b in range(B):
-        idx = (pids[b], off[b], 0, 0)
-        pk = jax.lax.dynamic_update_slice(pk, k_new[b][None, None], idx)
-        pv = jax.lax.dynamic_update_slice(pv, v_new[b][None, None], idx)
+        idx = (pids[b], off[b]) + (0,) * (pk.ndim - 2)
+        pk = jax.lax.dynamic_update_slice(pk, k_new[b].reshape(entry), idx)
+        pv = jax.lax.dynamic_update_slice(pv, v_new[b].reshape(entry), idx)
     return pk, pv
 
 

@@ -11,14 +11,39 @@ drives the Pallas ``index_map``, so page blocks stream HBM→VMEM directly
 from the slab (grid (B, W), pages innermost) and the context is never
 materialized as a separate array.
 
-Shapes follow the slab exactly (:func:`~marlin_tpu.models.transformer
-.init_kv_pages`): K/V pages are ``(num_pages, page_len, kv_heads, dh)``,
-queries arrive in the GQA-grouped form ``(B, kv_heads, group, dh)`` the
-decode step already uses (``group = heads // kv_heads``; plain MHA is the
-group=1 case), and the score/value contractions are the SAME einsums as
-:func:`~marlin_tpu.models.transformer._decode_step` (``kgd,tkd->kgt`` /
+Shapes follow the slab exactly, and the slab's RANK picks the body that
+reads a page (nothing else does: no argument, no flag, no model's name).
+The dense model (:func:`~marlin_tpu.models.transformer.init_kv_pages`)
+holds K/V pages ``(num_pages, page_len, kv_heads, dh)``; a
+:class:`~marlin_tpu.models.hybrid.ModelSpec` model
+(:func:`~marlin_tpu.models.hybrid.init_kv_pages`) ``(num_pages, page_len,
+kv_heads * dh)``, a token's heads side by side in one row. Queries arrive
+in the GQA-grouped form ``(B, kv_heads, group, dh)`` the decode step
+already uses (``group = heads // kv_heads``; plain MHA is the group=1
+case), and the score/value contractions are the SAME as
+:func:`~marlin_tpu.models.transformer._decode_step`'s (``kgd,tkd->kgt`` /
 ``kgt,tkd->kgd``, f32 scores, masked positions at −1e30) so the kernel's
-math is the reference path's math, re-scheduled. Softmax is the online
+math is the reference path's math, re-scheduled: on a rank-4 page as
+those two einsums, batched over the block's MIDDLE axis
+(:func:`_page_all_heads`); on a rank-3 page one KV head at a time, head
+``h``'s keys the lane slice ``[:, h * dh:(h + 1) * dh]``, a ``(page_len,
+dh)`` matrix of whole tiles that meets the head's ``group`` query rows in a
+plain matmul on the MXU (:func:`_page_head_by_head`).
+
+**Why two layouts, and which model holds which.** In VMEM a ``(page_len,
+kv_heads, dh)`` block is ``page_len`` tiles of ``(kv_heads, dh)``, one a
+token, 4 or 8 of a tile's sublanes filled, and one head's keys are one
+sublane out of each: the batched einsum pays a relayout that goes with
+``page_len`` and not with bytes. At a spec model's 256-token pages that was
+8.4-9.1 us a live grid step for 0.64-1.28 us of bytes; head by head over
+the flat page it is 1.3-2.8 us (Falcon-H1's 4 heads x group 5, Laguna's 8
+x 6 and 8 x 9; ``tools/attn_page_step.py``, PERF.md PR 38), and the
+float32 blocks of a 256-token page fit scoped VMEM, which the relayout's
+did not. The dense model keeps rank 4 and the einsum body: with ONE query
+row a head and 16 keys a page a per-head matmul is 64 tile loads a page for
+a handful of useful rows, and that kernel's remedy is more tokens a grid
+step, another mechanism (ROADMAP Queue 3 has the condition that ends the
+split). Softmax is the online
 (flash) form: running max ``m``, normalizer ``l`` and the f32 accumulator
 live in VMEM scratch across the page-sequential grid dimension; each page
 block rescales the accumulator by ``exp(m_old − m_new)``. Reduction order
@@ -121,7 +146,10 @@ def _paged_attn_kernel(*refs, page_len: int, windowed: bool = False):
     the length. A step whose page starts at or past ``lengths[b]`` skips the
     body; the first page visited holds a live position (position 0, or
     ``lower[b]``), so the running max is finite and ``l`` positive at the
-    flush."""
+    flush. The page block's RANK picks the body: ``(1, page_len, kvh, dh)``
+    meets all heads in one batched einsum (:func:`_page_all_heads`), ``(1,
+    page_len, kvh * dh)`` one head's lane slice at a time
+    (:func:`_page_head_by_head`)."""
     if windowed:
         (tables_ref, lengths_ref, first_ref, lower_ref, q_ref, k_ref, v_ref,
          o_ref, acc_ref, m_ref, l_ref) = refs
@@ -145,36 +173,92 @@ def _paged_attn_kernel(*refs, page_len: int, windowed: bool = False):
 
     @pl.when(page * page_len < lengths_ref[b])
     def _live_page():
-        q = q_ref[0]  # (kvh, group, dh) — compute dtype
-        k = k_ref[0]  # (page_len, kvh, dh)
-        v = v_ref[0]
-        dh = q.shape[-1]
-        # the _decode_step score einsum, f32 scores, same 1/sqrt(dh) scaling
-        s = jnp.einsum("kgd,tkd->kgt", q, k,
-                       preferred_element_type=jnp.float32) / math.sqrt(dh)
-        # absolute position of column t is page*page_len + t; live iff
-        # < length (and, for a window, >= lower)
-        at = page * page_len + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        keep = at < lengths_ref[b]
-        if windowed:
-            keep &= at >= lower_ref[b]
-        s = jnp.where(keep, s, _MASKED)
-        # online-softmax update: new running max, rescale the old accumulator
-        m_prev = m_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2))
-        m_ref[:] = m_new
-        alpha = jnp.exp(m_prev - m_new)  # 0.0 on the w==0 init (m_prev=-1e30)
-        p = jnp.exp(s - m_new[:, :, None])  # masked cols underflow to exact 0
-        l_ref[:] = alpha * l_ref[:] + jnp.sum(p, axis=2)
-        # probabilities meet V in the compute dtype — q's dtype, the same cast
-        # _decode_step applies (p.astype(cd)); the accumulator stays f32
-        pv = jnp.einsum("kgt,tkd->kgd", p.astype(q.dtype), v,
-                        preferred_element_type=jnp.float32)
-        acc_ref[:] = acc_ref[:] * alpha[:, :, None] + pv
+        def keep(shape, axis):
+            # absolute position of column t is page*page_len + t; live iff
+            # < length (and, for a window, >= lower)
+            at = page * page_len + jax.lax.broadcasted_iota(jnp.int32, shape,
+                                                            axis)
+            live = at < lengths_ref[b]
+            return live & (at >= lower_ref[b]) if windowed else live
+
+        body = _page_all_heads if len(k_ref.shape) == 4 else _page_head_by_head
+        body(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, keep)
 
     @pl.when(w == pl.num_programs(1) - 1)
     def _flush():
-        o_ref[0] = (acc_ref[:] / l_ref[:][:, :, None]).astype(o_ref.dtype)
+        l = l_ref[:]
+        o_ref[0] = (acc_ref[:] / (l[:, :, None] if l.ndim == 2 else l)
+                    ).astype(o_ref.dtype)
+
+
+def _page_all_heads(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, keep):
+    """One live page of a ``(page_len, kvh, dh)`` block: every KV head in one
+    einsum batched over the block's middle axis. The dense model's body (one
+    query row a head, 16 keys a page); scratch ``m``/``l`` are (kvh, group)."""
+    q = q_ref[0]  # (kvh, group, dh) — compute dtype
+    k = k_ref[0]  # (page_len, kvh, dh)
+    v = v_ref[0]
+    dh = q.shape[-1]
+    # the _decode_step score einsum, f32 scores, same 1/sqrt(dh) scaling
+    s = jnp.einsum("kgd,tkd->kgt", q, k,
+                   preferred_element_type=jnp.float32) / math.sqrt(dh)
+    s = jnp.where(keep(s.shape, 2), s, _MASKED)
+    # online-softmax update: new running max, rescale the old accumulator
+    m_prev = m_ref[:]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=2))
+    m_ref[:] = m_new
+    alpha = jnp.exp(m_prev - m_new)  # 0.0 on the w==0 init (m_prev=-1e30)
+    p = jnp.exp(s - m_new[:, :, None])  # masked cols underflow to exact 0
+    l_ref[:] = alpha * l_ref[:] + jnp.sum(p, axis=2)
+    # probabilities meet V in the compute dtype — q's dtype, the same cast
+    # _decode_step applies (p.astype(cd)); the accumulator stays f32
+    pv = jnp.einsum("kgt,tkd->kgd", p.astype(q.dtype), v,
+                    preferred_element_type=jnp.float32)
+    acc_ref[:] = acc_ref[:] * alpha[:, :, None] + pv
+
+
+def _page_head_by_head(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, keep):
+    """One live page of a ``(page_len, kvh * dh)`` block: head ``h``'s keys
+    are the lane slice ``[:, h * dh:(h + 1) * dh]``, a ``(page_len, dh)``
+    matrix of whole tiles at ``dh`` 128, and each head's group of query rows
+    meets it in a plain matmul over the minor dimensions (the latent
+    kernel's form). The same scores, mask, online softmax and cast as
+    :func:`_page_all_heads`, a head at a time (unrolled: ``kvh`` is 4-8);
+    scratch ``m``/``l`` are (kvh, group, 1). Read from the other layout, one
+    head's keys are one sublane out of each of ``page_len`` tiles, and the
+    relayout costs several times the page's bytes (PERF.md, PR 38)."""
+    kvh, group, dh = q_ref.shape[1:]
+    nt = (((1,), (1,)), ((), ()))      # contract both minor dimensions
+    live = keep((group, k_ref.shape[1]), 1)
+    for h in range(kvh):
+        q = q_ref[0, h]                            # (group, dh)
+        cols = slice(h * dh, (h + 1) * dh)
+        s = jax.lax.dot_general(
+            q, k_ref[0, :, cols], nt,
+            preferred_element_type=jnp.float32) / math.sqrt(dh)
+        s = jnp.where(live, s, _MASKED)
+        m_prev = m_ref[h]                          # (group, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        m_ref[h] = m_new
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[h] = acc_ref[h] * alpha + jnp.dot(
+            p.astype(q.dtype), v_ref[0, :, cols],
+            preferred_element_type=jnp.float32)
+
+
+def _page_block(slab, q):
+    """The block of one page of ``slab`` and the scratch of a row's online
+    softmax, by the slab's rank (see :func:`_paged_attn_kernel`): ``(block
+    shape, zeros that follow the page id in its block index, scratch)``."""
+    _, kvh, group, dh = q.shape
+    stat = (kvh, group) if slab.ndim == 4 else (kvh, group, 1)
+    return (1,) + slab.shape[1:], (0,) * (slab.ndim - 1), [
+        pltpu.VMEM((kvh, group, dh), jnp.float32),  # accumulator
+        pltpu.VMEM(stat, jnp.float32),              # running max m
+        pltpu.VMEM(stat, jnp.float32),              # normalizer l
+    ]
 
 
 @functools.partial(jax.jit, static_argnames=("page_len", "interpret"))
@@ -188,10 +272,11 @@ def _paged_decode_attention_call(q, k_pages, v_pages, tables, lengths,
     # THE in-place read: the block table entry is the K/V block index. A
     # step past the row's last live page names that page again: the block
     # index does not change, so the pipeline copies nothing for it
+    block, tail, scratch = _page_block(k_pages, q)
     page_spec = pl.BlockSpec(
-        (1, page_len, kvh, dh),
+        block,
         lambda b, w, tbl, lens: (
-            tbl[b, jnp.minimum(w, (lens[b] - 1) // page_len)], 0, 0, 0))
+            tbl[b, jnp.minimum(w, (lens[b] - 1) // page_len)], *tail))
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -199,11 +284,7 @@ def _paged_decode_attention_call(q, k_pages, v_pages, tables, lengths,
             grid=(B, W),
             in_specs=[row_spec, page_spec, page_spec],
             out_specs=row_spec,
-            scratch_shapes=[
-                pltpu.VMEM((kvh, group, dh), jnp.float32),  # accumulator
-                pltpu.VMEM((kvh, group), jnp.float32),      # running max m
-                pltpu.VMEM((kvh, group), jnp.float32),      # normalizer l
-            ],
+            scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct((B, kvh, group, dh), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -226,12 +307,13 @@ def _paged_decode_attention_window_call(q, k_pages, v_pages, tables, lengths,
                                windowed=True)
     row_spec = pl.BlockSpec((1, kvh, group, dh),
                             lambda b, w, tbl, lens, first, low: (b, 0, 0, 0))
+    block, tail, scratch = _page_block(k_pages, q)
     page_spec = pl.BlockSpec(
-        (1, page_len, kvh, dh),
+        block,
         lambda b, w, tbl, lens, first, low: (
             tbl[b, jax.lax.rem(jnp.minimum(first[b] + w,
                                            (lens[b] - 1) // page_len), W)],
-            0, 0, 0))
+            *tail))
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -239,11 +321,7 @@ def _paged_decode_attention_window_call(q, k_pages, v_pages, tables, lengths,
             grid=(B, W),
             in_specs=[row_spec, page_spec, page_spec],
             out_specs=row_spec,
-            scratch_shapes=[
-                pltpu.VMEM((kvh, group, dh), jnp.float32),
-                pltpu.VMEM((kvh, group), jnp.float32),
-                pltpu.VMEM((kvh, group), jnp.float32),
-            ],
+            scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct((B, kvh, group, dh), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -258,8 +336,11 @@ def paged_decode_attention(q, k_pages, v_pages, tables, lengths,
     """Decode attention for a batch of rows directly over the page slab.
 
     ``q`` is ``(B, kv_heads, group, dh)`` (the grouped decode-query form;
-    ``group = heads // kv_heads``), ``k_pages``/``v_pages`` the slab
-    ``(num_pages, page_len, kv_heads, dh)``, ``tables`` ``(B, W)`` int32
+    ``group = heads // kv_heads``), ``k_pages``/``v_pages`` the slab, as the
+    model holds it: ``(num_pages, page_len, kv_heads, dh)`` (the dense
+    model; every head in one batched einsum) or ``(num_pages, page_len,
+    kv_heads * dh)`` (a spec model; head by head on the MXU: module
+    docstring). The array's rank says which; ``tables`` ``(B, W)`` int32
     block tables (dummy page 0 beyond a row's extent), ``lengths`` ``(B,)``
     the number of live positions per row — for a decode step at position
     ``pos`` whose K/V entry is already written, ``pos + 1``. Returns the
@@ -282,12 +363,13 @@ def paged_decode_attention(q, k_pages, v_pages, tables, lengths,
     q = jnp.asarray(q)
     if q.ndim != 4:
         raise ValueError(f"q must be (B, kv_heads, group, dh), got {q.shape}")
-    if k_pages.shape != v_pages.shape or len(k_pages.shape) != 4:
+    kvh, dh = q.shape[1], q.shape[3]
+    if k_pages.shape != v_pages.shape or k_pages.ndim not in (3, 4):
         raise ValueError(f"k/v pages must share one (num_pages, page_len, "
-                         f"kv_heads, dh) shape, got {k_pages.shape} vs "
-                         f"{v_pages.shape}")
+                         f"kv_heads, dh) or (num_pages, page_len, kv_heads * "
+                         f"dh) shape, got {k_pages.shape} vs {v_pages.shape}")
     page_len = int(k_pages.shape[1])
-    if k_pages.shape[2] != q.shape[1] or k_pages.shape[3] != q.shape[3]:
+    if tuple(k_pages.shape[2:]) not in ((kvh, dh), (kvh * dh,)):
         raise ValueError(f"page slab {k_pages.shape} does not match query "
                          f"heads {q.shape}")
     if page_len % PAGE_SUBLANE:

@@ -5,8 +5,12 @@ short request in a long bucket, and admission would have to budget by
 bucket. So the cache is the classic paged design: ONE device-resident page
 slab per engine
 (:func:`~marlin_tpu.models.transformer.init_kv_pages` — ``(num_pages,
-page_len, kvh, dh)`` per layer, shared by every bucket) plus host-side
-bookkeeping per row:
+page_len, kvh, dh)`` per layer, shared by every bucket; a
+:class:`~marlin_tpu.models.hybrid.ModelSpec` model's K and V slabs are
+``(num_pages, page_len, kvh * dh)``, the same bytes with a token's heads in
+one row, the form its decode kernel reads fastest: nothing here goes by a
+slab's rank, only by its first axis, its bytes and its arrays) plus
+host-side bookkeeping per row:
 
 - **Block tables** — each live row holds an ordered list of page ids
   covering its positions; the decode program gathers by table, the chunked
@@ -571,7 +575,8 @@ class PagedKVPool:
         geo = self._geometry()
         item = np.dtype(geo["dtype"]).itemsize
         # shapes[i] is one page of layer i's arrays ((page_len, kvh, dh) for
-        # K and for V; (page_len, entry) for a latent layer's one array)
+        # K and for V, (page_len, kvh * dh) in a spec model's slab;
+        # (page_len, entry) for a latent layer's one array)
         per_page = sum(int(np.prod(shape)) * len(self.pages[name])
                        for name, shape in zip(geo["layers"], geo["shapes"]))
         return n_pages * per_page * item

@@ -648,29 +648,38 @@ def test_rmm_20000_compiles_for_2x2_v5e(topo_mesh):
     assert c.memory_analysis().peak_memory_in_bytes < 15 * 1024**3
 
 
-def _compile_laguna_decode_kernel(one_chip, group, page_len, pages, width):
-    """Laguna-S-2.1's decode kernel through real Mosaic: 32 rows, 8 KV heads
-    x 128, bf16, a slab of ``pages`` pages and a table ``width`` wide; query
-    group 6 is a full layer (the plain call), 9 a sliding layer (the window
-    call, ``width`` its ring)."""
+def _compile_spec_decode_kernel(one_chip, group, page_len, pages, width,
+                                rows=32, kvh=8, window=False, flat=True,
+                                dtype=jnp.bfloat16):
+    """A spec model's decode kernel through real Mosaic: ``rows`` rows,
+    ``kvh`` KV heads x 128, a slab of ``pages`` pages held as
+    :func:`~marlin_tpu.models.hybrid.init_kv_pages` holds them (``flat``:
+    a token's heads in one row) and a table ``width`` wide; the plain call,
+    or the window call over a ring of ``width``. Returns the compiled
+    call."""
     from marlin_tpu.ops.paged_attention import (
         _paged_decode_attention_call, _paged_decode_attention_window_call)
 
     def st(shape, dt=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
-    B, kvh, dh = 32, 8, 128
-    q = st((B, kvh, group, dh), jnp.bfloat16)
-    slab = st((pages, page_len, kvh, dh), jnp.bfloat16)
-    if group == 6:
-        c = _paged_decode_attention_call.trace(
-            q, slab, slab, st((B, width)), st((B,)), page_len=page_len,
+    dh = 128
+    q = st((rows, kvh, group, dh), dtype)
+    slab = st((pages, page_len, kvh * dh) if flat
+              else (pages, page_len, kvh, dh), dtype)
+    tables, row = st((rows, width)), st((rows,))
+    if window:
+        c = _paged_decode_attention_window_call.trace(
+            q, slab, slab, tables, row, row, row, page_len=page_len,
             interpret=False).lower().compile()
     else:
-        c = _paged_decode_attention_window_call.trace(
-            q, slab, slab, st((B, width)), st((B,)), st((B,)), st((B,)),
-            page_len=page_len, interpret=False).lower().compile()
+        c = _paged_decode_attention_call.trace(
+            q, slab, slab, tables, row, page_len=page_len,
+            interpret=False).lower().compile()
     assert "tpu_custom_call" in c.as_text()
+    # the slab is read in place: no padded or re-laid-out copy before the call
+    assert c.memory_analysis().temp_size_in_bytes < 1 << 20
+    return c
 
 
 @pytest.mark.parametrize("group", [6, 9], ids=["full-g6", "sliding-g9"])
@@ -681,19 +690,102 @@ def test_paged_window_kernel_mosaic_compiles_at_laguna_widths(one_chip,
     plain call over 64 pages) and 9 (sliding layers, the window call over a
     ring of 5 pages)."""
     pages, width = (1537, 64) if group == 6 else (201, 5)
-    _compile_laguna_decode_kernel(one_chip, group, 128, pages, width)
+    _compile_spec_decode_kernel(one_chip, group, 128, pages, width,
+                                window=group == 9)
 
 
-@pytest.mark.parametrize("group", [6, 9], ids=["full-g6", "sliding-g9"])
+# the three (K, V) decode calls the spec cells make, page_len 256: rows,
+# KV heads, group, slab pages, table width, window
+_CELL_CALLS = {
+    "laguna-full-g6": (32, 8, 6, 769, 32, False),
+    "laguna-sliding-g9": (32, 8, 9, 145, 4, True),
+    "falconh1-g5": (64, 4, 5, 641, 20, False),
+}
+
+
+@pytest.mark.parametrize("call", list(_CELL_CALLS))
 def test_paged_kernels_mosaic_compile_at_the_laguna_cells_shapes(one_chip,
-                                                                 group):
+                                                                 call):
     """Both variants as ``serve.laguna-longtail32`` runs them (page_len
     256): the plain call over the widest bucket's 32 pages of the 769-page
-    slab, the window call over a ring of 4 pages of the 145-page slab. Their
-    index maps clamp a scalar-prefetched length, which is what Mosaic has to
-    accept."""
-    pages, width = (769, 32) if group == 6 else (145, 4)
-    _compile_laguna_decode_kernel(one_chip, group, 256, pages, width)
+    slab, the window call over a ring of 4 pages of the 145-page slab; and
+    ``serve.falconh1-chat64``'s call, 64 rows of 4 KV heads over 20 pages.
+    Their index maps clamp a scalar-prefetched length, which is what Mosaic
+    has to accept, and each head's keys are a lane slice of the page's
+    ``(256, kv_heads * 128)`` block."""
+    rows, kvh, group, pages, width, window = _CELL_CALLS[call]
+    _compile_spec_decode_kernel(one_chip, group, 256, pages, width, rows=rows,
+                                kvh=kvh, window=window)
+
+
+def test_float32_pages_of_256_fit_scoped_vmem_only_head_by_head(one_chip):
+    """The float32 checks' blocks (``benchmarks/f32_check_falconh1.py``) at
+    the cell's page of 256: held ``(page_len, kvh * dh)`` the kernel
+    compiles within the default scoped VMEM; held ``(page_len, kvh, dh)``
+    its relayout of a 256-token block asks for more than there is (which is
+    why that check ran at pages of 128 until the layout changed)."""
+    rows, kvh, group, pages, width, _ = _CELL_CALLS["falconh1-g5"]
+    _compile_spec_decode_kernel(one_chip, group, 256, pages, width, rows=rows,
+                                kvh=kvh, dtype=jnp.float32)
+    with pytest.raises(Exception, match="vmem"):
+        _compile_spec_decode_kernel(one_chip, group, 256, pages, width,
+                                    rows=rows, kvh=kvh, flat=False,
+                                    dtype=jnp.float32)
+
+
+def test_spec_decode_program_holds_no_copy_of_a_slab(one_chip):
+    """The decode program of a spec model with Laguna's attention widths (8
+    KV heads x 128, 48 / 72 query heads, window 512, pages of 256; one full
+    and one sliding layer, everything else small) compiled whole for a v5e:
+    both kernels inside, both slabs (403 MB and 76 MB a layer) aliased to
+    the outputs, and no temporary the size of one: the entries are written
+    and the pages read where the slab lies (a slab the kernel could not read
+    as it is would be copied before every call, as a 320-column latent slab
+    was: PERF.md, PR 35)."""
+    from marlin_tpu.models import hybrid
+
+    cfg = {
+        "hidden_size": 256, "head_dim": 128, "num_key_value_heads": 8,
+        "num_hidden_layers": 2, "num_attention_heads": 48,
+        "layer_types": ["full_attention", "sliding_attention"],
+        "num_attention_heads_per_layer": [48, 72],
+        "mlp_layer_types": ["dense", "dense"], "sliding_window": 512,
+        "intermediate_size": 512, "moe_intermediate_size": 128,
+        "shared_expert_intermediate_size": 128, "num_experts": 8,
+        "num_experts_per_tok": 2, "moe_routed_scaling_factor": 2.5,
+        "vocab_size": 512, "rms_norm_eps": 1e-6,
+        "rope_parameters": {
+            "full_attention": {"rope_type": "default", "rope_theta": 10000,
+                               "partial_rotary_factor": 1},
+            "sliding_attention": {"rope_type": "default", "rope_theta": 10000,
+                                  "partial_rotary_factor": 1}},
+        "param_dtype": "bfloat16", "compute_dtype": "bfloat16"}
+    spec = hybrid.ModelSpec.from_config(cfg)
+    B, page_len, width, ring = 32, 256, 32, 4
+
+    def sds(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            jnp.shape(x), x.dtype, sharding=one_chip), tree)
+
+    def st(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    params = sds(jax.eval_shape(
+        lambda: hybrid.init_params(spec, jax.random.key(0))))
+    pages = sds(jax.eval_shape(
+        lambda: hybrid.init_kv_pages(spec, 769, 145, page_len)))
+    assert pages["l0"][0].shape == (769, page_len, 8 * 128)
+    slabs = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(pages))
+    with mt.config_context(pallas_interpret=False):
+        c = hybrid._lm_decode_paged_spec_jit.trace(
+            params, pages, st((B, width)), st((B, ring)), st((B,)), st((B,)),
+            st((B,)), st((B,), jnp.uint32), st((B,), jnp.float32),
+            st((B,), jnp.float32), st((B,)), spec=spec, page_len=page_len,
+            kernel="pallas").lower().compile()
+    assert c.as_text().count("tpu_custom_call") >= 2
+    m = c.memory_analysis()
+    assert m.alias_size_in_bytes >= slabs
+    assert m.temp_size_in_bytes < 145 * page_len * 8 * 128 * 2 // 4
 
 
 def test_dropless_expert_layer_compiles_for_v5e_as_a_grouped_matmul(one_chip):

@@ -71,6 +71,10 @@ def _serve_one(spec, params, prompt, steps, kernel):
     gtable[:need] = np.arange(1, need + 1)
     wtable = np.arange(1, ring + 1, dtype=np.int32)
     pages = init_kv_pages(params, 32, PAGE, spec, window_pages=16)
+    # a token's KV heads side by side in one row, in both page classes
+    row = spec.kv_heads * spec.head_dim
+    assert [pages[name][0].shape for name in ("l0", "l1")] == [
+        (32, PAGE, row), (16, PAGE, row)]
     padded = np.zeros(-(-n // CHUNK) * CHUNK, np.int32)
     padded[:n] = prompt
     for cs in range(0, len(padded), CHUNK):

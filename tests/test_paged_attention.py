@@ -1,7 +1,9 @@
 """Fused paged decode-attention kernel suite (interpret mode on CPU).
 
 Three layers: kernel-vs-numpy numerics (GQA head grouping, ragged per-row
-lengths, page-boundary lengths, dummy/mid-prefill rows), the greedy
+lengths, page-boundary lengths, dummy/mid-prefill rows; each over BOTH page
+layouts, the dense model's ``(page_len, kvh, dh)`` block and a spec model's
+``(page_len, kvh * dh)``, whose rank picks the kernel's body), the greedy
 bit-identity grid across decode backends (``kernel='pallas'`` vs the
 ``'gather'`` reference vs unpaged :func:`lm_generate` — the serving
 contract: swapping the attention kernel must not change a single emitted
@@ -39,9 +41,8 @@ def _ref_attention(q, k_pages, v_pages, tables, lengths):
     block table, mask past its length, softmax, weigh V. The obvious
     formulation the kernel must reproduce."""
     q = np.asarray(q, np.float32)
-    kp = np.asarray(k_pages, np.float32)
-    vp = np.asarray(v_pages, np.float32)
     B, kvh, group, dh = q.shape
+    kp, vp = _by_head(k_pages, kvh, dh), _by_head(v_pages, kvh, dh)
     W = tables.shape[1]
     page_len = kp.shape[1]
     out = np.zeros_like(q)
@@ -57,33 +58,54 @@ def _ref_attention(q, k_pages, v_pages, tables, lengths):
     return out
 
 
-def _random_case(rng, B=4, kvh=2, group=2, dh=8, W=3, num_pages=16,
+def _by_head(pages, kvh, dh):
+    """A slab of either layout as float32 ``(num_pages, page_len, kvh,
+    dh)``: the references below are written once."""
+    pages = np.asarray(pages, np.float32)
+    return pages.reshape(*pages.shape[:2], kvh, dh)
+
+
+# (kv heads, group, head dim, pages held (page_len, kvh * dh)): the dense
+# model's slab, and a spec model's at the three calls the cells make
+# (Falcon-H1's global layers, Laguna's full and sliding layers)
+_HEADS = {"dense-2x2": (2, 2, 8, False), "falconh1-4x5": (4, 5, 128, True),
+          "laguna-8x6": (8, 6, 128, True), "laguna-8x9": (8, 9, 128, True)}
+both_layouts = pytest.mark.parametrize("heads",
+                                       ["dense-2x2", "falconh1-4x5"])
+
+
+def _random_case(rng, B=4, heads="dense-2x2", W=3, num_pages=16,
                  dtype=np.float32):
+    kvh, group, dh, flat = _HEADS[heads]
+    slab = ((num_pages, PAGE_LEN, kvh * dh) if flat
+            else (num_pages, PAGE_LEN, kvh, dh))
     q = rng.standard_normal((B, kvh, group, dh)).astype(dtype)
-    kp = rng.standard_normal((num_pages, PAGE_LEN, kvh, dh)).astype(dtype)
-    vp = rng.standard_normal((num_pages, PAGE_LEN, kvh, dh)).astype(dtype)
+    kp = rng.standard_normal(slab).astype(dtype)
+    vp = rng.standard_normal(slab).astype(dtype)
     # distinct live pages per row (page 0 is the pool's dummy)
     tables = (1 + rng.permutation(num_pages - 1)[:B * W]).reshape(B, W)
     tables = tables.astype(np.int32)
     return q, kp, vp, tables
 
 
-def test_kernel_matches_reference_gqa_ragged():
+@both_layouts
+def test_kernel_matches_reference_gqa_ragged(heads):
     """GQA (kv_heads < heads) with ragged lengths straddling page
     boundaries: the in-place kernel matches the gathered reference."""
     rng = np.random.default_rng(0)
-    q, kp, vp, tables = _random_case(rng)
+    q, kp, vp, tables = _random_case(rng, heads=heads)
     lengths = np.array([1, 9, 17, 24], np.int32)  # mid-page, full-table
     got = paged_decode_attention(q, kp, vp, tables, lengths, interpret=True)
     want = _ref_attention(q, kp, vp, tables, lengths)
     np.testing.assert_allclose(np.asarray(got), want, atol=2e-6, rtol=2e-6)
 
 
-def test_kernel_page_boundary_lengths():
+@both_layouts
+def test_kernel_page_boundary_lengths(heads):
     """Lengths landing exactly on page edges — the off-by-one hotspot for
     the absolute-position mask ``w*page_len + t < length``."""
     rng = np.random.default_rng(1)
-    q, kp, vp, tables = _random_case(rng)
+    q, kp, vp, tables = _random_case(rng, heads=heads)
     for n in (PAGE_LEN - 1, PAGE_LEN, PAGE_LEN + 1, 2 * PAGE_LEN,
               3 * PAGE_LEN):
         lengths = np.full(4, n, np.int32)
@@ -94,12 +116,13 @@ def test_kernel_page_boundary_lengths():
                                    rtol=2e-6)
 
 
-def test_kernel_dummy_rows_are_harmless():
+@both_layouts
+def test_kernel_dummy_rows_are_harmless(heads):
     """Rows still prefilling ride the batch with an all-dummy (zero) table
     and length 1 — the dummy-row contract. Their outputs must be
     finite (the scheduler discards them) and must not perturb live rows."""
     rng = np.random.default_rng(2)
-    q, kp, vp, tables = _random_case(rng)
+    q, kp, vp, tables = _random_case(rng, heads=heads)
     lengths = np.array([12, 1, 20, 1], np.int32)
     tables = tables.copy()
     tables[1] = 0  # mid-prefill rows point at the dummy page
@@ -112,11 +135,12 @@ def test_kernel_dummy_rows_are_harmless():
                                rtol=2e-6)
 
 
-def test_kernel_bf16_matches_f32_reference():
+@both_layouts
+def test_kernel_bf16_matches_f32_reference(heads):
     """bf16 q/slab run the same masked online softmax; scores and the
     accumulator stay f32, so the error is operand rounding, not drift."""
     rng = np.random.default_rng(3)
-    q, kp, vp, tables = _random_case(rng)
+    q, kp, vp, tables = _random_case(rng, heads=heads)
     lengths = np.array([5, 11, 24, 16], np.int32)
     got = paged_decode_attention(
         jnp.asarray(q, jnp.bfloat16), jnp.asarray(kp, jnp.bfloat16),
@@ -127,11 +151,12 @@ def test_kernel_bf16_matches_f32_reference():
                                rtol=0.05)
 
 
-def test_kernel_length_clamping():
+@both_layouts
+def test_kernel_length_clamping(heads):
     """Out-of-range lengths clamp to [1, W*page_len] — a row can never
     attend past its table extent nor to zero positions."""
     rng = np.random.default_rng(4)
-    q, kp, vp, tables = _random_case(rng)
+    q, kp, vp, tables = _random_case(rng, heads=heads)
     wild = np.array([0, -3, 999, 24], np.int32)
     clamped = np.array([1, 1, 24, 24], np.int32)
     got = paged_decode_attention(q, kp, vp, tables, wild, interpret=True)
@@ -146,6 +171,13 @@ def test_page_len_validation_and_alignment():
     with pytest.raises(ValueError, match="multiple of"):
         paged_decode_attention(q, bad, bad, np.zeros((1, 2), np.int32),
                                np.ones(1, np.int32), interpret=True)
+    # a slab whose rows are not the query's heads, in either layout
+    for slab in ((4, 8, 3, 8), (4, 8, 3 * 8), (4, 8)):
+        with pytest.raises(ValueError, match="k/v pages|does not match"):
+            paged_decode_attention(q, np.zeros(slab, np.float32),
+                                   np.zeros(slab, np.float32),
+                                   np.zeros((1, 2), np.int32),
+                                   np.ones(1, np.int32), interpret=True)
     assert align_page_len(1) == PAGE_SUBLANE
     assert align_page_len(8) == 8
     assert align_page_len(9) == 16
@@ -171,8 +203,8 @@ def _ref_window_attention(q, k_pages, v_pages, tables, lengths, lower):
     ``[lower[b], lengths[b])``, position ``p`` read from ring slot
     ``(p // page_len) % W``, one position at a time."""
     q = np.asarray(q, np.float32)
-    kp = np.asarray(k_pages, np.float32)
-    vp = np.asarray(v_pages, np.float32)
+    kp = _by_head(k_pages, q.shape[1], q.shape[3])
+    vp = _by_head(v_pages, q.shape[1], q.shape[3])
     W = tables.shape[1]
     out = np.zeros_like(q)
     for b in range(q.shape[0]):
@@ -211,19 +243,32 @@ _DEAD_PAGE_CASES = {
 }
 
 
+# the head-by-head body on a spec model's slab, at the call of the cell
+# that runs it: (heads, variant, the cases above it takes)
+_SPEC_CASES = [
+    ("falconh1-4x5", "global", ["one", "page", "ragged"]),
+    ("laguna-8x6", "global", ["page+1", "full-table"]),
+    ("laguna-8x9", "window", ["one", "page+1", "full-ring",
+                              "lower-on-boundary", "wrapped-ring"]),
+]
+
+
 @pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
 @pytest.mark.parametrize(
-    "variant,case",
-    [(v, c) for v, cases in _DEAD_PAGE_CASES.items() for c in cases])
-def test_pages_past_a_rows_length_are_never_read(variant, case, dtype):
-    """Both variants, on lengths around every page edge: (a) the kernel
-    matches the obvious formulation, and (b) its output does not depend on
-    what a page wholly past a row's length (or the pool's dummy page 0)
-    holds — bit-equal with those pages full of NaN and of Inf. A kernel that
-    only masks such a page computes ``0 x Inf`` on it and fails (b)."""
+    "variant,case,heads",
+    [(v, c, "dense-2x2") for v, cases in _DEAD_PAGE_CASES.items()
+     for c in cases]
+    + [(v, c, h) for h, v, cases in _SPEC_CASES for c in cases])
+def test_pages_past_a_rows_length_are_never_read(variant, case, heads, dtype):
+    """Both variants and both page layouts, on lengths around every page
+    edge: (a) the kernel matches the obvious formulation, and (b) its
+    output does not depend on what a page wholly past a row's length (or the
+    pool's dummy page 0) holds — bit-equal with those pages full of NaN and
+    of Inf. A kernel that only masks such a page computes ``0 x Inf`` on it
+    and fails (b)."""
     rng = np.random.default_rng(7)
-    q, kp, vp, tables = _random_case(rng)
+    q, kp, vp, tables = _random_case(rng, heads=heads)
     B, W = tables.shape
     pos = np.array(_DEAD_PAGE_CASES[variant][case], np.int32)
     lengths = pos + 1
@@ -258,6 +303,26 @@ def test_pages_past_a_rows_length_are_never_read(variant, case, dtype):
                                        lengths, interpret=True, **window)
         np.testing.assert_array_equal(np.asarray(again, np.float32),
                                       np.asarray(got, np.float32))
+
+
+def test_the_two_page_layouts_hold_the_same_numbers():
+    """The same pages held ``(page_len, kvh, dh)`` and ``(page_len, kvh *
+    dh)`` (a reshape: a token's heads are side by side either way) go to
+    the two bodies of the kernel; the results differ by float association
+    inside one contraction at most. Global and windowed, a dummy row."""
+    rng = np.random.default_rng(11)
+    q, kp, vp, tables = _random_case(rng, heads="falconh1-4x5")
+    tables[2] = 0
+    k4, v4 = (_by_head(t, 4, 128) for t in (kp, vp))
+    lengths = np.array([24, 9, 1, 16], np.int32)
+    lower = np.array([9, 0, 0, 8], np.int32)
+    for window in ({}, dict(first_page=lower // PAGE_LEN, lower=lower)):
+        flat = paged_decode_attention(q, kp, vp, tables, lengths,
+                                      interpret=True, **window)
+        by_head = paged_decode_attention(q, k4, v4, tables, lengths,
+                                         interpret=True, **window)
+        np.testing.assert_allclose(np.asarray(flat), np.asarray(by_head),
+                                   atol=2e-6, rtol=2e-6)
 
 
 # ------------------------------------------- backend bit-identity grid

@@ -243,8 +243,9 @@ def test_from_config_reads_the_falcon_h1_keys(model):
     assert spec.state_slot_bytes() == 2 * (512 * 4 + 288 * 4)
     assert spec.state_slot_bytes("bfloat16") == 2 * (512 * 4 + 288 * 2)
     pages = init_kv_pages(params, 5, PAGE, spec, state_slots=3)
+    # K and V: a token's 2 KV heads x 16 side by side in one row
     assert [a.shape for a in pages["l1"]] == [
-        (5, 8, 2, 16), (5, 8, 2, 16), (3, 4, 16, 8), (3, 3, 96)]
+        (5, 8, 2 * 16), (5, 8, 2 * 16), (3, 4, 16, 8), (3, 3, 96)]
     with pytest.raises(ValueError, match="state slots"):
         init_kv_pages(params, 5, PAGE, spec)
 

@@ -2179,8 +2179,6 @@ class ServeEngine:
                                   pages=len(spages) + len(owned),
                                   shared_pages=len(spages),
                                   prompt_tokens=n, shared_tokens=shared_len)
-                if state_id:
-                    span.set_metadata(state_bytes=self._state_slot_bytes)
         self._record_pages(pool)
 
     def _prefill_paged_chunk(self, pool, pools, pf_queue, pipe) -> None:
@@ -2239,7 +2237,8 @@ class ServeEngine:
             try:
                 with annotate("serve.prefill.dispatch", rid=r.rid,
                               bucket=_bucket_tag(bucket), start=cs,
-                              tokens=tokens, final=int(final)) as dispatch:
+                              tokens=tokens, width=C,
+                              final=int(final)) as dispatch:
                     if self._state_slots:
                         # the valid tokens the mixers' scan advances over
                         dispatch.set_metadata(ssm_tokens=tokens)
@@ -2272,12 +2271,15 @@ class ServeEngine:
                         top_p=r.top_p, top_k=r.top_k,
                         compute_dtype=self.compute_dtype, moe=self.moe)
                     pages, first, counts = _program_result(out)
+                    # the program's number, on the span that launched it (a
+                    # span's fields cannot be set once it has closed)
+                    seq = pipe.dispatched()
+                    dispatch.set_metadata(seq=seq)
             except Exception as exc:
                 pf_queue.popleft()
                 self._paged_prefill_failure(pool, pools, bucket, slot, exc)
                 return 0  # end this iteration's budget loop
             pool.pages = pages
-            seq = pipe.dispatched()
             group.pf_next[slot] = cs + C
             self.flight.record(
                 "prefill", bucket=[p, s], slot=slot, rid=r.rid,
@@ -2332,8 +2334,8 @@ class ServeEngine:
         r = e.request
         with obs_trace.use(e.trace):
             try:
-                with annotate("serve.prefill.sync", rid=r.rid,
-                              final=1) as sync:
+                with annotate("serve.prefill.sync", rid=r.rid, final=1,
+                              seq=item.seq) as sync:
                     first = int(item.first)  # device sync: the chunk landed
                     self._moe_counts(sync, item.counts)
             except Exception as exc:
@@ -2495,10 +2497,10 @@ class ServeEngine:
         ``positions`` and ``steps_done`` advance NOW, the call's tokens
         become the feed and each row is pointed at its entry. Returns the
         :class:`_Launch` the landing needs, or None where nothing was
-        dispatched. ``span`` (``serve.decode.dispatch``) gets ``ahead`` (1
-        where an earlier decode call had not landed at this dispatch: the
-        step was pipelined), ``fed_rows`` (rows whose token came from the
-        device) and the work the
+        dispatched. ``span`` (``serve.decode.dispatch``) gets ``seq`` (the
+        call's place among the programs dispatched, which its landing's
+        span names too), ``ahead`` (1 where an earlier decode call had not
+        landed at this dispatch: the step was pipelined) and the work the
         call was given beside the work that is useful, over all the rows it
         carries: ``padded_rows`` x ``table_width`` pages against
         ``kv_tokens``, the positions the live rows attend (each row's cache
@@ -2595,15 +2597,14 @@ class ServeEngine:
                     global_kv_pages=int((at + 1).sum()),
                     window_kv_pages=int((at - low + 1).sum())
                     if self._ring else 0)
-        span.set_metadata(padded_rows=self.max_batch,
+        seq = pipe.dispatched()
+        span.set_metadata(seq=seq, padded_rows=self.max_batch,
                           table_width=tables.shape[1],
                           # analyze: ignore[host-sync] — host numpy
                           kv_tokens=int(positions.sum()) + rows,
-                          sampled_rows=int((temperature > 0).sum()),
-                          fed_rows=int((prev_index >= 0).sum()))
+                          sampled_rows=int((temperature > 0).sum()))
         return _Launch([(group, i, group.entries[i]) for group, i in carried],
-                       _call_tag(call), t0, nxt, counts, steps, due,
-                       pipe.dispatched(), {})
+                       _call_tag(call), t0, nxt, counts, steps, due, seq, {})
 
     @staticmethod
     def _moe_counts(span, counts) -> None:
@@ -2649,7 +2650,8 @@ class ServeEngine:
         ``eos`` or its step budget ends is retired, and a row that had left
         its slot is answered."""
         try:
-            with annotate("serve.decode.sync", bucket=launch.tag) as sync:
+            with annotate("serve.decode.sync", bucket=launch.tag,
+                          seq=launch.seq) as sync:
                 # analyze: ignore[host-sync] — THE one intentional sync per
                 # decode call: the host must see the emitted tokens to
                 # retire rows (the next call was launched first)

@@ -668,11 +668,18 @@ def test_only_a_final_chunk_is_waited_for_and_the_fields_say_it_engaged(
     assert [s["final"] for s in syncs] == [1, 1]
     calls = [s.fields for s in spans if s.name == "serve.decode.dispatch"
              and s.fields["rows"]]
-    assert {"ahead", "fed_rows"} <= set(calls[0])
+    assert {"ahead", "seq"} <= set(calls[0]) and "fed_rows" not in calls[0]
     assert calls[0]["ahead"] == 0 and all(c["ahead"] for c in calls[1:])
-    # every row of every call had its token on the device: its first token
-    # (the feed's write) or the last call's
-    assert [c["fed_rows"] for c in calls] == [c["rows"] for c in calls]
+    # chunks and calls are numbered together, in the device's order, and
+    # each landing names the number of the program whose result it brings
+    numbered = [s.fields["seq"] for s in spans
+                if s.name in ("serve.prefill.dispatch",
+                              "serve.decode.dispatch") and "seq" in s.fields]
+    assert numbered == list(range(numbered[0], numbered[0] + len(numbered)))
+    assert ([s.fields["seq"] for s in spans if s.name == "serve.decode.sync"]
+            == [c["seq"] for c in calls])
+    assert ([s["seq"] for s in syncs]
+            == [c["seq"] for c in chunks if c["final"]])
     retires = [s.fields for s in spans if s.name == "serve.decode.retire"]
     assert all(f["discarded"] == 0 for f in retires)
     # the first token's landing follows the dispatch of the call it rides

@@ -20,14 +20,16 @@ import pytest
 
 import jax
 
-from benchmarks import engine_spans
+from benchmarks import engine_spans, launches
 from benchmarks.trace_reduce import find_xplane
-from marlin_tpu.models import TransformerLM
+from marlin_tpu.models import TransformerLM, hybrid
 from marlin_tpu.models.planner import request_pages
 from marlin_tpu.models.transformer import lm_generate
 from marlin_tpu.serving import (STATUS_OK, Request, ServeEngine,
                                 pick_bucket)
+from marlin_tpu.serving.kvpool import group_chunk
 from marlin_tpu.utils.tracing import annotate
+from tests.test_state_space import tiny_cfg
 
 HEADS = 2
 PAGE_LEN = 4
@@ -46,14 +48,14 @@ SPANS = {
     "serve.admit": ({"rid", "bucket", "queue_wait_ms", "pages",
                      "shared_pages"}, "serve.iter"),
     "serve.prefill": ({"chunks", "tokens"}, "serve.iter"),
-    "serve.prefill.dispatch": ({"rid", "bucket", "start", "tokens", "final"},
-                               "serve.prefill"),
+    "serve.prefill.dispatch": ({"rid", "bucket", "start", "tokens", "width",
+                                "final", "seq"}, "serve.prefill"),
     # (a first token lands after the decode call its row rides has gone out)
-    "serve.prefill.sync": ({"rid", "final"}, "serve.decode"),
+    "serve.prefill.sync": ({"rid", "final", "seq"}, "serve.decode"),
     "serve.decode": ({"buckets", "dispatches"}, "serve.iter"),
-    "serve.decode.dispatch": ({"bucket", "rows", "ahead", "fed_rows"},
+    "serve.decode.dispatch": ({"bucket", "rows", "ahead", "seq"},
                               "serve.decode"),
-    "serve.decode.sync": ({"bucket"}, "serve.decode"),
+    "serve.decode.sync": ({"bucket", "seq"}, "serve.decode"),
     "serve.decode.retire": ({"bucket", "retired", "discarded"},
                             "serve.decode"),
 }
@@ -103,15 +105,16 @@ def _parked(eng, timeout=120.0):
         time.sleep(0.001)
 
 
-def _serve(params, schedule=SCHEDULE, capture_dir=None, requests=None, **kw):
+def _serve(params, schedule=SCHEDULE, capture_dir=None, requests=None,
+           make=_engine, **kw):
     """Queue ``schedule`` (or ``requests``, where greedy ones will not do)
-    on a paused engine, then let the worker run: the iterations are the
-    same in every run. With ``capture_dir`` the whole of
+    on a paused engine (``make``'s), then let the worker run: the iterations
+    are the same in every run. With ``capture_dir`` the whole of
     it happens under a profiler capture, which also sees one late request
     wake the worker from its wait. Returns results, records, spans and the
     engine's last snapshot."""
     sink = ListSink()
-    eng = _engine(params, log=sink, start=False, **kw)
+    eng = make(params, log=sink, start=False, **kw)
     eng.warmup()
     if capture_dir is not None:
         opts = jax.profiler.ProfileOptions()
@@ -173,6 +176,100 @@ def test_every_span_is_emitted_with_its_fields(traced, name):
     assert found, f"no marlin:{name} span in the capture"
     best = max(found, key=lambda s: len(s.fields))
     assert fields <= set(best.fields), (name, best.fields)
+
+
+# ---------------------------------------------------- the dispatches' numbers
+
+#: what a configuration-built engine (attention and a state-space mixer in
+#: every block, a state slot a row) is made with
+SPEC_ENGINE = {"buckets": ((16, 8), (32, 8), (48, 16)), "max_batch": 3,
+               "page_len": 8, "prefill_chunk": 16, "num_pages": 64}
+SPEC_SCHEDULE = ((5, 4), (37, 9), (12, 8), (40, 16), (9, 3), (30, 7))
+
+
+@pytest.fixture(scope="module", params=["dense", "spec"])
+def numbered(request, tmp_path_factory):
+    """A traced run of the dense engine and of a configuration-built one,
+    with what each was made with."""
+    if request.param == "dense":
+        run = request.getfixturevalue("traced")
+        return {**run, "buckets": BUCKETS, "max_batch": 4,
+                "page_len": PAGE_LEN, "prefill_chunk": 8, "stateful": False}
+    spec = hybrid.ModelSpec.from_config(tiny_cfg())
+
+    def make(params, **kw):
+        return ServeEngine(params, spec, **SPEC_ENGINE, **kw)
+
+    run = _serve(hybrid.init_params(spec, jax.random.key(3)),
+                 schedule=SPEC_SCHEDULE, make=make,
+                 capture_dir=tmp_path_factory.mktemp("spec"))
+    assert all(r.status == STATUS_OK for r in run["results"])
+    return {**run, **SPEC_ENGINE, "stateful": True}
+
+
+def test_seq_rises_by_one_across_chunks_and_calls(numbered):
+    launched = launches.numbered(numbered["spans"])
+    seqs = [s.fields["seq"] for s in launched]
+    assert len(seqs) > 10
+    assert {s.name for s in launched} == set(launches.DISPATCH)
+    assert seqs == list(range(seqs[0], seqs[0] + len(seqs)))
+    # a sweep that found no live row launched nothing and has no number
+    for s in numbered["spans"]:
+        if s.name == "serve.decode.dispatch":
+            assert ("seq" in s.fields) == bool(s.fields["rows"])
+
+
+def test_a_landing_names_a_dispatch_seen_earlier_and_calls_land_once(
+        numbered):
+    spans = numbered["spans"]
+    began = {s.fields["seq"]: s for s in launches.numbered(spans)}
+    for name in ("serve.prefill.sync", "serve.decode.sync"):
+        for sync in (s for s in spans if s.name == name):
+            dispatch = began[sync.fields["seq"]]
+            assert dispatch.name == name.replace(".sync", ".dispatch")
+            assert dispatch.end <= sync.start
+            assert dispatch.fields["bucket"] == sync.fields.get(
+                "bucket", dispatch.fields["bucket"])
+    calls = [s.fields["seq"] for s in launches.numbered(spans)
+             if s.name == "serve.decode.dispatch"]
+    landed = [s.fields["seq"] for s in spans if s.name == "serve.decode.sync"]
+    # each once, in dispatch order; only the last could still be in flight
+    assert landed in (calls, calls[:-1])
+    firsts = [s.fields["seq"] for s in spans if s.name == "serve.prefill.sync"]
+    assert firsts == [s.fields["seq"] for s in launches.numbered(spans)
+                      if s.fields.get("final")]
+
+
+def test_width_is_the_groups_compiled_chunk(numbered):
+    chunks = [s.fields for s in numbered["spans"]
+              if s.name == "serve.prefill.dispatch"]
+    assert chunks
+    for f in chunks:
+        bucket = tuple(int(x) for x in f["bucket"].split("x"))
+        assert f["width"] == group_chunk(bucket, numbered["page_len"],
+                                         numbered["prefill_chunk"])
+        assert 0 < f["tokens"] <= f["width"]
+    assert any(f["tokens"] < f["width"] for f in chunks)  # a prompt's tail
+    assert any(f["tokens"] == f["width"] for f in chunks)
+
+
+def test_fed_rows_and_the_admissions_state_bytes_are_gone(numbered):
+    by = collections.defaultdict(list)
+    for s in numbered["spans"]:
+        by[s.name].append(s.fields)
+    assert all("fed_rows" not in f for f in by["serve.decode.dispatch"])
+    assert by["serve.admit"]
+    assert all("state_bytes" not in f for f in by["serve.admit"])
+    slots = (len(numbered["buckets"]) * numbered["max_batch"]
+             if numbered["stateful"] else None)
+    for f in by["serve.iter"]:
+        # what the slots hold is the iteration's to say: a slot a resident
+        # row, of as many as the buckets have rows
+        assert f.get("state_slots") == slots
+        assert f.get("state_rows") == (f["resident_rows"] if slots else None)
+    calls = [f for f in by["serve.decode.dispatch"] if f["rows"]]
+    assert all(f.get("state_rows") == (f["rows"] if slots else None)
+               for f in calls)
 
 
 def test_dispatched_decode_spans_carry_the_work_they_were_given(traced):

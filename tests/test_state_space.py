@@ -530,7 +530,10 @@ def test_the_spans_carry_the_state_fields(served, model):
     assert max(f["state_rows"] for f in iters) > 3
     admits = by["serve.admit"]
     assert len(admits) == len(SCHEDULE)
-    assert all(f["state_bytes"] == slot_bytes for f in admits)
+    # what a slot costs is a constant of the engine: an admission's span
+    # does not repeat it, the iteration's counts the slots and the rows
+    assert all("state_bytes" not in f for f in admits)
+    assert all(0 <= f["state_rows"] <= f["state_slots"] for f in iters)
     chunks = by["serve.prefill.dispatch"]
     assert sum(f["ssm_tokens"] for f in chunks) == sum(n for n, _ in SCHEDULE)
     assert all(f["ssm_tokens"] == f["tokens"] for f in chunks)

@@ -184,10 +184,10 @@ class LatentSpec:
     def entry_width(self) -> int:
         """Columns of a cache entry as the slab stores it: ``entry_dim``
         rounded up to whole lane tiles of 128, the rest zeros. The chip
-        tiles an array's minor dimension by 128: handed a slab of 320
-        columns, the compiler copies the WHOLE slab into a padded one before
-        every kernel call (0.5 GB a layer at the published sizes, compiled
-        for a described v5e); stored padded, the kernel reads it in place.
+        tiles an array's minor dimension by 128 and holds a slab of 320
+        columns 384 wide: the decode kernel copies a page out of it in
+        whole lane tiles and refuses 320 (a ``BlockSpec`` of 320 made the
+        compiler copy the WHOLE slab first); stored padded it is read in place.
         A zero column adds nothing to a score (the query's are zero too)
         and lies past the value's ``kv_rank`` columns."""
         return -(-self.entry_dim // 128) * 128

@@ -68,13 +68,13 @@ block tables, the free/prefilling-slot contract) attend one masked-harmless
 position of the sacrificial page 0, in one live step.
 
 **The latent variant** (:func:`paged_decode_attention_latent`): a latent
-attention layer's page is ONE array ``(num_pages, page_len, entry)`` whose
-entry is shared by every head, and the absorbed decode query (``entry``
-wide, its softmax scale folded in) meets it directly: the page is the key,
-and its first ``value_dim`` columns are the value. The page is fetched once
-a grid step and serves as both; everything else (the scalar-prefetched
-table, the skip past a row's length, the clamped block index, the online
-softmax in scratch) is the kernel above with the KV-head axis gone.
+layer's page is ONE array ``(num_pages, page_len, entry)`` shared by every
+head; the absorbed decode query (``entry`` wide, scale folded in) meets it
+directly: the page is the key, its first ``value_dim`` columns the value.
+Its kernel takes a grid step a ROW and walks the row's live pages itself:
+the slab stays in HBM, the pages ahead are copied into a ring of slots
+while this page's softmax and values and the next page's scores are
+computed; the same table, mask and online softmax, no dead step (PR 40).
 
 ``interpret=`` defaults through :func:`~.pallas_kernels._interpret` —
 interpreter everywhere but real TPU — so the tier-1 CPU suites exercise
@@ -404,47 +404,92 @@ def paged_decode_attention(q, k_pages, v_pages, tables, lengths,
                                         interpret=bool(interpret))
 
 
-def _latent_attn_kernel(tables_ref, lengths_ref, q_ref, e_ref, o_ref, acc_ref,
-                        m_ref, l_ref, *, page_len: int, value_dim: int):
-    """Grid (B, W) as :func:`_paged_attn_kernel`. ``q_ref`` (1, H, entry) is
-    the row's absorbed, scaled query; ``e_ref`` (1, page_len, entry) the
-    page, read once: all ``entry`` columns are the key, the first
-    ``value_dim`` the value. The score is split at ``value_dim`` (a lane-tile
-    boundary at the published sizes) so that neither contraction crosses a
-    partial tile."""
+# page slots of the latent kernel: a page in use, the next being scored, the
+# others' copies in flight. A copy takes ~0.47 us from start to done and a
+# page's arithmetic 0.25, so two slots leave the step at 0.54 us; alone on
+# a v5e at the Mistral cell's call 4 / 5 / 6 / 8 slots read 0.313 / 0.273 /
+# 0.269 / 0.267 us a page, the copies alone 0.263 (PERF.md, PR 40)
+_LATENT_SLOTS = 6
+
+
+def _latent_attn_kernel(tables_ref, lengths_ref, q_ref, slab_ref, o_ref,
+                        page_buf, sem, *, page_len: int, value_dim: int):
+    """Grid (B,): one step a row, and the kernel walks the row's LIVE pages
+    itself. ``q_ref`` (1, H, entry) is the row's absorbed, scaled query;
+    ``slab_ref`` the whole slab, left in HBM; ``page_buf`` (slots, page_len,
+    entry) where pages land, page ``i`` in slot ``i % slots``, ``sem`` a DMA
+    semaphore a slot. Two things run beside a page's softmax and value
+    product: the copies of the pages after the next (started here: a block
+    index read from a table is one the pipeline does not run ahead of far
+    enough), and the NEXT page's scores, which the loop carries (one
+    iteration's matmuls have nothing to wait for in the other's reductions
+    and exponentials, and within one iteration the compiler interleaves
+    them). A page is read once: all ``entry`` columns are the key, the first
+    ``value_dim`` the value. The score is split at ``value_dim`` (a
+    lane-tile boundary at the published sizes) so that neither contraction
+    crosses a partial tile. Pages meet the online softmax in order, each
+    with the arithmetic of the grid-a-page kernel this replaced: the output
+    is that kernel's bit for bit. No step of any kind for a table entry past
+    the row's length."""
     b = pl.program_id(0)
-    w = pl.program_id(1)
+    length = lengths_ref[b]
+    n = (length - 1) // page_len + 1       # live pages; >= 1 by the clamp
+    slots = page_buf.shape[0]
+    q = q_ref[0]                           # (H, entry)
+    heads = q.shape[0]
+    nt = (((1,), (1,)), ((), ()))          # contract both minor dimensions
 
-    @pl.when(w == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, _MASKED)
-        l_ref[:] = jnp.zeros_like(l_ref)
+    def slot_of(i):
+        return jax.lax.rem(i, slots)
 
-    @pl.when(w * page_len < lengths_ref[b])
-    def _live_page():
-        q = q_ref[0]                       # (H, entry)
-        e = e_ref[0]                       # (page_len, entry)
-        c = e[:, :value_dim]
-        nt = (((1,), (1,)), ((), ()))      # contract both minor dimensions
-        s = (jax.lax.dot_general(q[:, :value_dim], c, nt,
+    def page_copy(i):
+        return pltpu.make_async_copy(slab_ref.at[tables_ref[b, i]],
+                                     page_buf.at[slot_of(i)],
+                                     sem.at[slot_of(i)])
+
+    def scores(i):
+        e = page_buf[slot_of(i)]                       # (page_len, entry)
+        s = (jax.lax.dot_general(q[:, :value_dim], e[:, :value_dim], nt,
                                  preferred_element_type=jnp.float32)
              + jax.lax.dot_general(q[:, value_dim:], e[:, value_dim:], nt,
                                    preferred_element_type=jnp.float32))
-        at = w * page_len + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(at < lengths_ref[b], s, _MASKED)
-        m_prev = m_ref[:]                  # (H, 1)
+        at = i * page_len + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        return jnp.where(at < length, s, _MASKED)
+
+    def attend(state, s, i):
+        m_prev, l_prev, acc = state
+        c = page_buf[slot_of(i), :, :value_dim]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        m_ref[:] = m_new
-        alpha = jnp.exp(m_prev - m_new)
+        alpha = jnp.exp(m_prev - m_new)    # 0.0 at the first page
         p = jnp.exp(s - m_new)
-        l_ref[:] = alpha * l_ref[:] + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jnp.dot(
+        l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+        return m_new, l_new, acc * alpha + jnp.dot(
             p.astype(q.dtype), c, preferred_element_type=jnp.float32)
 
-    @pl.when(w == pl.num_programs(1) - 1)
-    def _flush():
-        o_ref[0] = (acc_ref[:] / l_ref[:]).astype(o_ref.dtype)
+    for k in range(slots - 1):
+        @pl.when(k < n)
+        def _first_pages():
+            page_copy(k).start()
+    page_copy(0).wait()
+
+    def live_page(i, carry):
+        state, s = carry
+
+        @pl.when(i + slots - 1 < n)
+        def _page_ahead():
+            # into the slot of page i - 1, whose last reader has run
+            page_copy(i + slots - 1).start()
+
+        page_copy(i + 1).wait()
+        return attend(state, s, i), scores(i + 1)
+
+    state, s = jax.lax.fori_loop(0, n - 1, live_page, ((
+        jnp.full((heads, 1), _MASKED, jnp.float32),     # running max m
+        jnp.zeros((heads, 1), jnp.float32),             # normalizer l
+        jnp.zeros((heads, value_dim), jnp.float32)),    # accumulator
+        scores(0)))
+    _, l, acc = attend(state, s, n - 1)
+    o_ref[0] = (acc / l).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("value_dim", "interpret"))
@@ -453,33 +498,26 @@ def _paged_decode_attention_latent_call(q, slab, tables, lengths,
     """Its own jitted name, so a trace tells the kernel from the (K, V)
     variants."""
     B, H, E = q.shape
-    W = tables.shape[1]
     page_len = slab.shape[1]
     kernel = functools.partial(_latent_attn_kernel, page_len=page_len,
                                value_dim=value_dim)
-    page_spec = pl.BlockSpec(
-        (1, page_len, E),
-        lambda b, w, tbl, lens: (
-            tbl[b, jnp.minimum(w, (lens[b] - 1) // page_len)], 0, 0))
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(B, W),
-            in_specs=[pl.BlockSpec((1, H, E),
-                                   lambda b, w, tbl, lens: (b, 0, 0)),
-                      page_spec],
+            grid=(B,),
+            in_specs=[pl.BlockSpec((1, H, E), lambda b, tbl, lens: (b, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((1, H, value_dim),
-                                   lambda b, w, tbl, lens: (b, 0, 0)),
+                                   lambda b, tbl, lens: (b, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((H, value_dim), jnp.float32),  # accumulator
-                pltpu.VMEM((H, 1), jnp.float32),          # running max m
-                pltpu.VMEM((H, 1), jnp.float32),          # normalizer l
+                pltpu.VMEM((_LATENT_SLOTS, page_len, E), slab.dtype),
+                pltpu.SemaphoreType.DMA((_LATENT_SLOTS,)),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, value_dim), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(tables, lengths, q, slab)
 
@@ -514,6 +552,11 @@ def paged_decode_attention_latent(q, slab, tables, lengths, value_dim: int,
                          f"{tables.shape}")
     if interpret is None:
         interpret = _interpret()
+    if not interpret and slab.shape[2] % 128:
+        raise ValueError(
+            f"an entry of {slab.shape[2]} columns is not whole lane tiles of "
+            f"128: the chip copies a page out of the slab tile by tile; store "
+            f"it padded with zeros (LatentSpec.entry_width)")
     lengths = jnp.clip(jnp.asarray(lengths, jnp.int32), 1,
                        tables.shape[1] * page_len)
     return _paged_decode_attention_latent_call(

@@ -181,15 +181,54 @@ def test_the_absorbed_and_the_unabsorbed_form_agree(model, kernel):
                                    np.asarray(want[0]), atol=1e-5, rtol=0)
 
 
+# more pages than the kernel holds slots for, so that a slot is used again
+_WALK_WIDTH = 9
+_WALK_CASES = {
+    "one_token": [1],
+    "a_page_less_one": [PAGE - 1],
+    "a_page": [PAGE],
+    "a_page_and_one": [PAGE + 1],
+    "the_whole_table": [_WALK_WIDTH * PAGE],
+    "a_dummy_row": [0],
+    "all_of_them_in_one_call": [1, PAGE + 1, _WALK_WIDTH * PAGE, 0, PAGE,
+                                3 * PAGE - 1, 7 * PAGE + 3, PAGE - 1],
+}
+
+
+@pytest.mark.parametrize("case", list(_WALK_CASES))
+def test_the_kernel_walks_a_rows_live_pages(case):
+    """The kernel against the gathered formulation, over the lengths at
+    which its walk changes shape: no loop iteration at all (one page), the
+    mask on or off in the last page, the first page ahead, a table longer
+    than the slots (a slot holds a second page while the copies ahead are
+    in flight), a dummy row (all-zero table, length 0: one masked-harmless
+    position of page 0), and rows of all kinds in one call. 1e-5: float32
+    sums in another order."""
+    lengths = np.array(_WALK_CASES[case], np.int32)
+    B = len(lengths)
+    key = jax.random.key(B * 100 + int(lengths[0]))
+    slab = jax.random.normal(key, (B * _WALK_WIDTH + 1, PAGE, 128))
+    q = jax.random.normal(jax.random.fold_in(key, 1), (B, 4, 128)) / 8
+    tables = 1 + np.random.default_rng(3).permutation(
+        B * _WALK_WIDTH).reshape(B, _WALK_WIDTH).astype(np.int32)
+    tables[lengths == 0] = 0
+    got = paged_decode_attention_latent(q, slab, tables, lengths, 16)
+    want = hybrid._attend_latent_gather(q, slab, jnp.asarray(tables),
+                                        jnp.maximum(lengths, 1), 16, PAGE)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5,
+                               rtol=0)
+
+
 def test_the_kernel_reads_nothing_past_a_rows_length_and_no_pad_column():
-    """A NaN in a page past the row's length, in a table entry past it, or
-    (harmless by construction) nowhere in the value's columns: the output is
-    that of the clean slab. The pad columns are zeros in the slab and in
-    the query, so they add nothing to a score."""
+    """A NaN in a page past the row's length (one the table names and the
+    length does not reach, one a shorter row names too), in a table entry
+    past it, or (harmless by construction) nowhere in the value's columns:
+    the output is that of the clean slab. The pad columns are zeros in the
+    slab and in the query, so they add nothing to a score."""
     key = jax.random.key(9)
     q = jax.random.normal(key, (2, 4, 128)).at[..., 24:].set(0.0)
     slab = jax.random.normal(jax.random.fold_in(key, 1), (5, PAGE, 128))
-    tables = np.array([[1, 2, 3], [4, 0, 0]], np.int32)
+    tables = np.array([[1, 2, 3], [4, 3, 0]], np.int32)
     lengths = np.array([11, 3], np.int32)
     clean = paged_decode_attention_latent(q, slab, tables, lengths, 16)
     dirty = slab.at[3].set(jnp.nan).at[0].set(jnp.nan)
@@ -197,6 +236,10 @@ def test_the_kernel_reads_nothing_past_a_rows_length_and_no_pad_column():
     np.testing.assert_array_equal(np.asarray(got), np.asarray(clean))
     with pytest.raises(ValueError, match="value_dim"):
         paged_decode_attention_latent(q, slab, tables, lengths, 129)
+    # on the chip a page is copied out of the slab in whole lane tiles
+    with pytest.raises(ValueError, match="lane tiles"):
+        paged_decode_attention_latent(q[..., :24], slab[..., :24], tables,
+                                      lengths, 16, interpret=False)
 
 
 # through the cache -----------------------------------------------------------

@@ -13,6 +13,8 @@ style configuration dict (:meth:`ModelSpec.from_config`):
   (plain, or YaRN on part of each head), and ``latent`` (below);
 - a fourth kind of layer (``LayerSpec.ssm``; below): full attention AND a
   state-space mixer side by side on one normed input, their outputs added;
+- a fifth, ``linear`` (below): a gated delta-rule mixer and NO attention,
+  a layer that owns no page at all;
 - a per-head sigmoid gate on the attention output, before ``wo``;
 - a SwiGLU FFN, dense or a mixture of experts with a shared expert
   (:func:`~marlin_tpu.models.moe.moe_experts_ffn`: the layer is told which
@@ -59,6 +61,30 @@ branch (:func:`_parallel_mixers`, :func:`_ssm_mixer`, :func:`_ffn_half`,
 :func:`_embed`, :func:`_head_logits`); a model without them traces to the
 programs it traced to before.
 
+**A layer without pages** (:class:`DeltaSpec`; the ``olmo_hybrid``
+configuration family's ``linear_*`` keys). A ``linear`` layer's only mixer
+is recurrent: a gated delta rule (:mod:`~marlin_tpu.ops.delta_rule`) whose
+state is a ``key_dim x value_dim`` matrix a head, float32, stored ``(key_dim,
+heads * value_dim)`` so that the slab is whole lane tiles, with the tail of
+the causal convolution its queries, keys and values pass. It asks ``attend``
+for nothing and keeps no keys or values: its two arrays (states, tails) are
+indexed by the row's state slot alone, so the global page class covers only
+the model's ``full`` layers and the state slot the others. The family's block
+(:func:`_post_norm_layer`) norms each branch's OUTPUT (``x + rmsnorm(mixer(
+x))``), norms a full layer's queries and keys over the whole projection, and
+has no rotary embedding. Prefill (:func:`~marlin_tpu.ops.delta_rule
+.delta_chunk_scan`) and decode (:func:`~marlin_tpu.ops.delta_rule
+.delta_decode_update`) meet the slot as a state-space mixer's do.
+
+**A state that is shared by snapshot.** A slot is private to its row, but a
+COPY of it at a page boundary is as good to another row as the pages before
+that boundary: the pool keeps such copies in further slots of the same
+arrays (``serving/kvpool.py``), and :func:`state_slot_copy` is the one
+program that takes a snapshot (row slot -> snapshot slot, behind the chunk
+that wrote the state) and enters from one (snapshot slot -> row slot, ahead
+of the row's first chunk, which then starts past position 0 and so enters
+with the slot's contents: :func:`_enter_state`).
+
 **Latent attention** (:class:`LatentSpec`; the DeepSeek-V3 configuration
 family's keys). A token's cache entry is not a (K, V) pair per KV head but
 ONE vector: the normed down-projection ``c_kv`` (``kv_rank`` values) and a
@@ -97,8 +123,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["RopeSpec", "LatentSpec", "SsmSpec", "Multipliers", "LayerSpec",
-           "ModelSpec", "init_params",
+__all__ = ["RopeSpec", "LatentSpec", "SsmSpec", "DeltaSpec", "Multipliers",
+           "LayerSpec", "ModelSpec", "init_params", "state_slot_copy",
            "init_layer_params", "init_kv_pages", "window_ring_pages",
            "layer_forward", "prefill_paged", "decode_paged",
            "require_int_heads"]
@@ -254,6 +280,37 @@ class SsmSpec:
 
 
 @dataclasses.dataclass(frozen=True)
+class DeltaSpec:
+    """The sizes of a gated delta-rule (linear-attention) mixer (the
+    ``olmo_hybrid`` configuration family's ``linear_*`` keys): ``heads``
+    heads, keys and queries of ``key_dim`` values and values of
+    ``value_dim``, a state of ``key_dim x value_dim`` a head; a causal
+    depthwise convolution of ``conv`` taps over ``[q | k | v]``; the chunked
+    form's block ``chunk``; ``neg_eigval`` doubles the step ``b`` to ``(0,
+    2)``. The recurrent state is kept in ``state_dtype`` (float32), the
+    convolution's tail in the compute dtype."""
+
+    heads: int
+    key_dim: int
+    value_dim: int
+    conv: int
+    chunk: int = 64
+    neg_eigval: bool = True
+    state_dtype: str = "float32"
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels the convolution runs over: ``[q | k | v]``."""
+        return self.heads * (2 * self.key_dim + self.value_dim)
+
+    def slot_values(self) -> tuple:
+        """Values ONE row's slot holds in one layer: ``(recurrent state,
+        convolution tail)``."""
+        return (self.heads * self.key_dim * self.value_dim,
+                (self.conv - 1) * self.conv_dim)
+
+
+@dataclasses.dataclass(frozen=True)
 class Multipliers:
     """The fixed scalars a configuration family puts on its branches (the
     ``falcon_h1`` family's ``*_multiplier`` keys): on the embedding, on the
@@ -271,12 +328,20 @@ class Multipliers:
 
 @dataclasses.dataclass(frozen=True)
 class LayerSpec:
-    attn: str      # "full" | "sliding" | "latent"
+    #: "full" | "sliding" | "latent", or "linear": no attention, a gated
+    #: delta-rule mixer (:class:`DeltaSpec`) in its place, no page owned
+    attn: str
     q_heads: int
     ffn: str       # "dense" | "moe"
     #: a state-space mixer (:class:`SsmSpec`) beside the attention, both on
     #: the one normed input, their outputs added
     ssm: bool = False
+
+    @property
+    def has_state(self) -> bool:
+        """Whether the layer keeps a recurrent state (and a convolution's
+        tail) in the row's state slot: a mixer of either kind."""
+        return self.ssm or self.attn == "linear"
 
 
 _LAYER_TYPES_KEYS = (
@@ -296,6 +361,11 @@ _FALCON_H1_KEYS = (
     "num_key_value_heads", "rope_theta", "intermediate_size", "vocab_size",
     "mamba_n_heads", "mamba_d_head", "mamba_d_state", "mamba_n_groups",
     "mamba_d_conv", "mamba_chunk_size")
+_OLMO_HYBRID_KEYS = (
+    "num_hidden_layers", "layer_types", "hidden_size", "num_attention_heads",
+    "num_key_value_heads", "intermediate_size", "vocab_size",
+    "linear_num_key_heads", "linear_num_value_heads", "linear_key_head_dim",
+    "linear_value_head_dim", "linear_conv_kernel_dim")
 
 
 def _require(cfg: dict, keys: tuple, family: str) -> None:
@@ -350,6 +420,9 @@ class ModelSpec:
     ssm: SsmSpec | None = None
     #: the family's fixed branch multipliers (None: there are none)
     mults: Multipliers | None = None
+    #: the delta-rule mixers' sizes (the ``linear`` layers); with it the
+    #: block is the ``olmo_hybrid`` family's (:func:`_post_norm_layer`)
+    delta: DeltaSpec | None = None
 
     @classmethod
     def from_config(cls, cfg: dict, experts_total: int | None = None,
@@ -364,14 +437,20 @@ class ModelSpec:
         first one of the share. A configuration with ``kv_lora_rank`` is of
         the latent-attention family (:meth:`_from_latent_config`), one with
         ``mamba_d_ssm`` of the ``falcon_h1`` family
-        (:meth:`_from_falcon_h1_config`). A configuration that lacks keys
-        its family needs raises a ``ValueError`` that names them."""
+        (:meth:`_from_falcon_h1_config`), one with ``linear_key_head_dim``
+        of the ``olmo_hybrid`` family (:meth:`_from_olmo_hybrid_config`). A
+        configuration that lacks keys its family needs raises a
+        ``ValueError`` that names them."""
         if "kv_lora_rank" in cfg:
             _require(cfg, _LATENT_KEYS, "latent-attention (kv_lora_rank)")
             return cls._from_latent_config(cfg, experts_total, first_expert)
         if "mamba_d_ssm" in cfg:
             _require(cfg, _FALCON_H1_KEYS, "falcon_h1 (mamba_d_ssm)")
             return cls._from_falcon_h1_config(cfg)
+        if "linear_key_head_dim" in cfg:
+            _require(cfg, _OLMO_HYBRID_KEYS,
+                     "olmo_hybrid (linear_key_head_dim)")
+            return cls._from_olmo_hybrid_config(cfg)
         _require(cfg, _LAYER_TYPES_KEYS, "layer_types")
         n = int(cfg["num_hidden_layers"])
         kinds = {"full_attention": "full", "sliding_attention": "sliding"}
@@ -539,6 +618,52 @@ class ModelSpec:
             compute_dtype=str(cfg.get("compute_dtype", "bfloat16")),
             ssm=ssm, mults=mults)
 
+    @classmethod
+    def _from_olmo_hybrid_config(cls, cfg: dict):
+        """The ``olmo_hybrid`` family's keys: ``layer_types`` of
+        ``linear_attention`` (a gated delta-rule mixer, ``linear_*``, and no
+        attention: the layer owns no page) and ``full_attention`` (MHA or
+        GQA, queries and keys normed over the whole projection, NO rotary
+        embedding: ``rope_parameters.rope_theta`` is null), a dense SwiGLU
+        after either, every branch's OUTPUT normed before it joins the
+        stream. ``linear_chunk_size`` (the chunked form's block, default 64)
+        and ``linear_state_dtype`` (default float32) are not published
+        keys."""
+        theta = (cfg.get("rope_parameters") or {}).get("rope_theta")
+        if cfg.get("attention_bias") or theta is not None \
+                or cfg.get("hidden_act", "silu") != "silu" \
+                or cfg["linear_num_key_heads"] != cfg["linear_num_value_heads"]:
+            raise ValueError(
+                "not built for the olmo_hybrid family: attention_bias, a "
+                "rotary embedding (rope_theta not null), an activation "
+                "other than silu, or value heads grouped over key heads")
+        n = int(cfg["num_hidden_layers"])
+        kinds = {"full_attention": "full", "linear_attention": "linear"}
+        heads = int(cfg["num_attention_heads"])
+        layers = tuple(LayerSpec(kinds[cfg["layer_types"][i]], heads, "dense")
+                       for i in range(n))
+        delta = DeltaSpec(
+            heads=int(cfg["linear_num_key_heads"]),
+            key_dim=int(cfg["linear_key_head_dim"]),
+            value_dim=int(cfg["linear_value_head_dim"]),
+            conv=int(cfg["linear_conv_kernel_dim"]),
+            chunk=int(cfg.get("linear_chunk_size", 64)),
+            neg_eigval=bool(cfg.get("linear_allow_neg_eigval", False)),
+            state_dtype=str(cfg.get("linear_state_dtype", "float32")))
+        dh = int(cfg.get("head_dim") or int(cfg["hidden_size"]) // heads)
+        none = RopeSpec(theta=1.0, rotary_dim=0)   # never applied
+        return cls(
+            d_model=int(cfg["hidden_size"]), head_dim=dh,
+            kv_heads=int(cfg["num_key_value_heads"]), layers=layers,
+            window=0, rope_full=none, rope_sliding=none,
+            dense_width=int(cfg["intermediate_size"]), expert_width=0,
+            shared_width=0, n_experts=0, experts_held=0, first_expert=0,
+            top_k=0, routed_scale=1.0, vocab_held=int(cfg["vocab_size"]),
+            norm_eps=float(cfg.get("rms_norm_eps", 1e-6)),
+            param_dtype=str(cfg.get("param_dtype", "bfloat16")),
+            compute_dtype=str(cfg.get("compute_dtype", "bfloat16")),
+            delta=delta)
+
     @property
     def n_layers(self) -> int:
         return len(self.layers)
@@ -554,7 +679,13 @@ class ModelSpec:
     @property
     def has_state(self) -> bool:
         """Whether a row holds a recurrent-state slot beside its pages."""
-        return any(ly.ssm for ly in self.layers)
+        return any(ly.has_state for ly in self.layers)
+
+    @property
+    def mixer(self):
+        """The recurrent mixers' sizes, of either kind (None: no layer has
+        one): what a state slot is sized by."""
+        return self.ssm or self.delta
 
     def state_slot_bytes(self, compute_dtype: str | None = None) -> int:
         """Bytes ONE row's state slot holds over all layers: the recurrent
@@ -562,11 +693,11 @@ class ModelSpec:
         dtype, for each layer with a mixer (0 for a model without)."""
         if not self.has_state:
             return 0
-        state, tail = self.ssm.slot_values()
-        per_layer = (state * np.dtype(self.ssm.state_dtype).itemsize
+        state, tail = self.mixer.slot_values()
+        per_layer = (state * np.dtype(self.mixer.state_dtype).itemsize
                      + tail * jnp.dtype(compute_dtype
                                         or self.compute_dtype).itemsize)
-        return per_layer * sum(ly.ssm for ly in self.layers)
+        return per_layer * sum(ly.has_state for ly in self.layers)
 
     def page_values(self, kind: str, page_len: int) -> int:
         """Cache values ONE page id of class ``kind`` holds over all layers
@@ -643,7 +774,16 @@ def init_layer_params(spec: ModelSpec, ly: LayerSpec, key) -> dict:
     mu = spec.mults or Multipliers()
     lp = {"ln1": jnp.ones((d,), jnp.float32),
           "ln2": jnp.ones((d,), jnp.float32)}
-    if ly.ssm:
+    if ly.attn == "linear":
+        lp.update(_init_delta_params(spec, ks[8:15]))
+    elif spec.delta is not None:   # the family's full layer: QK-norm, no gate
+        lp.update(wq=_normal(ks[0], (d, hq), s, dt),
+                  wk=_normal(ks[1], (d, hk), s, dt),
+                  wv=_normal(ks[2], (d, hk), s, dt),
+                  q_norm=jnp.ones((hq,), jnp.float32),
+                  k_norm=jnp.ones((hk,), jnp.float32),
+                  wo=_normal(ks[4], (hq, d), hq ** -0.5, dt))
+    elif ly.ssm:
         lp.update(wq=_normal(ks[0], (d, hq), s, dt),
                   wk=_normal(ks[1], (d, hk), s / mu.key, dt),
                   wv=_normal(ks[2], (d, hk), s, dt),
@@ -721,17 +861,47 @@ def _init_ssm_params(spec: ModelSpec, ks) -> dict:
                          sm.d_inner ** -0.5 / sm.out_mult, dt)}
 
 
+def _init_delta_params(spec: ModelSpec, ks) -> dict:
+    """A delta-rule mixer's parameters, drawn so that a check can see the
+    mixer: projections N(0, 1/fan_in) (``[q | k | v]`` as one matrix, the
+    channels the convolution runs over); the convolution N(0, 1/taps), no
+    bias; ``A`` uniform in [0.5, 1.5] and the step log-uniform in [1e-3,
+    1e-1] (``dt_bias`` its inverse softplus) with ``W_a`` a tenth of its
+    fan-in's law, so that the decay ``a = exp(-A softplus(W_a u +
+    dt_bias))`` spreads over about 0.86 to 0.9995 (a state that remembers
+    tens to thousands of tokens); ``W_b`` at 0.3 of its law, so that ``b = 2
+    sigmoid(W_b u)`` passes 1 for half the tokens without saturating."""
+    ds, d, dt = spec.delta, spec.d_model, jnp.dtype(spec.param_dtype)
+    s, H, hv = d ** -0.5, ds.heads, ds.heads * ds.value_dim
+    step = jnp.exp(jax.random.uniform(
+        ks[3], (H,), jnp.float32, math.log(1e-3), math.log(1e-1)))
+    return {
+        "w_qkv": _normal(ks[0], (d, ds.conv_dim), s, dt),
+        "w_ab": jnp.concatenate([_normal(ks[1], (d, H), 0.1 * s, dt),
+                                 _normal(ks[2], (d, H), 0.3 * s, dt)], axis=1),
+        "w_g": _normal(ks[5], (d, hv), s, dt),
+        "conv_w": _normal(ks[6], (ds.conv, ds.conv_dim), ds.conv ** -0.5, dt),
+        "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+        "A_log": jnp.log(jax.random.uniform(ks[4], (H,), jnp.float32,
+                                            0.5, 1.5)),
+        "o_norm": jnp.ones((ds.value_dim,), jnp.float32),
+        "wo": _normal(jax.random.fold_in(ks[0], 1), (hv, d), hv ** -0.5, dt)}
+
+
 def init_params(spec: ModelSpec, key) -> dict:
     """Scaled-normal parameters, drawn a layer at a time; embedding and head
     are separate (untied) and hold ``vocab_held`` rows. With the family's
     multipliers (:class:`Multipliers`) the embedding reaches the stream at
-    order 1 and the logits spread by order 1."""
+    order 1 and the logits spread by order 1; so it does (N(0, 1)) in the
+    family whose blocks norm their branches' outputs and not their inputs
+    (``delta``): each branch joins the stream at order 1 behind its norm."""
     dt = jnp.dtype(spec.param_dtype)
     mu = spec.mults
     ks = jax.random.split(key, spec.n_layers + 2)
     table = (spec.vocab_held, spec.d_model)
-    p = {"emb": _normal_table(ks[0], table,
-                              0.02 if mu is None else 1.0 / mu.embedding, dt),
+    emb_std = 1.0 if spec.delta is not None else (
+        0.02 if mu is None else 1.0 / mu.embedding)
+    p = {"emb": _normal_table(ks[0], table, emb_std, dt),
          "head": _normal_table(ks[1], table, spec.d_model ** -0.5
                                / (1.0 if mu is None else mu.lm_head), dt),
          "ln_f": jnp.ones((spec.d_model,), jnp.float32)}
@@ -763,16 +933,27 @@ def init_kv_pages(spec: ModelSpec, num_pages: int, window_pages: int,
     indexed by a row's STATE SLOT and not by a page id: the recurrent states
     ``(state_slots, heads, state, head_dim)`` in the mixer's ``state_dtype``
     and the convolution tails ``(state_slots, conv - 1, conv_dim)``; slot 0
-    is the dummy."""
+    is the dummy. A ``linear`` layer has those two arrays and nothing else
+    (its states ``(state_slots, key_dim, heads * value_dim)``:
+    :mod:`~marlin_tpu.ops.delta_rule`). ``state_slots`` counts every slot of
+    the arrays: the rows' and, after them, the pool's snapshot slots."""
     if num_pages < 2 or (spec.has_window and window_pages < 2):
         raise ValueError(f"each page class needs >= 2 pages (page 0 is the "
                          f"dummy), got {num_pages} and {window_pages}")
     if spec.has_state and state_slots < 2:
-        raise ValueError(f"a model with state-space mixers needs >= 2 state "
+        raise ValueError(f"a model with recurrent mixers needs >= 2 state "
                          f"slots (slot 0 is the dummy), got {state_slots}")
     dt = jnp.dtype(compute_dtype or spec.compute_dtype)
 
+    def state_arrays(mixer, state_shape):
+        return (jnp.zeros((state_slots, *state_shape),
+                          jnp.dtype(mixer.state_dtype)),
+                jnp.zeros((state_slots, mixer.conv - 1, mixer.conv_dim), dt))
+
     def slabs(ly):
+        if ly.attn == "linear":   # no page: the state slot's two arrays
+            ds = spec.delta
+            return state_arrays(ds, (ds.key_dim, ds.heads * ds.value_dim))
         if ly.attn == "latent":
             return (jnp.zeros((num_pages, page_len,
                                spec.latent.entry_width), dt),)
@@ -783,10 +964,7 @@ def init_kv_pages(spec: ModelSpec, num_pages: int, window_pages: int,
         if not ly.ssm:
             return kv
         sm = spec.ssm
-        return kv + (
-            jnp.zeros((state_slots, sm.heads, sm.state, sm.head_dim),
-                      jnp.dtype(sm.state_dtype)),
-            jnp.zeros((state_slots, sm.conv - 1, sm.conv_dim), dt))
+        return kv + state_arrays(sm, (sm.heads, sm.state, sm.head_dim))
 
     return {f"l{i}": slabs(ly) for i, ly in enumerate(spec.layers)}
 
@@ -858,10 +1036,14 @@ def layer_forward(spec: ModelSpec, i: int, lp: dict, x, positions, valid,
     ``(assignments, local assignments, held experts touched)``, zeros for a
     dense FFN. A layer with a state-space mixer takes ``mix`` too
     (:func:`_parallel_mixers`): the recurrent state is handed in and out
-    there the way ``attend`` hands in the cache."""
+    there the way ``attend`` hands in the cache; so does a ``linear``
+    layer, which asks ``attend`` for nothing (:func:`_post_norm_layer`,
+    :func:`_delta_mixer`)."""
     ly = spec.layers[i]
     T, cd = x.shape[0], jnp.dtype(spec.compute_dtype)
     H, kvh, dh = ly.q_heads, spec.kv_heads, spec.head_dim
+    if spec.delta is not None:
+        return _post_norm_layer(spec, ly, lp, x, attend, mix)
     if ly.ssm:
         x = _parallel_mixers(spec, ly, lp, x, positions, attend, mix)
         return _ffn_half(spec, ly, lp, x, valid)
@@ -981,6 +1163,79 @@ def _ssm_mixer(spec: ModelSpec, sp: dict, u, mix):
     else:
         y = norm(y * gate)
     return _mm(y.astype(cd), sp["w_out"], jnp.float32) * sm.out_mult
+
+
+def _post_norm_layer(spec: ModelSpec, ly: LayerSpec, lp: dict, x, attend,
+                     mix):
+    """The ``olmo_hybrid`` family's block: no norm on a branch's input, one
+    on its OUTPUT: ``x = x + rmsnorm(mixer(x))``, then ``x = x +
+    rmsnorm(swiglu(x))``. The mixer of a ``linear`` layer is the delta rule
+    (:func:`_delta_mixer`, under the ``linear_attn`` scope); of a ``full``
+    layer, attention whose queries and keys pass an RMSNorm over the WHOLE
+    projection (a gain a column) before they are cut into heads, with no
+    rotary embedding and no head gate."""
+    cd = jnp.dtype(spec.compute_dtype)
+    T, H, kvh, dh = x.shape[0], ly.q_heads, spec.kv_heads, spec.head_dim
+    u = x.astype(cd)
+    if ly.attn == "linear":
+        with jax.named_scope("linear_attn"):
+            y = _delta_mixer(spec, lp, u, mix)
+    else:
+        with jax.named_scope("attn_full"):
+            q = _rmsnorm(_mm(u, lp["wq"], jnp.float32), lp["q_norm"],
+                         spec.norm_eps).astype(cd)
+            k = _rmsnorm(_mm(u, lp["wk"], jnp.float32), lp["k_norm"],
+                         spec.norm_eps).astype(cd)
+            v = _mm(u, lp["wv"])
+            o = attend(q.reshape(T, kvh, H // kvh, dh), k.reshape(T, kvh, dh),
+                       v.reshape(T, kvh, dh))
+            y = _mm(o.reshape(T, H * dh).astype(cd), lp["wo"], jnp.float32)
+    x = x + _rmsnorm(y, lp["ln1"], spec.norm_eps)
+    with jax.named_scope("ffn_dense"):
+        y = _swiglu(x.astype(cd), lp["w_gate"], lp["w_up"], lp["w_down"])
+    return x + _rmsnorm(y, lp["ln2"], spec.norm_eps), \
+        jnp.zeros((3,), jnp.int32)
+
+
+def _delta_operands(ds: DeltaSpec, conv, cd):
+    """From the convolution's output (T, conv_dim) float32 to the delta
+    rule's operands: SiLU, then per head ``q = q~ / |q~| * key_dim^-1/2``,
+    ``k = k~ / |k~|`` (the lengths in float32, 1e-6 under the root) and
+    ``v``, in ``cd``: ``q``, ``k`` (T, heads, key_dim), ``v`` (T, heads,
+    value_dim)."""
+    act = jax.nn.silu(conv)
+    T, H, K, V = conv.shape[0], ds.heads, ds.key_dim, ds.value_dim
+
+    def unit(x):
+        return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True)
+                                 + 1e-6)
+
+    q = unit(act[:, :H * K].reshape(T, H, K)) * K ** -0.5
+    k = unit(act[:, H * K:2 * H * K].reshape(T, H, K))
+    return q.astype(cd), k.astype(cd), \
+        act[:, 2 * H * K:].reshape(T, H, V).astype(cd)
+
+
+def _delta_mixer(spec: ModelSpec, lp: dict, u, mix):
+    """The gated delta-rule mixer over the branch input ``u`` (T, d) in the
+    compute dtype: ``[q~ | k~ | v~] = w_qkv u``; the log-decay ``g = -exp(
+    A_log) softplus(W_a u + dt_bias)`` and the step ``b = sigmoid(W_b u)``
+    (doubled with ``neg_eigval``), one a head, float32; ``mix(qkv (T,
+    conv_dim), g, b (T, heads) float32, lp)`` runs the causal convolution,
+    SiLU, the lengths (:func:`_delta_operands`) and the recurrence where the
+    program keeps the row's state and returns ``o`` (T, heads, value_dim)
+    float32; then an RMSNorm over each head's ``value_dim`` values (one gain
+    vector for all heads), the output gate ``silu(w_g u)``, ``wo``."""
+    ds, cd = spec.delta, jnp.dtype(spec.compute_dtype)
+    T, H, V = u.shape[0], ds.heads, ds.value_dim
+    ab = _mm(u, lp["w_ab"], jnp.float32)
+    g = -jnp.exp(lp["A_log"]) * jax.nn.softplus(ab[:, :H] + lp["dt_bias"])
+    beta = jax.nn.sigmoid(ab[:, H:]) * (2.0 if ds.neg_eigval else 1.0)
+    o = mix(_mm(u, lp["w_qkv"]), g, beta, lp)
+    o = _rmsnorm(o, lp["o_norm"], spec.norm_eps)
+    gate = jax.nn.silu(_mm(u, lp["w_g"], jnp.float32)).reshape(T, H, V)
+    return _mm((o * gate).reshape(T, H * V).astype(cd), lp["wo"],
+               jnp.float32)
 
 
 def _ffn_half(spec: ModelSpec, ly: LayerSpec, lp: dict, x, valid):
@@ -1266,7 +1521,7 @@ def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
                                chunk_start, length, seed, temperature, top_p,
                                top_k, spec: ModelSpec, page_len: int,
                                state_slot=None):
-    from ..ops import ssm as ssm_ops
+    from ..ops import delta_rule, ssm as ssm_ops
     from .transformer import _pick_token_row, _row_key
 
     C = chunk.shape[0]
@@ -1288,7 +1543,8 @@ def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
     w_slots = wtable[jnp.mod(s_page - wp + jnp.arange(wp), max(ring, 1))]
     ltable = jnp.pad(gtable, (0, flash_table_pages(
         gtable.shape[0], page_len) - gtable.shape[0]))
-    by_kind = {"full": gtable, "sliding": w_slots, "latent": ltable}
+    by_kind = {"full": gtable, "sliding": w_slots, "latent": ltable,
+               "linear": gtable}     # a linear layer has no slab to gather
     ctx = jax.lax.optimization_barrier({
         name: tuple(t[by_kind[ly.attn]].reshape(-1, *t.shape[2:])
                     for t in _kv_slabs(ly, pages[name]))
@@ -1324,6 +1580,30 @@ def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
                                                     0),
                 jax.lax.dynamic_update_index_in_dim(tails, t1, state_slot, 0))
             return y.reshape(C, sm.d_inner)
+
+        def mix_delta(qkv, g, beta, lp, name=name):
+            # as above, for a linear layer's delta rule: the slot's state
+            # (key_dim, heads * value_dim) entered and left, the padding
+            # neither decaying nor stepping (g 0 and b 0 there)
+            ds = spec.delta
+            states, tails = pages[name]
+            s0, t0 = _enter_state(chunk_start == 0, states[state_slot],
+                                  tails[state_slot])
+            conv, t1 = ssm_ops.causal_conv(
+                qkv, t0, lp["conv_w"], jnp.zeros((ds.conv_dim,), qkv.dtype),
+                jnp.clip(length - chunk_start, 0, C))
+            q, k, v = _delta_operands(ds, conv, qkv.dtype)
+            with jax.named_scope("delta_scan"):
+                o, s1 = delta_rule.delta_chunk_scan(
+                    q, k, v, jnp.where(valid[:, None], g, 0.0),
+                    jnp.where(valid[:, None], beta, 0.0),
+                    s0.reshape(ds.key_dim, ds.heads, ds.value_dim),
+                    block=min(ds.chunk, C))
+            new_state[name] = (
+                jax.lax.dynamic_update_index_in_dim(
+                    states, s1.reshape(states.shape[1:]), state_slot, 0),
+                jax.lax.dynamic_update_index_in_dim(tails, t1, state_slot, 0))
+            return o
 
         def attend_latent(q_nope, q_pe, entry, scale, wkv_b, name=name):
             new_kv[name] = (entry,)
@@ -1368,7 +1648,7 @@ def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
 
         x, c = layer_forward(spec, i, params[name], x, q_pos, valid,
                              attend_latent if ly.attn == "latent" else attend,
-                             mix)
+                             mix_delta if ly.attn == "linear" else mix)
         counts = counts + c
     # write the chunk's pages, one dynamic update a page (transformer.py has
     # the reason). A page wholly past the prompt goes to the dummy: in a
@@ -1378,7 +1658,7 @@ def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
         name = f"l{i}"
         slabs = _kv_slabs(ly, pages[name])
         fresh = [new.astype(t.dtype).reshape(cp, page_len, *t.shape[2:])
-                 for new, t in zip(new_kv[name], slabs)]
+                 for new, t in zip(new_kv.get(name, ()), slabs)]
         for j in range(cp):
             pid = (wtable[jnp.mod(s_page + j, ring)] if ly.attn == "sliding"
                    else gtable[s_page + j])
@@ -1411,14 +1691,19 @@ def _prefill_args(params, pages, tables, chunk, chunk_start, length,
 
 def _enter_state(fresh, state, tail):
     """What a prefill chunk enters with: the slot's state and tail, or
-    zeros where the chunk is the row's first (``fresh``): a slot is not
-    wiped when it changes hands."""
+    zeros where the chunk starts at position 0 (``fresh``): a slot is not
+    wiped when it changes hands. A row whose FIRST chunk starts past 0 (a
+    prefix hit) enters with what the engine copied into its slot ahead of
+    that chunk: the snapshot of the state at the prefix's end
+    (:func:`state_slot_copy`)."""
     return jnp.where(fresh, 0, state), jnp.where(fresh, 0, tail)
 
 
 def _kv_slabs(ly: LayerSpec, arrays: tuple) -> tuple:
     """A layer's arrays that a page id indexes (what follows them a state
     slot does: :func:`init_kv_pages`)."""
+    if ly.attn == "linear":
+        return ()
     return arrays[:2] if ly.ssm else arrays
 
 
@@ -1427,7 +1712,7 @@ def _state_slots(spec: ModelSpec, name: str, given) -> dict:
     for a spec with state, and only for one."""
     if bool(given) != spec.has_state:
         raise ValueError(
-            "a model with state-space mixers takes (global tables, window "
+            "a model with recurrent mixers takes (global tables, window "
             "rings, state slots); any other (global tables, window rings)")
     return {name: jnp.asarray(given[0], jnp.int32)} if given else {}
 
@@ -1477,7 +1762,7 @@ def _lm_decode_paged_spec_jit(params, pages, gtables, wtables, positions,
                               top_p, top_k, spec: ModelSpec, page_len: int,
                               kernel: str, prev_tokens=None, prev_index=None,
                               state_slots=None):
-    from ..ops import ssm as ssm_ops
+    from ..ops import delta_rule, ssm as ssm_ops
     from ..ops.paged_attention import (paged_decode_attention,
                                        paged_decode_attention_latent)
     from .transformer import (_pick_token_rows, _scatter_kv_entries,
@@ -1498,6 +1783,7 @@ def _lm_decode_paged_spec_jit(params, pages, gtables, wtables, positions,
     # kernel visits, lowest visible position)
     per_class = {"full": (gtables, gtables[rows, page], zero, zero)}
     per_class["latent"] = per_class["full"]  # one table, every position
+    per_class["linear"] = per_class["full"]  # no page: nothing reads it
     if spec.has_window:
         per_class["sliding"] = (wtables, wtables[rows, jnp.mod(page, ring)],
                                 lower // page_len, lower)
@@ -1522,6 +1808,21 @@ def _lm_decode_paged_spec_jit(params, pages, gtables, wtables, positions,
                 tails = tails.at[state_slots].set(t1)
             new_pages[name] += (states, tails)
             return y.reshape(B, sm.d_inner)
+
+        def mix_delta(qkv, g, beta, lp, name=name):
+            # as above, for a linear layer: its two arrays are all it has
+            ds = spec.delta
+            states, tails = pages[name]
+            with jax.named_scope("delta_update"):
+                conv, t1 = ssm_ops.conv_step(
+                    qkv, tails[state_slots], lp["conv_w"],
+                    jnp.zeros((ds.conv_dim,), qkv.dtype))
+                q, k, v = _delta_operands(ds, conv, qkv.dtype)
+                states, o = delta_rule.delta_decode_update(
+                    states, state_slots, q, k, v, g, beta, kernel=kernel)
+                tails = tails.at[state_slots].set(t1)
+            new_pages[name] = (states, tails)
+            return o
 
         def attend(q, k, v, name=name, ly=ly, tables=tables, pids=pids,
                    first_page=first_page, low=low):
@@ -1559,7 +1860,7 @@ def _lm_decode_paged_spec_jit(params, pages, gtables, wtables, positions,
 
         x, c = layer_forward(spec, i, params[name], x, pos, live,
                              attend_latent if ly.attn == "latent" else attend,
-                             mix)
+                             mix_delta if ly.attn == "linear" else mix)
         counts = counts + c
     logits = _head_logits(spec, params, x)
     nxt = _pick_token_rows(temperature, top_p, top_k, logits, seeds,
@@ -1611,6 +1912,49 @@ def precompile_paged(prefills, decodes) -> None:
     _compile_side_by_side(
         [(_lm_prefill_paged_spec_jit, *_prefill_args(*a)) for a in prefills]
         + [(_lm_decode_paged_spec_jit, *_decode_args(*a)) for a in decodes])
+
+
+def _copy_entry(spec: ModelSpec, pages, src, dst, state: bool):
+    """Entry ``src`` onto entry ``dst`` of every layer's state arrays
+    (``state``) or page arrays (not), the others handed through."""
+    out = {}
+    for i, ly in enumerate(spec.layers):
+        arrays = pages[f"l{i}"]
+        n = len(_kv_slabs(ly, arrays))
+        kv, st = arrays[:n], arrays[n:]
+        moved = tuple(t.at[dst].set(t[src]) for t in (st if state else kv))
+        out[f"l{i}"] = kv + moved if state else moved + st
+    return out
+
+
+@functools.partial(jax.jit, static_argnames=("spec",), donate_argnums=(0,))
+def _state_slot_copy_jit(pages, src, dst, spec: ModelSpec):
+    with jax.named_scope("state_snapshot"):
+        return _copy_entry(spec, pages, src, dst, state=True)
+
+
+def state_slot_copy(pages, src, dst, spec: ModelSpec):
+    """Copy state slot ``src`` onto slot ``dst`` in every layer that has a
+    recurrent mixer, state and convolution tail (``pages`` DONATED; ``src``
+    / ``dst`` traced: ONE compiled program an engine). Taking a snapshot and
+    entering from one are both this program, dispatched in the stream
+    behind the chunk that wrote ``src`` and ahead of whatever changes it
+    next; its operations sit under the ``state_snapshot`` scope."""
+    return _state_slot_copy_jit(pages, jnp.asarray(src, jnp.int32),
+                                jnp.asarray(dst, jnp.int32), spec=spec)
+
+
+@functools.partial(jax.jit, static_argnames=("spec",), donate_argnums=(0,))
+def _kv_page_copy_spec_jit(pages, src, dst, spec: ModelSpec):
+    return _copy_entry(spec, pages, src, dst, state=False)
+
+
+def kv_page_copy(pages, src, dst, spec: ModelSpec):
+    """:func:`~marlin_tpu.models.transformer.kv_page_copy` for a spec with
+    state: page ``src`` onto page ``dst`` in the arrays a page id indexes,
+    and in no other (a state slot's arrays go by another id)."""
+    return _kv_page_copy_spec_jit(pages, jnp.asarray(src, jnp.int32),
+                                  jnp.asarray(dst, jnp.int32), spec=spec)
 
 
 prefill_paged._cache_size = _lm_prefill_paged_spec_jit._cache_size

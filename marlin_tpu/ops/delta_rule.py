@@ -1,0 +1,302 @@
+"""The gated delta rule of a linear-attention layer, two ways.
+
+Per head (keys of ``K`` values, values of ``V``; the state ``S`` is ``K x
+V``, float32), with the log-decay ``g_t <= 0`` (``a_t = exp(g_t)``) and the
+step ``b_t`` in ``(0, 2)``::
+
+    S' = a_t S_{t-1}                          (decay first)
+    S_t = S' + b_t k_t (v_t - S'^T k_t)^T     (the delta step, against S')
+    o_t = S_t^T q_t
+
+Mamba-2's state (``ops/ssm.py``) moves by a scalar decay and an outer
+product; this one is also multiplied by ``I - b_t k_t k_t^T``, a rank-one
+matrix that depends on the key, whose eigenvalue ``1 - b_t`` reaches ``-1``.
+
+- :func:`delta_chunk_scan` runs it over ONE row's chunk of tokens, entered
+  with the row's state and leaving the state after the chunk's last valid
+  token: the chunked (WY / UT) form. Inside a block of ``C`` tokens the
+  steps ``d_t = b_t (v_t - S'^T k_t)`` solve a unit lower-triangular ``C x
+  C`` system a head (``(I + A) D = diag(b) V - diag(b gamma) K S_0``, ``A[t,
+  j] = b_t exp(cs_t - cs_j) k_t . k_j`` for ``j < t``, ``cs`` the running
+  sum of ``g`` inside the block, ``gamma = exp(cs)``); the system is solved
+  in float32, against ``V`` and ``K`` at once for every block of the chunk,
+  and only the ``chunk / C`` block states are chained one after another. A
+  decay ratio ``exp(cs_t - cs_j)`` exists for ``t >= j`` only and is masked
+  BEFORE the exponential. A position with ``g == 0`` and ``b == 0`` neither
+  decays the state nor adds to it: that is how the positions past a row's
+  length are kept out (the caller zeroes both).
+- :func:`delta_decode_update` advances one token for each row of a batch,
+  each row's state living in slot ``slots[b]`` of a slab. ``kernel="pallas"``
+  reads and writes each row's slot ONCE, in place
+  (:func:`_delta_decode_update_call`: the slot id is scalar-prefetched and
+  drives the block index, the slab is aliased to the output); the rows that
+  share the dummy slot 0 scribble on it and on nothing else.
+  ``kernel="gather"`` is the same arithmetic on a gathered copy.
+
+**The slab's layout**: ``(slots, K, heads * V)``, a row's state of head ``h``
+in the lane range ``[h V, (h + 1) V)`` of each of ``K`` rows. At the
+published sizes (30 heads, K 96, V 192) neither ``(96, 192)`` nor ``(192,
+96)`` is whole 128-lane tiles (held a head at a time the chip would pad 192
+lanes to 256: a third more bytes to hold and to move), but ``30 x 192 =
+5760`` is 45 tiles and 96 is 12 sublane tiles, so the slab is dense and a
+pair of heads (384 lanes) is three whole tiles: the kernel walks a block's
+heads in pairs. A key enters the update as a COLUMN broadcast along the
+lanes of its head (``K[k, h V + v] = k_h[k]``), made in the kernel from a
+row by transposing one 128 x 128 tile (as ``ops/ssm.py`` makes ``B``);
+decay, step and value enter as rows laid over the lanes.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .pallas_kernels import _interpret
+
+__all__ = ["delta_chunk_scan", "delta_decode_update", "state_to_slab",
+           "slab_to_state", "decode_update_supported", "decode_heads_block"]
+
+_LANES = 128
+
+
+def state_to_slab(state):
+    """``(..., heads, K, V)`` -> the slab's ``(..., K, heads * V)``."""
+    *lead, H, K, V = state.shape
+    return jnp.moveaxis(state, -3, -2).reshape(*lead, K, H * V)
+
+
+def slab_to_state(slab, heads: int):
+    """The slab's ``(..., K, heads * V)`` -> ``(..., heads, K, V)``."""
+    *lead, K, HV = slab.shape
+    return jnp.moveaxis(slab.reshape(*lead, K, heads, HV // heads), -2, -3)
+
+
+# ------------------------------------------------------- a chunk of one row
+
+def _small_mm(a, b):
+    """``a @ b`` for batches of SMALL float32 matrices (32 x 32 at most
+    here) as a multiply and a sum on the vector units, exact float32: on the
+    MXU each of these thousands of tiny products takes a whole pass of the
+    array, six at float32 precision."""
+    return jnp.sum(a[..., :, :, None] * b[..., None, :, :], axis=-2)
+
+
+def _unit_lower_inverse(A):
+    """``(I + A)^-1`` for strictly lower-triangular ``A`` (..., C, C), in
+    float32 whatever the caller's matmul precision: blocks of at most 16
+    rows by forward substitution a row at a time (row ``i`` of the inverse
+    is ``e_i - A[i] X``, the rows above it final), every batch entry at
+    once; larger ones from their two halves, ``[[Ta, 0], [-Tb A21 Ta,
+    Tb]]``, the halves inverted as one batch. (XLA's own triangular solve
+    takes 2.6 us a token and layer at the published sizes on the chip, more
+    than the whole rest of a prefill chunk's mixer: PERF.md, PR 42.)"""
+    C = A.shape[-1]
+    if C <= 16 or C % 2:
+        eye = jnp.eye(C, dtype=A.dtype)
+        X = jnp.broadcast_to(eye, A.shape)
+        for i in range(1, C):
+            X = X.at[..., i, :].set(
+                eye[i] - jnp.sum(A[..., i, :, None] * X, axis=-2))
+        return X
+    h = C // 2
+    T = _unit_lower_inverse(jnp.stack([A[..., :h, :h], A[..., h:, h:]],
+                                      axis=-3))
+    Ta, Tb = T[..., 0, :, :], T[..., 1, :, :]
+    low = -_small_mm(_small_mm(Tb, A[..., h:, :h]), Ta)
+    return jnp.concatenate(
+        [jnp.concatenate([Ta, jnp.zeros_like(Ta)], axis=-1),
+         jnp.concatenate([low, Tb], axis=-1)], axis=-2)
+
+
+def delta_chunk_scan(q, k, v, g, beta, state, block: int):
+    """The recurrence over one row's chunk. ``q``, ``k`` (T, H, K) and ``v``
+    (T, H, V) in the compute dtype (``q`` scaled, ``k`` of unit length);
+    ``g`` (T, H) float32 log-decay (0 where the position is not a token),
+    ``beta`` (T, H) float32 (0 there too); ``state`` (K, H, V), the state
+    before the chunk as the slab holds it (:func:`state_to_slab` with the
+    heads split). Matmul operands in ``q``'s dtype; the triangular system,
+    every decay, accumulation and the carried state in float32. Returns
+    ``(o (T, H, V) float32, state after the chunk)``."""
+    T, H, K = q.shape
+    V = v.shape[-1]
+    if T % block:
+        raise ValueError(f"a chunk of {T} tokens is not whole blocks of "
+                         f"{block}")
+    nc, C, cd, f32 = T // block, block, q.dtype, jnp.float32
+    qb, kb = q.reshape(nc, C, H, K), k.reshape(nc, C, H, K)
+    vb = v.reshape(nc, C, H, V)
+    gb = g.astype(f32).reshape(nc, C, H)
+    bb = beta.astype(f32).reshape(nc, C, H)
+    cs = jnp.cumsum(gb, axis=1)                                   # (nc, C, H)
+    t = jnp.arange(C)
+    seen = (t[:, None] >= t[None, :])[None, :, :, None]       # j <= t
+    before = (t[:, None] > t[None, :])[None, :, :, None]      # j < t
+    ratio = jnp.exp(jnp.where(seen, cs[:, :, None] - cs[:, None, :],
+                              -jnp.inf))                      # (nc, t, j, H)
+    kk = jnp.einsum("cthk,cjhk->ctjh", kb, kb, preferred_element_type=f32)
+    A = jnp.where(before, bb[:, :, None, :] * ratio * kk, 0.0)
+    gamma = jnp.exp(cs)
+    # (I + A) [W | U] = [diag(b) V | diag(b gamma) K], a head and block
+    rhs = jnp.concatenate(
+        [bb[..., None] * vb.astype(f32),
+         (bb * gamma)[..., None] * kb.astype(f32)], axis=-1)
+    # the inverse in float32; applied as every other product here is, its
+    # operands in the compute dtype and the sums in float32
+    solved = jnp.matmul(_unit_lower_inverse(A.transpose(0, 3, 1, 2))
+                        .astype(cd), rhs.transpose(0, 2, 1, 3).astype(cd),
+                        preferred_element_type=f32)
+    W, U = solved[..., :V], solved[..., V:].astype(cd)   # (nc, H, C, V | K)
+    P = (ratio * jnp.einsum("cthk,cjhk->ctjh", qb, kb,
+                            preferred_element_type=f32)
+         ).transpose(0, 3, 1, 2).astype(cd)                   # (nc, H, t, j)
+    q_in = (qb.astype(f32) * gamma[..., None]).astype(cd)
+    k_out = (kb.astype(f32) * jnp.exp(cs[:, -1:] - cs)[..., None]).astype(cd)
+    total = jnp.exp(cs[:, -1])                                     # (nc, H)
+    s = state.astype(f32)                                         # (K, H, V)
+    outs = []
+    for c in range(nc):       # the block states, chained
+        sc = s.astype(cd)
+        d = (W[c] - jnp.einsum("htk,khv->htv", U[c], sc,
+                               preferred_element_type=f32)).astype(cd)
+        outs.append(
+            jnp.einsum("thk,khv->thv", q_in[c], sc,
+                       preferred_element_type=f32)
+            + jnp.einsum("htj,hjv->thv", P[c], d,
+                         preferred_element_type=f32))
+        s = s * total[c][None, :, None] + jnp.einsum(
+            "jhk,hjv->khv", k_out[c], d, preferred_element_type=f32)
+    return jnp.concatenate(outs), s.astype(state.dtype)
+
+
+# ------------------------------------------------------- one token, many rows
+
+
+def decode_heads_block(heads: int, key_dim: int, value_dim: int) -> int:
+    """Heads a grid step of :func:`_delta_decode_update_call` holds: the
+    largest even divisor of ``heads`` whose float32 block stays under a
+    megabyte (0: the kernel does not take these sizes). Even, because the
+    kernel walks heads in pairs of whole lane tiles."""
+    if key_dim % 8 or key_dim > _LANES or (2 * value_dim) % _LANES \
+            or value_dim > _LANES * 2 or value_dim < _LANES // 2:
+        return 0
+    fits = [hb for hb in range(2, heads + 1, 2)
+            if heads % hb == 0 and hb * key_dim * value_dim * 4 <= 1 << 20]
+    return max(fits, default=0)
+
+
+def decode_update_supported(heads: int, key_dim: int, value_dim: int) -> bool:
+    """Whether :func:`_delta_decode_update_call` takes these sizes."""
+    return decode_heads_block(heads, key_dim, value_dim) > 0
+
+
+def _delta_update_kernel(slots_ref, state_ref, k_ref, q_ref, a_ref, b_ref,
+                         bv_ref, o_ref, out_ref, *, value_dim: int):
+    """Grid (B, head blocks). Blocks: ``state`` / ``out`` (1, K, hb V) of
+    the slab at slot ``slots[b]``; ``k``, ``q`` (1, 1, hb, 128) rows (a
+    head's ``K`` values, zeros after them); ``a`` (the decay), ``b`` (the
+    step) and ``bv`` (``b v``) (1, 1, hb V) rows, a head's scalars laid
+    along its lanes; ``o`` (1, 1, hb V)."""
+    del slots_ref  # only the index maps read it
+    K, V = state_ref.shape[1], value_dim
+    hb = k_ref.shape[2]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (K, _LANES), 1)
+
+    def column(ref, h):
+        # (1, 128) values k -> (K, 128): value k along row k
+        row = ref[0, 0, h:h + 1, :]
+        return jnp.broadcast_to(row, (_LANES, _LANES)).T[:K, :]
+
+    def pair(ref, h):
+        # the columns of heads h, h + 1 over the pair's 2 V lanes
+        c0, c1 = column(ref, h), column(ref, h + 1)
+        cut = V % _LANES                  # where head h + 1 begins in a tile
+        parts = [c0] * (V // _LANES)
+        if cut:
+            parts.append(jnp.where(lane < cut, c0, c1))
+        parts += [c1] * ((2 * V) // _LANES - len(parts))
+        return jnp.concatenate(parts, axis=1)
+
+    for h in range(0, hb, 2):
+        at = slice(h * V, (h + 2) * V)
+        kc, qc = pair(k_ref, h), pair(q_ref, h)
+        sd = state_ref[0, :, at].astype(jnp.float32) * a_ref[0, :, at]
+        u = jnp.sum(sd * kc, axis=0, keepdims=True)              # S'^T k
+        sn = sd + kc * (bv_ref[0, :, at] - b_ref[0, :, at] * u)
+        out_ref[0, :, at] = sn.astype(out_ref.dtype)
+        o_ref[0, :, at] = jnp.sum(sn * qc, axis=0, keepdims=True)
+
+
+@functools.partial(jax.jit, static_argnames=("heads", "heads_block",
+                                             "interpret"))
+def _delta_decode_update_call(slab, slots, k, q, a, b, bv, heads: int,
+                              heads_block: int, interpret: bool):
+    """The in-place update (its own jitted name: the kernel's operation in a
+    trace takes it). ``slab`` (S, K, H V); ``k``, ``q`` (B, H, K) float32;
+    ``a``, ``b``, ``bv`` (B, H V) float32 rows. Returns ``(slab, o (B, H
+    V))``."""
+    _, K, HV = slab.shape
+    B, H, hb = k.shape[0], heads, heads_block
+    V, nb = HV // H, H // hb
+
+    def rows(x):        # (B, H, K) -> (B, nb, hb, 128)
+        return jnp.pad(x, [(0, 0), (0, 0), (0, _LANES - K)]).reshape(
+            B, nb, hb, _LANES)
+
+    slot_spec = pl.BlockSpec((1, K, hb * V),
+                             lambda i, j, slots: (slots[i], 0, j))
+    head_spec = pl.BlockSpec((1, 1, hb, _LANES),
+                             lambda i, j, slots: (i, j, 0, 0))
+    lane_spec = pl.BlockSpec((1, 1, hb * V), lambda i, j, slots: (i, 0, j))
+    o, slab = pl.pallas_call(
+        functools.partial(_delta_update_kernel, value_dim=V),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, nb),
+            in_specs=[slot_spec, head_spec, head_spec, lane_spec, lane_spec,
+                      lane_spec],
+            out_specs=[lane_spec, slot_spec]),
+        out_shape=[jax.ShapeDtypeStruct((B, 1, HV), jnp.float32),
+                   jax.ShapeDtypeStruct(slab.shape, slab.dtype)],
+        # operand 0 is the scalar-prefetched slots; the slab is operand 1
+        input_output_aliases={1: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=32 << 20),
+        interpret=interpret,
+    )(slots, slab, rows(k), rows(q), a[:, None, :], b[:, None, :],
+      bv[:, None, :])
+    return slab, o[:, 0, :]
+
+
+def delta_decode_update(slab, slots, q, k, v, g, beta, kernel: str = "gather",
+                        interpret: bool | None = None):
+    """One token for each of B rows: ``slab`` (S, K, H V) the states,
+    ``slots`` (B,) each row's slot in it (the rows without one name the
+    dummy slot 0), ``q``, ``k`` (B, H, K), ``v`` (B, H, V), ``g`` (B, H)
+    log-decay, ``beta`` (B, H). Everything in float32 but the stored state
+    (the slab's dtype). Returns ``(slab, o (B, H, V) float32)``; ``slab`` is
+    updated in the live rows' slots and the dummy's and nowhere else."""
+    f32 = jnp.float32
+    B, H, K = q.shape
+    V = v.shape[-1]
+    qf, kf, vf = q.astype(f32), k.astype(f32), v.astype(f32)
+    a, bt = jnp.exp(g.astype(f32)), beta.astype(f32)               # (B, H)
+    hb = decode_heads_block(H, K, V)
+    if kernel == "pallas" and hb:
+        def lanes(x):   # (B, H) -> (B, H V): a head's scalar over its lanes
+            return jnp.repeat(x, V, axis=1)
+
+        slab, o = _delta_decode_update_call(
+            slab, slots.astype(jnp.int32), kf, qf, lanes(a), lanes(bt),
+            (bt[..., None] * vf).reshape(B, H * V), heads=H, heads_block=hb,
+            interpret=_interpret() if interpret is None else interpret)
+        return slab, o.reshape(B, H, V)
+    s = slab_to_state(slab[slots].astype(f32), H) * a[..., None, None]
+    d = bt[..., None] * (vf - jnp.einsum("bhkv,bhk->bhv", s, kf))
+    s = s + kf[..., None] * d[..., None, :]
+    o = jnp.einsum("bhkv,bhk->bhv", s, qf)
+    return slab.at[slots].set(state_to_slab(s).astype(slab.dtype)), o

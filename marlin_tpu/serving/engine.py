@@ -330,7 +330,12 @@ class ServeEngine:
     The KV cache is the paged pool (block tables over one shared page
     slab, prefix caching, chunked prefill); ``page_len``/``num_pages``/
     ``prefill_chunk``/``prefix_cache``/``decode_kernel`` override the
-    ``serve_*`` knobs.
+    ``serve_*`` knobs. A model with recurrent state (a
+    :class:`~marlin_tpu.models.hybrid.ModelSpec` with ``has_state``) keeps
+    the prefix cache OFF unless ``prefix_cache=True`` is asked for: it then
+    shares a prefix only up to a boundary whose state it kept a snapshot
+    of, in ``snapshot_slots`` further state slots (default: a row's worth,
+    ``len(buckets) * max_batch``; ``serving/kvpool.py``).
 
     ``programs`` registers additional :class:`~.programs.BucketProgram`
     instances (ALS scoring, PageRank queries, classification, ...) served
@@ -351,6 +356,7 @@ class ServeEngine:
                  decode_kernel: str | None = None,
                  window_pages: int | None = None,
                  state_slots: int | None = None,
+                 snapshot_slots: int | None = None,
                  programs=None,
                  clock=time.monotonic, log=None, start: bool = True):
         cfg = get_config()
@@ -436,28 +442,33 @@ class ServeEngine:
             self._window_page_bytes = kv_page_bytes(
                 params, heads, self._page_len, compute_dtype,
                 kind="sliding")
-        #: the recurrent-state slots (a ModelSpec with state-space mixers):
-        #: how many the pool holds, the dummy slot 0 among them (default:
-        #: one for every row of every bucket), and one slot's bytes over
-        #: all layers, charged at admission beside the row's pages
+        #: the recurrent-state slots (a ModelSpec with recurrent mixers):
+        #: how many the pool holds for rows, the dummy slot 0 among them
+        #: (default: one for every row of every bucket), one slot's bytes
+        #: over all layers, charged at admission beside the row's pages,
+        #: and the snapshot slots after them (0: no prefix is shared)
         self._state_slots = 0
         self._state_slot_bytes = 0
+        self._snapshot_slots = 0
         if self._spec is not None and self._spec.has_state:
-            if prefix_cache:
-                raise ValueError(
-                    "prefix_cache=True with a model that has state-space "
-                    "mixers: a shared page is no use without the recurrent "
-                    "state at the prefix's end, and snapshotting states is "
-                    "not built; leave it unset (off)")
-            self._prefix_cache = False
-            chunk = self._spec.ssm.chunk
+            #: the prefill dispatch span's field for the tokens a chunk's
+            #: mixers scan, named by the mixer's kind
+            self._mixer_tokens = ("ssm_tokens" if self._spec.ssm is not None
+                                  else "delta_tokens")
+            # a shared page is no use without the recurrent state at the
+            # prefix's end: sharing is opt-in, and rests on snapshots
+            self._prefix_cache = bool(prefix_cache)
+            if self._prefix_cache:
+                self._snapshot_slots = int(
+                    snapshot_slots or len(self.buckets) * self.max_batch)
+            chunk = self._spec.mixer.chunk
             for b in self.buckets:
                 width = group_chunk(b, self._page_len, self._prefill_chunk)
                 if width % min(chunk, width):
                     raise ValueError(
                         f"bucket {b}'s prefill chunk of {width} tokens is "
-                        f"not whole blocks of the chunked scan "
-                        f"({chunk}: mamba_chunk_size)")
+                        f"not whole blocks of the chunked scan ({chunk}: "
+                        f"mamba_chunk_size / linear_chunk_size)")
             self._state_slots = int(
                 state_slots or 1 + len(self.buckets) * self.max_batch)
             self._state_slot_bytes = self._spec.state_slot_bytes(
@@ -763,7 +774,8 @@ class ServeEngine:
                 self.params, self.heads, self._num_pages, self._page_len,
                 self.compute_dtype, self._prefix_cache,
                 window_pages=self._window_pages, ring=self._ring or 0,
-                state_slots=self._state_slots)
+                state_slots=self._state_slots,
+                snapshot_slots=self._snapshot_slots)
             self.metrics.record_pages(pool.capacity, 0, 0)
             # account the slab in the process memory ledger: the free rides
             # every drop path (recovery, slab loss, terminal close), so a
@@ -773,7 +785,8 @@ class ServeEngine:
             led.register(f"kvpool:{self._name}",
                          self._num_pages * self._page_bytes
                          + self._window_pages * self._window_page_bytes
-                         + self._state_slots * self._state_slot_bytes,
+                         + (self._state_slots + self._snapshot_slots)
+                         * self._state_slot_bytes,
                          "kvpool", owner=self._name)
         return pool
 
@@ -1715,11 +1728,12 @@ class ServeEngine:
             return 0
 
     def _refuse_private(self, what: str) -> None:
-        """Migration and prefix sharing move or share a row's pages; a
+        """Migration moves a row's pages (and, for prefixes, the cache's); a
         window layer's ring is neither shared nor serialized yet, and a
-        row's pages are no use without its recurrent state, which is not
-        either: for such a model these entry points raise rather than move
-        half a row."""
+        row's pages are no use without its recurrent state, which is shared
+        by snapshot inside ONE pool (``prefix_cache``) but is not
+        serialized, nor is a snapshot: for such a model these entry points
+        raise rather than move half a row."""
         if self._ring:
             raise MigrationError(
                 f"{what}: {self._name} serves a model with sliding-window "
@@ -1727,10 +1741,10 @@ class ServeEngine:
                 f"not built")
         if self._state_slots:
             raise MigrationError(
-                f"{what}: {self._name} serves a model with state-space "
+                f"{what}: {self._name} serves a model with recurrent "
                 f"mixers; a row's pages are no use without the recurrent "
-                f"state at their end, and snapshotting, sharing or "
-                f"serializing a state slot is not built")
+                f"state at their end, and serializing a state slot or a "
+                f"snapshot is not built")
 
     def _mig_post(self, kind: str, payload, timeout: float):
         """Post one op to the worker's migration mailbox and wait."""
@@ -2057,7 +2071,8 @@ class ServeEngine:
         mid-prefill has its prefilled prompt), ``pages_used`` adds what the
         prefix cache keeps, ``shared_pages`` are the pages with more than one
         referent (rows and the cache) and ``cached_pages`` the cache's
-        entries."""
+        entries; ``snapshot_slots`` the state snapshots the pool has room
+        for and ``snapshots_held`` those the cache's entries own."""
         resident = live = row_pages = window_pages = kv_tokens = 0
         for g in pools.values():
             if isinstance(g, ProgramRowSet):
@@ -2089,6 +2104,9 @@ class ServeEngine:
             out.update(state_slots=self._state_slots - 1,
                        state_rows=resident,
                        state_bytes=resident * self._state_slot_bytes)
+        if self._snapshot_slots:
+            out.update(snapshot_slots=self._snapshot_slots,
+                       snapshots_held=pool.snapshots_held())
         return out
 
     def _admit_paged(self, pool, pools, claimed, pf_queue) -> None:
@@ -2140,7 +2158,8 @@ class ServeEngine:
                             table_pages=self._decode_pages)
                 slot = group.free_slots()[0]
                 n = r.prompt.shape[0]
-                shared_len, spages = pool.match_prefix(r.prompt)
+                shared_len, spages, snap, seen_len = \
+                    pool.match_prefix_state(r.prompt)
                 need = request_pages(n, r.steps, self._page_len)
                 wpages, state_id = [], 0
                 try:
@@ -2169,6 +2188,12 @@ class ServeEngine:
                     continue
                 group.assign(slot, e, spages + owned, shared_len,
                              len(spages), wpages, state_id)
+                group.seen_len[slot] = seen_len if seen_len > shared_len else 0
+                if snap:
+                    # the state after the shared prefix, into the row's
+                    # slot: in the stream ahead of the row's first chunk,
+                    # which starts at shared_len and so enters from it
+                    pool.copy_state(snap, state_id)
                 pf_queue.append((e.bucket, slot, r.rid))
                 self.metrics.record_prefix(hit=bool(spages))
                 self.metrics.record_page_event(
@@ -2179,6 +2204,9 @@ class ServeEngine:
                                   pages=len(spages) + len(owned),
                                   shared_pages=len(spages),
                                   prompt_tokens=n, shared_tokens=shared_len)
+                if self._snapshot_slots:
+                    # the boundary the row's state was copied from
+                    span.set_metadata(snapshot_tokens=shared_len)
         self._record_pages(pool)
 
     def _prefill_paged_chunk(self, pool, pools, pf_queue, pipe) -> None:
@@ -2241,7 +2269,7 @@ class ServeEngine:
                               final=int(final)) as dispatch:
                     if self._state_slots:
                         # the valid tokens the mixers' scan advances over
-                        dispatch.set_metadata(ssm_tokens=tokens)
+                        dispatch.set_metadata(**{self._mixer_tokens: tokens})
                     chunk = group.prompts[slot][cs:cs + C]
                     if chunk.shape[0] < C:
                         # a prefix hit whose shared_len is page- but not
@@ -2275,6 +2303,14 @@ class ServeEngine:
                     # span's fields cannot be set once it has closed)
                     seq = pipe.dispatched()
                     dispatch.set_metadata(seq=seq)
+                    # analyze: ignore[host-sync] — host numpy bookkeeping
+                    seen = int(group.seen_len[slot])
+                    if pool.snapshot_due(cs + C, C, n, seen):
+                        pool.pages = pages  # (donated to the copy below)
+                        taken = self._take_snapshot(pool, group, slot,
+                                                    cs + C)
+                        pages = pool.pages
+                        dispatch.set_metadata(snapshots=taken)
             except Exception as exc:
                 pf_queue.popleft()
                 self._paged_prefill_failure(pool, pools, bucket, slot, exc)
@@ -2300,6 +2336,22 @@ class ServeEngine:
             if r.steps > 1:
                 self._feed_first(pool, pools, pipe, group, slot, first)
         return tokens
+
+    @staticmethod
+    def _take_snapshot(pool, group, slot: int, position: int) -> int:
+        """Copy the row's state, as the chunk just dispatched leaves it
+        after ``position`` tokens, into a snapshot slot: in the stream
+        behind that chunk and ahead of the row's next. The row owns the
+        snapshot until its prefill lands (:meth:`_land_first` publishes it
+        with the pages). Returns the snapshots taken: 0 where the pool has
+        no slot to give, which costs the request nothing."""
+        sid = pool.alloc_snapshot()
+        if not sid:
+            return 0
+        group.snapshots[slot][position] = sid
+        # analyze: ignore[host-sync] — host numpy bookkeeping arrays
+        pool.copy_state(int(group.state_ids[slot]), sid)
+        return 1
 
     def _feed_first(self, pool, pools, pipe, group, slot: int,
                     first) -> None:
@@ -2356,7 +2408,8 @@ class ServeEngine:
             group.ttft_s[slot] = self._clock() - e.enq_t
             # the prompt's full pages are final now — publish them for
             # copy-on-write reuse by later identical prefixes
-            pool.insert_prefix(r.prompt, group.row_pages[slot])
+            pool.insert_prefix(r.prompt, group.row_pages[slot],
+                               group.snapshots[slot])
             self._record_pages(pool)
             if r.steps == 1 or (r.eos is not None and first == r.eos):
                 self._retire_row_paged(pool, pools, group.bucket, slot,

@@ -72,22 +72,54 @@ flush, and the migration blobs walk each layer's arrays in order, and
 holds. Latent pages are of the global class, so they are shared through the
 prefix cache and migrate like any other.
 
-**A state slot beside the pages** (a ``ModelSpec`` with state-space
-mixers): such a layer's memory of the past is not keys and values in pages
-but, per row, a recurrent state of fixed size and the tail of a short
-convolution (:func:`~marlin_tpu.models.hybrid.init_kv_pages`: two more
-arrays a layer, after its pages). A row holds ONE state slot, an index of
-this pool (:meth:`PagedKVPool.alloc_state`) that serves every such layer,
-taken at admission with the row's pages and freed with them
+**A state slot beside the pages** (a ``ModelSpec`` with recurrent mixers:
+state-space ones beside attention, or delta-rule ones in layers that have NO
+attention and so no page): such a layer's memory of the past is not keys
+and values in pages but, per row, a recurrent state of fixed size and the
+tail of a short convolution (:func:`~marlin_tpu.models.hybrid
+.init_kv_pages`: two more arrays a layer, after its pages if it has any). A
+row holds ONE state slot, an index of this pool
+(:meth:`PagedKVPool.alloc_state`) that serves every such layer, taken at
+admission with the row's pages and freed with them
 (:meth:`PagedKVPool.release_row`); slot 0 is the dummy that padded decode
-rows scribble on. It neither grows nor pages, and it is no use to another
-row: a shared page is worthless without the state at the prefix's end, so
-the prefix cache is off and the migration entry points raise
-(:meth:`PagedKVPool._refuse_private`). Nothing zeroes a slot when it changes
-hands: a row's first prefill chunk enters with a zero state whatever the
-slot holds, and that program is behind every earlier call in the stream.
+rows scribble on. It neither grows nor pages. Nothing zeroes a slot when it
+changes hands: a row's first prefill chunk enters with a zero state whatever
+the slot holds, and that program is behind every earlier call in the stream.
 A :class:`PagedGroup` keeps a row's state slot as ``state_ids[slot]``: a
-pool-level id, NOT the row's slot (its place) in the group.
+pool-level id, NOT the row's slot (its place) in the group. The migration
+entry points raise for such a model (:meth:`PagedKVPool._refuse_private`).
+
+**Snapshots: a prefix shared WITH its state** (``snapshot_slots`` > 0). A
+shared page is no use to a row without the recurrent state at the prefix's
+end, so for a model with state the prefix cache works only where that state
+was kept. The state arrays hold ``snapshot_slots`` further slots after the
+rows' (same slab, same slot size); a SNAPSHOT is a copy row slot ->
+snapshot slot (:func:`~marlin_tpu.models.hybrid.state_slot_copy`, one
+program, dispatched in the stream behind the chunk that wrote the state).
+Its life:
+
+- *taken* by the engine behind a prefill chunk that ends on the row's
+  deepest shareable page boundary, or on the boundary up to which its
+  prompt's pages were found cached with no snapshot to enter from
+  (:meth:`PagedKVPool.snapshot_due`; the row owns it meanwhile:
+  :attr:`PagedGroup.snapshots`). A pool with no
+  snapshot slot to give (none free, none cached to evict) skips it: the row
+  is served all the same, its pages are merely not usable by others;
+- *published* with the row's pages when its prefill has landed
+  (:meth:`PagedKVPool.insert_prefix`): the cache entry of the page that ends
+  at the boundary now owns it (a boundary that already has one frees the
+  newcomer);
+- *hit* at admission (:meth:`PagedKVPool.match_prefix_state`): the match
+  stops at the deepest boundary WITH a snapshot, never hands out a page
+  past it, and the engine copies the snapshot into the row's slot ahead of
+  the row's first chunk, which starts at that boundary. A chain whose deepest snapshot is gone is a shorter
+  hit (or a miss), never an error;
+- *evicted* with its cache entry, or before it when a new snapshot needs
+  the slot: the oldest that was never hit first, then the least recently
+  hit (newest-first among the never-hit ones lost a new prefix's snapshot
+  to the next prompt's own boundary three times in four, and the prefix was
+  prefilled again and again: PERF.md, PR 42). It is never referenced by a
+  row after the copy was dispatched, so it needs no reference count.
 
 Everything here is host-side numpy/stdlib except the three compiled
 programs it drives; single-threaded by contract (only the engine worker
@@ -212,6 +244,11 @@ def auto_num_pages(buckets, max_batch: int, page_len: int) -> int:
     return pages
 
 
+#: "no snapshot" where a snapshot slot's id is expected (slot 0 is the
+#: rows' dummy, never a snapshot's)
+_NO_SNAPSHOT = 0
+
+
 class _CacheEntry:
     __slots__ = ("page", "parent", "children")
 
@@ -231,7 +268,8 @@ class PagedKVPool:
     def __init__(self, params: dict, heads, num_pages: int,
                  page_len: int, compute_dtype: str | None = None,
                  prefix_cache: bool = True, window_pages: int = 0,
-                 ring: int = 0, state_slots: int = 0):
+                 ring: int = 0, state_slots: int = 0,
+                 snapshot_slots: int = 0):
         from ..models.hybrid import ModelSpec
         from ..models.transformer import init_kv_pages
 
@@ -244,6 +282,22 @@ class PagedKVPool:
         #: has no state-space mixer)
         self.state_slots = int(state_slots) if stateful else 0
         self._sfree = list(range(self.state_slots - 1, 0, -1))
+        #: snapshot slots, after the rows' in the same arrays (ids
+        #: ``state_slots .. state_slots + snapshot_slots - 1``); with them a
+        #: model with state shares prefixes (module docstring)
+        self.snapshot_slots = int(snapshot_slots) if (
+            stateful and prefix_cache and not windowed) else 0
+        self._snapfree = list(range(
+            self.state_slots + self.snapshot_slots - 1,
+            self.state_slots - 1, -1))
+        #: cache key -> the snapshot slot that holds the state at the end
+        #: of that entry's page, least recently published or hit first; and
+        #: the keys whose snapshot a row has entered from at least once
+        self._snaps: OrderedDict[bytes, int] = OrderedDict()
+        self._snap_hit: set[bytes] = set()
+        self._spec = heads if stateful else None
+        self.snapshots_taken = 0
+        self.snapshot_evictions = 0
         #: pages of the window class (0: the model has no sliding layer)
         #: and the ring a row holds of them
         self.window_pages = int(window_pages) if windowed else 0
@@ -252,13 +306,13 @@ class PagedKVPool:
             raise ValueError("a model with sliding layers needs ring >= 1")
         self.pages = init_kv_pages(params, num_pages, page_len, heads,
                                    compute_dtype, self.window_pages,
-                                   self.state_slots)
+                                   self.state_slots + self.snapshot_slots)
         self._wfree = list(range(self.window_pages - 1, 0, -1))
         self._wref = np.zeros(self.window_pages, np.int32)
         if windowed:
             self._wref[0] = 1  # the window class's own dummy
             prefix_cache = False  # a window page is never shared
-        if stateful:
+        if stateful and not self.snapshot_slots:
             prefix_cache = False  # nor a page without the state at its end
         # pop() hands out ascending ids; page 0 never enters the list
         self._free = list(range(num_pages - 1, 0, -1))
@@ -304,6 +358,11 @@ class PagedKVPool:
         if self.state_slots:
             out.update(state_total=self.state_slots - 1,
                        state_used=self.state_used_count())
+        if self.snapshot_slots:
+            out.update(snapshot_total=self.snapshot_slots,
+                       snapshots_held=self.snapshots_held(),
+                       snapshots_taken=self.snapshots_taken,
+                       snapshot_evictions=self.snapshot_evictions)
         return out
 
     # ------------------------------------------------------ the window class
@@ -332,9 +391,10 @@ class PagedKVPool:
             self._wfree.append(int(p))
 
     def _refuse_private(self, what: str) -> None:
-        """Sharing and migration move pages; what a row holds besides (a
-        window ring, a recurrent-state slot) is neither shared nor
-        serialized, and the pages are no use without it."""
+        """Migration moves pages; what a row holds besides (a window ring,
+        a recurrent-state slot) is not serialized, and the pages are no use
+        without it. (Sharing: a window ring is never shared; a state is, by
+        snapshot, through the prefix cache alone.)"""
         if self.window_pages:
             raise NotImplementedError(
                 f"{what} is not built for a model with sliding-window "
@@ -342,10 +402,10 @@ class PagedKVPool:
                 f"neither shared nor serialized")
         if self.state_slots:
             raise NotImplementedError(
-                f"{what} is not built for a model with state-space mixers: "
-                f"a row's pages are no use without the recurrent state at "
-                f"their end, and a state slot is neither snapshotted, shared "
-                f"nor serialized")
+                f"{what} is not built for a model with recurrent mixers: a "
+                f"row's pages are no use without the recurrent state at "
+                f"their end, and neither a state slot nor a snapshot is "
+                f"serialized")
 
     # ------------------------------------------------------- the state slots
 
@@ -371,13 +431,92 @@ class PagedKVPool:
                 f"state slot {state_id} released twice"
             self._sfree.append(int(state_id))
 
+    # --------------------------------------------------------- the snapshots
+
+    def snapshot_due(self, chunk_end: int, chunk: int, prompt_len: int,
+                     seen_len: int = 0) -> bool:
+        """Whether the state behind a prefill chunk that ends at
+        ``chunk_end`` (``chunk`` tokens wide, whole pages) is worth a
+        snapshot. Two boundaries of a prompt are: the DEEPEST shareable one
+        (the next chunk's end lies past :meth:`_share_limit`: a later
+        request that extends this prompt shares up to there), and
+        ``seen_len``, the boundary up to which the prompt's pages were found
+        cached at admission WITHOUT a snapshot to enter from
+        (:meth:`match_prefix_state`): other requests demonstrably share that
+        prefix, and this row is prefilling it anyway. (The deepest alone
+        left a prefix whose first prompts all ran past it without a
+        snapshot for good, and every later request prefilled it again:
+        PERF.md, PR 42.) A shallower boundary serves only a request that
+        diverges inside a prefix nobody has been seen to share."""
+        limit = self._share_limit(prompt_len)
+        return bool(self.snapshot_slots) and chunk_end <= limit and (
+            limit < chunk_end + chunk or chunk_end == seen_len)
+
+    def alloc_snapshot(self) -> int:
+        """A snapshot slot for a row about to copy its state: a free one,
+        else the coldest cached one's (its entry stays, a shorter hit from
+        now on), else 0: every slot is held by a row whose prefill is in
+        flight, and the caller skips the snapshot."""
+        if self._snapfree:
+            sid = self._snapfree.pop()
+        elif self._snaps:
+            # the oldest that no row ever entered from, else the least
+            # recently hit: a prompt's own deepest boundary (which nobody
+            # shares) goes before a prefix that many do, and a NEW prefix's
+            # snapshot outlives every older one that was never hit
+            key = next((k for k in self._snaps if k not in self._snap_hit),
+                       next(iter(self._snaps)))
+            sid = self._forget_snapshot(key)
+        else:
+            return _NO_SNAPSHOT
+        self.snapshots_taken += 1
+        return sid
+
+    def _forget_snapshot(self, key: bytes) -> int:
+        """Take the snapshot off cache entry ``key`` (an eviction); its
+        slot."""
+        self._snap_hit.discard(key)
+        self.snapshot_evictions += 1
+        return self._snaps.pop(key)
+
+    def snapshots_held(self) -> int:
+        """Snapshots the cache's entries own (a hit can enter from)."""
+        return len(self._snaps)
+
+    def release_snapshots(self, ids) -> None:
+        """Snapshot slots a row took and never published go back."""
+        for sid in ids or ():
+            assert sid not in self._snapfree, \
+                f"snapshot slot {sid} released twice"
+            self._snapfree.append(int(sid))
+
+    def copy_page(self, src: int, dst: int) -> None:
+        """Page ``src`` onto page ``dst`` in every array a page id indexes
+        (and in no state array: those go by another id)."""
+        if self._spec is not None:
+            from ..models.hybrid import kv_page_copy
+
+            self.pages = kv_page_copy(self.pages, src, dst, self._spec)
+        else:
+            from ..models.transformer import kv_page_copy
+
+            self.pages = kv_page_copy(self.pages, src, dst)
+
+    def copy_state(self, src: int, dst: int) -> None:
+        """State slot ``src`` onto slot ``dst`` (rows' and snapshots' ids
+        alike), in the stream."""
+        from ..models.hybrid import state_slot_copy
+
+        self.pages = state_slot_copy(self.pages, src, dst, self._spec)
+
     def release_row(self, group: "PagedGroup", slot: int) -> list[int]:
         """Free everything the row in ``slot`` of ``group`` holds, of every
-        kind, and the slot itself: its window ring, its state slot, its
-        pages. The one funnel of every retirement path. Returns the pages
-        it held."""
+        kind, and the slot itself: its window ring, its state slot, the
+        snapshots it took and has not published, its pages. The one funnel
+        of every retirement path. Returns the pages it held."""
         self.release_window(group.window_row_pages[slot])
         self.release_state(int(group.state_ids[slot]))
+        self.release_snapshots((group.snapshots[slot] or {}).values())
         pages = group.release(slot)
         self.release(pages)
         return pages
@@ -440,12 +579,28 @@ class PagedKVPool:
         """Longest cached prefix of ``prompt`` in whole pages:
         ``(shared_len, pages)``, with one reference taken per matched page
         (the caller's row now co-owns them read-only). Counts a hit when
-        at least one page matched, else a miss."""
+        at least one page matched, else a miss. (A model with state:
+        :meth:`match_prefix_state`.)"""
+        return self.match_prefix_state(prompt)[:2]
+
+    def match_prefix_state(self, prompt: np.ndarray) \
+            -> tuple[int, list[int], int, int]:
+        """:meth:`match_prefix` and the snapshot to enter from:
+        ``(shared_len, pages, snapshot slot, seen_len)``. For a model with
+        state the match ends at the deepest cached boundary that HAS a
+        snapshot (0 pages where none has), and the slot returned holds the
+        state after ``shared_len`` tokens: the caller copies it into the
+        row's slot before the row's first chunk (0 for a model without
+        state). ``seen_len`` is how far the prompt's pages were found
+        cached, snapshot or none (>= ``shared_len``): where it is deeper,
+        the row's prefill is asked for a snapshot there
+        (:meth:`snapshot_due`)."""
         if not self.prefix_cache_enabled:
-            return 0, []
+            return 0, [], _NO_SNAPSHOT, 0
         prompt = np.asarray(prompt, np.int32)
         limit = self._share_limit(len(prompt))
         pages: list[int] = []
+        keys: list[bytes] = []
         key = b""
         k = 0
         while (k + 1) * self.page_len <= limit:
@@ -456,23 +611,39 @@ class PagedKVPool:
                 break
             self._cache.move_to_end(key)  # LRU touch
             pages.append(e.page)
+            keys.append(key)
             k += 1
+        snap, seen_len = _NO_SNAPSHOT, len(keys) * self.page_len
+        if self.snapshot_slots:
+            deepest = max((i for i, k_ in enumerate(keys)
+                           if k_ in self._snaps), default=-1)
+            del pages[deepest + 1:]
+            if pages:
+                self._snaps.move_to_end(keys[deepest])
+                self._snap_hit.add(keys[deepest])
+                snap = self._snaps[keys[deepest]]
         if pages:
             self.retain(pages)
             self.hits += 1
         else:
             self.misses += 1
-        return len(pages) * self.page_len, pages
+        return len(pages) * self.page_len, pages, snap, seen_len
 
-    def insert_prefix(self, prompt: np.ndarray, row_pages) -> int:
+    def insert_prefix(self, prompt: np.ndarray, row_pages,
+                      snapshots: dict | None = None) -> int:
         """Cache the row's completed full prompt pages (called once, when
         the row's prefill finishes — the pages' contents are final from
         then on). ``row_pages`` is the row's block table in position order.
         Already-cached prefixes are skipped (no double reference); each
         newly cached page gains one cache-owned reference that outlives
-        the row. Returns pages inserted."""
+        the row. ``snapshots`` (position -> snapshot slot, emptied here) are
+        the snapshots the row took: each goes to the entry of the page that
+        ends at its position (never hit yet: it goes before any that was,
+        after every older one that was not), or back to the free list where
+        that boundary has one already. Returns pages inserted."""
         if not self.prefix_cache_enabled:
-            return 0
+            return 0  # (and no snapshot exists: they rest on the cache)
+        snapshots = snapshots if snapshots is not None else {}
         prompt = np.asarray(prompt, np.int32)
         limit = self._share_limit(len(prompt))
         key = b""
@@ -484,14 +655,20 @@ class PagedKVPool:
             e = self._cache.get(key)
             if e is not None:
                 self._cache.move_to_end(key)
-                continue
-            page = int(row_pages[k])
-            self._cache[key] = entry = _CacheEntry(page, parent)
-            if parent is not None:
-                self._cache[parent].children += 1
-            del entry
-            self.retain([page])
-            inserted += 1
+            else:
+                page = int(row_pages[k])
+                self._cache[key] = _CacheEntry(page, parent)
+                if parent is not None:
+                    self._cache[parent].children += 1
+                self.retain([page])
+                inserted += 1
+            sid = snapshots.pop((k + 1) * self.page_len, None)
+            if sid and key not in self._snaps:
+                self._snaps[key] = sid
+            elif sid:
+                self.release_snapshots([sid])
+        self.release_snapshots(snapshots.values())  # past the share limit
+        snapshots.clear()
         return inserted
 
     def _evict_one(self) -> bool:
@@ -505,6 +682,8 @@ class PagedKVPool:
                 if e.parent is not None:
                     self._cache[e.parent].children -= 1
                 self.release([e.page])
+                if key in self._snaps:  # its snapshot goes with it
+                    self.release_snapshots([self._forget_snapshot(key)])
                 self.evictions += 1
                 return True
         return False
@@ -522,13 +701,11 @@ class PagedKVPool:
         :meth:`_share_limit`), so this is a cheap refcount check — but it
         is the contract that makes sharing safe against any future
         scheduler change, and the unit tests drive it directly."""
-        from ..models.transformer import kv_page_copy
-
         page = int(table[idx])
         if page == 0 or self._ref[page] <= 1:
             return False
         fresh = self.alloc(1)[0]
-        self.pages = kv_page_copy(self.pages, page, fresh)
+        self.copy_page(page, fresh)
         self.release([page])
         table[idx] = fresh
         self.cow_copies += 1
@@ -910,10 +1087,12 @@ class PagedKVPool:
 
     def _audit_state(self, groups) -> list[str]:
         """The state slots' half of :meth:`audit`: every slot either free or
-        held by exactly one resident row, every resident row holding one."""
+        held by exactly one resident row, every resident row holding one;
+        every snapshot slot free, owned by one cache entry that exists, or
+        held by one resident row that has yet to publish it."""
         if not self.state_slots:
             return []
-        errors: list[str] = []
+        errors: list[str] = self._audit_snapshots(groups)
         held = np.zeros(self.state_slots, np.int64)
         for g in groups:
             for slot in g.occupied_slots():
@@ -936,6 +1115,42 @@ class PagedKVPool:
             elif groups and held[sid] != 1:
                 errors.append(f"state slot {sid}: off the free list with "
                               f"{int(held[sid])} rows holding it")
+        return errors
+
+
+    def _audit_snapshots(self, groups) -> list[str]:
+        errors: list[str] = []
+        lo, hi = self.state_slots, self.state_slots + self.snapshot_slots
+        owners = np.zeros(hi, np.int64)
+        for key, sid in self._snaps.items():
+            if key not in self._cache:
+                errors.append(f"snapshot slot {sid} belongs to a cache "
+                              f"entry that is gone")
+            if lo <= sid < hi:
+                owners[sid] += 1
+            else:
+                errors.append(f"the cache holds snapshot slot {sid}, not "
+                              f"one of {lo}..{hi - 1}")
+        for g in groups:
+            for slot in g.occupied_slots():
+                for sid in (g.snapshots[slot] or {}).values():
+                    if lo <= sid < hi:
+                        owners[sid] += 1
+                    else:
+                        errors.append(f"a row holds snapshot slot {sid}, "
+                                      f"not one of {lo}..{hi - 1}")
+        fs = set(self._snapfree)
+        if len(fs) != len(self._snapfree):
+            errors.append("snapshot free list contains duplicate slots")
+        for sid in range(lo, hi):
+            if sid in fs and owners[sid]:
+                errors.append(f"free snapshot slot {sid} is still owned")
+            elif sid not in fs and owners[sid] > 1:
+                errors.append(f"snapshot slot {sid} has {owners[sid]} "
+                              f"owners")
+            elif sid not in fs and groups and not owners[sid]:
+                errors.append(f"snapshot slot {sid} leaked: off the free "
+                              f"list and owned by nothing")
         return errors
 
 
@@ -976,6 +1191,12 @@ class PagedGroup:
         #: in this group
         self.stateful = stateful
         self.state_ids = np.zeros(width, np.int32)
+        #: the snapshots a row took and has yet to publish with its pages:
+        #: position -> snapshot slot of the pool (None: a free slot); and
+        #: the boundary up to which the row's prompt was found cached at
+        #: admission with no snapshot to enter from (0: none such)
+        self.snapshots: list = [None] * width
+        self.seen_len = np.zeros(width, np.int64)
         #: stored table width: decode extent + chunk spill (a final chunk
         #: starting near the extent scatters into these dummy-page slots)
         self.table_width = self.pages_per_row + self.chunk_pages
@@ -1043,6 +1264,7 @@ class PagedGroup:
         self.window_tables[slot, :len(window_pages)] = window_pages
         self.window_row_pages[slot] = list(window_pages)
         self.state_ids[slot] = state_id
+        self.snapshots[slot] = {}
         self.shared_pages[slot] = n_shared
         self.pf_next[slot] = shared_len
         padded = np.zeros(_round_up(n, self.chunk), np.int32)
@@ -1114,6 +1336,7 @@ class PagedGroup:
         self.window_tables[slot, :] = 0
         self.window_row_pages[slot] = None
         self.state_ids[slot] = 0
+        self.snapshots[slot] = None
         self.shared_pages[slot] = 0
         self.pf_next[slot] = -1
         self.positions[slot] = 0
@@ -1348,8 +1571,8 @@ def warmup_paged(params: dict, heads: int, buckets, max_batch: int,
     Returns the buckets warmed."""
     import jax
 
-    from ..models.transformer import (feed_token, kv_page_copy,
-                                      lm_decode_paged, lm_prefill_paged)
+    from ..models.transformer import (feed_token, lm_decode_paged,
+                                      lm_prefill_paged)
     from .batcher import normalize_buckets
 
     buckets = normalize_buckets(buckets)
@@ -1389,6 +1612,8 @@ def warmup_paged(params: dict, heads: int, buckets, max_batch: int,
             compute_dtype=compute_dtype, moe=moe, kernel=kernel,
             prev_tokens=feed_token(feed, 0, first), prev_index=unfed)[:2]
     jax.block_until_ready(feed)
-    pool.pages = kv_page_copy(pool.pages, 0, 0)  # the last program
+    if pool.snapshot_slots:
+        pool.copy_state(0, 0)  # taking a snapshot and entering from one
+    pool.copy_page(0, 0)  # the last program
     jax.block_until_ready(pool.pages["l0"][0])
     return len(buckets)

@@ -892,3 +892,43 @@ def test_state_update_kernel_mosaic_compiles_in_place_at_published_widths(
     m = compiled.memory_analysis()
     assert m.alias_size_in_bytes >= slab_bytes
     assert m.temp_size_in_bytes < slab_bytes // 8
+
+
+def test_delta_update_kernel_mosaic_compiles_in_place_at_published_widths(
+        one_chip):
+    """The decode state update of a delta-rule layer (``ops/delta_rule.py``)
+    at the ``olmo_hybrid`` cell's shapes (24 rows, 30 heads, keys of 96,
+    values of 192: neither whole lane tiles, the slab ``(53, 96, 5760)``
+    is) compiles through Mosaic for a v5e, and updates the slab IN PLACE:
+    the 117 MB slab is aliased to the output and no temporary of its size
+    appears; so does the snapshot's copy of one slot onto another."""
+    from marlin_tpu.ops import delta_rule
+
+    B, H, K, V, S = 24, 30, 96, 192, 53
+    slab_bytes = S * K * H * V * 4
+
+    def st(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def step(slab, slots, q, k, v, g, b):
+        return delta_rule.delta_decode_update(slab, slots, q, k, v, g, b,
+                                              kernel="pallas",
+                                              interpret=False)
+
+    assert delta_rule.decode_heads_block(H, K, V) == 10
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+        st((S, K, H * V)), st((B,), jnp.int32), st((B, H, K)),
+        st((B, H, K)), st((B, H, V)), st((B, H)), st((B, H))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= slab_bytes
+    assert m.temp_size_in_bytes < slab_bytes // 8
+
+    def snapshot(slab, src, dst):
+        return slab.at[dst].set(slab[src])
+
+    copied = jax.jit(snapshot, donate_argnums=(0,)).lower(
+        st((S, K, H * V)), st((), jnp.int32), st((), jnp.int32)).compile()
+    m = copied.memory_analysis()
+    assert m.alias_size_in_bytes >= slab_bytes
+    assert m.temp_size_in_bytes < slab_bytes // 8

@@ -438,7 +438,7 @@ def test_the_pool_hands_out_state_slots_and_audits_them(model):
         pool.release_state(a)
     for entry in (pool.export_rows, pool.import_rows, pool.export_prefixes,
                   pool.import_prefixes):
-        with pytest.raises(NotImplementedError, match="state-space"):
+        with pytest.raises(NotImplementedError, match="recurrent mixers"):
             entry(b"" if "import" in entry.__name__ else [])
 
 
@@ -578,10 +578,14 @@ def test_a_pool_out_of_state_slots_fails_the_request_not_the_worker(model):
         eng.close()
 
 
-def test_prefix_sharing_and_migration_are_refused_for_a_spec_with_state(model):
+def test_migration_is_refused_for_a_spec_with_state_and_sharing_opt_in(model):
+    """Sharing a prefix is opt-in for a model with state (it rests on
+    snapshot slots: ``tests/test_delta_rule.py`` drives it); migration is
+    refused."""
     cfg, spec, params = model
-    with pytest.raises(ValueError, match="state-space"):
-        _engine(spec, params, prefix_cache=True, start=False)
+    shared = _engine(spec, params, prefix_cache=True, start=False)
+    assert shared._prefix_cache and shared._snapshot_slots == 3 * 3
+    shared.close()
     with pytest.raises(ValueError, match="mamba_chunk_size"):
         _engine(hybrid.ModelSpec.from_config(tiny_cfg(mamba_chunk_size=6)),
                 params, start=False)
@@ -593,7 +597,7 @@ def test_prefix_sharing_and_migration_are_refused_for_a_spec_with_state(model):
                                                "blob": b"x"},)),
                             (eng.export_prefixes, (4,)),
                             (eng.import_prefixes, (b"x",))):
-            with pytest.raises(MigrationError, match="state-space"):
+            with pytest.raises(MigrationError, match="recurrent"):
                 entry(*args)
         ok = eng.submit(_requests(((7, 3),))[0]).result(timeout=300)
         assert ok.status == "ok"  # refused, not broken

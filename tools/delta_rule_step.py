@@ -1,0 +1,181 @@
+"""What the gated delta rule costs alone on the chip, both forms.
+
+    chiprun --chips 1 -- python3 tools/delta_rule_step.py [--f32]
+
+At the ``serve.olmohybrid-sessions24`` cell's sizes (30 heads, keys of 96,
+values of 192; ``ops/delta_rule.py``):
+
+- **the decode update**: 24 live rows of 53 slots, the slab ``(slots, 96,
+  5760)`` float32 updated in place. ``pallas`` is the package's kernel,
+  ``gather`` the same arithmetic on a gathered copy that XLA fuses as it
+  likes; each timed as twelve calls (a model's twelve linear layers)
+  chained in one program over one donated slab, in microseconds a live row
+  and call, beside the least the bytes allow (a row's state read and
+  written once at the memory peak);
+- **the chunked form**: a chunk of 512 tokens (and of 1024) in blocks of
+  64, bfloat16 operands (``--f32``: float32), twelve calls chained in one
+  program, in nanoseconds a token and call, beside its flops at the bf16
+  peak and its bytes at the memory peak
+  (``benchmarks/costs_olmohybrid.py``); and the triangular system alone
+  (the solve of ``(I + A) X = [V | K]``), to see its share.
+
+Prints one JSON line a measurement and ends with ``{"ok": true, "device":
+...}``; needs a TPU (a time from the CPU's interpreter says nothing). No
+engine, no model."""
+
+from __future__ import annotations
+
+import argparse
+import json
+import pathlib
+import sys
+import time
+
+sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
+
+H, K, V = 30, 96, 192
+ROWS, SLOTS, LAYERS = 24, 53, 12
+HBM, FLOPS = 819e9, 197e12
+
+
+def _best(fn, args, repeats: int = 5) -> float:
+    import jax
+
+    out = fn(*args)
+    jax.block_until_ready(out)
+    best = float("inf")
+    for _ in range(repeats):
+        t0 = time.perf_counter()
+        out = fn(*(out[:1] + args[1:]) if isinstance(out, tuple) else args)
+        jax.block_until_ready(out)
+        best = min(best, time.perf_counter() - t0)
+    return best
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--f32", action="store_true")
+    args = ap.parse_args(argv)
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks import costs_olmohybrid as costs
+    from marlin_tpu.ops import delta_rule
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        print(json.dumps({"ok": False, "why": "not a TPU"}))
+        return 1
+    cfg = {"linear_num_key_heads": H, "linear_key_head_dim": K,
+           "linear_value_head_dim": V, "linear_conv_kernel_dim": 4,
+           "linear_chunk_size": 64, "num_hidden_layers": 1,
+           "layer_types": ["linear_attention"],
+           "compute_dtype": "float32" if args.f32 else "bfloat16"}
+    peaks = {"hbm_bytes_per_s": HBM, "bf16_flops_per_s": FLOPS}
+    cd = jnp.float32 if args.f32 else jnp.bfloat16
+    ks = jax.random.split(jax.random.key(0), 8)
+
+    def unit(x):
+        return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+
+    # ---- the decode update
+    slab = jax.random.normal(ks[0], (SLOTS, K, H * V), jnp.float32)
+    slots = jnp.arange(1, ROWS + 1, dtype=jnp.int32)
+    q = unit(jax.random.normal(ks[1], (ROWS, H, K))) * K ** -0.5
+    k = unit(jax.random.normal(ks[2], (ROWS, H, K)))
+    v = jax.random.normal(ks[3], (ROWS, H, V))
+    g = -jax.random.uniform(ks[4], (ROWS, H), minval=0.001, maxval=0.1)
+    b = jax.random.uniform(ks[5], (ROWS, H), minval=0.0, maxval=2.0)
+    least = 2.0 * costs.state_bytes(cfg) / HBM
+    outs = {}
+    for kernel in ("pallas", "gather"):
+        @jax.jit
+        def chain(slab, slots, q, k, v, g, b, kernel=kernel):
+            acc = 0.0
+            for i in range(LAYERS):   # (scaled a layer: nothing is shared)
+                slab, o = delta_rule.delta_decode_update(
+                    slab, slots, q, k * (1.0 - 0.01 * i), v, g, b,
+                    kernel=kernel, interpret=False)
+                acc = acc + o
+            return slab, acc
+
+        fn = jax.jit(chain, donate_argnums=(0,))
+        seconds = _best(fn, (jnp.array(slab), slots, q, k, v, g, b))
+        outs[kernel] = fn(jnp.array(slab), slots, q, k, v, g, b)[1]
+        us = 1e6 * seconds / (LAYERS * ROWS)
+        print(json.dumps({
+            "what": "decode_update", "kernel": kernel, "rows": ROWS,
+            "us_a_live_row_and_layer": us,
+            "least_us": 1e6 * least, "roofline_pct": 100 * 1e6 * least / us,
+            "ms_a_step_of_12_layers": 1e3 * seconds}), flush=True)
+    print(json.dumps({"what": "decode_update", "max_abs_diff_pallas_gather":
+                      float(jnp.abs(outs["pallas"] - outs["gather"]).max()),
+
+                      "output_scale": float(jnp.abs(outs["gather"]).max())}),
+          flush=True)
+
+    # ---- the chunked form
+    for T in (512, 1024):
+        q = (unit(jax.random.normal(ks[1], (T, H, K))) * K ** -0.5).astype(cd)
+        k = unit(jax.random.normal(ks[2], (T, H, K))).astype(cd)
+        v = jax.random.normal(ks[3], (T, H, V)).astype(cd)
+        g = -jax.random.uniform(ks[4], (T, H), minval=0.001, maxval=0.1)
+        b = jax.random.uniform(ks[5], (T, H), minval=0.0, maxval=2.0)
+        s0 = jax.random.normal(ks[6], (K, H, V), jnp.float32)
+
+        @jax.jit
+        def scan(s, q, k, v, g, b):
+            acc = 0.0
+            for i in range(LAYERS):   # (scaled a layer: nothing is shared)
+                o, s = delta_rule.delta_chunk_scan(
+                    q, k, v, g * (1.0 + 0.01 * i), b, s, block=64)
+                acc = acc + o
+            return s, acc
+
+        seconds = _best(scan, (s0, q, k, v, g, b))
+        least = costs.gdn_prefill_least_seconds(T, 1, cfg, peaks)
+        ns = 1e9 * seconds / (LAYERS * T)
+        print(json.dumps({
+            "what": "chunk_scan", "tokens": T, "dtype": str(jnp.dtype(cd)),
+            "ns_a_token_and_layer": ns,
+            "least_ns": 1e9 * least["seconds"] / T, "bound": least["bound"],
+            "roofline_pct": 100 * 1e9 * least["seconds"] / T / ns,
+            "ms_a_chunk_of_12_layers": 1e3 * seconds}), flush=True)
+
+        nc, C = T // 64, 64
+        A = jnp.tril(jax.random.normal(ks[7], (nc, H, C, C)) * 0.1, -1)
+        rhs = jax.random.normal(ks[6], (nc, H, C, V + K), jnp.float32)
+
+        @jax.jit
+        def solve(A, rhs):
+            acc = 0.0
+            for i in range(LAYERS):
+                acc = acc + jax.scipy.linalg.solve_triangular(
+                    jnp.eye(C) + A * (1.0 + 0.01 * i), rhs, lower=True,
+                    unit_diagonal=True)
+            return acc
+
+        @jax.jit
+        def blocked(A, rhs):
+            acc = 0.0
+            for i in range(LAYERS):
+                acc = acc + jnp.matmul(
+                    delta_rule._unit_lower_inverse(A * (1.0 + 0.01 * i))
+                    .astype(cd), rhs.astype(cd),
+                    preferred_element_type=jnp.float32)
+            return acc
+
+        for name, fn in (("xla_triangular_solve", solve),
+                         ("blocked_inverse", blocked)):
+            seconds = _best(fn, (A, rhs))
+            print(json.dumps({"what": "solve_alone", "how": name,
+                              "tokens": T, "ns_a_token_and_layer":
+                              1e9 * seconds / (LAYERS * T)}), flush=True)
+        print(json.dumps({"what": "solve_alone", "max_abs_diff": float(
+            jnp.abs(solve(A, rhs) - blocked(A, rhs)).max())}), flush=True)
+    print(json.dumps({"ok": True, "device": dev.device_kind}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -6,15 +6,14 @@ GATHERED each row's context out of the page slab by block table
 materialized copy — a per-step copy of every live row's whole context whose
 cost the serve bench measured at −5±3% tok/s vs the dense slab on no-prefix
 workloads (BENCH_ALL.json; ROADMAP "fused paged decode-attention kernel").
-This module is the kernel that erases the copy: the block table itself
-drives the Pallas ``index_map``, so page blocks stream HBM→VMEM directly
-from the slab (grid (B, W), pages innermost) and the context is never
-materialized as a separate array.
+This module is the kernels that erase the copy: the block table itself
+names the pages that stream HBM→VMEM directly from the slab, and the context
+is never materialized as a separate array.
 
-Shapes follow the slab exactly, and the slab's RANK picks the body that
-reads a page (nothing else does: no argument, no flag, no model's name).
-The dense model (:func:`~marlin_tpu.models.transformer.init_kv_pages`)
-holds K/V pages ``(num_pages, page_len, kv_heads, dh)``; a
+Shapes follow the slab exactly, and the slab's RANK picks the kernel
+(nothing else does: no argument, no flag, no model's name). The dense model
+(:func:`~marlin_tpu.models.transformer.init_kv_pages`) holds K/V pages
+``(num_pages, page_len, kv_heads, dh)``; a
 :class:`~marlin_tpu.models.hybrid.ModelSpec` model
 (:func:`~marlin_tpu.models.hybrid.init_kv_pages`) ``(num_pages, page_len,
 kv_heads * dh)``, a token's heads side by side in one row. Queries arrive
@@ -28,7 +27,11 @@ those two einsums, batched over the block's MIDDLE axis
 (:func:`_page_all_heads`); on a rank-3 page one KV head at a time, head
 ``h``'s keys the lane slice ``[:, h * dh:(h + 1) * dh]``, a ``(page_len,
 dh)`` matrix of whole tiles that meets the head's ``group`` query rows in a
-plain matmul on the MXU (:func:`_page_head_by_head`).
+plain matmul on the MXU (:func:`_page_head_by_head`), or, where a head has
+ONE query row (plain MHA: the shapes say so), every head in one matmul
+against the whole page from a block-diagonal query
+(:func:`_page_one_row_a_head`: thirty chains of one useful row each cost
+more than the page's bytes).
 
 **Why two layouts, and which model holds which.** In VMEM a ``(page_len,
 kv_heads, dh)`` block is ``page_len`` tiles of ``(kv_heads, dh)``, one a
@@ -43,29 +46,51 @@ did not. The dense model keeps rank 4 and the einsum body: with ONE query
 row a head and 16 keys a page a per-head matmul is 64 tile loads a page for
 a handful of useful rows, and that kernel's remedy is more tokens a grid
 step, another mechanism (ROADMAP Queue 3 has the condition that ends the
-split). Softmax is the online
-(flash) form: running max ``m``, normalizer ``l`` and the f32 accumulator
-live in VMEM scratch across the page-sequential grid dimension; each page
-block rescales the accumulator by ``exp(m_old − m_new)``. Reduction order
+split).
+
+**Two schedules.** The dense model's kernel (:func:`_paged_attn_kernel`)
+lays a grid over (B, W), pages innermost: the block table drives the Pallas
+``index_map``, a grid step a table entry. A spec model's
+(:func:`_kv_walk_kernel`, PR 43) takes a grid step a ROW and walks the
+row's live pages itself, as the latent kernel below does since PR 40: both
+slabs stay in HBM, the kernel reads each row's page count from the
+scalar-prefetched lengths and copies the K and V pages into a ring of VMEM
+slots (as many as fit ``_KV_RING_BYTES``: six of a Falcon-H1 page, three of
+an Olmo-Hybrid one), waiting only for the page it is about to use. The
+call's live pages are ONE stream of copies across its rows: while a row's
+last pages are computed the next row's first are already in flight, so a
+row costs its flush and not a copy's latency. A table entry past the row's
+length costs nothing at all, where the grid pays a step that does nothing
+(~0.2 us: four fifths of the steps at the chat cells' fill), and a live
+page's copy runs beside the pages before it instead of in front of its own
+arithmetic. The pages meet the online softmax in the same order, and head
+by head with the same arithmetic, so there the walk's output is the grid
+form's bit for bit (``tools/attn_page_step.py`` keeps the grid form of the
+flat slab as the yardstick; tests/test_paged_attention.py compares them).
+
+Softmax is the online (flash) form: running max ``m``, normalizer ``l`` and
+the f32 accumulator live in VMEM scratch across a row's pages; each page
+rescales the accumulator by ``exp(m_old − m_new)``. Reduction order
 therefore differs from the dense softmax by float associativity (logits
 agree to ~ulp); greedy argmax is unaffected, which is the serving
 bit-identity contract (tests/test_paged_attention.py drives it).
 
 Per-row ``lengths`` masks the tail: inside a row's last live page,
 positions ``>= lengths[b]`` score −1e30 exactly as the gather path masks
-them. A page wholly past a row's length is SKIPPED: its grid step runs
-neither einsum nor the softmax update (``pl.when``), and the index map names
-the row's last live page again, so the pipeline starts no copy for it and
-never reads the table entry. The numbers are those of masking it (it would
-contribute ``exp(−1e30 − m) = 0`` and rescale by ``exp(0) = 1``), except
-that the output no longer depends on what such a page holds: a NaN or Inf
-there used to turn ``0 × Inf`` into NaN. The first page a row visits always
-has a live position (lengths are clamped ≥ 1, mirroring the decode path's
-position clamp), so the running max is finite at the flush. The grid stays
-(B, W): an engine that pads to ``max_batch`` rows over a bucket's whole
-table pays one scalar compare for each step it skips. Dummy rows (all-zero
-block tables, the free/prefilling-slot contract) attend one masked-harmless
-position of the sacrificial page 0, in one live step.
+them. A page wholly past a row's length is never read: the walk does not
+visit it; on the grid its step runs neither einsum nor the softmax update
+(``pl.when``), and the index map names the row's last live page again, so
+the pipeline starts no copy for it and never reads the table entry. The
+numbers are those of masking it (it would contribute ``exp(−1e30 − m) = 0``
+and rescale by ``exp(0) = 1``), except that the output no longer depends on
+what such a page holds: a NaN or Inf there used to turn ``0 × Inf`` into
+NaN. The first page a row visits always has a live position (lengths are
+clamped ≥ 1, mirroring the decode path's position clamp), so the running
+max is finite at the flush. An engine that pads to ``max_batch`` rows over a
+bucket's whole table pays the dense grid one scalar compare for each step
+it skips. Dummy rows (all-zero block tables, the free/prefilling-slot
+contract) attend one masked-harmless position of the sacrificial page 0: one
+live page.
 
 **The latent variant** (:func:`paged_decode_attention_latent`): a latent
 layer's page is ONE array ``(num_pages, page_len, entry)`` shared by every
@@ -146,10 +171,9 @@ def _paged_attn_kernel(*refs, page_len: int, windowed: bool = False):
     the length. A step whose page starts at or past ``lengths[b]`` skips the
     body; the first page visited holds a live position (position 0, or
     ``lower[b]``), so the running max is finite and ``l`` positive at the
-    flush. The page block's RANK picks the body: ``(1, page_len, kvh, dh)``
-    meets all heads in one batched einsum (:func:`_page_all_heads`), ``(1,
-    page_len, kvh * dh)`` one head's lane slice at a time
-    (:func:`_page_head_by_head`)."""
+    flush. The dense model's kernel: a page block ``(1, page_len, kvh, dh)``
+    meets all heads in one batched einsum (:func:`_page_all_heads`); a spec
+    model's flat slab goes to :func:`_kv_walk_kernel`."""
     if windowed:
         (tables_ref, lengths_ref, first_ref, lower_ref, q_ref, k_ref, v_ref,
          o_ref, acc_ref, m_ref, l_ref) = refs
@@ -181,14 +205,11 @@ def _paged_attn_kernel(*refs, page_len: int, windowed: bool = False):
             live = at < lengths_ref[b]
             return live & (at >= lower_ref[b]) if windowed else live
 
-        body = _page_all_heads if len(k_ref.shape) == 4 else _page_head_by_head
-        body(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, keep)
+        _page_all_heads(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, keep)
 
     @pl.when(w == pl.num_programs(1) - 1)
     def _flush():
-        l = l_ref[:]
-        o_ref[0] = (acc_ref[:] / (l[:, :, None] if l.ndim == 2 else l)
-                    ).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[:] / l_ref[:][:, :, None]).astype(o_ref.dtype)
 
 
 def _page_all_heads(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, keep):
@@ -217,24 +238,25 @@ def _page_all_heads(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, keep):
     acc_ref[:] = acc_ref[:] * alpha[:, :, None] + pv
 
 
-def _page_head_by_head(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, keep):
-    """One live page of a ``(page_len, kvh * dh)`` block: head ``h``'s keys
-    are the lane slice ``[:, h * dh:(h + 1) * dh]``, a ``(page_len, dh)``
-    matrix of whole tiles at ``dh`` 128, and each head's group of query rows
-    meets it in a plain matmul over the minor dimensions (the latent
-    kernel's form). The same scores, mask, online softmax and cast as
-    :func:`_page_all_heads`, a head at a time (unrolled: ``kvh`` is 4-8);
-    scratch ``m``/``l`` are (kvh, group, 1). Read from the other layout, one
-    head's keys are one sublane out of each of ``page_len`` tiles, and the
-    relayout costs several times the page's bytes (PERF.md, PR 38)."""
+def _page_head_by_head(q_ref, k_page, v_page, acc_ref, m_ref, l_ref, live):
+    """One live page ``(page_len, kvh * dh)`` of a spec model (``k_page`` /
+    ``v_page``: refs of that shape): head ``h``'s keys are the lane slice
+    ``[:, h * dh:(h + 1) * dh]``, a ``(page_len, dh)`` matrix of whole tiles
+    at ``dh`` 128, and each head's group of query rows meets it in a plain
+    matmul over the minor dimensions (the latent kernel's form). The same
+    scores, mask (``live``: (group, page_len), true where the row attends
+    the position), online softmax and cast as :func:`_page_all_heads`, a
+    head at a time (unrolled: ``kvh`` is 4-30); scratch ``m``/``l`` are (kvh,
+    group, 1). Read from the other layout, one head's keys are one sublane
+    out of each of ``page_len`` tiles, and the relayout costs several times
+    the page's bytes (PERF.md, PR 38)."""
     kvh, group, dh = q_ref.shape[1:]
     nt = (((1,), (1,)), ((), ()))      # contract both minor dimensions
-    live = keep((group, k_ref.shape[1]), 1)
     for h in range(kvh):
         q = q_ref[0, h]                            # (group, dh)
         cols = slice(h * dh, (h + 1) * dh)
         s = jax.lax.dot_general(
-            q, k_ref[0, :, cols], nt,
+            q, k_page[:, cols], nt,
             preferred_element_type=jnp.float32) / math.sqrt(dh)
         s = jnp.where(live, s, _MASKED)
         m_prev = m_ref[h]                          # (group, 1)
@@ -244,26 +266,248 @@ def _page_head_by_head(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, keep):
         p = jnp.exp(s - m_new)
         l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=1, keepdims=True)
         acc_ref[h] = acc_ref[h] * alpha + jnp.dot(
-            p.astype(q.dtype), v_ref[0, :, cols],
+            p.astype(q.dtype), v_page[:, cols],
             preferred_element_type=jnp.float32)
 
 
+def _page_one_row_a_head(qbd_ref, k_page, v_page, acc_ref, m_ref, l_ref, live,
+                         dh: int):
+    """One live page of a spec model with ONE query row a KV head (group 1:
+    Olmo-Hybrid's 30 heads): every head in one matmul. ``qbd_ref`` (R, kvh *
+    dh), R the heads padded to whole sublane tiles, holds head ``h``'s query
+    in row ``h`` at lanes ``[h * dh, (h + 1) * dh)`` and zeros elsewhere, so
+    ``qbd @ k_page^T`` is (R, page_len) with row ``h`` the scores of head
+    ``h`` (the other heads' lanes add exact zeros); one online softmax over
+    the R rows; ``p @ v_page`` is (R, kvh * dh), of which row ``h`` holds
+    head ``h``'s values at ITS lanes (elsewhere another head's values under
+    this head's probabilities, never read): the accumulator keeps all of it
+    and the flush reads the diagonal blocks. Head by head the same page is
+    30 chains of matmul, softmax, matmul of one useful row, each waiting
+    for its own results: 7.95 us a page alone on the chip, against 1.5 us
+    this way and 5.2 us for the page's copy (``tools/attn_page_step.py``,
+    PERF.md PR 43). The same products, mask, online softmax and cast as
+    :func:`_page_head_by_head`; scratch ``m``/``l`` are (R, 1), ``acc`` (R,
+    kvh * dh)."""
+    nt = (((1,), (1,)), ((), ()))      # contract both minor dimensions
+    s = jax.lax.dot_general(qbd_ref[...], k_page[...], nt,
+                            preferred_element_type=jnp.float32) / math.sqrt(dh)
+    s = jnp.where(live, s, _MASKED)                # live: (1, page_len)
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    m_ref[...] = m_new
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)
+    l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+        p.astype(qbd_ref.dtype), v_page[...],
+        preferred_element_type=jnp.float32)
+
+
+def _block_diagonal_query(q_ref, qbd_ref, dh: int):
+    """``qbd_ref`` (R, kvh * dh) from ``q_ref`` (1, 1, kvh * dh), the heads'
+    one query row each side by side: row ``h`` keeps head ``h``'s lanes, the
+    rest is zero (:func:`_page_one_row_a_head`)."""
+    row = jax.lax.broadcasted_iota(jnp.int32, qbd_ref.shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, qbd_ref.shape, 1)
+    qbd_ref[...] = jnp.where(
+        col // dh == row,
+        jnp.broadcast_to(q_ref[0].astype(jnp.float32), qbd_ref.shape),
+        0.0).astype(qbd_ref.dtype)
+
+
+# page slots of the (K, V) walk: as many as _KV_RING_BYTES hold of one page's
+# keys AND values, at least the two that let one copy run beside the
+# arithmetic, at most _KV_MAX_SLOTS (a Falcon-H1 page is 2 x 262 KB and
+# wants several copies in flight: one takes ~0.5 us from start to done; an
+# Olmo-Hybrid page is 2 x 1.97 MB, its copy 5 us, and three slots cover it)
+_KV_RING_BYTES = 12 << 20
+_KV_MAX_SLOTS = 6
+
+
+def _kv_slots(slab) -> int:
+    pair = 2 * math.prod(slab.shape[1:]) * slab.dtype.itemsize
+    return int(min(_KV_MAX_SLOTS, max(2, _KV_RING_BYTES // pair)))
+
+
+def _kv_walk_kernel(*refs, page_len: int, windowed: bool):
+    """Grid (B,): one step a row of a spec model's flat slab, and the kernel
+    walks the row's LIVE pages itself, as :func:`_latent_attn_kernel` does.
+    ``k_hbm`` / ``v_hbm`` are the whole slabs, left in HBM. The call's live
+    pages are ONE stream (row 0's, then row 1's ...): stream entry ``g``
+    lands in slot ``g % slots`` of ``k_buf`` / ``v_buf`` (slots, page_len,
+    kvh * dh), a DMA semaphore a slot and array, and consuming entry ``g``
+    starts the copy of entry ``g + slots - 1``, whichever row holds it: a
+    row's first pages are in flight while the row before it is computed (a
+    row that starts its own costs 1.5-6.7 us where this costs 0.5-1.0;
+    PERF.md PR 43). ``cur`` (SMEM, kept across grid steps) is the cursor of
+    the copies: the row and page of the next entry to start and the count
+    started; ``used`` the count consumed. Plain: a row's pages are ``0 .. n
+    - 1``, ``n`` from its length. ``windowed``: pages ``first_page[b] ..``
+    the page of position ``lengths[b] - 1``, page ``p`` in table slot ``p %
+    W``, positions below ``lower[b]`` masked. No step of any kind for a
+    table entry past a row's length; a row's first page holds a live
+    position (position 0, or ``lower[b]``), so ``l`` is positive at the
+    flush.
+
+    The arithmetic of a page goes by the query rows a head has, which the
+    shapes show: several (GQA) meet :func:`_page_head_by_head`, pages in
+    order, the arithmetic of the grid-a-page kernel this replaced
+    (``tools/attn_page_step.py`` keeps that one as the yardstick): the
+    output is that kernel's bit for bit. ONE row a head meets
+    :func:`_page_one_row_a_head`, all heads in one matmul, from the
+    block-diagonal query ``qbd_ref`` built here once a row."""
+    n = 4 if windowed else 2               # scalar-prefetched arrays
+    (tables_ref, lengths_ref, *window), refs = refs[:n], refs[n:]
+    first_ref, lower_ref = window or (None, None)
+    (q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, cur, used, acc_ref, m_ref,
+     l_ref, *qbd) = refs
+    b = pl.program_id(0)
+    rows = pl.num_programs(0)
+    W = tables_ref.shape[1]
+    slots = k_buf.shape[0]
+    kvh, group, dh = o_ref.shape[1:]
+
+    def first_of(r):
+        return first_ref[r] if windowed else 0
+
+    def pages_of(r):  # live pages of row r; >= 1 by the clamp
+        return (lengths_ref[r] - 1) // page_len - first_of(r) + 1
+
+    def copies(pid, g):
+        slot = jax.lax.rem(g, slots)
+        return [pltpu.make_async_copy(hbm.at[pid], buf.at[slot],
+                                      sem.at[a, slot])
+                for a, (hbm, buf) in enumerate(((k_hbm, k_buf),
+                                                (v_hbm, v_buf)))]
+
+    def start_next():
+        r, p, g = cur[0], cur[1], cur[2]
+
+        @pl.when(r < rows)
+        def _start():
+            page = first_of(r) + p
+            pid = tables_ref[r, jax.lax.rem(page, W) if windowed else page]
+            for copy in copies(pid, g):
+                copy.start()
+            last = p + 1 >= pages_of(r)
+            cur[0] = jnp.where(last, r + 1, r)
+            cur[1] = jnp.where(last, 0, p + 1)
+            cur[2] = g + 1
+
+    @pl.when(b == 0)
+    def _first_pages():
+        cur[0] = cur[1] = cur[2] = 0
+        used[0] = 0
+        for _ in range(slots - 1):
+            start_next()
+
+    if qbd:
+        (qbd_ref,) = qbd
+        _block_diagonal_query(q_ref, qbd_ref, dh)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, _MASKED)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    length = lengths_ref[b]
+    base = used[0]
+
+    @pl.loop(0, pages_of(b))
+    def _live_page(i):
+        # into the slot of the entry before this one, whose reader has run
+        start_next()
+        for copy in copies(0, base + i):
+            copy.wait()
+        # absolute position of column t is page * page_len + t; live iff
+        # < length (and, for a window, >= lower)
+        at = (first_of(b) + i) * page_len + jax.lax.broadcasted_iota(
+            jnp.int32, (group, page_len), 1)
+        live = at < length
+        if windowed:
+            live &= at >= lower_ref[b]
+        slot = jax.lax.rem(base + i, slots)
+        if qbd:
+            _page_one_row_a_head(qbd_ref, k_buf.at[slot], v_buf.at[slot],
+                                 acc_ref, m_ref, l_ref, live, dh)
+        else:
+            _page_head_by_head(q_ref, k_buf.at[slot], v_buf.at[slot], acc_ref,
+                               m_ref, l_ref, live)
+
+    used[0] = base + pages_of(b)
+    if qbd:
+        for h in range(kvh):
+            o_ref[0, h] = (acc_ref[h:h + 1, h * dh:(h + 1) * dh]
+                           / l_ref[h:h + 1, :]).astype(o_ref.dtype)
+    else:
+        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+def _kv_walk_call(q, k_pages, v_pages, tables, lengths, window=(), *,
+                  interpret: bool, slots: int | None = None):
+    """The walk over a flat slab ``(num_pages, page_len, kvh * dh)``;
+    ``window`` is ``(first_page, lower)`` or empty. The ring is
+    :func:`_kv_slots`'s (``slots``: the tool's and the tests' to vary) and
+    the compiler is asked for the VMEM it needs."""
+    B, kvh, group, dh = q.shape
+    page_len, width = k_pages.shape[1:]
+    slots = slots or _kv_slots(k_pages)
+    ring = 2 * slots * page_len * width * k_pages.dtype.itemsize
+    row_spec = pl.BlockSpec((1, kvh, group, dh), lambda b, *_: (b, 0, 0, 0))
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    if group == 1:
+        heads = -(-kvh // 16) * 16      # whole sublane tiles in either dtype
+        q = q.reshape(B, 1, width)
+        q_spec = pl.BlockSpec((1, 1, width), lambda b, *_: (b, 0, 0))
+        state = [pltpu.VMEM((heads, width), jnp.float32),  # accumulator
+                 pltpu.VMEM((heads, 1), jnp.float32),      # running max m
+                 pltpu.VMEM((heads, 1), jnp.float32),      # normalizer l
+                 pltpu.VMEM((heads, width), q.dtype)]      # block-diagonal q
+    else:
+        q_spec = row_spec
+        state = [pltpu.VMEM((kvh, group, dh), jnp.float32),
+                 pltpu.VMEM((kvh, group, 1), jnp.float32),
+                 pltpu.VMEM((kvh, group, 1), jnp.float32)]
+    return pl.pallas_call(
+        functools.partial(_kv_walk_kernel, page_len=page_len,
+                          windowed=bool(window)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2 + len(window),
+            grid=(B,),
+            in_specs=[q_spec, in_hbm, in_hbm],
+            out_specs=row_spec,
+            scratch_shapes=[
+                pltpu.VMEM((slots, page_len, width), k_pages.dtype),
+                pltpu.VMEM((slots, page_len, width), v_pages.dtype),
+                pltpu.SemaphoreType.DMA((2, slots)),
+                pltpu.SMEM((3,), jnp.int32),    # cur: row, page, started
+                pltpu.SMEM((1,), jnp.int32),    # used
+                *state,
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, kvh, group, dh), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=max(16 << 20, ring + (4 << 20))),
+        interpret=interpret,
+    )(tables, lengths, *window, q, k_pages, v_pages)
+
+
 def _page_block(slab, q):
-    """The block of one page of ``slab`` and the scratch of a row's online
-    softmax, by the slab's rank (see :func:`_paged_attn_kernel`): ``(block
+    """The block of one page of the dense model's ``slab`` and the scratch
+    of a row's online softmax (see :func:`_paged_attn_kernel`): ``(block
     shape, zeros that follow the page id in its block index, scratch)``."""
     _, kvh, group, dh = q.shape
-    stat = (kvh, group) if slab.ndim == 4 else (kvh, group, 1)
     return (1,) + slab.shape[1:], (0,) * (slab.ndim - 1), [
         pltpu.VMEM((kvh, group, dh), jnp.float32),  # accumulator
-        pltpu.VMEM(stat, jnp.float32),              # running max m
-        pltpu.VMEM(stat, jnp.float32),              # normalizer l
+        pltpu.VMEM((kvh, group), jnp.float32),      # running max m
+        pltpu.VMEM((kvh, group), jnp.float32),      # normalizer l
     ]
 
 
 @functools.partial(jax.jit, static_argnames=("page_len", "interpret"))
 def _paged_decode_attention_call(q, k_pages, v_pages, tables, lengths,
                                  page_len: int, interpret: bool):
+    if k_pages.ndim == 3:
+        return _kv_walk_call(q, k_pages, v_pages, tables, lengths,
+                             interpret=interpret)
     B, kvh, group, dh = q.shape
     W = tables.shape[1]
     kernel = functools.partial(_paged_attn_kernel, page_len=page_len)
@@ -301,6 +545,9 @@ def _paged_decode_attention_window_call(q, k_pages, v_pages, tables, lengths,
     which position ``p`` lives in slot ``(p // page_len) % W``; the grid
     visits the W pages from ``first_page[b]`` on, in position order. Its own
     jitted name, so a trace tells the two attention kinds apart."""
+    if k_pages.ndim == 3:
+        return _kv_walk_call(q, k_pages, v_pages, tables, lengths,
+                             (first_page, lower), interpret=interpret)
     B, kvh, group, dh = q.shape
     W = tables.shape[1]
     kernel = functools.partial(_paged_attn_kernel, page_len=page_len,

@@ -694,28 +694,40 @@ def test_paged_window_kernel_mosaic_compiles_at_laguna_widths(one_chip,
                                 window=group == 9)
 
 
-# the three (K, V) decode calls the spec cells make, page_len 256: rows,
+# the four (K, V) decode calls the spec cells make, page_len 256: rows,
 # KV heads, group, slab pages, table width, window
 _CELL_CALLS = {
     "laguna-full-g6": (32, 8, 6, 769, 32, False),
     "laguna-sliding-g9": (32, 8, 9, 145, 4, True),
     "falconh1-g5": (64, 4, 5, 641, 20, False),
+    "olmohybrid-g1": (24, 30, 1, 320, 15, False),
 }
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
 @pytest.mark.parametrize("call", list(_CELL_CALLS))
 def test_paged_kernels_mosaic_compile_at_the_laguna_cells_shapes(one_chip,
-                                                                 call):
+                                                                 call, dtype):
     """Both variants as ``serve.laguna-longtail32`` runs them (page_len
     256): the plain call over the widest bucket's 32 pages of the 769-page
-    slab, the window call over a ring of 4 pages of the 145-page slab; and
-    ``serve.falconh1-chat64``'s call, 64 rows of 4 KV heads over 20 pages.
-    Their index maps clamp a scalar-prefetched length, which is what Mosaic
-    has to accept, and each head's keys are a lane slice of the page's
-    ``(256, kv_heads * 128)`` block."""
+    slab, the window call over a ring of 4 pages of the 145-page slab;
+    ``serve.falconh1-chat64``'s call, 64 rows of 4 KV heads over 20 pages;
+    and ``serve.olmohybrid-sessions24``'s, 24 rows of 30 KV heads, one query
+    row a head, over 15 pages. Since PR 43 each is the walk: the slabs stay
+    in HBM (no temporary: :func:`_compile_spec_decode_kernel`), the kernel
+    copies a row's live pages into its ring of slots, each head's keys a
+    lane slice of a slot's ``(256, kv_heads * 128)``; in bfloat16 and at
+    the float32 checks' pages, inside the VMEM the kernel asks for (the
+    ring and 4 MiB, 16 MiB at least: under a fifth of the chip's 128)."""
+    from marlin_tpu.ops.paged_attention import _kv_slots
+
     rows, kvh, group, pages, width, window = _CELL_CALLS[call]
     _compile_spec_decode_kernel(one_chip, group, 256, pages, width, rows=rows,
-                                kvh=kvh, window=window)
+                                kvh=kvh, window=window, dtype=dtype)
+    slab = jax.ShapeDtypeStruct((pages, 256, kvh * 128), dtype)
+    ring = 2 * _kv_slots(slab) * 256 * kvh * 128 * jnp.dtype(dtype).itemsize
+    assert ring + (4 << 20) <= 24 << 20
 
 
 def test_float32_pages_of_256_fit_scoped_vmem_only_head_by_head(one_chip):

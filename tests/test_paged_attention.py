@@ -67,9 +67,11 @@ def _by_head(pages, kvh, dh):
 
 # (kv heads, group, head dim, pages held (page_len, kvh * dh)): the dense
 # model's slab, and a spec model's at the three calls the cells make
-# (Falcon-H1's global layers, Laguna's full and sliding layers)
+# (Falcon-H1's global layers, Laguna's full and sliding layers,
+# Olmo-Hybrid's full layers)
 _HEADS = {"dense-2x2": (2, 2, 8, False), "falconh1-4x5": (4, 5, 128, True),
-          "laguna-8x6": (8, 6, 128, True), "laguna-8x9": (8, 9, 128, True)}
+          "laguna-8x6": (8, 6, 128, True), "laguna-8x9": (8, 9, 128, True),
+          "olmohybrid-30x1": (30, 1, 128, True)}
 both_layouts = pytest.mark.parametrize("heads",
                                        ["dense-2x2", "falconh1-4x5"])
 
@@ -248,6 +250,7 @@ _DEAD_PAGE_CASES = {
 _SPEC_CASES = [
     ("falconh1-4x5", "global", ["one", "page", "ragged"]),
     ("laguna-8x6", "global", ["page+1", "full-table"]),
+    ("olmohybrid-30x1", "global", ["one", "ragged", "full-table"]),
     ("laguna-8x9", "window", ["one", "page+1", "full-ring",
                               "lower-on-boundary", "wrapped-ring"]),
 ]
@@ -323,6 +326,149 @@ def test_the_two_page_layouts_hold_the_same_numbers():
                                          interpret=True, **window)
         np.testing.assert_allclose(np.asarray(flat), np.asarray(by_head),
                                    atol=2e-6, rtol=2e-6)
+
+
+# ------------------------------------- the walk against the grid-a-page body
+
+_RING = 8  # table width of the walk's cases: more pages than the ring's slots
+
+# last attended position of each of the four rows (a table of _RING pages;
+# the walk holds six slots at these page sizes), and for a window its size
+_WALK_CASES = {
+    "global": {
+        "one": [0, 0, 0, 0],
+        # a length on a page edge, one past it, the whole table
+        "page-edge": [PAGE_LEN - 1, 2 * PAGE_LEN - 1, PAGE_LEN,
+                      _RING * PAGE_LEN - 1],
+        # fewer live pages than slots, as many, one more, the table
+        "few-and-many": [3 * PAGE_LEN - 3, 6 * PAGE_LEN - 1, 6 * PAGE_LEN,
+                         _RING * PAGE_LEN - 2],
+        # rows 1 and 3 are dummy rows: an all-zero table, length 1
+        "dummy-rows": [5 * PAGE_LEN + 2, 0, 7 * PAGE_LEN, 0],
+    },
+    "window": {
+        "one": [0, 0, 0, 0],
+        # the live pages straddle the ring's end (slots 6, 7, 0, 1 ...)
+        "ring-wraps": [9 * PAGE_LEN + 3, 12 * PAGE_LEN, 15 * PAGE_LEN + 7,
+                       22 * PAGE_LEN + 1],
+        # lower falls inside the first page visited; row 3 has no lower yet
+        "lower-inside-first-page": [7 * PAGE_LEN + 3, 8 * PAGE_LEN + 5,
+                                    20 * PAGE_LEN - 2, 2 * PAGE_LEN + 1],
+    },
+}
+_WALK_WINDOW = 7 * PAGE_LEN - 2  # positions a windowed row attends
+
+
+def _walk_case(variant, case, heads, dtype, seed=23):
+    """Arrays of one of ``_WALK_CASES``, and the pages no row attends."""
+    rng = np.random.default_rng(seed)
+    q, kp, vp, tables = _random_case(rng, heads=heads, W=_RING,
+                                     num_pages=4 * _RING + 1)
+    pos = np.array(_WALK_CASES[variant][case], np.int32)
+    lengths = pos + 1
+    window = {}
+    first = np.zeros_like(pos)
+    if variant == "window":
+        lower = np.maximum(pos - _WALK_WINDOW + 1, 0).astype(np.int32)
+        first = lower // PAGE_LEN
+        window = dict(first_page=first, lower=lower)
+    if case == "dummy-rows":
+        tables[[1, 3]] = 0
+    visited = first[:, None] + np.arange(_RING)[None, :]
+    dead = visited * PAGE_LEN >= lengths[:, None]
+    dead_slots = np.zeros_like(dead)
+    np.put_along_axis(dead_slots, visited % _RING, dead, axis=1)
+    dead_pages = np.setdiff1d(tables[dead_slots], tables[~dead_slots])
+    cast = lambda a: jnp.asarray(a, dtype)
+    return cast(q), kp, vp, tables, lengths, window, dead_pages, cast
+
+
+@pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize(
+    "variant,case,heads",
+    [("global", c, h) for h in ("olmohybrid-30x1", "falconh1-4x5",
+                                "laguna-8x6")
+     for c in _WALK_CASES["global"]]
+    + [("window", c, "laguna-8x9") for c in _WALK_CASES["window"]])
+def test_the_walk_is_the_grid_a_page_body_bit_for_bit(variant, case, heads,
+                                                      dtype):
+    """A spec model's flat slab goes to the kernel that takes a grid step a
+    row and walks the row's live pages through a ring of slots; the kernel
+    it replaced (grid (rows, table width), a page a grid step: kept in
+    ``tools/attn_page_step.py`` as the yardstick) saw the same pages in the
+    same order, and where a head has several query rows (groups of 5, 6 and
+    9) with the same arithmetic, so the outputs are EQUAL, in both dtypes:
+    rows of length 1, lengths on a page edge, fewer live pages than slots
+    and more, dummy rows, a ring that wraps, ``lower`` inside the first
+    page. With ONE query row a head (30 heads) the walk contracts every head
+    in one matmul: the same products summed in another order, equal to a
+    few float32 ulps (one bfloat16 ulp of the output at most). And a NaN in
+    a page past a row's length does not reach the output."""
+    from tools.attn_page_step import _grid_kv_call
+
+    q, kp, vp, tables, lengths, window, dead_pages, cast = _walk_case(
+        variant, case, heads, dtype)
+    got = np.asarray(paged_decode_attention(
+        q, cast(kp), cast(vp), tables, lengths, interpret=True,
+        **window).astype(jnp.float32))
+    want = np.asarray(_grid_kv_call()(
+        q, cast(kp), cast(vp), jnp.asarray(tables), jnp.asarray(lengths),
+        *window.values()).astype(jnp.float32))
+    if _HEADS[heads][1] > 1:
+        np.testing.assert_array_equal(got, want)
+    else:
+        tol = 2e-6 if dtype is np.float32 else 2 ** -7
+        np.testing.assert_allclose(got, want, atol=tol, rtol=tol)
+    assert np.isfinite(got).all()
+    kx, vx = kp.copy(), vp.copy()
+    kx[dead_pages] = vx[dead_pages] = np.nan
+    if case != "dummy-rows":  # no row's live page is the pool's page 0
+        kx[0] = vx[0] = np.nan
+    again = paged_decode_attention(q, cast(kx), cast(vx), tables, lengths,
+                                   interpret=True, **window)
+    np.testing.assert_array_equal(np.asarray(again.astype(jnp.float32)), got)
+
+
+@pytest.mark.parametrize("slots", [2, 3])
+@pytest.mark.parametrize("variant,case", [("global", "few-and-many"),
+                                          ("window", "ring-wraps")])
+def test_a_ring_smaller_than_a_rows_pages_is_reused_in_order(variant, case,
+                                                             slots):
+    """The ring's size is the kernel's own business (``_kv_slots``: what
+    ``_KV_RING_BYTES`` hold of a page's keys and values, two at least): with
+    two or three slots under rows of up to eight live pages every slot is
+    written again behind its last reader, and the output is still the grid
+    body's."""
+    from marlin_tpu.ops.paged_attention import _kv_walk_call
+    from tools.attn_page_step import _grid_kv_call
+
+    heads = "laguna-8x9" if variant == "window" else "falconh1-4x5"
+    q, kp, vp, tables, lengths, window, _, cast = _walk_case(
+        variant, case, heads, jnp.bfloat16)
+    args = (q, cast(kp), cast(vp), jnp.asarray(tables), jnp.asarray(lengths))
+    win = tuple(jnp.asarray(w) for w in window.values())
+    got = _kv_walk_call(*args, win, interpret=True, slots=slots)
+    np.testing.assert_array_equal(
+        np.asarray(got.astype(jnp.float32)),
+        np.asarray(_grid_kv_call()(*args, *win).astype(jnp.float32)))
+
+
+def test_the_ring_takes_its_slots_from_a_pages_bytes():
+    """Six slots where a page is small (Falcon-H1's 2 x 262 KB, Laguna's 2 x
+    524 KB: several copies in flight cover a copy's latency), three at
+    Olmo-Hybrid's 2 x 1.97 MB (six would not fit the VMEM a kernel may
+    ask for by default), two at its float32 pages."""
+    from marlin_tpu.ops.paged_attention import _kv_slots
+
+    def slab(kvh, dtype):
+        return jax.ShapeDtypeStruct((2, 256, kvh * 128), dtype)
+
+    assert _kv_slots(slab(4, jnp.bfloat16)) == 6
+    assert _kv_slots(slab(8, jnp.bfloat16)) == 6
+    assert _kv_slots(slab(8, jnp.float32)) == 6
+    assert _kv_slots(slab(30, jnp.bfloat16)) == 3
+    assert _kv_slots(slab(30, jnp.float32)) == 2
 
 
 # ------------------------------------------- backend bit-identity grid

@@ -176,6 +176,11 @@ def test_supervisor_breaker_opens_after_restart_budget(params):
         assert sup.restart_count == 2     # the budget, then the breaker
         assert all(s in (STATUS_ERROR, STATUS_SHUTTING_DOWN)
                    for s in statuses), statuses
+        # close() retires the queue (the handles resolve) BEFORE it joins the
+        # worker and marks the engine closed: give that a moment
+        deadline = time.monotonic() + 5.0
+        while eng._state != "closed" and time.monotonic() < deadline:
+            time.sleep(0.01)
         assert eng._state == "closed"
         # post-breaker submissions resolve deterministically too
         r = eng.submit(Request(prompt=[1], steps=1)).result(timeout=5)

@@ -2,43 +2,49 @@
 
     chiprun --chips 1 -- python3 tools/attn_page_step.py [--f32]
 
-**The (K, V) kernel.** At the three calls the spec cells make (Falcon-H1's
-global call, Laguna's global and window calls) it times
-``paged_decode_attention`` over the SAME random pages held both ways:
-``(num_pages, page_len, kv_heads, head_dim)`` (the dense model's layout:
-every head in one einsum batched over the block's middle axis) and
-``(num_pages, page_len, kv_heads * head_dim)`` (a spec model's: a head's keys
-a lane slice, contracted head by head). Two calls a layout, one at the cell's
-lengths and one with every row at length 1, give the two unknowns:
-microseconds a LIVE grid step and a DEAD one.
+Every line times eight calls chained in ONE program (each call's lengths read
+off the previous call's output): a call of 50-600 us dispatched from the host
+reads the host. Two chains a line, one at the cell's lengths and one with
+every row at length 1, give two unknowns.
+
+**The (K, V) kernel** at the four calls the spec cells make
+(``olmohybrid.global``, ``falconh1.global``, ``laguna.global``,
+``laguna.window``), over a flat slab ``(num_pages, page_len, kv_heads *
+head_dim)``. ``<shape>`` is the package's kernel, which since PR 43 takes a
+grid step a ROW and walks the call's live pages itself (their copies in
+flight in a ring of slots): microseconds a live page and a row (it has no
+dead step), the call at other ring sizes (``call_us_by_slots``), and the same
+walk with the arithmetic taken out (``walk_copy_only_us_a_page``: the copies'
+own rate). The others are the grid-a-page form it replaced (grid (rows, table
+width), the page a block whose index map reads the table; PR 38's body), kept
+HERE as the yardstick with the package's own :func:`_page_head_by_head`
+inside, and what its live step is made of: ``.grid`` the whole body as it
+was, microseconds a LIVE grid step and a DEAD one; ``.copy_only`` the same
+copies with the matmuls taken out; ``.compute_only`` the same matmuls with
+the index map frozen on one page, so that no copy is issued after the first
+(``.scores_only`` / ``.values_only``: that arithmetic without its value
+matmul, and the value matmul alone; ``.all_heads.compute_only``: under the
+same frozen grid the package's OTHER page body, every head in one matmul
+from a block-diagonal query, which the package gives a model with ONE query
+row a head and this line tries at any group, ``max_abs_diff_from_grid``
+beside it). A step that reads ``copy_only + compute_only`` (less
+one dead step, which both hold) is not overlapping them; one that reads the
+larger of the two is. ``max_abs_diff_from_grid`` is the package's output
+against the grid form's (0.0: bit-equal).
 
 **The latent kernel** (shape ``mistral4.latent``: the Mistral cell's decode
 call, 32 rows of 65-70 pages, 32 heads against an entry of 320 values stored
-384 wide). Four lines, the same two calls each. ``mistral4.latent`` is the
-package's kernel, which walks a row's live pages itself (the copies of the
-pages ahead in flight, the next page's scores computed beside this page's
-softmax and values): microseconds a live page and a ROW (it has no dead
-step). The other three are the grid-a-page form that PR 40 took
-out of the package (grid (rows, table width), the page a block whose index
-map reads the table), kept HERE as the yardstick, and what its live step is
-made of: ``.grid`` the whole body, as it was; ``.copy_only``
-the same copies with the matmuls taken out (a page's bytes through the
-pipeline, and the step's own cost); ``.compute_only`` the same matmuls with
-the index map frozen on one page, so that no copy is issued after the first.
-A step that reads ``copy_only + compute_only`` is not overlapping them; one
-that reads the larger of the two is (the grid form read in between: 0.62 us
-for 0.35 and 0.52, PERF.md PR 40). ``max_abs_diff_from_grid`` is the
-package's output against the grid form's (0.0: bit-equal). The latent lines
-time eight calls chained in one program: a call of 50 us dispatched from
-the host reads the host.
+384 wide): the same four lines (PR 40; the grid form read 0.62 us for 0.35
+and 0.52).
 
-Prints one JSON line a shape (four for the latent shape) and ends with
-``{"ok": true, "device": ...}``; needs a TPU (a time from the CPU's
-interpreter says nothing). No engine, no model."""
+Prints one JSON line a body and ends with ``{"ok": true, "device": ...}``;
+needs a TPU (a time from the CPU's interpreter says nothing). No engine, no
+model."""
 
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import pathlib
 import sys
@@ -46,13 +52,22 @@ import time
 
 import numpy as np
 
-# (rows, table width, kv heads, group, pages of the slab, live pages a call,
-# window): the cells' decode calls as PERF.md section 5 reads them
+# the cells' (K, V) decode calls as PERF.md section 5 reads them: rows, table
+# width, kv heads, group, pages of the slab, live pages a call (``tokens``:
+# the least and most a row holds, where the cell's rows are all long), window
 SHAPES = {
-    "falconh1.global": (64, 20, 4, 5, 641, 250, False),
-    "laguna.global": (32, 32, 8, 6, 769, 190, False),
-    "laguna.window": (32, 4, 8, 9, 145, 85, True),
+    "olmohybrid.global": dict(rows=24, width=15, kvh=30, group=1, pages=320,
+                              tokens=(2100, 3700)),
+    "falconh1.global": dict(rows=64, width=20, kvh=4, group=5, pages=641,
+                            live_pages=250),
+    "laguna.global": dict(rows=32, width=32, kvh=8, group=6, pages=769,
+                          live_pages=190),
+    "laguna.window": dict(rows=32, width=4, kvh=8, group=9, pages=145,
+                          window=512),
 }
+# ring sizes the walk is also timed at (the package's own: _kv_slots)
+SLOTS = {"olmohybrid.global": (2, 4), "falconh1.global": (2, 3, 4, 8),
+         "laguna.global": (2, 3, 4, 8), "laguna.window": (2, 4)}
 PAGE_LEN, HEAD_DIM = 256, 128
 # the latent call: (rows, table width, heads, entry as stored, value columns,
 # pages of the slab, (least, most) tokens a row): a 16384-token document, a
@@ -61,17 +76,20 @@ LATENT_SHAPES = {"mistral4.latent": (32, 70, 32, 384, 256, 2561,
                                      (16400, 17664))}
 
 
-def _lengths(rng, rows: int, width: int, live_pages: int,
-             window: bool) -> np.ndarray:
-    """Row lengths (in tokens) whose pages sum to about ``live_pages``: a
-    long tail like the cells' (most rows a page or two, a few nearly the
-    table), each row at least one token. Under a window a row's length is
-    its position in a long context (the ring holds the last 512 of it)."""
-    if window:
+def _lengths(rng, shape: dict) -> np.ndarray:
+    """Row lengths (in tokens) as the cell's: between ``tokens``' bounds; or
+    a long tail whose pages sum to about ``live_pages`` (most rows a page or
+    two, a few nearly the table), each row at least one token; under a
+    window a row's position in a long context (the ring holds the last 512
+    of it)."""
+    rows, width = shape["rows"], shape["width"]
+    if "window" in shape:
         return np.clip(rng.lognormal(np.log(1500.0), 1.0, rows), 1,
                        8000).astype(np.int32)
+    if "tokens" in shape:
+        return rng.integers(*shape["tokens"], rows).astype(np.int32)
     raw = rng.lognormal(0.0, 1.0, rows)
-    pages = np.clip(np.round(raw * live_pages / raw.sum()), 1, width)
+    pages = np.clip(np.round(raw * shape["live_pages"] / raw.sum()), 1, width)
     return (pages * PAGE_LEN - rng.integers(0, PAGE_LEN, rows)).astype(
         np.int32)
 
@@ -90,94 +108,351 @@ def _time_call(fn, args, calls: int = 30, repeats: int = 5) -> float:
     return best
 
 
-_CHAIN = 8  # calls of a latent body in one timed program
+_CHAIN = 8  # calls of a body in one timed program
 
 
 def _chained(fn, inner: int = _CHAIN):
-    """``inner`` calls of ``fn(q, slab, tables, lengths)`` in ONE program, each
+    """``inner`` calls of ``fn(*arrays, lengths)`` in ONE program, each
     call's lengths read off the previous call's output (they never change:
     no output is that large), so that the calls run back to back on the
-    chip. A call of 50-200 us timed from the host reads the host's dispatch
+    chip. A call of 50-600 us timed from the host reads the host's dispatch
     (~200 us a call here), not the chip."""
     import jax
     import jax.numpy as jnp
 
     @jax.jit
-    def many(q, slab, tables, lengths):
+    def many(*args):
+        *arrays, lengths = args
+
         def one(_, carry):
             lens, total = carry
-            first = fn(q, slab, tables, lens)[0, 0, 0].astype(jnp.float32)
+            first = fn(*arrays, lens).ravel()[0].astype(jnp.float32)
             return lens + (first > 1e30).astype(lens.dtype), total + first
         return jax.lax.fori_loop(0, inner, one, (lengths, jnp.float32(0)))[1]
     return many
 
 
-def measure(name: str, dtype, seed: int = 0) -> dict:
+def _time_pair(fn, arrays, cell, ones) -> tuple[float, float]:
+    """Seconds a call of ``fn`` at the cell's lengths and with every row at
+    length 1, each the chained program's time over its calls."""
+    many = _chained(fn)
+    return (_time_call(many, (*arrays, cell), calls=4) / _CHAIN,
+            _time_call(many, (*arrays, ones), calls=4) / _CHAIN)
+
+
+def _a_step(walks: bool, live: int, rows: int, width: int, t1: float,
+            t0: float) -> dict:
+    """The two unknowns of a line from its two timed chains (seconds a call
+    at the cell's lengths and with every row at length 1). A walk has no
+    dead step: t = live * a + rows * r. A grid runs rows x width steps, of
+    which ``live`` (``rows``) hold attended positions."""
+    if walks:
+        a, r = np.linalg.solve([[live, rows], [rows, rows]], [t1, t0])
+        return {"live_step_us": a * 1e6, "row_us": r * 1e6}
+    steps = rows * width
+    a, d = np.linalg.solve([[live, steps - live], [rows, steps - rows]],
+                           [t1, t0])
+    return {"live_step_us": a * 1e6, "dead_step_us": d * 1e6}
+
+
+def _window_of(lens, window: int):
+    """The ring's first page and lowest visible position, as the decode
+    program derives them from a row's position."""
+    import jax.numpy as jnp
+
+    lower = jnp.maximum(lens - window, 0)
+    return lower // PAGE_LEN, lower
+
+
+def _scores_only(q_ref, k_page, v_page, acc_ref, m_ref, l_ref, live):
+    """:func:`_page_head_by_head` without its value matmul (a lane tile of
+    the probabilities goes into the accumulator in its place)."""
+    import math
+
     import jax
     import jax.numpy as jnp
 
-    from marlin_tpu.ops.paged_attention import paged_decode_attention
+    kvh, group, dh = q_ref.shape[1:]
+    nt = (((1,), (1,)), ((), ()))
+    for h in range(kvh):
+        s = jax.lax.dot_general(
+            q_ref[0, h], k_page[:, h * dh:(h + 1) * dh], nt,
+            preferred_element_type=jnp.float32) / math.sqrt(dh)
+        s = jnp.where(live, s, -1e30)
+        m_prev = m_ref[h]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        m_ref[h] = m_new
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[h] = acc_ref[h] * alpha + p[:, :dh]
 
-    rows, width, kvh, group, pages, live_pages, window = SHAPES[name]
+
+def _values_only(q_ref, k_page, v_page, acc_ref, m_ref, l_ref, live):
+    """Only the value matmul of :func:`_page_head_by_head`, under
+    probabilities of one."""
+    import jax.numpy as jnp
+
+    kvh, group, dh = q_ref.shape[1:]
+    p = jnp.where(live, 1.0, 0.0).astype(v_page.dtype)
+    l_ref[:] = jnp.ones_like(l_ref)
+    for h in range(kvh):
+        acc_ref[h] = acc_ref[h] + jnp.dot(
+            p, v_page[:, h * dh:(h + 1) * dh],
+            preferred_element_type=jnp.float32)
+
+
+def _block_diagonal(q, rows: int):
+    """``q`` (B, kvh, group, dh) as the all-heads body's left operand (B,
+    rows, kvh * dh): query row ``(h, g)`` in row ``h * group + g`` at head
+    ``h``'s lanes, zeros elsewhere and in the padding rows."""
+    import jax.numpy as jnp
+
+    B, kvh, group, dh = q.shape
+    flat = jnp.tile(q.reshape(B, kvh * group, 1, dh),
+                    (1, 1, kvh, 1)).reshape(B, kvh * group, kvh * dh)
+    own = (jnp.arange(kvh * dh) // dh)[None, :] == (
+        jnp.arange(kvh * group) // group)[:, None]
+    return jnp.pad(jnp.where(own, flat, 0),
+                   ((0, 0), (0, rows - kvh * group), (0, 0)))
+
+
+def _grid_kv_call(mode: str = "both", body: str = "head_by_head"):
+    """The (K, V) kernel over a flat slab as the package held it until PR
+    43: grid (rows, table width), one page of K and of V a grid step through
+    ``BlockSpec`` s whose index map reads the scalar-prefetched table, the
+    package's :func:`_page_head_by_head` on each live page; called as the
+    package's jitted calls are (lengths clamped by the caller), a window's
+    ``first_page`` and ``lower`` last. ``mode`` ``both`` is that kernel;
+    ``copy`` takes the arithmetic out (a live step adds a sublane tile of
+    each block into the accumulator, so that both are read); ``compute``
+    freezes the index map on page 1. ``body``: that arithmetic's parts
+    (``scores`` / ``values``: :func:`_scores_only`, :func:`_values_only`),
+    or the package's other page body, :func:`_page_one_row_a_head`, at ANY
+    group (``all_heads``: the block-diagonal query built out here,
+    :func:`_block_diagonal`; the accumulator (rows, kvh * dh), of which the
+    flush reads each head's rows at its lanes), each under the same grid."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from marlin_tpu.ops import paged_attention as pa
+
+    page_body = {"head_by_head": pa._page_head_by_head,
+                 "scores": _scores_only, "values": _values_only}.get(body)
+
+    def kernel(*refs, page_len, windowed):
+        scalars, refs = refs[:2 + 2 * windowed], refs[2 + 2 * windowed:]
+        tables_ref, lengths_ref = scalars[:2]
+        q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
+        b, w = pl.program_id(0), pl.program_id(1)
+        kvh, group, dh = o_ref.shape[1:]
+
+        @pl.when(w == 0)
+        def _init():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+            m_ref[...] = jnp.full_like(m_ref, pa._MASKED)
+            l_ref[...] = jnp.zeros_like(l_ref)
+
+        page = scalars[2][b] + w if windowed else w
+
+        @pl.when(page * page_len < lengths_ref[b])
+        def _live_page():
+            if mode == "copy":
+                acc_ref[0] += (k_ref[0, :group, :dh].astype(jnp.float32)
+                               + v_ref[0, :group, :dh].astype(jnp.float32))
+                l_ref[...] = jnp.ones_like(l_ref)
+                return
+            at = page * page_len + jax.lax.broadcasted_iota(
+                jnp.int32, (1 if page_body is None else group, page_len), 1)
+            live = at < lengths_ref[b]
+            if windowed:
+                live &= at >= scalars[3][b]
+            if page_body is None:
+                pa._page_one_row_a_head(q_ref.at[0], k_ref.at[0], v_ref.at[0],
+                                        acc_ref, m_ref, l_ref, live, dh)
+            else:
+                page_body(q_ref, k_ref.at[0], v_ref.at[0], acc_ref, m_ref,
+                          l_ref, live)
+
+        @pl.when(w == pl.num_programs(1) - 1)
+        def _flush():
+            if page_body is None:
+                for h in range(kvh):
+                    mine = slice(h * group, (h + 1) * group)
+                    o_ref[0, h] = (acc_ref[mine, h * dh:(h + 1) * dh]
+                                   / l_ref[mine, :]).astype(o_ref.dtype)
+            else:
+                o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+    @jax.jit
+    def call(q, k_pages, v_pages, tables, lengths, *window):
+        B, kvh, group, dh = q.shape
+        W, (page_len, width) = tables.shape[1], k_pages.shape[1:]
+
+        def page(b, w, tbl, lens, *win):
+            if mode == "compute":
+                return (1, 0, 0)
+            last = (lens[b] - 1) // page_len
+            if win:
+                return (tbl[b, jax.lax.rem(jnp.minimum(win[0][b] + w, last),
+                                           W)], 0, 0)
+            return (tbl[b, jnp.minimum(w, last)], 0, 0)
+
+        row_spec = pl.BlockSpec((1, kvh, group, dh),
+                                lambda b, w, *_: (b, 0, 0, 0))
+        page_spec = pl.BlockSpec((1, page_len, width), page)
+        q_spec, shapes = row_spec, [(kvh, group, dh), (kvh, group, 1),
+                                    (kvh, group, 1)]
+        if body == "all_heads":
+            rows = -(-kvh * group // 16) * 16   # whole tiles in either dtype
+            q = _block_diagonal(q, rows)
+            q_spec = pl.BlockSpec((1, rows, width), lambda b, w, *_: (b, 0, 0))
+            shapes = [(rows, width), (rows, 1), (rows, 1)]
+        return pl.pallas_call(
+            functools.partial(kernel, page_len=page_len,
+                              windowed=bool(window)),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2 + len(window), grid=(B, W),
+                in_specs=[q_spec, page_spec, page_spec],
+                out_specs=row_spec,
+                scratch_shapes=[pltpu.VMEM(shape, jnp.float32)
+                                for shape in shapes]),
+            out_shape=jax.ShapeDtypeStruct((B, kvh, group, dh), q.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary")),
+            interpret=jax.default_backend() != "tpu",
+        )(tables, lengths, *window, q, k_pages, v_pages)
+
+    return call
+
+
+def _windowed(call, window: int | None):
+    """``call`` at a row's position: under a window the ring's first page
+    and lowest visible position follow from the lengths."""
+    def at_lengths(q, k_pages, v_pages, tables, lengths):
+        win = _window_of(lengths, window) if window else ()
+        return call(q, k_pages, v_pages, tables, lengths, *win)
+    return at_lengths
+
+
+def _touch_a_page(q_ref, k_page, v_page, acc_ref, m_ref, l_ref, live, *_):
+    """In the place of a page's arithmetic: a sublane tile of the keys and
+    of the values goes into the accumulator, so that the slot is read."""
+    import jax.numpy as jnp
+
+    tile = acc_ref.shape[-2], min(acc_ref.shape[-1], 128)
+    at = (0,) * (acc_ref.ndim - 2) + (slice(tile[0]), slice(tile[1]))
+    acc_ref[at] += (k_page[:tile[0], :tile[1]].astype(jnp.float32)
+                    + v_page[:tile[0], :tile[1]].astype(jnp.float32))
+    l_ref[...] = jnp.ones_like(l_ref)
+
+
+def _walk_kv_call(copy_only: bool = False, **kw):
+    """The package's walk over a flat slab (``slots``: a ring of the tool's
+    choosing), called as :func:`_grid_kv_call` is. ``copy_only``: the same
+    walk, ring and copies with both of its page bodies replaced by
+    :func:`_touch_a_page` while the kernel is traced."""
+    import contextlib
+    from unittest import mock
+
+    import jax
+
+    from marlin_tpu.ops import paged_attention as pa
+
+    @jax.jit
+    def call(q, k_pages, v_pages, tables, lengths, *window):
+        bodies = mock.patch.multiple(
+            pa, _page_head_by_head=_touch_a_page,
+            _page_one_row_a_head=_touch_a_page) if copy_only \
+            else contextlib.nullcontext()
+        with bodies:
+            return pa._kv_walk_call(
+                q, k_pages, v_pages, tables, lengths, window,
+                interpret=jax.default_backend() != "tpu", **kw)
+    return call
+
+
+def measure(name: str, dtype, seed: int = 0) -> list[dict]:
+    """The lines of a (K, V) shape (module docstring)."""
+    import jax
+    import jax.numpy as jnp
+
+    shape = SHAPES[name]
+    rows, width, kvh, group, pages = (shape[k] for k in (
+        "rows", "width", "kvh", "group", "pages"))
+    window = shape.get("window")
     rng = np.random.default_rng(seed)
-    key = jax.random.key(seed)
-    kq, kk, kv = jax.random.split(key, 3)
-    slab4 = (pages, PAGE_LEN, kvh, HEAD_DIM)
-    k4 = jax.random.normal(kk, slab4, jnp.float32).astype(dtype)
-    v4 = jax.random.normal(kv, slab4, jnp.float32).astype(dtype)
+    kq, kk, kv = jax.random.split(jax.random.key(seed), 3)
+    slab = (pages, PAGE_LEN, kvh * HEAD_DIM)
+    k = jax.random.normal(kk, slab, jnp.float32).astype(dtype)
+    v = jax.random.normal(kv, slab, jnp.float32).astype(dtype)
     q = jax.random.normal(kq, (rows, kvh, group, HEAD_DIM),
                           jnp.float32).astype(dtype)
     tables = jnp.asarray(rng.integers(1, pages, (rows, width)), jnp.int32)
-    lengths = _lengths(rng, rows, width, live_pages, window)
-    out = {"shape": name, "dtype": str(jnp.dtype(dtype)), "rows": rows,
-           "table_width": width, "kv_heads": kvh, "group": group,
-           "page_bytes": 2 * PAGE_LEN * kvh * HEAD_DIM
-           * jnp.dtype(dtype).itemsize}
-    results = {}
-    for layout, (k, v) in {
-            "tkd": (k4, v4),
-            "t_kd": (k4.reshape(pages, PAGE_LEN, -1),
-                     v4.reshape(pages, PAGE_LEN, -1))}.items():
-        times = {}
-        for case, lens in (("cell", lengths),
-                           ("ones", np.ones((rows,), np.int32))):
-            lens = jnp.asarray(lens)
-            if window:
-                # the ring's first page and lowest visible position, as the
-                # decode program derives them from a row's position
-                lower = jnp.maximum(lens - 512, 0)
-                extra = dict(first_page=lower // PAGE_LEN, lower=lower)
-                live = int(jnp.sum((lens - 1) // PAGE_LEN
-                                   - lower // PAGE_LEN + 1))
-            else:
-                extra = {}
-                live = int(jnp.sum((lens - 1) // PAGE_LEN + 1))
-            fn = jax.jit(lambda q, k, v, t, n, extra=extra:
-                         paged_decode_attention(q, k, v, t, n, **extra))
-            try:
-                times[case] = (_time_call(fn, (q, k, v, tables, lens)), live)
-            except jax.errors.JaxRuntimeError as e:  # e.g. scoped VMEM
-                out[layout] = {"error": str(e).split(". ")[0][:300]}
-                break
-            if case == "cell":
-                results[layout] = np.asarray(
-                    fn(q, k, v, tables, lens).astype(jnp.float32))
-        if layout in out:
+    cell = jnp.asarray(_lengths(rng, shape))
+    ones = jnp.ones((rows,), jnp.int32)
+    if window:
+        first, _ = _window_of(cell, window)
+        live = int(jnp.sum((cell - 1) // PAGE_LEN - first + 1))
+    else:
+        live = int(jnp.sum((cell - 1) // PAGE_LEN + 1))
+    arrays = (q, k, v, tables)
+    base = {"dtype": str(jnp.dtype(dtype)), "rows": rows,
+            "table_width": width, "kv_heads": kvh, "group": group,
+            "live_pages": live,
+            "page_bytes": 2 * PAGE_LEN * kvh * HEAD_DIM
+            * jnp.dtype(dtype).itemsize}
+    base["bytes_us_a_page"] = base["page_bytes"] / 819e9 * 1e6
+    calls = {name: _walk_kv_call(),
+             name + ".grid": _grid_kv_call("both"),
+             name + ".copy_only": _grid_kv_call("copy"),
+             name + ".compute_only": _grid_kv_call("compute"),
+             name + ".scores_only": _grid_kv_call("compute", "scores"),
+             name + ".values_only": _grid_kv_call("compute", "values"),
+             name + ".all_heads.compute_only": _grid_kv_call("compute",
+                                                             "all_heads")}
+    lines, outs = [], {}
+    for label, call in calls.items():
+        fn = _windowed(call, window)
+        line = {"shape": label, **base}
+        lines.append(line)
+        try:
+            t1, t0 = _time_pair(fn, arrays, cell, ones)
+            outs[label] = np.asarray(fn(*arrays, cell).astype(jnp.float32))
+        except Exception as e:  # e.g. scoped VMEM
+            line["error"] = str(e).split(". ")[0][:300]
             continue
-        (t1, l1), (t0, l0) = times["cell"], times["ones"]
-        steps = rows * width
-        # t = live * a + (steps - live) * d at both points
-        a, d = np.linalg.solve([[l1, steps - l1], [l0, steps - l0]], [t1, t0])
-        out[layout] = {"call_us": t1 * 1e6, "live_pages": l1,
-                       "call_us_all_rows_length_1": t0 * 1e6,
-                       "live_step_us": a * 1e6, "dead_step_us": d * 1e6}
-    out["bytes_us_a_page"] = out["page_bytes"] / 819e9 * 1e6
-    if len(results) == 2:
-        out["max_abs_diff_between_layouts"] = float(
-            np.abs(results["tkd"] - results["t_kd"]).max())
-        out["live_step_ratio"] = (out["tkd"]["live_step_us"]
-                                  / out["t_kd"]["live_step_us"])
-    return out
+        line.update(call_us=t1 * 1e6, call_us_all_rows_length_1=t0 * 1e6,
+                    **_a_step(label == name, live, rows, width, t1, t0))
+    walk = lines[0]
+    try:  # the all-heads body's output, the copies back in
+        outs["all_heads"] = np.asarray(_windowed(_grid_kv_call(
+            "both", "all_heads"), window)(*arrays, cell).astype(jnp.float32))
+    except Exception as e:  # float32 Olmo blocks: scoped VMEM
+        lines[-1]["both_error"] = str(e).split(". ")[0][:200]
+    for line, label in ((walk, name), (lines[-1], "all_heads")):
+        if label in outs and name + ".grid" in outs:
+            line["max_abs_diff_from_grid"] = float(
+                np.abs(outs[label] - outs[name + ".grid"]).max())
+    if name in outs:
+        from marlin_tpu.ops.paged_attention import _kv_slots
+
+        walk["slots"] = _kv_slots(k)
+        walk["call_us_by_slots"] = {}
+        for slots in SLOTS[name]:
+            try:
+                fn = _windowed(_walk_kv_call(slots=slots), window)
+                t = _time_call(_chained(fn), (*arrays, cell),
+                               calls=4) / _CHAIN
+                walk["call_us_by_slots"][slots] = t * 1e6
+            except Exception as e:
+                walk["call_us_by_slots"][slots] = str(e).split(". ")[0][:200]
+        t1, t0 = _time_pair(_windowed(_walk_kv_call(copy_only=True), window),
+                            arrays, cell, ones)
+        walk["walk_copy_only_us_a_page"] = (t1 - t0) / (live - rows) * 1e6
+    return lines
 
 
 def _grid_latent_call(mode: str, value_dim: int):
@@ -187,8 +462,6 @@ def _grid_latent_call(mode: str, value_dim: int):
     to the letter; ``copy`` takes the matmuls out (a live step adds one
     sublane tile of the page into the accumulator, so that the block is
     read); ``compute`` freezes the index map on page 1."""
-    import functools
-
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -299,22 +572,11 @@ def measure_latent(name: str, dtype, seed: int = 0) -> list[dict]:
         name + ".compute_only": _grid_latent_call("compute", value_dim)}
     lines, outs = [], {}
     for label, fn in bodies.items():
-        many = _chained(fn)
-        t1 = _time_call(many, (q, slab, tables, cell), calls=4) / _CHAIN
-        t0 = _time_call(many, (q, slab, tables, ones), calls=4) / _CHAIN
+        t1, t0 = _time_pair(fn, (q, slab, tables), cell, ones)
         outs[label] = np.asarray(fn(q, slab, tables, cell).astype(jnp.float32))
-        line = {"shape": label, **base, "call_us": t1 * 1e6,
-                "call_us_all_rows_length_1": t0 * 1e6}
-        if label == name:
-            # t = live * a + rows * r at both points: no dead step exists
-            a, r = np.linalg.solve([[live, rows], [rows, rows]], [t1, t0])
-            line.update(live_step_us=a * 1e6, row_us=r * 1e6)
-        else:
-            steps = rows * width
-            a, d = np.linalg.solve([[live, steps - live],
-                                    [rows, steps - rows]], [t1, t0])
-            line.update(live_step_us=a * 1e6, dead_step_us=d * 1e6)
-        lines.append(line)
+        lines.append({"shape": label, **base, "call_us": t1 * 1e6,
+                      "call_us_all_rows_length_1": t0 * 1e6,
+                      **_a_step(label == name, live, rows, width, t1, t0)})
     lines[0]["max_abs_diff_from_grid"] = float(
         np.abs(outs[name] - outs[name + ".grid"]).max())
     return lines
@@ -338,7 +600,7 @@ def main(argv=None) -> int:
     dtype = jnp.float32 if args.f32 else jnp.bfloat16
     for name in args.shapes:
         lines = (measure_latent(name, dtype) if name in LATENT_SHAPES
-                 else [measure(name, dtype)])
+                 else measure(name, dtype))
         for line in lines:
             print(json.dumps(line), flush=True)
     print(json.dumps({"ok": True, "device": {

@@ -15,11 +15,14 @@ style configuration dict (:meth:`ModelSpec.from_config`):
   state-space mixer side by side on one normed input, their outputs added;
 - a fifth, ``linear`` (below): a gated delta-rule mixer and NO attention,
   a layer that owns no page at all;
+- a sixth, ``conv`` (below): a gated SHORT CONVOLUTION and no attention,
+  a layer that owns no page and no recurrent matrix either;
 - a per-head sigmoid gate on the attention output, before ``wo``;
 - a SwiGLU FFN, dense or a mixture of experts with a shared expert
   (:func:`~marlin_tpu.models.moe.moe_experts_ffn`: the layer is told which
-  experts it holds and computes their part of the result);
-- an untied head over the rows of the vocabulary that are held here;
+  experts it holds and computes their part of the result), or with none;
+- an untied head over the rows of the vocabulary that are held here, or
+  (``tied_head``) the embedding itself as the head: no second table;
 - parameters in ``param_dtype`` (norm gains and the router in float32);
   matmul operands and the KV cache in ``compute_dtype``; the residual
   stream, the norms, the router, the rotary embedding, the gate and every
@@ -76,6 +79,26 @@ has no rotary embedding. Prefill (:func:`~marlin_tpu.ops.delta_rule
 .delta_chunk_scan`) and decode (:func:`~marlin_tpu.ops.delta_rule
 .delta_decode_update`) meet the slot as a state-space mixer's do.
 
+**A layer whose only memory is a convolution's tail** (:class:`ConvSpec`; the
+``lfm2_moe`` configuration family's ``conv_*`` keys). A ``conv`` layer's
+mixer (:func:`_short_conv`, under the ``short_conv`` scope) is ``[b | c | z]
+= u W_in``, a causal depthwise convolution of ``taps`` taps over ``s = b *
+z`` (no activation), ``y = (c * conv(s)) W_out``. What it remembers of the
+past is the last ``taps - 1`` values of ``s``: ONE array a layer,
+``(state_slots, taps - 1, channels)`` in the compute dtype (it is
+overwritten every token, never accumulated into), indexed by the row's state
+slot as the other mixers' arrays are: the programs, the slot-to-slot copy
+and :meth:`ModelSpec.state_slot_bytes` walk the arrays a layer HAS
+(:meth:`ConvSpec.slot_arrays`), not a (state, tail) pair. The convolution is
+:func:`~marlin_tpu.ops.ssm.causal_conv` / :func:`~marlin_tpu.ops.ssm
+.conv_step`, the state-space and delta-rule mixers' own. The block is the
+generic pre-norm one; the family's ``full`` layer norms each head's queries
+and keys (one gain of ``head_dim`` for all heads) BEFORE the rotary
+embedding and has no head gate (``qk_norm``, ``head_gate``). A slot is tens
+of kilobytes where a recurrent matrix makes it tens of megabytes, so a
+snapshot costs less than the page it stands behind
+(``serving/kvpool.py`` takes one behind every chunk then).
+
 **A state that is shared by snapshot.** A slot is private to its row, but a
 COPY of it at a page boundary is as good to another row as the pages before
 that boundary: the pool keeps such copies in further slots of the same
@@ -123,7 +146,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["RopeSpec", "LatentSpec", "SsmSpec", "DeltaSpec", "Multipliers",
+__all__ = ["RopeSpec", "LatentSpec", "SsmSpec", "DeltaSpec", "ConvSpec",
+           "Multipliers",
            "LayerSpec", "ModelSpec", "init_params", "state_slot_copy",
            "init_layer_params", "init_kv_pages", "window_ring_pages",
            "layer_forward", "prefill_paged", "decode_paged",
@@ -272,11 +296,12 @@ class SsmSpec:
         """``mup`` laid over the input projection's outputs."""
         return np.repeat(np.asarray(self.mup, np.float32), self.segments)
 
-    def slot_values(self) -> tuple:
-        """Values ONE row's slot holds in one layer: ``(recurrent state,
-        convolution tail)``."""
-        return (self.heads * self.state * self.head_dim,
-                (self.conv - 1) * self.conv_dim)
+    def slot_arrays(self) -> tuple:
+        """What ONE row's slot holds in one layer, ``(shape, dtype)`` an
+        array (dtype None: the compute dtype): the recurrent state, then the
+        convolution's tail."""
+        return (((self.heads, self.state, self.head_dim), self.state_dtype),
+                ((self.conv - 1, self.conv_dim), None))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -303,11 +328,34 @@ class DeltaSpec:
         """Channels the convolution runs over: ``[q | k | v]``."""
         return self.heads * (2 * self.key_dim + self.value_dim)
 
-    def slot_values(self) -> tuple:
-        """Values ONE row's slot holds in one layer: ``(recurrent state,
-        convolution tail)``."""
-        return (self.heads * self.key_dim * self.value_dim,
-                (self.conv - 1) * self.conv_dim)
+    def slot_arrays(self) -> tuple:
+        """What ONE row's slot holds in one layer, ``(shape, dtype)`` an
+        array (dtype None: the compute dtype): the recurrent state as the
+        slab stores it (``(key_dim, heads * value_dim)``: whole lane tiles),
+        then the convolution's tail."""
+        return (((self.key_dim, self.heads * self.value_dim),
+                 self.state_dtype),
+                ((self.conv - 1, self.conv_dim), None))
+
+
+@dataclasses.dataclass(frozen=True)
+class ConvSpec:
+    """The sizes of a gated short-convolution mixer (the ``lfm2_moe``
+    configuration family's ``conv_L_cache`` key): a causal depthwise
+    convolution of ``taps`` taps over ``channels`` channels, no bias, no
+    activation. Its only memory is its tail."""
+
+    taps: int
+    channels: int
+
+    #: no chunked scan: a prefill chunk may be of any width
+    chunk = 1
+
+    def slot_arrays(self) -> tuple:
+        """What ONE row's slot holds in one layer: the convolution's tail
+        (the last ``taps - 1`` inputs) in the compute dtype, and NO state
+        part."""
+        return (((self.taps - 1, self.channels), None),)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -329,7 +377,8 @@ class Multipliers:
 @dataclasses.dataclass(frozen=True)
 class LayerSpec:
     #: "full" | "sliding" | "latent", or "linear": no attention, a gated
-    #: delta-rule mixer (:class:`DeltaSpec`) in its place, no page owned
+    #: delta-rule mixer (:class:`DeltaSpec`) in its place, no page owned; or
+    #: "conv": a gated short convolution (:class:`ConvSpec`) in its place
     attn: str
     q_heads: int
     ffn: str       # "dense" | "moe"
@@ -338,10 +387,16 @@ class LayerSpec:
     ssm: bool = False
 
     @property
+    def owns_pages(self) -> bool:
+        """Whether a page id indexes any array of the layer."""
+        return self.attn not in ("linear", "conv")
+
+    @property
     def has_state(self) -> bool:
-        """Whether the layer keeps a recurrent state (and a convolution's
-        tail) in the row's state slot: a mixer of either kind."""
-        return self.ssm or self.attn == "linear"
+        """Whether the layer keeps anything in the row's state slot: a
+        recurrent state and a convolution's tail (a mixer of either kind),
+        or a short convolution's tail alone."""
+        return self.ssm or not self.owns_pages
 
 
 _LAYER_TYPES_KEYS = (
@@ -366,6 +421,11 @@ _OLMO_HYBRID_KEYS = (
     "num_key_value_heads", "intermediate_size", "vocab_size",
     "linear_num_key_heads", "linear_num_value_heads", "linear_key_head_dim",
     "linear_value_head_dim", "linear_conv_kernel_dim")
+_LFM2_KEYS = (
+    "num_hidden_layers", "layer_types", "hidden_size", "num_attention_heads",
+    "num_key_value_heads", "intermediate_size", "vocab_size", "conv_L_cache",
+    "num_dense_layers", "moe_intermediate_size", "num_experts",
+    "num_experts_per_tok")
 
 
 def _require(cfg: dict, keys: tuple, family: str) -> None:
@@ -423,6 +483,17 @@ class ModelSpec:
     #: the delta-rule mixers' sizes (the ``linear`` layers); with it the
     #: block is the ``olmo_hybrid`` family's (:func:`_post_norm_layer`)
     delta: DeltaSpec | None = None
+    #: the short convolutions' sizes (the ``conv`` layers)
+    conv: ConvSpec | None = None
+    #: a ``full`` / ``sliding`` layer's per-head sigmoid gate on the
+    #: attention output, and an RMSNorm over each head's queries and keys
+    #: (one gain of ``head_dim`` for all heads) before the rotary embedding
+    head_gate: bool = True
+    qk_norm: bool = False
+    #: the head is the embedding itself (no ``head`` array)
+    tied_head: bool = False
+    #: added to the sum the picks' weights are renormalised by
+    renorm_eps: float = 0.0
 
     @classmethod
     def from_config(cls, cfg: dict, experts_total: int | None = None,
@@ -438,9 +509,10 @@ class ModelSpec:
         the latent-attention family (:meth:`_from_latent_config`), one with
         ``mamba_d_ssm`` of the ``falcon_h1`` family
         (:meth:`_from_falcon_h1_config`), one with ``linear_key_head_dim``
-        of the ``olmo_hybrid`` family (:meth:`_from_olmo_hybrid_config`). A
-        configuration that lacks keys its family needs raises a
-        ``ValueError`` that names them."""
+        of the ``olmo_hybrid`` family (:meth:`_from_olmo_hybrid_config`), one
+        with ``conv_L_cache`` of the ``lfm2_moe`` family
+        (:meth:`_from_lfm2_config`). A configuration that lacks keys its
+        family needs raises a ``ValueError`` that names them."""
         if "kv_lora_rank" in cfg:
             _require(cfg, _LATENT_KEYS, "latent-attention (kv_lora_rank)")
             return cls._from_latent_config(cfg, experts_total, first_expert)
@@ -451,6 +523,9 @@ class ModelSpec:
             _require(cfg, _OLMO_HYBRID_KEYS,
                      "olmo_hybrid (linear_key_head_dim)")
             return cls._from_olmo_hybrid_config(cfg)
+        if "conv_L_cache" in cfg:
+            _require(cfg, _LFM2_KEYS, "lfm2_moe (conv_L_cache)")
+            return cls._from_lfm2_config(cfg, experts_total, first_expert)
         _require(cfg, _LAYER_TYPES_KEYS, "layer_types")
         n = int(cfg["num_hidden_layers"])
         kinds = {"full_attention": "full", "sliding_attention": "sliding"}
@@ -664,6 +739,72 @@ class ModelSpec:
             compute_dtype=str(cfg.get("compute_dtype", "bfloat16")),
             delta=delta)
 
+    @classmethod
+    def _from_lfm2_config(cls, cfg: dict, experts_total, first_expert):
+        """The ``lfm2_moe`` family's keys: ``layer_types`` of ``conv`` (a
+        gated short convolution of ``conv_L_cache`` taps over ``hidden_size``
+        channels and no attention: the layer owns no page and keeps only
+        the convolution's tail) and ``full_attention`` (GQA, each head's
+        queries and keys normed before the rotary embedding over the whole
+        head, no gate); ``num_dense_layers`` leading dense SwiGLU layers
+        (``intermediate_size``), then expert layers (``num_experts`` x
+        ``moe_intermediate_size``, sigmoid scoring, ``use_expert_bias``: a
+        bias that only selects, the picks renormalised over their sum +
+        1e-6, NO shared expert); ``head_dim`` = hidden / heads where absent;
+        a tied head unless ``tie_word_embeddings`` is false."""
+        unbuilt = [k for k, bad in (
+            ("conv_bias", cfg.get("conv_bias", False)),
+            ("num_shared_experts", cfg.get("num_shared_experts", 0)),
+            ("norm_topk_prob", not cfg.get("norm_topk_prob", True)),
+            ("use_expert_bias", not cfg.get("use_expert_bias", True)),
+            ("rope_scaling", cfg.get("rope_scaling")),
+            ("attention_bias", cfg.get("attention_bias", False))) if bad]
+        kinds = {"full_attention": "full", "conv": "conv"}
+        n = int(cfg["num_hidden_layers"])
+        unknown = sorted(set(cfg["layer_types"][:n]) - set(kinds))
+        if unbuilt or unknown:
+            raise ValueError(
+                f"not built for the lfm2_moe family: the keys {unbuilt} as "
+                f"this configuration sets them (a convolution bias, a shared "
+                f"expert, picks not renormalised, a router without its "
+                f"selection bias, rope_scaling, attention_bias), layer "
+                f"types {unknown}")
+        heads, d = int(cfg["num_attention_heads"]), int(cfg["hidden_size"])
+        dense_first = int(cfg["num_dense_layers"])
+        layers = tuple(
+            LayerSpec(kinds[cfg["layer_types"][i]], heads,
+                      "dense" if i < dense_first else "moe")
+            for i in range(n))
+        dh = int(cfg.get("head_dim") or d // heads)
+        theta = cfg.get("rope_theta") or (
+            cfg.get("rope_parameters") or {}).get("rope_theta")
+        if theta is None:
+            raise ValueError("ModelSpec.from_config read this configuration "
+                             "as of the lfm2_moe family and could not read "
+                             "its keys ['rope_theta']")
+        rope = RopeSpec(theta=float(theta), rotary_dim=dh)
+        held = int(cfg["num_experts"])
+        total = _experts_total(held, experts_total, first_expert)
+        return cls(
+            d_model=d, head_dim=dh,
+            kv_heads=int(cfg["num_key_value_heads"]), layers=layers,
+            window=0, rope_full=rope, rope_sliding=rope,
+            dense_width=int(cfg["intermediate_size"]),
+            expert_width=int(cfg["moe_intermediate_size"]), shared_width=0,
+            n_experts=total, experts_held=held,
+            first_expert=int(first_expert),
+            top_k=int(cfg["num_experts_per_tok"]),
+            routed_scale=float(cfg.get("routed_scaling_factor", 1.0)),
+            vocab_held=int(cfg["vocab_size"]),
+            norm_eps=float(cfg.get("norm_eps", 1e-5)),
+            param_dtype=str(cfg.get("param_dtype", "bfloat16")),
+            compute_dtype=str(cfg.get("compute_dtype", "bfloat16")),
+            scoring="sigmoid",
+            conv=ConvSpec(taps=int(cfg["conv_L_cache"]), channels=d),
+            head_gate=False, qk_norm=True,
+            tied_head=bool(cfg.get("tie_word_embeddings", True)),
+            renorm_eps=1e-6)
+
     @property
     def n_layers(self) -> int:
         return len(self.layers)
@@ -683,20 +824,20 @@ class ModelSpec:
 
     @property
     def mixer(self):
-        """The recurrent mixers' sizes, of either kind (None: no layer has
-        one): what a state slot is sized by."""
-        return self.ssm or self.delta
+        """The sizes of the mixers that keep something in a state slot, of
+        whichever kind (None: no layer has one): what a slot is sized by."""
+        return self.ssm or self.delta or self.conv
 
     def state_slot_bytes(self, compute_dtype: str | None = None) -> int:
-        """Bytes ONE row's state slot holds over all layers: the recurrent
-        state in its own dtype and the convolution's tail in the compute
-        dtype, for each layer with a mixer (0 for a model without)."""
+        """Bytes ONE row's state slot holds over all layers: each array a
+        layer with a mixer keeps there (:meth:`slot_arrays` of its spec: a
+        recurrent state in its own dtype, a convolution's tail in the
+        compute dtype; 0 for a model without)."""
         if not self.has_state:
             return 0
-        state, tail = self.mixer.slot_values()
-        per_layer = (state * np.dtype(self.mixer.state_dtype).itemsize
-                     + tail * jnp.dtype(compute_dtype
-                                        or self.compute_dtype).itemsize)
+        cd = jnp.dtype(compute_dtype or self.compute_dtype)
+        per_layer = sum(math.prod(shape) * jnp.dtype(dt or cd).itemsize
+                        for shape, dt in self.mixer.slot_arrays())
         return per_layer * sum(ly.has_state for ly in self.layers)
 
     def page_values(self, kind: str, page_len: int) -> int:
@@ -776,6 +917,15 @@ def init_layer_params(spec: ModelSpec, ly: LayerSpec, key) -> dict:
           "ln2": jnp.ones((d,), jnp.float32)}
     if ly.attn == "linear":
         lp.update(_init_delta_params(spec, ks[8:15]))
+    elif ly.attn == "conv":
+        # [b | c | z] in that order; the taps N(0, 1/taps): the gated
+        # product reaches the output projection at order 1
+        cs = spec.conv
+        lp.update(w_in=_normal(ks[0], (d, 3 * cs.channels), s, dt),
+                  conv_w=_normal(ks[1], (cs.taps, cs.channels),
+                                 cs.taps ** -0.5, dt),
+                  w_out=_normal(ks[4], (cs.channels, d),
+                                cs.channels ** -0.5, dt))
     elif spec.delta is not None:   # the family's full layer: QK-norm, no gate
         lp.update(wq=_normal(ks[0], (d, hq), s, dt),
                   wk=_normal(ks[1], (d, hk), s, dt),
@@ -805,8 +955,12 @@ def init_layer_params(spec: ModelSpec, ly: LayerSpec, key) -> dict:
         lp.update(wq=_normal(ks[0], (d, hq), s, dt),
                   wk=_normal(ks[1], (d, hk), s, dt),
                   wv=_normal(ks[2], (d, hk), s, dt),
-                  wgate=_normal(ks[3], (d, ly.q_heads), s, dt),
                   wo=_normal(ks[4], (hq, d), hq ** -0.5, dt))
+        if spec.head_gate:
+            lp["wgate"] = _normal(ks[3], (d, ly.q_heads), s, dt)
+        if spec.qk_norm:
+            lp.update(q_norm=jnp.ones((dh,), jnp.float32),
+                      k_norm=jnp.ones((dh,), jnp.float32))
     if ly.ffn == "dense":
         f = spec.dense_width
         lp.update(w_gate=_normal(ks[5], (d, f), s / mu.mlp_gate, dt),
@@ -819,10 +973,11 @@ def init_layer_params(spec: ModelSpec, ly: LayerSpec, key) -> dict:
                                         jnp.float32) * s,
             "e_gate": _normal(ks[9], (e, d, fe), s, dt),
             "e_up": _normal(ks[10], (e, d, fe), s, dt),
-            "e_down": _normal(ks[11], (e, fe, d), fe ** -0.5, dt),
-            "s_gate": _normal(ks[12], (d, fs), s, dt),
-            "s_up": _normal(ks[13], (d, fs), s, dt),
-            "s_down": _normal(ks[14], (fs, d), fs ** -0.5, dt)}
+            "e_down": _normal(ks[11], (e, fe, d), fe ** -0.5, dt)}
+        if fs:   # a model without a shared expert holds no such array
+            lp["moe"].update(s_gate=_normal(ks[12], (d, fs), s, dt),
+                             s_up=_normal(ks[13], (d, fs), s, dt),
+                             s_down=_normal(ks[14], (fs, d), fs ** -0.5, dt))
         if spec.scoring == "sigmoid":
             # the family's load-balancing term: small beside the spacing of
             # the top scores (0.013 at 128 experts), so that it changes
@@ -890,7 +1045,10 @@ def _init_delta_params(spec: ModelSpec, ks) -> dict:
 
 def init_params(spec: ModelSpec, key) -> dict:
     """Scaled-normal parameters, drawn a layer at a time; embedding and head
-    are separate (untied) and hold ``vocab_held`` rows. With the family's
+    are separate (untied) and hold ``vocab_held`` rows, unless the spec ties
+    them (``tied_head``: ONE table, drawn N(0, 1/d) so that the logits it
+    gives as the head spread by order 1; a pre-norm block reads the stream
+    through a norm, so the small embedding costs nothing). With the family's
     multipliers (:class:`Multipliers`) the embedding reaches the stream at
     order 1 and the logits spread by order 1; so it does (N(0, 1)) in the
     family whose blocks norm their branches' outputs and not their inputs
@@ -899,12 +1057,14 @@ def init_params(spec: ModelSpec, key) -> dict:
     mu = spec.mults
     ks = jax.random.split(key, spec.n_layers + 2)
     table = (spec.vocab_held, spec.d_model)
-    emb_std = 1.0 if spec.delta is not None else (
-        0.02 if mu is None else 1.0 / mu.embedding)
+    head_std = spec.d_model ** -0.5 / (1.0 if mu is None else mu.lm_head)
+    emb_std = head_std if spec.tied_head else (
+        1.0 if spec.delta is not None else (
+            0.02 if mu is None else 1.0 / mu.embedding))
     p = {"emb": _normal_table(ks[0], table, emb_std, dt),
-         "head": _normal_table(ks[1], table, spec.d_model ** -0.5
-                               / (1.0 if mu is None else mu.lm_head), dt),
          "ln_f": jnp.ones((spec.d_model,), jnp.float32)}
+    if not spec.tied_head:
+        p["head"] = _normal_table(ks[1], table, head_std, dt)
     if not isinstance(ks, jax.core.Tracer):
         _compile_side_by_side([(init_layer_params, (spec, ly, ks[2]), {})
                                for ly in dict.fromkeys(spec.layers)])
@@ -923,9 +1083,13 @@ def init_kv_pages(spec: ModelSpec, num_pages: int, window_pages: int,
     (:attr:`LatentSpec.entry_width`). Page 0 of each class is its dummy. A
     token's heads lie side by side in ONE row, so head ``h``'s keys of a page
     are the lane slice ``[:, h * head_dim:(h + 1) * head_dim]``, a matrix of
-    whole tiles at ``head_dim`` 128 that the decode kernel contracts on the
-    MXU as it is (:func:`~marlin_tpu.ops.paged_attention
-    .paged_decode_attention` picks its body by the slab's rank; held
+    whole tiles where ``head_dim`` is a multiple of 128, which the decode
+    kernel contracts on the MXU as it is; at a narrower head (64: two heads
+    share a lane tile) the kernel never slices the page: every head meets
+    the whole page in one matmul from a block-diagonal query
+    (:func:`~marlin_tpu.ops.paged_attention
+    .paged_decode_attention` picks its body by the slab's rank and the
+    head's width; held
     ``(page_len, kv_heads, head_dim)``, as the dense model's 16-token pages
     are, a 256-token page cost the kernel 8-9 us a step for 0.6-1.3 us of
     bytes: PERF.md, PR 38). A
@@ -935,7 +1099,9 @@ def init_kv_pages(spec: ModelSpec, num_pages: int, window_pages: int,
     and the convolution tails ``(state_slots, conv - 1, conv_dim)``; slot 0
     is the dummy. A ``linear`` layer has those two arrays and nothing else
     (its states ``(state_slots, key_dim, heads * value_dim)``:
-    :mod:`~marlin_tpu.ops.delta_rule`). ``state_slots`` counts every slot of
+    :mod:`~marlin_tpu.ops.delta_rule`); a ``conv`` layer ONE, the tails
+    ``(state_slots, taps - 1, channels)``: a layer has the arrays its
+    mixer's ``slot_arrays()`` names. ``state_slots`` counts every slot of
     the arrays: the rows' and, after them, the pool's snapshot slots."""
     if num_pages < 2 or (spec.has_window and window_pages < 2):
         raise ValueError(f"each page class needs >= 2 pages (page 0 is the "
@@ -945,15 +1111,14 @@ def init_kv_pages(spec: ModelSpec, num_pages: int, window_pages: int,
                          f"slots (slot 0 is the dummy), got {state_slots}")
     dt = jnp.dtype(compute_dtype or spec.compute_dtype)
 
-    def state_arrays(mixer, state_shape):
-        return (jnp.zeros((state_slots, *state_shape),
-                          jnp.dtype(mixer.state_dtype)),
-                jnp.zeros((state_slots, mixer.conv - 1, mixer.conv_dim), dt))
+    def state_arrays(mixer):
+        return tuple(jnp.zeros((state_slots, *shape), jnp.dtype(sd or dt))
+                     for shape, sd in mixer.slot_arrays())
 
     def slabs(ly):
-        if ly.attn == "linear":   # no page: the state slot's two arrays
-            ds = spec.delta
-            return state_arrays(ds, (ds.key_dim, ds.heads * ds.value_dim))
+        if not ly.owns_pages:   # no page: the state slot's arrays alone
+            return state_arrays(spec.delta if ly.attn == "linear"
+                                else spec.conv)
         if ly.attn == "latent":
             return (jnp.zeros((num_pages, page_len,
                                spec.latent.entry_width), dt),)
@@ -963,8 +1128,7 @@ def init_kv_pages(spec: ModelSpec, num_pages: int, window_pages: int,
             for _ in range(2))
         if not ly.ssm:
             return kv
-        sm = spec.ssm
-        return kv + state_arrays(sm, (sm.heads, sm.state, sm.head_dim))
+        return kv + state_arrays(spec.ssm)
 
     return {f"l{i}": slabs(ly) for i, ly in enumerate(spec.layers)}
 
@@ -1038,7 +1202,7 @@ def layer_forward(spec: ModelSpec, i: int, lp: dict, x, positions, valid,
     (:func:`_parallel_mixers`): the recurrent state is handed in and out
     there the way ``attend`` hands in the cache; so does a ``linear``
     layer, which asks ``attend`` for nothing (:func:`_post_norm_layer`,
-    :func:`_delta_mixer`)."""
+    :func:`_delta_mixer`), and a ``conv`` layer (:func:`_short_conv`)."""
     ly = spec.layers[i]
     T, cd = x.shape[0], jnp.dtype(spec.compute_dtype)
     H, kvh, dh = ly.q_heads, spec.kv_heads, spec.head_dim
@@ -1051,17 +1215,45 @@ def layer_forward(spec: ModelSpec, i: int, lp: dict, x, positions, valid,
         with jax.named_scope("attn_latent"):
             x = _latent_attention(spec, ly, lp, x, positions, attend)
         return _ffn_half(spec, ly, lp, x, valid)
+    if ly.attn == "conv":
+        with jax.named_scope("short_conv"):
+            x = x + _short_conv(
+                spec, lp, _rmsnorm(x, lp["ln1"], spec.norm_eps).astype(cd),
+                mix)
+        return _ffn_half(spec, ly, lp, x, valid)
     rope = spec.rope_full if ly.attn == "full" else spec.rope_sliding
     with jax.named_scope(f"attn_{ly.attn}"):
         h = _rmsnorm(x, lp["ln1"], spec.norm_eps).astype(cd)
-        q = _rope(_mm(h, lp["wq"]).reshape(T, H, dh), positions, rope)
-        k = _rope(_mm(h, lp["wk"]).reshape(T, kvh, dh), positions, rope)
+        if spec.qk_norm:
+            # each head's queries and keys normed (float32, one gain for
+            # all heads) BEFORE the rotation, rounded once after it
+            q, k = (_rope(_rmsnorm(_mm(h, lp[w], jnp.float32).reshape(
+                T, n, dh), lp[g], spec.norm_eps), positions, rope).astype(cd)
+                for w, g, n in (("wq", "q_norm", H), ("wk", "k_norm", kvh)))
+        else:
+            q = _rope(_mm(h, lp["wq"]).reshape(T, H, dh), positions, rope)
+            k = _rope(_mm(h, lp["wk"]).reshape(T, kvh, dh), positions, rope)
         v = _mm(h, lp["wv"]).reshape(T, kvh, dh)
-        gate = jax.nn.sigmoid(_mm(h, lp["wgate"], jnp.float32))
+        if spec.head_gate:
+            gate = jax.nn.sigmoid(_mm(h, lp["wgate"], jnp.float32))
         o = attend(q.reshape(T, kvh, H // kvh, dh), k, v).reshape(T, H, dh)
-        o = (o.astype(jnp.float32) * gate[:, :, None]).astype(cd)
-        x = x + _mm(o.reshape(T, H * dh), lp["wo"], jnp.float32)
+        if spec.head_gate:
+            o = (o.astype(jnp.float32) * gate[:, :, None]).astype(cd)
+        x = x + _mm(o.reshape(T, H * dh).astype(cd), lp["wo"], jnp.float32)
     return _ffn_half(spec, ly, lp, x, valid)
+
+
+def _short_conv(spec: ModelSpec, lp: dict, u, mix):
+    """The gated short convolution over the normed input ``u`` (T, d) in the
+    compute dtype: ``[b | c | z] = u W_in`` (thirds, in that order, float32);
+    ``s = b * z``; ``mix(s (T, channels), lp)`` runs the causal depthwise
+    convolution where the program keeps the row's tail and returns it in
+    float32 (no bias, no activation); ``y = (c * conv(s)) W_out``."""
+    ch, cd = spec.conv.channels, u.dtype
+    p = _mm(u, lp["w_in"], jnp.float32)
+    b, c, z = p[:, :ch], p[:, ch:2 * ch], p[:, 2 * ch:]
+    return _mm((c * mix((b * z).astype(cd), lp)).astype(cd), lp["w_out"],
+               jnp.float32)
 
 
 def _latent_attention(spec: ModelSpec, ly: LayerSpec, lp: dict, x, positions,
@@ -1255,15 +1447,18 @@ def _ffn_half(spec: ModelSpec, ly: LayerSpec, lp: dict, x, valid):
         out, counts = moe_experts_ffn(
             lp["moe"], h, valid, top_k=spec.top_k,
             first_expert=spec.first_expert, routed_scale=spec.routed_scale,
-            compute_dtype=cd, scoring=spec.scoring)
+            compute_dtype=cd, scoring=spec.scoring,
+            renorm_eps=spec.renorm_eps)
     return x + out, counts
 
 
 def _head_logits(spec: ModelSpec, params: dict, x):
-    """Float32 logits over the held rows of the (untied) head."""
+    """Float32 logits over the held rows of the head: its own table, or
+    the embedding itself where the spec ties them."""
     xf = _rmsnorm(x, params["ln_f"], spec.norm_eps).astype(
         spec.compute_dtype)
-    logits = jnp.matmul(xf, params["head"].astype(xf.dtype).T,
+    head = params["emb" if spec.tied_head else "head"]
+    logits = jnp.matmul(xf, head.astype(xf.dtype).T,
                         preferred_element_type=jnp.float32)
     return logits if spec.mults is None else logits * spec.mults.lm_head
 
@@ -1544,7 +1739,8 @@ def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
     ltable = jnp.pad(gtable, (0, flash_table_pages(
         gtable.shape[0], page_len) - gtable.shape[0]))
     by_kind = {"full": gtable, "sliding": w_slots, "latent": ltable,
-               "linear": gtable}     # a linear layer has no slab to gather
+               "linear": gtable,     # a linear layer has no slab to gather
+               "conv": gtable}       # nor has a conv layer
     ctx = jax.lax.optimization_barrier({
         name: tuple(t[by_kind[ly.attn]].reshape(-1, *t.shape[2:])
                     for t in _kv_slabs(ly, pages[name]))
@@ -1605,6 +1801,19 @@ def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
                 jax.lax.dynamic_update_index_in_dim(tails, t1, state_slot, 0))
             return o
 
+        def mix_conv(s, lp, name=name):
+            # a conv layer's slot is its tail alone: entered (zeros at a
+            # row's first chunk, whatever the slot held) and left behind
+            # the chunk's last VALID input; padding moves nothing
+            (tails,) = pages[name]
+            (t0,) = _enter_state(chunk_start == 0, tails[state_slot])
+            conv, t1 = ssm_ops.causal_conv(
+                s, t0, lp["conv_w"], jnp.zeros((s.shape[1],), s.dtype),
+                jnp.clip(length - chunk_start, 0, C))
+            new_state[name] = (
+                jax.lax.dynamic_update_index_in_dim(tails, t1, state_slot, 0),)
+            return conv
+
         def attend_latent(q_nope, q_pe, entry, scale, wkv_b, name=name):
             new_kv[name] = (entry,)
             (ce,) = ctx[name]
@@ -1648,7 +1857,8 @@ def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
 
         x, c = layer_forward(spec, i, params[name], x, q_pos, valid,
                              attend_latent if ly.attn == "latent" else attend,
-                             mix_delta if ly.attn == "linear" else mix)
+                             {"linear": mix_delta,
+                              "conv": mix_conv}.get(ly.attn, mix))
         counts = counts + c
     # write the chunk's pages, one dynamic update a page (transformer.py has
     # the reason). A page wholly past the prompt goes to the dummy: in a
@@ -1689,20 +1899,20 @@ def _prefill_args(params, pages, tables, chunk, chunk_start, length,
                 **_state_slots(spec, "state_slot", state_slot)}
 
 
-def _enter_state(fresh, state, tail):
-    """What a prefill chunk enters with: the slot's state and tail, or
-    zeros where the chunk starts at position 0 (``fresh``): a slot is not
-    wiped when it changes hands. A row whose FIRST chunk starts past 0 (a
-    prefix hit) enters with what the engine copied into its slot ahead of
-    that chunk: the snapshot of the state at the prefix's end
-    (:func:`state_slot_copy`)."""
-    return jnp.where(fresh, 0, state), jnp.where(fresh, 0, tail)
+def _enter_state(fresh, *arrays):
+    """What a prefill chunk enters with: the slot's arrays (state and tail,
+    or a tail alone), or zeros where the chunk starts at position 0
+    (``fresh``): a slot is not wiped when it changes hands. A row whose
+    FIRST chunk starts past 0 (a prefix hit) enters with what the engine
+    copied into its slot ahead of that chunk: the snapshot of the state at
+    the prefix's end (:func:`state_slot_copy`)."""
+    return tuple(jnp.where(fresh, 0, a) for a in arrays)
 
 
 def _kv_slabs(ly: LayerSpec, arrays: tuple) -> tuple:
     """A layer's arrays that a page id indexes (what follows them a state
     slot does: :func:`init_kv_pages`)."""
-    if ly.attn == "linear":
+    if not ly.owns_pages:
         return ()
     return arrays[:2] if ly.ssm else arrays
 
@@ -1784,6 +1994,7 @@ def _lm_decode_paged_spec_jit(params, pages, gtables, wtables, positions,
     per_class = {"full": (gtables, gtables[rows, page], zero, zero)}
     per_class["latent"] = per_class["full"]  # one table, every position
     per_class["linear"] = per_class["full"]  # no page: nothing reads it
+    per_class["conv"] = per_class["full"]
     if spec.has_window:
         per_class["sliding"] = (wtables, wtables[rows, jnp.mod(page, ring)],
                                 lower // page_len, lower)
@@ -1824,6 +2035,15 @@ def _lm_decode_paged_spec_jit(params, pages, gtables, wtables, positions,
             new_pages[name] = (states, tails)
             return o
 
+        def mix_conv(s, lp, name=name):
+            # a conv layer: each live row's tail read and written in place
+            (tails,) = pages[name]
+            conv, t1 = ssm_ops.conv_step(
+                s, tails[state_slots], lp["conv_w"],
+                jnp.zeros((s.shape[1],), s.dtype))
+            new_pages[name] = (tails.at[state_slots].set(t1),)
+            return conv
+
         def attend(q, k, v, name=name, ly=ly, tables=tables, pids=pids,
                    first_page=first_page, low=low):
             pk, pv = _kv_slabs(ly, pages[name])
@@ -1860,7 +2080,8 @@ def _lm_decode_paged_spec_jit(params, pages, gtables, wtables, positions,
 
         x, c = layer_forward(spec, i, params[name], x, pos, live,
                              attend_latent if ly.attn == "latent" else attend,
-                             mix_delta if ly.attn == "linear" else mix)
+                             {"linear": mix_delta,
+                              "conv": mix_conv}.get(ly.attn, mix))
         counts = counts + c
     logits = _head_logits(spec, params, x)
     nxt = _pick_token_rows(temperature, top_p, top_k, logits, seeds,
@@ -1934,8 +2155,9 @@ def _state_slot_copy_jit(pages, src, dst, spec: ModelSpec):
 
 
 def state_slot_copy(pages, src, dst, spec: ModelSpec):
-    """Copy state slot ``src`` onto slot ``dst`` in every layer that has a
-    recurrent mixer, state and convolution tail (``pages`` DONATED; ``src``
+    """Copy state slot ``src`` onto slot ``dst`` in every layer that keeps
+    something there, each array the layer has (state and convolution tail,
+    or a short convolution's tail alone) (``pages`` DONATED; ``src``
     / ``dst`` traced: ONE compiled program an engine). Taking a snapshot and
     entering from one are both this program, dispatched in the stream
     behind the chunk that wrote ``src`` and ahead of whatever changes it

@@ -215,11 +215,32 @@ def moe_decode_ffn(mp: dict, h, top_k: int = 2):
     return jnp.sum(out * topv[:, None].astype(out.dtype), axis=0).astype(cd)
 
 
-#: rows of a grouped matmul's tile on the chip, and its weight tile (k, n)
+#: rows of a grouped matmul's tile on the chip, and its weight tile (k, n):
+#: the pick wherever both of an expert's widths are whole tiles of 1024
+#: (chosen at Laguna's 3072 x 1024 experts, PERF.md section 6, PR 31)
 _GMM_TILING = (128, 1024, 1024)
 
 
-def _grouped_matmul(xs, w, sizes, out_dtype):
+def _gmm_tiling(k: int, n: int) -> tuple:
+    """The grouped matmul's tile ``(rows, k, n)`` for experts of ``(k, n)``,
+    from those widths alone. An expert whose widths are both whole tiles of
+    1024 keeps :data:`_GMM_TILING`. Any other (1792 = 14 x 128) takes the
+    whole contraction in ONE tile, up to 2048, and 1024 columns: read on the
+    chip at 32 experts of 2048 x 1792 (``tools/gmm_tiling.py``; PERF.md
+    section 6, PR 44), a layer's three matmuls take 1039 us at a decode
+    call's 384 rows and 1293 at a chunk's 2048, against 1072 and 1441 at
+    ``_GMM_TILING`` (fewer grid steps) and 1127 / 1516 at whole tiles of 896
+    (a quarter-empty tile costs less than a narrower one). The rows stay
+    128 whatever an expert has: an expert whose rows straddle a row tile's
+    edge is visited, and its weights read, once a tile, and at 12 rows an
+    expert a tile of 16 rows reads most experts twice (1686 us; the bytes'
+    time is 860)."""
+    if k % 1024 == 0 and n % 1024 == 0:
+        return _GMM_TILING
+    return (_GMM_TILING[0], min(k, 2048), 1024)
+
+
+def _grouped_matmul(xs, w, sizes, out_dtype, tiling=None):
     """``xs[rows of group e] @ w[e]`` for rows sorted by group, ``sizes`` rows
     a group; rows past the last group belong to none and come back
     UNDEFINED (the caller never reads them). On the chip this is the Pallas
@@ -227,7 +248,8 @@ def _grouped_matmul(xs, w, sizes, out_dtype):
     weight tiles: XLA's own lowering of ``jax.lax.ragged_dot`` picks 512 x
     512, which at a prefill chunk's ~40 rows a group is bound by its grid
     steps (1.3 ms a call where the weights' bytes take 0.5; ``PERF.md``
-    section 6, PR 31). Both visit only the groups that have rows. Elsewhere
+    section 6, PR 31). The tile is :func:`_gmm_tiling`'s (``tiling``: the
+    tool's to vary; rows are padded to its row tile). Both visit only the groups that have rows. Elsewhere
     (the CPU tests) ``ragged_dot``'s reference lowering."""
     from ..ops.pallas_kernels import _interpret
 
@@ -236,22 +258,40 @@ def _grouped_matmul(xs, w, sizes, out_dtype):
                                   preferred_element_type=out_dtype)
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 
-    tm = _GMM_TILING[0]
-    pad = -xs.shape[0] % tm
+    tiling = tiling or _gmm_tiling(w.shape[1], w.shape[2])
+    pad = -xs.shape[0] % tiling[0]
     out = gmm(jnp.pad(xs, ((0, pad), (0, 0))) if pad else xs, w, sizes,
-              preferred_element_type=out_dtype, tiling=_GMM_TILING)
+              preferred_element_type=out_dtype, tiling=tiling)
     return out[:xs.shape[0]]
+
+
+def _picks(logits, mp: dict, top_k: int, scoring: str, renorm_eps: float):
+    """The router's picks from its float32 outputs ``logits`` (T, E):
+    ``(weights (T, k), experts (T, k))`` as :func:`moe_experts_ffn`
+    describes them, the weights renormalised over the picks."""
+    if scoring == "softmax":
+        topv, topi = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    elif scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        _, topi = jax.lax.top_k(scores + mp["e_bias"], top_k)
+        topv = jnp.take_along_axis(scores, topi, axis=-1)
+    else:
+        raise ValueError(f"unknown expert scoring {scoring!r}")
+    total = jnp.sum(topv, axis=-1, keepdims=True)
+    return topv / (total + renorm_eps if renorm_eps else total), topi
 
 
 def moe_experts_ffn(mp: dict, h, valid, *, top_k: int, first_expert: int = 0,
                     routed_scale: float = 1.0, compute_dtype=None,
-                    scoring: str = "softmax"):
+                    scoring: str = "softmax", renorm_eps: float = 0.0):
     """The dropless expert layer of the serving path, for a ``(T, d)`` batch
     of tokens — a prefill chunk or a decode bucket's rows alike, so both
     route identically: float32 softmax router over ALL the model's experts
     (``mp["router"]``: (d, E)), the ``top_k`` largest renormalised over
     themselves, SwiGLU experts, the routed sum times ``routed_scale``, plus
-    the shared expert (ungated). The layer holds the experts
+    the shared expert (ungated) where the model has one (``mp["s_gate"]``,
+    ``s_up``, ``s_down``; a layer without them adds nothing and runs no
+    matmul for it). The layer holds the experts
     ``[first_expert, first_expert + held)`` (``mp["e_gate"]``/``e_up``:
     (held, d, f), ``e_down``: (held, f, d)) and computes THEIR part of the
     result: what the absent experts would add is left out. There is no
@@ -264,7 +304,8 @@ def moe_experts_ffn(mp: dict, h, valid, *, top_k: int, first_expert: int = 0,
     ``scoring`` (static) ``sigmoid``: each router output through a sigmoid;
     the picks are the ``top_k`` largest of score + ``mp["e_bias"]`` (E,), a
     per-expert bias that only selects; the weights are the picks' own scores
-    renormalised over themselves.
+    renormalised over themselves (``renorm_eps`` is added to their sum
+    first, where a family's implementation does).
 
     The router reads ``h`` as it comes (float32 from the caller's norm: a
     rounded input flips near-ties among the picks); the experts' matmuls run
@@ -278,15 +319,7 @@ def moe_experts_ffn(mp: dict, h, valid, *, top_k: int, first_expert: int = 0,
     held = mp["e_gate"].shape[0]
     logits = jnp.matmul(h.astype(jnp.float32),
                         mp["router"].astype(jnp.float32), precision="highest")
-    if scoring == "softmax":
-        topv, topi = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
-    elif scoring == "sigmoid":
-        scores = jax.nn.sigmoid(logits)
-        _, topi = jax.lax.top_k(scores + mp["e_bias"], top_k)
-        topv = jnp.take_along_axis(scores, topi, axis=-1)
-    else:
-        raise ValueError(f"unknown expert scoring {scoring!r}")
-    topv = topv / jnp.sum(topv, axis=-1, keepdims=True)            # (T, k)
+    topv, topi = _picks(logits, mp, top_k, scoring, renorm_eps)    # (T, k)
     local = ((topi >= first_expert) & (topi < first_expert + held)
              & valid[:, None])
     # one row per assignment, sorted by held expert; what is not held here
@@ -307,11 +340,12 @@ def moe_experts_ffn(mp: dict, h, valid, *, top_k: int, first_expert: int = 0,
     at = jnp.argsort(order).reshape(T, top_k)
     routed = sum(jnp.where(local[:, j, None], ys[at[:, j]], 0.0)
                  * topv[:, j, None] for j in range(top_k))
-    shared = jnp.matmul(jax.nn.silu(hc @ mp["s_gate"].astype(cd))
-                        * (hc @ mp["s_up"].astype(cd)),
-                        mp["s_down"].astype(cd),
-                        preferred_element_type=jnp.float32)
-    out = routed_scale * routed + shared
+    out = routed_scale * routed
+    if "s_gate" in mp:
+        out = out + jnp.matmul(jax.nn.silu(hc @ mp["s_gate"].astype(cd))
+                               * (hc @ mp["s_up"].astype(cd)),
+                               mp["s_down"].astype(cd),
+                               preferred_element_type=jnp.float32)
     counts = jnp.stack([jnp.sum(valid) * top_k, n_local,
                         jnp.sum(sizes > 0)]).astype(jnp.int32)
     return out.astype(h.dtype), counts

@@ -31,7 +31,11 @@ plain matmul on the MXU (:func:`_page_head_by_head`), or, where a head has
 ONE query row (plain MHA: the shapes say so), every head in one matmul
 against the whole page from a block-diagonal query
 (:func:`_page_one_row_a_head`: thirty chains of one useful row each cost
-more than the page's bytes).
+more than the page's bytes). A head NARROWER than a lane tile (``dh`` 64:
+two heads share one tile, so a head's lane slice is half a tile) takes that
+one-matmul form at any group: the page is never sliced, the block-diagonal
+query holds ``group`` rows a head (:func:`_block_diagonal`, built by the
+caller) and the flush reads ``group`` rows a head.
 
 **Why two layouts, and which model holds which.** In VMEM a ``(page_len,
 kv_heads, dh)`` block is ``page_len`` tiles of ``(kv_heads, dh)``, one a
@@ -242,8 +246,9 @@ def _page_head_by_head(q_ref, k_page, v_page, acc_ref, m_ref, l_ref, live):
     """One live page ``(page_len, kvh * dh)`` of a spec model (``k_page`` /
     ``v_page``: refs of that shape): head ``h``'s keys are the lane slice
     ``[:, h * dh:(h + 1) * dh]``, a ``(page_len, dh)`` matrix of whole tiles
-    at ``dh`` 128, and each head's group of query rows meets it in a plain
-    matmul over the minor dimensions (the latent kernel's form). The same
+    where ``dh`` is a multiple of 128 (the only heads this body is given:
+    :func:`_kv_walk_call`), and each head's group of query rows meets it in
+    a plain matmul over the minor dimensions (the latent kernel's form). The same
     scores, mask (``live``: (group, page_len), true where the row attends
     the position), online softmax and cast as :func:`_page_all_heads`, a
     head at a time (unrolled: ``kvh`` is 4-30); scratch ``m``/``l`` are (kvh,
@@ -272,9 +277,12 @@ def _page_head_by_head(q_ref, k_page, v_page, acc_ref, m_ref, l_ref, live):
 
 def _page_one_row_a_head(qbd_ref, k_page, v_page, acc_ref, m_ref, l_ref, live,
                          dh: int):
-    """One live page of a spec model with ONE query row a KV head (group 1:
-    Olmo-Hybrid's 30 heads): every head in one matmul. ``qbd_ref`` (R, kvh *
-    dh), R the heads padded to whole sublane tiles, holds head ``h``'s query
+    """One live page of a spec model, every head in one matmul: ONE query
+    row a KV head (group 1: Olmo-Hybrid's 30 heads), or ``group`` rows a
+    head where a head is narrower than a lane tile (LFM2's 8 heads of 64 x
+    group 4: row ``h * group + g`` is head ``h``'s, and what follows reads
+    "row ``h``" for each of them). ``qbd_ref`` (R, kvh *
+    dh), R the rows padded to whole sublane tiles, holds head ``h``'s query
     in row ``h`` at lanes ``[h * dh, (h + 1) * dh)`` and zeros elsewhere, so
     ``qbd @ k_page^T`` is (R, page_len) with row ``h`` the scores of head
     ``h`` (the other heads' lanes add exact zeros); one online softmax over
@@ -329,7 +337,19 @@ def _kv_slots(slab) -> int:
     return int(min(_KV_MAX_SLOTS, max(2, _KV_RING_BYTES // pair)))
 
 
-def _kv_walk_kernel(*refs, page_len: int, windowed: bool):
+def _block_diagonal(q):
+    """``q`` (B, kvh, group, dh) as the block-diagonal query of
+    :func:`_page_one_row_a_head`, (B, R, kvh * dh): row ``h * group + g``
+    holds ``q[:, h, g]`` at lanes ``[h * dh, (h + 1) * dh)`` and zeros
+    elsewhere; R the ``kvh * group`` rows padded to whole sublane tiles."""
+    B, kvh, group, dh = q.shape
+    eye = jnp.eye(kvh, dtype=q.dtype)[None, :, None, :, None]
+    qbd = (q[:, :, :, None, :] * eye).reshape(B, kvh * group, kvh * dh)
+    return jnp.pad(qbd, ((0, 0), (0, -(kvh * group) % 16), (0, 0)))
+
+
+def _kv_walk_kernel(*refs, page_len: int, windowed: bool,
+                    block_diagonal: bool = False):
     """Grid (B,): one step a row of a spec model's flat slab, and the kernel
     walks the row's LIVE pages itself, as :func:`_latent_attn_kernel` does.
     ``k_hbm`` / ``v_hbm`` are the whole slabs, left in HBM. The call's live
@@ -355,7 +375,10 @@ def _kv_walk_kernel(*refs, page_len: int, windowed: bool):
     (``tools/attn_page_step.py`` keeps that one as the yardstick): the
     output is that kernel's bit for bit. ONE row a head meets
     :func:`_page_one_row_a_head`, all heads in one matmul, from the
-    block-diagonal query ``qbd_ref`` built here once a row."""
+    block-diagonal query ``qbd_ref`` built here once a row. With
+    ``block_diagonal`` (heads narrower than a lane tile) ``q_ref`` (1, R,
+    kvh * dh) IS that query, ``group`` rows a head
+    (:func:`_block_diagonal`), and meets the same body."""
     n = 4 if windowed else 2               # scalar-prefetched arrays
     (tables_ref, lengths_ref, *window), refs = refs[:n], refs[n:]
     first_ref, lower_ref = window or (None, None)
@@ -404,6 +427,9 @@ def _kv_walk_kernel(*refs, page_len: int, windowed: bool):
     if qbd:
         (qbd_ref,) = qbd
         _block_diagonal_query(q_ref, qbd_ref, dh)
+    elif block_diagonal:
+        qbd_ref = q_ref.at[0]
+    one_matmul = bool(qbd) or block_diagonal
     acc_ref[...] = jnp.zeros_like(acc_ref)
     m_ref[...] = jnp.full_like(m_ref, _MASKED)
     l_ref[...] = jnp.zeros_like(l_ref)
@@ -419,12 +445,12 @@ def _kv_walk_kernel(*refs, page_len: int, windowed: bool):
         # absolute position of column t is page * page_len + t; live iff
         # < length (and, for a window, >= lower)
         at = (first_of(b) + i) * page_len + jax.lax.broadcasted_iota(
-            jnp.int32, (group, page_len), 1)
+            jnp.int32, (1 if block_diagonal else group, page_len), 1)
         live = at < length
         if windowed:
             live &= at >= lower_ref[b]
         slot = jax.lax.rem(base + i, slots)
-        if qbd:
+        if one_matmul:
             _page_one_row_a_head(qbd_ref, k_buf.at[slot], v_buf.at[slot],
                                  acc_ref, m_ref, l_ref, live, dh)
         else:
@@ -432,10 +458,11 @@ def _kv_walk_kernel(*refs, page_len: int, windowed: bool):
                                m_ref, l_ref, live)
 
     used[0] = base + pages_of(b)
-    if qbd:
-        for h in range(kvh):
-            o_ref[0, h] = (acc_ref[h:h + 1, h * dh:(h + 1) * dh]
-                           / l_ref[h:h + 1, :]).astype(o_ref.dtype)
+    if one_matmul:
+        for h in range(kvh):    # the diagonal blocks: group rows a head
+            rows = slice(h * group, (h + 1) * group)
+            o_ref[0, h] = (acc_ref[rows, h * dh:(h + 1) * dh]
+                           / l_ref[rows, :]).astype(o_ref.dtype)
     else:
         o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
@@ -452,6 +479,7 @@ def _kv_walk_call(q, k_pages, v_pages, tables, lengths, window=(), *,
     ring = 2 * slots * page_len * width * k_pages.dtype.itemsize
     row_spec = pl.BlockSpec((1, kvh, group, dh), lambda b, *_: (b, 0, 0, 0))
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    narrow = group > 1 and dh % 128 != 0   # a head's lanes: part of a tile
     if group == 1:
         heads = -(-kvh // 16) * 16      # whole sublane tiles in either dtype
         q = q.reshape(B, 1, width)
@@ -460,6 +488,13 @@ def _kv_walk_call(q, k_pages, v_pages, tables, lengths, window=(), *,
                  pltpu.VMEM((heads, 1), jnp.float32),      # running max m
                  pltpu.VMEM((heads, 1), jnp.float32),      # normalizer l
                  pltpu.VMEM((heads, width), q.dtype)]      # block-diagonal q
+    elif narrow:
+        q = _block_diagonal(q)
+        heads = q.shape[1]
+        q_spec = pl.BlockSpec((1, heads, width), lambda b, *_: (b, 0, 0))
+        state = [pltpu.VMEM((heads, width), jnp.float32),
+                 pltpu.VMEM((heads, 1), jnp.float32),
+                 pltpu.VMEM((heads, 1), jnp.float32)]
     else:
         q_spec = row_spec
         state = [pltpu.VMEM((kvh, group, dh), jnp.float32),
@@ -467,7 +502,7 @@ def _kv_walk_call(q, k_pages, v_pages, tables, lengths, window=(), *,
                  pltpu.VMEM((kvh, group, 1), jnp.float32)]
     return pl.pallas_call(
         functools.partial(_kv_walk_kernel, page_len=page_len,
-                          windowed=bool(window)),
+                          windowed=bool(window), block_diagonal=narrow),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2 + len(window),
             grid=(B,),

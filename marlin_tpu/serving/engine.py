@@ -453,8 +453,10 @@ class ServeEngine:
         if self._spec is not None and self._spec.has_state:
             #: the prefill dispatch span's field for the tokens a chunk's
             #: mixers scan, named by the mixer's kind
-            self._mixer_tokens = ("ssm_tokens" if self._spec.ssm is not None
-                                  else "delta_tokens")
+            self._mixer_tokens = (
+                "ssm_tokens" if self._spec.ssm is not None else
+                "delta_tokens" if self._spec.delta is not None else
+                "conv_tokens")
             # a shared page is no use without the recurrent state at the
             # prefix's end: sharing is opt-in, and rests on snapshots
             self._prefix_cache = bool(prefix_cache)

@@ -271,6 +271,7 @@ class PagedKVPool:
                  ring: int = 0, state_slots: int = 0,
                  snapshot_slots: int = 0):
         from ..models.hybrid import ModelSpec
+        from ..models.planner import kv_page_bytes
         from ..models.transformer import init_kv_pages
 
         self.page_len = int(page_len)
@@ -296,6 +297,12 @@ class PagedKVPool:
         self._snaps: OrderedDict[bytes, int] = OrderedDict()
         self._snap_hit: set[bytes] = set()
         self._spec = heads if stateful else None
+        #: a snapshot that holds no more than the page it stands behind (a
+        #: short convolution's tails: tens of kilobytes) is taken behind
+        #: EVERY chunk (:meth:`snapshot_due`)
+        self._snapshot_cheap = stateful and heads.state_slot_bytes(
+            compute_dtype) <= kv_page_bytes(params, heads, self.page_len,
+                                            compute_dtype)
         self.snapshots_taken = 0
         self.snapshot_evictions = 0
         #: pages of the window class (0: the model has no sliding layer)
@@ -447,10 +454,16 @@ class PagedKVPool:
         left a prefix whose first prompts all ran past it without a
         snapshot for good, and every later request prefilled it again:
         PERF.md, PR 42.) A shallower boundary serves only a request that
-        diverges inside a prefix nobody has been seen to share."""
+        diverges inside a prefix nobody has been seen to share, which is
+        worth a slot only where slots are cheap: a model whose slot holds no
+        more than one of its pages (a short convolution's tails: a twentieth
+        of one; a recurrent matrix makes it two to eight) gets one behind EVERY chunk that ends
+        on a shareable boundary, so a prefix has its snapshot from the first
+        prompt that runs through it, whatever lies behind it."""
         limit = self._share_limit(prompt_len)
         return bool(self.snapshot_slots) and chunk_end <= limit and (
-            limit < chunk_end + chunk or chunk_end == seen_len)
+            self._snapshot_cheap or limit < chunk_end + chunk
+            or chunk_end == seen_len)
 
     def alloc_snapshot(self) -> int:
         """A snapshot slot for a row about to copy its state: a free one,

@@ -650,9 +650,9 @@ def test_rmm_20000_compiles_for_2x2_v5e(topo_mesh):
 
 def _compile_spec_decode_kernel(one_chip, group, page_len, pages, width,
                                 rows=32, kvh=8, window=False, flat=True,
-                                dtype=jnp.bfloat16):
+                                dtype=jnp.bfloat16, dh=128):
     """A spec model's decode kernel through real Mosaic: ``rows`` rows,
-    ``kvh`` KV heads x 128, a slab of ``pages`` pages held as
+    ``kvh`` KV heads x ``dh``, a slab of ``pages`` pages held as
     :func:`~marlin_tpu.models.hybrid.init_kv_pages` holds them (``flat``:
     a token's heads in one row) and a table ``width`` wide; the plain call,
     or the window call over a ring of ``width``. Returns the compiled
@@ -663,7 +663,6 @@ def _compile_spec_decode_kernel(one_chip, group, page_len, pages, width,
     def st(shape, dt=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
-    dh = 128
     q = st((rows, kvh, group, dh), dtype)
     slab = st((pages, page_len, kvh * dh) if flat
               else (pages, page_len, kvh, dh), dtype)
@@ -944,3 +943,89 @@ def test_delta_update_kernel_mosaic_compiles_in_place_at_published_widths(
     m = copied.memory_analysis()
     assert m.alias_size_in_bytes >= slab_bytes
     assert m.temp_size_in_bytes < slab_bytes // 8
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_paged_kernel_mosaic_compiles_at_eight_heads_of_64(one_chip, dtype):
+    """``serve.lfm2-agent96``'s call: 96 rows of 8 KV heads x 64 (two heads
+    to a lane tile), four query rows a head, over 24 pages of the 1537-page
+    slab, page_len 256. The walk never slices a head's half tile out of the
+    page: every head meets the whole ``(256, 512)`` page in one matmul from
+    the block-diagonal query, which the caller builds (32 rows x 512 lanes a
+    row of the call: the one temporary, 3 MB in bfloat16); the slabs stay in
+    HBM."""
+    from marlin_tpu.ops.paged_attention import _paged_decode_attention_call
+
+    def st(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    rows, kvh, group, dh, pages, width = 96, 8, 4, 64, 1537, 24
+    slab = st((pages, 256, kvh * dh), dtype)
+    c = _paged_decode_attention_call.trace(
+        st((rows, kvh, group, dh), dtype), slab, slab, st((rows, width)),
+        st((rows,)), page_len=256, interpret=False).lower().compile()
+    assert "tpu_custom_call" in c.as_text()
+    query = rows * kvh * group * kvh * dh * jnp.dtype(dtype).itemsize
+    assert c.memory_analysis().temp_size_in_bytes <= 2 * query
+
+
+def test_lfm2_programs_compile_for_v5e_at_the_cells_sizes(one_chip):
+    """Both paged programs of the ``lfm2_moe`` family at the cell's widths,
+    rows, page and table (hidden 2048, 32 / 8 heads x 64, dense 7168, ALL 32
+    experts of 1792, 65536 rows tied, 96 rows, pages of 256, a table of 24,
+    a chunk of 512, 1537 pages, 353 slots), four layers (both dense ones, a
+    full-attention layer and a conv layer on experts), compiled whole for a
+    v5e: the attention kernel and the six grouped matmuls inside, the slabs
+    aliased, the tails ONE array a conv layer, no second table for the head
+    and no temporary the size of a slab; so does the snapshot's copy."""
+    import json
+    import os
+
+    from marlin_tpu.models import hybrid
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmarks", "configs",
+                           "lfm2-8b-a1b-l16.json")) as f:
+        cfg = json.load(f)
+    eng = cfg["engine"]
+    spec = hybrid.ModelSpec.from_config(dict(cfg, num_hidden_layers=4))
+    assert [ly.attn for ly in spec.layers] == ["conv", "conv", "full", "conv"]
+    B, page_len = eng["max_batch"], eng["page_len"]
+    W = sum(eng["buckets"][-1]) // page_len
+    slots = eng["state_slots"] + eng["snapshot_slots"]
+
+    def sds(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            jnp.shape(x), x.dtype, sharding=one_chip), tree)
+
+    def st(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    params = sds(jax.eval_shape(
+        lambda: hybrid.init_params(spec, jax.random.key(0))))
+    assert "head" not in params
+    pages = sds(jax.eval_shape(lambda: hybrid.init_kv_pages(
+        spec, eng["num_pages"], 0, page_len, state_slots=slots)))
+    assert [a.shape for a in pages["l3"]] == [(slots, 2, 2048)]
+    slab = eng["num_pages"] * page_len * 512 * 2
+    with mt.config_context(pallas_interpret=False):
+        decode = hybrid._lm_decode_paged_spec_jit.trace(
+            params, pages, st((B, W)), st((B, 0)), st((B,)), st((B,)),
+            st((B,)), st((B,), jnp.uint32), st((B,), jnp.float32),
+            st((B,), jnp.float32), st((B,)), spec=spec, page_len=page_len,
+            kernel="pallas", prev_tokens=st((B,)), prev_index=st((B,)),
+            state_slots=st((B,))).lower().compile()
+        prefill = hybrid._lm_prefill_paged_spec_jit.trace(
+            params, pages, st((W + 2,)), st((0,)),
+            st((eng["prefill_chunk"],)), st(()), st(()), st((), jnp.uint32),
+            st((), jnp.float32), st((), jnp.float32), st(()), spec=spec,
+            page_len=page_len, state_slot=st(())).lower().compile()
+        copy = hybrid._state_slot_copy_jit.trace(
+            pages, st(()), st(()), spec=spec).lower().compile()
+    assert decode.as_text().count("tpu_custom_call") >= 1 + 6
+    assert prefill.as_text().count("tpu_custom_call") >= 6
+    for c in (decode, prefill, copy):
+        m = c.memory_analysis()
+        assert m.alias_size_in_bytes >= 2 * slab
+        assert m.temp_size_in_bytes < slab // 4

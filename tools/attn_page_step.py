@@ -7,10 +7,12 @@ off the previous call's output): a call of 50-600 us dispatched from the host
 reads the host. Two chains a line, one at the cell's lengths and one with
 every row at length 1, give two unknowns.
 
-**The (K, V) kernel** at the four calls the spec cells make
+**The (K, V) kernel** at the five calls the spec cells make
 (``olmohybrid.global``, ``falconh1.global``, ``laguna.global``,
-``laguna.window``), over a flat slab ``(num_pages, page_len, kv_heads *
-head_dim)``. ``<shape>`` is the package's kernel, which since PR 43 takes a
+``laguna.window``, and ``lfm2.global``: 8 heads of 64, two to a lane tile,
+where the package takes the all-heads body and ``.grid`` / ``.compute_only``
+say whether head by head lowers there at all and at what cost), over a flat
+slab ``(num_pages, page_len, kv_heads * head_dim)``. ``<shape>`` is the package's kernel, which since PR 43 takes a
 grid step a ROW and walks the call's live pages itself (their copies in
 flight in a ring of slots): microseconds a live page and a row (it has no
 dead step), the call at other ring sizes (``call_us_by_slots``), and the same
@@ -64,10 +66,13 @@ SHAPES = {
                           live_pages=190),
     "laguna.window": dict(rows=32, width=4, kvh=8, group=9, pages=145,
                           window=512),
+    "lfm2.global": dict(rows=96, width=24, kvh=8, group=4, pages=1537,
+                        tokens=(2200, 5100), dh=64),
 }
 # ring sizes the walk is also timed at (the package's own: _kv_slots)
 SLOTS = {"olmohybrid.global": (2, 4), "falconh1.global": (2, 3, 4, 8),
-         "laguna.global": (2, 3, 4, 8), "laguna.window": (2, 4)}
+         "laguna.global": (2, 3, 4, 8), "laguna.window": (2, 4),
+         "lfm2.global": (2, 4)}
 PAGE_LEN, HEAD_DIM = 256, 128
 # the latent call: (rows, table width, heads, entry as stored, value columns,
 # pages of the slab, (least, most) tokens a row): a 16384-token document, a
@@ -202,21 +207,6 @@ def _values_only(q_ref, k_page, v_page, acc_ref, m_ref, l_ref, live):
             preferred_element_type=jnp.float32)
 
 
-def _block_diagonal(q, rows: int):
-    """``q`` (B, kvh, group, dh) as the all-heads body's left operand (B,
-    rows, kvh * dh): query row ``(h, g)`` in row ``h * group + g`` at head
-    ``h``'s lanes, zeros elsewhere and in the padding rows."""
-    import jax.numpy as jnp
-
-    B, kvh, group, dh = q.shape
-    flat = jnp.tile(q.reshape(B, kvh * group, 1, dh),
-                    (1, 1, kvh, 1)).reshape(B, kvh * group, kvh * dh)
-    own = (jnp.arange(kvh * dh) // dh)[None, :] == (
-        jnp.arange(kvh * group) // group)[:, None]
-    return jnp.pad(jnp.where(own, flat, 0),
-                   ((0, 0), (0, rows - kvh * group), (0, 0)))
-
-
 def _grid_kv_call(mode: str = "both", body: str = "head_by_head"):
     """The (K, V) kernel over a flat slab as the package held it until PR
     43: grid (rows, table width), one page of K and of V a grid step through
@@ -229,8 +219,8 @@ def _grid_kv_call(mode: str = "both", body: str = "head_by_head"):
     freezes the index map on page 1. ``body``: that arithmetic's parts
     (``scores`` / ``values``: :func:`_scores_only`, :func:`_values_only`),
     or the package's other page body, :func:`_page_one_row_a_head`, at ANY
-    group (``all_heads``: the block-diagonal query built out here,
-    :func:`_block_diagonal`; the accumulator (rows, kvh * dh), of which the
+    group (``all_heads``: the block-diagonal query built out here, the
+    package's ``_block_diagonal``; the accumulator (rows, kvh * dh), of which the
     flush reads each head's rows at its lanes), each under the same grid."""
     import jax
     import jax.numpy as jnp
@@ -306,8 +296,8 @@ def _grid_kv_call(mode: str = "both", body: str = "head_by_head"):
         q_spec, shapes = row_spec, [(kvh, group, dh), (kvh, group, 1),
                                     (kvh, group, 1)]
         if body == "all_heads":
-            rows = -(-kvh * group // 16) * 16   # whole tiles in either dtype
-            q = _block_diagonal(q, rows)
+            q = pa._block_diagonal(q)   # rows: whole tiles in either dtype
+            rows = q.shape[1]
             q_spec = pl.BlockSpec((1, rows, width), lambda b, w, *_: (b, 0, 0))
             shapes = [(rows, width), (rows, 1), (rows, 1)]
         return pl.pallas_call(
@@ -382,13 +372,13 @@ def measure(name: str, dtype, seed: int = 0) -> list[dict]:
     shape = SHAPES[name]
     rows, width, kvh, group, pages = (shape[k] for k in (
         "rows", "width", "kvh", "group", "pages"))
-    window = shape.get("window")
+    window, head_dim = shape.get("window"), shape.get("dh", HEAD_DIM)
     rng = np.random.default_rng(seed)
     kq, kk, kv = jax.random.split(jax.random.key(seed), 3)
-    slab = (pages, PAGE_LEN, kvh * HEAD_DIM)
+    slab = (pages, PAGE_LEN, kvh * head_dim)
     k = jax.random.normal(kk, slab, jnp.float32).astype(dtype)
     v = jax.random.normal(kv, slab, jnp.float32).astype(dtype)
-    q = jax.random.normal(kq, (rows, kvh, group, HEAD_DIM),
+    q = jax.random.normal(kq, (rows, kvh, group, head_dim),
                           jnp.float32).astype(dtype)
     tables = jnp.asarray(rng.integers(1, pages, (rows, width)), jnp.int32)
     cell = jnp.asarray(_lengths(rng, shape))
@@ -402,7 +392,8 @@ def measure(name: str, dtype, seed: int = 0) -> list[dict]:
     base = {"dtype": str(jnp.dtype(dtype)), "rows": rows,
             "table_width": width, "kv_heads": kvh, "group": group,
             "live_pages": live,
-            "page_bytes": 2 * PAGE_LEN * kvh * HEAD_DIM
+            "head_dim": head_dim,
+            "page_bytes": 2 * PAGE_LEN * kvh * head_dim
             * jnp.dtype(dtype).itemsize}
     base["bytes_us_a_page"] = base["page_bytes"] / 819e9 * 1e6
     calls = {name: _walk_kv_call(),

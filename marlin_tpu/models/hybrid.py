@@ -1717,6 +1717,7 @@ def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
                                top_k, spec: ModelSpec, page_len: int,
                                state_slot=None):
     from ..ops import delta_rule, ssm as ssm_ops
+    from ..ops.paged_attention import fetch_pages
     from .transformer import _pick_token_row, _row_key
 
     C = chunk.shape[0]
@@ -1730,10 +1731,12 @@ def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
     ring = wtable.shape[0]
     wp = spec.window // page_len if spec.has_window else 0
     qb = min(C, max(page_len, C // 4))  # a sliding layer's query sub-block
-    # the context a chunk reads, gathered up front and pinned (see
-    # transformer._lm_prefill_paged_jit: the slab must not be re-laid-out):
-    # a full layer's whole table; a sliding layer's ring slots that hold the
-    # window/page_len pages before the chunk, in position order
+    # the context a chunk reads, fetched up front and pinned (see
+    # transformer._lm_prefill_paged_jit: the slab must not be re-laid-out),
+    # a page a copy (XLA's gather of a row wider than 1024 lanes passes over
+    # the WHOLE slab: PERF.md section 6, PR 45): a full layer's whole table;
+    # a sliding layer's ring slots that hold the window/page_len pages before
+    # the chunk, in position order
     Lg = gtable.shape[0] * page_len
     w_slots = wtable[jnp.mod(s_page - wp + jnp.arange(wp), max(ring, 1))]
     ltable = jnp.pad(gtable, (0, flash_table_pages(
@@ -1741,10 +1744,13 @@ def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
     by_kind = {"full": gtable, "sliding": w_slots, "latent": ltable,
                "linear": gtable,     # a linear layer has no slab to gather
                "conv": gtable}       # nor has a conv layer
-    ctx = jax.lax.optimization_barrier({
-        name: tuple(t[by_kind[ly.attn]].reshape(-1, *t.shape[2:])
-                    for t in _kv_slabs(ly, pages[name]))
-        for name, ly in ((f"l{i}", ly) for i, ly in enumerate(spec.layers))})
+    with jax.named_scope("ctx_gather"):
+        ctx = jax.lax.optimization_barrier({
+            name: tuple(fetch_pages(t, by_kind[ly.attn])
+                        .reshape(-1, *t.shape[2:])
+                        for t in _kv_slabs(ly, pages[name]))
+            for name, ly in ((f"l{i}", ly)
+                             for i, ly in enumerate(spec.layers))})
     g_pos = jnp.arange(Lg)
     w_pos = jnp.concatenate([(s_page - wp) * page_len
                              + jnp.arange(wp * page_len), q_pos])

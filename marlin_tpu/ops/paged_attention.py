@@ -844,3 +844,51 @@ def paged_decode_attention_latent(q, slab, tables, lengths, value_dim: int,
     return _paged_decode_attention_latent_call(
         q, slab, tables, lengths, value_dim=int(value_dim),
         interpret=bool(interpret))
+
+
+# prefill's side of the slab, below the decode kernels: their programs'
+# cache keys hold their line numbers (PERF.md section 7)
+__all__ += ["fetch_pages"]
+
+
+def _fetch_pages_kernel(table_ref, slab_ref, out_ref, sem):
+    """One grid step: page ``table[j]`` of the slab to page ``j`` of the
+    output, HBM to HBM, every copy started before the first is awaited (all
+    are one size, so they share the semaphore)."""
+    def copy(j):
+        return pltpu.make_async_copy(slab_ref.at[table_ref[j]],
+                                     out_ref.at[j], sem)
+
+    n = out_ref.shape[0]
+    jax.lax.fori_loop(0, n, lambda j, _: copy(j).start(), None)
+    jax.lax.fori_loop(0, n, lambda j, _: copy(j).wait(), None)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _fetch_pages_call(slab, table, interpret: bool):
+    return pl.pallas_call(
+        _fetch_pages_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(1,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[pltpu.SemaphoreType.DMA(())]),
+        out_shape=jax.ShapeDtypeStruct((table.shape[0], *slab.shape[1:]),
+                                       slab.dtype),
+        interpret=interpret)(table, slab)
+
+
+def fetch_pages(slab, table, interpret: bool | None = None) -> jax.Array:
+    """``slab[table]`` for a slab ``(num_pages, page_len, ...)`` and a table
+    ``(W,)`` of page ids: ``(W, page_len, ...)``, the same elements, each
+    page one copy that reads its page and nothing else. What a prefill chunk
+    of a spec model fetches a row's context with (``hybrid
+    ._lm_prefill_paged_spec_jit``, scope ``ctx_gather``): XLA's gather of a
+    row wider than 1024 lanes passes over the whole slab, and the kernel is
+    opaque to the compiler, which fused page-sized slices of a slab with the
+    chunk's page WRITES and rematerialized around them (PERF.md section 6,
+    PR 45)."""
+    if interpret is None:
+        interpret = _interpret()
+    return _fetch_pages_call(slab, jnp.asarray(table, jnp.int32),
+                             interpret=bool(interpret))

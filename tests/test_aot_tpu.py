@@ -1029,3 +1029,34 @@ def test_lfm2_programs_compile_for_v5e_at_the_cells_sizes(one_chip):
         m = c.memory_analysis()
         assert m.alias_size_in_bytes >= 2 * slab
         assert m.temp_size_in_bytes < slab // 4
+
+
+def test_the_context_fetch_compiles_for_v5e_and_passes_over_no_slab(one_chip):
+    """A prefill chunk's fetch of a row's context at the Olmo-Hybrid cell's
+    shapes (``paged_attention.fetch_pages``: a slab of 320 pages x 256
+    tokens x 3840 lanes, a table of 17) through real Mosaic: one kernel, no
+    operation whose output is as large as the slab or a lane piece of it, no
+    temporary. The gather it replaced does hold such operations at this
+    width (four ``slice``s of the slab), which is what a chunk paid 15.5 of
+    its 51 ms for (PERF.md section 6, PR 45)."""
+    import re
+
+    import marlin_tpu as mt
+    from marlin_tpu.ops.paged_attention import fetch_pages
+
+    def st(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def over_slab(fetch, lanes):
+        with mt.config_context(pallas_interpret=False):
+            c = jax.jit(fetch).trace(
+                st((320, 256, lanes), jnp.bfloat16), st((17,))) \
+                .lower().compile()
+        ops = re.findall(r"= \w+\[320,256,\d+\]\S* ([\w\-]+)\(", c.as_text())
+        return c, [op for op in ops if op != "parameter"]
+
+    c, ops = over_slab(fetch_pages, 3840)
+    assert "tpu_custom_call" in c.as_text() and not ops
+    assert c.memory_analysis().temp_size_in_bytes < 1 << 20
+    _, ops = over_slab(lambda t, tb: t[tb], 3840)
+    assert ops.count("slice") == 4

@@ -1081,15 +1081,15 @@ def init_kv_pages(spec: ModelSpec, num_pages: int, window_pages: int,
     (``(window_pages, ...)`` for a sliding one); ONE array ``(num_pages,
     page_len, entry_width)`` for a latent layer
     (:attr:`LatentSpec.entry_width`). Page 0 of each class is its dummy. A
-    token's heads lie side by side in ONE row, so head ``h``'s keys of a page
-    are the lane slice ``[:, h * head_dim:(h + 1) * head_dim]``, a matrix of
-    whole tiles where ``head_dim`` is a multiple of 128, which the decode
-    kernel contracts on the MXU as it is; at a narrower head (64: two heads
-    share a lane tile) the kernel never slices the page: every head meets
-    the whole page in one matmul from a block-diagonal query
+    token's heads lie side by side in ONE row, so a decode step's entry is
+    one contiguous row and a page one contiguous copy, which the decode
+    kernel contracts on the MXU as it is: it never slices the page, every
+    head meets the whole page in one matmul from a block-diagonal query,
+    whatever the heads' count, width (128, or 64: two heads to a lane tile)
+    and query rows
     (:func:`~marlin_tpu.ops.paged_attention
-    .paged_decode_attention` picks its body by the slab's rank and the
-    head's width; held
+    .paged_decode_attention` picks its body by the slab's rank alone;
+    held
     ``(page_len, kv_heads, head_dim)``, as the dense model's 16-token pages
     are, a 256-token page cost the kernel 8-9 us a step for 0.6-1.3 us of
     bytes: PERF.md, PR 38). A

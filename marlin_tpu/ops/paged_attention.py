@@ -24,33 +24,36 @@ case), and the score/value contractions are the SAME as
 ``kgt,tkd->kgd``, f32 scores, masked positions at −1e30) so the kernel's
 math is the reference path's math, re-scheduled: on a rank-4 page as
 those two einsums, batched over the block's MIDDLE axis
-(:func:`_page_all_heads`); on a rank-3 page one KV head at a time, head
-``h``'s keys the lane slice ``[:, h * dh:(h + 1) * dh]``, a ``(page_len,
-dh)`` matrix of whole tiles that meets the head's ``group`` query rows in a
-plain matmul on the MXU (:func:`_page_head_by_head`), or, where a head has
-ONE query row (plain MHA: the shapes say so), every head in one matmul
-against the whole page from a block-diagonal query
-(:func:`_page_one_row_a_head`: thirty chains of one useful row each cost
-more than the page's bytes). A head NARROWER than a lane tile (``dh`` 64:
-two heads share one tile, so a head's lane slice is half a tile) takes that
-one-matmul form at any group: the page is never sliced, the block-diagonal
-query holds ``group`` rows a head (:func:`_block_diagonal`, built by the
-caller) and the flush reads ``group`` rows a head.
+(:func:`_page_all_heads`); on a rank-3 page as ONE matmul for every head
+(:func:`_page_every_head`): the row's ``kv_heads * group`` queries stand one
+under the other in a block-diagonal query (row ``h * group + g`` holds head
+``h``'s ``g``-th query at the head's lanes ``[h * dh, (h + 1) * dh)`` and
+zeros elsewhere: :func:`_block_diagonal_query`, built in VMEM once a row),
+the page is the other operand whole, never sliced, and the other heads'
+lanes add exact zeros to a score. That holds for any count of heads, any
+group and any head width (a head of 64 shares a lane tile with its
+neighbour and is no special case), so a flat slab has one body.
 
 **Why two layouts, and which model holds which.** In VMEM a ``(page_len,
 kv_heads, dh)`` block is ``page_len`` tiles of ``(kv_heads, dh)``, one a
 token, 4 or 8 of a tile's sublanes filled, and one head's keys are one
 sublane out of each: the batched einsum pays a relayout that goes with
 ``page_len`` and not with bytes. At a spec model's 256-token pages that was
-8.4-9.1 us a live grid step for 0.64-1.28 us of bytes; head by head over
-the flat page it is 1.3-2.8 us (Falcon-H1's 4 heads x group 5, Laguna's 8
-x 6 and 8 x 9; ``tools/attn_page_step.py``, PERF.md PR 38), and the
-float32 blocks of a 256-token page fit scoped VMEM, which the relayout's
-did not. The dense model keeps rank 4 and the einsum body: with ONE query
-row a head and 16 keys a page a per-head matmul is 64 tile loads a page for
-a handful of useful rows, and that kernel's remedy is more tokens a grid
-step, another mechanism (ROADMAP Queue 3 has the condition that ends the
-split).
+8.4-9.1 us a live grid step for 0.64-1.28 us of bytes. Over the flat page a
+head's keys are a lane slice ``[:, h * dh:(h + 1) * dh]`` of whole tiles,
+and contracted a head at a time that is 1.3-2.8 us (PR 38: Falcon-H1's 4
+heads x group 5, Laguna's 8 x 6 and 8 x 9), the float32 blocks of a
+256-token page fit scoped VMEM, which the relayout's did not; but a head at
+a time is ``kv_heads`` chains of matmul, softmax, matmul that each wait for
+their own results, 1.15-2.28 us a page for copies of 0.70-1.41 (7.95 for
+5.2 at Olmo-Hybrid's thirty heads of one query row). Every head in one
+matmul does ``kv_heads`` times the useful flops and costs 0.55-1.5 us, under
+the page's copy at every shape a configuration has (R = 32 to 80 query rows;
+``tools/attn_page_step.py``, which keeps the head-at-a-time body as its
+yardstick; PERF.md PR 43, PR 44, PR 46). The dense model keeps rank 4 and
+the einsum body: with ONE query row a head and 16 keys a page its remedy is
+more tokens a grid step, another mechanism (ROADMAP Queue 3 has the
+condition that ends the split).
 
 **Two schedules.** The dense model's kernel (:func:`_paged_attn_kernel`)
 lays a grid over (B, W), pages innermost: the block table drives the Pallas
@@ -67,10 +70,11 @@ row costs its flush and not a copy's latency. A table entry past the row's
 length costs nothing at all, where the grid pays a step that does nothing
 (~0.2 us: four fifths of the steps at the chat cells' fill), and a live
 page's copy runs beside the pages before it instead of in front of its own
-arithmetic. The pages meet the online softmax in the same order, and head
-by head with the same arithmetic, so there the walk's output is the grid
-form's bit for bit (``tools/attn_page_step.py`` keeps the grid form of the
-flat slab as the yardstick; tests/test_paged_attention.py compares them).
+arithmetic. The pages meet the online softmax in the same order and with
+the same products, so on the chip the walk's output is the grid form's bit
+for bit (``tools/attn_page_step.py`` keeps the grid form of the flat slab,
+a head at a time, as the yardstick; tests/test_paged_attention.py compares
+them on the CPU, where a wider contraction sums in another order).
 
 Softmax is the online (flash) form: running max ``m``, normalizer ``l`` and
 the f32 accumulator live in VMEM scratch across a row's pages; each page
@@ -242,64 +246,32 @@ def _page_all_heads(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, keep):
     acc_ref[:] = acc_ref[:] * alpha[:, :, None] + pv
 
 
-def _page_head_by_head(q_ref, k_page, v_page, acc_ref, m_ref, l_ref, live):
+def _page_every_head(qbd_ref, k_page, v_page, acc_ref, m_ref, l_ref, live,
+                     dh: int):
     """One live page ``(page_len, kvh * dh)`` of a spec model (``k_page`` /
-    ``v_page``: refs of that shape): head ``h``'s keys are the lane slice
-    ``[:, h * dh:(h + 1) * dh]``, a ``(page_len, dh)`` matrix of whole tiles
-    where ``dh`` is a multiple of 128 (the only heads this body is given:
-    :func:`_kv_walk_call`), and each head's group of query rows meets it in
-    a plain matmul over the minor dimensions (the latent kernel's form). The same
-    scores, mask (``live``: (group, page_len), true where the row attends
-    the position), online softmax and cast as :func:`_page_all_heads`, a
-    head at a time (unrolled: ``kvh`` is 4-30); scratch ``m``/``l`` are (kvh,
-    group, 1). Read from the other layout, one head's keys are one sublane
-    out of each of ``page_len`` tiles, and the relayout costs several times
-    the page's bytes (PERF.md, PR 38)."""
-    kvh, group, dh = q_ref.shape[1:]
-    nt = (((1,), (1,)), ((), ()))      # contract both minor dimensions
-    for h in range(kvh):
-        q = q_ref[0, h]                            # (group, dh)
-        cols = slice(h * dh, (h + 1) * dh)
-        s = jax.lax.dot_general(
-            q, k_page[:, cols], nt,
-            preferred_element_type=jnp.float32) / math.sqrt(dh)
-        s = jnp.where(live, s, _MASKED)
-        m_prev = m_ref[h]                          # (group, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        m_ref[h] = m_new
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[h] = acc_ref[h] * alpha + jnp.dot(
-            p.astype(q.dtype), v_page[:, cols],
-            preferred_element_type=jnp.float32)
-
-
-def _page_one_row_a_head(qbd_ref, k_page, v_page, acc_ref, m_ref, l_ref, live,
-                         dh: int):
-    """One live page of a spec model, every head in one matmul: ONE query
-    row a KV head (group 1: Olmo-Hybrid's 30 heads), or ``group`` rows a
-    head where a head is narrower than a lane tile (LFM2's 8 heads of 64 x
-    group 4: row ``h * group + g`` is head ``h``'s, and what follows reads
-    "row ``h``" for each of them). ``qbd_ref`` (R, kvh *
-    dh), R the rows padded to whole sublane tiles, holds head ``h``'s query
-    in row ``h`` at lanes ``[h * dh, (h + 1) * dh)`` and zeros elsewhere, so
-    ``qbd @ k_page^T`` is (R, page_len) with row ``h`` the scores of head
-    ``h`` (the other heads' lanes add exact zeros); one online softmax over
-    the R rows; ``p @ v_page`` is (R, kvh * dh), of which row ``h`` holds
-    head ``h``'s values at ITS lanes (elsewhere another head's values under
-    this head's probabilities, never read): the accumulator keeps all of it
-    and the flush reads the diagonal blocks. Head by head the same page is
-    30 chains of matmul, softmax, matmul of one useful row, each waiting
-    for its own results: 7.95 us a page alone on the chip, against 1.5 us
-    this way and 5.2 us for the page's copy (``tools/attn_page_step.py``,
-    PERF.md PR 43). The same products, mask, online softmax and cast as
-    :func:`_page_head_by_head`; scratch ``m``/``l`` are (R, 1), ``acc`` (R,
-    kvh * dh)."""
+    ``v_page``: refs of that shape), every head in ONE matmul. ``qbd_ref``
+    (R, kvh * dh) is the row's block-diagonal query
+    (:func:`_block_diagonal_query`): query row ``h * group + g`` holds head
+    ``h``'s ``g``-th query at lanes ``[h * dh, (h + 1) * dh)`` and zeros
+    elsewhere, so ``qbd @ k_page^T`` is (R, page_len) with that row the
+    scores of head ``h`` (the other heads' lanes add exact zeros); one online
+    softmax over the R rows (``live``: (1, page_len), true where the row
+    attends the position); ``p @ v_page`` is (R, kvh * dh), of which a row
+    of head ``h`` holds its values at ITS lanes (elsewhere another head's
+    values under this head's probabilities, never read): the accumulator
+    keeps all of it and the flush reads the diagonal blocks. The page is
+    never sliced, so a head narrower than a lane tile (``dh`` 64) is no
+    special case. (A key that is not finite in ANY head's lanes of a live
+    page reaches every head's scores, ``0 x Inf``, where a head at a time
+    it reached its own head's: pages hold what the programs wrote.) The
+    same scores, mask, online softmax and cast as
+    :func:`_page_all_heads`; scratch ``m``/``l`` are (R, 1), ``acc`` (R, kvh
+    * dh). ``kvh`` times the useful flops, and still the cheaper form than a
+    head at a time (module docstring; PERF.md PR 43, PR 46)."""
     nt = (((1,), (1,)), ((), ()))      # contract both minor dimensions
     s = jax.lax.dot_general(qbd_ref[...], k_page[...], nt,
                             preferred_element_type=jnp.float32) / math.sqrt(dh)
-    s = jnp.where(live, s, _MASKED)                # live: (1, page_len)
+    s = jnp.where(live, s, _MASKED)
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     m_ref[...] = m_new
@@ -311,16 +283,20 @@ def _page_one_row_a_head(qbd_ref, k_page, v_page, acc_ref, m_ref, l_ref, live,
         preferred_element_type=jnp.float32)
 
 
-def _block_diagonal_query(q_ref, qbd_ref, dh: int):
-    """``qbd_ref`` (R, kvh * dh) from ``q_ref`` (1, 1, kvh * dh), the heads'
-    one query row each side by side: row ``h`` keeps head ``h``'s lanes, the
-    rest is zero (:func:`_page_one_row_a_head`)."""
+def _block_diagonal_query(q_ref, qbd_ref, group: int, dh: int):
+    """``qbd_ref`` (R, kvh * dh) from ``q_ref`` (1, R, dh), a row's queries
+    one under the other (row ``h * group + g`` is head ``h``'s ``g``-th; the
+    rows past ``kvh * group`` pad R to whole sublane tiles): every row
+    repeated across the heads' lanes, kept where the lanes are its own
+    head's, zero elsewhere (:func:`_page_every_head`). Built in VMEM once a
+    row: built by the caller it is an array ``kvh`` times the query's size
+    written and read back a call."""
+    kvh = qbd_ref.shape[1] // dh
     row = jax.lax.broadcasted_iota(jnp.int32, qbd_ref.shape, 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, qbd_ref.shape, 1)
-    qbd_ref[...] = jnp.where(
-        col // dh == row,
-        jnp.broadcast_to(q_ref[0].astype(jnp.float32), qbd_ref.shape),
-        0.0).astype(qbd_ref.dtype)
+    head = jax.lax.broadcasted_iota(jnp.int32, qbd_ref.shape, 1) // dh
+    mine = (row >= head * group) & (row < (head + 1) * group)
+    q = jnp.tile(q_ref[0].astype(jnp.float32), (1, kvh))
+    qbd_ref[...] = jnp.where(mine, q, 0.0).astype(qbd_ref.dtype)
 
 
 # page slots of the (K, V) walk: as many as _KV_RING_BYTES hold of one page's
@@ -337,19 +313,7 @@ def _kv_slots(slab) -> int:
     return int(min(_KV_MAX_SLOTS, max(2, _KV_RING_BYTES // pair)))
 
 
-def _block_diagonal(q):
-    """``q`` (B, kvh, group, dh) as the block-diagonal query of
-    :func:`_page_one_row_a_head`, (B, R, kvh * dh): row ``h * group + g``
-    holds ``q[:, h, g]`` at lanes ``[h * dh, (h + 1) * dh)`` and zeros
-    elsewhere; R the ``kvh * group`` rows padded to whole sublane tiles."""
-    B, kvh, group, dh = q.shape
-    eye = jnp.eye(kvh, dtype=q.dtype)[None, :, None, :, None]
-    qbd = (q[:, :, :, None, :] * eye).reshape(B, kvh * group, kvh * dh)
-    return jnp.pad(qbd, ((0, 0), (0, -(kvh * group) % 16), (0, 0)))
-
-
-def _kv_walk_kernel(*refs, page_len: int, windowed: bool,
-                    block_diagonal: bool = False):
+def _kv_walk_kernel(*refs, page_len: int, windowed: bool):
     """Grid (B,): one step a row of a spec model's flat slab, and the kernel
     walks the row's LIVE pages itself, as :func:`_latent_attn_kernel` does.
     ``k_hbm`` / ``v_hbm`` are the whole slabs, left in HBM. The call's live
@@ -369,21 +333,20 @@ def _kv_walk_kernel(*refs, page_len: int, windowed: bool,
     position (position 0, or ``lower[b]``), so ``l`` is positive at the
     flush.
 
-    The arithmetic of a page goes by the query rows a head has, which the
-    shapes show: several (GQA) meet :func:`_page_head_by_head`, pages in
-    order, the arithmetic of the grid-a-page kernel this replaced
-    (``tools/attn_page_step.py`` keeps that one as the yardstick): the
-    output is that kernel's bit for bit. ONE row a head meets
-    :func:`_page_one_row_a_head`, all heads in one matmul, from the
-    block-diagonal query ``qbd_ref`` built here once a row. With
-    ``block_diagonal`` (heads narrower than a lane tile) ``q_ref`` (1, R,
-    kvh * dh) IS that query, ``group`` rows a head
-    (:func:`_block_diagonal`), and meets the same body."""
+    ONE arithmetic for every page, whatever the heads' count, width and
+    query rows: :func:`_page_every_head`, all heads in one matmul, from the
+    block-diagonal query ``qbd_ref`` built here once a row out of ``q_ref``
+    (1, R, dh), the row's ``kvh * group`` queries one under the other; the
+    flush reads each head's ``group`` rows at its lanes. Pages meet the
+    online softmax in the order of the grid-a-page kernel this replaced
+    (``tools/attn_page_step.py`` keeps that one, a head at a time, as the
+    yardstick): on the chip the output is that kernel's bit for bit (the
+    other heads' lanes add exact zeros)."""
     n = 4 if windowed else 2               # scalar-prefetched arrays
     (tables_ref, lengths_ref, *window), refs = refs[:n], refs[n:]
     first_ref, lower_ref = window or (None, None)
     (q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, cur, used, acc_ref, m_ref,
-     l_ref, *qbd) = refs
+     l_ref, qbd_ref) = refs
     b = pl.program_id(0)
     rows = pl.num_programs(0)
     W = tables_ref.shape[1]
@@ -424,12 +387,7 @@ def _kv_walk_kernel(*refs, page_len: int, windowed: bool,
         for _ in range(slots - 1):
             start_next()
 
-    if qbd:
-        (qbd_ref,) = qbd
-        _block_diagonal_query(q_ref, qbd_ref, dh)
-    elif block_diagonal:
-        qbd_ref = q_ref.at[0]
-    one_matmul = bool(qbd) or block_diagonal
+    _block_diagonal_query(q_ref, qbd_ref, group, dh)
     acc_ref[...] = jnp.zeros_like(acc_ref)
     m_ref[...] = jnp.full_like(m_ref, _MASKED)
     l_ref[...] = jnp.zeros_like(l_ref)
@@ -445,76 +403,68 @@ def _kv_walk_kernel(*refs, page_len: int, windowed: bool,
         # absolute position of column t is page * page_len + t; live iff
         # < length (and, for a window, >= lower)
         at = (first_of(b) + i) * page_len + jax.lax.broadcasted_iota(
-            jnp.int32, (1 if block_diagonal else group, page_len), 1)
+            jnp.int32, (1, page_len), 1)
         live = at < length
         if windowed:
             live &= at >= lower_ref[b]
         slot = jax.lax.rem(base + i, slots)
-        if one_matmul:
-            _page_one_row_a_head(qbd_ref, k_buf.at[slot], v_buf.at[slot],
-                                 acc_ref, m_ref, l_ref, live, dh)
-        else:
-            _page_head_by_head(q_ref, k_buf.at[slot], v_buf.at[slot], acc_ref,
-                               m_ref, l_ref, live)
+        _page_every_head(qbd_ref, k_buf.at[slot], v_buf.at[slot], acc_ref,
+                         m_ref, l_ref, live, dh)
 
     used[0] = base + pages_of(b)
-    if one_matmul:
-        for h in range(kvh):    # the diagonal blocks: group rows a head
-            rows = slice(h * group, (h + 1) * group)
-            o_ref[0, h] = (acc_ref[rows, h * dh:(h + 1) * dh]
-                           / l_ref[rows, :]).astype(o_ref.dtype)
-    else:
-        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+    for h in range(kvh):    # the diagonal blocks: group rows a head
+        mine = slice(h * group, (h + 1) * group)
+        o_ref[0, h] = (acc_ref[mine, h * dh:(h + 1) * dh]
+                       / l_ref[mine, :]).astype(o_ref.dtype)
+
+
+def _query_rows(q):
+    """``q`` (B, kvh, group, dh) as the walk is handed it, (B, R, dh): a
+    row's ``kvh * group`` queries one under the other (row ``h * group + g``
+    head ``h``'s ``g``-th) and rows of zeros below them up to R, whole
+    sublane tiles in either dtype. No padding row reaches the output,
+    whatever it holds (:func:`_page_every_head`)."""
+    B, kvh, group, dh = q.shape
+    return jnp.pad(q.reshape(B, kvh * group, dh),
+                   ((0, 0), (0, -(kvh * group) % 16), (0, 0)))
 
 
 def _kv_walk_call(q, k_pages, v_pages, tables, lengths, window=(), *,
                   interpret: bool, slots: int | None = None):
     """The walk over a flat slab ``(num_pages, page_len, kvh * dh)``;
-    ``window`` is ``(first_page, lower)`` or empty. The ring is
-    :func:`_kv_slots`'s (``slots``: the tool's and the tests' to vary) and
-    the compiler is asked for the VMEM it needs."""
+    ``window`` is ``(first_page, lower)`` or empty. The kernel is handed a
+    row's queries one under the other (:func:`_query_rows`: R is 32 at
+    Falcon-H1's, Olmo-Hybrid's and LFM2's heads, 48 / 80 on Laguna's full /
+    sliding layers). The ring is :func:`_kv_slots`'s (``slots``: the tool's
+    and the tests' to vary) and the compiler is asked for the VMEM it
+    needs."""
     B, kvh, group, dh = q.shape
     page_len, width = k_pages.shape[1:]
     slots = slots or _kv_slots(k_pages)
     ring = 2 * slots * page_len * width * k_pages.dtype.itemsize
-    row_spec = pl.BlockSpec((1, kvh, group, dh), lambda b, *_: (b, 0, 0, 0))
+    rows = _query_rows(q)
+    heads = rows.shape[1]
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
-    narrow = group > 1 and dh % 128 != 0   # a head's lanes: part of a tile
-    if group == 1:
-        heads = -(-kvh // 16) * 16      # whole sublane tiles in either dtype
-        q = q.reshape(B, 1, width)
-        q_spec = pl.BlockSpec((1, 1, width), lambda b, *_: (b, 0, 0))
-        state = [pltpu.VMEM((heads, width), jnp.float32),  # accumulator
-                 pltpu.VMEM((heads, 1), jnp.float32),      # running max m
-                 pltpu.VMEM((heads, 1), jnp.float32),      # normalizer l
-                 pltpu.VMEM((heads, width), q.dtype)]      # block-diagonal q
-    elif narrow:
-        q = _block_diagonal(q)
-        heads = q.shape[1]
-        q_spec = pl.BlockSpec((1, heads, width), lambda b, *_: (b, 0, 0))
-        state = [pltpu.VMEM((heads, width), jnp.float32),
-                 pltpu.VMEM((heads, 1), jnp.float32),
-                 pltpu.VMEM((heads, 1), jnp.float32)]
-    else:
-        q_spec = row_spec
-        state = [pltpu.VMEM((kvh, group, dh), jnp.float32),
-                 pltpu.VMEM((kvh, group, 1), jnp.float32),
-                 pltpu.VMEM((kvh, group, 1), jnp.float32)]
     return pl.pallas_call(
         functools.partial(_kv_walk_kernel, page_len=page_len,
-                          windowed=bool(window), block_diagonal=narrow),
+                          windowed=bool(window)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2 + len(window),
             grid=(B,),
-            in_specs=[q_spec, in_hbm, in_hbm],
-            out_specs=row_spec,
+            in_specs=[pl.BlockSpec((1, heads, dh), lambda b, *_: (b, 0, 0)),
+                      in_hbm, in_hbm],
+            out_specs=pl.BlockSpec((1, kvh, group, dh),
+                                   lambda b, *_: (b, 0, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((slots, page_len, width), k_pages.dtype),
                 pltpu.VMEM((slots, page_len, width), v_pages.dtype),
                 pltpu.SemaphoreType.DMA((2, slots)),
                 pltpu.SMEM((3,), jnp.int32),    # cur: row, page, started
                 pltpu.SMEM((1,), jnp.int32),    # used
-                *state,
+                pltpu.VMEM((heads, width), jnp.float32),  # accumulator
+                pltpu.VMEM((heads, 1), jnp.float32),      # running max m
+                pltpu.VMEM((heads, 1), jnp.float32),      # normalizer l
+                pltpu.VMEM((heads, width), q.dtype),      # block-diagonal q
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, kvh, group, dh), q.dtype),
@@ -522,7 +472,7 @@ def _kv_walk_call(q, k_pages, v_pages, tables, lengths, window=(), *,
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=max(16 << 20, ring + (4 << 20))),
         interpret=interpret,
-    )(tables, lengths, *window, q, k_pages, v_pages)
+    )(tables, lengths, *window, rows, k_pages, v_pages)
 
 
 def _page_block(slab, q):
@@ -621,8 +571,9 @@ def paged_decode_attention(q, k_pages, v_pages, tables, lengths,
     ``group = heads // kv_heads``), ``k_pages``/``v_pages`` the slab, as the
     model holds it: ``(num_pages, page_len, kv_heads, dh)`` (the dense
     model; every head in one batched einsum) or ``(num_pages, page_len,
-    kv_heads * dh)`` (a spec model; head by head on the MXU: module
-    docstring). The array's rank says which; ``tables`` ``(B, W)`` int32
+    kv_heads * dh)`` (a spec model; every head in one matmul from a
+    block-diagonal query: module docstring). The array's rank says which;
+    ``tables`` ``(B, W)`` int32
     block tables (dummy page 0 beyond a row's extent), ``lengths`` ``(B,)``
     the number of live positions per row — for a decode step at position
     ``pos`` whose K/V entry is already written, ``pos + 1``. Returns the

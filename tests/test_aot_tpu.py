@@ -715,26 +715,37 @@ def test_paged_kernels_mosaic_compile_at_the_laguna_cells_shapes(one_chip,
     and ``serve.olmohybrid-sessions24``'s, 24 rows of 30 KV heads, one query
     row a head, over 15 pages. Since PR 43 each is the walk: the slabs stay
     in HBM (no temporary: :func:`_compile_spec_decode_kernel`), the kernel
-    copies a row's live pages into its ring of slots, each head's keys a
-    lane slice of a slot's ``(256, kv_heads * 128)``; in bfloat16 and at
-    the float32 checks' pages, inside the VMEM the kernel asks for (the
-    ring and 4 MiB, 16 MiB at least: under a fifth of the chip's 128)."""
+    copies a row's live pages into its ring of slots, and since PR 46 every
+    head meets a slot's whole ``(256, kv_heads * 128)`` page in one matmul
+    at any group, from a block-diagonal query of R = 48 / 80 / 32 / 32 rows
+    built in VMEM; in bfloat16 and at the float32 checks' pages, inside the
+    VMEM the kernel asks for (the ring and 4 MiB, 16 MiB at least: under a
+    fifth of the chip's 128), of which the 4 MiB hold the accumulator (R,
+    kv_heads * 128) in float32, the query beside it and a page's scores and
+    probabilities (R, 256) (Laguna's sliding layers in float32, the most:
+    0.32 + 0.32 + 0.16 MB)."""
     from marlin_tpu.ops.paged_attention import _kv_slots
 
     rows, kvh, group, pages, width, window = _CELL_CALLS[call]
     _compile_spec_decode_kernel(one_chip, group, 256, pages, width, rows=rows,
                                 kvh=kvh, window=window, dtype=dtype)
     slab = jax.ShapeDtypeStruct((pages, 256, kvh * 128), dtype)
-    ring = 2 * _kv_slots(slab) * 256 * kvh * 128 * jnp.dtype(dtype).itemsize
+    item = jnp.dtype(dtype).itemsize
+    ring = 2 * _kv_slots(slab) * 256 * kvh * 128 * item
     assert ring + (4 << 20) <= 24 << 20
+    R = -(-kvh * group // 16) * 16
+    assert R * (kvh * 128 * (4 + item) + 2 * 256 * 4) <= 1 << 20
 
 
-def test_float32_pages_of_256_fit_scoped_vmem_only_head_by_head(one_chip):
+def test_float32_pages_of_256_fit_scoped_vmem_only_in_the_flat_layout(
+        one_chip):
     """The float32 checks' blocks (``benchmarks/f32_check_falconh1.py``) at
     the cell's page of 256: held ``(page_len, kvh * dh)`` the kernel
-    compiles within the default scoped VMEM; held ``(page_len, kvh, dh)``
-    its relayout of a 256-token block asks for more than there is (which is
-    why that check ran at pages of 128 until the layout changed)."""
+    compiles within the VMEM it asks for (the page is never sliced or laid
+    out anew: it meets the block-diagonal query whole); held ``(page_len,
+    kvh, dh)`` the other kernel's relayout of a 256-token block asks for
+    more than there is (which is why that check ran at pages of 128 until
+    the layout changed)."""
     rows, kvh, group, pages, width, _ = _CELL_CALLS["falconh1-g5"]
     _compile_spec_decode_kernel(one_chip, group, 256, pages, width, rows=rows,
                                 kvh=kvh, dtype=jnp.float32)
@@ -952,9 +963,11 @@ def test_paged_kernel_mosaic_compiles_at_eight_heads_of_64(one_chip, dtype):
     to a lane tile), four query rows a head, over 24 pages of the 1537-page
     slab, page_len 256. The walk never slices a head's half tile out of the
     page: every head meets the whole ``(256, 512)`` page in one matmul from
-    the block-diagonal query, which the caller builds (32 rows x 512 lanes a
-    row of the call: the one temporary, 3 MB in bfloat16); the slabs stay in
-    HBM."""
+    the block-diagonal query, which the kernel builds in VMEM out of the
+    row's 32 queries of 64 (repeated across the eight heads' lanes, two
+    heads to a lane tile); the one temporary is those rows, 0.4 MB in
+    bfloat16 (the caller built the whole (32, 512) a row until PR 46, 3
+    MB); the slabs stay in HBM."""
     from marlin_tpu.ops.paged_attention import _paged_decode_attention_call
 
     def st(shape, dt=jnp.int32):
@@ -966,7 +979,7 @@ def test_paged_kernel_mosaic_compiles_at_eight_heads_of_64(one_chip, dtype):
         st((rows, kvh, group, dh), dtype), slab, slab, st((rows, width)),
         st((rows,)), page_len=256, interpret=False).lower().compile()
     assert "tpu_custom_call" in c.as_text()
-    query = rows * kvh * group * kvh * dh * jnp.dtype(dtype).itemsize
+    query = rows * kvh * group * dh * jnp.dtype(dtype).itemsize
     assert c.memory_analysis().temp_size_in_bytes <= 2 * query
 
 

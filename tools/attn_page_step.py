@@ -9,30 +9,35 @@ every row at length 1, give two unknowns.
 
 **The (K, V) kernel** at the five calls the spec cells make
 (``olmohybrid.global``, ``falconh1.global``, ``laguna.global``,
-``laguna.window``, and ``lfm2.global``: 8 heads of 64, two to a lane tile,
-where the package takes the all-heads body and ``.grid`` / ``.compute_only``
-say whether head by head lowers there at all and at what cost), over a flat
-slab ``(num_pages, page_len, kv_heads * head_dim)``. ``<shape>`` is the package's kernel, which since PR 43 takes a
-grid step a ROW and walks the call's live pages itself (their copies in
-flight in a ring of slots): microseconds a live page and a row (it has no
-dead step), the call at other ring sizes (``call_us_by_slots``), and the same
-walk with the arithmetic taken out (``walk_copy_only_us_a_page``: the copies'
-own rate). The others are the grid-a-page form it replaced (grid (rows, table
-width), the page a block whose index map reads the table; PR 38's body), kept
-HERE as the yardstick with the package's own :func:`_page_head_by_head`
-inside, and what its live step is made of: ``.grid`` the whole body as it
-was, microseconds a LIVE grid step and a DEAD one; ``.copy_only`` the same
-copies with the matmuls taken out; ``.compute_only`` the same matmuls with
-the index map frozen on one page, so that no copy is issued after the first
+``laguna.window``, and ``lfm2.global``: 8 heads of 64, two to a lane tile),
+over a flat slab ``(num_pages, page_len, kv_heads * head_dim)``.
+``<shape>.walk.all_heads`` is the package's kernel (``<shape>`` until PR 46):
+a grid step a ROW, the call's live pages walked as one stream of copies
+through a ring of slots (PR 43), every head of a page in ONE matmul from a
+block-diagonal query built in VMEM once a row (at any group since PR 46):
+microseconds a live page and a row (it has no dead step), the call at other
+ring sizes (``call_us_by_slots``), and the same walk with the arithmetic
+taken out (``walk_copy_only_us_a_page``: the copies' own rate). The others
+are the grid-a-page form it replaced (grid (rows, table width), the page a
+block whose index map reads the table; PR 38's body), kept HERE as the
+yardstick with the arithmetic the package held until PR 46 inside, a head
+at a time (:func:`_page_head_by_head`: the tool's own copy now), and what its
+live step is made of: ``.grid`` the whole body as it was, microseconds a
+LIVE grid step and a DEAD one; ``.copy_only`` the same copies with the
+matmuls taken out; ``.compute_only`` the same matmuls with the index map
+frozen on one page, so that no copy is issued after the first
 (``.scores_only`` / ``.values_only``: that arithmetic without its value
 matmul, and the value matmul alone; ``.all_heads.compute_only``: under the
-same frozen grid the package's OTHER page body, every head in one matmul
-from a block-diagonal query, which the package gives a model with ONE query
-row a head and this line tries at any group, ``max_abs_diff_from_grid``
-beside it). A step that reads ``copy_only + compute_only`` (less
-one dead step, which both hold) is not overlapping them; one that reads the
-larger of the two is. ``max_abs_diff_from_grid`` is the package's output
-against the grid form's (0.0: bit-equal).
+same frozen grid the package's page body, the query built by XLA out here,
+``max_abs_diff_from_grid`` beside it). A step that reads ``copy_only +
+compute_only`` (less one dead step, which both hold) is not overlapping
+them; one that reads the larger of the two is. ``max_abs_diff_from_grid`` is
+the package's output against the grid form's (0.0 on the chip: the other
+heads' lanes add exact zeros; the CPU's interpreter sums a contraction in
+another order). Two "copy" figures of one page: ``.copy_only`` is the
+grid's pipeline, a copy behind the step before it, a dead step's cost in it;
+``walk_copy_only_us_a_page`` the walk's ring with nothing to compute, the
+rate the package's kernel is held to.
 
 **The latent kernel** (shape ``mistral4.latent``: the Mistral cell's decode
 call, 32 rows of 65-70 pages, 32 heads against an entry of 320 values stored
@@ -169,6 +174,54 @@ def _window_of(lens, window: int):
     return lower // PAGE_LEN, lower
 
 
+def _page_head_by_head(q_ref, k_page, v_page, acc_ref, m_ref, l_ref, live):
+    """The yardstick's arithmetic, the package's own until PR 46: one live
+    page ``(page_len, kvh * dh)``, head ``h``'s keys the lane slice ``[:, h *
+    dh:(h + 1) * dh]``, each head's ``group`` query rows against it in a
+    plain matmul, softmax, matmul, a head at a time (``live``: (group,
+    page_len)); scratch ``acc`` (kvh, group, dh), ``m`` / ``l`` (kvh, group,
+    1). 1.15 / 2.20 / 2.28 us a page alone on the chip at Falcon-H1's,
+    Laguna's global and window shapes against copies of 0.70 / 1.41 / 1.40
+    (PERF.md PR 43): ``kvh`` chains that each wait for their own results."""
+    import math
+
+    import jax
+    import jax.numpy as jnp
+
+    kvh, group, dh = q_ref.shape[1:]
+    nt = (((1,), (1,)), ((), ()))      # contract both minor dimensions
+    for h in range(kvh):
+        q = q_ref[0, h]                            # (group, dh)
+        cols = slice(h * dh, (h + 1) * dh)
+        s = jax.lax.dot_general(
+            q, k_page[:, cols], nt,
+            preferred_element_type=jnp.float32) / math.sqrt(dh)
+        s = jnp.where(live, s, -1e30)
+        m_prev = m_ref[h]                          # (group, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        m_ref[h] = m_new
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[h] = acc_ref[h] * alpha + jnp.dot(
+            p.astype(q.dtype), v_page[:, cols],
+            preferred_element_type=jnp.float32)
+
+
+def _block_diagonal(q):
+    """``q`` (B, kvh, group, dh) as the block-diagonal query of the
+    package's page body, built by XLA: (B, R, kvh * dh), row ``h * group +
+    g`` holds ``q[:, h, g]`` at lanes ``[h * dh, (h + 1) * dh)`` and zeros
+    elsewhere; R the ``kvh * group`` rows padded to whole sublane tiles (the
+    package builds it in VMEM, a row at a time)."""
+    import jax.numpy as jnp
+
+    B, kvh, group, dh = q.shape
+    eye = jnp.eye(kvh, dtype=q.dtype)[None, :, None, :, None]
+    qbd = (q[:, :, :, None, :] * eye).reshape(B, kvh * group, kvh * dh)
+    return jnp.pad(qbd, ((0, 0), (0, -(kvh * group) % 16), (0, 0)))
+
+
 def _scores_only(q_ref, k_page, v_page, acc_ref, m_ref, l_ref, live):
     """:func:`_page_head_by_head` without its value matmul (a lane tile of
     the probabilities goes into the accumulator in its place)."""
@@ -210,18 +263,18 @@ def _values_only(q_ref, k_page, v_page, acc_ref, m_ref, l_ref, live):
 def _grid_kv_call(mode: str = "both", body: str = "head_by_head"):
     """The (K, V) kernel over a flat slab as the package held it until PR
     43: grid (rows, table width), one page of K and of V a grid step through
-    ``BlockSpec`` s whose index map reads the scalar-prefetched table, the
-    package's :func:`_page_head_by_head` on each live page; called as the
+    ``BlockSpec`` s whose index map reads the scalar-prefetched table,
+    :func:`_page_head_by_head` on each live page; called as the
     package's jitted calls are (lengths clamped by the caller), a window's
     ``first_page`` and ``lower`` last. ``mode`` ``both`` is that kernel;
     ``copy`` takes the arithmetic out (a live step adds a sublane tile of
     each block into the accumulator, so that both are read); ``compute``
     freezes the index map on page 1. ``body``: that arithmetic's parts
     (``scores`` / ``values``: :func:`_scores_only`, :func:`_values_only`),
-    or the package's other page body, :func:`_page_one_row_a_head`, at ANY
-    group (``all_heads``: the block-diagonal query built out here, the
-    package's ``_block_diagonal``; the accumulator (rows, kvh * dh), of which the
-    flush reads each head's rows at its lanes), each under the same grid."""
+    or the package's page body, ``_page_every_head`` (``all_heads``: the
+    block-diagonal query built out here, :func:`_block_diagonal`; the
+    accumulator (rows, kvh * dh), of which the flush reads each head's rows
+    at its lanes), each under the same grid."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -229,7 +282,7 @@ def _grid_kv_call(mode: str = "both", body: str = "head_by_head"):
 
     from marlin_tpu.ops import paged_attention as pa
 
-    page_body = {"head_by_head": pa._page_head_by_head,
+    page_body = {"head_by_head": _page_head_by_head,
                  "scores": _scores_only, "values": _values_only}.get(body)
 
     def kernel(*refs, page_len, windowed):
@@ -260,8 +313,8 @@ def _grid_kv_call(mode: str = "both", body: str = "head_by_head"):
             if windowed:
                 live &= at >= scalars[3][b]
             if page_body is None:
-                pa._page_one_row_a_head(q_ref.at[0], k_ref.at[0], v_ref.at[0],
-                                        acc_ref, m_ref, l_ref, live, dh)
+                pa._page_every_head(q_ref.at[0], k_ref.at[0], v_ref.at[0],
+                                    acc_ref, m_ref, l_ref, live, dh)
             else:
                 page_body(q_ref, k_ref.at[0], v_ref.at[0], acc_ref, m_ref,
                           l_ref, live)
@@ -296,7 +349,7 @@ def _grid_kv_call(mode: str = "both", body: str = "head_by_head"):
         q_spec, shapes = row_spec, [(kvh, group, dh), (kvh, group, 1),
                                     (kvh, group, 1)]
         if body == "all_heads":
-            q = pa._block_diagonal(q)   # rows: whole tiles in either dtype
+            q = _block_diagonal(q)   # rows: whole tiles in either dtype
             rows = q.shape[1]
             q_spec = pl.BlockSpec((1, rows, width), lambda b, w, *_: (b, 0, 0))
             shapes = [(rows, width), (rows, 1), (rows, 1)]
@@ -332,7 +385,7 @@ def _touch_a_page(q_ref, k_page, v_page, acc_ref, m_ref, l_ref, live, *_):
     of the values goes into the accumulator, so that the slot is read."""
     import jax.numpy as jnp
 
-    tile = acc_ref.shape[-2], min(acc_ref.shape[-1], 128)
+    tile = min(acc_ref.shape[-2], k_page.shape[0]), min(acc_ref.shape[-1], 128)
     at = (0,) * (acc_ref.ndim - 2) + (slice(tile[0]), slice(tile[1]))
     acc_ref[at] += (k_page[:tile[0], :tile[1]].astype(jnp.float32)
                     + v_page[:tile[0], :tile[1]].astype(jnp.float32))
@@ -342,7 +395,7 @@ def _touch_a_page(q_ref, k_page, v_page, acc_ref, m_ref, l_ref, live, *_):
 def _walk_kv_call(copy_only: bool = False, **kw):
     """The package's walk over a flat slab (``slots``: a ring of the tool's
     choosing), called as :func:`_grid_kv_call` is. ``copy_only``: the same
-    walk, ring and copies with both of its page bodies replaced by
+    walk, ring and copies with its page body replaced by
     :func:`_touch_a_page` while the kernel is traced."""
     import contextlib
     from unittest import mock
@@ -353,11 +406,9 @@ def _walk_kv_call(copy_only: bool = False, **kw):
 
     @jax.jit
     def call(q, k_pages, v_pages, tables, lengths, *window):
-        bodies = mock.patch.multiple(
-            pa, _page_head_by_head=_touch_a_page,
-            _page_one_row_a_head=_touch_a_page) if copy_only \
-            else contextlib.nullcontext()
-        with bodies:
+        body = mock.patch.object(pa, "_page_every_head", _touch_a_page) \
+            if copy_only else contextlib.nullcontext()
+        with body:
             return pa._kv_walk_call(
                 q, k_pages, v_pages, tables, lengths, window,
                 interpret=jax.default_backend() != "tpu", **kw)
@@ -396,7 +447,8 @@ def measure(name: str, dtype, seed: int = 0) -> list[dict]:
             "page_bytes": 2 * PAGE_LEN * kvh * head_dim
             * jnp.dtype(dtype).itemsize}
     base["bytes_us_a_page"] = base["page_bytes"] / 819e9 * 1e6
-    calls = {name: _walk_kv_call(),
+    package = name + ".walk.all_heads"
+    calls = {package: _walk_kv_call(),
              name + ".grid": _grid_kv_call("both"),
              name + ".copy_only": _grid_kv_call("copy"),
              name + ".compute_only": _grid_kv_call("compute"),
@@ -416,18 +468,18 @@ def measure(name: str, dtype, seed: int = 0) -> list[dict]:
             line["error"] = str(e).split(". ")[0][:300]
             continue
         line.update(call_us=t1 * 1e6, call_us_all_rows_length_1=t0 * 1e6,
-                    **_a_step(label == name, live, rows, width, t1, t0))
+                    **_a_step(label == package, live, rows, width, t1, t0))
     walk = lines[0]
     try:  # the all-heads body's output, the copies back in
         outs["all_heads"] = np.asarray(_windowed(_grid_kv_call(
             "both", "all_heads"), window)(*arrays, cell).astype(jnp.float32))
     except Exception as e:  # float32 Olmo blocks: scoped VMEM
         lines[-1]["both_error"] = str(e).split(". ")[0][:200]
-    for line, label in ((walk, name), (lines[-1], "all_heads")):
+    for line, label in ((walk, package), (lines[-1], "all_heads")):
         if label in outs and name + ".grid" in outs:
             line["max_abs_diff_from_grid"] = float(
                 np.abs(outs[label] - outs[name + ".grid"]).max())
-    if name in outs:
+    if package in outs:
         from marlin_tpu.ops.paged_attention import _kv_slots
 
         walk["slots"] = _kv_slots(k)

@@ -1,11 +1,11 @@
 # Repo-level entry points. `make check` is the default: the native-library
 # build (fails loudly when the toolchain is missing — a silent fallback to
 # the pure-Python data plane is a 100x perf bug that looks like a pass),
-# then the serving gate (tier-1 serving + resilience tests, then
-# tools/bench_compare.py over the BENCH_ALL.json serve_* records), the
-# out-of-core data-plane gate, the bench-gate selftest, and the obs-report
-# smoke — see tools/Makefile for the individual targets and their knobs
-# (SERVE_BASE/SERVE_NEW, OOC_BASE/OOC_NEW, BASE/NEW).
+# then the static-analysis gate, the seven pytest gates (serving, out-of-core
+# data plane, kernels, observability, fleet, programs, memory) and the
+# obs-report smoke — see tools/Makefile for the individual targets. None of
+# them times anything: speed is read on the chip by benchmarks/
+# (BENCHMARK.json, PERF.md).
 
 .DEFAULT_GOAL := check
 

@@ -3,7 +3,8 @@
 
 Drives the library's main paths once, through the entry points a user calls,
 in ONE process on ONE TPU chip, at the sizes the repo's own records name
-(BASELINE.md configs 1, 3, 4 and the ``r5`` rows of BENCH_ALL.json):
+(BASELINE.md configs 1, 3, 4 and the ``r5`` rows of PERF.md's table "Chip
+rows older than PR 1"):
 
 - matrix: the file-loaded 100x100 multiply (genmat + the native data plane,
   built from source), the 20000^2 adaptive multiply against a host float64
@@ -277,7 +278,7 @@ def phase_lm_serve(lm, params, on_tpu):
     import jax
 
     from marlin_tpu.models import lm_generate
-    from marlin_tpu.obs import memledger, perf
+    from marlin_tpu.obs import memledger
     from marlin_tpu.serving import STATUS_OK, ServeEngine, Supervisor
 
     eng = ServeEngine(params, lm.heads)  # every knob at its default
@@ -291,14 +292,6 @@ def phase_lm_serve(lm, params, on_tpu):
         t0 = time.perf_counter()
         eng.warmup()
         warm_s = time.perf_counter() - t0
-        # capture_paged_costs swallows every exception and marks the
-        # attempt: a trace or lowering that failed shows as tried-not-has
-        costs = perf.get_program_costs()
-        captured = {prog: all(costs.has(prog, eng._prog_key(b))
-                              for b in eng.buckets)
-                    for prog in ("lm_prefill_paged", "lm_decode_paged")}
-        check(captured["lm_decode_paged"], "lm_decode_paged cost capture "
-                                           "recorded a failed attempt")
 
         def submit(idx):
             for i in idx:
@@ -351,7 +344,7 @@ def phase_lm_serve(lm, params, on_tpu):
          new_tokens=snap["new_tokens"], decode_steps=snap["steps"],
          prefix_hits=snap["prefix_hits"], restarts=sup.restart_count,
          retries=snap["retries"], token_mismatches=len(mismatches),
-         costs_captured=captured, p50_step_s=snap["p50_step_s"],
+         p50_step_s=snap["p50_step_s"],
          p50_ttft_s=snap["p50_ttft_s"], busy_s=snap["busy_s"],
          warmup_s=round(warm_s, 3), serve_s=round(serve_s, 3),
          peak_bytes_in_use=peak_bytes())

@@ -161,15 +161,6 @@ class MarlinConfig:
     # elsewhere (interpret-mode Pallas is for tests, not serving). Greedy
     # token streams are identical across backends.
     serve_decode_kernel: str = "auto"
-    # Measured-peak admission calibration (obs/memledger.py): multiply the
-    # planner's per-bucket admission cost by the compiler-measured
-    # peak/planner ratio for that bucket's program (live ProgramCosts
-    # capture first, the AOT_MEMORY.json serve_buckets table second, 1.0
-    # when neither has measured this exact program), so admission charges
-    # what the program actually peaks at instead of the slab arithmetic
-    # the compiler runs 4-5x above. False = raw planner cost (the
-    # pre-ledger behavior).
-    serve_admission_calibration: bool = True
     # --- serving resilience (serving/supervisor.py, serving/router.py) ------
     # Supervisor watchdog: a worker whose heartbeat is older than this many
     # real seconds while work is pending is declared stuck and recovered
@@ -302,13 +293,6 @@ class MarlinConfig:
     # runs, not for a long-running serve loop flushing per event. Per-log
     # override: EventLog(..., max_bytes=...).
     obs_log_max_bytes: int = 0
-    # Roofline peak rates (obs/perf.py): FLOP/s and HBM bytes/s the
-    # achieved-performance fractions are computed against. None = detect
-    # from the device kind (the TPU-generation table in obs/perf.py; CPU
-    # backends get documented *nominal* placeholders) — set both explicitly
-    # when the table's number disagrees with your part's datasheet.
-    obs_peak_flops: float | None = None
-    obs_peak_bw: float | None = None
     # Where on-demand profiler captures (obs.perf.capture_profile, the
     # /debug/profile endpoint, SIGUSR2) and flight-recorder dumps land.
     # None = <tempdir>/marlin_tpu_captures. The directory rotates: captures

@@ -1,8 +1,8 @@
 """MarlinChunk binary container — the native out-of-core data plane.
 
-BENCH_ALL.json config 4 measures the problem: the tall-skinny Gramian runs
-~10,900 GFLOP/s device-resident but single-digit GFLOP/s end-to-end, because
-the host side of every streamed op is a text parser. The prefetch pipeline
+The tall-skinny Gramian runs ~10,900 GFLOP/s device-resident (PERF.md, "Chip
+rows older than PR 1") and only at the pace of its producer end to end: the
+host side of every streamed op was a text parser. The prefetch pipeline
 (parallel/prefetch.py) already overlaps production with device compute; this
 module replaces the production itself. A ``.mchunk`` file is a fixed-layout
 sequence of CRC32C-checksummed row-major chunks behind a 64-byte header

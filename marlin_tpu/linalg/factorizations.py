@@ -89,7 +89,7 @@ def _resolve_schedule(schedule: str, nb: int, pivot: str = "block",
         raise ValueError('schedule="shrinking" supports pivot="block" only '
                          '(panel pivoting keeps the masked full-width loop)')
     if schedule == "auto":
-        # Measured on the v5e (BENCH_ALL r5, 8192²): LU shrinking beats
+        # Measured on the v5e (PERF.md's r5 table, 8192²): LU shrinking beats
         # masked 2758 vs 2069 GFLOP/s, but Cholesky masked beats shrinking
         # 1480 vs 1319 — Cholesky's symmetric trailing update keeps the MXU
         # busier in the single fori_loop program than LU's, so the unrolled

@@ -29,10 +29,9 @@ from __future__ import annotations
 
 import dataclasses
 import json
-import os
 
 __all__ = ["plan_context", "ContextPlan", "usable_hbm_bytes",
-           "kv_page_bytes", "request_pages", "bucket_calibration"]
+           "kv_page_bytes", "request_pages"]
 
 GIB = 1024 ** 3
 
@@ -40,36 +39,6 @@ GIB = 1024 ** 3
 # exists, reserve this much of raw capacity for the runtime/framework — the
 # v5e reserves a slice of its 16 GiB that compile-time accounting never sees.
 DEFAULT_RESERVE_BYTES = 3 * GIB // 4  # 0.75 GiB
-
-_AOT_MEMORY = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.dirname(os.path.abspath(__file__)))), "AOT_MEMORY.json")
-
-
-def bucket_calibration(program_key: str,
-                       report: str | None = None) -> int | None:
-    """The compiler-measured peak (bytes) for the serve bucket whose AOT
-    calibration row carries exactly ``program_key`` — from the
-    AOT_MEMORY.json ``serve_buckets`` table tools/aot_report.py writes.
-    Keying on the full program key (model geometry, batch, dtype, page
-    geometry all fold in — serving/batcher.bucket_program_key) means a toy
-    test model can never inherit the bench model's calibration. None when
-    the report is absent or carries no row for this program — admission
-    then falls back to the raw planner estimate (obs/memledger.
-    admission_ratio)."""
-    path = report or _AOT_MEMORY
-    try:
-        with open(path) as f:
-            buckets = json.load(f).get("serve_buckets", {}).get("buckets", {})
-    except (FileNotFoundError, ValueError, OSError):
-        return None
-    for info in buckets.values():
-        if isinstance(info, dict) and info.get("program_key") == program_key:
-            peak = info.get("compiler_peak_bytes")
-            try:
-                return int(peak) if peak else None
-            except (TypeError, ValueError):
-                return None
-    return None
 
 
 def usable_hbm_bytes(total_bytes: int = 16 * GIB,

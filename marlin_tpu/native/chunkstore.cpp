@@ -1,9 +1,9 @@
 // chunkstore — the native binary chunk container behind the out-of-core
 // data plane (marlin_tpu/io/chunkstore.py binds this via ctypes).
 //
-// BENCH_ALL.json config 4 names the problem this solves: the tall-skinny
-// Gramian runs ~10,900 GFLOP/s device-resident but single-digit GFLOP/s
-// end-to-end, because the host side of the stream is a text parser. The
+// The problem this solves (PERF.md, "Chip rows older than PR 1"): the
+// tall-skinny Gramian runs ~10,900 GFLOP/s device-resident, but end to end
+// the host side of the stream is a text parser and sets the pace. The
 // prefetch pipeline (PR 2) proved the overlap works and left the producer
 // as the wall; this library replaces the producer with an mmap'd binary
 // format the OS page cache can feed at memory speed, checksum-validated,

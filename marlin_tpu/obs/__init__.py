@@ -30,12 +30,9 @@ ServeMetrics counters, StageTimes, a test-only compile tally):
   ``/debug/slo``.
 - :mod:`~marlin_tpu.obs.memledger` — the HBM ledger: process-global
   per-component device-memory attribution with exact debit on free,
-  the three-view reconciler (``marlin_mem_*`` gauges, ``GET
-  /debug/memory``), measured-peak admission calibration, leak
+  the reconciler (``marlin_mem_*`` gauges, ``GET /debug/memory``), leak
   detection, and OOM forensics dumps.
-- :mod:`~marlin_tpu.obs.perf` — performance introspection: per-program
-  roofline accounting (XLA cost models joined with measured wall times →
-  ``marlin_program_*`` series and the analyzer's utilization table), the
+- :mod:`~marlin_tpu.obs.perf` — performance introspection: the
   single-flight on-demand profiler capture (``POST /debug/profile``,
   SIGUSR2), and the step-time flight recorder (``GET /debug/flight``).
 

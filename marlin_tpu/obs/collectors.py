@@ -163,8 +163,7 @@ def log_device_memory(log=None, **fields) -> None:
 def install_default_collectors(registry: MetricsRegistry | None = None,
                                ) -> None:
     """Everything a scrape endpoint should carry: the compile bridge, the
-    device-memory/planner gauges, the program-cost/roofline collector
-    (obs/perf.py — ``marlin_program_*``), the memory-ledger reconciler
+    device-memory/planner gauges, the memory-ledger reconciler
     (obs/memledger.py — ``marlin_mem_*``, each scrape doubling as one
     leak-detection window), and the prefetch family pre-registration (so a
     serving-only process still exposes the prefetch series at zero instead
@@ -173,9 +172,7 @@ def install_default_collectors(registry: MetricsRegistry | None = None,
     install_compile_metrics(reg)
     install_device_memory_gauges(reg)
     from .memledger import install_memledger_gauges
-    from .perf import install_program_costs
 
-    install_program_costs(reg)
     install_memledger_gauges(reg)
     if reg is get_registry():
         # prefetch declares its families lazily on first pipeline; touch

@@ -17,9 +17,8 @@ router's, a bench's) and renders, at a poll interval:
   scale-out/in/rebalance history with outcomes;
 - **memory** — when the server carries ``/debug/memory`` (the
   MemoryLedger, obs/memledger.py): per-component stacked occupancy of
-  the registered bytes, the live vs unattributed reconciliation ("n/a"
-  on backends without ``memory_stats``), and the per-bucket
-  planner-ratio/calibration table;
+  the registered bytes and the live vs unattributed reconciliation
+  ("n/a" on backends without ``memory_stats``);
 - **event tail** — the recent SLO breach/clear transitions plus the
   migration/restart counters' movement.
 
@@ -232,7 +231,7 @@ def render(metrics: dict, slo: dict, history: dict | None = None,
     if (fleet or {}).get("fleets"):
         lines.append(rule)
 
-    # memory: per-component stacked occupancy + reconciliation + ratios
+    # memory: per-component stacked occupancy + reconciliation
     if memory is not None:
         comps = memory.get("components") or {}
         total = memory.get("registered_bytes") or 0
@@ -246,17 +245,6 @@ def render(metrics: dict, slo: dict, history: dict | None = None,
         for comp, b in sorted(comps.items(), key=lambda kv: -kv[1]):
             frac = b / total if total else 0.0
             lines.append(f"    {comp:<12}{bar(frac)} {b:>14}")
-        ratios = memory.get("planner_ratios") or ()
-        if ratios:
-            lines.append("    bucket       planner B      measured B  "
-                         "ratio  calib")
-            for r in ratios:
-                lines.append(
-                    f"    {str(r.get('bucket', '?')):<10}"
-                    f"{_fmt(r.get('planner_bytes')):>12} "
-                    f"{_fmt(r.get('measured_peak_bytes')):>15}  "
-                    f"{_fmt(r.get('planner_ratio')):>5}  "
-                    f"{_fmt(r.get('calibration')):>5}")
         for a in list(memory.get("leak_alerts") or ())[-3:]:
             lines.append(f"    LEAK {a.get('component', '?')}: freed "
                          f"{a.get('freed_bytes', '?')} B, live held over "

@@ -1,12 +1,10 @@
 """MemoryLedger — full device-memory attribution for the serving stack.
 
 Every other resource in the spine is observed and governed (latency → the
-SLO engine, compute → the roofline tables, topology → fleet metrics), but
-device memory — the resource that actually sizes a fleet — was blind
-arithmetic: AOT_MEMORY.json shows the compiler's measured peak running
-~4-5x above the planner's slab math, admission charges the optimistic
-number, and nobody can say which subsystem owns a given byte of HBM. This
-module closes that gap with three pieces:
+SLO engine, compute → the device trace ``benchmarks/`` reads, topology →
+fleet metrics), but device memory — the resource that actually sizes a
+fleet — was blind arithmetic: nobody could say which subsystem owns a given
+byte of HBM. This module closes that gap with two pieces:
 
 - :class:`MemoryLedger` — a process-global, thread-safe account where every
   device-resident allocation registers a named, component-labeled footprint
@@ -18,30 +16,20 @@ module closes that gap with three pieces:
   recomputation in the :meth:`~marlin_tpu.serving.kvpool.PagedKVPool.audit`
   style and carries every accounting anomaly (double register, strict free
   of an unknown name, a flow entry driven negative) as an error.
-- **The three-view reconciler** — :func:`reconcile` joins (a) the ledger's
-  registered bytes, (b) live ``device.memory_stats()`` where the backend
-  provides it (graceful ``None`` → rendered "n/a" on CPU), and (c) the
-  compiler ``memory_analysis()`` peaks already captured by ProgramCosts —
-  exposed as the ``marlin_mem_{registered,live,unattributed}_bytes``
-  gauge families (:func:`install_memledger_gauges`, a render-time
-  collector like the device-memory gauges) and ``GET /debug/memory``
-  (:func:`memory_payload`).
-- **Measured-peak admission calibration** — :func:`admission_ratio`
-  answers "how far above the planner's slab estimate does this bucket's
-  program actually peak", preferring a live ProgramCosts measurement for
-  the exact program key, falling back to the AOT_MEMORY.json table the
-  planner reads (:func:`~marlin_tpu.models.planner.bucket_calibration`),
-  else 1.0. The serving engine multiplies its per-bucket admission cost by
-  this ratio when ``serve_admission_calibration`` is on, so admission
-  stops over-admitting by the 4-5x the planner under-counts.
+- **The reconciler** — :func:`reconcile` joins (a) the ledger's
+  registered bytes and (b) live ``device.memory_stats()`` where the backend
+  provides it (graceful ``None`` → rendered "n/a" on CPU) — exposed as the
+  ``marlin_mem_{registered,live,unattributed}_bytes`` gauge families
+  (:func:`install_memledger_gauges`, a render-time collector like the
+  device-memory gauges) and ``GET /debug/memory`` (:func:`memory_payload`).
 
 Plus two alarm paths: :class:`LeakDetector` (a component freed in the
 ledger whose live bytes do not drop across N observation windows →
 ``kind="mem"`` / ``ev="leak"`` event + SLO-style hooks) and
 :func:`dump_oom_forensics` (on RESOURCE_EXHAUSTED / allocation failure the
-engine dumps the full ledger + per-bucket ratios + every flight-recorder
-ring to ONE JSONL artifact *before* the retry path runs — the OOM
-post-mortem that used to evaporate with the retry).
+engine dumps the full ledger + every flight-recorder ring to ONE JSONL
+artifact *before* the retry path runs — the OOM post-mortem that used to
+evaporate with the retry).
 
 Import cost is stdlib-only; jax is imported lazily inside the live-bytes
 probe. All mutators run under one lock — the 8-thread scrape stress test
@@ -58,8 +46,7 @@ import time
 
 __all__ = ["MemoryLedger", "LeakDetector", "KNOWN_COMPONENTS",
            "get_ledger", "get_leak_detector", "reset_ledger",
-           "live_device_bytes", "reconcile", "measured_peak_bytes",
-           "admission_ratio", "ratio_table", "memory_payload",
+           "live_device_bytes", "reconcile", "memory_payload",
            "install_memledger_gauges", "emit_snapshot", "is_oom_error",
            "dump_oom_forensics"]
 
@@ -387,13 +374,10 @@ def get_leak_detector() -> LeakDetector:
 
 
 def reset_ledger() -> None:
-    """Test hook: clear the ledger, the leak detector, and the cached
-    admission ratios."""
+    """Test hook: clear the ledger and the leak detector."""
     _LEDGER.reset()
     if _DETECTOR is not None:
         _DETECTOR.reset()
-    with _ratio_lock:
-        _ratio_cache.clear()
     global _last_oom_dump
     _last_oom_dump = 0.0
 
@@ -452,7 +436,7 @@ def live_device_bytes() -> int | None:
 
 
 def reconcile(ledger: MemoryLedger | None = None) -> dict:
-    """The three-view join: ledger-registered bytes (by component), live
+    """The join: ledger-registered bytes (by component), live
     backend bytes (None → "n/a"), and the unattributed remainder
     ``live - registered`` (only when live is known; negative means the
     ledger over-counts — reported, not clamped, because that asymmetry is
@@ -470,91 +454,6 @@ def reconcile(ledger: MemoryLedger | None = None) -> dict:
     else:
         out["unattributed_frac"] = None
     return out
-
-
-# ----------------------------------------------- measured-peak calibration
-
-_ratio_lock = threading.Lock()
-_ratio_cache: dict[tuple, float] = {}
-
-_RATIO_FLOOR = 1.0   # calibration only ever tightens admission
-_RATIO_CAP = 32.0    # a corrupt table must not brick admission entirely
-
-
-def measured_peak_bytes(programs, key: str) -> int | None:
-    """The compiler-measured peak for one program key: the max
-    ``peak_bytes`` over the given ProgramCosts families at ``key`` (the
-    prefill/decode pair peak together — the slab is shared), or None when
-    nothing measured (CPU trace-only captures carry no memory analysis)."""
-    try:
-        from . import perf
-
-        peak = 0
-        for row in perf.get_program_costs().rows():
-            if row.get("program") in programs and row.get("key") == key:
-                peak = max(peak, int(row.get("peak_bytes") or 0))
-        return peak or None
-    except Exception:
-        return None
-
-
-def admission_ratio(planner_bytes: int, programs, key: str) -> float:
-    """measured peak / planner estimate for one bucket's program key,
-    clamped to ``[1, 32]`` and cached per key (admission-path hot).
-    Preference order: a live ProgramCosts measurement for the EXACT key
-    (model dims, page geometry, and kernel all key in — a toy test model
-    can never inherit the bench model's ratio), then the AOT_MEMORY.json
-    calibration table keyed the same way
-    (:func:`~marlin_tpu.models.planner.bucket_calibration`), else 1.0 —
-    uncalibrated admission is exactly the pre-ledger behavior."""
-    ck = (tuple(programs), key)
-    with _ratio_lock:
-        cached = _ratio_cache.get(ck)
-    if cached is not None:
-        return cached
-    ratio = 1.0
-    if planner_bytes > 0:
-        peak = measured_peak_bytes(programs, key)
-        if peak is None:
-            try:
-                from ..models.planner import bucket_calibration
-
-                peak = bucket_calibration(key)
-            except Exception:
-                peak = None
-        if peak:
-            ratio = min(max(peak / float(planner_bytes), _RATIO_FLOOR),
-                        _RATIO_CAP)
-    with _ratio_lock:
-        _ratio_cache[ck] = ratio
-    return ratio
-
-
-def ratio_table() -> list[dict]:
-    """The per-bucket planner-ratio table for /debug/memory and the ops
-    console: one row per AOT-calibrated serve bucket (planner slab bytes,
-    compiler peak, measured/planner ratio), merged from AOT_MEMORY.json's
-    ``serve_buckets`` report. Empty when the report has not run."""
-    try:
-        from ..models.planner import _AOT_MEMORY
-
-        with open(_AOT_MEMORY) as f:
-            buckets = json.load(f).get("serve_buckets", {}).get(
-                "buckets", {})
-    except Exception:
-        return []
-    rows = []
-    for name, info in sorted(buckets.items()):
-        if not isinstance(info, dict) or "error" in info:
-            continue
-        rows.append({
-            "bucket": name,
-            "planner_bytes": info.get("planner_slab_bytes"),
-            "measured_peak_bytes": info.get("compiler_peak_bytes"),
-            "planner_ratio": info.get("peak_planner_ratio"),
-            "calibration": info.get("calibration"),
-        })
-    return rows
 
 
 # ----------------------------------------------------- exposition / gauges
@@ -604,9 +503,8 @@ def install_memledger_gauges(registry=None) -> None:
 
 def memory_payload() -> tuple[int, dict]:
     """(status_code, body) for ``GET /debug/memory``: the full ledger
-    snapshot, the self-audit, the three-view reconciliation (live/
-    unattributed render "n/a" on CPU), the per-bucket planner-ratio
-    table, and recent leak alerts. 503 when the audit reports a
+    snapshot, the self-audit, the reconciliation (live/unattributed
+    render "n/a" on CPU), and recent leak alerts. 503 when the audit reports a
     violation (an inconsistent account is as out-of-rotation as an
     inconsistent pool); never raises."""
     try:
@@ -625,7 +523,6 @@ def memory_payload() -> tuple[int, dict]:
                                    else rec["unattributed_bytes"]),
             "unattributed_frac": ("n/a" if rec["unattributed_frac"] is None
                                   else rec["unattributed_frac"]),
-            "planner_ratios": ratio_table(),
             "leak_alerts": list(get_leak_detector().alerts),
         }
         return (200 if audit["ok"] else 503), body
@@ -653,8 +550,8 @@ def is_oom_error(exc: BaseException) -> bool:
 def dump_oom_forensics(reason: str, extra: dict | None = None,
                        min_interval_s: float = 5.0) -> str | None:
     """Dump the full memory post-mortem to ONE JSONL artifact — the
-    ledger (entries + audit + reconciliation), the per-bucket planner
-    ratios, and every live flight-recorder ring — and land a
+    ledger (entries + audit + reconciliation) and every live
+    flight-recorder ring — and land a
     ``kind="mem"`` / ``ev="oom_dump"`` event pointing at it. Called by
     the engine's allocation-failure paths BEFORE the retry runs (the
     retry rebuilds pools and destroys the evidence). Rate-limited
@@ -679,9 +576,6 @@ def dump_oom_forensics(reason: str, extra: dict | None = None,
         lines = [json.dumps(head, default=str)]
         for e in led.entries():
             lines.append(json.dumps({"kind": "mem", "ev": "entry", **e}))
-        for row in ratio_table():
-            lines.append(json.dumps({"kind": "mem", "ev": "ratio", **row},
-                                    default=str))
         for rec in perf.flight_records():
             lines.append(json.dumps(rec, default=str))
         cap_dir = perf._capture_dir()

@@ -15,11 +15,6 @@ profiler UI, no live process:
   three lines; streams carrying ``ev="page"`` records (the paged KV pool)
   additionally get a paging line — prefix-cache hit rate, peak page
   occupancy, copy-on-write splits.
-- **program utilization** — ``kind="program"`` records (obs/perf.py): XLA
-  cost models (``ev="cost"``) and measured-utilization snapshots
-  (``ev="util"``, emitted by engine close / streamed ops / the autotuner)
-  rendered as a roofline table — calls, achieved GFLOP/s, and the
-  fraction of the attainable rate, per compiled program and configuration.
 - **compile / memory timelines** — ``kind="compile"`` records (the
   jax.monitoring bridge) and ``kind="memory"`` samples
   (:func:`~marlin_tpu.obs.collectors.log_device_memory`) as time-offset
@@ -49,7 +44,9 @@ __all__ = ["load_events", "parse_when", "trace_join", "analyze", "main",
 #: still render through the generic per-kind latency table, but they must
 #: be declared here: an undeclared kind is a black-box stream, and the
 #: static analyzer (tools/analyze, doc-sync check) fails the gate on any
-#: emission site this set does not cover.
+#: emission site this set does not cover. ``"program"`` is emitted by no
+#: one any more (the wall-clock roofline records of older logs): it stays
+#: declared so that such a log still parses, and renders no table.
 KNOWN_KINDS = frozenset({
     "ckpt", "compile", "fleet", "flight", "mem", "memory", "prefetch",
     "profile", "program", "resume", "resume_skip", "retry",
@@ -303,42 +300,6 @@ def _serving_section(events: list[dict]) -> list[str]:
     return out
 
 
-def _program_section(events: list[dict]) -> list[str]:
-    """The roofline table: the LAST ``ev="util"`` snapshot per
-    (program, key) — snapshots are cumulative, so the last one is the
-    run's total — plus a count of cost-only programs that never got a
-    timing joined."""
-    utils: dict[tuple, dict] = {}
-    cost_only: set = set()
-    for r in events:
-        if r.get("kind") != "program":
-            continue
-        pk = (r.get("program"), r.get("key"))
-        if r.get("ev") == "util":
-            utils[pk] = r
-        elif r.get("ev") == "cost":
-            cost_only.add(pk)
-    out = ["== program utilization =="]
-    if not utils and not cost_only:
-        out.append("(no program records — obs.perf cost capture never ran)")
-        return out
-    if utils:
-        out.append(f"{'program':<20}{'key':<36}{'calls':>7}{'GFLOP/s':>10}"
-                   f"{'roofline':>10}")
-        for (prog, key), r in sorted(utils.items()):
-            ach = r.get("achieved_flops_per_s")
-            frac = r.get("roofline_frac")
-            out.append(
-                f"{str(prog):<20}{str(key):<36}{r.get('calls', 0):>7}"
-                f"{(f'{ach / 1e9:.2f}' if ach else '-'):>10}"
-                f"{(f'{frac * 100:.2f}%' if frac is not None else '-'):>10}")
-    unmeasured = cost_only - set(utils)
-    if unmeasured:
-        out.append(f"({len(unmeasured)} program(s) with a captured cost "
-                   f"model but no joined timing)")
-    return out
-
-
 def _timeline_section(events: list[dict], t0: float) -> list[str]:
     out = []
     compiles = [r for r in events if r.get("kind") == "compile"
@@ -435,8 +396,6 @@ def analyze(events: list[dict], skipped: int = 0) -> str:
     out.extend(_trace_section(events))
     out.append("")
     out.extend(_serving_section(events))
-    out.append("")
-    out.extend(_program_section(events))
     mem_sec = _memory_attribution_section(events)
     if mem_sec:
         out.append("")

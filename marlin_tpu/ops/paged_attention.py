@@ -4,8 +4,8 @@ The paged decode path (models/transformer.lm_decode_paged) historically
 GATHERED each row's context out of the page slab by block table
 (``t[tables].reshape(B, L, ...)``) and then ran dense attention over the
 materialized copy — a per-step copy of every live row's whole context whose
-cost the serve bench measured at −5±3% tok/s vs the dense slab on no-prefix
-workloads (BENCH_ALL.json; ROADMAP "fused paged decode-attention kernel").
+cost a CPU serve bench older than PR 27 read at −5±3% tok/s vs the dense
+slab on no-prefix workloads (no chip reading; `PERF.md` has the chip's).
 This module is the kernels that erase the copy: the block table itself
 names the pages that stream HBM→VMEM directly from the slab, and the context
 is never materialized as a separate array.
@@ -127,7 +127,7 @@ from jax.experimental.pallas import tpu as pltpu
 from .pallas_kernels import _interpret
 
 __all__ = ["paged_decode_attention", "paged_decode_attention_latent",
-           "align_page_len", "paged_attention_cost", "PAGE_SUBLANE"]
+           "align_page_len", "PAGE_SUBLANE"]
 
 # TPU sublane multiple: the kernel's K/V block second-to-minor dimension is
 # page_len, so pages must stay a multiple of this for an unpadded block
@@ -145,26 +145,6 @@ def align_page_len(page_len: int) -> int:
     if page_len < 1:
         raise ValueError(f"page_len must be >= 1, got {page_len}")
     return -(-page_len // PAGE_SUBLANE) * PAGE_SUBLANE
-
-
-def paged_attention_cost(batch: int, table_width: int, page_len: int,
-                         kv_heads: int, group: int, dh: int,
-                         itemsize: int = 4) -> dict:
-    """Analytic cost model for one kernel call, ``cost_analysis()``-shaped
-    (the ProgramCosts capture fallback for the Mosaic path, where the
-    pallas_call is opaque to XLA's analysis; interpret-mode lowerings are
-    analyzed as ordinary XLA ops and don't need this). FLOPs are the two
-    (group·dh × page_len) contractions per (row, page, kv-head); bytes are
-    one in-place pass over each row's table extent of the slab plus q/out.
-    It prices the TABLE HANDED IN, ``batch`` x ``table_width`` pages, not
-    the pages visited: the kernel skips every page past a row's length
-    (the engine counts the visited ones as ``kv_pages`` on its
-    ``serve.decode.dispatch`` span)."""
-    t = batch * table_width * kv_heads
-    flops = 2.0 * 2.0 * t * group * dh * page_len
-    kv_bytes = 2.0 * t * page_len * dh * itemsize
-    qo_bytes = 2.0 * batch * kv_heads * group * dh * itemsize
-    return {"flops": flops, "bytes accessed": kv_bytes + qo_bytes}
 
 
 def _paged_attn_kernel(*refs, page_len: int, windowed: bool = False):

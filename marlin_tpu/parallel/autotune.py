@@ -247,26 +247,10 @@ def tune_multiply(mat, other, strategies=None, reps: int = 3,
     if not explicit:
         strategies = _candidates(mat, other_shape,
                                  jnp.dtype(other_dtype).itemsize)
-    # roofline substrate for strategy ranking (obs/perf.py): every timed
-    # candidate lands in the ProgramCosts registry with the multiply's
-    # analytic cost model — achieved-FLOP/s per strategy is what the
-    # autotune-over-generated-kernels direction (ROADMAP) selects on
-    from ..obs import perf
-
-    costs = perf.get_program_costs()
     m, k = mat.shape
     n = other_shape[1]
     a_item = jnp.dtype(mat.data.dtype).itemsize
     b_item = jnp.dtype(other_dtype).itemsize
-    analytic = {"flops": 2.0 * m * k * n,
-                "bytes accessed": float(m * k * a_item + k * n * b_item
-                                        + m * n * max(a_item, b_item))}
-
-    def _prog_key(s):
-        return perf.program_key(
-            strategy=s, shape=f"{m}x{k}x{n}", dtype=str(mat.data.dtype),
-            prec=precision or "config", devices=mat.mesh.devices.size)
-
     results = []
     with _scratch_accounted(f"multiply:{m}x{k}x{n}",
                             m * n * max(a_item, b_item)):
@@ -281,8 +265,6 @@ def tune_multiply(mat, other, strategies=None, reps: int = 3,
                 evaluate(c)
                 elapsed = time.perf_counter() - t0
                 results.append((s, elapsed / reps))
-                costs.capture("multiply", _prog_key(s), cost=analytic)
-                costs.observe("multiply", _prog_key(s), elapsed, calls=reps)
             except UnknownStrategyError:
                 # an engine rejecting the strategy name is a skippable
                 # candidate; any other ValueError is a genuinely broken run
@@ -291,7 +273,6 @@ def tune_multiply(mat, other, strategies=None, reps: int = 3,
                 continue
     if not results:
         raise ValueError("no viable multiply strategy could be timed")
-    costs.emit("multiply")  # utilization snapshots for the analyzer's table
     results.sort(key=lambda kv: kv[1])
     if not explicit:
         key = _cache_key(mat, other, precision)
@@ -343,19 +324,16 @@ def _gemm_key(m: int, k: int, n: int, dtype) -> tuple:
     return ("gemm", (int(m), int(k), int(n)), str(dtype), *_device_sig())
 
 
-def _time_candidates(program: str, candidates, run, prog_key, analytic,
-                     reps: int, scratch_bytes: int = 0):
+def _time_candidates(program: str, candidates, run, reps: int,
+                     scratch_bytes: int = 0):
     """Shared measurement loop: compile, time ``reps`` back-to-back calls
-    (utils.profiling.evaluate forces true completion), land each candidate
-    in ProgramCosts with the problem's analytic cost — achieved-FLOP/s per
-    candidate is the ranking the report table shows. A candidate that
-    fails to build/run is skipped, not fatal (the family generator can
-    propose a tile the backend rejects). ``scratch_bytes`` accounts the
-    tuning window's result-buffer residency in the memory ledger."""
-    from ..obs import perf
+    (utils.profiling.evaluate forces true completion) and rank by measured
+    time. A candidate that fails to build/run is skipped, not fatal (the
+    family generator can propose a tile the backend rejects).
+    ``scratch_bytes`` accounts the tuning window's result-buffer residency
+    in the memory ledger."""
     from ..utils.profiling import evaluate
 
-    costs = perf.get_program_costs()
     results = []
     with _scratch_accounted(program, scratch_bytes) if scratch_bytes \
             else contextlib.nullcontext():
@@ -371,11 +349,8 @@ def _time_candidates(program: str, candidates, run, prog_key, analytic,
             except Exception:
                 continue
             results.append((name, elapsed / reps))
-            costs.capture(program, prog_key(name), cost=analytic)
-            costs.observe(program, prog_key(name), elapsed, calls=reps)
     if not results:
         raise ValueError(f"no {program} candidate could be timed")
-    costs.emit(program)
     results.sort(key=lambda kv: kv[1])
     return results
 
@@ -410,17 +385,8 @@ def tune_gemm(a, b, candidates=None, reps: int = 3) -> list[tuple[str, float]]:
         t = tile_family.parse_gemm_candidate(name)
         return pallas_matmul(a, b, bm=t.bm, bn=t.bn, bk=t.bk)
 
-    from ..obs import perf
-
-    analytic = {"flops": 2.0 * m * k * n,
-                "bytes accessed": float((m * k + k * n + m * n) * item)}
-
-    def prog_key(name):
-        return perf.program_key(candidate=name, shape=f"{m}x{k}x{n}",
-                                dtype=str(a.dtype))
-
-    results = _time_candidates("gemm", candidates, run, prog_key, analytic,
-                               reps, scratch_bytes=m * n * item)
+    results = _time_candidates("gemm", candidates, run, reps,
+                               scratch_bytes=m * n * item)
     if not explicit:
         key = _gemm_key(m, k, n, a.dtype)
         _CACHE[key] = results[0][0]
@@ -485,21 +451,7 @@ def tune_bsr(bsr, b, candidates=None, reps: int = 2) -> list[tuple[str, float]]:
             return bsr.multiply(arr, backend="pallas")
         return bsr.multiply(arr, chunk_blocks=cb)
 
-    from ..obs import perf
-
-    bs = bsr.block_size
-    analytic = {"flops": 2.0 * bsr.nnzb * bs * bs * p,
-                "bytes accessed": float(
-                    bsr.nnzb * (bs * bs + bs * p) * item
-                    + bsr.shape[0] * p * item)}
-
-    def prog_key(name):
-        return perf.program_key(candidate=name,
-                                shape=f"{bsr.shape[0]}x{bsr.shape[1]}",
-                                bs=bs, nnzb=bsr.nnzb, p=p)
-
-    results = _time_candidates("bsr_spmm", candidates, run, prog_key,
-                               analytic, reps,
+    results = _time_candidates("bsr_spmm", candidates, run, reps,
                                scratch_bytes=bsr.shape[0] * p * item)
     if not explicit:
         key = _bsr_key(bsr, p, arr.dtype)

@@ -58,24 +58,6 @@ def _resolve_precision(precision):
     return precision or get_config().matmul_precision
 
 
-def _capture_matmul_cost(program: str, fn, a, b, **meta) -> None:
-    """Land one multiply engine's XLA cost model (flops, bytes accessed) in
-    the process ProgramCosts registry (perf.capture_traced: tried-gated
-    trace+lower — two set lookups per call after a configuration's first,
-    even when lowering fails). Timings are joined by the autotuner and the
-    benches; this side contributes the cost model the roofline fractions
-    divide by. Never raises."""
-    from ..obs import perf
-
-    try:
-        key = perf.program_key(
-            shape=f"{a.shape[0]}x{a.shape[1]}x{b.shape[1]}",
-            dtype=str(a.dtype), **meta)
-        perf.get_program_costs().capture_traced(program, key, fn, (a, b))
-    except Exception:
-        pass
-
-
 def build_rmm_mesh(split: tuple[int, int, int], devices=None) -> Mesh:
     """Arrange devices into the (m_split, k_split, n_split) grid chosen by the
     CARMA heuristic — the mesh-shaped descendant of ``MatrixMultPartitioner``'s
@@ -150,8 +132,6 @@ def rmm_matmul(
     a = jax.device_put(a, NamedSharding(mesh3, P(_M, _K)))
     b = jax.device_put(b, NamedSharding(mesh3, P(_K, _N)))
     fn = _rmm_fn(mesh3, _resolve_precision(precision), accum_dtype or a.dtype)
-    _capture_matmul_cost("rmm_matmul", fn, a, b,
-                         split="x".join(map(str, split)))
     c = fn(a, b)
     return c[:m, :n] if (mp, np_) != (m, n) else c
 
@@ -188,7 +168,6 @@ def broadcast_matmul(
     fn = _broadcast_fn(
         out_sharding, replicate, _resolve_precision(precision), accum_dtype or a.dtype
     )
-    _capture_matmul_cost("broadcast_matmul", fn, a, b, replicate=replicate)
     return fn(a, b)
 
 
@@ -213,8 +192,6 @@ def gspmd_matmul(
     whatever shardings they carry and the compiler inserts the collective
     schedule. Competes with :func:`rmm_matmul` in examples/rmm_compare."""
     fn = _gspmd_fn(out_sharding, _resolve_precision(precision), accum_dtype or a.dtype)
-    _capture_matmul_cost("gspmd_matmul", fn, a, b,
-                         devices=out_sharding.mesh.devices.size)
     return fn(a, b)
 
 

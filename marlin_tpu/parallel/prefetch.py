@@ -1,7 +1,7 @@
 """Asynchronous host→device prefetch for the out-of-core streaming layer.
 
-BENCH_ALL.json's config-4 split names the problem: the tall-skinny Gramian
-runs ~10,901 GFLOP/s with operands resident but only ~4 GFLOP/s end-to-end —
+The problem: the tall-skinny Gramian's rank update runs ~10,901 GFLOP/s with
+operands resident (PERF.md, "Chip rows older than PR 1"), but end to end
 the device idles while the caller's thread synchronously reads a chunk,
 converts its dtype, and dispatches the upload, one chunk at a time. The
 reference never faced this (Spark's shuffle fetches overlap task compute for

@@ -32,7 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..config import get_config
-from ..obs import perf, trace as obs_trace
+from ..obs import trace as obs_trace
 from ..utils.profiling import StageTimes
 from .prefetch import ChunkPrefetcher
 
@@ -63,17 +63,6 @@ def _gram_accumulate_impl(g, x, precision):
 
 _gram_accumulate = jax.jit(_gram_accumulate_impl,
                            static_argnames=("precision",))
-
-
-def _capture_chunk_cost(program: str, jitfn, args, static: dict,
-                        key: str) -> None:
-    """Land one chunk program's XLA cost model in the process ProgramCosts
-    registry (perf.capture_traced: tried-gated trace+lower, never raises).
-    Runs once per streamed op, on its first chunk — the tail chunk may be
-    shorter and compile its own program, but the leading geometry is what
-    the op spends its time in."""
-    perf.get_program_costs().capture_traced(program, key, jitfn, args,
-                                            static)
 
 
 def iter_row_chunks(a, chunk_rows: int) -> Iterator[np.ndarray]:
@@ -182,21 +171,12 @@ def streamed_matmul(
     # one span per streamed op: the prefetcher's producer threads inherit it
     # (it is created inside), so the op's chunk records + close summary join
     # into one trace in the JSONL (docs/observability.md)
-    n_chunks, prog_key, t_op = 0, None, time.perf_counter()
     with obs_trace.span("streamed_matmul"):
         stream, closer = _chunk_stream(a_source, chunk_rows, transfer_dtype,
                                        prefetch, stats)
         try:
             for x in stream:
                 saw_chunk = True
-                if prog_key is None:
-                    prog_key = perf.program_key(
-                        chunk=f"{x.shape[0]}x{x.shape[1]}",
-                        n=b_dev.shape[1], dtype=str(x.dtype))
-                    _capture_chunk_cost("streamed_matmul", _chunk_mm,
-                                        (x, b_dev),
-                                        {"precision": precision}, prog_key)
-                n_chunks += 1
                 with stats.timed("compute"):
                     pending.append(chunk_mm(x))
                 drain(1)  # keep one result in flight: overlap compute + D2H
@@ -205,13 +185,6 @@ def streamed_matmul(
             drain(0)
         finally:
             closer()
-        if prog_key is not None:
-            # end-to-end wall against the per-chunk cost model: the
-            # roofline fraction IS the out-of-core gap, quantified per op
-            costs = perf.get_program_costs()
-            costs.observe("streamed_matmul", prog_key,
-                          time.perf_counter() - t_op, calls=n_chunks)
-            costs.emit("streamed_matmul")
     return out if out is not None else np.concatenate(results, axis=0)
 
 
@@ -259,7 +232,6 @@ def streamed_gramian(
     # with no explicit transfer dtype, upload in the accumulation dtype (the
     # pre-existing contract: `dtype` governs both upload width and accumulator)
     effective_transfer = transfer_dtype if transfer_dtype is not None else dtype
-    n_chunks, prog_key, t_op = 0, None, time.perf_counter()
     with obs_trace.span("streamed_gramian"):  # as in streamed_matmul
         stream, closer = _chunk_stream(a_source, chunk_rows,
                                        effective_transfer, prefetch, stats)
@@ -271,13 +243,6 @@ def streamed_gramian(
                 if g is None:
                     n_cols = x.shape[1]
                     g = jnp.zeros((n_cols, n_cols), dtype)
-                    prog_key = perf.program_key(
-                        chunk=f"{x.shape[0]}x{x.shape[1]}",
-                        dtype=str(x.dtype), acc=jnp.dtype(dtype).name)
-                    _capture_chunk_cost("streamed_gramian", _gram_accumulate,
-                                        (g, x), {"precision": precision},
-                                        prog_key)
-                n_chunks += 1
                 with stats.timed("compute"):
                     g = accumulate(g, x)
         finally:
@@ -285,10 +250,4 @@ def streamed_gramian(
         if g is None:
             raise ValueError("empty input stream")
         with stats.timed("drain"):
-            out = np.asarray(jax.device_get(g))
-        if prog_key is not None:  # e2e wall, as in streamed_matmul
-            costs = perf.get_program_costs()
-            costs.observe("streamed_gramian", prog_key,
-                          time.perf_counter() - t_op, calls=n_chunks)
-            costs.emit("streamed_gramian")
-        return out
+            return np.asarray(jax.device_get(g))

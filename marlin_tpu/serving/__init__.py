@@ -32,7 +32,6 @@ Quick start::
 
 from .batcher import (  # noqa: F401
     BatchFormer,
-    bucket_kv_bytes,
     normalize_buckets,
     pick_bucket,
 )

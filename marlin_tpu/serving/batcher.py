@@ -25,9 +25,8 @@ compile. The discipline here:
 
 A bucket's row state (block tables, cursors, sampling vectors) is
 :class:`~.kvpool.PagedGroup`; :func:`~.kvpool.decode_inputs` packs the live
-rows of every bucket's group into one decode call; warm-up and cost capture
-of the programs are :func:`~.kvpool.warmup_paged` and
-:func:`~.kvpool.capture_paged_costs`. The pool sizes by page arithmetic:
+rows of every bucket's group into one decode call; warm-up of the programs
+is :func:`~.kvpool.warmup_paged`. The pool sizes by page arithmetic:
 ``models/planner.kv_page_bytes`` × ``serve_num_pages`` is its steady-state
 footprint, whatever the bucket set.
 """
@@ -37,8 +36,7 @@ from __future__ import annotations
 import collections
 from typing import Iterable, Sequence
 
-__all__ = ["normalize_buckets", "pick_bucket", "bucket_kv_bytes",
-           "BatchFormer", "bucket_program_key"]
+__all__ = ["normalize_buckets", "pick_bucket", "BatchFormer"]
 
 Bucket = tuple[int, int]  # (P_bucket, steps_bucket)
 
@@ -68,28 +66,6 @@ def pick_bucket(prompt_len: int, steps: int,
         if prompt_len <= p and steps <= s:
             return (p, s)
     return None
-
-
-def bucket_kv_bytes(params: dict, heads: int, bucket: Bucket,
-                    compute_dtype=None, batch: int = 1) -> int:
-    """KV-cache bytes of one bucket row at its full extent (times
-    ``batch``): layers x 2 x (P + steps) x kv_heads x dh in the compute
-    dtype. The planner's side of the measured-peak admission calibration
-    (``ServeEngine._calibrate_cost``: what
-    :func:`~marlin_tpu.obs.memledger.admission_ratio` compares a bucket
-    program's compiled peak with). The admission charge itself is the
-    request's actual pages (``PagedLMProgram.admission_cost``)."""
-    import jax.numpy as jnp
-
-    from ..models.transformer import _n_layers
-
-    p, s = bucket
-    d = params["emb"].shape[1]
-    dh = d // heads
-    kv_dim = params["l0"]["wk"].shape[1]  # kv_heads * dh (GQA-aware)
-    dt = jnp.dtype(compute_dtype) if compute_dtype else params["emb"].dtype
-    return _n_layers(params) * 2 * (p + s) * (kv_dim // dh) * dh \
-        * dt.itemsize * batch
 
 
 class _Group:
@@ -158,30 +134,3 @@ class BatchFormer:
         the prefill-on-admit path: a freed row takes the best pending
         request immediately."""
         return self._groups[bucket].take(n) if bucket in self._groups else []
-
-
-def bucket_program_key(params: dict, bucket: Bucket, max_batch: int,
-                       compute_dtype=None) -> str:
-    """The roofline-accounting key for one bucket's compiled programs
-    (obs/perf.py). Capture sites (warmup/group creation) and measurement
-    sites (the engine's step/prefill timings) MUST both build the key here,
-    or the cost/timing join silently misses."""
-    import jax.numpy as jnp
-
-    from ..obs import perf
-
-    p, s = bucket
-    dt = jnp.dtype(compute_dtype) if compute_dtype else params["emb"].dtype
-    # the model geometry is part of the program identity: two models with
-    # the same bucket/width/dtype compile different programs with different
-    # costs, and their entries must not collide
-    v, d = params["emb"].shape
-    try:
-        from ..models.transformer import _n_layers
-
-        layers = _n_layers(params)
-    except Exception:
-        layers = "?"
-    return perf.program_key(bucket=f"{p}x{s}", rows=max_batch, dtype=dt.name,
-                            model=f"v{v}d{d}l{layers}")
-

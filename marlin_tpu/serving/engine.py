@@ -104,11 +104,10 @@ from ..obs.exposition import (register_health_provider,
 from ..obs.metrics import get_registry
 from ..utils import faults
 from ..utils.tracing import annotate
-from .batcher import BatchFormer, bucket_kv_bytes, normalize_buckets
+from .batcher import BatchFormer, normalize_buckets
 from .kvpool import (PagedGroup, PagedKVPool, PagePoolExhausted,
-                     auto_num_pages, auto_window_pages,
-                     capture_paged_costs, decode_inputs, decode_pages,
-                     group_chunk, paged_program_key, warmup_paged)
+                     auto_num_pages, auto_window_pages, decode_inputs,
+                     decode_pages, group_chunk, warmup_paged)
 from .metrics import ServeMetrics
 from .programs import PagedLMProgram, ProgramRowSet
 from .request import (SHED_REASON_PREFIX, STATUS_ERROR, STATUS_EXPIRED,
@@ -399,10 +398,9 @@ class ServeEngine:
             from ..ops.paged_attention import align_page_len
 
             self._page_len = align_page_len(self._page_len)
-        #: the one decode program has the widest bucket's shape: its table
-        #: width, and the bucket under whose key its calls are timed
+        #: the one decode program has the widest bucket's shape: this is its
+        #: table's width
         self._decode_pages = decode_pages(self.buckets, self._page_len)
-        self._decode_bucket = max(self.buckets, key=sum)
         self._prefill_chunk = int(cfg.serve_prefill_chunk
                                   if prefill_chunk is None else prefill_chunk)
         self._prefix_cache = bool(cfg.serve_prefix_cache
@@ -534,16 +532,12 @@ class ServeEngine:
         # the deadline-admission estimate's only input
         self._service_ewma = 0.0
         self._thread = self._make_thread(0)
-        # --- performance introspection (obs/perf.py) -----------------------
+        # --- the flight recorder (obs/perf.py) -----------------------------
         # the step-time black box: per-iteration records from the worker
         # loop, dumped on worker faults, on close, and via GET /debug/flight
         self.flight = perf.FlightRecorder(name=self._name)
         self._heartbeat: float | None = None  # real clock; worker stamps it
         self._live_rows = 0                   # worker-written, healthz-read
-        self._prog_keys: dict[tuple, str] = {}
-        # per-bucket measured-peak admission ratio (obs/memledger.py),
-        # resolved once on first admission to that bucket
-        self._calib_ratios: dict[tuple, float] = {}
         self._finalized = False
         # readiness: /healthz reports this engine's lifecycle and 503s once
         # it leaves "accepting" (weakref — the provider must never pin a
@@ -731,39 +725,6 @@ class ServeEngine:
         p["shed_count"] = self._queue.shed_count
         return p
 
-    def _prog_key(self, bucket) -> str:
-        """The roofline-accounting key for this engine's programs at one
-        bucket (cached — it sits on the per-step path): the page geometry
-        and the decode kernel are part of it (kvpool.paged_program_key)."""
-        key = self._prog_keys.get(bucket)
-        if key is None:
-            key = self._prog_keys[bucket] = paged_program_key(
-                self.params, bucket, self.max_batch, self._page_len,
-                self.compute_dtype, self._decode_kernel)
-        return key
-
-    def _calibrate_cost(self, request, pbucket, cost: int) -> int:
-        """Measured-peak admission calibration (obs/memledger.py): scale
-        the planner's per-bucket charge by the compiler-measured
-        peak/planner ratio for this bucket's program key, so admission
-        stops over-admitting by what the KV arithmetic under-counts
-        (AOT_MEMORY.json serve_buckets). LM only — one-shot programs
-        price their actual padded device row; the ratio resolves once per
-        bucket (live ProgramCosts first, the AOT table second, 1.0 when
-        neither measured this exact program) and is cached."""
-        if request.program != "lm" or self._spec is not None:
-            return cost  # a spec's programs have no captured cost model
-        ratio = self._calib_ratios.get(pbucket)
-        if ratio is None:
-            planner = bucket_kv_bytes(self.params, self.heads, pbucket,
-                                      self.compute_dtype,
-                                      batch=self.max_batch)
-            ratio = memledger.admission_ratio(
-                planner, ("lm_prefill_paged", "lm_decode_paged"),
-                self._prog_key(pbucket))
-            self._calib_ratios[pbucket] = ratio
-        return int(cost * ratio) if ratio != 1.0 else cost
-
     def _ensure_kvpool(self) -> PagedKVPool:
         """The engine's one paged pool, built lazily (warmup or the first
         admission) and rebuilt zeroed after a recovery or slab loss."""
@@ -811,22 +772,12 @@ class ServeEngine:
 
     def _finalize_obs(self) -> None:
         """Terminal observability flush (close/drain, idempotent): dump the
-        flight ring and land the program-utilization snapshots
-        (``kind="program"``/``ev="util"``) in the EventLog, then drop out
-        of the /healthz registry — a terminated engine must not hold the
-        process at 503."""
+        flight ring, then drop out of the /healthz registry — a terminated
+        engine must not hold the process at 503."""
         if self._finalized:
             return
         self._finalized = True
         self._flight_dump("close")
-        try:
-            families = ["lm_decode_paged", "lm_prefill_paged"]
-            families += [p.cost_program for n, p in self._programs.items()
-                         if n != "lm" and p.cost_program]
-            for prog in dict.fromkeys(families):
-                perf.get_program_costs().emit(prog)
-        except Exception:
-            pass
         # a terminated engine must leave the memory ledger clean: sweep
         # everything it still owns (the KV slab, unconsumed migration
         # blobs) and land one attribution snapshot for the post-hoc report
@@ -1035,8 +986,6 @@ class ServeEngine:
                 cost = prog.admission_cost(request, pbucket)
             except ValueError as exc:
                 return self._refuse(handle, STATUS_REJECTED, str(exc))
-            if get_config().serve_admission_calibration:
-                cost = self._calibrate_cost(request, pbucket, cost)
             reason = self._queue.try_admit(
                 cost, priority=request.priority,
                 deadline_slack_s=(request.deadline - now
@@ -1284,8 +1233,6 @@ class ServeEngine:
             if not live:
                 continue
             entries = [rows.entries[i] for i in live]
-            pkey = prog.program_key(bucket[1:],
-                                    prog.step_width(len(entries)))
             try:
                 faults.fire("serve.program_step",
                             path=f"{bucket[0]}-{len(entries)}")
@@ -1297,8 +1244,7 @@ class ServeEngine:
                 continue
             wall = time.perf_counter() - t0
             self.metrics.record_step(
-                bucket, len(live), rows.width, wall, program_key=pkey,
-                program=prog.cost_program, label=bucket[0])
+                bucket, len(live), rows.width, wall, label=bucket[0])
             self.flight.record(
                 "step", bucket=list(bucket), rows=len(live), seconds=wall,
                 queue_depth=self._queue.count, compiles=_compile_count())
@@ -1822,11 +1768,6 @@ class ServeEngine:
             group = pools.get(bucket)
             if group is None and bucket in self.buckets:
                 group = pools[bucket] = self._new_group(bucket)
-                capture_paged_costs(
-                    self.params, self.heads, bucket, self.max_batch,
-                    pool, self._prefill_chunk, self.compute_dtype,
-                    self.moe, key=self._prog_key(bucket),
-                    kernel=self._decode_kernel)
             bound = False
             try:
                 faults.fire("serve.migrate",
@@ -2147,17 +2088,6 @@ class ServeEngine:
                 group = pools.get(e.bucket)
                 if group is None:
                     group = pools[e.bucket] = self._new_group(e.bucket)
-                    # no-warmup path: the cost models of the bucket's
-                    # prefill and of the one decode program (the widest
-                    # bucket's shape, under its key) still land with their
-                    # first (lazy) compile
-                    for bucket in (e.bucket, self._decode_bucket):
-                        capture_paged_costs(
-                            self.params, self.heads, bucket, self.max_batch,
-                            pool, self._prefill_chunk, self.compute_dtype,
-                            self.moe, key=self._prog_key(bucket),
-                            kernel=self._decode_kernel,
-                            table_pages=self._decode_pages)
                 slot = group.free_slots()[0]
                 n = r.prompt.shape[0]
                 shared_len, spages, snap, seen_len = \
@@ -2328,8 +2258,8 @@ class ServeEngine:
                 # nothing waits for this chunk: it has no interval of its
                 # own (its device time is inside the next landing's)
                 self.metrics.record_prefill(
-                    e.bucket, 0.0, rid=r.rid, program="lm_prefill_paged",
-                    chunk=[cs, tokens], final=False)
+                    e.bucket, 0.0, rid=r.rid, chunk=[cs, tokens],
+                    final=False)
                 return tokens
             pf_queue.popleft()
             group.begin_decode(slot)
@@ -2379,8 +2309,8 @@ class ServeEngine:
         worker waits for a prefill program): the ``prefill`` record with the
         token (``new_tokens == 1``, written now that it is here: TTFT is a
         real arrival time; its seconds are the interval since the landing
-        before it, which join the prefill program's cost model where they
-        are this chunk's alone: :meth:`_Pipeline.landed`), the
+        before it, this chunk's alone only where its predecessor had landed:
+        :meth:`_Pipeline.landed`), the
         prompt's pages published for prefix sharing, and the row retired
         where that token ends it. A row retired with its chunk in flight
         (deadline, a failed neighbour) is matched by entry and skipped."""
@@ -2399,13 +2329,11 @@ class ServeEngine:
                 return
             t = time.perf_counter()
             seconds, pipe.landed_t = t - max(item.t0, pipe.landed_t), t
-            alone = pipe.landed(item.seq)
+            pipe.landed(item.seq)
             if group.entries[slot] is not e:
                 return
             self.metrics.record_prefill(
-                e.bucket, seconds, rid=r.rid,
-                program_key=self._prog_key(e.bucket) if alone else None,
-                program="lm_prefill_paged", chunk=item.chunk, final=True)
+                e.bucket, seconds, rid=r.rid, chunk=item.chunk, final=True)
             group.land_first(slot, first)
             group.ttft_s[slot] = self._clock() - e.enq_t
             # the prompt's full pages are final now — publish them for
@@ -2719,7 +2647,7 @@ class ServeEngine:
             return
         t = time.perf_counter()
         seconds, pipe.landed_t = t - max(launch.t0, pipe.landed_t), t
-        alone = pipe.landed(launch.seq)
+        pipe.landed(launch.seq)
         with annotate("serve.decode.retire", bucket=launch.tag) as retire:
             landed = [(n, group, i)
                       for n, (group, i, e) in enumerate(launch.rows)
@@ -2728,14 +2656,6 @@ class ServeEngine:
                 got = launch.steps.setdefault(group.bucket, [0, 0.0])
                 got[0] += 1
                 got[1] += seconds / len(landed)
-            if alone:
-                # the call's wall time joins the one decode program's cost
-                # model, which stands under the widest bucket's key (its
-                # shape); an interval that holds a chunk's device time too
-                # is left out (the mean is over the calls observed)
-                perf.get_program_costs().observe(
-                    "lm_decode_paged", self._prog_key(self._decode_bucket),
-                    seconds)
             self._record_steps(pool, launch.steps, launch.due)
             now = self._clock()
             retired = 0

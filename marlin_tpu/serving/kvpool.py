@@ -137,9 +137,8 @@ from collections import OrderedDict
 import numpy as np
 
 __all__ = ["PagedKVPool", "PagedGroup", "PagePoolExhausted",
-           "MigrationCorruptError", "auto_num_pages", "paged_program_key",
-           "warmup_paged", "capture_paged_costs", "decode_inputs",
-           "decode_pages"]
+           "MigrationCorruptError", "auto_num_pages", "warmup_paged",
+           "decode_inputs", "decode_pages"]
 
 
 class PagePoolExhausted(RuntimeError):
@@ -1446,127 +1445,6 @@ def decode_inputs(rows, width: int, pages_per_row: int,
 # ---------------------------------------------------------------- programs
 
 
-def paged_program_key(params: dict, bucket, max_batch: int,
-                      page_len: int, compute_dtype=None,
-                      kernel: str = "gather") -> str:
-    """Roofline-accounting key for one bucket's PAGED programs: the slab
-    geometry joins the identity (the same bucket at a different page_len
-    compiles different programs), and so does the decode-attention backend
-    — gather vs the fused pallas kernel are different programs with
-    different rooflines. The gather default keeps pre-kernel key strings
-    (and their persisted bench anchors) unchanged."""
-    from .batcher import bucket_program_key
-
-    key = bucket_program_key(params, bucket, max_batch,
-                             compute_dtype) + f"/page{page_len}"
-    return key if kernel == "gather" else key + f"/k{kernel}"
-
-
-def capture_paged_costs(params: dict, heads: int, bucket, max_batch: int,
-                        pool: PagedKVPool, prefill_chunk: int,
-                        compute_dtype: str | None = None,
-                        moe: tuple | None = None,
-                        key: str | None = None,
-                        kernel: str = "gather",
-                        table_pages: int | None = None) -> None:
-    """Capture the XLA cost models of a bucket's paged programs into the
-    process ProgramCosts registry under the bucket's key: its chunked
-    prefill, and — where the bucket is as wide as the engine's ONE decode
-    program, whose table has ``table_pages`` columns (:func:`decode_pages`;
-    None: this bucket's own extent, an engine of one bucket) — that decode
-    program, whose shape is the widest bucket's and whose calls are timed
-    under its key. Trace + lower only (no backend compile; the real compile
-    already happened or is about to through the jit cache). Gated per
-    (program, key) on ATTEMPTED, not
-    succeeded, so repeated calls — the engine invokes this on every group
-    creation, with its cached ``key`` — cost two dict lookups after the
-    first, and a backend without ``cost_analysis()`` does not re-pay the
-    trace on every dispatch. Never raises (observability must not fail
-    warmup or a dispatch).
-
-    With ``kernel='pallas'`` on a Mosaic (non-interpret) lowering, the
-    pallas_call is a custom call XLA's cost analysis scores at zero — the
-    decode capture supplements the analysis with the kernel's analytic
-    cost (:func:`~marlin_tpu.ops.paged_attention.paged_attention_cost`) so
-    ``marlin_program_roofline_frac`` covers the kernel too; interpret-mode
-    lowerings are plain XLA ops and need no supplement."""
-    import jax
-    import jax.numpy as jnp
-
-    from ..obs import perf
-
-    costs = perf.get_program_costs()
-    if key is None:
-        key = paged_program_key(params, bucket, max_batch, pool.page_len,
-                                compute_dtype, kernel)
-    own_pages = decode_pages([bucket], pool.page_len)
-    table_pages = table_pages or own_pages
-    programs = ("lm_prefill_paged",) + (
-        ("lm_decode_paged",) if own_pages == table_pages else ())
-    if all(costs.tried(name, key) for name in programs):
-        return
-    if not isinstance(heads, int):
-        # a ModelSpec's programs have their own signatures; their cost
-        # models are not captured (the attempt is marked, as after a failed
-        # trace), so the roofline gauges skip them
-        for name in programs:
-            costs.capture(name, key)
-        return
-    from ..models.transformer import (_lm_decode_paged_jit,
-                                      _lm_prefill_paged_jit, _n_layers,
-                                      init_kv_pages)
-
-    def st(shape, dtype=jnp.int32):
-        return jax.ShapeDtypeStruct(shape, dtype)
-
-    sds = lambda tree: jax.tree.map(  # noqa: E731
-        lambda x: jax.ShapeDtypeStruct(jnp.shape(x), x.dtype), tree)
-    g = PagedGroup(bucket, max_batch, pool.page_len, prefill_chunk)
-    try:
-        pages = sds(jax.eval_shape(
-            lambda pp: init_kv_pages(pp, pool.num_pages, pool.page_len,
-                                     heads, compute_dtype), params))
-        pre = _lm_prefill_paged_jit.trace(
-            sds(params), pages, st((g.table_width,)), st((g.chunk,)),
-            st(()), st(()), st((), jnp.uint32), st((), jnp.float32),
-            st((), jnp.float32), st(()), heads=heads,
-            page_len=pool.page_len, compute_dtype=compute_dtype,
-            moe=moe).lower()
-        costs.capture("lm_prefill_paged", key, lowered=pre)
-        if "lm_decode_paged" not in programs:
-            return
-        dec = _lm_decode_paged_jit.trace(
-            sds(params), pages, st((max_batch, table_pages)),
-            st((max_batch,)), st((max_batch,)), st((max_batch,)),
-            st((max_batch,), jnp.uint32), st((max_batch,), jnp.float32),
-            st((max_batch,), jnp.float32), st((max_batch,)), heads=heads,
-            page_len=pool.page_len, compute_dtype=compute_dtype,
-            moe=moe, kernel=kernel, prev_tokens=st((max_batch,)),
-            prev_index=st((max_batch,))).lower()
-        dec_cost = None
-        if kernel == "pallas":
-            from ..ops.pallas_kernels import _interpret
-            from ..ops.paged_attention import paged_attention_cost
-
-            if not _interpret():
-                d = params["emb"].shape[1]
-                dh = d // heads
-                kvh = params["l0"]["wk"].shape[1] // dh
-                slab = pages["l0"][0]
-                kc = paged_attention_cost(
-                    max_batch, table_pages, pool.page_len, kvh,
-                    heads // kvh, dh, jnp.dtype(slab.dtype).itemsize)
-                dec_cost = dict(dec.cost_analysis() or {})
-                n = _n_layers(params)
-                for field in ("flops", "bytes accessed"):
-                    dec_cost[field] = (float(dec_cost.get(field, 0.0))
-                                       + n * kc[field])
-        costs.capture("lm_decode_paged", key, lowered=dec, cost=dec_cost)
-    except Exception:
-        for name in programs:  # even a failed trace marks the attempt
-            costs.capture(name, key)
-
-
 def warmup_paged(params: dict, heads: int, buckets, max_batch: int,
                  pool: PagedKVPool, prefill_chunk: int,
                  compute_dtype: str | None = None,
@@ -1610,10 +1488,7 @@ def warmup_paged(params: dict, heads: int, buckets, max_batch: int,
              for g in groups],
             [(params, pool.pages, *dummy, heads, pool.page_len,
               resolve_decode_kernel(kernel), feed, unfed)])
-    for bucket, g in zip(buckets, groups):
-        capture_paged_costs(params, heads, bucket, max_batch, pool,
-                            prefill_chunk, compute_dtype, moe,
-                            kernel=kernel, table_pages=table_pages)
+    for g in groups:
         pool.pages, first = lm_prefill_paged(
             params, pool.pages, g.prefill_tables(0),
             np.zeros(g.chunk, np.int32), 0, 1, heads=heads,

@@ -73,8 +73,8 @@ dispatch, so ``ttft_s`` is genuinely earlier than ``total_s`` — the
 headline latency row-level scheduling buys, and what paged chunked prefill
 bounds under long-prompt load (docs/serving.md).
 
-:meth:`ServeMetrics.snapshot` aggregates everything for tests and the bench
-(`bench_all.py serve`) without re-reading the log file. Its percentiles run
+:meth:`ServeMetrics.snapshot` aggregates everything for tests and the
+benchmark's driver without re-reading the log file. Its percentiles run
 over *uniform reservoir samples* (:class:`Reservoir`, Algorithm R with an
 injectable RNG) — the previous first-``keep_latencies``-then-drop scheme
 silently stopped sampling after warmup, biasing every long-run percentile
@@ -87,7 +87,6 @@ import random
 import threading
 
 from ..obs.metrics import get_registry, percentile  # noqa: F401  (re-export)
-from ..obs.perf import get_program_costs
 from ..utils.tracing import get_default_event_log
 
 __all__ = ["ServeMetrics", "Reservoir", "percentile"]
@@ -290,23 +289,17 @@ class ServeMetrics:
 
     def record_prefill(self, bucket, seconds: float,
                        rid: int | None = None,
-                       program_key: str | None = None,
-                       program: str = "lm_prefill_paged",
                        chunk=None, final: bool = True) -> None:
         """One prefill dispatch. The row's FIRST token is emitted by the
         COMPLETING dispatch (real TTFT), so that one counts toward
         ``new_tokens`` — without it, steps=1 traffic would report zero
         tokens; paged chunked prefill additionally records one
         zero-new-token event per earlier chunk (``chunk`` = [start,
-        tokens], ``final=False``). ``program_key`` joins the wall time onto
-        the bucket's captured XLA cost model for ``program`` (obs/perf.py)
-        — the roofline side of the same record."""
+        tokens], ``final=False``)."""
         emitted = 1 if final else 0
         with self._lock:
             self.new_tokens += emitted
             self.busy_s += seconds
-        if program_key is not None:
-            get_program_costs().observe(program, program_key, seconds)
         self._m_dispatch.labels(kind="prefill").inc()
         if emitted:
             self._m_tokens.inc()
@@ -321,16 +314,11 @@ class ServeMetrics:
 
     def record_step(self, bucket, rows: int, max_batch: int,
                     seconds: float,
-                    program_key: str | None = None,
-                    program: str = "lm_decode_paged",
                     label: str | None = None) -> None:
         """One decode step over a bucket's rows: ``rows`` live slots each
-        emitted one token (``new_tokens`` == ``rows``). ``program_key``
-        joins the step's wall time onto ``program``'s cost model, feeding
-        ``marlin_program_roofline_frac``. ``label`` marks a non-LM
-        BucketProgram batch (the serving-program name, distinct from
-        ``program`` — the ProgramCosts family): its rows are program rows,
-        not generated tokens, so they count into
+        emitted one token (``new_tokens`` == ``rows``). ``label`` marks a
+        non-LM BucketProgram batch (the serving-program name): its rows are
+        program rows, not generated tokens, so they count into
         ``marlin_serve_program_rows_total{program}`` instead of the token
         counters and never touch LM's tok/s arithmetic."""
         with self._lock:
@@ -343,8 +331,6 @@ class ServeMetrics:
             else:
                 self.program_steps += 1
                 self.program_rows += rows
-        if program_key is not None:
-            get_program_costs().observe(program, program_key, seconds)
         self._m_dispatch.labels(kind="step").inc()
         self._m_busy.inc(seconds)
         self._m_occupancy.set(rows / max_batch)

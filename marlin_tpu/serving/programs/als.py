@@ -25,7 +25,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...config import get_config
-from ...obs import perf
 from . import register_program
 from .base import BucketProgram
 
@@ -65,7 +64,6 @@ class ALSScoreProgram(BucketProgram):
     """user → top-k item recommendations against resident ALS factors."""
 
     name = "als"
-    cost_program = "als_score"
     resource_unit = "one padded score row: num_items x 4 bytes"
 
     def __init__(self, model):
@@ -130,11 +128,6 @@ class ALSScoreProgram(BucketProgram):
     def admission_cost(self, request, bucket):
         return self.num_items * 4
 
-    def program_key(self, bucket, width=None):
-        return perf.program_key(
-            prog=self.name, users=self.num_users, items=self.num_items,
-            rank=self.rank, k=bucket[0], width=width or self.width)
-
     # ------------------------------------------------------------- mechanism
     def warmup(self) -> int:
         n = 0
@@ -143,8 +136,6 @@ class ALSScoreProgram(BucketProgram):
             uf, pf = self._uf, self._pf
         for (k,) in self.buckets():
             for w in self.widths:
-                self._capture_cost(self.program_key((k,), w), _als_topk,
-                                   uf, pf, users[w], k=k)
                 _als_topk(uf, pf, users[w], k=k)
                 n += 1
         return n

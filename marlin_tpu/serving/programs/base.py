@@ -5,10 +5,9 @@ batch forming, the single worker thread, supervisor retry, freeze/adopt
 migration, the ``serve`` event stream) was built for paged LM decode but is
 not LM-specific: what the spine actually needs from a workload is a handful
 of *policy* answers — which static bucket does this request round up to,
-what does it cost the admission budget, what's the compiled-program key for
-ProgramCosts — plus one *mechanism*: execute a padded batch of rows. A
-:class:`BucketProgram` is exactly that contract. The paged-LM path is the
-first implementation (:mod:`.lm`, unchanged behavior); ALS scoring,
+what does it cost the admission budget — plus one *mechanism*: execute a
+padded batch of rows. A :class:`BucketProgram` is exactly that contract.
+The paged-LM path is the first implementation (:mod:`.lm`); ALS scoring,
 incremental PageRank queries, and batched classification (:mod:`.als`,
 :mod:`.pagerank`, :mod:`.classify`) ride the same spine as additional
 request types keyed by ``Request.program``.
@@ -36,7 +35,6 @@ import threading
 from typing import Any, Sequence
 
 from ...config import get_config
-from ...obs import perf
 
 __all__ = ["BucketProgram", "ProgramRowSet"]
 
@@ -84,11 +82,10 @@ class BucketProgram:
         freeze ──► (state blob | fallback requeue) ──► adopt on the target
 
     Subclasses implement the policy surface (:meth:`pick_bucket`,
-    :meth:`admission_cost`, :meth:`program_key`, :meth:`warmup`,
-    :meth:`step`) and may override :meth:`validate`, :meth:`freeze`, and
-    :meth:`adopt`. ``name`` keys the registry and ``Request.program``;
-    ``cost_program`` names the ProgramCosts family the step timings land
-    in; ``resource_unit`` documents what ``admission_cost`` bytes mean.
+    :meth:`admission_cost`, :meth:`warmup`, :meth:`step`) and may override
+    :meth:`validate`, :meth:`freeze`, and :meth:`adopt`. ``name`` keys the
+    registry and ``Request.program``; ``resource_unit`` documents what
+    ``admission_cost`` bytes mean.
 
     Batch widths are the static shape axis shared by all programs: the
     ``serve_program_batches`` config knob lists the padded widths, a step
@@ -97,7 +94,6 @@ class BucketProgram:
     the ``compile_count`` fixture in tests."""
 
     name: str = ""
-    cost_program: str = ""
     resource_unit: str = "bytes resident per in-flight request"
 
     def __init__(self):
@@ -137,10 +133,6 @@ class BucketProgram:
         charged against the engine's single AdmissionQueue HBM budget."""
         raise NotImplementedError
 
-    def program_key(self, bucket, width: int | None = None) -> str:
-        """ProgramCosts key for one compiled (bucket, width) variant."""
-        raise NotImplementedError
-
     def step_width(self, live: int) -> int:
         """Smallest configured padded width covering ``live`` rows."""
         for w in self.widths:
@@ -150,8 +142,8 @@ class BucketProgram:
 
     # ------------------------------------------------------------- mechanism
     def warmup(self) -> int:
-        """Compile every (bucket, width) variant ahead of traffic and land
-        its cost record in ProgramCosts; returns the variant count."""
+        """Compile every (bucket, width) variant ahead of traffic; returns
+        the variant count."""
         raise NotImplementedError
 
     def step(self, bucket, requests) -> list:
@@ -201,15 +193,3 @@ class BucketProgram:
                          owner=f"program:{self.name}")
         except Exception:
             pass
-
-    def _capture_cost(self, key: str, fn, *args, **static) -> None:
-        """Land one compile-cost record for ``fn(*args, **static)`` in
-        ProgramCosts unless already tried — warmup bookkeeping shared by
-        every program."""
-        costs = perf.get_program_costs()
-        if not costs.tried(self.cost_program, key):
-            try:
-                costs.capture(self.cost_program, key,
-                              lowered=fn.lower(*args, **static))
-            except Exception:  # pragma: no cover - cost capture is advisory
-                pass

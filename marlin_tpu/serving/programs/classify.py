@@ -19,7 +19,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...ml.neural_network import mlp_forward
-from ...obs import perf
 from . import register_program
 from .base import BucketProgram
 
@@ -66,7 +65,6 @@ class ClassifyProgram(BucketProgram):
     """feature vector → class probabilities over a resident model."""
 
     name = "classify"
-    cost_program = "classify_fwd"
     resource_unit = "one padded feature row: feature_dim x 4 bytes"
 
     def __init__(self, model, activation: str = "sigmoid"):
@@ -114,11 +112,6 @@ class ClassifyProgram(BucketProgram):
     def admission_cost(self, request, bucket):
         return self.feature_dim * 4
 
-    def program_key(self, bucket, width=None):
-        return perf.program_key(
-            prog=self.name, kind=self._kind, dim=self.feature_dim,
-            out=self.num_outputs, width=width or self.width)
-
     # ------------------------------------------------------------- mechanism
     def _fwd(self, params, x):
         if self._kind == "logreg":
@@ -131,12 +124,6 @@ class ClassifyProgram(BucketProgram):
             params = self._params
         for w in self.widths:
             x = jnp.zeros((w, self.feature_dim), jnp.float32)
-            fn = _logreg_proba if self._kind == "logreg" else _mlp_proba
-            if self._kind == "logreg":
-                self._capture_cost(self.program_key((), w), fn, params, x)
-            else:
-                self._capture_cost(self.program_key((), w), fn, params, x,
-                                   activation=self._activation)
             self._fwd(params, x)
             n += 1
         return n

@@ -2,9 +2,9 @@
 
 The *policy* of LM traffic — bucket rounding
 (:func:`~marlin_tpu.serving.batcher.pick_bucket`), page-unit admission
-pricing (:func:`~marlin_tpu.models.planner.request_pages` × page bytes),
-the pool-capacity refusal, and the ProgramCosts keys — answers through the
-same :class:`~.base.BucketProgram` surface every other program uses. The
+pricing (:func:`~marlin_tpu.models.planner.request_pages` × page bytes)
+and the pool-capacity refusal — answers through the same
+:class:`~.base.BucketProgram` surface every other program uses. The
 *mechanism* (chunked prefill, the decode step, KV page bookkeeping) is the
 engine's paged loop: that one code path is what keeps greedy output
 bit-identical to ``lm_generate``. :meth:`PagedLMProgram.step` is therefore
@@ -28,7 +28,6 @@ class PagedLMProgram(BucketProgram):
     """token prompt → generated tokens via the engine's paged loop."""
 
     name = "lm"
-    cost_program = "lm_decode_paged"
     resource_unit = "actual KV pages x page bytes"
 
     def __init__(self, engine):
@@ -78,9 +77,6 @@ class PagedLMProgram(BucketProgram):
         # row holds whatever its length
         return (pages * eng._page_bytes + ring * eng._window_page_bytes
                 + eng._state_slot_bytes)
-
-    def program_key(self, bucket, width=None):
-        return self._eng._prog_key(bucket)
 
     # ------------------------------------------------------------- mechanism
     def warmup(self) -> int:

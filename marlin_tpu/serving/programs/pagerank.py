@@ -27,7 +27,6 @@ import numpy as np
 from ...config import get_config
 from ...ml.pagerank import (TransitionOperator, _pagerank_step,
                             build_transition_operator)
-from ...obs import perf
 from . import register_program
 from .base import BucketProgram
 
@@ -60,7 +59,6 @@ class PageRankQueryProgram(BucketProgram):
     """node → top-k out-neighbors by live PageRank over a resident graph."""
 
     name = "pagerank"
-    cost_program = "pagerank_query"
     resource_unit = "one padded edge-mask row: num_edges x 4 bytes"
 
     def __init__(self, edges, n: int | None = None, damping: float = 0.85):
@@ -137,11 +135,6 @@ class PageRankQueryProgram(BucketProgram):
     def admission_cost(self, request, bucket):
         return self.num_edges * 4
 
-    def program_key(self, bucket, width=None):
-        return perf.program_key(
-            prog=self.name, n=self.n, edges=self.num_edges, k=bucket[0],
-            width=width or self.width)
-
     # ------------------------------------------------------------- mechanism
     def warmup(self) -> int:
         n = 0
@@ -151,9 +144,6 @@ class PageRankQueryProgram(BucketProgram):
             ranks = self._ranks
         for (k,) in self.buckets():
             for w in self.widths:
-                self._capture_cost(self.program_key((k,), w),
-                                   _pr_neighbor_topk, op.src, op.dst, ranks,
-                                   nodes[w], k=k)
                 _pr_neighbor_topk(op.src, op.dst, ranks, nodes[w], k=k)
                 n += 1
         return n

@@ -193,8 +193,8 @@ class AdmissionQueue:
     Tracks every admitted-but-not-retired request: ``depth`` bounds how many
     may be pending or in flight at once, ``budget_bytes`` bounds the summed
     KV-cache cost the engine would hold if everything admitted ran (cost per
-    request = its bucket row's cache bytes, :func:`..serving.batcher
-    .bucket_kv_bytes`). ``try_admit`` returns ``None`` on admission or the
+    request = its pages' bytes and its state slot: ``PagedLMProgram
+    .admission_cost``). ``try_admit`` returns ``None`` on admission or the
     rejection reason string; ``release`` returns the request's capacity when
     the engine retires it. ``close(reason)`` flips the gate shut (drain /
     shutdown) — everything after is rejected with that reason.

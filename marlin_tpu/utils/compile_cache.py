@@ -1,7 +1,7 @@
 """JAX's persistent compilation cache, placed from outside.
 
-Called first thing by the chip entry points (``chip_smoke.py``, ``bench.py``,
-``bench_all.py``) and by nothing at import time. ``JAX_COMPILATION_CACHE_DIR``
+Called first thing by the chip entry points (``chip_smoke.py``, ``bench.py``)
+and by nothing at import time. ``JAX_COMPILATION_CACHE_DIR``
 decides where the cache lives: when it is set JAX reads it itself and no
 directory is set in code; otherwise the cache sits at the fixed
 ``<checkout>/.jax_cache`` (the path is part of the cache key, so it never

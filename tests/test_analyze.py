@@ -83,7 +83,6 @@ def test_consistency_tree_finds_every_seeded_drift():
         "configure",
         "doc-sync:metrics:marlin_mini_depth@undocumented",
         "doc-sync:metrics:marlin_mini_ghost@ghost",
-        "doc-sync:metrics:marlin_mini_missing_total@bench-want",
         "doc-sync:memory:mystery_comp@undocumented",
         "doc-sync:memory:phantom_comp@ghost",
         "doc-sync:events:kind:mystery@unknown",

@@ -269,3 +269,56 @@ def test_version_key_survives_merge(mesh):
     data = json.load(open(mt.get_config().autotune_cache_path))
     assert data["__version__"] == autotune._DISK_VERSION
     assert data[repr(key2)] == "rmm"
+
+
+# ------------------------------------------- ranking is by the measured time
+
+
+class _FakeTime:
+    """The module's clock, advanced only by the fake engines below."""
+
+    def __init__(self):
+        self.now = 0.0
+
+    def perf_counter(self):
+        return self.now
+
+
+@pytest.mark.parametrize("fastest", ["gspmd", "ring", "broadcast"])
+def test_the_fastest_timed_strategy_wins_is_cached_and_persisted(
+        mesh, monkeypatch, fastest):
+    """Three engines with set durations and one that refuses its name: the
+    ranking is the measured seconds a multiply and nothing else (no cost
+    model stands between a timing and the choice), the refused candidate is
+    skipped, and the winner reaches both cache layers, from where a
+    restarted process dispatches it without timing anything."""
+    from marlin_tpu.parallel.matmul import UnknownStrategyError
+
+    a = mt.DenseVecMatrix.random(80, 32, 32, mesh=mesh)
+    b = mt.DenseVecMatrix.random(81, 32, 32, mesh=mesh)
+    cost = {"gspmd": 0.004, "ring": 0.003, "broadcast": 0.002}
+    cost[fastest] = 0.001
+    clock, timed = _FakeTime(), []
+
+    def multiply(self, other, strategy=None, precision=None):
+        if strategy == "rmm":
+            raise UnknownStrategyError(strategy)
+        timed.append(strategy)
+        clock.now += cost[strategy]
+        return self.data
+
+    monkeypatch.setattr(autotune, "time", clock)
+    monkeypatch.setattr(autotune, "_candidates",
+                        lambda *_: ["gspmd", "rmm", "ring", "broadcast"])
+    monkeypatch.setattr(mt.DenseVecMatrix, "multiply", multiply)
+    results = autotune.tune_multiply(a, b, reps=4)
+    # one compile call and four timed ones a candidate; seconds a multiply
+    assert timed == [s for s in ("gspmd", "ring", "broadcast")
+                     for _ in range(5)]
+    assert [s for s, _ in results] == sorted(cost, key=cost.get)
+    assert dict(results) == pytest.approx(cost)
+    key = autotune._cache_key(a, b, None)
+    assert autotune._CACHE == {key: fastest}
+    _simulate_restart()
+    del timed[:]
+    assert autotune.best_strategy(a, b) == fastest and not timed

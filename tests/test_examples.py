@@ -231,3 +231,62 @@ def test_chip_entry_points_refuse_a_cpu(script):
     assert proc.returncode != 0
     assert "needs a TPU" in proc.stderr
     assert proc.stdout.strip() == ""
+
+
+# ----------------------------------------------------- `make check`'s gates
+
+GATES = ["serve-gate", "ooc-gate", "kernel-gate", "obs-gate", "fleet-gate",
+         "program-gate", "mem-gate"]
+
+
+@pytest.mark.parametrize("gate", GATES)
+def test_a_gate_is_pytest_over_files_that_exist(gate):
+    """Each ``*-gate`` target of tools/Makefile is a pytest run of tier-1
+    suites and nothing else (no timing is diffed there: speed is read on the
+    chip by benchmarks/), every file it names is in the tree, and ``check``
+    runs it."""
+    if shutil.which("make") is None:
+        pytest.skip("no make")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+    def dry(target):
+        proc = subprocess.run(["make", "-C", "tools", "-n", target],
+                              capture_output=True, text=True, cwd=root,
+                              timeout=60)
+        assert proc.returncode == 0, proc.stderr
+        return [ln for ln in proc.stdout.replace("\\\n", " ").splitlines()
+                if ln.strip() and not ln.startswith("make")]
+
+    commands = dry(gate)
+    assert len(commands) == 1, commands
+    words = commands[0].split()
+    assert words[:3] == ["cd", "..", "&&"] and "JAX_PLATFORMS=cpu" in words
+    assert words[words.index("-m"):][:2] == ["-m", "pytest"]
+    suites = [w for w in words if w.startswith("tests/")]
+    assert len(suites) >= 2 and len(set(suites)) == len(suites)
+    for path in suites:
+        assert os.path.isfile(os.path.join(root, path)), path
+    assert "'not slow'" in commands[0]
+    assert commands[0] in dry("check")
+
+
+def test_make_check_is_the_analyzer_the_gates_and_the_report_smoke():
+    """The root ``make check`` builds the native libraries and hands over to
+    tools/Makefile's ``check``: the analyzer's selftest and gate, the seven
+    pytest gates, the obs-report smoke over the checked-in log, and no
+    command that diffs a timing."""
+    if shutil.which("make") is None:
+        pytest.skip("no make")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run(["make", "-n", "check"], capture_output=True,
+                          text=True, cwd=root, timeout=60)
+    assert proc.returncode == 0, proc.stderr
+    out = proc.stdout.replace("\\\n", " ")
+    lines = [ln for ln in out.splitlines() if ln.startswith("cd .. && ")]
+    assert sum("-m pytest" in ln for ln in lines) == len(GATES)
+    assert [ln for ln in lines if "-m pytest" not in ln] == [
+        "cd .. && python -m tools.analyze --selftest",
+        "cd .. && python -m tools.analyze",
+        "cd .. && python -m marlin_tpu.obs.report "
+        "tools/fixtures/obs_events.jsonl"]
+    assert "marlin_tpu/native" in out and "bench" not in out.lower()

@@ -116,7 +116,7 @@ def test_cholesky_schedules_agree(mesh):
 
 
 def test_auto_schedule_is_op_aware():
-    # r5 on-chip shoot-out (BENCH_ALL, 8192²): shrinking wins for LU,
+    # r5 on-chip shoot-out (PERF.md's r5 table, 8192²): shrinking wins for LU,
     # masked wins for Cholesky — "auto" must resolve per op
     from marlin_tpu.linalg.factorizations import _resolve_schedule
 

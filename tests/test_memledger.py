@@ -20,9 +20,6 @@ The acceptance bars, bottom up:
   live baseline, resolves on a real live-byte drop, and alerts after
   ``windows`` samples; OOM forensics dump one parseable JSONL artifact,
   rate-limited and pruned to the newest 16.
-- **Calibration**: admission_ratio prefers a live ProgramCosts peak,
-  falls back to the AOT table, clamps to [1, 32], caches per key, and a
-  toy CPU model calibrates at exactly 1.0 — pre-ledger admission.
 """
 
 import json
@@ -38,22 +35,16 @@ from marlin_tpu.obs.memledger import (
     KNOWN_COMPONENTS,
     LeakDetector,
     MemoryLedger,
-    admission_ratio,
     dump_oom_forensics,
     emit_snapshot,
     install_memledger_gauges,
     is_oom_error,
     memory_payload,
-    ratio_table,
     reconcile,
 )
 from marlin_tpu.obs.metrics import MetricsRegistry
 from marlin_tpu.obs.report import _memory_attribution_section, load_events
 from marlin_tpu.utils.tracing import EventLog, set_default_event_log
-
-HEADS = 2
-BUCKETS = ((8, 8), (16, 8))
-PAGE_LEN = 4
 
 # install_memledger_gauges is idempotent per id(registry); pin every test
 # registry for the module's lifetime so CPython can never hand a later
@@ -84,14 +75,6 @@ def default_log(tmp_path):
     yield log
     set_default_event_log(prev)
     log.close()
-
-
-@pytest.fixture(scope="module")
-def params():
-    from marlin_tpu.models import TransformerLM
-
-    return TransformerLM(vocab=32, d_model=16, heads=HEADS, layers=2,
-                         seed=9).init_params()
 
 
 # --------------------------------------------------------- exact accounting
@@ -367,7 +350,7 @@ def test_memory_payload_ok_shape(monkeypatch):
     assert body["components"] == {"program": 800}
     assert body["entries"][0]["owner"] == "eng"
     assert body["unattributed_bytes"] == 1200
-    assert isinstance(body["planner_ratios"], list)
+    assert "planner_ratios" not in body  # admission charges one number
     assert body["leak_alerts"] == []
 
 
@@ -485,94 +468,6 @@ def test_oom_dump_prunes_to_newest_16(tmp_path):
     mine = [n for n in os.listdir(tmp_path)
             if n.startswith("marlin_oom_") and n.endswith(".jsonl")]
     assert len(mine) <= 16
-
-
-# ------------------------------------------------ measured-peak calibration
-
-
-def test_admission_ratio_planner_zero_is_uncalibrated():
-    assert admission_ratio(0, ("lm_decode_paged",), "k0") == 1.0
-    assert admission_ratio(-5, ("lm_decode_paged",), "k0b") == 1.0
-
-
-def test_admission_ratio_prefers_live_measurement(monkeypatch):
-    monkeypatch.setattr(memledger, "measured_peak_bytes",
-                        lambda programs, key: 5000)
-    assert admission_ratio(1000, ("p",), "k1") == 5.0
-
-
-def test_admission_ratio_clamps_to_floor_and_cap(monkeypatch):
-    # calibration only ever tightens admission (floor 1.0), and a corrupt
-    # table must not brick it entirely (cap 32.0)
-    monkeypatch.setattr(memledger, "measured_peak_bytes",
-                        lambda programs, key: 500)
-    assert admission_ratio(1000, ("p",), "k2") == 1.0
-    monkeypatch.setattr(memledger, "measured_peak_bytes",
-                        lambda programs, key: 100_000)
-    assert admission_ratio(1000, ("p",), "k3") == 32.0
-
-
-def test_admission_ratio_caches_per_key(monkeypatch):
-    calls = []
-
-    def fake_peak(programs, key):
-        calls.append(key)
-        return 3000
-
-    monkeypatch.setattr(memledger, "measured_peak_bytes", fake_peak)
-    assert admission_ratio(1000, ("p",), "k4") == 3.0
-    assert admission_ratio(1000, ("p",), "k4") == 3.0
-    assert calls == ["k4"]  # second hit came from the cache
-    memledger.reset_ledger()  # the test hook clears the cache too
-    assert admission_ratio(1000, ("p",), "k4") == 3.0
-    assert calls == ["k4", "k4"]
-
-
-def test_admission_ratio_falls_back_to_aot_table(monkeypatch):
-    from marlin_tpu.models import planner
-
-    monkeypatch.setattr(memledger, "measured_peak_bytes",
-                        lambda programs, key: None)
-    monkeypatch.setattr(planner, "bucket_calibration",
-                        lambda key: 4500)
-    assert admission_ratio(1000, ("p",), "k5") == 4.5
-    monkeypatch.setattr(planner, "bucket_calibration", lambda key: None)
-    assert admission_ratio(1000, ("p",), "k6") == 1.0
-
-
-def test_ratio_table_reads_the_aot_report(monkeypatch):
-    from marlin_tpu.models import planner
-
-    rows = ratio_table()
-    # the committed AOT_MEMORY.json carries the calibrated serve buckets
-    assert rows, "AOT_MEMORY.json serve_buckets missing or empty"
-    for r in rows:
-        assert set(r) == {"bucket", "planner_bytes",
-                          "measured_peak_bytes", "planner_ratio",
-                          "calibration"}
-        assert r["calibration"] >= 1.0
-    monkeypatch.setattr(planner, "_AOT_MEMORY", "/nonexistent/x.json")
-    assert ratio_table() == []
-
-
-def test_engine_calibration_neutral_on_toy_cpu_model(params, monkeypatch):
-    # a toy CPU model's program key is never in the AOT table and CPU
-    # ProgramCosts carry no memory analysis -> ratio exactly 1.0, the
-    # admission charge bit-identical to pre-ledger behavior; a measured
-    # ratio scales the charge
-    from marlin_tpu.serving import Request, ServeEngine
-
-    with ServeEngine(params, HEADS, buckets=BUCKETS, max_batch=4,
-                     max_wait_ms=0.0, queue_depth=64, page_len=PAGE_LEN,
-                     num_pages=256) as eng:
-        req = Request(prompt=[1, 2, 3], steps=2)
-        bucket = BUCKETS[0]
-        assert eng._calibrate_cost(req, bucket, 10) == 10
-        eng._calib_ratios.clear()
-        monkeypatch.setattr(memledger, "admission_ratio",
-                            lambda planner, programs, key: 4.0)
-        assert eng._calibrate_cost(req, bucket, 10) == 40
-        assert eng._calibrate_cost(req, bucket, 7) == 28  # cached ratio
 
 
 # ----------------------------------------------------- snapshots and reports

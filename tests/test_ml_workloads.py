@@ -186,7 +186,7 @@ def test_pagerank_edge_operator_matches_dense(mesh):
 
 def test_pagerank_edge_operator_graph_scale(mesh):
     # 100k nodes / 1M edges never densifies (dense would be 40 GB); the
-    # full-scale criterion (10^7 nodes / 10^8 edges) runs in bench_all
+    # full-scale criterion (10^7 nodes / 10^8 edges) has no cell yet
     rng = np.random.default_rng(0)
     n, e = 100_000, 1_000_000
     edges = np.stack([rng.integers(0, n, e), rng.integers(0, n, e)], axis=1)

@@ -25,7 +25,6 @@ from marlin_tpu.models.transformer import (init_kv_pages, lm_decode_paged,
                                            lm_generate, lm_prefill_paged,
                                            resolve_decode_kernel)
 from marlin_tpu.ops.paged_attention import (PAGE_SUBLANE, align_page_len,
-                                            paged_attention_cost,
                                             paged_decode_attention)
 from marlin_tpu.serving import STATUS_OK, Request, ServeEngine
 
@@ -185,16 +184,6 @@ def test_page_len_validation_and_alignment():
     assert align_page_len(9) == 16
     with pytest.raises(ValueError):
         align_page_len(0)
-
-
-def test_cost_model_shape():
-    """The analytic cost dict feeds ProgramCosts.capture on the Mosaic
-    path: cost_analysis()-shaped keys, flops/bytes scale with the table."""
-    c1 = paged_attention_cost(4, 3, 8, 2, 2, 16)
-    c2 = paged_attention_cost(4, 6, 8, 2, 2, 16)
-    assert set(c1) == {"flops", "bytes accessed"}
-    assert c2["flops"] == 2 * c1["flops"]
-    assert c1["flops"] > 0 and c1["bytes accessed"] > 0
 
 
 # ------------------------------------------------ pages no row holds

@@ -1,13 +1,13 @@
 """Performance-introspection suite (obs/perf.py + its integrations).
 
-The load-bearing tests: roofline math against fake ``cost_analysis()``
-dicts (zero-time and zero-flop are *results*, not crashes), the flight
-recorder dumping a parseable black box when the existing
-``serve.decode_step`` fault point fires, the single-flight guarantee of
-``/debug/profile`` (second concurrent request gets 409 — two overlapping
-jax.profiler traces corrupt each other), the upgraded ``/healthz``
-readiness states, and the bench regression gate firing on the checked-in
-seeded fixture while passing the clean pair.
+The load-bearing tests: the flight recorder dumping a parseable black box
+when the existing ``serve.decode_step`` fault point fires, the single-flight
+guarantee of ``/debug/profile`` (second concurrent request gets 409 — two
+overlapping jax.profiler traces corrupt each other), the upgraded
+``/healthz`` readiness states, and what is left where the wall-clock
+roofline registry stood: no ``marlin_program`` family on a scrape, one
+flight dump however often an engine is closed, and an old log's
+``kind="program"`` records parsed without a table.
 """
 
 import json
@@ -16,7 +16,6 @@ import threading
 import urllib.error
 import urllib.request
 
-import numpy as np
 import pytest
 
 from marlin_tpu.config import config_context
@@ -24,9 +23,7 @@ from marlin_tpu.obs import perf
 from marlin_tpu.obs.exposition import (MetricsServer, health_payload,
                                        register_health_provider,
                                        unregister_health_provider)
-from marlin_tpu.obs.metrics import MetricsRegistry
-from marlin_tpu.obs.perf import (FlightRecorder, ProgramCosts, program_key,
-                                 roofline)
+from marlin_tpu.obs.perf import FlightRecorder
 from marlin_tpu.obs.report import analyze, load_events
 from marlin_tpu.utils import faults
 from marlin_tpu.utils.faults import RaiseFault
@@ -50,211 +47,6 @@ def lm_params():
 
     return TransformerLM(vocab=32, d_model=16, heads=HEADS, layers=2,
                          seed=9).init_params()
-
-
-# ------------------------------------------------------------- roofline math
-
-
-def test_roofline_compute_bound():
-    # intensity 100 F/B, bw bound 1e9*100 = 1e11 > peak 1e10 -> compute bound
-    r = roofline(flops=1e8, bytes_accessed=1e6, seconds=0.1,
-                 peak_flops=1e10, peak_bw=1e9)
-    assert r["achieved_flops_per_s"] == pytest.approx(1e9)
-    assert r["attainable_flops_per_s"] == pytest.approx(1e10)
-    assert r["roofline_frac"] == pytest.approx(0.1)
-
-
-def test_roofline_bandwidth_bound():
-    # intensity 0.1 F/B: attainable = bw * intensity = 1e8 << peak flops
-    r = roofline(flops=1e6, bytes_accessed=1e7, seconds=0.01,
-                 peak_flops=1e12, peak_bw=1e9)
-    assert r["attainable_flops_per_s"] == pytest.approx(1e8)
-    assert r["roofline_frac"] == pytest.approx(1.0)  # achieved == attainable
-
-
-def test_roofline_zero_time_and_zero_flops():
-    # zero/None time: no measurement, never a ZeroDivisionError
-    for sec in (0, 0.0, None):
-        r = roofline(1e9, 1e6, sec, 1e12, 1e9)
-        assert r["achieved_flops_per_s"] is None
-        assert r["roofline_frac"] is None
-    # zero-FLOP program (pure transfer): bandwidth roofline
-    r = roofline(0, 1e6, 0.001, 1e12, 1e9)
-    assert r["achieved_flops_per_s"] is None
-    assert r["achieved_bytes_per_s"] == pytest.approx(1e9)
-    assert r["roofline_frac"] == pytest.approx(1.0)
-    # zero flops AND zero bytes: nothing to say
-    r = roofline(0, 0, 0.001, 1e12, 1e9)
-    assert r["roofline_frac"] is None
-    # flops but no peaks known: fraction stays unreported
-    r = roofline(1e9, 1e6, 0.1, None, None)
-    assert r["achieved_flops_per_s"] == pytest.approx(1e10)
-    assert r["roofline_frac"] is None
-
-
-def test_roofline_not_clamped():
-    # achieved > attainable surfaces as frac > 1 (a wrong peak table is
-    # worth seeing, not hiding)
-    r = roofline(1e12, 1e6, 0.1, 1e12, None)
-    assert r["roofline_frac"] == pytest.approx(10.0)
-
-
-def test_peak_rates_config_override():
-    with config_context(obs_peak_flops=5e12, obs_peak_bw=7e11):
-        assert perf.peak_rates() == (5e12, 7e11)
-    pf, bw = perf.peak_rates()  # CPU detection: nominal but present
-    assert pf and pf > 0 and bw and bw > 0
-
-
-# ------------------------------------------------------------- program costs
-
-
-def test_program_costs_fake_cost_dict(default_log):
-    costs = ProgramCosts()
-    key = program_key(bucket="8x4", rows=4, dtype="float32")
-    assert key == "bucket=8x4 rows=4 dtype=float32"
-    snap = costs.capture("prog", key,
-                         cost={"flops": 1000.0, "bytes accessed": 500.0},
-                         log=default_log)
-    assert snap["flops"] == 1000.0 and snap["bytes"] == 500.0
-    assert costs.has("prog", key)
-    costs.observe("prog", key, seconds=0.002, calls=4)
-    with config_context(obs_peak_flops=1e7, obs_peak_bw=1e9):
-        (row,) = costs.rows()
-    assert row["calls"] == 4 and row["seconds_per_call"] == 0.0005
-    assert row["achieved_flops_per_s"] == pytest.approx(2e6)
-    assert row["roofline_frac"] == pytest.approx(0.2)
-    # cost record landed exactly once (second capture is a no-op event-wise)
-    costs.capture("prog", key, cost={"flops": 1000.0})
-    recs = [r for r in default_log.read() if r["kind"] == "program"]
-    assert [r["ev"] for r in recs] == ["cost"]
-
-
-def test_program_costs_zero_flop_and_unmeasured():
-    costs = ProgramCosts()
-    costs.capture("xfer", "k", cost={"flops": 0.0, "bytes accessed": 1e6})
-    costs.observe("xfer", "k", seconds=0.001)
-    costs.capture("never_timed", "k", cost={"flops": 5.0})
-    with config_context(obs_peak_flops=1e12, obs_peak_bw=1e9):
-        rows = {r["program"]: r for r in costs.rows()}
-    assert rows["xfer"]["roofline_frac"] == pytest.approx(1.0)  # bw roofline
-    assert rows["never_timed"]["roofline_frac"] is None
-    assert rows["never_timed"]["calls"] == 0
-
-
-def test_program_costs_capture_real_lowered_and_render():
-    import jax
-    import jax.numpy as jnp
-
-    f = jax.jit(lambda a, b: a @ b)
-    a, b = jnp.ones((64, 32)), jnp.ones((32, 16))
-    reg = MetricsRegistry()
-    costs = ProgramCosts()
-    key = program_key(m=64, k=32, n=16)
-    snap = costs.capture("mm", key, lowered=f.trace(a, b).lower())
-    assert snap is not None and snap["flops"] == pytest.approx(2 * 64 * 32 * 16)
-    costs.observe("mm", key, 0.001)
-    reg.add_collector(lambda: costs.collect(reg))
-    text = reg.render()
-    assert 'marlin_program_flops{program="mm",key="m=64 k=32 n=16"}' in text
-    assert "marlin_program_achieved_flops_per_s" in text
-    assert "marlin_program_roofline_frac" in text
-
-
-def test_program_costs_capture_never_raises():
-    class Boom:
-        def cost_analysis(self):
-            raise RuntimeError("no analysis on this backend")
-
-    costs = ProgramCosts()
-    assert costs.capture("p", "k", lowered=Boom()) is None
-    assert not costs.has("p", "k")
-    # ...but the ATTEMPT is recorded: hot-path capture sites gate on
-    # tried(), so a backend without cost_analysis() pays the trace exactly
-    # once, never once per dispatch
-    assert costs.tried("p", "k")
-    assert not costs.tried("p", "other")
-
-
-def test_capture_sites_do_not_retry_failed_traces(monkeypatch):
-    """A bucket whose trace fails must not be re-traced on every dispatch:
-    capture_paged_costs marks the attempt for BOTH paged programs even when
-    the lowering path raises (simulated via params the page-slab derivation
-    chokes on), and the next call stops at the gate."""
-    import types
-
-    from marlin_tpu.serving import kvpool
-
-    built = []
-
-    class CountedGroup(kvpool.PagedGroup):
-        def __init__(self, *a, **kw):
-            built.append(a)
-            super().__init__(*a, **kw)
-
-    monkeypatch.setattr(kvpool, "PagedGroup", CountedGroup)
-    bad_params = {"emb": np.zeros((4, 4), np.float32)}  # no l0: trace dies
-    pool = types.SimpleNamespace(page_len=4, num_pages=8)
-    kvpool.capture_paged_costs(bad_params, HEADS, (8, 4), 4, pool, 8)
-    assert len(built) == 1  # it got as far as the trace
-    key = kvpool.paged_program_key(bad_params, (8, 4), 4, 4)
-    costs = perf.get_program_costs()
-    for prog in ("lm_prefill_paged", "lm_decode_paged"):
-        assert costs.tried(prog, key)
-        assert not costs.has(prog, key)
-    kvpool.capture_paged_costs(bad_params, HEADS, (8, 4), 4, pool, 8)
-    assert len(built) == 1  # gated on attempted: nothing traced again
-
-
-def test_program_emit_and_report_table(default_log):
-    costs = ProgramCosts()
-    key = program_key(bucket="8x4")
-    costs.capture("lm_decode_paged", key,
-                  cost={"flops": 4000.0, "bytes accessed": 1000.0},
-                  log=default_log)
-    costs.observe("lm_decode_paged", key, seconds=0.004, calls=8)
-    with config_context(obs_peak_flops=1e7, obs_peak_bw=1e9):
-        assert costs.emit(log=default_log) == 1
-    out = analyze(default_log.read())
-    assert "== program utilization ==" in out
-    assert "lm_decode_paged" in out
-    # achieved = 4000 / 0.0005 = 8 MFLOP/s = 0.01 GFLOP/s; frac = 0.8
-    assert "80.00%" in out
-
-
-# ---------------------------------------------------------- serving roofline
-
-
-def test_warmup_captures_and_steps_join(lm_params, default_log):
-    """The tentpole integration: warmup captures the bucket cost models,
-    live decode steps join their wall times, and the global registry renders
-    marlin_program_roofline_frac for the active bucket."""
-    from marlin_tpu import obs
-    from marlin_tpu.serving import Request, ServeEngine
-    from marlin_tpu.serving.kvpool import paged_program_key
-
-    with obs.MetricsServer(port=0) as srv:
-        with ServeEngine(lm_params, HEADS, buckets=((8, 4),), max_batch=4,
-                         max_wait_ms=0.0, queue_depth=32) as eng:
-            eng.warmup()
-            key = paged_program_key(lm_params, (8, 4), 4, eng._page_len)
-            assert perf.get_program_costs().has("lm_decode_paged", key)
-            assert perf.get_program_costs().has("lm_prefill_paged", key)
-            hs = [eng.submit(Request(prompt=[1, 2, 3], steps=3))
-                  for _ in range(4)]
-            eng.drain()
-            assert all(h.result(timeout=30).ok for h in hs)
-            text = urllib.request.urlopen(srv.url, timeout=10).read().decode()
-    rows = {(r["program"], r["key"]): r
-            for r in perf.get_program_costs().rows()}
-    row = rows[("lm_decode_paged", key)]
-    assert row["calls"] >= 1 and row["flops"] > 0
-    assert row["roofline_frac"] is not None  # CPU nominal peaks exist
-    assert "marlin_program_roofline_frac{" in text
-    # engine close emitted util snapshots: the analyzer's table works from
-    # the JSONL alone
-    out = analyze(default_log.read())
-    assert "== program utilization ==" in out and "lm_decode_paged" in out
 
 
 # ------------------------------------------------------------ flight recorder
@@ -500,102 +292,72 @@ def test_debug_flight_endpoint(lm_params):
         assert mine and any(r["ev"] in ("step", "prefill") for r in mine)
 
 
-# --------------------------------------------------------------- bench gate
+# ------------------------------------------- where the roofline registry stood
+
+FIXTURE_LOG = os.path.join(os.path.dirname(__file__), "..", "tools",
+                           "fixtures", "obs_events.jsonl")
 
 
-def _gate(base, new):
-    from tools.bench_compare import main
+def test_engine_closed_twice_lands_one_flight_dump_and_a_clean_healthz(
+        lm_params, tmp_path, default_log):
+    """``close()`` is the terminal flush: the ring is dumped once, whoever
+    closes again (a supervisor, a ``with`` block's exit) lands nothing
+    more, and the engine has left ``/healthz`` for good."""
+    from marlin_tpu.serving import Request, ServeEngine
 
-    fixtures = os.path.join(os.path.dirname(__file__), "..", "tools",
-                            "fixtures")
-    return main([os.path.join(fixtures, base), os.path.join(fixtures, new)])
+    with config_context(obs_profile_dir=str(tmp_path)):
+        eng = ServeEngine(lm_params, HEADS, buckets=((8, 4),), max_batch=4,
+                          max_wait_ms=0.0, queue_depth=8)
+        assert eng.submit(Request(prompt=[1, 2], steps=3)).result(
+            timeout=60).ok
+        eng.close()
+        eng.close()
+    dumps = [r for r in default_log.read() if r["kind"] == "flight"
+             and r.get("ev") == "dump" and r.get("src") == eng._name]
+    assert [d["reason"] for d in dumps] == ["close"]
+    recs, skipped = load_events(dumps[0]["path"])
+    assert skipped == 0 and {"prefill", "step"} <= {r["ev"] for r in recs}
+    # nothing the engine emitted is a record of the registry that went
+    assert not [r for r in default_log.read() if r["kind"] == "program"]
+    _, payload = health_payload()
+    assert all(e.get("name") != eng._name for e in payload["engines"])
 
 
-def test_bench_gate_clean_pair_passes(capsys):
-    assert _gate("bench_gate_base.json", "bench_gate_clean.json") == 0
-    assert "gate passed" in capsys.readouterr().out
+def test_metrics_scrape_has_no_program_family(lm_params):
+    """A served request and the default collectors leave ``/metrics`` with
+    the serving and memory families and none of the ``marlin_program`` ones:
+    no wall-clock rate is exposed as a device number."""
+    from marlin_tpu.obs.collectors import install_default_collectors
+    from marlin_tpu.obs.metrics import get_registry
+    from marlin_tpu.serving import Request, ServeEngine
+
+    install_default_collectors()
+    with ServeEngine(lm_params, HEADS, buckets=((8, 4),), max_batch=4,
+                     max_wait_ms=0.0, queue_depth=8) as eng:
+        eng.warmup()
+        assert eng.submit(Request(prompt=[1, 2], steps=3)).result(
+            timeout=60).ok
+        text = get_registry().render()
+    names = {line.split()[2] for line in text.splitlines()
+             if line.startswith("# TYPE ")}
+    assert "marlin_serve_step_seconds" in names
+    assert "marlin_mem_registered_bytes" in names
+    assert not [n for n in names if n.startswith("marlin_program")]
 
 
-def test_bench_gate_fires_on_seeded_regression(capsys):
-    assert _gate("bench_gate_base.json", "bench_gate_regressed.json") == 1
+@pytest.mark.parametrize("window", [(), ("--since", "1004", "--until",
+                                         "1009")])
+def test_report_parses_old_program_records_without_a_table(window, capsys):
+    """The checked-in fixture log holds ``kind="program"`` records of the
+    registry that is gone: they load, count as events and render no
+    section, over the whole file and over a window."""
+    from marlin_tpu.obs.report import KNOWN_KINDS, main
+
+    recs, skipped = load_events(FIXTURE_LOG)
+    assert skipped <= 1  # the fixture's one torn line
+    assert [r for r in recs if r["kind"] == "program"]
+    assert "program" in KNOWN_KINDS
+    assert main([*window, FIXTURE_LOG]) == 0
     out = capsys.readouterr().out
-    assert "GATE FAILED" in out
-    assert "2_dense_4000" in out and "REGRESSION" in out
-    assert "ttft p50 672->1400 ms" in out  # the TTFT leg fired too
-    assert "5_FAILED" in out  # new crash counts as a regression
-
-
-def test_bench_gate_frac_is_informational():
-    from tools.bench_compare import compare
-
-    base = {"serve_decode_roofline": {"config": "serve_decode_roofline",
-                                      "value": 0.30, "unit": "frac"}}
-    new = {"serve_decode_roofline": {"config": "serve_decode_roofline",
-                                     "value": 0.05, "unit": "frac"}}
-    rows, regressed = compare(base, new)
-    assert not regressed  # utilization explains regressions, never IS one
-    assert rows[0][5] == "info"
-
-
-def test_bench_gate_zero_baseline_still_fires():
-    from tools.bench_compare import compare
-
-    base = {"acc": {"config": "acc", "value": 0.0, "unit": "rel err"}}
-    worse = {"acc": {"config": "acc", "value": 0.5, "unit": "rel err"}}
-    same = {"acc": {"config": "acc", "value": 0.0, "unit": "rel err"}}
-    _, regressed = compare(base, worse)
-    assert regressed  # any rise off an exact-zero lower-better baseline
-    _, regressed = compare(base, same)
-    assert not regressed
-
-
-def test_bench_gate_threshold_override(tmp_path, capsys):
-    from tools.bench_compare import compare, load
-
-    fixtures = os.path.join(os.path.dirname(__file__), "..", "tools",
-                            "fixtures")
-    base = load(os.path.join(fixtures, "bench_gate_base.json"))
-    new = load(os.path.join(fixtures, "bench_gate_clean.json"))
-    # tighten one config to 1%: the clean pair's -2.2% wobble now trips
-    rows, regressed = compare(base, new, tolerance=0.25,
-                              thresholds={"2_dense_4000": 0.01})
-    assert regressed
-    assert [r for r in rows if r[0] == "2_dense_4000"][0][5] == "REGRESSION"
-
-
-# ----------------------------------------------------------- streamed / tune
-
-
-def test_streamed_gramian_observes_costs(default_log):
-    from marlin_tpu.parallel.streaming import streamed_gramian
-
-    chunks = [np.ones((16, 8), np.float32)] * 3
-    streamed_gramian(iter(chunks), prefetch=False)
-    rows = [r for r in perf.get_program_costs().rows()
-            if r["program"] == "streamed_gramian"
-            and "chunk=16x8" in r["key"]]
-    assert rows and rows[0]["calls"] >= 3 and rows[0]["flops"] > 0
-    utils = [r for r in default_log.read() if r["kind"] == "program"
-             and r.get("ev") == "util"
-             and r.get("program") == "streamed_gramian"]
-    assert utils
-
-
-def test_autotune_lands_candidate_timings():
-    import marlin_tpu as mt
-    from marlin_tpu.parallel.autotune import tune_multiply
-
-    mesh = mt.create_mesh()
-    a = mt.DenseVecMatrix.random(0, 64, 64, mesh=mesh)
-    b = mt.DenseVecMatrix.random(1, 64, 64, mesh=mesh)
-    # gspmd + broadcast (rmm's jax.shard_map path is broken at the seed on
-    # this jax version — tracked in tier-1's pre-existing failures)
-    results = tune_multiply(a, b, strategies=["gspmd", "broadcast"], reps=1)
-    assert results
-    rows = [r for r in perf.get_program_costs().rows()
-            if r["program"] == "multiply" and "shape=64x64x64" in r["key"]]
-    strategies = {r["key"].split()[0].split("=")[1] for r in rows}
-    assert {"gspmd", "broadcast"} <= strategies
-    assert all(r["calls"] >= 1 and r["flops"] == pytest.approx(2 * 64**3)
-               for r in rows if r["key"].split()[0].split("=")[1]
-               in ("gspmd", "broadcast"))
+    assert "program utilization" not in out and "roofline" not in out
+    assert out.startswith("== marlin_tpu.obs.report ==")

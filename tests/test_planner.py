@@ -122,3 +122,144 @@ def test_chips_topology_validation():
     plan = plan_context(1000, lm, chips=3, hbm_budget=GIB,
                         measure=lambda m: (GIB // 2, ""))
     assert plan.fits
+
+
+# ------------------------------- the admission unit: a page, a slot, as held
+#
+# Admission charges one number (no ratio stands on it): a request's pages of
+# each class times ``kv_page_bytes`` of that class, plus its state slot. The
+# cases hold that number to the bytes ``init_kv_pages`` really allocates a
+# page id / a slot, for the dense block and one tiny model of every served
+# family (tests/test_ctx_gather.py's five).
+
+PAGE = 8
+
+
+def _dense(**kw):
+    lm = TransformerLM(vocab=32, d_model=32, heads=4, layers=3, seed=2, **kw)
+    return lm.init_params(), lm.heads
+
+
+def _spec_model(cfg):
+    import jax
+
+    from marlin_tpu.models import hybrid
+
+    spec = hybrid.ModelSpec.from_config(cfg)
+    return hybrid.init_params(spec, jax.random.key(0)), spec
+
+
+def _family(name):
+    from tests import test_delta_rule as olmo
+    from tests.test_hybrid_model import tiny_cfg as laguna_cfg
+    from tests.test_latent_attention import tiny_cfg as mistral_cfg
+    from tests.test_short_conv import tiny_cfg as lfm2_cfg
+    from tests.test_state_space import tiny_cfg as falcon_cfg
+
+    if name == "dense":
+        return _dense()
+    if name == "dense-gqa":
+        return _dense(kv_heads=2)
+    return _spec_model({"window-ring+experts": laguna_cfg,
+                        "latent+experts": mistral_cfg,
+                        "ssm": falcon_cfg, "delta-rule": olmo.tiny_cfg,
+                        "short-conv": lfm2_cfg}[name]())
+
+
+def _held_bytes(pages, heads):
+    """(bytes one GLOBAL page id names over all layers, one WINDOW page id,
+    one state slot), from the arrays' own ``nbytes``: a layer's page slabs
+    come first in its tuple (two, ONE for a latent layer, none for a layer
+    that owns no page), its slot's arrays after them."""
+    full = sliding = slot = 0
+    for i in range(len(pages)):
+        arrays = pages[f"l{i}"]
+        ly = None if isinstance(heads, int) else heads.layers[i]
+        n_slabs = (2 if ly is None else 0 if not ly.owns_pages
+                   else 1 if ly.attn == "latent" else 2)
+        for a in arrays[:n_slabs]:
+            if ly is not None and ly.attn == "sliding":
+                sliding += a.nbytes // a.shape[0]
+            else:
+                full += a.nbytes // a.shape[0]
+        slot += sum(a.nbytes // a.shape[0] for a in arrays[n_slabs:])
+    return full, sliding, slot
+
+
+FAMILIES = ["dense", "dense-gqa", "window-ring+experts", "latent+experts",
+            "ssm", "delta-rule", "short-conv"]
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_admission_charges_the_pages_and_the_slot_the_pool_holds(family):
+    from marlin_tpu.models.planner import kv_page_bytes, request_pages
+    from marlin_tpu.serving import Request, ServeEngine
+
+    params, heads = _family(family)
+    spec = None if isinstance(heads, int) else heads
+    eng = ServeEngine(params, heads, buckets=((24, 8), (72, 24)),
+                      max_batch=2, page_len=PAGE, prefill_chunk=16,
+                      num_pages=40, queue_depth=8, start=False)
+    try:
+        full, sliding, slot = _held_bytes(eng._ensure_kvpool().pages, heads)
+        # the planner's unit is what a page id / a slot holds, class by class
+        assert kv_page_bytes(params, heads, PAGE, kind="full") == full > 0
+        if spec is not None:
+            assert kv_page_bytes(params, heads, PAGE,
+                                 kind="sliding") == sliding
+            assert spec.state_slot_bytes() == slot
+        assert (sliding > 0) == (family == "window-ring+experts")
+        assert (slot > 0) == (family in ("ssm", "delta-rule", "short-conv"))
+        # ... and a request is charged exactly that, times what it can pin
+        charged = []
+        for n, steps in ((5, 3), (40, 20)):
+            before = eng._queue.bytes_in_flight
+            h = eng.submit(Request(prompt=[1 + i % 30 for i in range(n)],
+                                   steps=steps))
+            assert not h.done(), h.result(timeout=0).reason
+            pages = request_pages(n, steps, PAGE)
+            ring = min(pages, eng._ring) if eng._ring else 0
+            want = pages * full + ring * sliding + slot
+            assert eng._queue.bytes_in_flight - before == want
+            charged.append(want)
+        assert charged[1] > charged[0]
+        if sliding:  # the long request pins a whole ring, no more
+            assert eng._ring < request_pages(40, 20, PAGE)
+    finally:
+        eng.close()
+    assert eng._queue.bytes_in_flight == 0
+
+
+@pytest.mark.parametrize("family,kind,values_a_position", [
+    # layers x {k, v} x kv_heads x head_dim, by hand from each tiny model
+    ("dense", "full", 3 * 2 * 4 * 8),
+    ("dense-gqa", "full", 3 * 2 * 2 * 8),
+    # F S S S F: a page id of a class names a page in that class's layers
+    ("window-ring+experts", "full", 2 * 2 * 2 * 16),
+    ("window-ring+experts", "sliding", 3 * 2 * 2 * 16),
+    # three latent layers: ONE entry of 16 + 8 columns, stored 128 wide
+    ("latent+experts", "full", 3 * 128),
+    ("latent+experts", "sliding", 0),
+])
+def test_kv_page_bytes_of_a_page_kind_is_its_slabs_row(family, kind,
+                                                       values_a_position):
+    """At another page length and a compute dtype handed in (bfloat16 over
+    float32 parameters): the unit follows the slabs' shapes, not the
+    parameters' dtype."""
+    from marlin_tpu.models.planner import kv_page_bytes
+    from marlin_tpu.models.transformer import init_kv_pages
+
+    params, heads = _family(family)
+    got = kv_page_bytes(params, heads, 16, compute_dtype="bfloat16",
+                        kind=kind)
+    assert got == values_a_position * 16 * 2
+    pages = init_kv_pages(params, 3, 16, heads, compute_dtype="bfloat16",
+                          window_pages=5)
+    full, sliding, slot = _held_bytes(pages, heads)
+    assert got == (sliding if kind == "sliding" else full) and slot == 0
+    for i in range(len(pages)):  # a class's slabs hold that class's pages
+        for a in pages[f"l{i}"]:
+            sl = not isinstance(heads, int) \
+                and heads.layers[i].attn == "sliding"
+            assert a.shape[:2] == (5 if sl else 3, 16)
+            assert a.dtype == "bfloat16"

@@ -336,6 +336,46 @@ def test_prefetch_summary_event_in_eventlog(tmp_path):
     assert "produce_s" in ev and "stall_s" in ev
 
 
+@pytest.mark.parametrize("op", ["matmul", "gramian"])
+def test_a_streamed_op_leaves_its_stages_and_one_summary_and_no_more(
+        op, tmp_path):
+    """Seven whole chunks and a short tail: the stages count every chunk
+    (the result's fetches differ: a chunk at a time for the product, once
+    for the Gramian), the EventLog holds ONE ``prefetch`` summary, under
+    the op's span, and no record of any other kind: a streamed op reports
+    its stages, not a rate."""
+    from marlin_tpu.utils.tracing import EventLog, set_default_event_log
+
+    rng = np.random.default_rng(13)
+    a = rng.standard_normal((7 * 64 + 10, 8)).astype(np.float32)
+    b = rng.standard_normal((8, 5)).astype(np.float32)
+    st = StageTimes()
+    log = EventLog(str(tmp_path / "events.jsonl"))
+    prev = set_default_event_log(log)
+    try:
+        if op == "matmul":
+            got = mt.streamed_matmul(a, b, chunk_rows=64, stats=st)
+            want = a @ b
+        else:
+            got = mt.streamed_gramian(a, chunk_rows=64, stats=st)
+            want = a.T @ a
+    finally:
+        set_default_event_log(prev)
+        log.close()
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-3)
+    assert {k: st.counts[k] for k in ("produce", "transfer", "compute")} \
+        == {"produce": 8, "transfer": 8, "compute": 8}
+    assert st.counts["drain"] == (8 if op == "matmul" else 1)
+    assert st.seconds["compute"] > 0 and "stall" in st.seconds
+    # (a process with the compile bridge installed also logs its compiles)
+    recs = [r for r in log.read() if r["kind"] != "compile"]
+    assert [r["kind"] for r in recs] == ["prefetch"]
+    (ev,) = recs
+    assert ev["chunks"] == 8 and ev["trace_id"]
+    assert {"produce_s", "transfer_s", "stall_s"} <= set(ev)
+    assert not _alive_workers()
+
+
 # ------------------------------------------------------------- io loaders
 def test_mnist_chunked_loader_matches_bulk(tmp_path):
     from marlin_tpu.io import mnist

@@ -563,8 +563,8 @@ def test_a_recovery_finds_the_row_that_left_its_slot(params, monkeypatch):
 
 
 def test_an_interval_is_a_programs_own_only_where_its_predecessor_landed():
-    """What joins the per-program cost model: a landing that follows a
-    chunk nobody waited for holds that chunk's device time too."""
+    """A landing that follows a chunk nobody waited for holds that chunk's
+    device time too: ``landed`` says so."""
     pipe = engine_mod._Pipeline(4)
     chunk, call, final, after = (pipe.dispatched() for _ in range(4))
     assert (chunk, call, final, after) == (1, 2, 3, 4)
@@ -575,26 +575,17 @@ def test_an_interval_is_a_programs_own_only_where_its_predecessor_landed():
     assert not pipe.landed(late)
 
 
-def test_only_intervals_of_one_program_join_the_cost_model(params,
-                                                           monkeypatch):
-    """A row decodes while a prompt of three chunks prefills beside it: the
-    two decode calls that follow a chunk nobody waited for hold that
-    chunk's device time in their interval and are left out; every other
-    call, and the final chunk, is observed."""
-    from marlin_tpu.obs import perf
-
-    def calls(eng, program, bucket):
-        return sum(r["calls"] for r in perf.get_program_costs().rows()
-                   if r["program"] == program
-                   and r["key"] == eng._prog_key(bucket))
-
+def test_the_spans_seq_tells_the_calls_behind_a_chunk_nobody_waited_for(
+        params, monkeypatch, spans):
+    """A row decodes while a prompt of three chunks prefills beside it. By
+    the dispatch spans' ``seq`` the two decode calls that went out right
+    behind a chunk that is not final are the ones whose landing interval
+    holds that chunk's device time too; every program but such a chunk is
+    landed, once."""
     eng = _engine(params, max_batch=2, start=False)
     try:
         eng.warmup()
-        programs = [("lm_decode_paged", eng._decode_bucket),
-                    ("lm_prefill_paged", BUCKETS[0]),
-                    ("lm_prefill_paged", BUCKETS[1])]
-        before = [calls(eng, *p) for p in programs]
+        del spans[:]
         with _watched(monkeypatch) as log:
             hands = _Hands(eng)
             short = eng.submit(Request(prompt=_prompt(1, 4), steps=8))
@@ -602,12 +593,22 @@ def test_only_intervals_of_one_program_join_the_cost_model(params,
             long = eng.submit(Request(prompt=_prompt(6, 20), steps=2))
             while not (short.done() and long.done()):
                 hands.iterate()
-        after = [calls(eng, *p) for p in programs]
     finally:
         eng.close()
     assert _landed_once(log) == list(range(1, 8))
+    chunks = {s.fields["seq"]: s.fields["final"] for s in spans
+              if s.name == "serve.prefill.dispatch"}
+    calls = [s.fields["seq"] for s in spans
+             if s.name == "serve.decode.dispatch" and "seq" in s.fields]
+    landed = [s.fields["seq"] for s in spans
+              if s.name in ("serve.prefill.sync", "serve.decode.sync")]
+    assert len(calls) == 7 and sorted(chunks.values()) == [0, 0, 1, 1]
+    assert sorted([*chunks, *calls]) == list(range(1, 12))  # one numbering
+    assert sorted(landed) == sorted(calls + [q for q, f in chunks.items()
+                                             if f])
     # calls 2 and 3 went out behind the long prompt's first two chunks
-    assert [b - a for a, b in zip(before, after)] == [7 - 2, 1, 1]
+    assert [n for n, q in enumerate(calls, 1)
+            if chunks.get(q - 1) == 0] == [2, 3]
 
 
 # ------------------------------------------ (f) a freeze with a step in flight

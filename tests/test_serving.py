@@ -36,7 +36,6 @@ from marlin_tpu.serving import (
     BatchFormer,
     Request,
     ServeEngine,
-    bucket_kv_bytes,
     normalize_buckets,
     percentile,
     pick_bucket,
@@ -110,16 +109,6 @@ def test_normalize_and_pick_bucket():
         normalize_buckets([(8, 4), (8, 4)])
     with pytest.raises(ValueError, match="at least one"):
         normalize_buckets([])
-
-
-def test_bucket_kv_bytes(params):
-    # layers(2) x k&v(2) x max_len(8+4) x kv_heads(2) x dh(8) x f32(4)
-    assert bucket_kv_bytes(params, HEADS, (8, 4)) == 2 * 2 * 12 * 2 * 8 * 4
-    assert bucket_kv_bytes(params, HEADS, (8, 4), batch=4) == \
-        4 * bucket_kv_bytes(params, HEADS, (8, 4))
-    # bf16 halves the cache — the GQA/serving memory story end to end
-    assert bucket_kv_bytes(params, HEADS, (8, 4), compute_dtype="bfloat16") \
-        == bucket_kv_bytes(params, HEADS, (8, 4)) // 2
 
 
 def test_admission_queue_bounds():

@@ -14,8 +14,7 @@ is invisible to every runtime test — the code works, the docs lie:
   it), and attribute reads off ``get_config()`` that name no field.
 - **metrics** — every family registered in the package
   (``reg.counter/gauge/histogram("marlin_*", ...)``) vs the metric table in
-  ``docs/observability.md`` (both directions), plus the bench scrape
-  acceptance list (``bench_all.py``'s ``want`` tuple) ⊆ registered.
+  ``docs/observability.md`` (both directions).
 - **memory** — ``obs/memledger.py`` ``KNOWN_COMPONENTS`` (the HBM
   ledger's attribution vocabulary) vs the component table inside
   ``docs/observability.md``'s "Memory attribution" section (both
@@ -44,7 +43,6 @@ SCOPE = "repo"
 CONFIG_REL = "marlin_tpu/config.py"
 REPORT_REL = "marlin_tpu/obs/report.py"
 MEMLEDGER_REL = "marlin_tpu/obs/memledger.py"
-BENCH_REL = "bench_all.py"
 DOC_ROBUST = "docs/robustness.md"
 DOC_CONFIG = "docs/configuration.md"
 DOC_OBS = "docs/observability.md"
@@ -305,28 +303,6 @@ def _registered_metrics(repo: Repo) -> dict[str, tuple[str, int]]:
     return out
 
 
-def _bench_want(repo: Repo) -> list[tuple[str, int]]:
-    sf = repo.file(BENCH_REL)
-    if sf is None or sf.tree is None:
-        return []
-    out: list[tuple[str, int]] = []
-    for node in ast.walk(sf.tree):
-        value = None
-        if isinstance(node, ast.Assign) and any(
-                isinstance(t, ast.Name) and t.id == "want"
-                for t in node.targets):
-            value = node.value
-        elif isinstance(node, ast.AugAssign) and isinstance(
-                node.target, ast.Name) and node.target.id == "want":
-            value = node.value
-        if isinstance(value, (ast.Tuple, ast.List)):
-            for el in value.elts:
-                s = str_const(el)
-                if s and s.startswith("marlin_"):
-                    out.append((s, el.lineno))
-    return out
-
-
 def _check_metrics(repo: Repo, findings: list[Finding]) -> None:
     registered = _registered_metrics(repo)
     if not registered:
@@ -351,15 +327,6 @@ def _check_metrics(repo: Repo, findings: list[Finding]) -> None:
                              f"nothing registers"),
                     hint="drop the row or restore the family",
                     key=f"{NAME}:metrics:{name}@ghost"))
-    for name, line in _bench_want(repo):
-        if name not in registered:
-            findings.append(Finding(
-                check=NAME, path=BENCH_REL, line=line,
-                message=(f"bench scrape want-list expects {name!r} which "
-                         f"nothing registers — the serve_obs acceptance "
-                         f"record can never reach full marks"),
-                hint="fix the want-list entry or register the family",
-                key=f"{NAME}:metrics:{name}@bench-want"))
 
 
 # ----------------------------------------------------------------- memory

@@ -1,8 +1,8 @@
 #!/usr/bin/env python
 """Compiler-verified HBM accounting for the long-context configs.
 
-AOT-compiles the SAME jitted programs bench_all's `lct_long` / `attn_long`
-configs execute — `lm_train_step` (ring flash attention + remat + chunked LM
+AOT-compiles the SAME jitted programs ``chip_smoke.py``'s long-context phase
+executes — `lm_train_step` (ring flash attention + remat + chunked LM
 head) and the ring flash forward — against a compile-only v5e topology
 (utils/aot.py: libtpu, no chip), and records the TPU compiler's own
 memory analysis per sequence length into AOT_MEMORY.json.
@@ -138,8 +138,8 @@ def main(seqs):
         "reserve (models/planner.usable_hbm_bytes)")
     report["program"] = (
         "lm_train_step d256/h2/l2/v512 remat+loss_chunk16k "
-        "ring_flash (= bench_all config_lct_long) and the "
-        "ring-flash causal forward at d=128 (= config_attn_long)")
+        "ring_flash (the `lct_32768tok` row of PERF.md's r5 table) and the "
+        "ring-flash causal forward at d=128 (its `ring_attention` rows)")
     for sec in ("lct_long", "lct_long_bf16", "attn_long", "lct_long_4chip"):
         report.setdefault(sec, {})
     for seq in seqs:

@@ -17,6 +17,10 @@ style configuration dict (:meth:`ModelSpec.from_config`):
   a layer that owns no page at all;
 - a sixth, ``conv`` (below): a gated SHORT CONVOLUTION and no attention,
   a layer that owns no page and no recurrent matrix either;
+- a seventh, ``sparse`` (below): attention over a SELECTION of the row's
+  blocks, chosen by a score over mean-pooled keys kept beside the pages;
+- an eighth, ``lightning`` (below): linear attention with a constant decay
+  a head and NO attention, a layer that owns no page;
 - a per-head sigmoid gate on the attention output, before ``wo``;
 - a SwiGLU FFN, dense or a mixture of experts with a shared expert
   (:func:`~marlin_tpu.models.moe.moe_experts_ffn`: the layer is told which
@@ -99,6 +103,32 @@ of kilobytes where a recurrent matrix makes it tens of megabytes, so a
 snapshot costs less than the page it stands behind
 (``serving/kvpool.py`` takes one behind every chunk then).
 
+**Attention over a selection, and lightning layers between** (:class:`~marlin_tpu
+.ops.sparse_attention.SparseSpec`, :class:`LightningSpec`; the
+``minicpm_sala`` configuration family's ``mixer_types``, ``sparse_config``
+and ``lightning_*`` keys). A ``sparse`` layer is GQA attention with NO rotary
+embedding whose queries, from position ``dense_len`` on, attend ``topk``
+blocks of 64 tokens and not the row's whole context
+(:mod:`~marlin_tpu.ops.sparse_attention` has the selection's equations). What
+it selects BY is a second, smaller cache: a KV head's keys mean-pooled over
+windows of 32 tokens 16 apart, a THIRD array a layer beside its K and V
+slabs, ``(num_pages, page_len / stride, kv_heads * head_dim)``, indexed by
+the same page id (so copy-on-write, prefix sharing and eviction move a
+page's compressed keys with the page: :func:`_kv_slabs`, :func:`_copy_entry`),
+an entry owned by the page in which its window ENDS (so a shared page's
+entries are a function of the shared prefix alone). Prefill writes a
+chunk's entries with its pages and applies each query's selection as a mask
+inside the key loop; decode completes an entry at the token that ends its
+window, selects on the device and hands each (row, KV head) a LIST of blocks
+to :func:`~marlin_tpu.ops.paged_attention.paged_decode_attention_blocks`. A
+``lightning`` layer (:mod:`~marlin_tpu.ops.lightning`) keeps a ``heads x
+head_dim x head_dim`` float32 state in the row's slot (no convolution, no
+tail), shared through snapshots as a delta-rule state is. The family's block
+(:func:`_sala_layer`) is pre-norm with muP multipliers (:class:`Multipliers`:
+``scale_emb`` on the embedding, ``scale_depth / sqrt(mup_denominator)`` on
+both branches, ``dim_model_base / hidden_size`` on the logits), per-head
+QK-norm and an elementwise sigmoid output gate on both mixers.
+
 **A state that is shared by snapshot.** A slot is private to its row, but a
 COPY of it at a page boundary is as good to another row as the pages before
 that boundary: the pool keeps such copies in further slots of the same
@@ -146,8 +176,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops.sparse_attention import SparseSpec
+
 __all__ = ["RopeSpec", "LatentSpec", "SsmSpec", "DeltaSpec", "ConvSpec",
-           "Multipliers",
+           "LightningSpec", "SparseSpec", "Multipliers",
            "LayerSpec", "ModelSpec", "init_params", "state_slot_copy",
            "init_layer_params", "init_kv_pages", "window_ring_pages",
            "layer_forward", "prefill_paged", "decode_paged",
@@ -359,6 +391,32 @@ class ConvSpec:
 
 
 @dataclasses.dataclass(frozen=True)
+class LightningSpec:
+    """The sizes of a lightning-attention mixer (the ``minicpm_sala``
+    configuration family's ``lightning_*`` keys): ``heads`` heads of
+    ``head_dim`` (keys, queries and values alike; no grouping), a state of
+    ``head_dim x head_dim`` a head with a CONSTANT decay a head; the chunked
+    form's block ``chunk``. ``first_layer`` and ``layers_total`` place the
+    held layers in the published model: the decay of head ``h`` of held
+    layer ``i`` is :func:`~marlin_tpu.ops.lightning.lightning_decay` of
+    published layer ``first_layer + i`` of ``layers_total``. The state is
+    kept in ``state_dtype`` (float32)."""
+
+    heads: int
+    head_dim: int
+    chunk: int = 64
+    first_layer: int = 0
+    layers_total: int = 1
+    state_dtype: str = "float32"
+
+    def slot_arrays(self) -> tuple:
+        """What ONE row's slot holds in one layer: the recurrent state
+        ``(heads, head_dim, head_dim)``, and no tail."""
+        return (((self.heads, self.head_dim, self.head_dim),
+                 self.state_dtype),)
+
+
+@dataclasses.dataclass(frozen=True)
 class Multipliers:
     """The fixed scalars a configuration family puts on its branches (the
     ``falcon_h1`` family's ``*_multiplier`` keys): on the embedding, on the
@@ -374,11 +432,27 @@ class Multipliers:
     lm_head: float = 1.0
 
 
+#: the kinds of layer (``LayerSpec.attn``): kind -> (a page id indexes some
+#: array of the layer, the layer keeps something in the row's state slot).
+#: One line a kind: ``full`` / ``sliding`` (K and V per KV head), ``latent``
+#: (one entry a token), ``sparse`` (K, V and the compressed keys a sparse
+#: layer selects by), ``linear`` (a gated delta rule: state and tail),
+#: ``conv`` (a short convolution: a tail), ``lightning`` (a state)
+_LAYER_KINDS = {
+    "full": (True, False),
+    "sliding": (True, False),
+    "latent": (True, False),
+    "sparse": (True, False),
+    "linear": (False, True),
+    "conv": (False, True),
+    "lightning": (False, True),
+}
+
+
 @dataclasses.dataclass(frozen=True)
 class LayerSpec:
-    #: "full" | "sliding" | "latent", or "linear": no attention, a gated
-    #: delta-rule mixer (:class:`DeltaSpec`) in its place, no page owned; or
-    #: "conv": a gated short convolution (:class:`ConvSpec`) in its place
+    #: a key of :data:`_LAYER_KINDS`: what the layer's mixer is and what it
+    #: remembers the past in
     attn: str
     q_heads: int
     ffn: str       # "dense" | "moe"
@@ -388,15 +462,16 @@ class LayerSpec:
 
     @property
     def owns_pages(self) -> bool:
-        """Whether a page id indexes any array of the layer."""
-        return self.attn not in ("linear", "conv")
+        """Whether a page id indexes any array of the layer
+        (:data:`_LAYER_KINDS`)."""
+        return _LAYER_KINDS[self.attn][0]
 
     @property
     def has_state(self) -> bool:
-        """Whether the layer keeps anything in the row's state slot: a
-        recurrent state and a convolution's tail (a mixer of either kind),
-        or a short convolution's tail alone."""
-        return self.ssm or not self.owns_pages
+        """Whether the layer keeps anything in the row's state slot
+        (:data:`_LAYER_KINDS`; or a state-space mixer beside its
+        attention)."""
+        return self.ssm or _LAYER_KINDS[self.attn][1]
 
 
 _LAYER_TYPES_KEYS = (
@@ -426,6 +501,16 @@ _LFM2_KEYS = (
     "num_key_value_heads", "intermediate_size", "vocab_size", "conv_L_cache",
     "num_dense_layers", "moe_intermediate_size", "num_experts",
     "num_experts_per_tok")
+
+
+_SALA_KEYS = (
+    "num_hidden_layers", "mixer_types", "hidden_size", "head_dim",
+    "num_attention_heads", "num_key_value_heads", "intermediate_size",
+    "vocab_size", "lightning_nh", "lightning_nkv", "lightning_head_dim",
+    "rope_theta", "scale_emb", "scale_depth", "mup_denominator",
+    "dim_model_base", "sparse_config")
+_SPARSE_CONFIG_KEYS = ("kernel_size", "kernel_stride", "block_size", "topk",
+                       "init_blocks", "window_size", "dense_len")
 
 
 def _require(cfg: dict, keys: tuple, family: str) -> None:
@@ -485,6 +570,11 @@ class ModelSpec:
     delta: DeltaSpec | None = None
     #: the short convolutions' sizes (the ``conv`` layers)
     conv: ConvSpec | None = None
+    #: the block selection's sizes (the ``sparse`` layers) and the lightning
+    #: mixers' (the ``lightning`` layers); with them the block is the
+    #: ``minicpm_sala`` family's (:func:`_sala_layer`)
+    sparse: SparseSpec | None = None
+    lightning: LightningSpec | None = None
     #: a ``full`` / ``sliding`` layer's per-head sigmoid gate on the
     #: attention output, and an RMSNorm over each head's queries and keys
     #: (one gain of ``head_dim`` for all heads) before the rotary embedding
@@ -511,8 +601,10 @@ class ModelSpec:
         (:meth:`_from_falcon_h1_config`), one with ``linear_key_head_dim``
         of the ``olmo_hybrid`` family (:meth:`_from_olmo_hybrid_config`), one
         with ``conv_L_cache`` of the ``lfm2_moe`` family
-        (:meth:`_from_lfm2_config`). A configuration that lacks keys its
-        family needs raises a ``ValueError`` that names them."""
+        (:meth:`_from_lfm2_config`), one with ``mixer_types`` of the
+        ``minicpm_sala`` family (:meth:`_from_sala_config`). A configuration
+        that lacks keys its family needs raises a ``ValueError`` that names
+        them."""
         if "kv_lora_rank" in cfg:
             _require(cfg, _LATENT_KEYS, "latent-attention (kv_lora_rank)")
             return cls._from_latent_config(cfg, experts_total, first_expert)
@@ -526,6 +618,11 @@ class ModelSpec:
         if "conv_L_cache" in cfg:
             _require(cfg, _LFM2_KEYS, "lfm2_moe (conv_L_cache)")
             return cls._from_lfm2_config(cfg, experts_total, first_expert)
+        if "mixer_types" in cfg:
+            _require(cfg, _SALA_KEYS, "minicpm_sala (mixer_types)")
+            _require(cfg["sparse_config"], _SPARSE_CONFIG_KEYS,
+                     "minicpm_sala (mixer_types), in its sparse_config,")
+            return cls._from_sala_config(cfg)
         _require(cfg, _LAYER_TYPES_KEYS, "layer_types")
         n = int(cfg["num_hidden_layers"])
         kinds = {"full_attention": "full", "sliding_attention": "sliding"}
@@ -805,6 +902,86 @@ class ModelSpec:
             tied_head=bool(cfg.get("tie_word_embeddings", True)),
             renorm_eps=1e-6)
 
+    @classmethod
+    def _from_sala_config(cls, cfg: dict):
+        """The ``minicpm_sala`` family's keys: ``mixer_types`` of
+        ``minicpm4`` (GQA attention with NO rotary embedding, dense below
+        ``sparse_config.dense_len`` and over ``topk`` selected blocks from
+        it on: a ``sparse`` layer) and ``lightning-attn`` (linear attention
+        with a constant decay a head, the rotary embedding over the whole
+        head: a ``lightning`` layer, which owns no page); per-head QK-norm
+        and an elementwise sigmoid output gate on both; a dense SwiGLU after
+        either; muP multipliers (``scale_emb``, ``scale_depth /
+        sqrt(mup_denominator)`` on both branches, ``dim_model_base /
+        hidden_size`` on the logits); an untied head. ``num_hidden_layers``
+        layers are taken from the FRONT of ``mixer_types``; ``first_layer``
+        (default 0) is the published index of the first of them and
+        ``mup_denominator`` the published depth (the decay's and the
+        branches' scale stay the published model's when fewer layers are
+        held). ``sparse_config`` (the MiniCPM4 family's), ``lightning_chunk_
+        size`` (default 64) and ``lightning_state_dtype`` (default float32)
+        are not published keys of this model."""
+        n = int(cfg["num_hidden_layers"])
+        kinds = {"minicpm4": "sparse", "lightning-attn": "lightning"}
+        sc = cfg["sparse_config"]
+        unknown = sorted(set(cfg["mixer_types"][:n]) - set(kinds))
+        unbuilt = [k for k, bad in (
+            ("attention_bias", cfg.get("attention_bias", False)),
+            ("attn_use_rope", cfg.get("attn_use_rope", False)),
+            ("lightning_use_rope", not cfg.get("lightning_use_rope", True)),
+            ("qk_norm", not cfg.get("qk_norm", True)),
+            ("use_output_gate", not cfg.get("use_output_gate", True)),
+            ("use_output_norm", not cfg.get("use_output_norm", True)),
+            ("attn_use_output_gate",
+             not cfg.get("attn_use_output_gate", True)),
+            ("tie_word_embeddings", cfg.get("tie_word_embeddings", False)),
+            ("hidden_act", cfg.get("hidden_act", "silu") != "silu"),
+            ("lightning_nkv", cfg["lightning_nkv"] != cfg["lightning_nh"]),
+            ("lightning_head_dim",
+             cfg["lightning_head_dim"] != cfg["head_dim"]),
+            ("sparse_config.kernel_size",
+             sc["kernel_size"] != 2 * sc["kernel_stride"])) if bad]
+        if unbuilt or unknown:
+            raise ValueError(
+                f"not built for the minicpm_sala family: the keys {unbuilt} "
+                f"as this configuration sets them (a projection bias, a "
+                f"rotary embedding in the sparse layers or none in the "
+                f"lightning ones, no QK-norm, a mixer without its output "
+                f"gate or norm, a tied head, an activation other than silu, "
+                f"grouped lightning heads or their own head size, pooling "
+                f"windows other than two strides wide), mixer types "
+                f"{unknown}")
+        heads, dh = int(cfg["num_attention_heads"]), int(cfg["head_dim"])
+        layers = tuple(LayerSpec(kinds[cfg["mixer_types"][i]], heads, "dense")
+                       for i in range(n))
+        depth = int(cfg["mup_denominator"])
+        r = float(cfg["scale_depth"]) / math.sqrt(depth)
+        mults = Multipliers(
+            embedding=float(cfg["scale_emb"]), attention_out=r, mlp_down=r,
+            lm_head=float(cfg["dim_model_base"]) / float(cfg["hidden_size"]))
+        rope = RopeSpec(theta=float(cfg["rope_theta"]), rotary_dim=dh)
+        return cls(
+            d_model=int(cfg["hidden_size"]), head_dim=dh,
+            kv_heads=int(cfg["num_key_value_heads"]), layers=layers,
+            window=0, rope_full=rope, rope_sliding=rope,
+            dense_width=int(cfg["intermediate_size"]), expert_width=0,
+            shared_width=0, n_experts=0, experts_held=0, first_expert=0,
+            top_k=0, routed_scale=1.0, vocab_held=int(cfg["vocab_size"]),
+            norm_eps=float(cfg.get("rms_norm_eps", 1e-6)),
+            param_dtype=str(cfg.get("param_dtype", "bfloat16")),
+            compute_dtype=str(cfg.get("compute_dtype", "bfloat16")),
+            mults=mults, head_gate=False, qk_norm=True,
+            sparse=SparseSpec(
+                stride=int(sc["kernel_stride"]), block=int(sc["block_size"]),
+                topk=int(sc["topk"]), init_blocks=int(sc["init_blocks"]),
+                window=int(sc["window_size"]), dense_len=int(sc["dense_len"])),
+            lightning=LightningSpec(
+                heads=int(cfg["lightning_nh"]),
+                head_dim=int(cfg["lightning_head_dim"]),
+                chunk=int(cfg.get("lightning_chunk_size", 64)),
+                first_layer=int(cfg.get("first_layer", 0)), layers_total=depth,
+                state_dtype=str(cfg.get("lightning_state_dtype", "float32"))))
+
     @property
     def n_layers(self) -> int:
         return len(self.layers)
@@ -826,7 +1003,7 @@ class ModelSpec:
     def mixer(self):
         """The sizes of the mixers that keep something in a state slot, of
         whichever kind (None: no layer has one): what a slot is sized by."""
-        return self.ssm or self.delta or self.conv
+        return self.ssm or self.delta or self.conv or self.lightning
 
     def state_slot_bytes(self, compute_dtype: str | None = None) -> int:
         """Bytes ONE row's state slot holds over all layers: each array a
@@ -842,13 +1019,18 @@ class ModelSpec:
 
     def page_values(self, kind: str, page_len: int) -> int:
         """Cache values ONE page id of class ``kind`` holds over all layers
-        (``full``: the global class, the full and the latent layers;
-        ``sliding``: the window class): K and V per KV head for a full or a
-        sliding layer, one latent entry for a latent one."""
-        kinds = ("sliding",) if kind == "sliding" else ("full", "latent")
-        return page_len * sum(
-            self.latent.entry_width if ly.attn == "latent"
-            else 2 * self.kv_heads * self.head_dim
+        (``full``: the global class, the full, the latent and the sparse
+        layers; ``sliding``: the window class): K and V per KV head for a
+        full or a sliding layer, one latent entry for a latent one, K, V and
+        the compressed keys (``1 / stride`` of an entry a token) for a sparse
+        one."""
+        kinds = ("sliding",) if kind == "sliding" else (
+            "full", "latent", "sparse")
+        kv = self.kv_heads * self.head_dim
+        return sum(
+            page_len * self.latent.entry_width if ly.attn == "latent"
+            else 2 * page_len * kv + page_len // self.sparse.stride * kv
+            if ly.attn == "sparse" else 2 * page_len * kv
             for ly in self.layers if ly.attn in kinds)
 
 
@@ -917,6 +1099,8 @@ def init_layer_params(spec: ModelSpec, ly: LayerSpec, key) -> dict:
           "ln2": jnp.ones((d,), jnp.float32)}
     if ly.attn == "linear":
         lp.update(_init_delta_params(spec, ks[8:15]))
+    elif ly.attn in ("sparse", "lightning"):
+        lp.update(_init_sala_params(spec, ly, ks[8:15]))
     elif ly.attn == "conv":
         # [b | c | z] in that order; the taps N(0, 1/taps): the gated
         # product reaches the output projection at order 1
@@ -1043,6 +1227,44 @@ def _init_delta_params(spec: ModelSpec, ks) -> dict:
         "wo": _normal(jax.random.fold_in(ks[0], 1), (hv, d), hv ** -0.5, dt)}
 
 
+#: the QK-norm gain a sparse layer is drawn with: scores ``q . k /
+#: sqrt(head_dim)`` of normed queries and keys spread by the product of the
+#: two gains, 3 here. At gains of 1 a softmax over tens of thousands of
+#: random keys is nearly flat, the attention output nearly zero, and
+#: attending densely in the selection's place would pass any check
+_SPARSE_QK_GAIN = 3.0 ** 0.5
+
+
+def _init_sala_params(spec: ModelSpec, ly: LayerSpec, ks) -> dict:
+    """A ``sparse`` or a ``lightning`` layer's mixer (the ``minicpm_sala``
+    family), drawn so that a check can see the mechanisms: projections N(0,
+    1/fan_in), the output projection divided by the branch's multiplier
+    (``scale_depth / sqrt(mup_denominator)``) so that the branch reaches the
+    stream at order 1; a sparse layer's QK-norm gains :data:`_SPARSE_QK_GAIN`
+    (a softmax with a few dozen keys that matter among tens of thousands:
+    which blocks are chosen then changes the output), a lightning layer's
+    1; the gates ``hidden`` wide on both mixers. A lightning layer's
+    ``decay`` (float32, a head) is set by :func:`init_params`, which knows
+    the layer's index."""
+    d, dh, dt = spec.d_model, spec.head_dim, jnp.dtype(spec.param_dtype)
+    s, r = d ** -0.5, spec.mults.attention_out
+    if ly.attn == "lightning":
+        ls = spec.lightning
+        hq = hk = ls.heads * ls.head_dim
+        gain, extra = 1.0, {"o_norm": jnp.ones((hq,), jnp.float32),
+                            "decay": jnp.ones((ls.heads,), jnp.float32)}
+    else:
+        hq, hk = ly.q_heads * dh, spec.kv_heads * dh
+        gain, extra = _SPARSE_QK_GAIN, {}
+    return {"wq": _normal(ks[0], (d, hq), s, dt),
+            "wk": _normal(ks[1], (d, hk), s, dt),
+            "wv": _normal(ks[2], (d, hk), s, dt),
+            "w_g": _normal(ks[3], (d, hq), s, dt),
+            "wo": _normal(ks[4], (hq, d), hq ** -0.5 / r, dt),
+            "q_norm": jnp.full((dh,), gain, jnp.float32),
+            "k_norm": jnp.full((dh,), gain, jnp.float32), **extra}
+
+
 def init_params(spec: ModelSpec, key) -> dict:
     """Scaled-normal parameters, drawn a layer at a time; embedding and head
     are separate (untied) and hold ``vocab_held`` rows, unless the spec ties
@@ -1070,6 +1292,12 @@ def init_params(spec: ModelSpec, key) -> dict:
                                for ly in dict.fromkeys(spec.layers)])
     for i in range(spec.n_layers):
         p[f"l{i}"] = init_layer_params(spec, spec.layers[i], ks[2 + i])
+        if spec.layers[i].attn == "lightning":
+            from ..ops.lightning import lightning_decay
+
+            ls = spec.lightning
+            p[f"l{i}"]["decay"] = jnp.asarray(lightning_decay(
+                ls.heads, ls.first_layer + i, ls.layers_total))
     return p
 
 
@@ -1101,7 +1329,13 @@ def init_kv_pages(spec: ModelSpec, num_pages: int, window_pages: int,
     (its states ``(state_slots, key_dim, heads * value_dim)``:
     :mod:`~marlin_tpu.ops.delta_rule`); a ``conv`` layer ONE, the tails
     ``(state_slots, taps - 1, channels)``: a layer has the arrays its
-    mixer's ``slot_arrays()`` names. ``state_slots`` counts every slot of
+    mixer's ``slot_arrays()`` names (a ``lightning`` layer ONE, the states
+    ``(state_slots, heads, head_dim, head_dim)``). A ``sparse`` layer has a
+    THIRD page-indexed array after K and V: its compressed keys ``(num_pages,
+    page_len / stride, kv_heads * head_dim)``, entry ``e`` of page ``p`` the
+    mean of the ``2 * stride`` keys that END at token ``p * page_len + (e +
+    1) * stride - 1`` (:mod:`~marlin_tpu.ops.sparse_attention`).
+    ``state_slots`` counts every slot of
     the arrays: the rows' and, after them, the pool's snapshot slots."""
     if num_pages < 2 or (spec.has_window and window_pages < 2):
         raise ValueError(f"each page class needs >= 2 pages (page 0 is the "
@@ -1117,15 +1351,23 @@ def init_kv_pages(spec: ModelSpec, num_pages: int, window_pages: int,
 
     def slabs(ly):
         if not ly.owns_pages:   # no page: the state slot's arrays alone
-            return state_arrays(spec.delta if ly.attn == "linear"
-                                else spec.conv)
+            return state_arrays({"linear": spec.delta, "conv": spec.conv,
+                                 "lightning": spec.lightning}[ly.attn])
         if ly.attn == "latent":
             return (jnp.zeros((num_pages, page_len,
                                spec.latent.entry_width), dt),)
         kv = tuple(
-            jnp.zeros((num_pages if ly.attn == "full" else window_pages,
+            jnp.zeros((window_pages if ly.attn == "sliding" else num_pages,
                        page_len, spec.kv_heads * spec.head_dim), dt)
             for _ in range(2))
+        if ly.attn == "sparse":   # the compressed keys ride with the page
+            if page_len % spec.sparse.block:
+                raise ValueError(
+                    f"page_len {page_len} is not whole blocks of "
+                    f"{spec.sparse.block} tokens (sparse_config.block_size)")
+            return kv + (jnp.zeros(
+                (num_pages, page_len // spec.sparse.stride,
+                 spec.kv_heads * spec.head_dim), dt),)
         if not ly.ssm:
             return kv
         return kv + state_arrays(spec.ssm)
@@ -1208,6 +1450,8 @@ def layer_forward(spec: ModelSpec, i: int, lp: dict, x, positions, valid,
     H, kvh, dh = ly.q_heads, spec.kv_heads, spec.head_dim
     if spec.delta is not None:
         return _post_norm_layer(spec, ly, lp, x, attend, mix)
+    if spec.lightning is not None:
+        return _sala_layer(spec, ly, lp, x, positions, valid, attend, mix)
     if ly.ssm:
         x = _parallel_mixers(spec, ly, lp, x, positions, attend, mix)
         return _ffn_half(spec, ly, lp, x, valid)
@@ -1241,6 +1485,71 @@ def layer_forward(spec: ModelSpec, i: int, lp: dict, x, positions, valid,
             o = (o.astype(jnp.float32) * gate[:, :, None]).astype(cd)
         x = x + _mm(o.reshape(T, H * dh).astype(cd), lp["wo"], jnp.float32)
     return _ffn_half(spec, ly, lp, x, valid)
+
+
+def _sala_layer(spec: ModelSpec, ly: LayerSpec, lp: dict, x, positions,
+                valid, attend, mix):
+    """The ``minicpm_sala`` family's block: pre-norm, ``x = x + r
+    mixer(rmsnorm(x))`` then ``x = x + r swiglu(rmsnorm(x))`` with ``r =
+    scale_depth / sqrt(mup_denominator)`` (:class:`Multipliers`:
+    ``attention_out`` and ``mlp_down``). The mixer of a ``sparse`` layer is
+    :func:`_sparse_attention` under the ``attn_sparse`` scope, of a
+    ``lightning`` layer :func:`_lightning_mixer` under ``lightning_attn``."""
+    u = _rmsnorm(x, lp["ln1"], spec.norm_eps).astype(spec.compute_dtype)
+    if ly.attn == "lightning":
+        with jax.named_scope("lightning_attn"):
+            y = _lightning_mixer(spec, lp, u, positions, mix)
+    else:
+        with jax.named_scope("attn_sparse"):
+            y = _sparse_attention(spec, ly, lp, u, attend)
+    return _ffn_half(spec, ly, lp, x + spec.mults.attention_out * y, valid)
+
+
+def _head_norm(x, g, eps: float, heads: int):
+    """The family's QK-norm: ``x`` (T, heads * dh) float32 cut into heads,
+    each normed over its ``dh`` values with the one gain ``g`` (dh,)."""
+    return _rmsnorm(x.reshape(x.shape[0], heads, -1), g, eps)
+
+
+def _sparse_attention(spec: ModelSpec, ly: LayerSpec, lp: dict, u, attend):
+    """The ``minicpm4`` mixer over the normed input ``u`` (T, d) in the
+    compute dtype: GQA projections, per-head QK-norm, NO rotary embedding;
+    ``attend(q (T, kv_heads, group, head_dim), k, v (T, kv_heads,
+    head_dim))`` stores the keys, the values and the keys' compressed form
+    where the program keeps its cache and attends densely or over the
+    selected blocks by each query's position; then the elementwise sigmoid
+    gate ``sigmoid(W_g u)`` and ``wo``."""
+    cd = u.dtype
+    T, H, kvh, dh = u.shape[0], ly.q_heads, spec.kv_heads, spec.head_dim
+    q = _head_norm(_mm(u, lp["wq"], jnp.float32), lp["q_norm"],
+                   spec.norm_eps, H).astype(cd)
+    k = _head_norm(_mm(u, lp["wk"], jnp.float32), lp["k_norm"],
+                   spec.norm_eps, kvh).astype(cd)
+    v = _mm(u, lp["wv"]).reshape(T, kvh, dh)
+    gate = jax.nn.sigmoid(_mm(u, lp["w_g"], jnp.float32))
+    o = attend(q.reshape(T, kvh, H // kvh, dh), k, v)
+    return _mm((o.reshape(T, H * dh).astype(jnp.float32) * gate).astype(cd),
+               lp["wo"], jnp.float32)
+
+
+def _lightning_mixer(spec: ModelSpec, lp: dict, u, positions, mix):
+    """The ``lightning-attn`` mixer over the normed input ``u`` (T, d) in
+    the compute dtype: projections, per-head QK-norm, the rotary embedding
+    over the whole head on queries and keys, ``q / sqrt(head_dim)``;
+    ``mix(q, k, v (T, heads, head_dim), lp)`` runs the recurrence ``S =
+    lambda S + k v^T; o = S^T q`` where the program keeps the row's state
+    and returns ``o`` (T, heads, head_dim) float32; then an RMSNorm over ALL
+    ``heads * head_dim`` columns, the elementwise sigmoid gate, ``wo``."""
+    ls, cd = spec.lightning, u.dtype
+    T, H, K = u.shape[0], ls.heads, ls.head_dim
+    q, k = (_rope(_head_norm(_mm(u, lp[w], jnp.float32), lp[g],
+                             spec.norm_eps, H), positions, spec.rope_full)
+            for w, g in (("wq", "q_norm"), ("wk", "k_norm")))
+    o = mix((q * K ** -0.5).astype(cd), k.astype(cd),
+            _mm(u, lp["wv"]).reshape(T, H, K), lp)
+    o = _rmsnorm(o.reshape(T, H * K), lp["o_norm"], spec.norm_eps)
+    gate = jax.nn.sigmoid(_mm(u, lp["w_g"], jnp.float32))
+    return _mm((o * gate).astype(cd), lp["wo"], jnp.float32)
 
 
 def _short_conv(spec: ModelSpec, lp: dict, u, mix):
@@ -1707,6 +2016,79 @@ def _absorbed_query(q_nope, q_pe, scale, wkv_b, la: LatentSpec):
     return (q * scale[:, None, None]).astype(cd)
 
 
+def _attend_sparse_chunk(q, k, v, cc, q_pos, sp: SparseSpec, kv_block: int):
+    """Prefill's attention of a sparse layer: ``q`` (T, kvh, g, dh) at
+    ``q_pos`` over the row's context ``k``, ``v`` (L, kvh, dh) (key ``j`` at
+    position ``j``) by its compressed keys ``cc`` (L / stride, kvh, dh), one
+    KV head at a time: each query's blocks (``sparse_select`` scope:
+    :func:`~marlin_tpu.ops.sparse_attention.select_blocks`, every block for a
+    query below ``dense_len``) as a mask inside the key loop (``sparse_attend``
+    scope: :func:`~marlin_tpu.ops.sparse_attention.attend_selected`). A chunk that
+    lies wholly below ``dense_len`` skips the selection."""
+    from ..ops import sparse_attention
+
+    T, nb = q.shape[0], k.shape[0] // sp.block
+
+    def one(args):
+        qh, kh, vh, ch = args
+
+        def select():
+            idx, taken = sparse_attention.select_blocks(qh, ch, q_pos, sp)
+            return sparse_attention.block_mask(idx, taken, nb)
+
+        with jax.named_scope("sparse_select"):
+            mask = jax.lax.cond(q_pos[-1] < sp.dense_len,
+                                lambda: jnp.ones((T, nb), bool), select)
+        with jax.named_scope("sparse_attend"):
+            return sparse_attention.attend_selected(qh, kh, vh, q_pos, mask,
+                                                    sp.block, kv_block)
+
+    o = jax.lax.map(one, (q.transpose(1, 0, 2, 3), k.transpose(1, 0, 2),
+                          v.transpose(1, 0, 2), cc.transpose(1, 0, 2)))
+    return o.transpose(1, 0, 2, 3)
+
+
+def _complete_entries(pk, pc, tables, pos, page_len: int, stride: int):
+    """Decode's write of a sparse layer's compressed keys: a row whose token
+    at ``pos`` ENDS a pooling window (``pos % stride == stride - 1``) reads
+    the window's ``2 * stride`` keys back out of the K slab ``pk`` (the
+    token's own just written; the first half may lie in the page before) and
+    writes their mean to its entry, in the page of ``pos``; any other row
+    writes to the dummy page 0. One entry a row and step at most, in place."""
+    from ..ops.sparse_attention import compress_keys
+
+    width = pk.shape[-1]
+    here = pos // stride * stride
+    back = jnp.maximum(here - stride, 0)
+    for b in range(pos.shape[0]):  # as _scatter_kv_entries: in-place updates
+        halves = [jax.lax.dynamic_slice(
+            pk, (tables[b, at[b] // page_len], at[b] % page_len, 0),
+            (1, stride, width))[0] for at in (back, here)]
+        entry = compress_keys(jnp.concatenate(halves), stride)
+        pid = jnp.where(pos[b] % stride == stride - 1,
+                        tables[b, pos[b] // page_len], 0)
+        pc = jax.lax.dynamic_update_slice(
+            pc, entry[None].astype(pc.dtype),
+            (pid, pos[b] % page_len // stride, 0))
+    return pc
+
+
+def _select_decode_blocks(q, cc, pos, sp: SparseSpec):
+    """Each (row, KV head)'s list of blocks for a decode step: ``q`` (B, kvh,
+    g, dh), ``cc`` (B, M, kvh, dh) the rows' compressed keys, ``pos`` (B,).
+    Returns ``(idx, taken)`` (B, kvh, S)
+    (:func:`~marlin_tpu.ops.sparse_attention.select_blocks` a row and
+    head)."""
+    from ..ops import sparse_attention
+
+    def one(qh, ch, p):                  # (g, dh), (M, dh), ()
+        idx, taken = sparse_attention.select_blocks(qh[None], ch, p[None], sp)
+        return idx[0], taken[0]
+
+    per_head = jax.vmap(one, in_axes=(0, 1, None))
+    return jax.vmap(per_head)(q, cc, pos)
+
+
 # ------------------------------------------------------------- paged prefill
 
 
@@ -1716,7 +2098,7 @@ def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
                                chunk_start, length, seed, temperature, top_p,
                                top_k, spec: ModelSpec, page_len: int,
                                state_slot=None):
-    from ..ops import delta_rule, ssm as ssm_ops
+    from ..ops import delta_rule, lightning, sparse_attention, ssm as ssm_ops
     from ..ops.paged_attention import fetch_pages
     from .transformer import _pick_token_row, _row_key
 
@@ -1741,12 +2123,12 @@ def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
     w_slots = wtable[jnp.mod(s_page - wp + jnp.arange(wp), max(ring, 1))]
     ltable = jnp.pad(gtable, (0, flash_table_pages(
         gtable.shape[0], page_len) - gtable.shape[0]))
-    by_kind = {"full": gtable, "sliding": w_slots, "latent": ltable,
-               "linear": gtable,     # a linear layer has no slab to gather
-               "conv": gtable}       # nor has a conv layer
+    # every other kind goes by the global table (a sparse layer's K, V and
+    # compressed keys alike; a layer without pages has no slab to fetch)
+    by_kind = {"sliding": w_slots, "latent": ltable}
     with jax.named_scope("ctx_gather"):
         ctx = jax.lax.optimization_barrier({
-            name: tuple(fetch_pages(t, by_kind[ly.attn])
+            name: tuple(fetch_pages(t, by_kind.get(ly.attn, gtable))
                         .reshape(-1, *t.shape[2:])
                         for t in _kv_slabs(ly, pages[name]))
             for name, ly in ((f"l{i}", ly)
@@ -1820,6 +2202,48 @@ def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
                 jax.lax.dynamic_update_index_in_dim(tails, t1, state_slot, 0),)
             return conv
 
+        def mix_lightning(q, k, v, lp, name=name):
+            # a lightning layer's slot is its state alone: entered (zeros at
+            # a row's first chunk) and left behind the chunk's last VALID
+            # token; padding neither decays it nor adds to it
+            (states,) = pages[name]
+            (s0,) = _enter_state(chunk_start == 0, states[state_slot])
+            with jax.named_scope("lightning_scan"):
+                o, s1 = lightning.lightning_chunk_scan(
+                    q, k, v, jnp.log(lp["decay"]), valid, s0,
+                    block=min(spec.lightning.chunk, C))
+            new_state[name] = (
+                jax.lax.dynamic_update_index_in_dim(states, s1, state_slot,
+                                                    0),)
+            return o
+
+        def attend_sparse(q, k, v, name=name):
+            # the chunk's keys and values join the context as a full
+            # layer's do; its compressed entries (those whose windows END
+            # in the chunk: the first reaches `stride` tokens back into the
+            # page before, zeros before position 0, where no window is
+            # complete) are written with its pages and join the row's
+            sp = spec.sparse
+            ck, cv, cc = ctx[name]
+            k2, v2 = (new.astype(c.dtype).reshape(C, -1)
+                      for new, c in ((k, ck), (v, cv)))
+            before = jnp.where(chunk_start == 0, 0, jax.lax.dynamic_slice(
+                ck, (jnp.maximum(chunk_start - sp.stride, 0), 0),
+                (sp.stride, ck.shape[1])))
+            fresh = sparse_attention.compress_keys(
+                jnp.concatenate([before, k2]), sp.stride)
+            new_kv[name] = (k, v, fresh)
+            ck = jax.lax.dynamic_update_slice(ck, k2, (chunk_start, 0))
+            cv = jax.lax.dynamic_update_slice(cv, v2, (chunk_start, 0))
+            cc = jax.lax.dynamic_update_slice(
+                cc, fresh, (chunk_start // sp.stride, 0))
+
+            def heads(c):
+                return c.reshape(c.shape[0], spec.kv_heads, spec.head_dim)
+
+            return _attend_sparse_chunk(q, heads(ck), heads(cv), heads(cc),
+                                        q_pos, sp, kv_block=C)
+
         def attend_latent(q_nope, q_pe, entry, scale, wkv_b, name=name):
             new_kv[name] = (entry,)
             (ce,) = ctx[name]
@@ -1862,9 +2286,10 @@ def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
             return jnp.concatenate(outs)
 
         x, c = layer_forward(spec, i, params[name], x, q_pos, valid,
-                             attend_latent if ly.attn == "latent" else attend,
-                             {"linear": mix_delta,
-                              "conv": mix_conv}.get(ly.attn, mix))
+                             {"latent": attend_latent,
+                              "sparse": attend_sparse}.get(ly.attn, attend),
+                             {"linear": mix_delta, "conv": mix_conv,
+                              "lightning": mix_lightning}.get(ly.attn, mix))
         counts = counts + c
     # write the chunk's pages, one dynamic update a page (transformer.py has
     # the reason). A page wholly past the prompt goes to the dummy: in a
@@ -1873,7 +2298,7 @@ def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
     for i, ly in enumerate(spec.layers):
         name = f"l{i}"
         slabs = _kv_slabs(ly, pages[name])
-        fresh = [new.astype(t.dtype).reshape(cp, page_len, *t.shape[2:])
+        fresh = [new.astype(t.dtype).reshape(cp, *t.shape[1:])
                  for new, t in zip(new_kv.get(name, ()), slabs)]
         for j in range(cp):
             pid = (wtable[jnp.mod(s_page + j, ring)] if ly.attn == "sliding"
@@ -1978,8 +2403,9 @@ def _lm_decode_paged_spec_jit(params, pages, gtables, wtables, positions,
                               top_p, top_k, spec: ModelSpec, page_len: int,
                               kernel: str, prev_tokens=None, prev_index=None,
                               state_slots=None):
-    from ..ops import delta_rule, ssm as ssm_ops
-    from ..ops.paged_attention import (paged_decode_attention,
+    from ..ops import delta_rule, lightning, sparse_attention, ssm as ssm_ops
+    from ..ops.paged_attention import (fetch_pages, paged_decode_attention,
+                                       paged_decode_attention_blocks,
                                        paged_decode_attention_latent)
     from .transformer import (_pick_token_rows, _scatter_kv_entries,
                               _select_tokens)
@@ -1996,11 +2422,11 @@ def _lm_decode_paged_spec_jit(params, pages, gtables, wtables, positions,
     zero = jnp.zeros((B,), jnp.int32)
     lower = jnp.maximum(pos - spec.window + 1, 0)
     # per class: (tables, the page id this step writes, first page the
-    # kernel visits, lowest visible position)
-    per_class = {"full": (gtables, gtables[rows, page], zero, zero)}
-    per_class["latent"] = per_class["full"]  # one table, every position
-    per_class["linear"] = per_class["full"]  # no page: nothing reads it
-    per_class["conv"] = per_class["full"]
+    # kernel visits, lowest visible position). Every kind but a sliding layer
+    # goes by the global table (a latent and a sparse layer too: every
+    # position; a layer without pages reads none of it)
+    one_table = (gtables, gtables[rows, page], zero, zero)
+    per_class = {}
     if spec.has_window:
         per_class["sliding"] = (wtables, wtables[rows, jnp.mod(page, ring)],
                                 lower // page_len, lower)
@@ -2008,7 +2434,7 @@ def _lm_decode_paged_spec_jit(params, pages, gtables, wtables, positions,
     new_pages, counts = {}, jnp.zeros((3,), jnp.int32)
     for i, ly in enumerate(spec.layers):
         name = f"l{i}"
-        tables, pids, first_page, low = per_class[ly.attn]
+        tables, pids, first_page, low = per_class.get(ly.attn, one_table)
 
         def mix(xbc, dt, sp, name=name):
             # one token a row: each live row's slot read and written once;
@@ -2064,6 +2490,39 @@ def _lm_decode_paged_spec_jit(params, pages, gtables, wtables, positions,
             return paged_decode_attention(q, pk, pv, tables, lengths,
                                           first_page=first_page, lower=low)
 
+        def mix_lightning(q, k, v, lp, name=name):
+            # a lightning layer: each live row's state read and written once
+            (states,) = pages[name]
+            with jax.named_scope("lightning_update"):
+                states, o = lightning.lightning_decode_update(
+                    states, state_slots, q, k, v, jnp.log(lp["decay"]),
+                    kernel=kernel)
+            new_pages[name] = (states,)
+            return o
+
+        def attend_sparse(q, k, v, name=name, pids=pids):
+            # the token's key and value as a full layer's; the compressed
+            # entry of a window this token ends; then each (row, KV head)
+            # picks its blocks by the row's compressed keys and attends them
+            sp = spec.sparse
+            pk, pv, pc = pages[name]
+            pk, pv = _scatter_kv_entries(pk, pv, k.astype(pk.dtype),
+                                         v.astype(pv.dtype), pids, off)
+            pc = _complete_entries(pk, pc, gtables, pos, page_len, sp.stride)
+            new_pages[name] = (pk, pv, pc)
+            with jax.named_scope("sparse_select"):
+                cc = (fetch_pages(pc, gtables.reshape(-1))
+                      if kernel == "pallas" else pc[gtables])
+                idx, taken = _select_decode_blocks(
+                    q, cc.reshape(B, -1, spec.kv_heads, spec.head_dim), pos,
+                    sp)
+            if kernel != "pallas":
+                return sparse_attention.attend_blocks_gather(
+                    q, pk, pv, gtables, idx, taken, lengths, sp.block)
+            return paged_decode_attention_blocks(
+                q, pk, pv, gtables, idx, taken.sum(axis=-1), lengths,
+                sp.block)
+
         def attend_latent(q_nope, q_pe, entry, scale, wkv_b, name=name,
                           tables=tables, pids=pids):
             (slab,) = pages[name]
@@ -2085,9 +2544,10 @@ def _lm_decode_paged_spec_jit(params, pages, gtables, wtables, positions,
                               preferred_element_type=jnp.float32)
 
         x, c = layer_forward(spec, i, params[name], x, pos, live,
-                             attend_latent if ly.attn == "latent" else attend,
-                             {"linear": mix_delta,
-                              "conv": mix_conv}.get(ly.attn, mix))
+                             {"latent": attend_latent,
+                              "sparse": attend_sparse}.get(ly.attn, attend),
+                             {"linear": mix_delta, "conv": mix_conv,
+                              "lightning": mix_lightning}.get(ly.attn, mix))
         counts = counts + c
     logits = _head_logits(spec, params, x)
     nxt = _pick_token_rows(temperature, top_p, top_k, logits, seeds,

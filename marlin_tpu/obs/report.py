@@ -60,7 +60,7 @@ KNOWN_KINDS = frozenset({
 KNOWN_SERVE_EVS = frozenset({
     "breaker", "enqueue", "migrate", "page", "prefill", "rebalance",
     "reject", "replica_add", "replica_retire", "replica_rotate", "restart",
-    "result", "retry", "route_failover", "step", "swap",
+    "result", "retry", "route_failover", "sparse", "step", "swap",
 })
 
 

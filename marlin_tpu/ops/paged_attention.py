@@ -823,3 +823,169 @@ def fetch_pages(slab, table, interpret: bool | None = None) -> jax.Array:
         interpret = _interpret()
     return _fetch_pages_call(slab, jnp.asarray(table, jnp.int32),
                              interpret=bool(interpret))
+
+
+# a sparse layer's side of the slab, below everything older (as above: the
+# older kernels' cache keys hold their line numbers)
+__all__ += ["paged_decode_attention_blocks"]
+
+# blocks the list walk meets in one matmul: 16 blocks of 64 tokens are a
+# (group, 1024) score tile
+_BLOCKS_A_STEP = 16
+
+
+def _kv_blocks_kernel(tables_ref, blocks_ref, counts_ref, lengths_ref, q_ref,
+                      k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, *, block: int,
+                      page_len: int, step: int):
+    """Grid (B, kvh): one step a (row, KV head), which walks ITS LIST of
+    blocks and not the row's table. ``blocks_ref`` (B * kvh, S) names the
+    blocks (of ``block`` tokens), the first ``counts_ref[row]`` of them real;
+    block ``n`` is tokens ``n * block ..`` of the row, a ``(block, dh)``
+    window of page ``tables[b, n * block // page_len]`` at this head's
+    lanes, copied HBM to VMEM as it is (the slabs stay in HBM). Every copy of
+    a list is started before the first is awaited (all are one size: one
+    semaphore an array), then the list is met ``step`` blocks at a time with
+    the online softmax of the other kernels; a token at or past
+    ``lengths[b]`` (in the query's own block) and the slots past the count
+    are masked. The buffers are zeroed at the call's first step: a slot past
+    the count is multiplied by a probability of exactly 0 and must hold
+    something finite."""
+    b, h = pl.program_id(0), pl.program_id(1)
+    kvh = pl.num_programs(1)
+    g, dh = q_ref.shape[2:]
+    S = blocks_ref.shape[1]
+    row = b * kvh + h
+    n = counts_ref[row]
+    length = lengths_ref[b]
+    lane0 = h * dh if dh % 128 else pl.multiple_of(h * dh, 128)
+
+    @pl.when((b == 0) & (h == 0))
+    def _zero():
+        k_buf[...] = jnp.zeros_like(k_buf)
+        v_buf[...] = jnp.zeros_like(v_buf)
+
+    def copies(i):
+        tok = blocks_ref[row, i] * block
+        pid = tables_ref[b, tok // page_len]
+        off = pl.multiple_of(jax.lax.rem(tok, page_len), block)
+        dst = pl.ds(pl.multiple_of(i * block, block), block)
+        return [pltpu.make_async_copy(
+            hbm.at[pid, pl.ds(off, block), pl.ds(lane0, dh)],
+            buf.at[dst, :], sem.at[a])
+            for a, (hbm, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf)))]
+
+    @pl.loop(0, n)
+    def _start(i):
+        for copy in copies(i):
+            copy.start()
+
+    @pl.loop(0, n)
+    def _wait(i):
+        for copy in copies(i):
+            copy.wait()
+
+    q = q_ref[0, 0]                                   # (g, dh)
+    width = step * block
+    nt = (((1,), (1,)), ((), ()))      # contract both minor dimensions
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+
+    def meet(j, carry):
+        m_prev, l_prev, acc = carry
+        rows = pl.ds(pl.multiple_of(j * width, width), width)
+        # absolute position of column t: its block's first token + t % block
+        at = jnp.full((1, width), length, jnp.int32)
+        for i in range(step):
+            slot = j * step + i
+            first = blocks_ref[row, jnp.minimum(slot, S - 1)] * block
+            at = jnp.where((col // block == i) & (slot < n),
+                           first + col % block, at)
+        s = jax.lax.dot_general(q, k_buf[rows, :], nt,
+                                preferred_element_type=jnp.float32) \
+            / math.sqrt(dh)
+        s = jnp.where(at < length, s, _MASKED)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.where(at < length, jnp.exp(s - m_new), 0.0)
+        return (m_new, alpha * l_prev + jnp.sum(p, axis=1, keepdims=True),
+                acc * alpha + jnp.dot(p.astype(q.dtype), v_buf[rows, :],
+                                      preferred_element_type=jnp.float32))
+
+    _, l, acc = jax.lax.fori_loop(
+        0, (n + step - 1) // step, meet,
+        (jnp.full((g, 1), _MASKED, jnp.float32),
+         jnp.zeros((g, 1), jnp.float32), jnp.zeros((g, dh), jnp.float32)))
+    o_ref[0, 0] = (acc / l).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+def _paged_decode_attention_blocks_call(q, k_pages, v_pages, tables, blocks,
+                                        counts, lengths, block: int,
+                                        interpret: bool):
+    """Its own jitted name, so a trace tells the list walk from the table
+    walks."""
+    B, kvh, g, dh = q.shape
+    page_len = k_pages.shape[1]
+    S = blocks.shape[-1]
+    step = min(_BLOCKS_A_STEP, S)
+    slots = -(-S // step) * step     # the last matmul's tile is whole
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    row_spec = pl.BlockSpec((1, 1, g, dh), lambda b, h, *_: (b, h, 0, 0))
+    buf = slots * block * dh * k_pages.dtype.itemsize
+    return pl.pallas_call(
+        functools.partial(_kv_blocks_kernel, block=block, page_len=page_len,
+                          step=step),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(B, kvh),
+            in_specs=[row_spec, in_hbm, in_hbm],
+            out_specs=row_spec,
+            scratch_shapes=[
+                pltpu.VMEM((slots * block, dh), k_pages.dtype),
+                pltpu.VMEM((slots * block, dh), v_pages.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=max(16 << 20, 2 * buf + (8 << 20))),
+        interpret=interpret,
+    )(tables, blocks.reshape(B * kvh, S), counts.reshape(B * kvh), lengths, q,
+      k_pages, v_pages)
+
+
+def paged_decode_attention_blocks(q, k_pages, v_pages, tables, blocks, counts,
+                                  lengths, block: int,
+                                  interpret: bool | None = None) -> jax.Array:
+    """Decode attention of each (row, KV head) over a LIST of blocks of the
+    row's pages, read in place. ``q`` (B, kv_heads, group, dh); the slabs
+    ``(num_pages, page_len, kv_heads * dh)`` (a spec model's), ``page_len`` a
+    multiple of ``block``; ``tables`` (B, W); ``blocks`` (B, kv_heads, S)
+    int32: block ``n`` is the row's tokens ``n * block .. (n + 1) * block -
+    1``, and the first ``counts[b, h]`` (>= 1) entries of a list are
+    attended, each once (a block named twice is counted twice); ``lengths``
+    (B,): a token at or past a row's length is masked (at least one listed
+    token must lie below it). The group's heads share the list, two KV heads
+    have their own. Returns ``(B, kv_heads, group, dh)`` in ``q``'s dtype;
+    :func:`~marlin_tpu.ops.sparse_attention.attend_blocks_gather` is the
+    same arithmetic on gathered blocks. Only the listed blocks are read:
+    ``counts x block x dh`` keys and as many values a (row, KV head)."""
+    q = jnp.asarray(q)
+    B, kvh, _, dh = q.shape
+    if k_pages.shape != v_pages.shape or k_pages.ndim != 3 \
+            or k_pages.shape[2] != kvh * dh:
+        raise ValueError(f"the slabs must be (num_pages, page_len, kv_heads "
+                         f"* dh) alike, got {k_pages.shape} and "
+                         f"{v_pages.shape} for queries {q.shape}")
+    page_len = int(k_pages.shape[1])
+    if page_len % block or block % PAGE_SUBLANE:
+        raise ValueError(f"a page of {page_len} tokens is not whole blocks "
+                         f"of {block}, or those not whole sublane tiles")
+    if interpret is None:
+        interpret = _interpret()
+    as_i32 = lambda x: jnp.asarray(x, jnp.int32)  # noqa: E731
+    return _paged_decode_attention_blocks_call(
+        q, k_pages, v_pages, as_i32(tables), as_i32(blocks),
+        jnp.maximum(as_i32(counts), 1),
+        jnp.clip(as_i32(lengths), 1, tables.shape[1] * page_len),
+        block=int(block), interpret=bool(interpret))

@@ -151,7 +151,9 @@ class _Launch(typing.NamedTuple):
     the buckets announced at this call's landing). ``seq`` is its place
     among the programs dispatched (:meth:`_Pipeline.dispatched`);
     ``leaving`` holds, by index into ``rows``, the rows that have left
-    their slots with this call in flight (:class:`_Leaving`)."""
+    their slots with this call in flight (:class:`_Leaving`); ``sparse``,
+    for a model with sparse-attention layers, what :meth:`ServeEngine
+    ._sparse_blocks` counted of the call's rows (else None)."""
 
     rows: list
     tag: str
@@ -162,6 +164,7 @@ class _Launch(typing.NamedTuple):
     due: list
     seq: int
     leaving: dict
+    sparse: tuple | None = None
 
 
 class _First(typing.NamedTuple):
@@ -454,6 +457,7 @@ class ServeEngine:
             self._mixer_tokens = (
                 "ssm_tokens" if self._spec.ssm is not None else
                 "delta_tokens" if self._spec.delta is not None else
+                "lightning_tokens" if self._spec.lightning is not None else
                 "conv_tokens")
             # a shared page is no use without the recurrent state at the
             # prefix's end: sharing is opt-in, and rests on snapshots
@@ -2587,7 +2591,27 @@ class ServeEngine:
                           kv_tokens=int(positions.sum()) + rows,
                           sampled_rows=int((temperature > 0).sum()))
         return _Launch([(group, i, group.entries[i]) for group, i in carried],
-                       _call_tag(call), t0, nxt, counts, steps, due, seq, {})
+                       _call_tag(call), t0, nxt, counts, steps, due, seq, {},
+                       self._sparse_blocks(positions[:rows]))
+
+    def _sparse_blocks(self, positions) -> tuple | None:
+        """What a decode call's sparse-attention layers read of its live
+        rows' contexts, from the rows' positions alone (the selection's
+        SIZE is a function of the position; which blocks, the device's):
+        ``(blocks attended, blocks the contexts hold, rows in the sparse
+        regime)``, the blocks summed over rows, KV heads and sparse layers.
+        A row at position ``p`` holds ``p // block + 1`` blocks and attends
+        all of them below ``dense_len``, ``topk`` of them (all, where fewer
+        exist) from it on. None for a model without such layers."""
+        sp = None if self._spec is None else self._spec.sparse
+        if sp is None:
+            return None
+        held = positions // sp.block + 1
+        sparse = positions >= sp.dense_len
+        each = self._spec.kv_heads * len(self._spec.layer_names("sparse"))
+        # analyze: ignore[host-sync] — host numpy bookkeeping
+        return (int(np.where(sparse, np.minimum(held, sp.topk), held).sum())
+                * each, int(held.sum()) * each, int(sparse.sum()))
 
     @staticmethod
     def _moe_counts(span, counts) -> None:
@@ -2640,6 +2664,12 @@ class ServeEngine:
                 # retire rows (the next call was launched first)
                 toks = np.asarray(launch.nxt)  # sync
                 self._moe_counts(sync, launch.counts)
+                if launch.sparse is not None:
+                    attended, held, sparse_rows = launch.sparse
+                    sync.set_metadata(sparse_blocks_attended=attended,
+                                      sparse_blocks_held=held,
+                                      sparse_rows=sparse_rows)
+                    self.metrics.record_sparse(attended, held, sparse_rows)
         except Exception as exc:
             self._fail_paged_call(pool, pools, launch.rows, exc,
                                   leaving=launch.leaving)

@@ -219,6 +219,16 @@ class ServeMetrics:
             "Rows executed by one-shot (non-LM) BucketProgram step "
             "dispatches, by program",
             labelnames=("program",))
+        self._m_sparse = reg.counter(
+            "marlin_serve_sparse_blocks_total",
+            "KV blocks of the live rows' contexts at the sparse-attention "
+            "layers' decode steps (summed over rows, KV heads and sparse "
+            "layers): those the selection attended, and those held",
+            labelnames=("kind",))
+        self._m_sparse_rows = reg.counter(
+            "marlin_serve_sparse_rows_total",
+            "Rows that decoded a step in the sparse regime (position at or "
+            "past dense_len)")
         self._m_prog_swaps = reg.counter(
             "marlin_serve_program_swaps_total",
             "Atomic model hot-updates (swap_model) on resident "
@@ -348,6 +358,16 @@ class ServeMetrics:
             fields["new_tokens"] = 0
             fields["program"] = label
         self._emit(**fields)
+
+    def record_sparse(self, attended: int, held: int, rows: int) -> None:
+        """One landed decode call of a model with sparse-attention layers:
+        the KV blocks its live rows attended and held (summed over rows, KV
+        heads and sparse layers) and the rows past ``dense_len``."""
+        self._m_sparse.labels(kind="attended").inc(attended)
+        self._m_sparse.labels(kind="held").inc(held)
+        self._m_sparse_rows.inc(rows)
+        self._emit(ev="sparse", blocks_attended=attended, blocks_held=held,
+                   rows=rows)
 
     def record_swap(self, program: str) -> None:
         """One atomic model hot-update (``swap_model``) installed on a

@@ -1073,3 +1073,66 @@ def test_the_context_fetch_compiles_for_v5e_and_passes_over_no_slab(one_chip):
     assert c.memory_analysis().temp_size_in_bytes < 1 << 20
     _, ops = over_slab(lambda t, tb: t[tb], 3840)
     assert ops.count("slice") == 4
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_block_walk_kernel_mosaic_compiles_at_the_sala_cells_shapes(one_chip,
+                                                                    dtype):
+    """``serve.minicpm-sala-longdoc16``'s sparse decode call: 16 rows of 2 KV
+    heads x 128 with 16 query rows a head, each (row, KV head) a list of up
+    to 128 blocks of 64 tokens (a quarter of a 256-token page, ONE head's 128
+    lanes: a strided window of the (256, 256) page, its lane offset the grid's
+    head), over a table of 136 pages of the 3264-page slab. The slabs stay in
+    HBM; the scoped VMEM holds the two block buffers (2 MB each in bfloat16,
+    4 MB in float32: the f32 check's); so does the fetch of the rows'
+    compressed keys, a page of 16 entries a copy."""
+    from marlin_tpu.ops.paged_attention import (
+        _fetch_pages_call, _paged_decode_attention_blocks_call)
+
+    def st(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    rows, kvh, group, dh, pages, width, slots = 16, 2, 16, 128, 3264, 136, 128
+    slab = st((pages, 256, kvh * dh), dtype)
+    c = _paged_decode_attention_blocks_call.trace(
+        st((rows, kvh, group, dh), dtype), slab, slab, st((rows, width)),
+        st((rows, kvh, slots)), st((rows, kvh)), st((rows,)), block=64,
+        interpret=False).lower().compile()
+    assert "tpu_custom_call" in c.as_text()
+    query = rows * kvh * group * dh * jnp.dtype(dtype).itemsize
+    assert c.memory_analysis().temp_size_in_bytes <= 4 * query
+    f = _fetch_pages_call.trace(st((pages, 16, kvh * dh), dtype),
+                                st((rows * width,)),
+                                interpret=False).lower().compile()
+    assert "tpu_custom_call" in f.as_text()
+
+
+def test_lightning_update_kernel_mosaic_compiles_in_place_at_published_widths(
+        one_chip):
+    """The decode state update of a lightning layer (``ops/lightning.py``) at
+    the ``minicpm_sala`` cell's shapes (16 rows, 32 heads of 128 x 128
+    float32: sixteen whole tiles a head, 33 slots) compiles through Mosaic
+    for a v5e and updates the slab IN PLACE: the 69 MB slab is aliased to the
+    output and no temporary of its size appears."""
+    from marlin_tpu.ops import lightning
+
+    B, H, K, S = 16, 32, 128, 33
+    slab_bytes = S * H * K * K * 4
+
+    def st(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def step(slab, slots, q, k, v, log_decay):
+        return lightning.lightning_decode_update(slab, slots, q, k, v,
+                                                 log_decay, kernel="pallas",
+                                                 interpret=False)
+
+    assert lightning.decode_update_supported(H, K, K)
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+        st((S, H, K, K)), st((B,), jnp.int32), st((B, H, K)), st((B, H, K)),
+        st((B, H, K)), st((H,))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= slab_bytes
+    assert m.temp_size_in_bytes < slab_bytes // 8

@@ -130,7 +130,7 @@ def test_chips_topology_validation():
 # each class times ``kv_page_bytes`` of that class, plus its state slot. The
 # cases hold that number to the bytes ``init_kv_pages`` really allocates a
 # page id / a slot, for the dense block and one tiny model of every served
-# family (tests/test_ctx_gather.py's five).
+# family (tests/test_ctx_gather.py's five and the ``minicpm_sala`` one).
 
 PAGE = 8
 
@@ -154,6 +154,7 @@ def _family(name):
     from tests.test_hybrid_model import tiny_cfg as laguna_cfg
     from tests.test_latent_attention import tiny_cfg as mistral_cfg
     from tests.test_short_conv import tiny_cfg as lfm2_cfg
+    from tests.test_sparse_attention import tiny_cfg as sala_cfg
     from tests.test_state_space import tiny_cfg as falcon_cfg
 
     if name == "dense":
@@ -163,20 +164,22 @@ def _family(name):
     return _spec_model({"window-ring+experts": laguna_cfg,
                         "latent+experts": mistral_cfg,
                         "ssm": falcon_cfg, "delta-rule": olmo.tiny_cfg,
-                        "short-conv": lfm2_cfg}[name]())
+                        "short-conv": lfm2_cfg,
+                        "sparse+lightning": sala_cfg}[name]())
 
 
 def _held_bytes(pages, heads):
     """(bytes one GLOBAL page id names over all layers, one WINDOW page id,
     one state slot), from the arrays' own ``nbytes``: a layer's page slabs
-    come first in its tuple (two, ONE for a latent layer, none for a layer
-    that owns no page), its slot's arrays after them."""
+    come first in its tuple (two, ONE for a latent layer, THREE for a sparse
+    layer, whose compressed keys ride with the page, none for a layer that
+    owns no page), its slot's arrays after them."""
     full = sliding = slot = 0
     for i in range(len(pages)):
         arrays = pages[f"l{i}"]
         ly = None if isinstance(heads, int) else heads.layers[i]
         n_slabs = (2 if ly is None else 0 if not ly.owns_pages
-                   else 1 if ly.attn == "latent" else 2)
+                   else {"latent": 1, "sparse": 3}.get(ly.attn, 2))
         for a in arrays[:n_slabs]:
             if ly is not None and ly.attn == "sliding":
                 sliding += a.nbytes // a.shape[0]
@@ -187,7 +190,7 @@ def _held_bytes(pages, heads):
 
 
 FAMILIES = ["dense", "dense-gqa", "window-ring+experts", "latent+experts",
-            "ssm", "delta-rule", "short-conv"]
+            "ssm", "delta-rule", "short-conv", "sparse+lightning"]
 
 
 @pytest.mark.parametrize("family", FAMILIES)
@@ -209,7 +212,8 @@ def test_admission_charges_the_pages_and_the_slot_the_pool_holds(family):
                                  kind="sliding") == sliding
             assert spec.state_slot_bytes() == slot
         assert (sliding > 0) == (family == "window-ring+experts")
-        assert (slot > 0) == (family in ("ssm", "delta-rule", "short-conv"))
+        assert (slot > 0) == (family in ("ssm", "delta-rule", "short-conv",
+                                         "sparse+lightning"))
         # ... and a request is charged exactly that, times what it can pin
         charged = []
         for n, steps in ((5, 3), (40, 20)):

@@ -2016,36 +2016,46 @@ def _absorbed_query(q_nope, q_pe, scale, wkv_b, la: LatentSpec):
     return (q * scale[:, None, None]).astype(cd)
 
 
-def _attend_sparse_chunk(q, k, v, cc, q_pos, sp: SparseSpec, kv_block: int):
+def _attend_sparse_chunk(q, k, v, cc, q_pos, valid, sp: SparseSpec):
     """Prefill's attention of a sparse layer: ``q`` (T, kvh, g, dh) at
-    ``q_pos`` over the row's context ``k``, ``v`` (L, kvh, dh) (key ``j`` at
-    position ``j``) by its compressed keys ``cc`` (L / stride, kvh, dh), one
-    KV head at a time: each query's blocks (``sparse_select`` scope:
-    :func:`~marlin_tpu.ops.sparse_attention.select_blocks`, every block for a
-    query below ``dense_len``) as a mask inside the key loop (``sparse_attend``
-    scope: :func:`~marlin_tpu.ops.sparse_attention.attend_selected`). A chunk that
-    lies wholly below ``dense_len`` skips the selection."""
+    ``q_pos`` (``valid``: the rows that hold a token) over the row's context
+    ``k``, ``v`` (L, kvh * dh) (key ``j`` at position ``j``, a token's heads
+    side by side) by its compressed keys ``cc`` (L / stride, kvh * dh).
+    Under the ``sparse_select`` scope each query's blocks, one KV head at a
+    time (:func:`~marlin_tpu.ops.sparse_attention.select_blocks`; every
+    block for a query below ``dense_len``, and a chunk that lies wholly
+    below it skips the scores), and from them each TILE of queries' list of
+    the blocks any of its tokens took
+    (:func:`~marlin_tpu.ops.sparse_attention.tile_lists`); under
+    ``sparse_attend`` the kernel that walks those lists and meets no other
+    block (:func:`~marlin_tpu.ops.paged_attention.sparse_prefill_attention`:
+    one form in both regimes; a tile of padding is not computed). Returns
+    the attended values (T, kvh, g, dh) and ``(blocks the tiles met, blocks
+    their tokens took)``, summed over tiles and KV heads."""
     from ..ops import sparse_attention
+    from ..ops.paged_attention import sparse_prefill_attention
 
-    T, nb = q.shape[0], k.shape[0] // sp.block
+    T, kvh, g, dh = q.shape
+    nb = k.shape[0] // sp.block
 
     def one(args):
-        qh, kh, vh, ch = args
+        qh, ch = args
+        idx, taken = sparse_attention.select_blocks(qh, ch, q_pos, sp)
+        return sparse_attention.block_mask(idx, taken, nb)
 
-        def select():
-            idx, taken = sparse_attention.select_blocks(qh, ch, q_pos, sp)
-            return sparse_attention.block_mask(idx, taken, nb)
-
-        with jax.named_scope("sparse_select"):
-            mask = jax.lax.cond(q_pos[-1] < sp.dense_len,
-                                lambda: jnp.ones((T, nb), bool), select)
-        with jax.named_scope("sparse_attend"):
-            return sparse_attention.attend_selected(qh, kh, vh, q_pos, mask,
-                                                    sp.block, kv_block)
-
-    o = jax.lax.map(one, (q.transpose(1, 0, 2, 3), k.transpose(1, 0, 2),
-                          v.transpose(1, 0, 2), cc.transpose(1, 0, 2)))
-    return o.transpose(1, 0, 2, 3)
+    with jax.named_scope("sparse_select"):
+        mask = jax.lax.cond(
+            q_pos[-1] < sp.dense_len,
+            lambda: jnp.ones((kvh, T, nb), bool),
+            lambda: jax.lax.map(one, (
+                q.transpose(1, 0, 2, 3),
+                cc.reshape(-1, kvh, dh).transpose(1, 0, 2))))
+        lists, rounds, words, met, taken = sparse_attention.tile_lists(
+            mask, q_pos, valid, sp.block, sparse_attention.tile_tokens(T, g))
+    with jax.named_scope("sparse_attend"):
+        o = sparse_prefill_attention(q, k, v, q_pos, lists, rounds, words,
+                                     sp.block)
+    return o, jnp.stack([met, taken])
 
 
 def _complete_entries(pk, pc, tables, pos, page_len: int, stride: int):
@@ -2138,6 +2148,7 @@ def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
                              + jnp.arange(wp * page_len), q_pos])
     x = _embed(spec, params, chunk)
     new_kv, new_state, counts = {}, {}, jnp.zeros((3,), jnp.int32)
+    walked = []  # a sparse layer's (blocks its tiles met, their tokens took)
     for i, ly in enumerate(spec.layers):
         name = f"l{i}"
 
@@ -2238,11 +2249,9 @@ def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
             cc = jax.lax.dynamic_update_slice(
                 cc, fresh, (chunk_start // sp.stride, 0))
 
-            def heads(c):
-                return c.reshape(c.shape[0], spec.kv_heads, spec.head_dim)
-
-            return _attend_sparse_chunk(q, heads(ck), heads(cv), heads(cc),
-                                        q_pos, sp, kv_block=C)
+            o, met = _attend_sparse_chunk(q, ck, cv, cc, q_pos, valid, sp)
+            walked.append(met)
+            return o
 
         def attend_latent(q_nope, q_pe, entry, scale, wkv_b, name=name):
             new_kv[name] = (entry,)
@@ -2312,6 +2321,8 @@ def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
     logits = _head_logits(spec, params, x[idx])
     first = _pick_token_row(temperature, top_p, top_k, logits,
                             _row_key(seed, 0))
+    if walked:  # after the expert layers' three
+        counts = jnp.concatenate([counts, sum(walked).astype(jnp.int32)])
     return new_pages, first, counts, logits
 
 
@@ -2366,7 +2377,10 @@ def prefill_paged(params, pages, tables, chunk, chunk_start, length,
     with state-space mixers, its state slot after them. Returns
     ``(pages, first, counts, logits)``: ``counts`` the expert layers'
     ``(assignments, local assignments, experts touched)`` summed over
-    layers, ``logits`` the float32 logits ``first`` was picked from."""
+    layers and, for a model with sparse layers, after them ``(blocks the
+    chunk's tiles met, blocks their tokens took)`` summed over those layers
+    (:func:`_attend_sparse_chunk`), ``logits`` the float32 logits ``first``
+    was picked from."""
     args, static = _prefill_args(params, pages, tables, chunk, chunk_start,
                                  length, spec, page_len, seed, temperature,
                                  top_p, top_k)

@@ -834,6 +834,28 @@ __all__ += ["paged_decode_attention_blocks"]
 _BLOCKS_A_STEP = 16
 
 
+def _meet_keys(q, keys, values, seen, carry):
+    """The meeting step of the two list walks (decode's below, prefill's at
+    the end of the file): ``q`` (R, dh) against ``keys``, ``values`` (width,
+    dh) with the online softmax of the other kernels, ``seen`` (R or 1,
+    width) true where a query row attends a column. A row that has met no
+    key of its own yet (its running max still the mask's value) must not
+    count the masked ones (``exp(0) = 1``): ``p`` is zeroed where not seen.
+    ``carry`` and the result are ``(m, l, acc)``: (R, 1), (R, 1), (R, dh)
+    float32."""
+    m_prev, l_prev, acc = carry
+    nt = (((1,), (1,)), ((), ()))      # contract both minor dimensions
+    s = jax.lax.dot_general(q, keys, nt, preferred_element_type=jnp.float32) \
+        / math.sqrt(q.shape[-1])
+    s = jnp.where(seen, s, _MASKED)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
+    return (m_new, alpha * l_prev + jnp.sum(p, axis=1, keepdims=True),
+            acc * alpha + jnp.dot(p.astype(q.dtype), values,
+                                  preferred_element_type=jnp.float32))
+
+
 def _kv_blocks_kernel(tables_ref, blocks_ref, counts_ref, lengths_ref, q_ref,
                       k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, *, block: int,
                       page_len: int, step: int):
@@ -886,11 +908,9 @@ def _kv_blocks_kernel(tables_ref, blocks_ref, counts_ref, lengths_ref, q_ref,
 
     q = q_ref[0, 0]                                   # (g, dh)
     width = step * block
-    nt = (((1,), (1,)), ((), ()))      # contract both minor dimensions
     col = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
 
     def meet(j, carry):
-        m_prev, l_prev, acc = carry
         rows = pl.ds(pl.multiple_of(j * width, width), width)
         # absolute position of column t: its block's first token + t % block
         at = jnp.full((1, width), length, jnp.int32)
@@ -899,16 +919,8 @@ def _kv_blocks_kernel(tables_ref, blocks_ref, counts_ref, lengths_ref, q_ref,
             first = blocks_ref[row, jnp.minimum(slot, S - 1)] * block
             at = jnp.where((col // block == i) & (slot < n),
                            first + col % block, at)
-        s = jax.lax.dot_general(q, k_buf[rows, :], nt,
-                                preferred_element_type=jnp.float32) \
-            / math.sqrt(dh)
-        s = jnp.where(at < length, s, _MASKED)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.where(at < length, jnp.exp(s - m_new), 0.0)
-        return (m_new, alpha * l_prev + jnp.sum(p, axis=1, keepdims=True),
-                acc * alpha + jnp.dot(p.astype(q.dtype), v_buf[rows, :],
-                                      preferred_element_type=jnp.float32))
+        return _meet_keys(q, k_buf[rows, :], v_buf[rows, :], at < length,
+                          carry)
 
     _, l, acc = jax.lax.fori_loop(
         0, (n + step - 1) // step, meet,
@@ -988,4 +1000,246 @@ def paged_decode_attention_blocks(q, k_pages, v_pages, tables, blocks, counts,
         q, k_pages, v_pages, as_i32(tables), as_i32(blocks),
         jnp.maximum(as_i32(counts), 1),
         jnp.clip(as_i32(lengths), 1, tables.shape[1] * page_len),
+        block=int(block), interpret=bool(interpret))
+
+
+# prefill's attention of a sparse layer, below everything older
+__all__ += ["sparse_prefill_attention"]
+
+# a tile's list of blocks is met _BLOCKS_A_STEP blocks a matmul; which of a
+# round's blocks each TOKEN of the tile took rides beside the list as one
+# word a listed block (bit t: the tile's t-th token), _LIST_LANES words a row
+_LIST_LANES = 128
+# rounds of K and V blocks in VMEM: one being met, the others' copies in
+# flight
+_TILE_SLOTS = 4
+
+
+def _tile_blocks_kernel(lists_ref, rounds_ref, next_ref, qpos_ref, q_ref,
+                        words_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem,
+                        cur, used, spread_ref, *, block: int, step: int,
+                        group: int):
+    """Grid (tiles, kvh): one step a (tile of ``tq`` query tokens, KV head),
+    which walks the UNION of the blocks its tokens took and no other.
+    ``q_ref`` (1, R, dh): the tile's ``R = tq * group`` query rows, row ``t *
+    group + g`` token ``t``'s ``g``-th head of this KV head's group.
+    ``lists_ref`` (tiles * kvh, S) names the blocks of grid step ``tile * kvh
+    + h`` in ``rounds_ref[step]`` ROUNDS of ``step`` blocks (the last round
+    filled up with block 0, which no token is marked for); ``words_ref`` (1,
+    1, S / lanes, lanes) holds for each listed block the tokens that took it
+    (bit ``t``). ``k_hbm`` / ``v_hbm`` (L, kvh * dh) stay in HBM: block ``n``
+    is rows ``n * block ..`` at this head's lanes, a ``(block, dh)`` window
+    copied as it is into its place in a round's ``(step * block, dh)``
+    buffer. The call's rounds are ONE stream across its grid steps, as the
+    table walk's pages are (:func:`_kv_walk_kernel`): stream entry ``e``
+    lands in slot ``e % slots``, consuming an entry starts the copies of
+    entry ``e + slots - 1``, whichever grid step holds it (``next_ref[g +
+    1]``: the next grid step after ``g`` that has a round; ``next_ref[0]`` the
+    first), and a round is awaited ONCE an array, by the bytes of its whole
+    buffer (every round copies ``step`` blocks, so a buffer is whole before
+    it is met and is never zeroed). A round meets the tile in one matmul; key
+    column ``c`` of block ``n`` is seen by query row ``(t, g)`` iff bit ``t``
+    of the block's word is set AND its position ``n * block + c % block <=
+    q_pos[t]``: a tile's union
+    never shows a block to a token that did not take it. The words of a round
+    reach the score tile's shape through the MXU: the tokens' bits ``(tq,
+    lanes)`` 0 / 1 times the constant ``spread_ref[round % (lanes / step)]``
+    (lanes, step * block), which repeats list entry ``i``'s column ``block``
+    times, then each token's row under its ``group`` query rows (spread at
+    all ``R`` rows it is a third matmul the size of the scores': 1.59 ms a
+    call of the cell's chunk against 1.31, PERF.md PR 49). A grid
+    step without a round (a tile wholly past the prompt) copies nothing,
+    multiplies nothing and writes zeros, as does a row that saw no key."""
+    tile, h = pl.program_id(0), pl.program_id(1)
+    kvh = pl.num_programs(1)
+    total = pl.num_programs(0) * kvh
+    gs = tile * kvh + h
+    R, dh = q_ref.shape[1:]
+    tq = R // group
+    slots, width = k_buf.shape[:2]
+    lanes = words_ref.shape[-1]
+    per = lanes // step                  # rounds a row of words
+
+    def arrays():
+        return enumerate(((k_hbm, k_buf), (v_hbm, v_buf)))
+
+    def start_next():
+        g, r, e = cur[0], cur[1], cur[2]
+
+        @pl.when(g < total)
+        def _start():
+            slot = jax.lax.rem(e, slots)
+            lane0 = jax.lax.rem(g, kvh) * dh
+            if dh % 128 == 0:
+                lane0 = pl.multiple_of(lane0, 128)
+            for i in range(step):
+                first = pl.multiple_of(lists_ref[g, r * step + i] * block,
+                                       block)
+                for a, (hbm, buf) in arrays():
+                    pltpu.make_async_copy(
+                        hbm.at[pl.ds(first, block), pl.ds(lane0, dh)],
+                        buf.at[slot, pl.ds(i * block, block), :],
+                        sem.at[a, slot]).start()
+            last = r + 1 >= rounds_ref[g]
+            cur[0] = jnp.where(last, next_ref[g + 1], g)
+            cur[1] = jnp.where(last, 0, r + 1)
+            cur[2] = e + 1
+
+    @pl.when(gs == 0)
+    def _first_rounds():
+        entry = jax.lax.broadcasted_iota(jnp.int32, spread_ref.shape[1:], 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, spread_ref.shape[1:], 1)
+        for k in range(per):
+            spread_ref[k] = (entry == k * step + col // block).astype(
+                spread_ref.dtype)
+        cur[0], cur[1], cur[2] = next_ref[0], 0, 0
+        used[0] = 0
+        for _ in range(slots - 1):
+            start_next()
+
+    q = q_ref[0]
+    row_token = jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0) // group
+    token = jax.lax.broadcasted_iota(jnp.int32, (tq, 1), 0)
+    q_pos = jnp.zeros((R, 1), jnp.int32)
+    bit = jnp.zeros((tq, 1), jnp.int32)       # token t's bit of a word
+    for t in range(tq):
+        q_pos = jnp.where(row_token == t, qpos_ref[tile * tq + t], q_pos)
+        bit = jnp.where(token == t,   # 1 << 31 as the int32 it is
+                        jnp.int32((1 << t) - (t == 31) * 2 ** 32), bit)
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+    base = used[0]
+    n = rounds_ref[gs]
+
+    def meet(r, carry):
+        # into the slot of the entry before this one, whose reader has run
+        start_next()
+        slot = jax.lax.rem(base + r, slots)
+        for a, (_, buf) in arrays():     # the round's copies, by their bytes
+            pltpu.make_async_copy(buf.at[slot], buf.at[slot],
+                                  sem.at[a, slot]).wait()
+        words = words_ref[0, 0, pl.ds(r // per, 1), :]       # (1, lanes)
+        # 0 / 1 operands, exact in one bfloat16 pass whatever precision the
+        # caller's context asks of its own matmuls
+        took = jnp.dot(((words & bit) != 0).astype(spread_ref.dtype),
+                       spread_ref[jax.lax.rem(r, per)],
+                       precision=jax.lax.Precision.DEFAULT,
+                       preferred_element_type=jnp.float32)     # (tq, width)
+        took = jnp.concatenate(
+            [jnp.broadcast_to(took[t:t + 1], (group, width))
+             for t in range(tq)], axis=0)                      # (R, width)
+        # absolute position of column c: its block's first token + c % block
+        at = jnp.zeros((1, width), jnp.int32)
+        for i in range(step):
+            at = jnp.where(col // block == i,
+                           lists_ref[gs, r * step + i] * block + col % block,
+                           at)
+        return _meet_keys(q, k_buf[slot], v_buf[slot],
+                          (took > 0.5) & (at <= q_pos), carry)
+
+    _, l, acc = jax.lax.fori_loop(
+        0, n, meet,
+        (jnp.full((R, 1), _MASKED, jnp.float32),
+         jnp.zeros((R, 1), jnp.float32), jnp.zeros((R, dh), jnp.float32)))
+    used[0] = base + n
+    o_ref[0] = jnp.where(l > 0, acc / jnp.where(l > 0, l, 1.0),
+                         0.0).astype(o_ref.dtype)
+
+
+def _next_with_a_round(rounds):
+    """The stream's way past the grid steps that copy nothing: entry ``g + 1``
+    the first grid step after ``g`` that has a round, entry 0 the first of
+    all, the count of grid steps where there is none."""
+    n = rounds.shape[0]
+    at = jnp.where(rounds > 0, jnp.arange(n), n)
+    return jnp.concatenate([jax.lax.cummin(at, reverse=True),
+                            jnp.full((1,), n)]).astype(jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+def _sparse_prefill_attention_call(q, k, v, q_pos, lists, rounds, words,
+                                   block: int, interpret: bool):
+    """Its own jitted name, so a trace tells prefill's walk from decode's
+    (``_paged_decode_attention_blocks_call``, which the benchmark finds by
+    name)."""
+    T, kvh, group, dh = q.shape
+    tiles, S = lists.shape[1:]
+    R = T // tiles * group
+    lanes, step, slots = _LIST_LANES, _BLOCKS_A_STEP, _TILE_SLOTS
+    width = step * block
+    # grid step tile * kvh + h: its list and its rounds
+    flat = tiles * kvh
+    rounds = rounds.T.reshape(flat)
+    nxt = _next_with_a_round(rounds)
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    rows = pl.BlockSpec((1, R, dh), lambda t, h, *_: (h, t, 0))
+    ring = 2 * slots * width * dh * k.dtype.itemsize
+    tile_bytes = R * width * 4
+    out = pl.pallas_call(
+        functools.partial(_tile_blocks_kernel, block=block, step=step,
+                          group=group),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(tiles, kvh),
+            in_specs=[rows,
+                      pl.BlockSpec((1, 1, S // lanes, lanes),
+                                   lambda t, h, *_: (h, t, 0, 0)),
+                      in_hbm, in_hbm],
+            out_specs=rows,
+            scratch_shapes=[
+                pltpu.VMEM((slots, width, dh), k.dtype),
+                pltpu.VMEM((slots, width, dh), v.dtype),
+                pltpu.SemaphoreType.DMA((2, slots)),
+                pltpu.SMEM((3,), jnp.int32),  # cur: grid step, round, started
+                pltpu.SMEM((1,), jnp.int32),    # used
+                pltpu.VMEM((lanes // step, lanes, width), jnp.bfloat16),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((kvh, T * group, dh), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=max(32 << 20,
+                                 ring + 12 * tile_bytes + (8 << 20))),
+        interpret=interpret,
+    )(lists.transpose(1, 0, 2).reshape(flat, S), rounds, nxt, q_pos,
+      q.transpose(1, 0, 2, 3).reshape(kvh, T * group, dh),
+      words.reshape(kvh, tiles, S // lanes, lanes), k, v)
+    return out.reshape(kvh, T, group, dh).transpose(1, 0, 2, 3)
+
+
+def sparse_prefill_attention(q, k, v, q_pos, lists, rounds, words, block: int,
+                             interpret: bool | None = None) -> jax.Array:
+    """Prefill's attention of a sparse layer over the blocks its queries
+    took, a TILE of queries at a time. ``q`` (T, kv_heads, group, dh) at
+    positions ``q_pos`` (T,); ``k``, ``v`` (L, kv_heads * dh) the row's
+    context, key ``j`` at position ``j``, a token's heads side by side (what
+    ``fetch_pages`` leaves: contiguous, so block ``n`` is rows ``n * block
+    ..``); ``lists`` (kv_heads, tiles, S) int32, ``rounds`` (kv_heads, tiles)
+    and ``words`` (kv_heads, tiles, S) int32 from
+    :func:`~marlin_tpu.ops.sparse_attention.tile_lists`: tile ``i`` is
+    queries ``i * T / tiles ..``, its list the blocks ANY of its tokens took,
+    met ``rounds * step`` entries of it, bit ``t`` of a block's word set iff
+    the tile's ``t``-th token took it. Query ``t`` attends the keys ``<=
+    q_pos[t]`` of ITS blocks, one softmax over them (bfloat16 or float32
+    operands as given, float32 scores and accumulation); a token marked for
+    no block (padding) gets zeros. Returns (T, kv_heads, group, dh) in
+    ``q``'s dtype; :func:`~marlin_tpu.ops.sparse_attention.attend_selected`
+    is the same arithmetic as a mask over every key. Only the listed blocks
+    are read, and no score leaves VMEM."""
+    q = jnp.asarray(q)
+    T, kvh, group, dh = q.shape
+    tiles, S = lists.shape[1:]
+    if k.shape != v.shape or k.ndim != 2 or k.shape[1] != kvh * dh \
+            or k.shape[0] % block:
+        raise ValueError(f"the context must be (L, kv_heads * dh) alike and "
+                         f"whole blocks of {block}, got {k.shape} and "
+                         f"{v.shape} for queries {q.shape}")
+    if T % tiles or T // tiles > 32 or S % _LIST_LANES:
+        raise ValueError(f"{tiles} tiles of {T} queries with lists of {S}: "
+                         f"a tile holds at most 32 tokens (a word's bits) and "
+                         f"a list whole rows of {_LIST_LANES} entries")
+    if interpret is None:
+        interpret = _interpret()
+    as_i32 = lambda x: jnp.asarray(x, jnp.int32)  # noqa: E731
+    return _sparse_prefill_attention_call(
+        q, k, v, as_i32(q_pos), as_i32(lists), as_i32(rounds), as_i32(words),
         block=int(block), interpret=bool(interpret))

@@ -33,12 +33,22 @@ A query below ``dense_len`` takes every block it can see. The selection of a
 token depends on its POSITION alone, never on how a prompt was cut into
 chunks.
 
-Prefill computes a chunk's selection as a (query, block) mask applied inside
-the key loop (:func:`attend_selected`: selecting IS masking; it costs the
-dense FLOPs). Decode hands each (row, KV head) a LIST of blocks to
+Both programs hand the attention LISTS of blocks and read those blocks and
+no other. Decode: each (row, KV head) its own list, to
 :func:`~marlin_tpu.ops.paged_attention.paged_decode_attention_blocks`, which
-reads those blocks out of the page slab and no other;
-:func:`attend_blocks_gather` is the same arithmetic on gathered blocks.
+reads the blocks out of the page slab (:func:`attend_blocks_gather` is the
+same arithmetic on gathered blocks). Prefill: a chunk's queries are cut into
+TILES of :func:`tile_tokens` tokens; a tile of a KV head lists the UNION of
+the blocks its tokens took, and which of its tokens took each
+(:func:`tile_lists`), to
+:func:`~marlin_tpu.ops.paged_attention.sparse_prefill_attention`, which
+copies a tile's blocks out of the row's context and shows each token its
+own: a union never leaks a block to a token that did not choose it, and a
+tile of padding is not computed. In the dense regime a tile lists every block
+it can see: the same kernel. :func:`attend_selected` is the reference
+formulation the tests hold that kernel to: the selection as a (query, block)
+mask inside a loop over EVERY key (selecting IS masking; the dense FLOPs, its
+float32 scores in HBM), which the prefill program ran until PR 49.
 """
 
 from __future__ import annotations
@@ -49,8 +59,11 @@ import math
 import jax
 import jax.numpy as jnp
 
+from .paged_attention import _BLOCKS_A_STEP as _ROUND, _LIST_LANES
+
 __all__ = ["SparseSpec", "compress_keys", "select_blocks", "block_mask",
-           "attend_selected", "attend_blocks_gather"]
+           "tile_tokens", "tile_lists", "attend_selected",
+           "attend_blocks_gather"]
 
 _MASKED = -1e30  # as ops/paged_attention.py: exp() underflows to exactly 0
 
@@ -162,10 +175,70 @@ def block_mask(idx, taken, n_blocks: int):
     return hit.any(axis=1)
 
 
+#: rows of a prefill tile's matmuls, its tokens x the group's heads: two
+#: passes of the MXU's 128 rows. At the MiniCPM-SALA cell's chunk (a group of
+#: 16) tiles of 8 / 16 / 32 tokens read 1.41 / 1.40 / 1.50 ms a layer with
+#: their lists where neighbours choose apart and 0.50 / 0.33 / 0.27 where
+#: they choose alike (tools/sparse_attend_step.py; PERF.md PR 49): fewer
+#: rows copy a block once for fewer tokens, more rows meet a wider union
+_TILE_ROWS = 256
+
+
+def tile_tokens(T: int, group: int) -> int:
+    """Tokens a prefill tile of a chunk of ``T`` queries holds: as many as
+    fill ``_TILE_ROWS`` rows with the group's heads, at most the 32 bits of a
+    word, a divisor of ``T``."""
+    return math.gcd(T, max(1, min(32, _TILE_ROWS // group)))
+
+
+def tile_lists(mask, q_pos, valid, block: int, tq: int):
+    """What :func:`~marlin_tpu.ops.paged_attention.sparse_prefill_attention`
+    walks: ``mask`` (kvh, T, NB) the blocks each query took
+    (:func:`block_mask`; all ones for a query below ``dense_len``), queries
+    at ``q_pos`` (T,), ``valid`` (T,) false for padding. A query counts only
+    the blocks it can see (``<=`` its own), padding counts none. Tile ``i``
+    (queries ``i * tq ..``) of a KV head lists the blocks ANY of its tokens
+    took, ascending. Returns ``(lists, rounds, words, met, taken)``:
+    ``lists`` (kvh, T / tq, S) int32 with ``S`` whole rows of ``_LIST_LANES``
+    entries, block 0 past a list's end; ``rounds`` (kvh, T / tq) the
+    ``_ROUND`` blocks' rounds that hold a list (0: a tile of padding);
+    ``words`` (kvh, T / tq, S) int32, bit ``t`` of entry ``s`` set iff the
+    tile's ``t``-th token took block ``lists[.., s]`` (0 past the end);
+    ``met`` the blocks the tiles list and ``taken`` the blocks their tokens
+    took, summed over tiles and heads: ``taken / met`` is how many tokens of
+    a tile share a block it copies."""
+    kvh, T, nb = mask.shape
+    b_idx = jnp.arange(nb, dtype=jnp.int32)
+    mine = mask & (valid[:, None]
+                   & (b_idx[None, :] <= (q_pos // block)[:, None]))[None]
+    bits = jnp.left_shift(jnp.uint32(1), jnp.arange(tq, dtype=jnp.uint32))
+    words = jnp.sum(jnp.where(mine.reshape(kvh, T // tq, tq, nb),
+                              bits[None, None, :, None], jnp.uint32(0)),
+                    axis=2, dtype=jnp.uint32)
+    listed = words != 0
+    # a listed block's place in its tile's list: the listed blocks before it
+    # (a triangular matmul; counts to NB are exact), then entry s of a list
+    # is the one block whose place is s: no sort, no gather
+    place = jnp.einsum("htb,bc->htc", listed.astype(jnp.bfloat16),
+                       jnp.triu(jnp.ones((nb, nb), jnp.bfloat16), 1),
+                       preferred_element_type=jnp.float32).astype(jnp.int32)
+    S = -(-nb // _LIST_LANES) * _LIST_LANES
+    here = listed[:, :, None, :] & (
+        place[:, :, None, :] == jnp.arange(S, dtype=jnp.int32)[:, None])
+    lists = jnp.sum(jnp.where(here, b_idx, 0), axis=-1, dtype=jnp.int32)
+    words = jnp.sum(jnp.where(here, jax.lax.bitcast_convert_type(
+        words, jnp.int32)[:, :, None, :], 0), axis=-1, dtype=jnp.int32)
+    count = listed.sum(axis=-1, dtype=jnp.int32)
+    return (lists, -(-count // _ROUND), words, count.sum(),
+            mine.sum(dtype=jnp.int32))
+
+
 def attend_selected(q, k, v, q_pos, mask, block: int, kv_block: int):
-    """Prefill's attention of one KV head's group over the blocks each query
-    selected: ``q`` (T, g, dh) at ``q_pos``; ``k``, ``v`` (L, dh), key ``j``
-    at position ``j``; ``mask`` (T, L / block) from :func:`block_mask`. Key
+    """The reference formulation of prefill's tile walk (no program calls
+    it): the attention of one KV head's group over the blocks each query
+    selected, as a mask over every key. ``q`` (T, g, dh) at ``q_pos``;
+    ``k``, ``v`` (L, dh), key ``j`` at position ``j``; ``mask`` (T, L /
+    block) from :func:`block_mask`. Key
     ``j`` is visible to query ``i`` iff ``j <= q_pos[i]`` and the query took
     block ``j // block``. The keys are met ``kv_block`` at a time with a
     running softmax, and a key block that begins after the last query is

@@ -2616,17 +2616,25 @@ class ServeEngine:
     @staticmethod
     def _moe_counts(span, counts) -> None:
         """A ModelSpec's programs return, after the tokens, what their
-        expert layers counted (summed over layers): set on the span in which
-        the result landed — the dispatch's own span has closed by then, and
-        a span's fields cannot be set afterwards. Read only while a trace is
+        expert layers counted (summed over layers) and, a prefill chunk of a
+        model with sparse layers, after those the blocks its tiles of
+        queries met and the blocks their tokens took
+        (``hybrid._attend_sparse_chunk``): set on the span in which the
+        result landed — the dispatch's own span has closed by then, and a
+        span's fields cannot be set afterwards. Read only while a trace is
         taken; the arrays have landed with the tokens."""
         if counts is not None and span.is_enabled():
-            # analyze: ignore[host-sync] — three ints that rode back with
+            # analyze: ignore[host-sync] — a few ints that rode back with
             # the tokens the caller has just synced on
-            assigned, local, touched = (int(c) for c in np.asarray(counts))
+            assigned, local, touched, *walked = (
+                int(c) for c in np.asarray(counts))
             span.set_metadata(moe_assignments=assigned,
                               moe_local_assignments=local,
                               moe_experts_touched=touched)
+            if walked:
+                met, taken = walked
+                span.set_metadata(sparse_blocks_met=met,
+                                  sparse_blocks_taken=taken)
 
     def _leave_paged(self, pool, pools, launch: _Launch) -> None:
         """Free the slots of the rows whose step budget ends with ``launch``,

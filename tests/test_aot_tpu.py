@@ -1108,6 +1108,37 @@ def test_block_walk_kernel_mosaic_compiles_at_the_sala_cells_shapes(one_chip,
     assert "tpu_custom_call" in f.as_text()
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_tile_walk_kernel_mosaic_compiles_at_the_sala_cells_shapes(one_chip,
+                                                                   dtype):
+    """``serve.minicpm-sala-longdoc16``'s sparse prefill call: a chunk of 512
+    queries in 32 tiles of 16 tokens (256 query rows a KV head), each (tile,
+    KV head) a list of up to 544 blocks of 64 tokens in rounds of 16 (640
+    entries: five rows of words), over a context of 34 816 keys left in HBM;
+    the scoped VMEM holds four rounds of K and V (2 MB in bfloat16), the
+    spread constant (2 MB) and a round's (256, 1024) score tiles. The lists
+    are scalar-prefetched: 64 x 640 words of SMEM."""
+    from marlin_tpu.ops.paged_attention import _sparse_prefill_attention_call
+
+    def st(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    T, kvh, group, dh, keys, tiles, S = 512, 2, 16, 128, 34816, 32, 640
+    ctx = st((keys, kvh * dh), dtype)
+    # float32 as the f32 checks run it, under "highest": the kernel's own
+    # bfloat16 matmul (the tokens' bits) must not inherit that
+    with jax.default_matmul_precision(
+            "highest" if dtype == jnp.float32 else "default"):
+        c = _sparse_prefill_attention_call.trace(
+            st((T, kvh, group, dh), dtype), ctx, ctx, st((T,)),
+            st((kvh, tiles, S)), st((kvh, tiles)), st((kvh, tiles, S)),
+            block=64, interpret=False).lower().compile()
+    assert "tpu_custom_call" in c.as_text()
+    query = T * kvh * group * dh * jnp.dtype(dtype).itemsize
+    assert c.memory_analysis().temp_size_in_bytes <= 4 * query
+
+
 def test_lightning_update_kernel_mosaic_compiles_in_place_at_published_widths(
         one_chip):
     """The decode state update of a lightning layer (``ops/lightning.py``) at

@@ -3,7 +3,8 @@ a SELECTION of a row's blocks, chosen on the device by a score over
 mean-pooled keys kept beside the pages, one such layer to every few lightning
 layers (``tests/test_lightning.py`` has those). Here: the selection against
 the plain reference's literal steps, the block-walk decode kernel against
-gathered blocks, the paged programs against the reference
+gathered blocks, prefill's tile-walk kernel against the mask over every key,
+the paged programs against the reference
 (``benchmarks/reference/serve_minicpmsala.py``) across ``dense_len``, chunk,
 page, block and pooling-window edges, a second row that enters from the first
 row's pages and snapshot, each flaw the comparison must catch, and the engine
@@ -27,8 +28,9 @@ from benchmarks.trace_reduce import find_xplane
 from marlin_tpu.models import hybrid
 from marlin_tpu.models.transformer import (init_kv_pages, lm_decode_paged,
                                            lm_prefill_paged)
-from marlin_tpu.ops import sparse_attention
-from marlin_tpu.ops.paged_attention import paged_decode_attention_blocks
+from marlin_tpu.ops import paged_attention, sparse_attention
+from marlin_tpu.ops.paged_attention import (paged_decode_attention_blocks,
+                                            sparse_prefill_attention)
 from marlin_tpu.serving import Request, ServeEngine
 
 PAGE, CHUNK = 8, 16
@@ -338,6 +340,156 @@ def test_the_block_walk_kernel_is_the_gathered_blocks_attention(dtype, S):
                                np.asarray(want, np.float32), atol=tol)
 
 
+# the prefill kernel ----------------------------------------------------------
+
+
+_SP = sparse_attention.SparseSpec(stride=2, block=8, topk=6, init_blocks=1,
+                                  window=16, dense_len=40)
+#: name: (tile tokens, first position, tokens that are not padding, blocks of
+#: the context, dtype, the free blocks: "chosen" by the selection, or drawn
+#: by hand so that a tile's tokens share "none" or "all" of them)
+TILE_CASES = {
+    "tq2": (2, 100, 32, 20, "float32", "chosen"),
+    "tq4": (4, 100, 32, 20, "float32", "chosen"),
+    "tq8": (8, 100, 32, 20, "float32", "chosen"),
+    "tq16": (16, 100, 32, 20, "float32", "chosen"),
+    "tq32": (32, 100, 32, 20, "float32", "chosen"),
+    "wholly_below_dense_len": (8, 0, 32, 20, "float32", "chosen"),
+    "straddles_dense_len": (8, 24, 32, 20, "float32", "chosen"),
+    "last_tiles_hold_no_token": (4, 100, 13, 20, "float32", "chosen"),
+    "a_tile_of_padding_between_tokens": (4, 100, -8, 20, "float32", "chosen"),
+    "tokens_share_no_free_block": (4, 100, 32, 20, "float32", "none"),
+    "tokens_share_every_free_block": (8, 100, 32, 20, "float32", "all"),
+    "context_not_whole_rounds": (8, 130, 30, 21, "float32", "chosen"),
+    "bfloat16": (8, 100, 27, 20, "bfloat16", "chosen"),
+}
+
+
+def _tile_case(name: str, T: int = 32, kvh: int = 2, g: int = 2,
+               dh: int = 16):
+    """A chunk of ``T`` queries from ``start`` over a context of ``nb``
+    blocks, the two KV heads' selections drawn apart; ``tokens`` < 0: the
+    SECOND eight rows are the padding."""
+    tq, start, tokens, nb, dtype, free = TILE_CASES[name]
+    sp, rng = _SP, np.random.default_rng(len(name))
+    L, dt = nb * sp.block, jnp.dtype(dtype)
+    q = jnp.asarray(rng.normal(size=(T, kvh, g, dh)) * 2, dt)
+    k, v = (jnp.asarray(rng.normal(size=(L, kvh * dh)) * s, dt)
+            for s in (2, 1))
+    q_pos = start + jnp.arange(T)
+    valid = np.arange(T) < tokens if tokens > 0 else \
+        (np.arange(T) < 8) | (np.arange(T) >= 16)
+    own = np.asarray(q_pos) // sp.block
+    masks = []
+    for h in range(kvh):
+        if free == "chosen":
+            ext = jnp.concatenate([jnp.zeros((sp.stride, dh), dt),
+                                   k.reshape(L, kvh, dh)[:, h]])
+            idx, taken = sparse_attention.select_blocks(
+                q[:, h], sparse_attention.compress_keys(ext, sp.stride),
+                q_pos, sp)
+            masks.append(np.asarray(sparse_attention.block_mask(idx, taken,
+                                                                nb)))
+            continue
+        b = np.arange(nb)
+        m = (b[None] == 0) | ((b[None] > own[:, None] - sp.window_blocks)
+                              & (b[None] <= own[:, None]))
+        for t in range(T):   # three free blocks a token, of blocks 1..9
+            pick = [1 + h, 4 + h, 7 + h] if free == "all" else \
+                [1 + (3 * (t % tq) + j + h) % 9 for j in range(3)]
+            m[t, pick] = True
+        masks.append(m)
+    return q, k, v, q_pos, jnp.asarray(valid), jnp.asarray(np.stack(masks)), tq
+
+
+def _masked_attention(q, k, v, q_pos, mask):
+    L, kvh = k.shape[0], q.shape[1]
+    heads = lambda x: x.reshape(L, kvh, -1)  # noqa: E731
+    return np.asarray(jnp.stack([sparse_attention.attend_selected(
+        q[:, h], heads(k)[:, h], heads(v)[:, h], q_pos, mask[h], _SP.block, 24)
+        for h in range(kvh)], axis=1).astype(jnp.float32))
+
+
+@pytest.mark.parametrize("case", TILE_CASES)
+def test_the_tile_walk_kernel_is_the_masked_attention(case):
+    """Prefill's kernel meets the UNION of a tile's tokens' blocks and shows
+    each token its own: the mask over every key
+    (:func:`sparse_attention.attend_selected`), for every tile size the code
+    may pick, in both regimes and across ``dense_len``, with tiles (trailing
+    or not) that hold no token, unions of one block and of every block,
+    a context that is not whole rounds of 16 blocks, lists that differ
+    between the KV heads; a row of padding is zeros."""
+    q, k, v, q_pos, valid, mask, tq = _tile_case(case)
+    lists, rounds, words, met, taken = sparse_attention.tile_lists(
+        mask, q_pos, valid, _SP.block, tq)
+    got = np.asarray(sparse_prefill_attention(
+        q, k, v, q_pos, lists, rounds, words, _SP.block).astype(jnp.float32))
+    want = _masked_attention(q, k, v, q_pos, mask)
+    live = np.asarray(valid)
+    tol = TIGHT if q.dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(got[live], want[live], atol=tol)
+    assert not got[~live].any()
+    # the counters: what the tokens took (the blocks they can see), and the
+    # unions the tiles list, KV head by KV head
+    seen = np.asarray(mask) & live[None, :, None] & (
+        np.arange(mask.shape[2])[None, None, :]
+        <= (np.asarray(q_pos) // _SP.block)[None, :, None])
+    union = seen.reshape(2, -1, tq, mask.shape[2]).any(axis=2)
+    assert int(taken) == seen.sum() and int(met) == union.sum()
+    np.testing.assert_array_equal(np.asarray(rounds), -(-union.sum(-1) // 16))
+    # the KV heads chose apart: another list, or other tokens a block
+    assert case == "wholly_below_dense_len" or any(
+        (np.asarray(x)[0] != np.asarray(x)[1]).any() for x in (lists, words))
+    if case == "tokens_share_every_free_block":
+        assert int(taken) > 5 * int(met)
+    if case == "tokens_share_no_free_block":   # 3 + 3 tq of them a tile
+        assert union[:, 0].sum(-1).min() >= 3 + 9
+
+
+_tile_lists = sparse_attention.tile_lists   # the flaws below replace it
+
+
+def _every_tile_mate_sees_it(*args):
+    lists, rounds, words, met, taken = _tile_lists(*args)
+    return lists, rounds, jnp.where(words != 0, -1, 0), met, taken
+
+
+def _no_step_is_passed_over(rounds):
+    return jnp.arange(1, rounds.shape[0] + 2, dtype=jnp.int32).at[0].set(0)
+
+
+@pytest.mark.parametrize("flaw", ["a_tile_mates_block_is_seen",
+                                  "a_tile_of_padding_is_met_by_a_live_row"])
+def test_the_tile_walk_kernel_catches_its_flaws(flaw, monkeypatch):
+    """The two flaws the tiled form invites, each far outside the agreement
+    above: a token that sees a block only its tile-mate took, and the round
+    of a tile that holds no token landing in a live tile's softmax (the
+    stream of copies not passing over the grid step without a round)."""
+    q, k, v, q_pos, valid, mask, tq = _tile_case(
+        "a_tile_of_padding_between_tokens")
+    args = (mask, q_pos, valid, _SP.block, tq)
+    call = paged_attention._sparse_prefill_attention_call
+    if flaw == "a_tile_mates_block_is_seen":
+        lists, rounds, words, _, _ = _every_tile_mate_sees_it(*args)
+    else:
+        lists, rounds, words, _, _ = sparse_attention.tile_lists(
+            mask, q_pos, jnp.ones_like(valid), _SP.block, tq)
+        dead = ~np.asarray(valid).reshape(-1, tq).any(axis=1)
+        rounds = jnp.where(dead[None], 0, rounds)
+        monkeypatch.setattr(paged_attention, "_next_with_a_round",
+                            _no_step_is_passed_over)
+        call.clear_cache()
+    try:
+        got = np.asarray(sparse_prefill_attention(
+            q, k, v, q_pos, lists, rounds, words, _SP.block))
+    finally:
+        monkeypatch.undo()
+        call.clear_cache()
+    live = np.asarray(valid)
+    want = _masked_attention(q, k, v, q_pos, mask)
+    assert np.abs(got - want)[live].max() > CAUGHT, flaw
+
+
 # the programs against the reference ------------------------------------------
 
 
@@ -450,11 +602,23 @@ def _tamper_sparse(spec, fn):
     return between
 
 
+def _padding_met_by_the_last(mask, q_pos, valid, block, tq):
+    """The flaw of a tile past the length computed INTO a valid row: the
+    rows of padding keep their tiles, and what they took the last token of
+    the prompt takes too."""
+    last = jnp.argmin(valid) - 1          # valid is a prefix of the chunk
+    padding = (mask & ~valid[None, :, None]).any(axis=1)        # (kvh, NB)
+    mask = mask.at[:, last].set(jnp.where(valid.all(), mask[:, last],
+                                          mask[:, last] | padding))
+    return _tile_lists(mask, q_pos, valid, block, tq)
+
+
 FLAWS = ["selection_dropped", "forced_blocks_left_out",
          "forced_blocks_outside_the_topk", "group_summed_before_the_softmax",
          "windows_not_overlapping", "rope_in_the_sparse_layer",
          "branch_scale_of_the_held_depth", "qk_norm_gain_lost",
-         "gate_dropped", "entries_not_written_in_decode"]
+         "gate_dropped", "entries_not_written_in_decode",
+         "a_tile_mates_block_is_seen", "a_tile_past_the_length_is_met"]
 
 
 @pytest.mark.parametrize("flaw", FLAWS)
@@ -498,6 +662,16 @@ def test_each_flaw_fails_the_comparison(flaw, model, monkeypatch):
         kw["steps"] = 30   # far enough that a stale entry is in reach
         kw["decode"] = fresh_decode(monkeypatch, hybrid, "_complete_entries",
                                     lambda pk, pc, *a: pc)
+    elif flaw == "a_tile_mates_block_is_seen":   # a tile's union for all
+        kw["prefill"] = fresh_prefill(monkeypatch, sparse_attention,
+                                      "tile_lists", _every_tile_mate_sees_it)
+    elif flaw == "a_tile_past_the_length_is_met":
+        # tiles of four tokens; the last chunk's (five tokens of sixteen)
+        # two tiles of padding are listed like the others, and what their
+        # tokens took is shown to the prompt's last token
+        monkeypatch.setattr(sparse_attention, "tile_tokens", lambda T, g: 4)
+        kw["prefill"] = fresh_prefill(monkeypatch, sparse_attention,
+                                      "tile_lists", _padding_met_by_the_last)
     with jax.default_matmul_precision("highest"):
         toks, served, _ = serve_one(spec, params, prompt_of(101),
                                     kw.pop("steps", 8), **kw)
@@ -628,6 +802,17 @@ def test_the_spans_and_records_carry_the_sparse_counters(served, model):
         assert f["sparse_rows"] == rows
         assert f["sparse_blocks_attended"] == rows * 6 * 4
         assert rows * 9 * 4 <= f["sparse_blocks_held"] <= rows * 13 * 4
+    # a final chunk's landing carries what its tiles met and what their
+    # tokens took, summed over the 2 KV heads of the 2 sparse layers: every
+    # question lies past dense_len (6 blocks a token, the n tokens of a
+    # question's LAST chunk 6 n x 4), and a tile lists a block once however
+    # many of its tokens took it
+    firsts = [f for f in by["serve.prefill.sync"] if f.get("final")]
+    assert len(firsts) == len(SESSIONS)
+    assert all(0 < f["sparse_blocks_met"] <= f["sparse_blocks_taken"]
+               for f in firsts)
+    assert sorted(f["sparse_blocks_taken"] for f in firsts) == sorted(
+        24 * ((turn - 1) % CHUNK + 1) for _, turn, _ in SESSIONS)
     # the log= records carry the same counts, one a landed call
     assert sum(f["sparse_blocks_held"] for f in syncs) == sum(
         r["blocks_held"] for r in served["records"])

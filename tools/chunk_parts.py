@@ -8,7 +8,9 @@ only; runs where the trace lies, on the chip's machine or here).
 
 (both in one ``chiprun`` call: the trace does not come back). Arguments: an
 ``.xplane.pb`` or the directory that holds one, then regular expressions
-over the traced programs' names (default ``prefill`` and ``decode``).
+over the traced programs' names (default ``prefill`` and ``decode``);
+``--levels=N`` among them keeps ``N`` levels of a scope's path (default 4: a
+scope inside a loop's ``while/body/closed_call`` needs 6).
 
 For each pattern one JSON line (the executions inside the capture, their
 median and mean device time, the sum of the parts) and two tables in
@@ -50,17 +52,19 @@ def self_seconds(ops):
     return out
 
 
-def bucket(scope: str) -> str:
+def bucket(scope: str, levels: int = 4) -> str:
     parts = [p for p in scope.split("/") if not p.startswith("jit(")]
     keep = [re.sub(r"\d+", "#", p) for p in parts[:-1]]
-    return "/".join(keep[:4]) or "(none)"
+    return "/".join(keep[:levels]) or "(none)"
 
 
 def main(argv):
     path = argv[0]
     if os.path.isdir(path):
         path = tr.find_xplane(path)
-    patterns = argv[1:] or ["prefill", "decode"]
+    levels = [int(a.split("=")[1]) for a in argv if a.startswith("--levels=")]
+    patterns = [a for a in argv[1:] if not a.startswith("--levels=")] \
+        or ["prefill", "decode"]
     trace = tr.load(path)
     dev = trace.devices[0]
     scopes = op_scopes(path)
@@ -81,7 +85,7 @@ def main(argv):
         by_op, by_scope = collections.Counter(), collections.Counter()
         for ev, s in self_seconds(ops):
             by_op[tr.label(ev) + "  @" + scopes.get(ev.name, "")[-70:]] += s
-            by_scope[bucket(scopes.get(ev.name, ""))] += s
+            by_scope[bucket(scopes.get(ev.name, ""), *levels)] += s
         names = collections.Counter(tr.module_short(m.name) for m in mods)
         print(json.dumps({
             "pattern": pat, "executions": n, "modules": names,

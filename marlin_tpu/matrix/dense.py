@@ -293,7 +293,8 @@ class DenseMatrix(DistributedMatrix):
 
             strategy = best_strategy(self, other, precision=precision)
 
-        from ..parallel.matmul import matmul_padded
+        from ..parallel.matmul import plan_padded
+        from ..utils.tracing import annotate
 
         if isinstance(other, DenseMatrix):
             b_pad, (kb, n) = other.data, other.shape
@@ -311,7 +312,7 @@ class DenseMatrix(DistributedMatrix):
 
         # fused single-dispatch path: padded operands in, padded+sharded
         # result out — no host-side pad/placement, no from_array round-trip
-        c_pad = matmul_padded(
+        plan = plan_padded(
             self.data,
             b_pad,
             (m, k, n),
@@ -322,7 +323,15 @@ class DenseMatrix(DistributedMatrix):
             broadcast_threshold_mb=broadcast_threshold_mb,
             precision=precision,
         )
-        if c_pad is not None:
+        if plan is not None:
+            with annotate(
+                "matmul.dispatch",
+                strategy=plan.strategy,
+                split="x".join(map(str, plan.split or ())),
+                program=plan.program,
+                moved_bytes=plan.moved_bytes,
+            ):
+                c_pad = plan.fn(self.data, b_pad)
             return klass(c_pad, (m, n), self.mesh, out_spec)
 
         # legacy logical-array path (ring, or an RMM split over a device subset)

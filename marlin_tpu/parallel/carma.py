@@ -7,8 +7,25 @@ the core budget is spent (utils/MTUtils.scala:139-175, citing the CARMA paper
 IPDPS'13; ``dimToSplit`` MTUtils.scala:204-213). Here the same heuristic picks
 the shape of the 3-D device mesh used by :func:`marlin_tpu.parallel.rmm_matmul`
 — i.e. it decides how many mesh slots each of m/k/n gets, which in turn decides
-which ICI collectives XLA inserts (a k-split becomes a psum/reduce-scatter; an
-m- or n-split is collective-free).
+what crosses the ICI and WHEN:
+
+- an m- or n-split moves OPERAND bytes. Of two row-sharded operands
+  (``P("rows", None)``, a ``DenseVecMatrix``) an m-split leaves A where it
+  lies, and an n-split fetches the rows of B's column panel that the chip does
+  not hold. Those bytes exist before the dot starts, so they can travel under
+  it: :func:`marlin_tpu.parallel.ring.ring_local` sends a panel of B along
+  ``rows`` while the panel before it is multiplied, and a panel is hidden once
+  a step's dot outlasts its transfer (on a v5e at ``precision="high"``: about
+  2,800 rows of A a chip and more; smaller products are the broadcast
+  strategy's).
+- a k-split moves RESULT-sized partial products (a psum / reduce-scatter over
+  ``k``), which do not exist until the dot has ended: that collective is
+  exposed whatever the schedule.
+
+So a tie between the sides goes to m, then n, and the contraction is split
+only where it is strictly the longest remaining side (the reference's
+``dimToSplit`` breaks the tie m, k, n: on Spark every split is a shuffle and
+the order is immaterial).
 """
 
 from __future__ import annotations
@@ -16,9 +33,11 @@ from __future__ import annotations
 
 def dim_to_split(m: float, k: float, n: float) -> int:
     """Index (0=m, 1=k, 2=n) of the largest current per-shard dimension —
-    the dimension whose split saves the most communication (MTUtils.scala:204-213)."""
+    the dimension whose split saves the most communication
+    (MTUtils.scala:204-213). A tie goes to m, then n, then k: see the module
+    docstring."""
     dims = (m, k, n)
-    return max(range(3), key=lambda i: dims[i])
+    return max((0, 2, 1), key=lambda i: dims[i])
 
 
 def split_method(m: int, k: int, n: int, parallelism: int) -> tuple[int, int, int]:

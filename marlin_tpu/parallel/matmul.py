@@ -26,6 +26,19 @@ dynamic shuffles:
   "RMMv2 vs RMMv3" competition (examples/RMMcompare.scala:13-16) resolved by
   the compiler per shape.
 
+What an RMM split moves, for the row-sharded operands a ``DenseVecMatrix``
+holds (``P("rows", None)``: chip (r, c) has A[r, :] and B[k_r, :]): an m-split
+moves nothing of A; an n-split fetches the rows of B[:, n_c] the chip lacks;
+a k-split re-places an operand AND sums result-sized partials after the dot.
+Operand bytes can travel under the dot, partial products cannot (see
+``parallel/carma.py``). So where the split is (rows, 1, cols), the caller's
+whole mesh, and both operands arrive row-sharded, the fused program is a ring
+along ``rows`` over B's column panels (``ring2d``,
+:func:`marlin_tpu.parallel.ring.ring_local`): no reshard, no reduction, one
+panel of B in flight under the dot of the panel before it. Every other
+layout or split (a ``BlockMatrix`` operand, a k-split, a subset mesh) runs
+the 3-D mesh program with its ``psum``.
+
 All functions take/return *logical* (unpadded) arrays; shard-divisibility
 padding happens inside the jitted program and is sliced off before returning.
 """
@@ -33,6 +46,7 @@ padding happens inside the jitted program and is sliced off before returning.
 from __future__ import annotations
 
 import functools
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -42,6 +56,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..config import get_config
 from ..mesh import default_mesh, pad_to_multiple
 from .carma import split_method
+from .ring import ring_local, ring_matmul
 
 _M, _K, _N = "m", "k", "n"
 
@@ -127,8 +142,8 @@ def rmm_matmul(
     if (kp, np_) != (k, n):
         b = jnp.pad(b, ((0, kp - k), (0, np_ - n)))
     # place operands on the 3-D mesh (may be a device subset when the CARMA
-    # split doesn't fill the device count); shard_map then runs collective-free
-    # along m/n and psums along k.
+    # split doesn't fill the device count): the placement moves what of its
+    # blocks a device lacks; shard_map then psums along k.
     a = jax.device_put(a, NamedSharding(mesh3, P(_M, _K)))
     b = jax.device_put(b, NamedSharding(mesh3, P(_K, _N)))
     fn = _rmm_fn(mesh3, _resolve_precision(precision), accum_dtype or a.dtype)
@@ -232,7 +247,7 @@ def _resolve_strategy(
 
 @functools.lru_cache(maxsize=128)
 def _fused_fn(
-    strategy: str,
+    program: str,
     mkn: tuple[int, int, int],
     out_pad: tuple[int, int],
     out_sharding: NamedSharding,
@@ -257,7 +272,39 @@ def _fused_fn(
         c = jnp.pad(c, ((0, mp_out - m), (0, np_out - n)))
         return jax.lax.with_sharding_constraint(c, out_sharding)
 
-    if strategy == "rmm":
+    def _placed(x_pad, logical, padded, sharding):
+        """The logical extent of ``x_pad``, zero-padded to the program's own
+        grid and laid out as the program takes it."""
+        (r, c), (rp, cp) = logical, padded
+        x = jnp.pad(x_pad[:r, :c], ((0, rp - r), (0, cp - c)))
+        return jax.lax.with_sharding_constraint(x, sharding)
+
+    if program == "ring2d":
+        # the (rows, 1, cols) split on the caller's own mesh: both operands
+        # stay where they lie and B's column panels ride the ring along rows
+        mesh = out_sharding.mesh
+        rows, cols = mesh.axis_names
+        pm, pn = mesh.shape[rows], mesh.shape[cols]
+        mp_r, kp_r, np_r = (
+            pad_to_multiple(m, pm), pad_to_multiple(k, pm), pad_to_multiple(n, pn)
+        )
+        held = NamedSharding(mesh, P(rows, None))
+        local = ring_local(rows, precision, accum_dtype, col_axis=cols)
+
+        @jax.jit
+        def f(a_pad, b_pad):
+            a = _placed(a_pad, (m, k), (mp_r, kp_r), held)
+            b = _placed(b_pad, (k, n), (kp_r, np_r), held)
+            c = jax.shard_map(
+                local, mesh=mesh,
+                in_specs=(P(rows, None), P(rows, None)),
+                out_specs=P(rows, cols),
+            )(a, b)
+            return _finish(c[:m, :n])
+
+        return f
+
+    if program == "rmm":
         pm, pk, pn = (mesh3.shape[_M], mesh3.shape[_K], mesh3.shape[_N])
         mp_r, kp_r, np_r = (
             pad_to_multiple(m, pm), pad_to_multiple(k, pk), pad_to_multiple(n, pn)
@@ -272,10 +319,8 @@ def _fused_fn(
 
         @jax.jit
         def f(a_pad, b_pad):
-            a = jnp.pad(a_pad[:m, :k], ((0, mp_r - m), (0, kp_r - k)))
-            b = jnp.pad(b_pad[:k, :n], ((0, kp_r - k), (0, np_r - n)))
-            a = jax.lax.with_sharding_constraint(a, sh_a)
-            b = jax.lax.with_sharding_constraint(b, sh_b)
+            a = _placed(a_pad, (m, k), (mp_r, kp_r), sh_a)
+            b = _placed(b_pad, (k, n), (kp_r, np_r), sh_b)
             c = jax.shard_map(
                 local, mesh=mesh3,
                 in_specs=(P(_M, _K), P(_K, _N)), out_specs=P(_M, _N),
@@ -284,7 +329,7 @@ def _fused_fn(
 
         return f
 
-    if strategy in ("broadcast", "broadcast_a"):
+    if program == "broadcast":
         repl = NamedSharding(out_sharding.mesh, P())
 
         @jax.jit
@@ -310,6 +355,147 @@ def _fused_fn(
     return f
 
 
+class FusedPlan(NamedTuple):
+    """What the dispatch decided for one multiply: the resolved ``strategy``,
+    the (m, k, n) ``split`` (``None`` where the strategy has none), the fused
+    ``program`` that runs it (``ring2d`` / ``rmm`` / ``broadcast`` /
+    ``gspmd``), ``moved_bytes`` (what the plan sends the chip that receives
+    most: the blocks it needs and lacks, and its share of a sum over k; -1
+    for ``gspmd``, whose schedule is the compiler's) and the jitted program."""
+
+    strategy: str
+    split: tuple[int, int, int] | None
+    program: str
+    moved_bytes: int
+    fn: Callable
+
+
+def _layout(x, mesh: Mesh):
+    """``(sharding, shape)`` as ``x`` arrives. A traced operand shows no
+    sharding: it is taken as row-sharded over the caller's mesh (its rows
+    padded to divide it), which is what the program it gets then asks for by
+    its own sharding constraint."""
+    sh = getattr(x, "sharding", None)
+    if sh is not None:
+        return sh, tuple(x.shape)
+    rows = mesh.axis_names[0]
+    return (NamedSharding(mesh, P(rows, None)),
+            (pad_to_multiple(x.shape[0], mesh.shape[rows]), x.shape[1]))
+
+
+def _held_rows(have, mesh: Mesh) -> bool:
+    """``P(rows, None)`` over the caller's mesh: what a ``DenseVecMatrix`` holds."""
+    return have[0].is_equivalent_to(
+        NamedSharding(mesh, P(mesh.axis_names[0], None)), 2)
+
+
+def _lacking_bytes(have, want: NamedSharding, want_shape, itemsize) -> int:
+    """Bytes of its block under ``want`` that the worst-off device does not
+    hold of an operand lying as ``have`` (index rectangles intersected)."""
+    have, shape = have
+    haves = have.devices_indices_map(shape)
+    worst = 0
+    nothing = (slice(0, 0),) * len(shape)  # a device the operand is not on
+    for dev, w in want.devices_indices_map(tuple(want_shape)).items():
+        h = haves.get(dev, nothing)
+        block = held = 1
+        for d, size in enumerate(want_shape):
+            lo, hi, _ = w[d].indices(size)
+            hlo, hhi, _ = h[d].indices(shape[d])
+            block *= hi - lo
+            held *= max(0, min(hi, hhi) - max(lo, hlo))
+        worst = max(worst, block - held)
+    return worst * itemsize
+
+
+@functools.lru_cache(maxsize=128)
+def _plan(strategy, split, mkn, out_pad, out_sharding, precision, accum_dtype,
+          itemsize, a_have, b_have) -> FusedPlan | None:
+    m, k, n = mkn
+    mesh = out_sharding.mesh
+    devs = list(mesh.devices.flat)
+    mesh3, program, which = None, strategy, "b"
+    if strategy == "rmm":
+        if split is None:
+            split = split_method(m, k, n, len(devs))
+        pm, pk, pn = split
+        if pm * pk * pn != len(devs):
+            return None  # subset mesh — not expressible in one executable
+        mp_r, kp_r, np_r = (
+            pad_to_multiple(m, pm), pad_to_multiple(k, pk), pad_to_multiple(n, pn)
+        )
+        if (
+            len(devs) > 1
+            and len(mesh.axis_names) == 2
+            and split == (mesh.devices.shape[0], 1, mesh.devices.shape[1])
+            and _held_rows(a_have, mesh)
+            and _held_rows(b_have, mesh)
+        ):
+            program = "ring2d"
+            moved = (pm - 1) * (pad_to_multiple(k, pm) // pm) * (np_r // pn) * itemsize
+        else:
+            mesh3 = build_rmm_mesh(split, devs)
+            moved = (
+                _lacking_bytes(a_have, NamedSharding(mesh3, P(_M, _K)),
+                               (mp_r, kp_r), itemsize)
+                + _lacking_bytes(b_have, NamedSharding(mesh3, P(_K, _N)),
+                                 (kp_r, np_r), itemsize)
+                # the least a sum over k sends: all but its own share of the
+                # chip's partial block
+                + (mp_r // pm) * (np_r // pn) * (pk - 1) // pk
+                * jnp.dtype(accum_dtype).itemsize
+            )
+    elif strategy in ("broadcast", "broadcast_a"):
+        program, which = "broadcast", "a" if strategy == "broadcast_a" else "b"
+        have = a_have if which == "a" else b_have
+        moved = _lacking_bytes(have, NamedSharding(mesh, P()), have[1], itemsize)
+    else:
+        moved = -1
+    fn = _fused_fn(program, mkn, out_pad, out_sharding, precision, accum_dtype,
+                   mesh3, which)
+    return FusedPlan(strategy, split, program, moved, fn)
+
+
+def plan_padded(
+    a_pad: jax.Array,
+    b_pad: jax.Array,
+    mkn: tuple[int, int, int],
+    out_sharding: NamedSharding,
+    out_pad: tuple[int, int],
+    strategy: str = "auto",
+    split: tuple[int, int, int] | None = None,
+    broadcast_threshold_mb: float | None = None,
+    precision: str | None = None,
+    accum_dtype=None,
+) -> FusedPlan | None:
+    """Choose the fused program of one padded-in / padded-out multiply from
+    what can be seen of it: the shapes, the caller's mesh and the layouts the
+    two operands arrive in (see the module docstring).
+
+    Returns ``None`` when the requested configuration has no fused program
+    (an RMM split that doesn't fill the mesh — one XLA executable cannot span
+    two different device sets — or the ring strategy, which manages its own
+    placement); callers fall back to the legacy logical-array path."""
+    strategy = _resolve_strategy(
+        mkn, jnp.dtype(b_pad.dtype).itemsize, strategy, broadcast_threshold_mb
+    )
+    if strategy == "ring":
+        return None
+    mesh = out_sharding.mesh
+    return _plan(
+        strategy,
+        None if split is None else tuple(split),
+        tuple(mkn),
+        tuple(out_pad),
+        out_sharding,
+        _resolve_precision(precision),
+        accum_dtype or a_pad.dtype,
+        jnp.dtype(b_pad.dtype).itemsize,
+        _layout(a_pad, mesh),
+        _layout(b_pad, mesh),
+    )
+
+
 def matmul_padded(
     a_pad: jax.Array,
     b_pad: jax.Array,
@@ -327,39 +513,11 @@ def matmul_padded(
     ``a_pad``/``b_pad`` carry their matrices' zero-padded layouts; ``mkn`` is
     the logical (m, k, n). Returns the result already padded to ``out_pad`` and
     sharded as ``out_sharding`` — the caller can construct the result matrix
-    around it directly, with no further placement.
-
-    Returns ``None`` when the requested configuration has no fused program
-    (an RMM split that doesn't fill the mesh — one XLA executable cannot span
-    two different device sets — or the ring strategy, which manages its own
-    placement); callers fall back to the legacy logical-array path."""
-    m, k, n = mkn
-    strategy = _resolve_strategy(
-        mkn, jnp.dtype(b_pad.dtype).itemsize, strategy, broadcast_threshold_mb
-    )
-
-    mesh3 = None
-    if strategy == "rmm":
-        devs = list(out_sharding.mesh.devices.flat)
-        if split is None:
-            split = split_method(m, k, n, len(devs))
-        if split[0] * split[1] * split[2] != len(devs):
-            return None  # subset mesh — not expressible in one executable
-        mesh3 = build_rmm_mesh(split, devs)
-    elif strategy == "ring":
-        return None
-
-    fn = _fused_fn(
-        strategy,
-        (m, k, n),
-        out_pad,
-        out_sharding,
-        _resolve_precision(precision),
-        accum_dtype or a_pad.dtype,
-        mesh3,
-        "a" if strategy == "broadcast_a" else "b",
-    )
-    return fn(a_pad, b_pad)
+    around it directly, with no further placement — or ``None`` where
+    :func:`plan_padded` has no fused program."""
+    plan = plan_padded(a_pad, b_pad, mkn, out_sharding, out_pad, strategy, split,
+                       broadcast_threshold_mb, precision, accum_dtype)
+    return None if plan is None else plan.fn(a_pad, b_pad)
 
 
 def matmul(
@@ -400,8 +558,6 @@ def matmul(
     if strategy == "gspmd":
         return gspmd_matmul(a, b, out_sharding, precision, accum_dtype)
     if strategy == "ring":
-        from .ring import ring_matmul
-
         return ring_matmul(
             a, b, out_sharding.mesh, out_sharding.mesh.axis_names[0],
             precision, accum_dtype,

@@ -648,6 +648,64 @@ def test_rmm_20000_compiles_for_2x2_v5e(topo_mesh):
     assert c.memory_analysis().peak_memory_in_bytes < 15 * 1024**3
 
 
+def _compile_square_cell(topo_mesh, n, shape, **kw):
+    """A matmul cell's own program: ``matmul_padded`` of two row-sharded
+    ``(n, n)`` float32 operands at ``precision="high"`` on a ``shape`` mesh
+    of described chips, as ``DenseVecMatrix.multiply`` dispatches it."""
+    from marlin_tpu.parallel.matmul import matmul_padded
+
+    mesh = topo_mesh(("rows", "cols"), shape)
+    row = NamedSharding(mesh, P("rows", None))
+    out = NamedSharding(mesh, P("rows", "cols") if shape[1] > 1
+                        else P("rows", None))
+    a = jax.ShapeDtypeStruct((n, n), jnp.float32, sharding=row)
+    return jax.jit(lambda x, y: matmul_padded(
+        x, y, (n, n, n), out, (n, n), precision="high", **kw)) \
+        .trace(a, a).lower().compile()
+
+
+def test_square_mesh4_cell_rides_the_ring_on_2x2_v5e(topo_mesh):
+    """``matmul.square-mesh4``'s program (n = 36864, ``auto``): the CARMA
+    (2, 1, 2) split as a ring along ``rows``. The one collective is the
+    permute of the block of B's column panel a chip lacks, started BEFORE the
+    first dot and awaited after it; nothing is reduced or gathered; a chip's
+    peak is under the k-split program's 12.23 GB."""
+    c = _compile_square_cell(topo_mesh, 36864, (2, 2), strategy="auto")
+    text = c.as_text()
+    assert "collective-permute" in text
+    for gone in ("all-reduce", "reduce-scatter", "all-gather", "all-to-all"):
+        assert gone not in text, gone
+    entry = text[text.index("ENTRY"):]
+    start = entry.index(" collective-permute-start(")
+    done = entry.index(" collective-permute-done(")
+    assert "f32[18432,18432]" in entry[entry.rindex("\n", 0, start):start]
+    dots = [i for i in range(len(entry)) if entry.startswith("kind=kOutput", i)]
+    assert len(dots) == 2 and start < dots[0] < done < dots[1]
+    peak = c.memory_analysis().peak_memory_in_bytes
+    assert peak < 10.5e9 < 12.23e9
+
+
+def test_explicit_k_split_keeps_its_psum_at_the_cells_size(topo_mesh):
+    """``split=(2, 2, 1)`` at the cell's size is still the parent's program:
+    a reduction over the split contraction, 12.2 GB a chip."""
+    c = _compile_square_cell(topo_mesh, 36864, (2, 2), strategy="rmm",
+                             split=(2, 2, 1))
+    text = c.as_text()
+    assert "all-reduce" in text or "reduce-scatter" in text
+    assert 11.5e9 < c.memory_analysis().peak_memory_in_bytes < 13e9
+
+
+def test_square_1chip_cell_holds_no_collective(topo_mesh):
+    """``matmul.square-1chip``'s program (n = 28672 on a (1, 1) mesh): the
+    split is (1, 1, 1) and no collective of any kind exists."""
+    text = _compile_square_cell(topo_mesh, 28672, (1, 1),
+                                strategy="auto").as_text()
+    for gone in ("all-reduce", "reduce-scatter", "all-gather", "all-to-all",
+                 "collective-permute"):
+        assert gone not in text, gone
+    assert text.count("kind=kOutput") == 1
+
+
 def _compile_spec_decode_kernel(one_chip, group, page_len, pages, width,
                                 rows=32, kvh=8, window=False, flat=True,
                                 dtype=jnp.bfloat16, dh=128):

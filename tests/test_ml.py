@@ -156,9 +156,39 @@ def test_carma_prefers_long_dim():
     # k is dominant -> k gets the splits (contraction-parallel, psum over k)
     ms, ks, ns = split_method(64, 65536, 64, 8)
     assert ks == 8 and ms == 1 and ns == 1
-    # m dominant -> row-parallel, collective-free
+    # m dominant -> row-parallel: A stays where it lies
     ms, ks, ns = split_method(65536, 64, 64, 8)
     assert ms == 8
+
+
+_CUBE = 36864
+
+
+@pytest.mark.parametrize("mkn, chips, want", [
+    # a cube: ties go to m, then n; the contraction only once both are halved
+    ((_CUBE, _CUBE, _CUBE), 1, (1, 1, 1)),
+    ((_CUBE, _CUBE, _CUBE), 2, (2, 1, 1)),
+    ((_CUBE, _CUBE, _CUBE), 4, (2, 1, 2)),
+    ((_CUBE, _CUBE, _CUBE), 8, (2, 2, 2)),
+    ((_CUBE, _CUBE, _CUBE), 16, (4, 2, 2)),
+    # a budget that is no power of two is not exceeded
+    ((_CUBE, _CUBE, _CUBE), 6, (2, 1, 2)),
+    # k strictly the longest still gets k, m the longest m, n the longest n
+    ((64, 65536, 64), 8, (1, 8, 1)),
+    ((100, 101, 100), 2, (1, 2, 1)),
+    ((65536, 64, 64), 8, (8, 1, 1)),
+    ((64, 64, 65536), 8, (1, 1, 8)),
+    # n ties with k: n
+    ((64, 4096, 4096), 2, (1, 1, 2)),
+    # a Gramian's shape (A^T A of a tall A): the contraction, then m before n
+    ((512, 1 << 20, 512), 16, (1, 16, 1)),
+])
+def test_carma_tie_break_spares_the_contraction(mkn, chips, want):
+    """A tie goes to m, then n, then k: an m- or n-split moves operand bytes,
+    known before the dot; a k-split moves partial products, which are not."""
+    got = split_method(*mkn, chips)
+    assert got == want
+    assert got[0] * got[1] * got[2] <= chips
 
 
 def test_near_square_split():

@@ -21,6 +21,8 @@ style configuration dict (:meth:`ModelSpec.from_config`):
   blocks, chosen by a score over mean-pooled keys kept beside the pages;
 - an eighth, ``lightning`` (below): linear attention with a constant decay
   a head and NO attention, a layer that owns no page;
+- a ninth, ``kda`` (below): a gated delta rule whose decay is a VECTOR a
+  head, in a model whose every layer is an expert layer;
 - a per-head sigmoid gate on the attention output, before ``wo``;
 - a SwiGLU FFN, dense or a mixture of experts with a shared expert
   (:func:`~marlin_tpu.models.moe.moe_experts_ffn`: the layer is told which
@@ -129,6 +131,22 @@ tail), shared through snapshots as a delta-rule state is. The family's block
 both branches, ``dim_model_base / hidden_size`` on the logits), per-head
 QK-norm and an elementwise sigmoid output gate on both mixers.
 
+**A delta rule that decays a channel, beside held experts** (:class:`KdaSpec`;
+the ``solar_open2`` configuration family's ``gqa_layers``,
+``linear_attn_config`` and ``kda_*`` keys). A ``kda`` layer is a ``linear``
+layer's sibling (Kimi Delta Attention): the same state ``(key_dim, heads *
+value_dim)`` float32 and convolution tail in the row's slot, the same two
+programs' plumbing, but the decay is one value a CHANNEL of the key
+(``diag(a_t) S``), made by a low-rank projection, and the output gate is a
+low-rank sigmoid (:func:`_kda_mixer`; :mod:`~marlin_tpu.ops.delta_rule` takes
+the decay's rank as the form). The family's ``full`` layer has no positional
+term at all and an ELEMENTWISE sigmoid gate on the attention's output
+(:func:`_gated_attention`, the ``minicpm_sala`` family's without its
+QK-norm); the block is pre-norm (:func:`_solar_layer`) and EVERY layer's FFN
+is an expert layer of which a share is held (``first_expert``, ``experts_
+held``): the first family in which a layer keeps something in the row's state
+slot AND routes over experts it holds a part of.
+
 **A state that is shared by snapshot.** A slot is private to its row, but a
 COPY of it at a page boundary is as good to another row as the pages before
 that boundary: the pool keeps such copies in further slots of the same
@@ -178,8 +196,8 @@ import numpy as np
 
 from ..ops.sparse_attention import SparseSpec
 
-__all__ = ["RopeSpec", "LatentSpec", "SsmSpec", "DeltaSpec", "ConvSpec",
-           "LightningSpec", "SparseSpec", "Multipliers",
+__all__ = ["RopeSpec", "LatentSpec", "SsmSpec", "DeltaSpec", "KdaSpec",
+           "ConvSpec", "LightningSpec", "SparseSpec", "Multipliers",
            "LayerSpec", "ModelSpec", "init_params", "state_slot_copy",
            "init_layer_params", "init_kv_pages", "window_ring_pages",
            "layer_forward", "prefill_paged", "decode_paged",
@@ -371,6 +389,18 @@ class DeltaSpec:
 
 
 @dataclasses.dataclass(frozen=True)
+class KdaSpec(DeltaSpec):
+    """The sizes of a delta-rule mixer whose decay is a vector a head (Kimi
+    Delta Attention; the ``solar_open2`` family's ``linear_attn_config`` and
+    ``kda_*`` keys): a :class:`DeltaSpec` (state, tail, convolution and
+    chunked form alike) with ``key_dim`` decays a head and token. ``rank``
+    is the width of the two low-rank projections (decay and output gate;
+    ``kda_use_full_proj`` false)."""
+
+    rank: int = 128
+
+
+@dataclasses.dataclass(frozen=True)
 class ConvSpec:
     """The sizes of a gated short-convolution mixer (the ``lfm2_moe``
     configuration family's ``conv_L_cache`` key): a causal depthwise
@@ -437,7 +467,8 @@ class Multipliers:
 #: One line a kind: ``full`` / ``sliding`` (K and V per KV head), ``latent``
 #: (one entry a token), ``sparse`` (K, V and the compressed keys a sparse
 #: layer selects by), ``linear`` (a gated delta rule: state and tail),
-#: ``conv`` (a short convolution: a tail), ``lightning`` (a state)
+#: ``conv`` (a short convolution: a tail), ``lightning`` (a state), ``kda``
+#: (a delta rule that decays a channel: state and tail)
 _LAYER_KINDS = {
     "full": (True, False),
     "sliding": (True, False),
@@ -446,6 +477,7 @@ _LAYER_KINDS = {
     "linear": (False, True),
     "conv": (False, True),
     "lightning": (False, True),
+    "kda": (False, True),
 }
 
 
@@ -509,6 +541,14 @@ _SALA_KEYS = (
     "vocab_size", "lightning_nh", "lightning_nkv", "lightning_head_dim",
     "rope_theta", "scale_emb", "scale_depth", "mup_denominator",
     "dim_model_base", "sparse_config")
+_SOLAR_OPEN2_KEYS = (
+    "num_hidden_layers", "hidden_size", "head_dim", "num_attention_heads",
+    "num_key_value_heads", "vocab_size", "moe_intermediate_size",
+    "n_routed_experts", "n_shared_experts", "num_experts_per_tok",
+    "gqa_layers", "linear_attn_config", "use_gqa_gate", "use_rope",
+    "kda_use_full_proj", "kda_allow_neg_eigval")
+_LINEAR_ATTN_CONFIG_KEYS = ("short_conv_kernel_size", "head_dim", "num_heads",
+                            "num_kv_heads")
 _SPARSE_CONFIG_KEYS = ("kernel_size", "kernel_stride", "block_size", "topk",
                        "init_blocks", "window_size", "dense_len")
 
@@ -580,6 +620,9 @@ class ModelSpec:
     #: (one gain of ``head_dim`` for all heads) before the rotary embedding
     head_gate: bool = True
     qk_norm: bool = False
+    #: the mixers' sizes of the ``kda`` layers; with it the block is the
+    #: ``solar_open2`` family's (:func:`_solar_layer`)
+    kda: KdaSpec | None = None
     #: the head is the embedding itself (no ``head`` array)
     tied_head: bool = False
     #: added to the sum the picks' weights are renormalised by
@@ -602,9 +645,10 @@ class ModelSpec:
         of the ``olmo_hybrid`` family (:meth:`_from_olmo_hybrid_config`), one
         with ``conv_L_cache`` of the ``lfm2_moe`` family
         (:meth:`_from_lfm2_config`), one with ``mixer_types`` of the
-        ``minicpm_sala`` family (:meth:`_from_sala_config`). A configuration
-        that lacks keys its family needs raises a ``ValueError`` that names
-        them."""
+        ``minicpm_sala`` family (:meth:`_from_sala_config`), one with
+        ``gqa_layers`` of the ``solar_open2`` family
+        (:meth:`_from_solar_open2_config`). A configuration that lacks keys
+        its family needs raises a ``ValueError`` that names them."""
         if "kv_lora_rank" in cfg:
             _require(cfg, _LATENT_KEYS, "latent-attention (kv_lora_rank)")
             return cls._from_latent_config(cfg, experts_total, first_expert)
@@ -623,6 +667,12 @@ class ModelSpec:
             _require(cfg["sparse_config"], _SPARSE_CONFIG_KEYS,
                      "minicpm_sala (mixer_types), in its sparse_config,")
             return cls._from_sala_config(cfg)
+        if "gqa_layers" in cfg:
+            _require(cfg, _SOLAR_OPEN2_KEYS, "solar_open2 (gqa_layers)")
+            _require(cfg["linear_attn_config"], _LINEAR_ATTN_CONFIG_KEYS,
+                     "solar_open2 (gqa_layers), in its linear_attn_config,")
+            return cls._from_solar_open2_config(cfg, experts_total,
+                                                first_expert)
         _require(cfg, _LAYER_TYPES_KEYS, "layer_types")
         n = int(cfg["num_hidden_layers"])
         kinds = {"full_attention": "full", "sliding_attention": "sliding"}
@@ -982,6 +1032,79 @@ class ModelSpec:
                 first_layer=int(cfg.get("first_layer", 0)), layers_total=depth,
                 state_dtype=str(cfg.get("lightning_state_dtype", "float32"))))
 
+    @classmethod
+    def _from_solar_open2_config(cls, cfg: dict, experts_total,
+                                 first_expert):
+        """The ``solar_open2`` family's keys: the layers named in
+        ``gqa_layers`` are GQA attention with NO positional term (``use_rope``
+        false) and an elementwise sigmoid gate on the attention's output
+        (``use_gqa_gate``): ``full`` layers; every other layer is a ``kda``
+        layer (``linear_attn_config``: ``num_heads`` heads of ``head_dim``
+        for keys and values alike, ``num_kv_heads`` null, a convolution of
+        ``short_conv_kernel_size`` taps; ``kda_allow_neg_eigval`` doubles
+        the step, ``kda_use_full_proj`` false makes decay and gate low-rank
+        projections). EVERY layer from ``first_k_dense_replace`` (0) on is an
+        expert layer in the DeepSeek-V3 family's keys (``n_routed_experts``
+        held here, ``n_shared_experts`` x ``moe_intermediate_size`` shared,
+        sigmoid scoring, a bias that only selects); an untied head.
+        ``kda_gate_rank`` (default: the mixer's ``head_dim``),
+        ``kda_chunk_size`` (default 64) and ``kda_state_dtype`` (default
+        float32) are not published keys."""
+        la = cfg["linear_attn_config"]
+        unbuilt = [k for k, bad in (
+            ("use_rope", cfg["use_rope"]),
+            ("use_gqa_gate", not cfg["use_gqa_gate"]),
+            ("kda_use_full_proj", cfg["kda_use_full_proj"]),
+            ("linear_attn_config.num_kv_heads",
+             la["num_kv_heads"] not in (None, la["num_heads"])),
+            ("first_k_dense_replace", cfg.get("first_k_dense_replace", 0)),
+            ("norm_topk_prob", not cfg.get("norm_topk_prob", True)),
+            ("n_group", int(cfg.get("n_group", 1)) != 1),
+            ("attention_bias", cfg.get("attention_bias", False)),
+            ("tie_word_embeddings", cfg.get("tie_word_embeddings", False)),
+            ("hidden_act", cfg.get("hidden_act", "silu") != "silu")) if bad]
+        if unbuilt:
+            raise ValueError(
+                f"not built for the solar_open2 family: the keys {unbuilt} "
+                f"as this configuration sets them (a rotary embedding in the "
+                f"GQA layers, a GQA layer without its gate, full-rank decay "
+                f"and gate projections, value heads grouped over key heads, "
+                f"leading dense layers, picks not renormalised, expert "
+                f"groups, a projection bias, a tied head, an activation "
+                f"other than silu)")
+        heads = int(cfg["num_attention_heads"])
+        gqa = set(int(i) for i in cfg["gqa_layers"])
+        layers = tuple(LayerSpec("full" if i in gqa else "kda", heads, "moe")
+                       for i in range(int(cfg["num_hidden_layers"])))
+        kd = int(la["head_dim"])
+        kda = KdaSpec(
+            heads=int(la["num_heads"]), key_dim=kd, value_dim=kd,
+            conv=int(la["short_conv_kernel_size"]),
+            chunk=int(cfg.get("kda_chunk_size", 64)),
+            neg_eigval=bool(cfg["kda_allow_neg_eigval"]),
+            state_dtype=str(cfg.get("kda_state_dtype", "float32")),
+            rank=int(cfg.get("kda_gate_rank", kd)))
+        held = int(cfg["n_routed_experts"])
+        total = _experts_total(held, experts_total, first_expert)
+        width = int(cfg["moe_intermediate_size"])
+        none = RopeSpec(theta=1.0, rotary_dim=0)   # never applied
+        return cls(
+            d_model=int(cfg["hidden_size"]), head_dim=int(cfg["head_dim"]),
+            kv_heads=int(cfg["num_key_value_heads"]), layers=layers,
+            window=0, rope_full=none, rope_sliding=none,
+            dense_width=int(cfg.get("intermediate_size", 0)),
+            expert_width=width,
+            shared_width=width * int(cfg["n_shared_experts"]),
+            n_experts=total, experts_held=held,
+            first_expert=int(first_expert),
+            top_k=int(cfg["num_experts_per_tok"]),
+            routed_scale=float(cfg.get("routed_scaling_factor", 1.0)),
+            vocab_held=int(cfg["vocab_size"]),
+            norm_eps=float(cfg.get("rms_norm_eps", 1e-5)),
+            param_dtype=str(cfg.get("param_dtype", "bfloat16")),
+            compute_dtype=str(cfg.get("compute_dtype", "bfloat16")),
+            scoring="sigmoid", kda=kda, head_gate=False)
+
     @property
     def n_layers(self) -> int:
         return len(self.layers)
@@ -1003,7 +1126,8 @@ class ModelSpec:
     def mixer(self):
         """The sizes of the mixers that keep something in a state slot, of
         whichever kind (None: no layer has one): what a slot is sized by."""
-        return self.ssm or self.delta or self.conv or self.lightning
+        return (self.ssm or self.delta or self.kda or self.conv
+                or self.lightning)
 
     def state_slot_bytes(self, compute_dtype: str | None = None) -> int:
         """Bytes ONE row's state slot holds over all layers: each array a
@@ -1099,6 +1223,14 @@ def init_layer_params(spec: ModelSpec, ly: LayerSpec, key) -> dict:
           "ln2": jnp.ones((d,), jnp.float32)}
     if ly.attn == "linear":
         lp.update(_init_delta_params(spec, ks[8:15]))
+    elif ly.attn == "kda":
+        lp.update(_init_kda_params(spec, ks[0:8]))
+    elif spec.kda is not None:   # the family's full layer: no norm, a gate
+        lp.update(wq=_normal(ks[0], (d, hq), s, dt),
+                  wk=_normal(ks[1], (d, hk), s, dt),
+                  wv=_normal(ks[2], (d, hk), s, dt),
+                  w_g=_normal(ks[3], (d, hq), s, dt),
+                  wo=_normal(ks[4], (hq, d), hq ** -0.5, dt))
     elif ly.attn in ("sparse", "lightning"):
         lp.update(_init_sala_params(spec, ly, ks[8:15]))
     elif ly.attn == "conv":
@@ -1211,20 +1343,58 @@ def _init_delta_params(spec: ModelSpec, ks) -> dict:
     tens to thousands of tokens); ``W_b`` at 0.3 of its law, so that ``b = 2
     sigmoid(W_b u)`` passes 1 for half the tokens without saturating."""
     ds, d, dt = spec.delta, spec.d_model, jnp.dtype(spec.param_dtype)
-    s, H, hv = d ** -0.5, ds.heads, ds.heads * ds.value_dim
-    step = jnp.exp(jax.random.uniform(
-        ks[3], (H,), jnp.float32, math.log(1e-3), math.log(1e-1)))
+    s, H = d ** -0.5, ds.heads
     return {
-        "w_qkv": _normal(ks[0], (d, ds.conv_dim), s, dt),
+        **_delta_rule_draws(ds, d, dt, ks, decays=H),
         "w_ab": jnp.concatenate([_normal(ks[1], (d, H), 0.1 * s, dt),
                                  _normal(ks[2], (d, H), 0.3 * s, dt)], axis=1),
-        "w_g": _normal(ks[5], (d, hv), s, dt),
+        "w_g": _normal(ks[5], (d, H * ds.value_dim), s, dt)}
+
+
+def _delta_rule_draws(ds: DeltaSpec, d: int, dt, ks, decays: int) -> dict:
+    """What a ``linear`` and a ``kda`` mixer draw alike: ``[q | k | v]`` and
+    ``wo`` N(0, 1/fan_in), the convolution N(0, 1/taps), ``A`` uniform in
+    [0.5, 1.5] a head, the step log-uniform in [1e-3, 1e-1] over ``decays``
+    values (a head's one, or one a channel of its key; ``dt_bias`` its
+    inverse softplus), the output norm's gain 1."""
+    hv = ds.heads * ds.value_dim
+    step = jnp.exp(jax.random.uniform(
+        ks[3], (decays,), jnp.float32, math.log(1e-3), math.log(1e-1)))
+    return {
+        "w_qkv": _normal(ks[0], (d, ds.conv_dim), d ** -0.5, dt),
         "conv_w": _normal(ks[6], (ds.conv, ds.conv_dim), ds.conv ** -0.5, dt),
         "dt_bias": step + jnp.log(-jnp.expm1(-step)),
-        "A_log": jnp.log(jax.random.uniform(ks[4], (H,), jnp.float32,
+        "A_log": jnp.log(jax.random.uniform(ks[4], (ds.heads,), jnp.float32,
                                             0.5, 1.5)),
         "o_norm": jnp.ones((ds.value_dim,), jnp.float32),
         "wo": _normal(jax.random.fold_in(ks[0], 1), (hv, d), hv ** -0.5, dt)}
+
+
+def _init_kda_params(spec: ModelSpec, ks) -> dict:
+    """A ``kda`` mixer's parameters, drawn so that a check can see the
+    mechanism: projections N(0, 1/fan_in) (``[q | k | v]`` as one matrix, the
+    channels the convolution runs over); the convolution N(0, 1/taps), no
+    bias. The decay ``a = exp(-A softplus(W_a2 (W_a1 u) + dt_bias))``: ``A``
+    uniform in [0.5, 1.5] a HEAD, the step log-uniform in [1e-3, 1e-1] a
+    CHANNEL (``dt_bias`` its inverse softplus) and ``W_a2`` at a tenth of its
+    fan-in's law, so that the decays spread over about 0.86 to 0.9995 and
+    DIFFER across a head's channels (one scalar a head in their place is
+    another model). ``W_b`` at 0.3 of its law (``b = 2 sigmoid(W_b u)``
+    passes 1 for half the tokens without saturating); the gate's ``W_z2
+    (W_z1 u)`` of order 1 with a bias N(0, 0.5^2): a sigmoid away from 0 and
+    1."""
+    kd, d, dt = spec.kda, spec.d_model, jnp.dtype(spec.param_dtype)
+    s, H, r = d ** -0.5, kd.heads, kd.rank
+    hk, hv = H * kd.key_dim, H * kd.value_dim
+    ka, kz = jax.random.split(ks[1]), jax.random.split(ks[5])
+    return {
+        **_delta_rule_draws(kd, d, dt, ks, decays=hk),
+        "w_a1": _normal(ka[0], (d, r), s, dt),
+        "w_a2": _normal(ka[1], (r, hk), 0.1 * r ** -0.5, dt),
+        "w_b": _normal(ks[2], (d, H), 0.3 * s, dt),
+        "w_z1": _normal(kz[0], (d, r), s, dt),
+        "w_z2": _normal(kz[1], (r, hv), r ** -0.5, dt),
+        "b_z": 0.5 * jax.random.normal(ks[7], (hv,), jnp.float32)}
 
 
 #: the QK-norm gain a sparse layer is drawn with: scores ``q . k /
@@ -1325,8 +1495,8 @@ def init_kv_pages(spec: ModelSpec, num_pages: int, window_pages: int,
     indexed by a row's STATE SLOT and not by a page id: the recurrent states
     ``(state_slots, heads, state, head_dim)`` in the mixer's ``state_dtype``
     and the convolution tails ``(state_slots, conv - 1, conv_dim)``; slot 0
-    is the dummy. A ``linear`` layer has those two arrays and nothing else
-    (its states ``(state_slots, key_dim, heads * value_dim)``:
+    is the dummy. A ``linear`` (and a ``kda``) layer has those two arrays
+    and nothing else (its states ``(state_slots, key_dim, heads * value_dim)``:
     :mod:`~marlin_tpu.ops.delta_rule`); a ``conv`` layer ONE, the tails
     ``(state_slots, taps - 1, channels)``: a layer has the arrays its
     mixer's ``slot_arrays()`` names (a ``lightning`` layer ONE, the states
@@ -1351,7 +1521,8 @@ def init_kv_pages(spec: ModelSpec, num_pages: int, window_pages: int,
 
     def slabs(ly):
         if not ly.owns_pages:   # no page: the state slot's arrays alone
-            return state_arrays({"linear": spec.delta, "conv": spec.conv,
+            return state_arrays({"linear": spec.delta, "kda": spec.kda,
+                                 "conv": spec.conv,
                                  "lightning": spec.lightning}[ly.attn])
         if ly.attn == "latent":
             return (jnp.zeros((num_pages, page_len,
@@ -1444,7 +1615,8 @@ def layer_forward(spec: ModelSpec, i: int, lp: dict, x, positions, valid,
     (:func:`_parallel_mixers`): the recurrent state is handed in and out
     there the way ``attend`` hands in the cache; so does a ``linear``
     layer, which asks ``attend`` for nothing (:func:`_post_norm_layer`,
-    :func:`_delta_mixer`), and a ``conv`` layer (:func:`_short_conv`)."""
+    :func:`_delta_mixer`), a ``kda`` layer (:func:`_solar_layer`,
+    :func:`_kda_mixer`) and a ``conv`` layer (:func:`_short_conv`)."""
     ly = spec.layers[i]
     T, cd = x.shape[0], jnp.dtype(spec.compute_dtype)
     H, kvh, dh = ly.q_heads, spec.kv_heads, spec.head_dim
@@ -1452,6 +1624,8 @@ def layer_forward(spec: ModelSpec, i: int, lp: dict, x, positions, valid,
         return _post_norm_layer(spec, ly, lp, x, attend, mix)
     if spec.lightning is not None:
         return _sala_layer(spec, ly, lp, x, positions, valid, attend, mix)
+    if spec.kda is not None:
+        return _solar_layer(spec, ly, lp, x, valid, attend, mix)
     if ly.ssm:
         x = _parallel_mixers(spec, ly, lp, x, positions, attend, mix)
         return _ffn_half(spec, ly, lp, x, valid)
@@ -1493,7 +1667,7 @@ def _sala_layer(spec: ModelSpec, ly: LayerSpec, lp: dict, x, positions,
     mixer(rmsnorm(x))`` then ``x = x + r swiglu(rmsnorm(x))`` with ``r =
     scale_depth / sqrt(mup_denominator)`` (:class:`Multipliers`:
     ``attention_out`` and ``mlp_down``). The mixer of a ``sparse`` layer is
-    :func:`_sparse_attention` under the ``attn_sparse`` scope, of a
+    :func:`_gated_attention` under the ``attn_sparse`` scope, of a
     ``lightning`` layer :func:`_lightning_mixer` under ``lightning_attn``."""
     u = _rmsnorm(x, lp["ln1"], spec.norm_eps).astype(spec.compute_dtype)
     if ly.attn == "lightning":
@@ -1501,7 +1675,7 @@ def _sala_layer(spec: ModelSpec, ly: LayerSpec, lp: dict, x, positions,
             y = _lightning_mixer(spec, lp, u, positions, mix)
     else:
         with jax.named_scope("attn_sparse"):
-            y = _sparse_attention(spec, ly, lp, u, attend)
+            y = _gated_attention(spec, ly, lp, u, attend)
     return _ffn_half(spec, ly, lp, x + spec.mults.attention_out * y, valid)
 
 
@@ -1511,20 +1685,27 @@ def _head_norm(x, g, eps: float, heads: int):
     return _rmsnorm(x.reshape(x.shape[0], heads, -1), g, eps)
 
 
-def _sparse_attention(spec: ModelSpec, ly: LayerSpec, lp: dict, u, attend):
-    """The ``minicpm4`` mixer over the normed input ``u`` (T, d) in the
-    compute dtype: GQA projections, per-head QK-norm, NO rotary embedding;
-    ``attend(q (T, kv_heads, group, head_dim), k, v (T, kv_heads,
-    head_dim))`` stores the keys, the values and the keys' compressed form
-    where the program keeps its cache and attends densely or over the
-    selected blocks by each query's position; then the elementwise sigmoid
-    gate ``sigmoid(W_g u)`` and ``wo``."""
+def _gated_attention(spec: ModelSpec, ly: LayerSpec, lp: dict, u, attend):
+    """GQA attention with NO positional term and an elementwise output gate
+    over the normed input ``u`` (T, d) in the compute dtype (the
+    ``minicpm4`` mixer of a ``sparse`` layer, the ``solar_open2`` family's
+    ``full`` layer): GQA projections, per-head QK-norm where the spec has
+    one; ``attend(q (T, kv_heads, group, head_dim), k, v (T, kv_heads,
+    head_dim))`` stores the keys and the values (a sparse layer's: the keys'
+    compressed form too) where the program keeps its cache and attends (a
+    sparse layer's: densely or over the selected blocks by each query's
+    position); then the elementwise sigmoid gate ``sigmoid(W_g u)`` and
+    ``wo``."""
     cd = u.dtype
     T, H, kvh, dh = u.shape[0], ly.q_heads, spec.kv_heads, spec.head_dim
-    q = _head_norm(_mm(u, lp["wq"], jnp.float32), lp["q_norm"],
-                   spec.norm_eps, H).astype(cd)
-    k = _head_norm(_mm(u, lp["wk"], jnp.float32), lp["k_norm"],
-                   spec.norm_eps, kvh).astype(cd)
+    if spec.qk_norm:
+        q = _head_norm(_mm(u, lp["wq"], jnp.float32), lp["q_norm"],
+                       spec.norm_eps, H).astype(cd)
+        k = _head_norm(_mm(u, lp["wk"], jnp.float32), lp["k_norm"],
+                       spec.norm_eps, kvh).astype(cd)
+    else:
+        q = _mm(u, lp["wq"]).reshape(T, H, dh)
+        k = _mm(u, lp["wk"]).reshape(T, kvh, dh)
     v = _mm(u, lp["wv"]).reshape(T, kvh, dh)
     gate = jax.nn.sigmoid(_mm(u, lp["w_g"], jnp.float32))
     o = attend(q.reshape(T, kvh, H // kvh, dh), k, v)
@@ -1735,6 +1916,50 @@ def _delta_mixer(spec: ModelSpec, lp: dict, u, mix):
     o = mix(_mm(u, lp["w_qkv"]), g, beta, lp)
     o = _rmsnorm(o, lp["o_norm"], spec.norm_eps)
     gate = jax.nn.silu(_mm(u, lp["w_g"], jnp.float32)).reshape(T, H, V)
+    return _mm((o * gate).reshape(T, H * V).astype(cd), lp["wo"],
+               jnp.float32)
+
+
+def _solar_layer(spec: ModelSpec, ly: LayerSpec, lp: dict, x, valid, attend,
+                 mix):
+    """The ``solar_open2`` family's block: pre-norm, ``x = x +
+    mixer(rmsnorm(x))`` then the expert layer (:func:`_ffn_half`). The
+    mixer of a ``kda`` layer is :func:`_kda_mixer` under the ``attn_kda``
+    scope, of a ``full`` layer :func:`_gated_attention` under
+    ``attn_full``."""
+    u = _rmsnorm(x, lp["ln1"], spec.norm_eps).astype(spec.compute_dtype)
+    if ly.attn == "kda":
+        with jax.named_scope("attn_kda"):
+            y = _kda_mixer(spec, lp, u, mix)
+    else:
+        with jax.named_scope("attn_full"):
+            y = _gated_attention(spec, ly, lp, u, attend)
+    return _ffn_half(spec, ly, lp, x + y, valid)
+
+
+def _kda_mixer(spec: ModelSpec, lp: dict, u, mix):
+    """The delta-rule mixer with a decay a channel over the normed input
+    ``u`` (T, d) in the compute dtype: ``[q~ | k~ | v~] = w_qkv u``; the
+    log-decay ``g = -exp(A_log) softplus(W_a2 (W_a1 u) + dt_bias)``, ``(T,
+    heads, key_dim)`` float32 (``A_log`` a head, ``dt_bias`` a channel), and
+    the step ``b = sigmoid(W_b u)`` a head (doubled with ``neg_eigval``);
+    ``mix(qkv (T, conv_dim), g, b, lp)`` runs the causal convolution, SiLU,
+    the lengths (:func:`_delta_operands`) and the recurrence where the
+    program keeps the row's state and returns ``o`` (T, heads, value_dim)
+    float32; then an RMSNorm over each head's values (one gain vector for
+    all heads), the low-rank gate ``sigmoid(W_z2 (W_z1 u) + b_z)``,
+    ``wo``."""
+    kd, cd = spec.kda, u.dtype
+    T, H, K, V = u.shape[0], kd.heads, kd.key_dim, kd.value_dim
+    g = -jnp.exp(lp["A_log"])[None, :, None] * jax.nn.softplus(
+        (_mm(_mm(u, lp["w_a1"]), lp["w_a2"], jnp.float32)
+         + lp["dt_bias"]).reshape(T, H, K))
+    beta = jax.nn.sigmoid(_mm(u, lp["w_b"], jnp.float32)) * (
+        2.0 if kd.neg_eigval else 1.0)
+    o = mix(_mm(u, lp["w_qkv"]), g, beta, lp)
+    o = _rmsnorm(o, lp["o_norm"], spec.norm_eps)
+    gate = jax.nn.sigmoid(_mm(_mm(u, lp["w_z1"]), lp["w_z2"], jnp.float32)
+                          + lp["b_z"]).reshape(T, H, V)
     return _mm((o * gate).reshape(T, H * V).astype(cd), lp["wo"],
                jnp.float32)
 
@@ -2177,10 +2402,11 @@ def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
             return y.reshape(C, sm.d_inner)
 
         def mix_delta(qkv, g, beta, lp, name=name):
-            # as above, for a linear layer's delta rule: the slot's state
-            # (key_dim, heads * value_dim) entered and left, the padding
-            # neither decaying nor stepping (g 0 and b 0 there)
-            ds = spec.delta
+            # as above, for a linear or a kda layer's delta rule: the slot's
+            # state (key_dim, heads * value_dim) entered and left, the
+            # padding neither decaying nor stepping (g 0 and b 0 there; g
+            # is one a head or one a channel)
+            ds = spec.delta or spec.kda
             states, tails = pages[name]
             s0, t0 = _enter_state(chunk_start == 0, states[state_slot],
                                   tails[state_slot])
@@ -2188,9 +2414,11 @@ def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
                 qkv, t0, lp["conv_w"], jnp.zeros((ds.conv_dim,), qkv.dtype),
                 jnp.clip(length - chunk_start, 0, C))
             q, k, v = _delta_operands(ds, conv, qkv.dtype)
-            with jax.named_scope("delta_scan"):
+            with jax.named_scope(
+                    "delta_scan" if spec.kda is None else "kda_scan"):
                 o, s1 = delta_rule.delta_chunk_scan(
-                    q, k, v, jnp.where(valid[:, None], g, 0.0),
+                    q, k, v,
+                    jnp.where(valid.reshape(C, *(1,) * (g.ndim - 1)), g, 0.0),
                     jnp.where(valid[:, None], beta, 0.0),
                     s0.reshape(ds.key_dim, ds.heads, ds.value_dim),
                     block=min(ds.chunk, C))
@@ -2297,7 +2525,8 @@ def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
         x, c = layer_forward(spec, i, params[name], x, q_pos, valid,
                              {"latent": attend_latent,
                               "sparse": attend_sparse}.get(ly.attn, attend),
-                             {"linear": mix_delta, "conv": mix_conv,
+                             {"linear": mix_delta, "kda": mix_delta,
+                              "conv": mix_conv,
                               "lightning": mix_lightning}.get(ly.attn, mix))
         counts = counts + c
     # write the chunk's pages, one dynamic update a page (transformer.py has
@@ -2467,10 +2696,12 @@ def _lm_decode_paged_spec_jit(params, pages, gtables, wtables, positions,
             return y.reshape(B, sm.d_inner)
 
         def mix_delta(qkv, g, beta, lp, name=name):
-            # as above, for a linear layer: its two arrays are all it has
-            ds = spec.delta
+            # as above, for a linear or a kda layer: its two arrays are all
+            # it has
+            ds = spec.delta or spec.kda
             states, tails = pages[name]
-            with jax.named_scope("delta_update"):
+            with jax.named_scope(
+                    "delta_update" if spec.kda is None else "kda_update"):
                 conv, t1 = ssm_ops.conv_step(
                     qkv, tails[state_slots], lp["conv_w"],
                     jnp.zeros((ds.conv_dim,), qkv.dtype))
@@ -2560,7 +2791,8 @@ def _lm_decode_paged_spec_jit(params, pages, gtables, wtables, positions,
         x, c = layer_forward(spec, i, params[name], x, pos, live,
                              {"latent": attend_latent,
                               "sparse": attend_sparse}.get(ly.attn, attend),
-                             {"linear": mix_delta, "conv": mix_conv,
+                             {"linear": mix_delta, "kda": mix_delta,
+                              "conv": mix_conv,
                               "lightning": mix_lightning}.get(ly.attn, mix))
         counts = counts + c
     logits = _head_logits(spec, params, x)

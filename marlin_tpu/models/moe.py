@@ -221,23 +221,34 @@ def moe_decode_ffn(mp: dict, h, top_k: int = 2):
 _GMM_TILING = (128, 1024, 1024)
 
 
-def _gmm_tiling(k: int, n: int) -> tuple:
+def _gmm_tiling(k: int, n: int, itemsize: int = 2) -> tuple:
     """The grouped matmul's tile ``(rows, k, n)`` for experts of ``(k, n)``,
-    from those widths alone. An expert whose widths are both whole tiles of
-    1024 keeps :data:`_GMM_TILING`. Any other (1792 = 14 x 128) takes the
-    whole contraction in ONE tile, up to 2048, and 1024 columns: read on the
-    chip at 32 experts of 2048 x 1792 (``tools/gmm_tiling.py``; PERF.md
-    section 6, PR 44), a layer's three matmuls take 1039 us at a decode
-    call's 384 rows and 1293 at a chunk's 2048, against 1072 and 1441 at
-    ``_GMM_TILING`` (fewer grid steps) and 1127 / 1516 at whole tiles of 896
-    (a quarter-empty tile costs less than a narrower one). The rows stay
-    128 whatever an expert has: an expert whose rows straddle a row tile's
-    edge is visited, and its weights read, once a tile, and at 12 rows an
-    expert a tile of 16 rows reads most experts twice (1686 us; the bytes'
-    time is 860)."""
-    if k % 1024 == 0 and n % 1024 == 0:
-        return _GMM_TILING
-    return (_GMM_TILING[0], min(k, 2048), 1024)
+    from those widths and the operands' ``itemsize`` alone. An expert whose
+    widths are both whole tiles of 1024 keeps :data:`_GMM_TILING`. Any other
+    (1792 = 14 x 128, 1280 = 10 x 128) takes the whole contraction in ONE
+    tile, up to 2048, and 1024 columns: read on the chip at 32 experts of
+    2048 x 1792 (``tools/gmm_tiling.py``; PERF.md section 6, PR 44), a
+    layer's three matmuls take 1039 us at a decode call's 384 rows and 1293
+    at a chunk's 2048, against 1072 and 1441 at ``_GMM_TILING`` (fewer grid
+    steps) and 1127 / 1516 at whole tiles of 896 (a quarter-empty tile costs
+    less than a narrower one). The rows stay 128 whatever an expert has: an
+    expert whose rows straddle a row tile's edge is visited, and its weights
+    read, once a tile, and at 12 rows an expert a tile of 16 rows reads most
+    experts twice (1686 us; the bytes' time is 860). Operands wider than
+    bfloat16 (a float32 check) take as many BYTES of the contraction a
+    tile, so half its length: the two buffers of a 2048 x 1024 float32
+    weight tile are 16 MB, the whole of the kernel's VMEM
+    (``RESOURCE_EXHAUSTED``; PERF.md section 7, PR 44). An expert of at
+    most 1280 columns (ten lane tiles) is ONE column tile: read at 40
+    experts of 4096 x 1280 (PERF.md section 6, PR 51), a second tile of 1024
+    that is three quarters empty costs a layer 1798 us at a decode call's
+    128 rows and 2152 at a chunk's 512 where the whole width takes 1594 and
+    1912 (the bytes' time is 1383 / 1536)."""
+    tile = _GMM_TILING if k % 1024 == 0 and n % 1024 == 0 else (
+        _GMM_TILING[0], min(k, 2048), n if n <= 1280 else 1024)
+    if itemsize <= 2:
+        return tile
+    return (tile[0], max(128, tile[1] * 2 // itemsize // 128 * 128), tile[2])
 
 
 def _grouped_matmul(xs, w, sizes, out_dtype, tiling=None):
@@ -258,7 +269,8 @@ def _grouped_matmul(xs, w, sizes, out_dtype, tiling=None):
                                   preferred_element_type=out_dtype)
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 
-    tiling = tiling or _gmm_tiling(w.shape[1], w.shape[2])
+    tiling = tiling or _gmm_tiling(w.shape[1], w.shape[2],
+                                   jnp.dtype(w.dtype).itemsize)
     pad = -xs.shape[0] % tiling[0]
     out = gmm(jnp.pad(xs, ((0, pad), (0, 0))) if pad else xs, w, sizes,
               preferred_element_type=out_dtype, tiling=tiling)

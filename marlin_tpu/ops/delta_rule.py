@@ -8,6 +8,11 @@ step ``b_t`` in ``(0, 2)``::
     S_t = S' + b_t k_t (v_t - S'^T k_t)^T     (the delta step, against S')
     o_t = S_t^T q_t
 
+The decay is one scalar a head (``g`` of rank 2: the ``olmo_hybrid`` family's
+gated delta rule) or a VECTOR a head, one value a channel of the key (``g`` of
+rank 3, ``S' = diag(a_t) S_{t-1}``: Kimi Delta Attention, the ``solar_open2``
+family's ``kda_*`` keys); ``g``'s rank picks the form in both functions.
+
 Mamba-2's state (``ops/ssm.py``) moves by a scalar decay and an outer
 product; this one is also multiplied by ``I - b_t k_t k_t^T``, a rank-one
 matrix that depends on the key, whose eigenvalue ``1 - b_t`` reaches ``-1``.
@@ -22,7 +27,12 @@ matrix that depends on the key, whose eigenvalue ``1 - b_t`` reaches ``-1``.
   in float32, against ``V`` and ``K`` at once for every block of the chunk,
   and only the ``chunk / C`` block states are chained one after another. A
   decay ratio ``exp(cs_t - cs_j)`` exists for ``t >= j`` only and is masked
-  BEFORE the exponential. A position with ``g == 0`` and ``b == 0`` neither
+  BEFORE the exponential. With a decay a channel the ratio sits INSIDE the
+  contraction over the key's channels and cannot be factored out of ``k_t .
+  k_j``: :func:`_channel_decay_terms` builds ``A`` from exact sums inside
+  sub-blocks of 16 tokens and from keys rescaled against a sub-block's
+  entering decay between them; everything after ``A`` is shared. A
+  position with ``g == 0`` and ``b == 0`` neither
   decays the state nor adds to it: that is how the positions past a row's
   length are kept out (the caller zeroes both).
 - :func:`delta_decode_update` advances one token for each row of a batch,
@@ -43,12 +53,16 @@ pair of heads (384 lanes) is three whole tiles: the kernel walks a block's
 heads in pairs. A key enters the update as a COLUMN broadcast along the
 lanes of its head (``K[k, h V + v] = k_h[k]``), made in the kernel from a
 row by transposing one 128 x 128 tile (as ``ops/ssm.py`` makes ``B``);
-decay, step and value enter as rows laid over the lanes.
+decay, step and value enter as rows laid over the lanes; a decay a channel
+enters as the key does, a column along its head's lanes. At 64 heads of 128
+x 128 the slab's ``(128, 8192)`` is whole tiles head by head and a block is
+16 heads.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -112,25 +126,14 @@ def _unit_lower_inverse(A):
          jnp.concatenate([low, Tb], axis=-1)], axis=-2)
 
 
-def delta_chunk_scan(q, k, v, g, beta, state, block: int):
-    """The recurrence over one row's chunk. ``q``, ``k`` (T, H, K) and ``v``
-    (T, H, V) in the compute dtype (``q`` scaled, ``k`` of unit length);
-    ``g`` (T, H) float32 log-decay (0 where the position is not a token),
-    ``beta`` (T, H) float32 (0 there too); ``state`` (K, H, V), the state
-    before the chunk as the slab holds it (:func:`state_to_slab` with the
-    heads split). Matmul operands in ``q``'s dtype; the triangular system,
-    every decay, accumulation and the carried state in float32. Returns
-    ``(o (T, H, V) float32, state after the chunk)``."""
-    T, H, K = q.shape
-    V = v.shape[-1]
-    if T % block:
-        raise ValueError(f"a chunk of {T} tokens is not whole blocks of "
-                         f"{block}")
-    nc, C, cd, f32 = T // block, block, q.dtype, jnp.float32
-    qb, kb = q.reshape(nc, C, H, K), k.reshape(nc, C, H, K)
-    vb = v.reshape(nc, C, H, V)
-    gb = g.astype(f32).reshape(nc, C, H)
-    bb = beta.astype(f32).reshape(nc, C, H)
+def _scalar_decay_terms(qb, kb, gb, bb):
+    """One decay a head and token (``gb`` (nc, C, H)): the block's matrices
+    ``A`` (strictly lower) and ``P`` (nc, t, j, H) float32, the keys of the
+    system's right-hand side ``b gamma k`` (float32), the queries that meet
+    the entering state, the keys that leave the block (both in the compute
+    dtype) and the block's whole decay, laid over a state ``(K, H, V)``."""
+    f32, cd = jnp.float32, qb.dtype
+    C = qb.shape[1]
     cs = jnp.cumsum(gb, axis=1)                                   # (nc, C, H)
     t = jnp.arange(C)
     seen = (t[:, None] >= t[None, :])[None, :, :, None]       # j <= t
@@ -140,22 +143,97 @@ def delta_chunk_scan(q, k, v, g, beta, state, block: int):
     kk = jnp.einsum("cthk,cjhk->ctjh", kb, kb, preferred_element_type=f32)
     A = jnp.where(before, bb[:, :, None, :] * ratio * kk, 0.0)
     gamma = jnp.exp(cs)
+    P = ratio * jnp.einsum("cthk,cjhk->ctjh", qb, kb,
+                           preferred_element_type=f32)
+    q_in = (qb.astype(f32) * gamma[..., None]).astype(cd)
+    k_out = (kb.astype(f32) * jnp.exp(cs[:, -1:] - cs)[..., None]).astype(cd)
+    return (A, P, (bb * gamma)[..., None] * kb.astype(f32), q_in, k_out,
+            gamma[:, -1][:, None, :, None])                    # (nc, 1, H, 1)
+
+
+def _channel_decay_terms(qb, kb, gb, bb, sub: int):
+    """As :func:`_scalar_decay_terms` for a decay a CHANNEL (``gb`` (nc, C,
+    H, K)): ``A[t, j] = b_t sum_c k_t[c] k_j[c] exp(cs_t[c] - cs_j[c])``, the
+    ratio INSIDE the contraction. A pair of tokens in one sub-block of
+    ``sub`` tokens is met exactly, a multiply and a sum in float32 (the
+    difference masked before the exponential). A pair in two sub-blocks is a
+    matmul of keys rescaled against the decay that ENTERS the later
+    sub-block, ``k_t exp(cs_t - ref)`` and ``k_j exp(ref - cs_j)``: ``cs``
+    only falls, so both exponents are at most 0 and neither factor leaves
+    float32's range whatever the decay (``exp(-cs_j)`` alone would)."""
+    f32, cd = jnp.float32, qb.dtype
+    nc, C, H, K = kb.shape
+    n = C // sub
+    cs = jnp.cumsum(gb, axis=1)                                # (nc, C, H, K)
+    kf = kb.astype(f32)
+    qk = jnp.stack([kf, qb.astype(f32)])                    # (2, nc, C, H, K)
+    t = jnp.arange(sub)
+    seen = (t[:, None] >= t[None, :])[None, None, :, :, None, None]
+    css, kss = cs.reshape(nc, n, sub, H, K), kf.reshape(nc, n, sub, H, K)
+    ratio = jnp.exp(jnp.where(seen, css[:, :, :, None] - css[:, :, None, :],
+                              -jnp.inf))                # (nc, n, t, j, H, K)
+    inside = jnp.sum(qk.reshape(2, nc, n, sub, 1, H, K) * kss[:, :, None]
+                     * ratio, axis=-1)                  # (2, nc, n, t, j, H)
+    rows = []
+    for i in range(n):
+        lo, hi = i * sub, (i + 1) * sub
+        parts = [inside[:, :, i]]
+        if i:
+            ref = cs[:, lo - 1][:, None]                       # (nc, 1, H, K)
+            late = (qk[:, :, lo:hi] * jnp.exp(cs[:, lo:hi] - ref)).astype(cd)
+            early = (kf[:, :lo] * jnp.exp(ref - cs[:, :lo])).astype(cd)
+            parts.insert(0, jnp.einsum("xcthk,cjhk->xctjh", late, early,
+                                       preferred_element_type=f32))
+        if hi < C:
+            parts.append(jnp.zeros((2, nc, sub, C - hi, H), f32))
+        rows.append(jnp.concatenate(parts, axis=3))
+    kk, P = jnp.concatenate(rows, axis=2)                      # (nc, t, j, H)
+    tt = jnp.arange(C)
+    before = (tt[:, None] > tt[None, :])[None, :, :, None]
+    A = jnp.where(before, bb[:, :, None, :] * kk, 0.0)
+    gamma = jnp.exp(cs)
+    q_in = (qk[1] * gamma).astype(cd)
+    k_out = (kf * jnp.exp(cs[:, -1:] - cs)).astype(cd)
+    return (A, P, bb[..., None] * gamma * kf, q_in, k_out,
+            gamma[:, -1].transpose(0, 2, 1)[..., None])        # (nc, K, H, 1)
+
+
+def delta_chunk_scan(q, k, v, g, beta, state, block: int, sub: int = 16):
+    """The recurrence over one row's chunk. ``q``, ``k`` (T, H, K) and ``v``
+    (T, H, V) in the compute dtype (``q`` scaled, ``k`` of unit length);
+    ``g`` float32 log-decay, (T, H) one a head or (T, H, K) one a CHANNEL of
+    the key (its rank picks the form; 0 where the position is not a token),
+    ``beta`` (T, H) float32 (0 there too); ``state`` (K, H, V), the state
+    before the chunk as the slab holds it (:func:`state_to_slab` with the
+    heads split). Matmul operands in ``q``'s dtype; the triangular system,
+    every decay, accumulation and the carried state in float32. ``sub``: the
+    channel form's sub-block (:func:`_channel_decay_terms`). Returns ``(o (T,
+    H, V) float32, state after the chunk)``."""
+    T, H, K = q.shape
+    V = v.shape[-1]
+    if T % block:
+        raise ValueError(f"a chunk of {T} tokens is not whole blocks of "
+                         f"{block}")
+    nc, C, cd, f32 = T // block, block, q.dtype, jnp.float32
+    qb, kb = q.reshape(nc, C, H, K), k.reshape(nc, C, H, K)
+    vb = v.reshape(nc, C, H, V)
+    bb = beta.astype(f32).reshape(nc, C, H)
+    if g.ndim == 2:
+        A, P, rhs_k, q_in, k_out, total = _scalar_decay_terms(
+            qb, kb, g.astype(f32).reshape(nc, C, H), bb)
+    else:
+        sub = math.gcd(sub, C)
+        A, P, rhs_k, q_in, k_out, total = _channel_decay_terms(
+            qb, kb, g.astype(f32).reshape(nc, C, H, K), bb, sub)
     # (I + A) [W | U] = [diag(b) V | diag(b gamma) K], a head and block
-    rhs = jnp.concatenate(
-        [bb[..., None] * vb.astype(f32),
-         (bb * gamma)[..., None] * kb.astype(f32)], axis=-1)
+    rhs = jnp.concatenate([bb[..., None] * vb.astype(f32), rhs_k], axis=-1)
     # the inverse in float32; applied as every other product here is, its
     # operands in the compute dtype and the sums in float32
     solved = jnp.matmul(_unit_lower_inverse(A.transpose(0, 3, 1, 2))
                         .astype(cd), rhs.transpose(0, 2, 1, 3).astype(cd),
                         preferred_element_type=f32)
     W, U = solved[..., :V], solved[..., V:].astype(cd)   # (nc, H, C, V | K)
-    P = (ratio * jnp.einsum("cthk,cjhk->ctjh", qb, kb,
-                            preferred_element_type=f32)
-         ).transpose(0, 3, 1, 2).astype(cd)                   # (nc, H, t, j)
-    q_in = (qb.astype(f32) * gamma[..., None]).astype(cd)
-    k_out = (kb.astype(f32) * jnp.exp(cs[:, -1:] - cs)[..., None]).astype(cd)
-    total = jnp.exp(cs[:, -1])                                     # (nc, H)
+    P = P.transpose(0, 3, 1, 2).astype(cd)                    # (nc, H, t, j)
     s = state.astype(f32)                                         # (K, H, V)
     outs = []
     for c in range(nc):       # the block states, chained
@@ -167,7 +245,7 @@ def delta_chunk_scan(q, k, v, g, beta, state, block: int):
                        preferred_element_type=f32)
             + jnp.einsum("htj,hjv->thv", P[c], d,
                          preferred_element_type=f32))
-        s = s * total[c][None, :, None] + jnp.einsum(
+        s = s * total[c] + jnp.einsum(
             "jhk,hjv->khv", k_out[c], d, preferred_element_type=f32)
     return jnp.concatenate(outs), s.astype(state.dtype)
 
@@ -197,9 +275,12 @@ def _delta_update_kernel(slots_ref, state_ref, k_ref, q_ref, a_ref, b_ref,
                          bv_ref, o_ref, out_ref, *, value_dim: int):
     """Grid (B, head blocks). Blocks: ``state`` / ``out`` (1, K, hb V) of
     the slab at slot ``slots[b]``; ``k``, ``q`` (1, 1, hb, 128) rows (a
-    head's ``K`` values, zeros after them); ``a`` (the decay), ``b`` (the
-    step) and ``bv`` (``b v``) (1, 1, hb V) rows, a head's scalars laid
-    along its lanes; ``o`` (1, 1, hb V)."""
+    head's ``K`` values, zeros after them); ``b`` (the step) and ``bv`` (``b
+    v``) (1, 1, hb V) rows, a head's scalars laid along its lanes; ``a``
+    (the decay) such a row where a head has ONE decay, or (1, 1, hb, 128)
+    rows as ``k``'s where it has one a channel of the key (it then enters as
+    a column along the head's lanes, as the key does): its block's rank says
+    which; ``o`` (1, 1, hb V)."""
     del slots_ref  # only the index maps read it
     K, V = state_ref.shape[1], value_dim
     hb = k_ref.shape[2]
@@ -223,7 +304,8 @@ def _delta_update_kernel(slots_ref, state_ref, k_ref, q_ref, a_ref, b_ref,
     for h in range(0, hb, 2):
         at = slice(h * V, (h + 2) * V)
         kc, qc = pair(k_ref, h), pair(q_ref, h)
-        sd = state_ref[0, :, at].astype(jnp.float32) * a_ref[0, :, at]
+        ac = pair(a_ref, h) if len(a_ref.shape) == 4 else a_ref[0, :, at]
+        sd = state_ref[0, :, at].astype(jnp.float32) * ac
         u = jnp.sum(sd * kc, axis=0, keepdims=True)              # S'^T k
         sn = sd + kc * (bv_ref[0, :, at] - b_ref[0, :, at] * u)
         out_ref[0, :, at] = sn.astype(out_ref.dtype)
@@ -236,8 +318,8 @@ def _delta_decode_update_call(slab, slots, k, q, a, b, bv, heads: int,
                               heads_block: int, interpret: bool):
     """The in-place update (its own jitted name: the kernel's operation in a
     trace takes it). ``slab`` (S, K, H V); ``k``, ``q`` (B, H, K) float32;
-    ``a``, ``b``, ``bv`` (B, H V) float32 rows. Returns ``(slab, o (B, H
-    V))``."""
+    ``b``, ``bv`` (B, H V) float32 rows; ``a`` such a row (a decay a head) or
+    (B, H, K) (a decay a channel). Returns ``(slab, o (B, H V))``."""
     _, K, HV = slab.shape
     B, H, hb = k.shape[0], heads, heads_block
     V, nb = HV // H, H // hb
@@ -256,7 +338,8 @@ def _delta_decode_update_call(slab, slots, k, q, a, b, bv, heads: int,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B, nb),
-            in_specs=[slot_spec, head_spec, head_spec, lane_spec, lane_spec,
+            in_specs=[slot_spec, head_spec, head_spec,
+                      head_spec if a.ndim == 3 else lane_spec, lane_spec,
                       lane_spec],
             out_specs=[lane_spec, slot_spec]),
         out_shape=[jax.ShapeDtypeStruct((B, 1, HV), jnp.float32),
@@ -267,7 +350,8 @@ def _delta_decode_update_call(slab, slots, k, q, a, b, bv, heads: int,
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=32 << 20),
         interpret=interpret,
-    )(slots, slab, rows(k), rows(q), a[:, None, :], b[:, None, :],
+    )(slots, slab, rows(k), rows(q),
+      rows(a) if a.ndim == 3 else a[:, None, :], b[:, None, :],
       bv[:, None, :])
     return slab, o[:, 0, :]
 
@@ -284,18 +368,20 @@ def delta_decode_update(slab, slots, q, k, v, g, beta, kernel: str = "gather",
     B, H, K = q.shape
     V = v.shape[-1]
     qf, kf, vf = q.astype(f32), k.astype(f32), v.astype(f32)
-    a, bt = jnp.exp(g.astype(f32)), beta.astype(f32)               # (B, H)
+    a, bt = jnp.exp(g.astype(f32)), beta.astype(f32)    # (B, H[, K]), (B, H)
     hb = decode_heads_block(H, K, V)
     if kernel == "pallas" and hb:
         def lanes(x):   # (B, H) -> (B, H V): a head's scalar over its lanes
             return jnp.repeat(x, V, axis=1)
 
         slab, o = _delta_decode_update_call(
-            slab, slots.astype(jnp.int32), kf, qf, lanes(a), lanes(bt),
+            slab, slots.astype(jnp.int32), kf, qf,
+            a if a.ndim == 3 else lanes(a), lanes(bt),
             (bt[..., None] * vf).reshape(B, H * V), heads=H, heads_block=hb,
             interpret=_interpret() if interpret is None else interpret)
         return slab, o.reshape(B, H, V)
-    s = slab_to_state(slab[slots].astype(f32), H) * a[..., None, None]
+    s = slab_to_state(slab[slots].astype(f32), H) * (
+        a[..., None] if a.ndim == 3 else a[..., None, None])
     d = bt[..., None] * (vf - jnp.einsum("bhkv,bhk->bhv", s, kf))
     s = s + kf[..., None] * d[..., None, :]
     o = jnp.einsum("bhkv,bhk->bhv", s, qf)

@@ -457,6 +457,7 @@ class ServeEngine:
             self._mixer_tokens = (
                 "ssm_tokens" if self._spec.ssm is not None else
                 "delta_tokens" if self._spec.delta is not None else
+                "kda_tokens" if self._spec.kda is not None else
                 "lightning_tokens" if self._spec.lightning is not None else
                 "conv_tokens")
             # a shared page is no use without the recurrent state at the
@@ -472,7 +473,8 @@ class ServeEngine:
                     raise ValueError(
                         f"bucket {b}'s prefill chunk of {width} tokens is "
                         f"not whole blocks of the chunked scan ({chunk}: "
-                        f"mamba_chunk_size / linear_chunk_size)")
+                        f"mamba_chunk_size / linear_chunk_size / "
+                        f"kda_chunk_size)")
             self._state_slots = int(
                 state_slots or 1 + len(self.buckets) * self.max_batch)
             self._state_slot_bytes = self._spec.state_slot_bytes(

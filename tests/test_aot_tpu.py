@@ -1225,3 +1225,106 @@ def test_lightning_update_kernel_mosaic_compiles_in_place_at_published_widths(
     m = compiled.memory_analysis()
     assert m.alias_size_in_bytes >= slab_bytes
     assert m.temp_size_in_bytes < slab_bytes // 8
+
+
+def test_kda_update_kernel_mosaic_compiles_in_place_at_published_widths(
+        one_chip):
+    """The decode state update with a decay a CHANNEL (``ops/delta_rule.py``,
+    the ``solar_open2`` cell's shapes: 128 rows, 64 heads of 128 x 128, the
+    slab ``(193, 128, 8192)`` whole lane tiles head by head, 16 heads a
+    block) compiles through Mosaic for a v5e with the decay entering as a
+    column along its head's lanes, and updates the slab IN PLACE: the 810 MB
+    slab is aliased to the output and no temporary of its size appears. It
+    is the kernel of the ``olmo_hybrid`` cell (the test above): the decay's
+    rank picks the operand's form."""
+    from marlin_tpu.ops import delta_rule
+
+    B, H, K, V, S = 128, 64, 128, 128, 193
+    slab_bytes = S * K * H * V * 4
+
+    def st(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def step(slab, slots, q, k, v, g, b):
+        return delta_rule.delta_decode_update(slab, slots, q, k, v, g, b,
+                                              kernel="pallas",
+                                              interpret=False)
+
+    assert delta_rule.decode_heads_block(H, K, V) == 16
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+        st((S, K, H * V)), st((B,), jnp.int32), st((B, H, K)),
+        st((B, H, K)), st((B, H, V)), st((B, H, K)), st((B, H))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= slab_bytes
+    assert m.temp_size_in_bytes < slab_bytes // 8
+
+
+def test_solaropen2_programs_compile_for_v5e_at_the_cells_sizes(one_chip):
+    """Both paged programs of the ``solar_open2`` family at the cell's
+    widths, rows, page and table (hidden 4096, 64 / 8 heads x 128, 64 KDA
+    heads of 128 x 128, 40 of 320 experts of 1280 in every layer, 24576 rows
+    untied, 128 rows, pages of 256, a table of 32, a chunk of 512, 3585
+    pages, 193 slots), the GQA layer and one KDA layer (both on experts: the
+    first family whose layer keeps a state slot AND holds a share of the
+    experts), compiled whole for a v5e: the attention kernel, the state
+    update and the six grouped matmuls inside, the slabs aliased and no
+    temporary the size of a slab; so does the snapshot's copy. With the two
+    KDA layers left out here (0.95 GB of weights each) the programs' peaks
+    stay under the chip's 16.9e9."""
+    import json
+    import os
+
+    from marlin_tpu.models import hybrid
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmarks", "configs",
+                           "solar-open2-ep8-l4.json")) as f:
+        cfg = json.load(f)
+    eng, share = cfg["engine"], cfg["deployment_share"]
+    spec = hybrid.ModelSpec.from_config(
+        dict(cfg, num_hidden_layers=2), experts_total=share["experts_total"],
+        first_expert=share["first_expert"])
+    assert [(ly.attn, ly.ffn) for ly in spec.layers] == [("full", "moe"),
+                                                         ("kda", "moe")]
+    B, page_len = eng["max_batch"], eng["page_len"]
+    W = sum(eng["buckets"][-1]) // page_len
+    slots = eng["state_slots"] + eng["snapshot_slots"]
+
+    def sds(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            jnp.shape(x), x.dtype, sharding=one_chip), tree)
+
+    def st(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    params = sds(jax.eval_shape(
+        lambda: hybrid.init_params(spec, jax.random.key(0))))
+    pages = sds(jax.eval_shape(lambda: hybrid.init_kv_pages(
+        spec, eng["num_pages"], 0, page_len, state_slots=slots)))
+    assert [a.shape for a in pages["l1"]] == [(slots, 128, 8192),
+                                              (slots, 3, 24576)]
+    kv = 2 * eng["num_pages"] * page_len * 1024 * 2
+    state = slots * spec.state_slot_bytes()
+    assert spec.state_slot_bytes() == 4194304 + 147456
+    with mt.config_context(pallas_interpret=False):
+        decode = hybrid._lm_decode_paged_spec_jit.trace(
+            params, pages, st((B, W)), st((B, 0)), st((B,)), st((B,)),
+            st((B,)), st((B,), jnp.uint32), st((B,), jnp.float32),
+            st((B,), jnp.float32), st((B,)), spec=spec, page_len=page_len,
+            kernel="pallas", prev_tokens=st((B,)), prev_index=st((B,)),
+            state_slots=st((B,))).lower().compile()
+        prefill = hybrid._lm_prefill_paged_spec_jit.trace(
+            params, pages, st((W + 2,)), st((0,)),
+            st((eng["prefill_chunk"],)), st(()), st(()), st((), jnp.uint32),
+            st((), jnp.float32), st((), jnp.float32), st(()), spec=spec,
+            page_len=page_len, state_slot=st(())).lower().compile()
+        copy = hybrid._state_slot_copy_jit.trace(
+            pages, st(()), st(()), spec=spec).lower().compile()
+    assert decode.as_text().count("tpu_custom_call") >= 1 + 1 + 6
+    assert prefill.as_text().count("tpu_custom_call") >= 6
+    for c in (decode, prefill, copy):
+        m = c.memory_analysis()
+        assert m.alias_size_in_bytes >= kv + state
+        assert m.temp_size_in_bytes < min(kv, state) // 2
+        assert m.peak_memory_in_bytes < 16.9e9

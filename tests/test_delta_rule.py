@@ -140,12 +140,13 @@ def _prompt(n: int, seed: int = 0):
 
 
 def _naive(q, k, v, g, b, S, decay_first=True):
-    """The three lines, a token at a time, float64. ``S`` (H, K, V)."""
+    """The three lines, a token at a time, float64. ``S`` (H, K, V); ``g``
+    (T, H) one decay a head or (T, H, K) one a channel of the key."""
     q, k, v, g, b = (np.asarray(a, np.float64) for a in (q, k, v, g, b))
     S = np.asarray(S, np.float64)
     out = []
     for t in range(q.shape[0]):
-        Sd = S * np.exp(g[t])[:, None, None]
+        Sd = S * np.exp(g[t]).reshape(*g[t].shape, *(1,) * (3 - g[t].ndim))
         against = Sd if decay_first else S
         d = b[t][:, None] * (v[t] - np.einsum("hkv,hk->hv", against, k[t]))
         S = Sd + k[t][:, :, None] * d[:, None, :]

@@ -157,17 +157,23 @@ def test_a_bfloat16_model_stays_near_the_reference(model):
 # (b), (c) ------------------------------------------------------------------
 
 
-def _expert_layer_by_hand(mp, h, top_k, scale):
-    """The uncut expert layer, token by token, pick by pick."""
+def _expert_layer_by_hand(mp, h, top_k, scale, scoring="softmax"):
+    """The uncut expert layer, token by token, pick by pick. ``sigmoid``:
+    the picks by score + ``e_bias``, the weights from the scores alone."""
     h = np.asarray(h, np.float64)
     f = lambda a: np.asarray(a, np.float64)  # noqa: E731
     silu = lambda x: x / (1 + np.exp(-x))  # noqa: E731
     logits = h @ f(mp["router"])
-    s = np.exp(logits - logits.max(-1, keepdims=True))
-    s /= s.sum(-1, keepdims=True)
+    if scoring == "sigmoid":
+        s = 1 / (1 + np.exp(-logits))
+        by = s + f(mp["e_bias"])
+    else:
+        s = np.exp(logits - logits.max(-1, keepdims=True))
+        s /= s.sum(-1, keepdims=True)
+        by = s
     out = (silu(h @ f(mp["s_gate"])) * (h @ f(mp["s_up"]))) @ f(mp["s_down"])
     for t in range(h.shape[0]):
-        picks = np.argsort(-s[t], kind="stable")[:top_k]
+        picks = np.argsort(-by[t], kind="stable")[:top_k]
         for i in picks:
             e = (silu(h[t] @ f(mp["e_gate"][i])) * (h[t] @ f(mp["e_up"][i]))
                  ) @ f(mp["e_down"][i])
@@ -182,26 +188,33 @@ def _share(mp, first, held):
     return cut
 
 
-@pytest.mark.parametrize("shares", [4, 2, 1])
-def test_the_shares_of_the_expert_layer_add_up(model, shares):
+@pytest.mark.parametrize("shares, scoring", [
+    (4, "softmax"), (2, "softmax"), (1, "softmax"),
+    (8, "sigmoid")])    # the solar_open2 family's: eight chips share a layer
+def test_the_shares_of_the_expert_layer_add_up(model, shares, scoring):
     """What each of ``shares`` chips computes for its own experts, with the
     shared expert (which every chip computes alike) counted once, is the
-    uncut layer."""
+    uncut layer; under sigmoid scoring with a bias that only selects as
+    under the softmax."""
     _, spec, params = model
     mp = params["l2"]["moe"]
+    if scoring == "sigmoid":
+        mp = dict(mp, e_bias=0.05 * jax.random.normal(jax.random.key(6),
+                                                      (16,), jnp.float32))
     h = jax.random.normal(jax.random.key(5), (24, 64), jnp.float32)
     valid = jnp.ones((24,), bool)
     held = 16 // shares
-    outs = [moe_experts_ffn(_share(mp, k * held, held), h, valid, top_k=4,
-                            first_expert=k * held, routed_scale=2.5)
+    kw = dict(top_k=4, routed_scale=2.5, scoring=scoring)
+    outs = [moe_experts_ffn(_share(mp, k * held, held), h, valid,
+                            first_expert=k * held, **kw)
             for k in range(shares)]
     shared_only, _ = moe_experts_ffn(_share(mp, 0, held), h,
-                                     jnp.zeros((24,), bool), top_k=4,
-                                     first_expert=0, routed_scale=2.5)
+                                     jnp.zeros((24,), bool), first_expert=0,
+                                     **kw)
     total = sum(np.asarray(o, np.float64) for o, _ in outs) \
         - (shares - 1) * np.asarray(shared_only, np.float64)
-    np.testing.assert_allclose(total, _expert_layer_by_hand(mp, h, 4, 2.5),
-                               atol=2e-5)
+    np.testing.assert_allclose(
+        total, _expert_layer_by_hand(mp, h, 4, 2.5, scoring), atol=2e-5)
     counts = np.sum([np.asarray(c) for _, c in outs], axis=0)
     assert counts[1] == 24 * 4          # every assignment fell on one share
     assert all(int(c[0]) == 24 * 4 for _, c in outs)

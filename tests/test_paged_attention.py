@@ -503,7 +503,10 @@ def test_every_flat_slab_of_the_configurations_meets_one_page_body():
     """The package has ONE arithmetic for a rank-3 slab: at the heads of
     every attention layer of every benchmark configuration that reads as a
     spec (Falcon-H1 4 x 5, Laguna 8 x 6 and 8 x 9, Olmo-Hybrid 30 x 1, LFM2
-    8 x 4 of 64; the latent configuration holds no (K, V) slab) the traced
+    8 x 4 of 64, Solar-Open2 8 x 8; the latent configuration holds no (K, V)
+    slab; a configuration that holds a share of its experts reads as a spec
+    here without the share's arguments, which no head count depends on) the
+    traced
     kernel holds two matmuls, a page's scores and its values, whatever the
     count of heads: no loop over heads around them."""
     import glob
@@ -529,7 +532,7 @@ def test_every_flat_slab_of_the_configurations_meets_one_page_body():
                    for ly in spec.layers if ly.attn in ("full", "sliding")}
     assert shapes == {(4, 5, 128, False), (8, 6, 128, False),
                       (8, 9, 128, True), (30, 1, 128, False),
-                      (8, 4, 64, False)}
+                      (8, 4, 64, False), (8, 8, 128, False)}
     for kvh, group, dh, windowed in shapes:
         q = jnp.zeros((2, kvh, group, dh), jnp.bfloat16)
         slab = jnp.zeros((5, PAGE_LEN, kvh * dh), jnp.bfloat16)

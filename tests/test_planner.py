@@ -151,6 +151,7 @@ def _spec_model(cfg):
 
 def _family(name):
     from tests import test_delta_rule as olmo
+    from tests import test_kda as solar
     from tests.test_hybrid_model import tiny_cfg as laguna_cfg
     from tests.test_latent_attention import tiny_cfg as mistral_cfg
     from tests.test_short_conv import tiny_cfg as lfm2_cfg
@@ -161,6 +162,13 @@ def _family(name):
         return _dense()
     if name == "dense-gqa":
         return _dense(kv_heads=2)
+    if name == "kda+experts":   # a state slot AND a held share of experts
+        import jax
+
+        from marlin_tpu.models import hybrid
+
+        spec = solar._spec(solar.tiny_cfg())
+        return hybrid.init_params(spec, jax.random.key(0)), spec
     return _spec_model({"window-ring+experts": laguna_cfg,
                         "latent+experts": mistral_cfg,
                         "ssm": falcon_cfg, "delta-rule": olmo.tiny_cfg,
@@ -190,7 +198,8 @@ def _held_bytes(pages, heads):
 
 
 FAMILIES = ["dense", "dense-gqa", "window-ring+experts", "latent+experts",
-            "ssm", "delta-rule", "short-conv", "sparse+lightning"]
+            "ssm", "delta-rule", "short-conv", "sparse+lightning",
+            "kda+experts"]
 
 
 @pytest.mark.parametrize("family", FAMILIES)
@@ -213,7 +222,7 @@ def test_admission_charges_the_pages_and_the_slot_the_pool_holds(family):
             assert spec.state_slot_bytes() == slot
         assert (sliding > 0) == (family == "window-ring+experts")
         assert (slot > 0) == (family in ("ssm", "delta-rule", "short-conv",
-                                         "sparse+lightning"))
+                                         "sparse+lightning", "kda+experts"))
         # ... and a request is charged exactly that, times what it can pin
         charged = []
         for n, steps in ((5, 3), (40, 20)):

@@ -536,13 +536,30 @@ def test_a_layer_without_a_shared_expert_draws_and_runs_none(model):
     (2048, 4096, (128, 1024, 1024)),    # ... down
     (2048, 1792, (128, 2048, 1024)),    # LFM2, gate / up
     (1792, 2048, (128, 1792, 1024)),    # ... down
+    (4096, 1280, (128, 2048, 1280)),    # Solar-Open2, gate / up
+    (1280, 4096, (128, 1280, 1024)),    # ... down
     (5120, 1536, (128, 2048, 1024))])   # a wider stream, experts of 1536
 def test_the_grouped_matmuls_tile_follows_the_widths(k, n, want):
     """(128, 1024, 1024) wherever an expert's widths are whole tiles of 1024
-    (every call the Laguna and Mistral cells make); experts of 1792 take the
-    whole contraction in one tile (up to 2048) and 1024 columns, as read on
-    the chip; the rows stay 128."""
-    assert moe._gmm_tiling(k, n) == want
+    (every call the Laguna and Mistral cells make); experts of 1792 or 1280
+    take the whole contraction in one tile (up to 2048) and 1024 columns (a
+    width of at most 1280 whole), as read on the chip; the rows stay 128. The bfloat16 tiles of the expert
+    cells are pinned here: operands of two bytes are the default."""
+    assert moe._gmm_tiling(k, n) == moe._gmm_tiling(k, n, 2) == want
+
+
+@pytest.mark.parametrize("k, n, want", [
+    (4096, 2048, (128, 512, 1024)),     # Mistral's float32 check
+    (2048, 1792, (128, 1024, 1024)),    # LFM2's: 2048 x 1024 x 4 B twice
+    (1792, 2048, (128, 896, 1024)),     # filled the kernel's 16 MB
+    (4096, 1280, (128, 1024, 1280)),    # Solar-Open2's
+    (1280, 4096, (128, 640, 1024))])
+def test_float32_operands_take_half_the_contraction_a_tile(k, n, want):
+    """A float32 check's grouped matmul holds as many BYTES of the
+    contraction a tile as the bfloat16 one: half its length, whole lane
+    tiles, so its two weight buffers fit the kernel's VMEM."""
+    assert moe._gmm_tiling(k, n, 4) == want
+    assert want[1] * want[2] * 4 * 2 <= 10 << 20
 
 
 # the decode kernel at heads narrower than a lane tile ------------------------

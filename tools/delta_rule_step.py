@@ -1,9 +1,12 @@
 """What the gated delta rule costs alone on the chip, both forms.
 
-    chiprun --chips 1 -- python3 tools/delta_rule_step.py [--f32]
+    chiprun --chips 1 -- python3 tools/delta_rule_step.py [--f32] [--kda]
 
 At the ``serve.olmohybrid-sessions24`` cell's sizes (30 heads, keys of 96,
-values of 192; ``ops/delta_rule.py``):
+values of 192; ``ops/delta_rule.py``), or with ``--kda`` at the
+``serve.solaropen2-reason128`` cell's (64 heads of 128 x 128, a decay a
+CHANNEL of the key, 128 live rows of 193 slots, three layers; each form's
+``max_abs_diff`` from the token-by-token recurrence is printed too):
 
 - **the decode update**: 24 live rows of 53 slots, the slab ``(slots, 96,
   5760)`` float32 updated in place. ``pallas`` is the package's kernel,
@@ -33,9 +36,28 @@ import time
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
 
-H, K, V = 30, 96, 192
-ROWS, SLOTS, LAYERS = 24, 53, 12
 HBM, FLOPS = 819e9, 197e12
+
+
+def _recurrence(q, k, v, g, b, s):
+    """The three lines a token at a time, float32 at the highest precision:
+    ``q``, ``k`` (T, H, K), ``v`` (T, H, V), ``g`` (T, H[, K]), ``b`` (T,
+    H), ``s`` (H, K, V). Returns ``(o (T, H, V), s)``."""
+    import jax
+    import jax.numpy as jnp
+
+    def step(s, tok):
+        q_t, k_t, v_t, g_t, b_t = tok
+        a = jnp.exp(g_t)
+        s = s * (a[..., None] if a.ndim == 2 else a[:, None, None])
+        d = b_t[:, None] * (v_t - jnp.einsum("hkv,hk->hv", s, k_t,
+                                             precision="highest"))
+        s = s + k_t[:, :, None] * d[:, None, :]
+        return s, jnp.einsum("hkv,hk->hv", s, q_t, precision="highest")
+
+    f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
+    s, o = jax.lax.scan(step, f32(s), tuple(f32(x) for x in (q, k, v, g, b)))
+    return o, s
 
 
 def _best(fn, args, repeats: int = 5) -> float:
@@ -55,22 +77,36 @@ def _best(fn, args, repeats: int = 5) -> float:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--f32", action="store_true")
+    ap.add_argument("--kda", action="store_true")
     args = ap.parse_args(argv)
     import jax
     import jax.numpy as jnp
 
-    from benchmarks import costs_olmohybrid as costs
+    from benchmarks import costs_olmohybrid, costs_solaropen2
     from marlin_tpu.ops import delta_rule
 
     dev = jax.devices()[0]
     if dev.platform != "tpu":
         print(json.dumps({"ok": False, "why": "not a TPU"}))
         return 1
-    cfg = {"linear_num_key_heads": H, "linear_key_head_dim": K,
-           "linear_value_head_dim": V, "linear_conv_kernel_dim": 4,
-           "linear_chunk_size": 64, "num_hidden_layers": 1,
-           "layer_types": ["linear_attention"],
-           "compute_dtype": "float32" if args.f32 else "bfloat16"}
+    if args.kda:
+        H, K, V, ROWS, SLOTS, LAYERS = 64, 128, 128, 128, 193, 3
+        costs, least_scan = costs_solaropen2, \
+            costs_solaropen2.kda_prefill_least_seconds
+        cfg = {"linear_attn_config": {"num_heads": H, "head_dim": K,
+                                      "short_conv_kernel_size": 4},
+               "kda_chunk_size": 64, "num_hidden_layers": 1,
+               "gqa_layers": []}
+    else:
+        H, K, V, ROWS, SLOTS, LAYERS = 30, 96, 192, 24, 53, 12
+        costs, least_scan = costs_olmohybrid, \
+            costs_olmohybrid.gdn_prefill_least_seconds
+        cfg = {"linear_num_key_heads": H, "linear_key_head_dim": K,
+               "linear_value_head_dim": V, "linear_conv_kernel_dim": 4,
+               "linear_chunk_size": 64, "num_hidden_layers": 1,
+               "layer_types": ["linear_attention"]}
+    cfg["compute_dtype"] = "float32" if args.f32 else "bfloat16"
+    decays = (H, K) if args.kda else (H,)   # one a channel / one a head
     peaks = {"hbm_bytes_per_s": HBM, "bf16_flops_per_s": FLOPS}
     cd = jnp.float32 if args.f32 else jnp.bfloat16
     ks = jax.random.split(jax.random.key(0), 8)
@@ -84,7 +120,8 @@ def main(argv=None) -> int:
     q = unit(jax.random.normal(ks[1], (ROWS, H, K))) * K ** -0.5
     k = unit(jax.random.normal(ks[2], (ROWS, H, K)))
     v = jax.random.normal(ks[3], (ROWS, H, V))
-    g = -jax.random.uniform(ks[4], (ROWS, H), minval=0.001, maxval=0.1)
+    g = -jax.random.uniform(ks[4], (ROWS,) + decays, minval=0.001,
+                            maxval=0.1)
     b = jax.random.uniform(ks[5], (ROWS, H), minval=0.0, maxval=2.0)
     least = 2.0 * costs.state_bytes(cfg) / HBM
     outs = {}
@@ -107,10 +144,19 @@ def main(argv=None) -> int:
             "what": "decode_update", "kernel": kernel, "rows": ROWS,
             "us_a_live_row_and_layer": us,
             "least_us": 1e6 * least, "roofline_pct": 100 * 1e6 * least / us,
-            "ms_a_step_of_12_layers": 1e3 * seconds}), flush=True)
+            "layers": LAYERS, "ms_a_step": 1e3 * seconds}), flush=True)
+    # one layer's update of every live row, by the recurrence's one step
+    want = jax.vmap(lambda s, *tok: _recurrence(
+        *(x[None] for x in tok), s)[0][0])(
+            delta_rule.slab_to_state(slab[slots], H), q, k, v, g, b)
+    one = {kernel: delta_rule.delta_decode_update(
+        jnp.array(slab), slots, q, k, v, g, b, kernel=kernel,
+        interpret=False)[1] for kernel in outs}
     print(json.dumps({"what": "decode_update", "max_abs_diff_pallas_gather":
                       float(jnp.abs(outs["pallas"] - outs["gather"]).max()),
-
+                      "max_abs_diff_from_the_recurrence": {
+                          kernel: float(jnp.abs(o - want).max())
+                          for kernel, o in one.items()},
                       "output_scale": float(jnp.abs(outs["gather"]).max())}),
           flush=True)
 
@@ -119,7 +165,8 @@ def main(argv=None) -> int:
         q = (unit(jax.random.normal(ks[1], (T, H, K))) * K ** -0.5).astype(cd)
         k = unit(jax.random.normal(ks[2], (T, H, K))).astype(cd)
         v = jax.random.normal(ks[3], (T, H, V)).astype(cd)
-        g = -jax.random.uniform(ks[4], (T, H), minval=0.001, maxval=0.1)
+        g = -jax.random.uniform(ks[4], (T,) + decays, minval=0.001,
+                                maxval=0.1)
         b = jax.random.uniform(ks[5], (T, H), minval=0.0, maxval=2.0)
         s0 = jax.random.normal(ks[6], (K, H, V), jnp.float32)
 
@@ -133,14 +180,21 @@ def main(argv=None) -> int:
             return s, acc
 
         seconds = _best(scan, (s0, q, k, v, g, b))
-        least = costs.gdn_prefill_least_seconds(T, 1, cfg, peaks)
+        least = least_scan(T, 1, cfg, peaks)
         ns = 1e9 * seconds / (LAYERS * T)
+        o, _ = delta_rule.delta_chunk_scan(q, k, v, g, b, s0, block=64)
+        want, _ = _recurrence(q, k, v, g, b, jnp.moveaxis(s0, 1, 0))
         print(json.dumps({
             "what": "chunk_scan", "tokens": T, "dtype": str(jnp.dtype(cd)),
             "ns_a_token_and_layer": ns,
             "least_ns": 1e9 * least["seconds"] / T, "bound": least["bound"],
             "roofline_pct": 100 * 1e9 * least["seconds"] / T / ns,
-            "ms_a_chunk_of_12_layers": 1e3 * seconds}), flush=True)
+            "layers": LAYERS, "ms_a_chunk": 1e3 * seconds,
+            "max_abs_diff_from_the_recurrence":
+            float(jnp.abs(o - want).max()),
+            "output_scale": float(jnp.abs(want).max())}), flush=True)
+        if args.kda:
+            continue
 
         nc, C = T // 64, 64
         A = jnp.tril(jax.random.normal(ks[7], (nc, H, C, C)) * 0.1, -1)

@@ -64,8 +64,15 @@ table, slot 0 the dummy. Prefill (:func:`~marlin_tpu.ops.ssm
 .ssd_chunk_scan`) enters a chunk with the slot's state and tail and leaves
 them after the chunk's last valid token; a row's first chunk enters with
 zeros whatever the slot's last row left (:func:`_enter_state`). Decode
-(:func:`~marlin_tpu.ops.ssm.ssd_decode_update`) reads and writes each live
-row's slot once, in place. The family's fixed multipliers sit on every
+reads and writes each live row's slot once, in place: the state
+(:func:`~marlin_tpu.ops.ssm.ssd_decode_update`) and the tail beside it
+(:func:`~marlin_tpu.ops.ssm.conv_step_slots`: no tails array is gathered,
+scattered or copied in a decode program). A slot of the tails array is whole
+tiles in one piece for that, ``((conv - 1) * channels / 128`` rounded up to
+8``, 128)`` where the channels are whole lane tiles
+(:func:`~marlin_tpu.ops.ssm.tail_slot_shape`; a prefill chunk reshapes the
+one row it enters and leaves). The family's
+fixed multipliers sit on every
 branch (:func:`_parallel_mixers`, :func:`_ssm_mixer`, :func:`_ffn_half`,
 :func:`_embed`, :func:`_head_logits`); a model without them traces to the
 programs it traced to before.
@@ -83,7 +90,9 @@ the model's ``full`` layers and the state slot the others. The family's block
 x))``), norms a full layer's queries and keys over the whole projection, and
 has no rotary embedding. Prefill (:func:`~marlin_tpu.ops.delta_rule
 .delta_chunk_scan`) and decode (:func:`~marlin_tpu.ops.delta_rule
-.delta_decode_update`) meet the slot as a state-space mixer's do.
+.delta_decode_update`, the tail by :func:`~marlin_tpu.ops.ssm
+.conv_step_slots`) meet the slot as a state-space mixer's do: a decode call
+reads and writes a row's state and its tail in the slot, in place.
 
 **A layer whose only memory is a convolution's tail** (:class:`ConvSpec`; the
 ``lfm2_moe`` configuration family's ``conv_*`` keys). A ``conv`` layer's
@@ -97,7 +106,9 @@ slot as the other mixers' arrays are: the programs, the slot-to-slot copy
 and :meth:`ModelSpec.state_slot_bytes` walk the arrays a layer HAS
 (:meth:`ConvSpec.slot_arrays`), not a (state, tail) pair. The convolution is
 :func:`~marlin_tpu.ops.ssm.causal_conv` / :func:`~marlin_tpu.ops.ssm
-.conv_step`, the state-space and delta-rule mixers' own. The block is the
+.conv_step`, the state-space and delta-rule mixers' own (decode gathers the
+live rows' tails and scatters them back: the slot-indexed step of the other
+two is not this layer's). The block is the
 generic pre-norm one; the family's ``full`` layer norms each head's queries
 and keys (one gain of ``head_dim`` for all heads) BEFORE the rotary
 embedding and has no head gate (``qk_norm``, ``head_gate``). A slot is tens
@@ -349,9 +360,11 @@ class SsmSpec:
     def slot_arrays(self) -> tuple:
         """What ONE row's slot holds in one layer, ``(shape, dtype)`` an
         array (dtype None: the compute dtype): the recurrent state, then the
-        convolution's tail."""
+        convolution's tail (:func:`~marlin_tpu.ops.ssm.tail_slot_shape`)."""
+        from ..ops.ssm import tail_slot_shape
+
         return (((self.heads, self.state, self.head_dim), self.state_dtype),
-                ((self.conv - 1, self.conv_dim), None))
+                (tail_slot_shape(self.conv, self.conv_dim), None))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -382,10 +395,13 @@ class DeltaSpec:
         """What ONE row's slot holds in one layer, ``(shape, dtype)`` an
         array (dtype None: the compute dtype): the recurrent state as the
         slab stores it (``(key_dim, heads * value_dim)``: whole lane tiles),
-        then the convolution's tail."""
+        then the convolution's tail
+        (:func:`~marlin_tpu.ops.ssm.tail_slot_shape`)."""
+        from ..ops.ssm import tail_slot_shape
+
         return (((self.key_dim, self.heads * self.value_dim),
                  self.state_dtype),
-                ((self.conv - 1, self.conv_dim), None))
+                (tail_slot_shape(self.conv, self.conv_dim), None))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1494,7 +1510,11 @@ def init_kv_pages(spec: ModelSpec, num_pages: int, window_pages: int,
     layer with a state-space mixer has two more arrays after its pages,
     indexed by a row's STATE SLOT and not by a page id: the recurrent states
     ``(state_slots, heads, state, head_dim)`` in the mixer's ``state_dtype``
-    and the convolution tails ``(state_slots, conv - 1, conv_dim)``; slot 0
+    and the convolution tails ``(state_slots, (conv - 1) * conv_dim / 128``
+    rounded up to 8``, 128)`` (a slot whole tiles in one piece, which the
+    decode step writes in place; ``(state_slots, conv - 1, conv_dim)`` where
+    ``conv_dim`` is not whole lane tiles:
+    :func:`~marlin_tpu.ops.ssm.tail_slot_shape`); slot 0
     is the dummy. A ``linear`` (and a ``kda``) layer has those two arrays
     and nothing else (its states ``(state_slots, key_dim, heads * value_dim)``:
     :mod:`~marlin_tpu.ops.delta_rule`); a ``conv`` layer ONE, the tails
@@ -2387,7 +2407,8 @@ def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
             s0, t0 = _enter_state(chunk_start == 0, states[state_slot],
                                   tails[state_slot])
             conv, t1 = ssm_ops.causal_conv(
-                xbc, t0, sp["conv_w"], sp["conv_b"],
+                xbc, ssm_ops.slot_tails(t0, sm.conv, sm.conv_dim),
+                sp["conv_w"], sp["conv_b"],
                 jnp.clip(length - chunk_start, 0, C))
             xs, Bm, Cm = _scan_operands(sm, conv, xbc.dtype)
             with jax.named_scope("ssm_scan"):
@@ -2398,7 +2419,9 @@ def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
             new_state[name] = (
                 jax.lax.dynamic_update_index_in_dim(states, s1, state_slot,
                                                     0),
-                jax.lax.dynamic_update_index_in_dim(tails, t1, state_slot, 0))
+                jax.lax.dynamic_update_index_in_dim(
+                    tails, ssm_ops.tails_slots(t1, sm.conv, sm.conv_dim),
+                    state_slot, 0))
             return y.reshape(C, sm.d_inner)
 
         def mix_delta(qkv, g, beta, lp, name=name):
@@ -2411,7 +2434,8 @@ def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
             s0, t0 = _enter_state(chunk_start == 0, states[state_slot],
                                   tails[state_slot])
             conv, t1 = ssm_ops.causal_conv(
-                qkv, t0, lp["conv_w"], jnp.zeros((ds.conv_dim,), qkv.dtype),
+                qkv, ssm_ops.slot_tails(t0, ds.conv, ds.conv_dim),
+                lp["conv_w"], jnp.zeros((ds.conv_dim,), qkv.dtype),
                 jnp.clip(length - chunk_start, 0, C))
             q, k, v = _delta_operands(ds, conv, qkv.dtype)
             with jax.named_scope(
@@ -2425,7 +2449,9 @@ def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
             new_state[name] = (
                 jax.lax.dynamic_update_index_in_dim(
                     states, s1.reshape(states.shape[1:]), state_slot, 0),
-                jax.lax.dynamic_update_index_in_dim(tails, t1, state_slot, 0))
+                jax.lax.dynamic_update_index_in_dim(
+                    tails, ssm_ops.tails_slots(t1, ds.conv, ds.conv_dim),
+                    state_slot, 0))
             return o
 
         def mix_conv(s, lp, name=name):
@@ -2680,18 +2706,19 @@ def _lm_decode_paged_spec_jit(params, pages, gtables, wtables, positions,
         tables, pids, first_page, low = per_class.get(ly.attn, one_table)
 
         def mix(xbc, dt, sp, name=name):
-            # one token a row: each live row's slot read and written once;
-            # the rows no live row fills name the dummy slot 0
+            # one token a row: each live row's slot read and written once,
+            # tail and state alike; the rows no live row fills name the
+            # dummy slot 0
             sm = spec.ssm
             states, tails = pages[name][2:]
             with jax.named_scope("ssm_update"):
-                conv, t1 = ssm_ops.conv_step(xbc, tails[state_slots],
-                                             sp["conv_w"], sp["conv_b"])
+                conv, tails = ssm_ops.conv_step_slots(
+                    tails, state_slots, xbc, sp["conv_w"], sp["conv_b"],
+                    kernel=kernel)
                 xs, Bm, Cm = _scan_operands(sm, conv, xbc.dtype)
                 states, y = ssm_ops.ssd_decode_update(
                     states, state_slots, xs, dt, -jnp.exp(sp["A_log"]), Bm,
                     Cm, sp["D"], kernel=kernel)
-                tails = tails.at[state_slots].set(t1)
             new_pages[name] += (states, tails)
             return y.reshape(B, sm.d_inner)
 
@@ -2702,13 +2729,12 @@ def _lm_decode_paged_spec_jit(params, pages, gtables, wtables, positions,
             states, tails = pages[name]
             with jax.named_scope(
                     "delta_update" if spec.kda is None else "kda_update"):
-                conv, t1 = ssm_ops.conv_step(
-                    qkv, tails[state_slots], lp["conv_w"],
-                    jnp.zeros((ds.conv_dim,), qkv.dtype))
+                conv, tails = ssm_ops.conv_step_slots(
+                    tails, state_slots, qkv, lp["conv_w"],
+                    jnp.zeros((ds.conv_dim,), qkv.dtype), kernel=kernel)
                 q, k, v = _delta_operands(ds, conv, qkv.dtype)
                 states, o = delta_rule.delta_decode_update(
                     states, state_slots, q, k, v, g, beta, kernel=kernel)
-                tails = tails.at[state_slots].set(t1)
             new_pages[name] = (states, tails)
             return o
 

@@ -30,7 +30,14 @@ tile; the contraction with ``C`` is a sum over sublanes.
 
 The causal depthwise convolution that feeds the recurrence is here too
 (:func:`causal_conv`, :func:`conv_step`): ``taps`` taps over time a channel,
-carried across calls as the last ``taps - 1`` inputs (the "tail").
+carried across calls as the last ``taps - 1`` inputs (the "tail"). A decode
+call reads and writes each live row's tail IN ITS SLOT, as the state beside
+it is (:func:`conv_step_slots`; ``kernel="pallas"``: one
+:func:`_conv_slots_call` a layer, the slot id scalar-prefetched, the slab
+aliased to the output, the taps and the bias resident; ``kernel="gather"``:
+:func:`conv_step`, the reference, on a gathered copy). A slot of the slab is
+whole tiles in one piece for that (:func:`tail_slot_shape`). The delta-rule
+mixers' tails (:mod:`~marlin_tpu.ops.delta_rule`) take the same step.
 """
 
 from __future__ import annotations
@@ -44,8 +51,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .pallas_kernels import _interpret
 
-__all__ = ["causal_conv", "conv_step", "ssd_chunk_scan", "ssd_decode_update",
-           "decode_update_supported"]
+__all__ = ["causal_conv", "conv_step", "conv_step_slots", "tail_slot_shape",
+           "slot_tails", "tails_slots", "conv_slots_supported",
+           "ssd_chunk_scan", "ssd_decode_update", "decode_update_supported"]
 
 _LANES = 128
 
@@ -77,6 +85,175 @@ def conv_step(u, tails, w, b):
     out = b.astype(jnp.float32)[None, :] + jnp.einsum(
         "bkc,kc->bc", ext.astype(jnp.float32), w.astype(jnp.float32))
     return out, ext[:, 1:].astype(tails.dtype)
+
+
+def conv_slots_supported(channels: int) -> bool:
+    """Whether :func:`_conv_slots_call` takes a tail of ``channels``
+    channels: whole 128-lane tiles."""
+    return channels % _LANES == 0
+
+
+def tail_slot_shape(taps: int, channels: int) -> tuple:
+    """What ONE slot of a tails slab holds. Where the channels are whole lane
+    tiles, ``(rows, 128)``: the ``(taps - 1, channels)`` values in that order
+    (tap ``k`` the rows ``k * channels / 128`` onward), then zeros up to a
+    whole sublane tile of 8 rows, so that a slot is whole tiles, lies in one
+    piece and can be copied alone. ``(taps - 1, channels)`` otherwise. (XLA
+    keeps ``(slots, taps - 1, channels)`` with the SLOTS on the sublanes, two
+    slots to a 32-bit word in bfloat16: no slot can then be written alone,
+    and a program that tries copies the whole array into another layout and
+    back.) :func:`slot_tails` and :func:`tails_slots` pass between the two."""
+    if conv_slots_supported(channels):
+        return (-(-(taps - 1) * channels // _LANES // 8) * 8, _LANES)
+    return (taps - 1, channels)
+
+
+def slot_tails(values, taps: int, channels: int):
+    """``(..., *tail_slot_shape)`` as the slab holds it to ``(..., taps - 1,
+    channels)``."""
+    lead, n = values.shape[:-2], (taps - 1) * channels
+    return values.reshape(*lead, -1)[..., :n].reshape(*lead, taps - 1,
+                                                      channels)
+
+
+def tails_slots(tails, taps: int, channels: int):
+    """``(..., taps - 1, channels)`` to what the slab holds,
+    :func:`slot_tails`' inverse."""
+    lead, shape = tails.shape[:-2], tail_slot_shape(taps, channels)
+    flat = tails.reshape(*lead, -1)
+    pad = shape[0] * shape[1] - flat.shape[-1]
+    if pad:
+        flat = jnp.pad(flat, [(0, 0)] * len(lead) + [(0, pad)])
+    return flat.reshape(*lead, *shape)
+
+
+#: tails in flight either way in :func:`_conv_slots_kernel`, and the most
+#: rows one of its grid steps takes
+_TAILS_IN_FLIGHT = 4
+_ROWS_A_STEP = 8
+
+
+def _conv_slots_kernel(slots_ref, slab_ref, u_ref, w_ref, b_ref, out_ref,
+                       new_slab_ref, tails, shifted, fetched, stored, *,
+                       rows: int):
+    """Grid (rows / rb,), a step ``rb`` rows. ``slab`` / ``new_slab`` (S,
+    *:func:`tail_slot_shape`), the one array, left where it lies: row
+    ``r``'s tail is copied out of slot ``slots[r]`` into ``tails[r % D]`` ``D
+    - 1`` rows ahead of its turn, and the tail that follows it from
+    ``shifted[r % D]`` back into the slot behind it, awaited ``D`` rows on
+    (and at the last step): a row's copy is small, so several must be in
+    flight to fill the memory's pipe. Blocks: ``u`` (rb, R, 128); ``w``
+    (taps, R, 128) and ``b`` (R, 128), the same at every step; ``out`` (rb,
+    R, 128) float32."""
+    i = pl.program_id(0)
+    rb = u_ref.shape[0]
+    taps, R = w_ref.shape[:2]
+    D, held, f32 = tails.shape[0], (taps - 1) * R, jnp.float32
+
+    def fetch(r):
+        at = jax.lax.rem(r, D)
+        return pltpu.make_async_copy(slab_ref.at[slots_ref[r]], tails.at[at],
+                                     fetched.at[at])
+
+    def store(r):
+        at = jax.lax.rem(r, D)
+        return pltpu.make_async_copy(shifted.at[at],
+                                     new_slab_ref.at[slots_ref[r]],
+                                     stored.at[at])
+
+    @pl.when(i == 0)
+    def _():
+        for r in range(min(D - 1, rows)):
+            fetch(r).start()
+
+    for j in range(rb):
+        r = i * rb + j
+        at = jax.lax.rem(r, D)
+        pl.when(r + D - 1 < rows)(lambda r=r: fetch(r + D - 1).start())
+        fetch(r).wait()
+        pl.when(r >= D)(lambda r=r: store(r - D).wait())
+        u = u_ref[j]
+        acc = b_ref[...].astype(f32) + u.astype(f32) * w_ref[
+            taps - 1].astype(f32)
+        for k in range(taps - 1):
+            t = tails[at, k * R:(k + 1) * R, :]
+            acc = acc + t.astype(f32) * w_ref[k].astype(f32)
+            if k:
+                shifted[at, (k - 1) * R:k * R, :] = t
+        shifted[at, held - R:held, :] = u.astype(shifted.dtype)
+        if shifted.shape[1] > held:     # what pads the slot to whole tiles
+            shifted[at, held:, :] = jnp.zeros(
+                (shifted.shape[1] - held, _LANES), shifted.dtype)
+        out_ref[j] = acc
+        store(r).start()
+
+    @pl.when(i == pl.num_programs(0) - 1)
+    def _():
+        for r in range(max(rows - D, 0), rows):
+            store(r).wait()
+
+
+def _conv_slots_call(slab, slots, u, w, b, interpret: bool):
+    """The in-place step (no jitted name of its own: the kernel's operation
+    in a trace sits under the caller's scope). ``slab`` (S,
+    *:func:`tail_slot_shape`); ``u`` (B, R, 128); ``w`` (taps, R, 128);
+    ``b`` (R, 128). Returns ``(out (B, R, 128) float32, slab)``. The slab is
+    held to HBM: left to choose, XLA moves a slab of tens of megabytes into
+    VMEM ahead of the call and back behind it, all of it. The caller's
+    program must DONATE the slab, as the paged programs do: where XLA has to
+    copy a slab that is held to HBM, its memory assignment aborts the
+    compile (jaxlib 0.9.0: "Conflicting pending required assignment")."""
+    B, R = u.shape[:2]
+    rb = max(d for d in range(1, _ROWS_A_STEP + 1) if B % d == 0)
+    row_spec = pl.BlockSpec((rb, R, _LANES), lambda i, slots: (i, 0, 0))
+    in_place = pl.BlockSpec(memory_space=pltpu.HBM)
+    buffers = pltpu.VMEM((_TAILS_IN_FLIGHT, *slab.shape[1:]), slab.dtype)
+    copies = pltpu.SemaphoreType.DMA((_TAILS_IN_FLIGHT,))
+    return pl.pallas_call(
+        functools.partial(_conv_slots_kernel, rows=B),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B // rb,),
+            in_specs=[in_place, row_spec,
+                      pl.BlockSpec(w.shape, lambda i, slots: (0, 0, 0)),
+                      pl.BlockSpec(b.shape, lambda i, slots: (0, 0))],
+            out_specs=[row_spec, in_place],
+            scratch_shapes=[buffers, buffers, copies, copies]),
+        out_shape=[jax.ShapeDtypeStruct((B, R, _LANES), jnp.float32),
+                   pltpu.HBM(slab.shape, slab.dtype)],
+        # operand 0 is the scalar-prefetched slots; the slab is operand 1
+        input_output_aliases={1: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(slots, slab, u, w, b)
+
+
+def conv_step_slots(slab, slots, u, w, b, kernel: str = "gather",
+                    interpret: bool | None = None):
+    """:func:`conv_step` for B rows whose tails live in a slab: ``slab`` (S,
+    *:func:`tail_slot_shape`) the tails, ``slots`` (B,) each row's slot in
+    it (the rows without one name the dummy slot 0), ``u`` (B, ch), ``w``
+    (taps, ch), ``b`` (ch,). Returns ``(out (B, ch) float32, slab)``;
+    ``slab`` is advanced in the live rows' slots and the dummy's and nowhere
+    else. ``kernel="pallas"`` reads and writes each row's slot ONCE, in
+    place (:func:`_conv_slots_call`, where :func:`conv_slots_supported`;
+    the jitted program around it must donate ``slab``);
+    ``kernel="gather"`` is :func:`conv_step` on a gathered copy."""
+    B, ch = u.shape
+    taps = w.shape[0]
+    if slab.shape[1:] != tail_slot_shape(taps, ch):
+        raise ValueError(f"a slot of the tails slab is {slab.shape[1:]}, not "
+                         f"{tail_slot_shape(taps, ch)} (tail_slot_shape)")
+    if kernel == "pallas" and conv_slots_supported(ch):
+        R = ch // _LANES
+        out, slab = _conv_slots_call(
+            slab, slots.astype(jnp.int32), u.reshape(B, R, _LANES),
+            w.reshape(taps, R, _LANES), b.reshape(R, _LANES),
+            interpret=_interpret() if interpret is None else interpret)
+        return out.reshape(B, ch), slab
+    out, t1 = conv_step(u, slot_tails(slab[slots], taps, ch), w, b)
+    return out, slab.at[slots].set(tails_slots(t1, taps, ch))
 
 
 # ------------------------------------------------------- a chunk of one row

@@ -1260,6 +1260,57 @@ def test_kda_update_kernel_mosaic_compiles_in_place_at_published_widths(
     assert m.temp_size_in_bytes < slab_bytes // 8
 
 
+def _ops_as_large_as(text: str, shape: tuple) -> set:
+    """The names of a compiled program's operations whose output has
+    ``shape``, those that only hand an array on left out."""
+    import re
+
+    whole = re.escape("[" + ",".join(map(str, shape)) + "]")
+    return {m.group(1) for m in re.finditer(
+        r"= \w+" + whole + r"\S* ([\w\-]+)\(", text)} - {
+            "parameter", "bitcast", "get-tuple-element"}
+
+
+@pytest.mark.parametrize("rows, slots, channels, dtype", [
+    (128, 193, 24576, jnp.bfloat16), (24, 53, 11520, jnp.bfloat16),
+    (64, 65, 5120, jnp.bfloat16), (24, 53, 11520, jnp.float32)])
+def test_tail_step_kernel_mosaic_compiles_in_place_at_published_widths(
+        one_chip, rows, slots, channels, dtype):
+    """The convolution's decode step on tails in their slots
+    (``ops/ssm.py:conv_step_slots``) at the three tailed cells' sizes
+    (Solar-Open2 128 rows of 193 slots x 3 x 24576, Olmo-Hybrid 24 of 53 x 3
+    x 11520: 90 lane tiles a tap, not whole sublane tiles; Falcon-H1 64 of
+    65 x 3 x 5120) compiles through Mosaic for a v5e and advances the slab
+    IN PLACE and IN HBM: XLA keeps the slab row-major, a slot in one piece,
+    the slab is aliased to the output and nothing else in the program is as
+    large (left to choose, XLA moved the whole slab into VMEM ahead of the
+    call and back behind it)."""
+    from marlin_tpu.ops import ssm
+
+    def st(shape, dt=dtype):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def step(slab, slots, u, w, b):
+        return ssm.conv_step_slots(slab, slots, u, w, b, kernel="pallas",
+                                   interpret=False)
+
+    shape = (slots, *ssm.tail_slot_shape(4, channels))
+    assert ssm.conv_slots_supported(channels) and shape[2] == 128
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+        st(shape), st((rows,), jnp.int32), st((rows, channels)),
+        st((4, channels)), st((channels,))).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # XLA keeps the slab row-major, a slot's tiles one after another
+    assert "entry_computation_layout={(%s[%s]{2,1,0:T(8,128)" % (
+        {jnp.bfloat16: "bf16", jnp.float32: "f32"}[dtype],
+        ",".join(map(str, shape))) in text
+    assert _ops_as_large_as(text, shape) == set()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= np.prod(shape) * jnp.dtype(dtype).itemsize
+    assert m.temp_size_in_bytes == 0
+
+
 def test_solaropen2_programs_compile_for_v5e_at_the_cells_sizes(one_chip):
     """Both paged programs of the ``solar_open2`` family at the cell's
     widths, rows, page and table (hidden 4096, 64 / 8 heads x 128, 64 KDA
@@ -1303,7 +1354,7 @@ def test_solaropen2_programs_compile_for_v5e_at_the_cells_sizes(one_chip):
     pages = sds(jax.eval_shape(lambda: hybrid.init_kv_pages(
         spec, eng["num_pages"], 0, page_len, state_slots=slots)))
     assert [a.shape for a in pages["l1"]] == [(slots, 128, 8192),
-                                              (slots, 3, 24576)]
+                                              (slots, 576, 128)]
     kv = 2 * eng["num_pages"] * page_len * 1024 * 2
     state = slots * spec.state_slot_bytes()
     assert spec.state_slot_bytes() == 4194304 + 147456
@@ -1321,8 +1372,14 @@ def test_solaropen2_programs_compile_for_v5e_at_the_cells_sizes(one_chip):
             page_len=page_len, state_slot=st(())).lower().compile()
         copy = hybrid._state_slot_copy_jit.trace(
             pages, st(()), st(()), spec=spec).lower().compile()
-    assert decode.as_text().count("tpu_custom_call") >= 1 + 1 + 6
+    assert decode.as_text().count("tpu_custom_call") >= 1 + 1 + 1 + 6
     assert prefill.as_text().count("tpu_custom_call") >= 6
+    # the tails: a slot in one piece, advanced in place by its own kernel; no
+    # operation of the decode program makes another array of their size
+    # (until PR 52: two whole copies, a gather and a scatter a layer)
+    assert f"bf16[{slots},576,128]{{2,1,0:T(8,128)(2,1)}} parameter" \
+        in decode.as_text()
+    assert _ops_as_large_as(decode.as_text(), (slots, 576, 128)) == set()
     for c in (decode, prefill, copy):
         m = c.memory_analysis()
         assert m.alias_size_in_bytes >= kv + state

@@ -1,6 +1,7 @@
 """What the gated delta rule costs alone on the chip, both forms.
 
     chiprun --chips 1 -- python3 tools/delta_rule_step.py [--f32] [--kda]
+    chiprun --chips 1 -- python3 tools/delta_rule_step.py --tails [--f32]
 
 At the ``serve.olmohybrid-sessions24`` cell's sizes (30 heads, keys of 96,
 values of 192; ``ops/delta_rule.py``), or with ``--kda`` at the
@@ -21,6 +22,24 @@ CHANNEL of the key, 128 live rows of 193 slots, three layers; each form's
   peak and its bytes at the memory peak
   (``benchmarks/costs_olmohybrid.py``); and the triangular system alone
   (the solve of ``(I + A) X = [V | K]``), to see its share.
+
+With ``--tails`` it times, in the other measurements' place, **the
+convolution's decode step alone** (``ops/ssm.py``: every live row's tail
+advanced by one token) at the three cells that keep a tail beside a state:
+Solar-Open2 (128 live rows of 193 slots, 3 x 24576 values a row, three
+layers), Olmo-Hybrid (24 of 53, 3 x 11520, twelve) and Falcon-H1 (64 of 65,
+3 x 5120, six), bfloat16 (``--f32``: float32). ``pallas`` is the package's
+kernel (a row's slot read and written once, in place), ``gather`` the same
+arithmetic on a gathered copy, both on the slab as the pool keeps it
+(:func:`~marlin_tpu.ops.ssm.tail_slot_shape`); ``gather.rows`` is the form
+the programs ran until PR 52, the slab ``(slots, taps - 1, channels)``.
+Each is the slope between programs of 32 and of 96 passes over a model's
+layers chained on one donated slab (a pass alone, 0.03-1 ms, reads the host's
+dispatch; every step's output is summed, so no form can leave it out), in
+microseconds a live row and layer beside the least the tail's bytes allow
+(read and written once at the memory peak); ``ops_over_slab`` counts by name
+the operations of a pass whose output is as large as the slab (a ``copy`` is
+a copy of all of it).
 
 Prints one JSON line a measurement and ends with ``{"ok": true, "device":
 ...}``; needs a TPU (a time from the CPU's interpreter says nothing). No
@@ -74,10 +93,103 @@ def _best(fn, args, repeats: int = 5) -> float:
     return best
 
 
+#: the tail step's three sizes: (live rows, slots, channels, layers)
+TAILS = {"solaropen2": (128, 193, 24576, 3),
+         "olmohybrid": (24, 53, 11520, 12),
+         "falconh1": (64, 65, 5120, 6)}
+TAPS = 4
+#: passes over a model's layers in the two programs a slope is read from
+PASSES = (32, 96)
+
+
+def _tails(cd, tiny: bool) -> None:
+    """One line a size and form (the module's text)."""
+    import collections
+    import functools
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from marlin_tpu.ops import ssm
+
+    def rows_form(slab, slots, u, w, b):
+        out, t1 = ssm.conv_step(u, slab[slots], w, b)
+        return out, slab.at[slots].set(t1)
+
+    forms = {
+        "pallas": functools.partial(ssm.conv_step_slots, kernel="pallas",
+                                    interpret=False if not tiny else None),
+        "gather": functools.partial(ssm.conv_step_slots, kernel="gather"),
+        "gather.rows": rows_form}
+    for name, (rows, nslots, ch, layers) in TAILS.items():
+        if tiny:
+            rows, nslots, ch = 4, 7, ch // 64 // 128 * 128 or 128
+        ks = jax.random.split(jax.random.key(1), 4)
+        tails = jax.random.normal(ks[0], (nslots, TAPS - 1, ch)).astype(cd)
+        slots = jnp.arange(1, rows + 1, dtype=jnp.int32)
+        u = jax.random.normal(ks[1], (rows, ch)).astype(cd)
+        w = jax.random.normal(ks[2], (TAPS, ch)).astype(cd)
+        b = jax.random.normal(ks[3], (ch,)).astype(cd)
+        least = 2.0 * (TAPS - 1) * ch * jnp.dtype(cd).itemsize / HBM
+        want = None
+        for form, step in forms.items():
+            slab = (tails if form == "gather.rows" else
+                    ssm.tails_slots(tails, TAPS, ch))
+
+            @functools.partial(jax.jit, donate_argnums=(0,))
+            def chain(slab, passes, slots, u, w, b, step=step):
+                def one_pass(_, carry):
+                    slab, acc = carry
+                    for _ in range(layers):
+                        out, slab = step(slab, slots, u, w, b)
+                        acc = acc + out.sum()   # (every output is read once)
+                    return slab, acc
+
+                return jax.lax.fori_loop(0, passes, one_pass, (slab, 0.0))
+
+            args = (slots, u, w, b)
+            text = chain.lower(slab, 1, *args).compile().as_text()
+            whole = re.escape("[" + ",".join(map(str, slab.shape)) + "]")
+            over = dict(collections.Counter(
+                m.group(1) for m in re.finditer(
+                    r"= \w+" + whole + r"\S* ([\w\-]+)\(", text)
+                if m.group(1) not in ("parameter", "bitcast", "tuple",
+                                      "get-tuple-element", "while")))
+            seconds = {passes: _best(chain, (jnp.array(slab), passes) + args)
+                       for passes in PASSES}
+            # (donated, as every program donates its slabs: XLA's memory
+            # assignment aborts on a slab held to HBM that it has to copy)
+            out1, slab1 = jax.jit(step, donate_argnums=(0,))(
+                jnp.array(slab), *args)
+            got = (out1, slab1 if form == "gather.rows" else
+                   ssm.slot_tails(slab1, TAPS, ch))
+            want = got if want is None else want
+            us = 1e6 * (seconds[PASSES[1]] - seconds[PASSES[0]]) / (
+                (PASSES[1] - PASSES[0]) * layers * rows)
+            print(json.dumps({
+                "what": "tail_step", "cell": name, "form": form,
+                "dtype": str(jnp.dtype(cd)), "rows": rows, "slots": nslots,
+                "channels": ch, "layers": layers,
+                "us_a_live_row_and_layer": us, "least_us": 1e6 * least,
+                "roofline_pct": 100 * 1e6 * least / us if us > 0 else None,
+                "ms_a_step_of_the_layers": 1e-3 * us * layers * rows,
+                "ms_the_programs": [1e3 * seconds[p] for p in PASSES],
+                "ops_over_slab": over,
+                "max_abs_diff_out": float(jnp.abs(
+                    got[0] - want[0]).max()),
+                "tails_equal": bool((got[1] == want[1]).all())}),
+                flush=True)
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--f32", action="store_true")
     ap.add_argument("--kda", action="store_true")
+    ap.add_argument("--tails", action="store_true")
+    ap.add_argument("--tiny", action="store_true",
+                    help="--tails at a small size on a CPU: the control "
+                    "flow alone (ends non-zero)")
     args = ap.parse_args(argv)
     import jax
     import jax.numpy as jnp
@@ -86,6 +198,11 @@ def main(argv=None) -> int:
     from marlin_tpu.ops import delta_rule
 
     dev = jax.devices()[0]
+    if args.tails and (args.tiny or dev.platform == "tpu"):
+        _tails(jnp.float32 if args.f32 else jnp.bfloat16, args.tiny)
+        print(json.dumps({"ok": dev.platform == "tpu",
+                          "device": dev.device_kind}))
+        return 0 if dev.platform == "tpu" else 1
     if dev.platform != "tpu":
         print(json.dumps({"ok": False, "why": "not a TPU"}))
         return 1

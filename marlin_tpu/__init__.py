@@ -17,6 +17,12 @@ Quick start::
     (l, u, p) = a.lu_decompose(mode="dist")
 """
 
+import sys as _sys
+import time as _time
+
+_T_IMPORT = _time.perf_counter()  # the `startup.import` span opens here
+_JAX_PRELOADED = "jax" in _sys.modules
+
 from .config import MarlinConfig, config_context, get_config, set_config  # noqa: F401
 from .mesh import (  # noqa: F401
     COLS,
@@ -64,3 +70,7 @@ from . import obs  # noqa: F401
 from . import random  # noqa: F401
 
 __version__ = "0.3.0"
+
+obs.collectors.startup_record().add_span(
+    "startup.import", _T_IMPORT, _time.perf_counter(),
+    jax_preloaded=_JAX_PRELOADED)

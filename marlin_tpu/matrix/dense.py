@@ -51,6 +51,18 @@ def _grid_divisors(mesh: Mesh, spec: P) -> tuple[int, int]:
     return tuple(out)
 
 
+def _first_dispatch(plan, a_pad, b_pad):
+    """A cached plan's first run in the process: its program is traced,
+    lowered and compiled (or loaded from the persistent cache) here, under a
+    start-up span, so the record says what a caller's first product cost."""
+    from ..obs.collectors import startup_span
+
+    plan.dispatched = True
+    with startup_span("matmul.first_dispatch", program=plan.program,
+                      split="x".join(map(str, plan.split or ()))):
+        return plan.fn(a_pad, b_pad)
+
+
 class DenseMatrix(DistributedMatrix):
     """A dense matrix sharded over a device mesh. See module docstring."""
 
@@ -331,7 +343,10 @@ class DenseMatrix(DistributedMatrix):
                 program=plan.program,
                 moved_bytes=plan.moved_bytes,
             ):
-                c_pad = plan.fn(self.data, b_pad)
+                if plan.dispatched:
+                    c_pad = plan.fn(self.data, b_pad)
+                else:
+                    c_pad = _first_dispatch(plan, self.data, b_pad)
             return klass(c_pad, (m, n), self.mesh, out_spec)
 
         # legacy logical-array path (ring, or an RMM split over a device subset)

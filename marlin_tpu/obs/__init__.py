@@ -10,8 +10,9 @@ ServeMetrics counters, StageTimes, a test-only compile tally):
   text exposition (every existing counter in the library records here).
 - :mod:`~marlin_tpu.obs.exposition` — stdlib ``http.server`` ``/metrics``
   endpoint; :func:`start_from_config` starts it from ``obs_http_port``.
-- :mod:`~marlin_tpu.obs.collectors` — the jax.monitoring compile bridge,
-  device-memory gauges next to the planner's HBM budget.
+- :mod:`~marlin_tpu.obs.collectors` — the jax.monitoring compile bridge
+  and the process's start-up record (installed when this package is
+  imported), device-memory gauges next to the planner's HBM budget.
 - :mod:`~marlin_tpu.obs.trace` — contextvars span propagation so every
   EventLog record carries ``trace_id``/``span_id``/``parent_id`` and one
   serving request (or checkpoint save, or streamed op) is one joinable
@@ -60,6 +61,10 @@ from .memledger import (  # noqa: F401
     get_leak_detector,
     get_ledger,
 )
+
+# every process hears its own compile path from here on (jax is imported by
+# now: marlin_tpu.config came first); listeners only, nothing starts
+collectors.install_compile_metrics()
 
 __all__ = ["trace", "collectors", "memledger", "perf", "Counter", "Gauge",
            "Histogram", "MetricsRegistry", "get_registry", "percentile",

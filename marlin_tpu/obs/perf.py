@@ -142,11 +142,17 @@ class FlightRecorder:
                     _capture_dir(),
                     f"flight-{self.name or 'ring'}-{slug}-{stamp}-"
                     f"{os.getpid()}-{next(_dump_ids)}.jsonl")
+            from .collectors import startup_event
+
             with open(path, "w") as f:
                 for r in recs:
                     if reason:
                         r = {**r, "reason": reason}
                     f.write(json.dumps(r) + "\n")
+                # what the process spent starting up, and every program it
+                # compiled since: a black box that says "compiles=15" should
+                # also say which
+                f.write(json.dumps(startup_event()) + "\n")
             self._prune_dumps(os.path.dirname(path))
         except Exception:
             return None

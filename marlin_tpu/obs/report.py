@@ -19,6 +19,10 @@ profiler UI, no live process:
   jax.monitoring bridge) and ``kind="memory"`` samples
   (:func:`~marlin_tpu.obs.collectors.log_device_memory`) as time-offset
   listings, so a recompile storm or an HBM creep is visible at a glance.
+- **start-up** — the last ``kind="startup"`` record (a flight dump ends with
+  one; :func:`~marlin_tpu.obs.collectors.startup_event`): the start-up
+  spans, what the programs inside them cost to trace, lower, compile and
+  load, and the programs compiled outside any span, by name.
 
 Reading is torn-line tolerant (the same skip-and-flag contract as
 ``EventLog.read``): a crash mid-write costs one partial line, never the
@@ -51,7 +55,7 @@ KNOWN_KINDS = frozenset({
     "ckpt", "compile", "fleet", "flight", "mem", "memory", "prefetch",
     "profile", "program", "resume", "resume_skip", "retry",
     "retry_deadline", "retry_exhausted", "serve", "slo", "stage_times",
-    "step_failure", "timer",
+    "startup", "step_failure", "timer",
 })
 
 #: the ``ev=`` discriminators of ``kind="serve"`` records (the
@@ -309,7 +313,9 @@ def _timeline_section(events: list[dict], t0: float) -> list[str]:
         out.append(f"compiles: {len(compiles)}, total "
                    f"{sum(r['seconds'] for r in compiles):.3f}s")
         for r in compiles[:20]:
-            out.append(f"  t+{r['t'] - t0:.3f}s  {r['seconds']:.3f}s")
+            what = (f"  {r['fun_name']} ({r.get('cache', '?')})"
+                    if r.get("fun_name") else "")
+            out.append(f"  t+{r['t'] - t0:.3f}s  {r['seconds']:.3f}s{what}")
         if len(compiles) > 20:
             out.append(f"  ... {len(compiles) - 20} more")
     else:
@@ -379,6 +385,43 @@ def _memory_attribution_section(events: list[dict]) -> list[str]:
     return out
 
 
+def _startup_section(events: list[dict]) -> list[str]:
+    recs = [r for r in events if r.get("kind") == "startup"
+            and isinstance(r.get("totals"), dict)]
+    if not recs:
+        return []
+    rec = recs[-1]
+    out = ["== startup =="]
+    for s in rec.get("spans", []):
+        took = ("open" if s.get("t1") is None
+                else f"{s['t1'] - s['t0']:.3f}s")
+        fields = " ".join(f"{k}={v}" for k, v in
+                          sorted((s.get("fields") or {}).items()))
+        inside = f" in {s['parent']}" if s.get("parent") else ""
+        out.append(f"  {s['name']:<24}{took:>10}{inside}  {fields}".rstrip())
+    t = rec["totals"]
+    out.append(f"programs inside a span: {t['programs_compiled']} compiled "
+               f"{t['compile_s']:.3f}s, {t['programs_loaded']} loaded from "
+               f"the cache {t['cache_load_s']:.3f}s, trace+lower "
+               f"{t['trace_lower_s']:.3f}s")
+    slow = sorted((r for r in rec.get("programs", [])
+                   if r.get("within") and r.get("cache") != "hit"),
+                  key=lambda r: -r["backend_s"])
+    for r in slow[:10]:
+        out.append(f"  compiled {r['fun_name']}  {r['backend_s']:.3f}s "
+                   f"({r['cache']}) in {','.join(r['within'])}")
+    outside = sorted(rec.get("outside", {}).items(),
+                     key=lambda kv: (-kv[1]["backend_s"], kv[0]))
+    if outside:
+        out.append(f"programs outside any span: "
+                   f"{sum(o['programs'] for _, o in outside)} "
+                   f"({len(outside)} names)")
+        for name, o in outside[:10]:
+            out.append(f"  {name}  x{o['programs']}  backend "
+                       f"{o['backend_s']:.3f}s  hits {o['hits']}")
+    return out
+
+
 def analyze(events: list[dict], skipped: int = 0) -> str:
     """The full deterministic report for one event stream."""
     out = ["== marlin_tpu.obs.report =="]
@@ -402,6 +445,10 @@ def analyze(events: list[dict], skipped: int = 0) -> str:
         out.extend(mem_sec)
     out.append("")
     out.extend(_timeline_section(events, t0))
+    startup = _startup_section(events)
+    if startup:
+        out.append("")
+        out.extend(startup)
     return "\n".join(out) + "\n"
 
 

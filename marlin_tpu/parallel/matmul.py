@@ -45,8 +45,9 @@ padding happens inside the jitted program and is sliced off before returning.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
-from typing import Callable, NamedTuple
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
@@ -355,19 +356,24 @@ def _fused_fn(
     return f
 
 
-class FusedPlan(NamedTuple):
+@dataclasses.dataclass(eq=False)
+class FusedPlan:
     """What the dispatch decided for one multiply: the resolved ``strategy``,
     the (m, k, n) ``split`` (``None`` where the strategy has none), the fused
     ``program`` that runs it (``ring2d`` / ``rmm`` / ``broadcast`` /
     ``gspmd``), ``moved_bytes`` (what the plan sends the chip that receives
     most: the blocks it needs and lacks, and its share of a sum over k; -1
-    for ``gspmd``, whose schedule is the compiler's) and the jitted program."""
+    for ``gspmd``, whose schedule is the compiler's) and the jitted program.
+    ``dispatched`` is set by ``DenseMatrix.multiply`` once it has run the
+    plan: the first dispatch of a cached plan is where its program is traced,
+    lowered and compiled or loaded (the ``matmul.first_dispatch`` span)."""
 
     strategy: str
     split: tuple[int, int, int] | None
     program: str
     moved_bytes: int
     fn: Callable
+    dispatched: bool = False
 
 
 def _layout(x, mesh: Mesh):

@@ -94,7 +94,7 @@ import numpy as np
 from ..config import get_config
 from ..models.hybrid import ModelSpec
 from ..obs import memledger, perf, trace as obs_trace
-from ..obs.collectors import compile_count as _compile_count
+from ..obs.collectors import compile_count as _compile_count, startup_span
 from ..obs.exposition import (register_health_provider,
                               register_kvpool_provider,
                               register_slo_provider,
@@ -346,6 +346,7 @@ class ServeEngine:
     Usable as a context manager (``close()`` on exit); ``start=False`` defers
     the worker thread so tests can stage a queue before any dispatch."""
 
+    @startup_span("serve.engine.init")
     def __init__(self, params: dict, heads, *, buckets=None,
                  max_batch: int | None = None,
                  max_wait_ms: float | None = None,
@@ -652,16 +653,17 @@ class ServeEngine:
         drives the live pool."""
         self._warming = True
         try:
-            with self._cond:  # never race a worker's lazy pool creation
-                pool = self._ensure_kvpool()
-            n = warmup_paged(self.params, self.heads, self.buckets,
-                             self.max_batch, pool, self._prefill_chunk,
-                             self.compute_dtype, self.moe,
-                             kernel=self._decode_kernel)
-            for name, prog in self._programs.items():
-                if name != "lm":  # LM compiled above against the live pool
-                    n += prog.warmup()
-            return n
+            with startup_span("serve.warmup", buckets=len(self.buckets)):
+                with self._cond:  # never race a worker's lazy pool creation
+                    pool = self._ensure_kvpool()
+                n = warmup_paged(self.params, self.heads, self.buckets,
+                                 self.max_batch, pool, self._prefill_chunk,
+                                 self.compute_dtype, self.moe,
+                                 kernel=self._decode_kernel)
+                for name, prog in self._programs.items():
+                    if name != "lm":  # LM compiled above against the live pool
+                        n += prog.warmup()
+                return n
         finally:
             self._warming = False
 
@@ -736,15 +738,18 @@ class ServeEngine:
         admission) and rebuilt zeroed after a recovery or slab loss."""
         pool = self._kvpool
         if pool is None:
-            # analyze: single-writer — the pool pointer belongs to the live
-            # scheduler generation; _recover/close swap it only after the
-            # worker they superseded has stopped dispatching
-            pool = self._kvpool = PagedKVPool(
-                self.params, self.heads, self._num_pages, self._page_len,
-                self.compute_dtype, self._prefix_cache,
-                window_pages=self._window_pages, ring=self._ring or 0,
-                state_slots=self._state_slots,
-                snapshot_slots=self._snapshot_slots)
+            with startup_span("serve.kvpool.init",
+                              pages_total=self._num_pages,
+                              state_slots=self._state_slots):
+                # analyze: single-writer — the pool pointer belongs to the
+                # live scheduler generation; _recover/close swap it only
+                # after the worker they superseded has stopped dispatching
+                pool = self._kvpool = PagedKVPool(
+                    self.params, self.heads, self._num_pages, self._page_len,
+                    self.compute_dtype, self._prefix_cache,
+                    window_pages=self._window_pages, ring=self._ring or 0,
+                    state_slots=self._state_slots,
+                    snapshot_slots=self._snapshot_slots)
             self.metrics.record_pages(pool.capacity, 0, 0)
             # account the slab in the process memory ledger: the free rides
             # every drop path (recovery, slab loss, terminal close), so a

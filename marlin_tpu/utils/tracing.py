@@ -6,7 +6,9 @@ adds:
 
 - :func:`annotate` — a timed span with fields, written into the
   `jax.profiler` trace (and nowhere else) as ``marlin:<name>``: the engine's
-  phases on the device trace's clock.
+  phases on the device trace's clock. The few spans of a process's
+  START-UP are also kept in memory, with every program compiled under them:
+  :func:`marlin_tpu.obs.collectors.startup_span` wraps this function.
 - :class:`EventLog` — append-only JSON-lines event log (step timings, bytes
   moved, custom counters) for post-hoc analysis without a profiler UI. Every
   record automatically carries the active span context

@@ -303,9 +303,13 @@ def test_stage_times_feed_registry():
 def test_compile_bridge_counts_and_logs(default_log):
     import jax
 
-    collectors.install_compile_metrics()
-    fam = obs.get_registry().counter("marlin_compile_total")
-    before_metric = fam.value
+    fam = obs.get_registry().counter("marlin_compile_total",
+                                     labelnames=("result",))
+
+    def events():
+        return sum(c.value for c in fam.children().values())
+
+    before_metric = events()
     before_count = collectors.compile_count()
 
     @jax.jit
@@ -314,9 +318,11 @@ def test_compile_bridge_counts_and_logs(default_log):
 
     f(np.float32(2.0))
     assert collectors.compile_count() - before_count >= 1
-    assert fam.value - before_metric >= 1
+    assert events() - before_metric >= 1
     compiles = [r for r in default_log.read() if r["kind"] == "compile"]
     assert compiles and all(r["seconds"] > 0 for r in compiles)
+    assert any(r["fun_name"] == "jit(f)" and r["cache"] in ("off", "miss")
+               for r in compiles)
 
 
 # --------------------------------------------------------------- exposition

@@ -141,9 +141,12 @@ def test_flight_dump_on_decode_step_fault(lm_params, tmp_path, default_log):
     recs, skipped = load_events(path)
     assert skipped == 0 and recs
     # the ring shows the fault itself plus the iterations leading up to it
-    evs = {r["ev"] for r in recs}
+    *ring, startup = recs  # the dump ends with the process's start-up record
+    evs = {r["ev"] for r in ring}
     assert "decode_fault" in evs and "prefill" in evs
-    assert all(r["kind"] == "flight" for r in recs)
+    assert all(r["kind"] == "flight" for r in ring)
+    assert startup["kind"] == "startup"
+    assert "serve.engine.init" in {s["name"] for s in startup["spans"]}
     analyze(recs)  # parseable by the analyzer, end to end
 
 
@@ -316,7 +319,8 @@ def test_engine_closed_twice_lands_one_flight_dump_and_a_clean_healthz(
              and r.get("ev") == "dump" and r.get("src") == eng._name]
     assert [d["reason"] for d in dumps] == ["close"]
     recs, skipped = load_events(dumps[0]["path"])
-    assert skipped == 0 and {"prefill", "step"} <= {r["ev"] for r in recs}
+    assert skipped == 0 and {"prefill", "step"} <= {
+        r["ev"] for r in recs if r["kind"] == "flight"}
     # nothing the engine emitted is a record of the registry that went
     assert not [r for r in default_log.read() if r["kind"] == "program"]
     _, payload = health_payload()

@@ -275,7 +275,9 @@ def test_flight_dump_on_worker_crash_is_report_parseable(params, tmp_path):
         events, skipped = obs_report.load_events(
             str(tmp_path / sorted(dumps)[0]))
         assert events and skipped == 0
-        assert all(r.get("kind") == "flight" for r in events)
+        # the ring, then the process's start-up record
+        assert all(r.get("kind") == "flight" for r in events[:-1])
+        assert events[-1]["kind"] == "startup"
         text = obs_report.analyze(events)
         assert "marlin_tpu.obs.report" in text
 

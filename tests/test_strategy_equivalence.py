@@ -154,9 +154,12 @@ def test_ring2d_matches_dot_on_every_mesh(shape, mkn):
 def test_auto_takes_the_ring_on_a_cube_and_says_so(monkeypatch):
     """``strategy="auto"`` on a cube over a full 2x2 mesh: CARMA's (2, 1, 2),
     the ring program, one ``marlin:matmul.dispatch`` span a product with the
-    plan's fields."""
+    plan's fields; the plan's first product in the process has a
+    ``matmul.first_dispatch`` span inside it, a later one has not."""
+    from marlin_tpu.parallel.matmul import _plan
     from marlin_tpu.utils import tracing
 
+    _plan.cache_clear()  # whatever ran before: this plan is new
     spans = []
     real = tracing.annotate
     monkeypatch.setattr(tracing, "annotate", lambda name, **f: (
@@ -167,9 +170,14 @@ def test_auto_takes_the_ring_on_a_cube_and_says_so(monkeypatch):
     mb = mt.DenseVecMatrix.from_array(b, mesh)
     c = ma.multiply(mb, broadcast_threshold_mb=0, precision="highest")
     np.testing.assert_allclose(c.to_numpy(), _dot32(a, b), rtol=1e-5, atol=1e-5)
-    assert spans == [("matmul.dispatch", dict(
+    dispatch = ("matmul.dispatch", dict(
         strategy="rmm", split="2x1x2", program="ring2d",
-        moved_bytes=48 * 48 * 4))]
+        moved_bytes=48 * 48 * 4))
+    assert spans == [dispatch, ("matmul.first_dispatch", dict(
+        program="ring2d", split="2x1x2"))]
+    del spans[:]
+    ma.multiply(mb, broadcast_threshold_mb=0, precision="highest")
+    assert spans == [dispatch]
     _, collectives = _plan_of(ma, mb, broadcast_threshold_mb=0)
     assert collectives == {"collective-permute"}
 

@@ -73,7 +73,7 @@ def programs(mesh, n):
 
     k_split, ring = plan(strategy="rmm", split=(2, 2, 1)), plan()
     if (k_split.program, ring.program) != ("rmm", "ring2d"):
-        raise SystemExit(f"unexpected plans: {k_split[:4]} {ring[:4]}")
+        raise SystemExit(f"unexpected plans: {k_split} {ring}")
     gathered = _fused_fn(
         "rmm", (n, n, n), (n, n), out, PRECISION, jnp.dtype(jnp.float32),
         build_rmm_mesh((2, 1, 2), list(mesh.devices.flat)), "b")

@@ -415,6 +415,21 @@ class KdaSpec(DeltaSpec):
 
     rank: int = 128
 
+    def scan_blocks(self, tokens: int, width: int) -> int:
+        """Blocks of the chunk scan ONE layer runs for a chunk ``width``
+        positions wide that holds ``tokens`` tokens: those that hold a
+        token where the scan's kernel takes these sizes
+        (:func:`~marlin_tpu.ops.delta_rule.chunk_scan_supported`), every
+        block of the chunk where XLA's form runs."""
+        from ..ops import delta_rule
+
+        block = min(self.chunk, width)
+        if delta_rule.chunk_scan_supported(
+                self.heads, self.key_dim, self.value_dim, block,
+                state_dtype=self.state_dtype):
+            return -(-tokens // block)
+        return width // block
+
 
 @dataclasses.dataclass(frozen=True)
 class ConvSpec:
@@ -2433,10 +2448,13 @@ def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
             states, tails = pages[name]
             s0, t0 = _enter_state(chunk_start == 0, states[state_slot],
                                   tails[state_slot])
-            conv, t1 = ssm_ops.causal_conv(
-                qkv, ssm_ops.slot_tails(t0, ds.conv, ds.conv_dim),
-                lp["conv_w"], jnp.zeros((ds.conv_dim,), qkv.dtype),
-                jnp.clip(length - chunk_start, 0, C))
+            tail0 = ssm_ops.slot_tails(t0, ds.conv, ds.conv_dim)
+            bias = jnp.zeros((ds.conv_dim,), qkv.dtype)
+            # (traced in the order it had: a linear layer's program lowers
+            # to the text it lowered to before the scan took the count)
+            tokens = jnp.clip(length - chunk_start, 0, C)
+            conv, t1 = ssm_ops.causal_conv(qkv, tail0, lp["conv_w"], bias,
+                                           tokens)
             q, k, v = _delta_operands(ds, conv, qkv.dtype)
             with jax.named_scope(
                     "delta_scan" if spec.kda is None else "kda_scan"):
@@ -2445,7 +2463,7 @@ def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
                     jnp.where(valid.reshape(C, *(1,) * (g.ndim - 1)), g, 0.0),
                     jnp.where(valid[:, None], beta, 0.0),
                     s0.reshape(ds.key_dim, ds.heads, ds.value_dim),
-                    block=min(ds.chunk, C))
+                    block=min(ds.chunk, C), valid=tokens)
             new_state[name] = (
                 jax.lax.dynamic_update_index_in_dim(
                     states, s1.reshape(states.shape[1:]), state_slot, 0),

@@ -35,6 +35,25 @@ matrix that depends on the key, whose eigenvalue ``1 - b_t`` reaches ``-1``.
   position with ``g == 0`` and ``b == 0`` neither
   decays the state nor adds to it: that is how the positions past a row's
   length are kept out (the caller zeroes both).
+
+  **Which sizes take a kernel** (:func:`chunk_scan_supported`; the choice
+  is made from the operands, as the prefill program picks its flash kernel):
+  a decay a CHANNEL over heads of 128 x 128 in blocks of 64 (the
+  ``solar_open2`` family's) runs as ONE ``pallas_call``
+  (:func:`_kda_chunk_kernel`): a grid step holds two pairs of heads and one
+  block, the heads' states stay in VMEM over the chunk's blocks, the pairs
+  inside a sub-block never leave the chip (XLA's form writes their masked
+  exponentials, 268 MB a layer, to HBM and reads them back), and a block
+  that begins at or past the chunk's ``valid`` count is skipped: the state
+  passes through and ``o`` is zeros there. The same arithmetic at the same
+  precisions: float32 decays, exponentials, sub-block pairs, inverse, sums
+  and state; matmul operands in the compute dtype. Everything else takes
+  XLA's form (:func:`_delta_chunk_scan_xla`), the tests' second witness.
+  The scalar decay (``olmo_hybrid``: 30 heads, K 96, V 192) does NOT take
+  the kernel yet: neither size is whole lane tiles, a pair of its heads'
+  tokens do not tile the kernel's layouts, and its cost is not the channel
+  pairs this kernel was written for but the inverse and its product (657 of
+  1075 ns a token and layer, PERF.md PR 42): a second kernel.
 - :func:`delta_decode_update` advances one token for each row of a batch,
   each row's state living in slot ``slots[b]`` of a slab. ``kernel="pallas"``
   reads and writes each row's slot ONCE, in place
@@ -66,15 +85,22 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .pallas_kernels import _interpret
 
 __all__ = ["delta_chunk_scan", "delta_decode_update", "state_to_slab",
-           "slab_to_state", "decode_update_supported", "decode_heads_block"]
+           "slab_to_state", "decode_update_supported", "decode_heads_block",
+           "chunk_scan_supported"]
 
 _LANES = 128
+#: the block and the sub-block the kernel is written for: a pair of heads'
+#: 2 x 64 tokens are one lane tile, a sub-block's 16 offsets one roll each
+_KDA_BLOCK, _KDA_SUB = 64, 16
+#: pairs of heads a grid step may hold (the most that divide the heads)
+_KDA_PAIRS = (1, 2)
 
 
 def state_to_slab(state):
@@ -198,7 +224,295 @@ def _channel_decay_terms(qb, kb, gb, bb, sub: int):
             gamma[:, -1].transpose(0, 2, 1)[..., None])        # (nc, K, H, 1)
 
 
-def delta_chunk_scan(q, k, v, g, beta, state, block: int, sub: int = 16):
+def _kda_chunk_kernel(valid_ref, q_ref, k_ref, v_ref, g_ref, b_ref, w_ref,
+                      s0_ref, o_ref, s_ref):
+    """Grid (groups of ``n`` PAIRS of heads, blocks of the chunk), the blocks
+    in turn. Blocks: ``q``, ``k``, ``v``, ``g`` (64, n x 2 x 128) and ``o``
+    the same, a head's 128 values in its lane tile; ``b`` (1, 1, n, 128) each
+    pair's steps as a ROW, lane ``64 h + t``; ``w`` (15, 128, 128) the 0 / 1
+    matrices of :func:`_kda_shift_matrices`, whole; ``s0`` / ``s`` (128, n x
+    2 x 128), the heads' states, ``s`` resident over the blocks (its block
+    index does not move): the carried state. The pairs of a step share
+    nothing; ``one_pair`` is a generator and the step runs its pairs a stage
+    at a time, in turn.
+
+    Two layouts of a pair's 128 (head, token) rows. NATURAL: ``(h, t)`` down
+    the sublanes, a key's channels along the lanes: what the MXU products
+    take. TRANSPOSED: channels down, ``(h, t)`` along the lanes: there the
+    pairs inside a sub-block are a shift along the lanes by ``d = t - j``, a
+    product and a sum DOWN the sublanes (vector adds, no lane reduction),
+    one row of 128 pairs an offset. A sub-block's 16 x 16 tile is kept as
+    ``(16 j, 128 (h, t))``: lane ``(h, t)`` holds row ``t``'s 16 entries, so
+    forward substitution runs on all eight tiles of the pair at once with
+    the same rolls.
+
+    What the stages wait for (the compiler's own schedule for a v5e, which
+    runs a step's stages one after another: ~2800 bundles a pair): the lane
+    rolls, three units that each take a vreg every ~8 cycles, in the pairs
+    and the substitution; the MXU's result pops in the float32 products of
+    the joins (six passes each). Hence the keys' shift by the MXU where they
+    are bfloat16, the substitution by halves of 8 (78 rolled vregs for 240),
+    and g's running sum as three bfloat16 passes."""
+    f32, bf16, cd = jnp.float32, jnp.bfloat16, q_ref.dtype
+    # every product pins its precision: left to the caller's context, a
+    # float32 check's ``default_matmul_precision("highest")`` reaches the
+    # bfloat16 ones, which Mosaic refuses ("Bad lhs type")
+    high, one_pass = jax.lax.Precision.HIGHEST, jax.lax.Precision.DEFAULT
+    prec = high if cd == f32 else one_pass
+    c = pl.program_id(1)
+    B, S, L = _KDA_BLOCK, _KDA_SUB, _LANES
+
+    def mm(a, b, precision=prec):
+        return jnp.dot(a, b, preferred_element_type=f32, precision=precision)
+
+    @pl.when(c == 0)
+    def _enter():
+        s_ref[...] = s0_ref[...]
+
+    def one_pair(pair):
+        lanes = [slice((2 * pair + h) * L, (2 * pair + h + 1) * L)
+                 for h in (0, 1)]
+
+        def stack(ref):       # (64, 2 x 128) -> (128 (h, t), 128)
+            return jnp.concatenate([ref[:, at] for at in lanes], axis=0)
+
+        # (row >> 6: its head, B = 64; >> 4: its sub-block, S = 16)
+        row = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
+        same_head = (row >> 6) == (col >> 6)
+        top = row < B                                  # head 0's rows
+        k2, q2 = stack(k_ref).astype(f32), stack(q_ref).astype(f32)
+        # the running sum of g inside the block, a head at a time: a
+        # triangle of ones times g as the three bfloat16 that add up to it
+        # exactly, so three passes of the MXU are float32's own sums
+        g_hi = stack(g_ref)
+        tri = jnp.where(same_head & (col <= row), 1.0, 0.0).astype(bf16)
+        parts = []
+        for _ in range(3):
+            parts.append(g_hi.astype(bf16))
+            g_hi = g_hi - parts[-1].astype(f32)
+        cs2 = sum(mm(tri, part, one_pass) for part in reversed(parts))
+        csT, kT, qT = cs2.T, k2.T, q2.T
+
+        def earlier(d):
+            # the keys d tokens earlier. bfloat16 keys go through the MXU, a
+            # product with a 0 / 1 matrix that moves every lane d to the
+            # right (exact: each entry is one key times one): the vector
+            # units' lane rolls, three at a time and busy with the decays',
+            # are what this stage waits for
+            if cd == bf16:
+                return mm(kT.astype(bf16), w_ref[d - 1], one_pass)
+            return pltpu.roll(kT, d, 1)
+
+        yield
+        brow = b_ref[0, 0, pair:pair + 1]                          # (1, 128)
+
+        # ---- pairs inside a sub-block, an offset d at a time
+        tl = jax.lax.broadcasted_iota(jnp.int32, (1, L), 1) & (S - 1)
+        kk, qk = [None], [jnp.sum(qT * kT, axis=0, keepdims=True)]
+        for d in range(1, S):
+            # (masked BEFORE the exponential: -inf where t - d leaves the
+            # sub-block, whatever the difference there)
+            w = jnp.exp(csT - pltpu.roll(csT, d, 1)
+                        + jnp.where(tl >= d, 0.0, -jnp.inf)) * earlier(d)
+            kk.append(jnp.sum(kT * w, axis=0, keepdims=True))
+            qk.append(jnp.sum(qT * w, axis=0, keepdims=True))
+            yield
+        # a tile as (16 j, 128 (h, t)): entry [j, (h, t)] is offset t - j
+        jl = jax.lax.broadcasted_iota(jnp.int32, (S, L), 0)
+        tc = jax.lax.broadcasted_iota(jnp.int32, (S, L), 1) & (S - 1)
+        qd = jnp.zeros((S, L), f32)
+        for d in range(S):
+            qd = jnp.where(tc - jl == d, qk[d], qd)
+        # (I + A)^-1 of the eight diagonal tiles. Each tile's two halves of
+        # 8 a row at a time: row i is e_i - sum_m A[i, m] X[m], the rows
+        # above it final; X[m] of lane (h, t) lies d = i - m lanes to its
+        # left
+        a = [None] + [brow * kk[d] for d in range(1, S)]
+        half = S // 2
+        j8 = jax.lax.broadcasted_iota(jnp.int32, (half, L), 0)
+        t16 = jax.lax.broadcasted_iota(jnp.int32, (half, L), 1) & (S - 1)
+        t8 = t16 & (half - 1)
+        # xt's rows j < 8 and j >= 8 apart: a vreg each
+        eyes = [jnp.where(t16 == j8 + at, 1.0, 0.0) for at in (0, half)]
+        xs = eyes
+        for i in range(1, half):
+            a8 = [jnp.where(t8 >= d, a[d], 0.0) for d in range(1, i + 1)]
+            xs = [jnp.where(t8 == i, eye - sum(
+                a8[d - 1] * pltpu.roll(x, d, 1) for d in range(1, i + 1)), x)
+                for eye, x in zip(eyes, xs)]
+            yield
+        # then the tile from its halves, [[Ta, 0], [-Tb A21 Ta, Tb]]: both
+        # products again sums over offsets of a rolled factor, the left one
+        # entering by its diagonals (a row of A21 reaches 1..15 to the left,
+        # a row of Tb 0..7)
+        z = sum(jnp.where((t16 >= half) & (t16 - e < half), a[e], 0.0)
+                * pltpu.roll(xs[0], e, 1) for e in range(1, S))
+        yield
+        low = sum(jnp.sum(jnp.where(j8 + half == t16 - e, xs[1], 0.0),
+                          axis=0, keepdims=True)
+                  * (pltpu.roll(z, e, 1) if e else z) for e in range(half))
+        xt = jnp.concatenate([xs[0] - low, xs[1]], axis=0)
+        yield
+
+        def tiles(x):   # (16 j, 128 (h, t)) -> block-diagonal (128, 128)
+            same_tile = (row >> 4) == (col >> 4)
+            return jnp.where(same_tile, jnp.concatenate([x] * (L // S), 0),
+                             0.0).T
+
+        # ---- pairs in two sub-blocks: keys rescaled against the decay that
+        # enters the later one, on the MXU
+        tpos = row & (B - 1)
+        outs = []
+        for i in range(1, B // S):
+            refs = [cs2[h * B + i * S - 1:h * B + i * S] for h in (0, 1)]
+            early = (k2 * jnp.exp(jnp.where(
+                tpos < i * S, jnp.where(top, refs[0], refs[1]) - cs2,
+                -jnp.inf))).astype(cd)
+            late = []
+            for x2 in (k2, q2):
+                for h in (0, 1):
+                    at = slice(h * B + i * S, h * B + (i + 1) * S)
+                    late.append(x2[at] * jnp.exp(cs2[at] - refs[h]))
+            outs.append(jax.lax.dot_general(
+                jnp.concatenate(late, axis=0).astype(cd), early,
+                (((1,), (1,)), ((), ())), preferred_element_type=f32,
+                precision=prec))                         # (64, 128 (h, j))
+            yield
+        zero = jnp.zeros((S, L), f32)
+
+        def between(x):   # x: 0 the keys' rows, 2 the queries'
+            return jnp.concatenate(
+                [piece for h in (0, 1) for piece in
+                 [zero] + [o[(x + h) * S:(x + h + 1) * S] for o in outs]],
+                axis=0)
+
+        bcol = jnp.broadcast_to(brow, (L, L)).T   # b of row (h, t), every lane
+        a_off = jnp.where(same_head, bcol * between(0), 0.0)
+        P = jnp.where(same_head & (col <= row), tiles(qd) + between(2),
+                      0.0).astype(cd)
+        # the tiles' inverses joined by halves: [[Ta, 0], [-Tb A21 Ta, Tb]]
+        # (only the rows of the lower halves change: the 16-row groups 1 and
+        # 3 of a head, then 2 and 3; the products take those rows alone)
+        nr, ncol = (row >> 4) & 3, (col >> 4) & 3
+        inv = tiles(xt)
+        for lower, groups in ((((nr & 1) == 1) & (ncol == nr - 1),
+                               (1, 3, 5, 7)),
+                              ((nr >= 2) & (ncol < 2), (2, 3, 6, 7))):
+            pieces = [inv[n * S:(n + 1) * S] for n in range(L // S)]
+            new = mm(mm(jnp.concatenate([pieces[n] for n in groups], axis=0),
+                        jnp.where(lower, a_off, 0.0), high), inv, high)
+            for at, n in enumerate(groups):
+                pieces[n] = pieces[n] - new[at * S:(at + 1) * S]
+            inv = jnp.concatenate(pieces, axis=0)
+            yield
+
+        # ---- (I + A) [W | U] = [diag(b) V | diag(b gamma) K]
+        gamma = jnp.exp(cs2)
+        rhs = jnp.concatenate([bcol * stack(v_ref).astype(f32),
+                               bcol * gamma * k2], axis=1).astype(cd)
+        solved = mm(inv.astype(cd), rhs)
+        W, U = solved[:, :L], solved[:, L:].astype(cd)
+        q_in = (q2 * gamma).astype(cd)
+        yield
+        # ---- the chain: d = W - U S, o = q_in S + P d, S = S tot + k_out^T d
+        heads = [slice(h * B, (h + 1) * B) for h in (0, 1)]
+        sc = [s_ref[:, at].astype(cd) for at in lanes]
+        d2 = jnp.concatenate([(W[at] - mm(U[at], s)).astype(cd)
+                              for at, s in zip(heads, sc)], axis=0)
+        o2 = jnp.concatenate([mm(q_in[at], s) for at, s in zip(heads, sc)],
+                             axis=0) + mm(P, d2)
+        lastT = jnp.where(top, cs2[B - 1:B], cs2[2 * B - 1:2 * B]).T
+        k_outT, totT = kT * jnp.exp(lastT - csT), jnp.exp(lastT)
+        turned = pltpu.roll(totT, B, 1)
+        for h in (0, 1):
+            mine = (col >> 6) == h
+            o_ref[:, lanes[h]] = o2[heads[h]]
+            s_ref[:, lanes[h]] = (
+                s_ref[:, lanes[h]] * jnp.where(mine, totT, turned)
+                + mm(jnp.where(mine, k_outT, 0.0).astype(cd), d2))
+
+    # a block past the row's length does NOTHING: its index maps name the
+    # last live block again, so no copy moves in or out either
+    @pl.when(c * B < valid_ref[0])
+    def _block():
+        # the step's pairs a stage at a time, in turn (each ``yield`` of
+        # ``one_pair`` ends a stage): written one whole pair after another
+        # the compiler's schedule runs them one after another too
+        pairs = [one_pair(pair) for pair in range(b_ref.shape[2])]
+        while pairs:
+            pairs = [run for run in pairs if next(run, run) is None]
+
+
+def chunk_scan_supported(heads: int, key_dim: int, value_dim: int, block: int,
+                         sub: int = _KDA_SUB, channel_decay: bool = True,
+                         state_dtype="float32") -> bool:
+    """Whether :func:`_kda_chunk_call` takes these sizes: a decay a
+    channel, heads of 128 x 128 (a head's keys and values each ONE lane
+    tile) in pairs, blocks of 64 in sub-blocks of 16, a float32 state."""
+    return (channel_decay and key_dim == _LANES and value_dim == _LANES
+            and heads % 2 == 0 and block == _KDA_BLOCK and sub == _KDA_SUB
+            and jnp.dtype(state_dtype) == jnp.float32)
+
+
+@functools.lru_cache(maxsize=1)
+def _kda_shift_matrices():
+    """The kernel's ``w_ref``: ``[d - 1][m, l] = (m == l - d)`` over a
+    pair's 128 (head, token) lanes, d in 1..15: a product with it moves
+    every lane ``d`` to the right."""
+    lane = np.arange(2 * _KDA_BLOCK)
+    return np.stack([lane[:, None] == lane[None, :] - d
+                     for d in range(1, _KDA_SUB)]).astype(jnp.bfloat16)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "pairs"))
+def _kda_chunk_call(q, k, v, g, beta, state, valid, interpret: bool,
+                    pairs: int):
+    """The chunk scan with a decay a channel as ONE kernel (its own jitted
+    name: the kernel's operation in a trace takes it). Operands as
+    :func:`delta_chunk_scan`'s; ``valid`` an int32 scalar."""
+    T, H, K = q.shape
+    C, nc, n = _KDA_BLOCK, T // _KDA_BLOCK, pairs
+    # a pair's steps as a row, lane 64 h + t
+    brow = beta.astype(jnp.float32).reshape(nc, C, H // 2, 2).transpose(
+        0, 2, 3, 1).reshape(nc, H // (2 * n), n, 2 * C)
+
+    def at(c, valid):     # a block past the row's length moves nothing
+        return jnp.minimum(c, jnp.maximum((valid[0] + C - 1) // C - 1, 0))
+
+    tok = pl.BlockSpec((C, 2 * n * K), lambda p, c, valid: (at(c, valid), p))
+    st = pl.BlockSpec((K, 2 * n * K), lambda p, c, valid: (0, p))
+    o, s = pl.pallas_call(
+        _kda_chunk_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(H // (2 * n), nc),
+            in_specs=[tok, tok, tok, tok,
+                      pl.BlockSpec((1, 1, n, 2 * C),
+                                   lambda p, c, valid: (at(c, valid), p, 0,
+                                                        0)),
+                      pl.BlockSpec((_KDA_SUB - 1, 2 * C, 2 * C),
+                                   lambda p, c, valid: (0, 0, 0)),
+                      st],
+            out_specs=[tok, st]),
+        out_shape=[jax.ShapeDtypeStruct((T, H * K), jnp.float32),
+                   jax.ShapeDtypeStruct((K, H * K), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=64 << 20),
+        interpret=interpret,
+    )(jnp.reshape(valid, (1,)).astype(jnp.int32), q.reshape(T, H * K),
+      k.reshape(T, H * K), v.reshape(T, H * K),
+      g.astype(jnp.float32).reshape(T, H * K), brow, _kda_shift_matrices(),
+      state.reshape(K, H * K))
+    # the blocks the kernel skipped were never written
+    ran = jnp.arange(T) < (valid + C - 1) // C * C
+    return (jnp.where(ran[:, None, None], o.reshape(T, H, K), 0.0),
+            s.reshape(K, H, K))
+
+
+def delta_chunk_scan(q, k, v, g, beta, state, block: int, sub: int = 16,
+                     valid=None, interpret: bool | None = None):
     """The recurrence over one row's chunk. ``q``, ``k`` (T, H, K) and ``v``
     (T, H, V) in the compute dtype (``q`` scaled, ``k`` of unit length);
     ``g`` float32 log-decay, (T, H) one a head or (T, H, K) one a CHANNEL of
@@ -207,13 +521,30 @@ def delta_chunk_scan(q, k, v, g, beta, state, block: int, sub: int = 16):
     before the chunk as the slab holds it (:func:`state_to_slab` with the
     heads split). Matmul operands in ``q``'s dtype; the triangular system,
     every decay, accumulation and the carried state in float32. ``sub``: the
-    channel form's sub-block (:func:`_channel_decay_terms`). Returns ``(o (T,
-    H, V) float32, state after the chunk)``."""
+    channel form's sub-block (:func:`_channel_decay_terms`). ``valid``: how
+    many of the chunk's positions are tokens (a traced scalar; all of them
+    when None): the caller has zeroed ``g`` and ``beta`` past it either way,
+    and the kernel (:func:`chunk_scan_supported`) skips the blocks that
+    begin at or past it, whose ``o`` it leaves zero. Returns ``(o (T, H, V)
+    float32, state after the chunk)``."""
     T, H, K = q.shape
     V = v.shape[-1]
     if T % block:
         raise ValueError(f"a chunk of {T} tokens is not whole blocks of "
                          f"{block}")
+    if chunk_scan_supported(H, K, V, block, sub, g.ndim == 3, state.dtype):
+        return _kda_chunk_call(
+            q, k, v, g, beta, state, T if valid is None else valid,
+            interpret=_interpret() if interpret is None else interpret,
+            pairs=max(n for n in _KDA_PAIRS if H % (2 * n) == 0))
+    return _delta_chunk_scan_xla(q, k, v, g, beta, state, block, sub)
+
+
+def _delta_chunk_scan_xla(q, k, v, g, beta, state, block: int, sub: int = 16):
+    """:func:`delta_chunk_scan` as XLA's program: every size, the scalar
+    decay, and the kernel's second witness in the tests."""
+    T, H, K = q.shape
+    V = v.shape[-1]
     nc, C, cd, f32 = T // block, block, q.dtype, jnp.float32
     qb, kb = q.reshape(nc, C, H, K), k.reshape(nc, C, H, K)
     vb = v.reshape(nc, C, H, V)

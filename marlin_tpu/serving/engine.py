@@ -2213,6 +2213,11 @@ class ServeEngine:
                     if self._state_slots:
                         # the valid tokens the mixers' scan advances over
                         dispatch.set_metadata(**{self._mixer_tokens: tokens})
+                        if self._spec.kda is not None:
+                            # the blocks of the scan a layer runs for it
+                            dispatch.set_metadata(
+                                kda_blocks=self._spec.kda.scan_blocks(
+                                    tokens, C))
                     chunk = group.prompts[slot][cs:cs + C]
                     if chunk.shape[0] < C:
                         # a prefix hit whose shared_len is page- but not

@@ -1095,7 +1095,8 @@ def test_lfm2_programs_compile_for_v5e_at_the_cells_sizes(one_chip):
         copy = hybrid._state_slot_copy_jit.trace(
             pages, st(()), st(()), spec=spec).lower().compile()
     assert decode.as_text().count("tpu_custom_call") >= 1 + 6
-    assert prefill.as_text().count("tpu_custom_call") >= 6
+    # (the six grouped matmuls and, since PR 55, the chunk scan's kernel)
+    assert prefill.as_text().count("tpu_custom_call") >= 6 + 1
     for c in (decode, prefill, copy):
         m = c.memory_analysis()
         assert m.alias_size_in_bytes >= 2 * slab
@@ -1260,6 +1261,38 @@ def test_kda_update_kernel_mosaic_compiles_in_place_at_published_widths(
     assert m.temp_size_in_bytes < slab_bytes // 8
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_kda_chunk_scan_kernel_mosaic_compiles_at_published_widths(one_chip,
+                                                                   dtype):
+    """The chunk scan with a decay a CHANNEL at the ``solar_open2`` cell's
+    prefill chunk (512 positions, 64 heads of 128 x 128, blocks of 64, the
+    count of tokens a traced scalar) compiles through Mosaic for a v5e as
+    ONE custom call, and nothing the size of XLA's form's masked
+    exponentials (268 MB a layer) is left in the program: what the call
+    keeps beside its operands is under a chunk's float32 output."""
+    from marlin_tpu.ops import delta_rule
+
+    T, H, K = 512, 64, 128
+
+    def st(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def scan(q, k, v, g, b, s, valid):
+        return delta_rule.delta_chunk_scan(q, k, v, g, b, s, block=64,
+                                           valid=valid, interpret=False)
+
+    assert delta_rule.chunk_scan_supported(H, K, K, 64)
+    # (under the float32 checks' context too: the kernel pins every
+    # product's precision, a bfloat16 one at "highest" does not compile)
+    with jax.default_matmul_precision("highest"):
+        compiled = jax.jit(scan).lower(
+            st((T, H, K), dtype), st((T, H, K), dtype), st((T, H, K), dtype),
+            st((T, H, K)), st((T, H)), st((K, H, K)),
+            st((), jnp.int32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes <= T * H * K * 4
+
+
 def _ops_as_large_as(text: str, shape: tuple) -> set:
     """The names of a compiled program's operations whose output has
     ``shape``, those that only hand an array on left out."""
@@ -1373,7 +1406,8 @@ def test_solaropen2_programs_compile_for_v5e_at_the_cells_sizes(one_chip):
         copy = hybrid._state_slot_copy_jit.trace(
             pages, st(()), st(()), spec=spec).lower().compile()
     assert decode.as_text().count("tpu_custom_call") >= 1 + 1 + 1 + 6
-    assert prefill.as_text().count("tpu_custom_call") >= 6
+    # (the six grouped matmuls and, since PR 55, the chunk scan's kernel)
+    assert prefill.as_text().count("tpu_custom_call") >= 6 + 1
     # the tails: a slot in one piece, advanced in place by its own kernel; no
     # operation of the decode program makes another array of their size
     # (until PR 52: two whole copies, a gather and a scatter a layer)

@@ -357,7 +357,7 @@ def _zero_state_arrays(spec, index: int):
 
 def _scan_as(decay_first: bool):
     """The chunked form's place taken by the token-by-token recurrence."""
-    def scan(q, k, v, g, b, state, block):
+    def scan(q, k, v, g, b, state, block, valid=None):
         def step(S, tok):
             q_t, k_t, v_t, g_t, b_t = tok
             Sd = S * jnp.exp(g_t)[None, :, None]

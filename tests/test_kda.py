@@ -25,8 +25,9 @@ from marlin_tpu.models import hybrid
 from marlin_tpu.models.transformer import init_kv_pages
 from marlin_tpu.ops import delta_rule
 from marlin_tpu.serving import Request, ServeEngine
-from tests.test_delta_rule import (CAUGHT, CHUNK, PAGE, TIGHT, VOCAB, _naive,
-                                   _prompt, _serve_one, _table)
+from tests.test_delta_rule import (CAUGHT, CHUNK, PAGE, TIGHT, VOCAB,
+                                   _capture, _naive, _prompt, _serve_one,
+                                   _table)
 
 EXPERTS, FIRST = 16, 4
 
@@ -176,6 +177,151 @@ def test_a_decay_constant_over_a_heads_channels_is_the_scalar_form(form):
         np.testing.assert_allclose(a, w, atol=2e-6)
 
 
+# the chunk scan's kernel ---------------------------------------------------
+
+
+def _kernel_case(T, valid, strongest, cd, H=2, seed=0):
+    """Operands the kernel takes (heads of 128 x 128, blocks of 64) with
+    ``valid`` tokens of ``T`` positions, the rest masked as the prefill
+    program masks them; the recurrence over the tokens alone."""
+    K = V = 128
+    rng = np.random.default_rng(1000 * T + valid + seed)
+    q, k, v, g, b = _operands(rng, T, H, K, V, strongest)
+    q, k, v = (x.astype(cd) for x in (q, k, v))
+    g, b = g.at[valid:].set(0.0), b.at[valid:].set(0.0)
+    S0 = rng.normal(size=(H, K, V))
+    want = _naive(*(np.asarray(x.astype(jnp.float32))[:valid]
+                    for x in (q, k, v)), g[:valid], b[:valid], S0) \
+        if valid else (np.zeros((0, H, V)), S0)
+    return (q, k, v, g, b,
+            jnp.moveaxis(jnp.asarray(S0, jnp.float32), 0, 1)), want
+
+
+#: (positions, tokens, heads): one block and several; nothing, inside the
+#: first block, on a block boundary, inside the last block, everything; one
+#: pair of heads a grid step and two
+WIDTHS = [(64, 0, 2), (64, 17, 2), (64, 64, 2), (256, 0, 2), (256, 40, 2),
+          (256, 128, 2), (256, 200, 2), (256, 256, 2), (128, 100, 4),
+          (128, 128, 4)]
+
+
+@pytest.mark.parametrize("strongest", [0.3, 8.0])
+@pytest.mark.parametrize("T, valid, H", WIDTHS)
+def test_the_chunk_kernel_is_the_recurrence_and_xlas_form(T, valid, H,
+                                                          strongest):
+    """float32 operands: the kernel against the token-by-token recurrence to
+    the tolerance XLA's form is held to, and against XLA's form; an entering
+    state that is not zero; the strongest decay sums to far under float32's
+    smallest exponent inside a block."""
+    args, (want_o, want_S) = _kernel_case(T, valid, strongest, jnp.float32,
+                                          H=H)
+    if strongest >= 8 and valid >= 64:
+        assert np.cumsum(np.asarray(args[3]), 0).min() < -200
+    with jax.default_matmul_precision("highest"):
+        o, S = delta_rule.delta_chunk_scan(*args, block=64,
+                                           valid=jnp.int32(valid))
+        xo, xS = delta_rule._delta_chunk_scan_xla(*args, block=64)
+    assert np.isfinite(o).all() and np.isfinite(S).all()
+    # the state where a block's running sum of g reaches -280: a ratio is
+    # the exponential of the DIFFERENCE of two such sums, each rounded to
+    # 3e-5; XLA's form reads 5e-6 to 2.5e-5 there over seeds, as this does
+    loose = 4e-5 if strongest >= 8 else 1e-5
+    np.testing.assert_allclose(o[:valid], want_o, atol=1e-5)
+    np.testing.assert_allclose(jnp.moveaxis(S, 1, 0), want_S, atol=loose)
+    # (two witnesses, each that close to the recurrence)
+    np.testing.assert_allclose(o[:valid], xo[:valid], atol=2e-5)
+    np.testing.assert_allclose(S, xS, atol=2 * loose)
+
+
+@pytest.mark.parametrize("T, valid, H", WIDTHS[1:3] + WIDTHS[4:])
+def test_the_chunk_kernel_in_bfloat16_is_the_recurrence(T, valid, H):
+    """bfloat16 operands (XLA's form has a product in them that the CPU does
+    not run): against the recurrence over the same rounded operands, to what
+    rounding U, d, P and the state's operand to eight bits leaves. (With
+    bfloat16 keys the kernel moves them by the MXU, not by a lane roll.)"""
+    args, (want_o, want_S) = _kernel_case(T, valid, 3.0, jnp.bfloat16, H=H)
+    o, S = delta_rule.delta_chunk_scan(*args, block=64,
+                                       valid=jnp.int32(valid))
+    assert 0.05 < np.abs(want_o).max() and 0.5 < np.abs(want_S).max()
+    np.testing.assert_allclose(o[:valid], want_o, atol=3e-3)
+    np.testing.assert_allclose(jnp.moveaxis(S, 1, 0), want_S, atol=2e-2)
+
+
+@pytest.mark.parametrize("cd", [jnp.float32, jnp.bfloat16])
+def test_a_skipped_block_leaves_the_state_bit_equal_and_writes_zeros(cd):
+    """No token: the state leaves as it entered, bit for bit, and every
+    output is zero. Tokens in the first block of three: the two blocks past
+    them write zeros, and the state is the state a chunk of that one block
+    leaves, bit for bit. Without the count the same chunk runs every block
+    (the padding masked by ``g = 0, b = 0``) and leaves the same state to
+    rounding."""
+    args, _ = _kernel_case(192, 50, 3.0, cd, H=4, seed=1)
+    o, S = delta_rule.delta_chunk_scan(*args, block=64, valid=jnp.int32(0))
+    assert not np.asarray(o).any()
+    np.testing.assert_array_equal(S, args[5])
+    o, S = delta_rule.delta_chunk_scan(*args, block=64, valid=jnp.int32(50))
+    assert np.asarray(o[:50]).any() and not np.asarray(o[64:]).any()
+    o1, S1 = delta_rule.delta_chunk_scan(*(x[:64] for x in args[:5]),
+                                         args[5], block=64)
+    np.testing.assert_array_equal(o[:64], o1)
+    np.testing.assert_array_equal(S, S1)
+    o3, S3 = delta_rule.delta_chunk_scan(*args, block=64)
+    np.testing.assert_array_equal(o[:50], o3[:50])
+    np.testing.assert_allclose(S, S3, atol=1e-6)
+
+
+def _scan_jaxpr(T, H, K, V, block, channel=True, state_dtype=jnp.float32):
+    sds = jax.ShapeDtypeStruct
+    f32 = jnp.float32
+    fn = jax.jit(lambda q, k, v, g, b, s: delta_rule.delta_chunk_scan(
+        q, k, v, g, b, s, block=block, valid=jnp.int32(T)))
+    traced = fn.trace(sds((T, H, K), f32), sds((T, H, K), f32),
+                      sds((T, H, V), f32),
+                      sds((T, H, K) if channel else (T, H), f32),
+                      sds((T, H), f32), sds((K, H, V), state_dtype))
+    return str(traced.jaxpr), traced.lower().as_text()
+
+
+@pytest.mark.parametrize("what, T, H, K, V, block, channel, state", [
+    ("a decay a head", 128, 2, 128, 128, 64, False, jnp.float32),
+    ("the Olmo-Hybrid head", 128, 2, 96, 192, 64, False, jnp.float32),
+    ("keys of 96", 128, 2, 96, 128, 64, True, jnp.float32),
+    ("values of 192", 128, 2, 128, 192, 64, True, jnp.float32),
+    ("an odd count of heads", 128, 3, 128, 128, 64, True, jnp.float32),
+    ("a block of 32", 128, 2, 128, 128, 32, True, jnp.float32),
+    ("a state in bfloat16", 128, 2, 128, 128, 64, True, jnp.bfloat16)])
+def test_sizes_the_kernel_does_not_take_run_xlas_form(what, T, H, K, V, block,
+                                                      channel, state):
+    """The choice is made from the operands: anything but a decay a channel
+    over pairs of 128 x 128 heads in blocks of 64 lowers to XLA's program,
+    with no kernel and no custom call in it."""
+    assert not delta_rule.chunk_scan_supported(H, K, V, block,
+                                               channel_decay=channel,
+                                               state_dtype=state)
+    jaxpr, text = _scan_jaxpr(T, H, K, V, block, channel, state)
+    assert "pallas_call" not in jaxpr, what
+    assert "custom_call" not in text and "custom-call" not in text, what
+
+
+def test_the_published_sizes_take_the_kernel():
+    assert delta_rule.chunk_scan_supported(64, 128, 128, 64)
+    jaxpr, _ = _scan_jaxpr(128, 4, 128, 128, 64)
+    assert jaxpr.count("pallas_call") == 1
+    assert "cumsum" not in jaxpr        # nothing of XLA's form beside it
+
+
+def test_the_span_counts_the_blocks_a_layer_runs():
+    """``KdaSpec.scan_blocks``: the blocks that hold a token where the
+    kernel takes the sizes, every block of the chunk where it does not."""
+    big = hybrid.KdaSpec(heads=64, key_dim=128, value_dim=128, conv=4,
+                         chunk=64)
+    assert [big.scan_blocks(n, 512) for n in (0, 1, 64, 65, 301, 512)] \
+        == [0, 1, 1, 2, 5, 8]
+    assert big.scan_blocks(20, 32) == 1      # a chunk narrower than a block
+    small = hybrid.KdaSpec(heads=3, key_dim=12, value_dim=12, conv=4, chunk=8)
+    assert [small.scan_blocks(n, 16) for n in (1, 9, 16)] == [2, 2, 2]
+
+
 # the spec --------------------------------------------------------------------
 
 
@@ -269,6 +415,27 @@ def test_chunked_prefill_then_decode_agree_with_the_reference(
     assert np.abs(state[2]).max() > 0 and not state[[1, 3]].any()
 
 
+def test_a_prefill_through_the_chunk_kernel_agrees_with_the_reference():
+    """Heads of 128 x 128 in blocks of 64, chunks of 128: the chunk scan is
+    the kernel. A prompt of 150 tokens ends 22 tokens into its second chunk,
+    whose second block is skipped; then 4 decode steps from the state and
+    the tail the chunks left: float32, tightly, as XLA's form is held."""
+    cfg = tiny_cfg(num_hidden_layers=2, kda_chunk_size=64,
+                   linear_attn_config={"short_conv_kernel_size": 4,
+                                       "head_dim": 128, "num_heads": 2,
+                                       "num_kv_heads": None})
+    spec = _spec(cfg)
+    assert spec.kda.scan_blocks(22, 128) == 1
+    params = hybrid.init_params(spec, jax.random.key(5))
+    with jax.default_matmul_precision("highest"):
+        toks, served, _ = _serve_one(spec, params, _prompt(150), 5, "pallas",
+                                     chunk=128)
+        want = np.asarray(reference.logits_at(
+            params, cfg, toks[:-1], np.arange(149, len(toks) - 1), 192))
+    assert np.abs(want).max() > 1.0
+    np.testing.assert_allclose(served, want, atol=TIGHT)
+
+
 def _shared(spec, params):
     """Row A (45 tokens, slot 2, pages 1..) prefills in chunks of one page
     and leaves a snapshot of its state behind the chunk that ends at 32
@@ -324,7 +491,9 @@ SESSIONS = ((0, 5, 4), (1, 9, 6), (2, 13, 8), (0, 11, 5), (1, 3, 3),
 
 
 @pytest.fixture(scope="module")
-def served(model):
+def served(model, tmp_path_factory):
+    """The sessions through a ServeEngine under a profiler capture: the
+    requests, their results, the pool's audit afterwards, the spans."""
     cfg, spec, params = model
     eng = ServeEngine(params, spec, buckets=BUCKETS, max_batch=3,
                       page_len=PAGE, prefill_chunk=CHUNK, num_pages=96,
@@ -334,12 +503,11 @@ def served(model):
     reqs = [Request(prompt=np.concatenate([systems[h], _prompt(own, seed=i)]),
                     steps=steps, temperature=0.0)
             for i, (h, own, steps) in enumerate(SESSIONS)]
-    handles = eng.submit_many(reqs)
-    eng.start()
-    results = [h.result(timeout=300) for h in handles]
+    results, spans = _capture(eng, reqs,
+                              str(tmp_path_factory.mktemp("capture")))
     audit = eng.kvpool_audit()
     eng.close()
-    return reqs, results, audit
+    return reqs, results, audit, spans
 
 
 def test_the_engine_serves_the_reference_through_snapshots_and_a_share(
@@ -350,7 +518,7 @@ def test_the_engine_serves_the_reference_through_snapshots_and_a_share(
     snapshot, in a model whose every layer routes over experts of which it
     holds a quarter."""
     cfg, spec, params = model
-    reqs, results, audit = served
+    reqs, results, audit, _ = served
     assert [r.status for r in results] == ["ok"] * len(SESSIONS)
     with jax.default_matmul_precision("highest"):
         for req, res in zip(reqs, results):
@@ -365,3 +533,16 @@ def test_the_engine_serves_the_reference_through_snapshots_and_a_share(
     assert sum(s == 4 for s in shared) >= 4 and set(shared) <= {0, 4}, shared
     assert audit["ok"], audit["errors"]
     assert audit["state_used"] == 0 and audit["snapshots_held"] >= 3
+
+
+def test_the_prefill_spans_carry_the_tokens_and_the_blocks(served, model):
+    """``kda_tokens`` is the chunk's valid tokens and ``kda_blocks`` the
+    blocks of the scan a layer ran for it: at heads of 12 XLA's form runs,
+    which computes every block of the chunk whatever it holds."""
+    cfg, spec, params = model
+    chunks = [s.fields for s in served[3]
+              if s.name == "serve.prefill.dispatch"]
+    assert chunks and any(f["tokens"] < f["width"] for f in chunks)
+    assert all(f["kda_tokens"] == f["tokens"] for f in chunks)
+    assert all(f["kda_blocks"] == f["width"] // spec.kda.chunk == 2
+               for f in chunks)

@@ -21,7 +21,14 @@ CHANNEL of the key, 128 live rows of 193 slots, three layers; each form's
   program, in nanoseconds a token and call, beside its flops at the bf16
   peak and its bytes at the memory peak
   (``benchmarks/costs_olmohybrid.py``); and the triangular system alone
-  (the solve of ``(I + A) X = [V | K]``), to see its share.
+  (the solve of ``(I + A) X = [V | K]``), to see its share. With ``--kda``
+  in their place: a chunk of 512 positions of which 512, 320 and 64 are
+  tokens, one line a count with the package's KERNEL
+  (``delta_rule.delta_chunk_scan``, which skips the blocks past the count)
+  beside XLA's form (``delta_rule._delta_chunk_scan_xla``, which computes
+  every position), three layers chained: milliseconds a chunk and layer,
+  nanoseconds a VALID token and layer beside the least its bytes allow,
+  and each form's ``max_abs_diff_from_the_recurrence``.
 
 With ``--tails`` it times, in the other measurements' place, **the
 convolution's decode step alone** (``ops/ssm.py``: every live row's tail
@@ -79,6 +86,12 @@ def _recurrence(q, k, v, g, b, s):
     return o, s
 
 
+def _unit(x):
+    import jax.numpy as jnp
+
+    return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+
+
 def _best(fn, args, repeats: int = 5) -> float:
     import jax
 
@@ -91,6 +104,65 @@ def _best(fn, args, repeats: int = 5) -> float:
         jax.block_until_ready(out)
         best = min(best, time.perf_counter() - t0)
     return best
+
+
+def _kda_chunks(cfg, peaks, least_scan, cd, ks, sizes) -> None:
+    """A chunk of 512 positions of which 512, 320 and 64 are tokens: the
+    package's kernel (``delta_chunk_scan`` at these sizes) beside XLA's form
+    (``_delta_chunk_scan_xla``, which computes every position whatever the
+    count), one line a count."""
+    import jax
+    import jax.numpy as jnp
+
+    from marlin_tpu.ops import delta_rule
+
+    H, K, V, LAYERS = sizes
+    T = 512
+    q = (_unit(jax.random.normal(ks[1], (T, H, K))) * K ** -0.5).astype(cd)
+    k = _unit(jax.random.normal(ks[2], (T, H, K))).astype(cd)
+    v = jax.random.normal(ks[3], (T, H, V)).astype(cd)
+    g = -jax.random.uniform(ks[4], (T, H, K), minval=0.001, maxval=0.1)
+    b = jax.random.uniform(ks[5], (T, H), minval=0.0, maxval=2.0)
+    s0 = jax.random.normal(ks[6], (K, H, V), jnp.float32)
+    forms = {
+        "kernel": lambda *a, valid: delta_rule.delta_chunk_scan(
+            *a, block=64, valid=valid, interpret=False),
+        "xla": lambda *a, valid: delta_rule._delta_chunk_scan_xla(
+            *a, block=64)}
+    for valid in (512, 320, 64):
+        live = (jnp.arange(T) < valid)
+        gv, bv = g * live[:, None, None], b * live[:, None]
+        want, want_s = _recurrence(q[:valid], k[:valid], v[:valid],
+                                   gv[:valid], bv[:valid],
+                                   jnp.moveaxis(s0, 1, 0))
+        least = least_scan(valid, 1, cfg, peaks)
+        line = {"what": "chunk_scan", "positions": T, "valid": valid,
+                "dtype": str(jnp.dtype(cd)), "layers": LAYERS,
+                "least_ns_a_valid_token": 1e9 * least["seconds"] / valid,
+                "bound": least["bound"],
+                "output_scale": float(jnp.abs(want).max())}
+        for name, form in forms.items():
+            @jax.jit
+            def scan(s, q, k, v, g, b, n, form=form):
+                acc = 0.0
+                for i in range(LAYERS):   # (scaled a layer: nothing shared)
+                    o, s = form(q, k, v, g * (1.0 + 0.01 * i), b, s, valid=n)
+                    acc = acc + o
+                return s, acc
+
+            n = jnp.int32(valid)
+            seconds = _best(scan, (s0, q, k, v, gv, bv, n))
+            o, s1 = jax.jit(form)(q, k, v, gv, bv, s0, valid=n)
+            ns = 1e9 * seconds / (LAYERS * valid)
+            line[name] = {
+                "ms_a_chunk_and_layer": 1e3 * seconds / LAYERS,
+                "ns_a_valid_token_and_layer": ns,
+                "roofline_pct": 100 * 1e9 * least["seconds"] / valid / ns,
+                "max_abs_diff_from_the_recurrence":
+                float(jnp.abs(o[:valid] - want).max()),
+                "state_max_abs_diff": float(jnp.abs(
+                    jnp.moveaxis(s1, 1, 0) - want_s).max())}
+        print(json.dumps(line), flush=True)
 
 
 #: the tail step's three sizes: (live rows, slots, channels, layers)
@@ -228,8 +300,7 @@ def main(argv=None) -> int:
     cd = jnp.float32 if args.f32 else jnp.bfloat16
     ks = jax.random.split(jax.random.key(0), 8)
 
-    def unit(x):
-        return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    unit = _unit
 
     # ---- the decode update
     slab = jax.random.normal(ks[0], (SLOTS, K, H * V), jnp.float32)
@@ -278,6 +349,10 @@ def main(argv=None) -> int:
           flush=True)
 
     # ---- the chunked form
+    if args.kda:
+        _kda_chunks(cfg, peaks, least_scan, cd, ks, (H, K, V, LAYERS))
+        print(json.dumps({"ok": True, "device": dev.device_kind}))
+        return 0
     for T in (512, 1024):
         q = (unit(jax.random.normal(ks[1], (T, H, K))) * K ** -0.5).astype(cd)
         k = unit(jax.random.normal(ks[2], (T, H, K))).astype(cd)
@@ -310,9 +385,6 @@ def main(argv=None) -> int:
             "max_abs_diff_from_the_recurrence":
             float(jnp.abs(o - want).max()),
             "output_scale": float(jnp.abs(want).max())}), flush=True)
-        if args.kda:
-            continue
-
         nc, C = T // 64, 64
         A = jnp.tril(jax.random.normal(ks[7], (nc, H, C, C)) * 0.1, -1)
         rhs = jax.random.normal(ks[6], (nc, H, C, V + K), jnp.float32)

@@ -1,0 +1,112 @@
+"""The compiler's own schedule of the chunk scan's kernel, without a chip.
+
+    JAX_PLATFORMS=cpu python3 tools/kernel_bundles.py [--pairs 1 2 4] [--f32]
+
+Compiles ``ops/delta_rule.py:delta_chunk_scan`` at the
+``serve.solaropen2-reason128`` cell's prefill chunk (512 positions, 64 heads
+of 128 x 128) for a described ``v5e:2x2`` with the TPU compiler's dumps on
+(``LIBTPU_INIT_ARGS=--xla_jf_dump_to=... --xla_jf_dump_llo_text=true``, in a
+child process: the flags are read when libtpu loads) and reads the kernel's
+``final_hlo-static-per-bundle-utilization`` file: one line a bundle (a VLIW
+instruction word, one a cycle when nothing stalls) with the slots it fills of
+each unit (capacity a bundle: MXU 4, XLU 3, VALU 4, EUP 1, loads 3, stores
+1). Prints, for each count of head pairs a grid step holds, one JSON line:
+the bundles of ONE grid step (every loop of the kernel is unrolled, so the
+file is one pass over the body), bundles a pair, each unit's filled slots,
+and the same by windows of 500 bundles: where a unit's column stands at its
+capacity the stage waits for that unit, where every column is low it waits
+for a chain of results. PR 55 cut the kernel from 4227 to ~3050 bundles a
+pair reading this (PERF.md section 6); a time comes only from the chip
+(``tools/delta_rule_step.py --kda``). ~40 s a count."""
+
+from __future__ import annotations
+
+import argparse
+import glob
+import json
+import os
+import pathlib
+import shutil
+import subprocess
+import sys
+import tempfile
+
+ROOT = str(pathlib.Path(__file__).resolve().parent.parent)
+UNITS = ("MXU", "XLU", "VALU", "EUP", "VLOAD", "VLOAD:FILL", "VSTORE",
+         "VSTORE:SPILL", "SALU")
+
+
+def _compile(pairs: int, f32: bool) -> None:
+    """In the child: compile the scan for one described chip."""
+    sys.path.insert(0, ROOT)
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from marlin_tpu.ops import delta_rule
+    from marlin_tpu.utils.aot import tpu_topology
+
+    delta_rule._KDA_PAIRS = tuple(sorted({1, pairs}))
+    one = NamedSharding(Mesh(np.array(
+        [tpu_topology("v5e:2x2").devices[0]]).reshape(1, 1), ("a", "b")), P())
+    T, H, K = 512, 64, 128
+    cd = jnp.float32 if f32 else jnp.bfloat16
+
+    def st(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+    jax.jit(lambda q, k, v, g, b, s, n: delta_rule.delta_chunk_scan(
+        q, k, v, g, b, s, block=64, valid=n, interpret=False)).lower(
+            st((T, H, K), cd), st((T, H, K), cd), st((T, H, K), cd),
+            st((T, H, K)), st((T, H)), st((K, H, K)),
+            st((), jnp.int32)).compile()
+
+
+def read(path: str, window: int = 500) -> dict:
+    rows = []
+    for line in open(path):
+        parts = line.split()
+        if len(parts) == len(UNITS) and all(p.isdigit() for p in parts):
+            rows.append([int(p) for p in parts])
+    rows = rows[1:]                      # the first such line is the capacity
+    return {"bundles": len(rows),
+            "slots": {u: sum(r[i] for r in rows) for i, u in enumerate(UNITS)},
+            "windows": [[at] + [sum(r[i] for r in rows[at:at + window])
+                                for i in (0, 1, 2, 3)]
+                        for at in range(0, len(rows), window)]}
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--pairs", type=int, nargs="+", default=[2])
+    ap.add_argument("--f32", action="store_true")
+    ap.add_argument("--child", type=int)
+    args = ap.parse_args(argv)
+    if args.child:
+        _compile(args.child, args.f32)
+        return 0
+    for pairs in args.pairs:
+        where = tempfile.mkdtemp(prefix="kernel_bundles_")
+        env = dict(os.environ, JAX_PLATFORMS="cpu", LIBTPU_INIT_ARGS=(
+            f"--xla_jf_dump_to={where} --xla_jf_dump_llo_text=true"))
+        subprocess.run([sys.executable, __file__, "--child", str(pairs)]
+                       + (["--f32"] if args.f32 else []), env=env,
+                       capture_output=True)   # (a later dump may abort it)
+        found = glob.glob(os.path.join(
+            where, "*_kda_chunk_call*final_hlo-static-per-bundle-"
+            "utilization.txt"))
+        line = {"pairs_a_step": pairs, "f32": args.f32}
+        if found:
+            got = read(found[0])
+            line.update(bundles_a_pair=got["bundles"] / pairs, **got,
+                        windows_are="[first bundle, MXU, XLU, VALU, EUP]")
+        else:
+            line["error"] = "the kernel's schedule was not dumped"
+        print(json.dumps(line), flush=True)
+        shutil.rmtree(where, ignore_errors=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -10,7 +10,8 @@ style configuration dict (:meth:`ModelSpec.from_config`):
 - layers of three attention kinds, ``full`` (every earlier position),
   ``sliding`` (the last ``window`` positions: key ``j`` is visible to query
   ``i`` iff ``0 <= i - j < window``), each with its own rotary embedding
-  (plain, or YaRN on part of each head), and ``latent`` (below);
+  (plain, or YaRN on part of each head), and ``latent`` (below; with an
+  indexer, over the TOKENS it selects);
 - a fourth kind of layer (``LayerSpec.ssm``; below): full attention AND a
   state-space mixer side by side on one normed input, their outputs added;
 - a fifth, ``linear`` (below): a gated delta-rule mixer and NO attention,
@@ -193,6 +194,37 @@ programs meet it differently, and give the same numbers:
   (:func:`~marlin_tpu.ops.paged_attention.paged_decode_attention_latent`
   reads each page once, in place; :func:`_attend_latent_gather` is the same
   arithmetic on gathered pages).
+
+**An indexer that picks the tokens a latent layer attends**
+(:class:`IndexerSpec` on :class:`LatentSpec`; the ``deepseek_v32`` family's
+``index_*`` keys; :mod:`~marlin_tpu.ops.dsa` has the equations). Such a layer
+keeps a SECOND memory of a token beside its latent entry: one **index key**
+of ``index_head_dim`` values (a LayerNorm of its own projection of the
+block's input, rotated rotate-half), in a second page-indexed array a layer,
+``(num_pages, page_len, index_head_dim)``, of the global class and indexed by
+the same page id, so prefix sharing, copy-on-write and eviction move a
+page's index keys with its latents (:func:`_kv_slabs`, :func:`_copy_entry`,
+:meth:`ModelSpec.page_values`). A query scores every position it can see,
+``I(t, s) = sum_j w_j(t) relu(q^I_j(t) . k^I(s))`` over ``index_n_heads``
+index queries made from the query latent (:func:`_index_operands`, under
+the ``dsa_index`` scope), takes the ``index_topk`` largest (ties to the
+lower position; all positions where fewer exist) and attends THOSE entries
+and no other, every head, one softmax over the set
+(:func:`_attend_selected_tokens`: the k-th score by a radix search, the mask
+and a tile of queries' lists under ``dsa_select``; the gather of a tile's
+entries and the absorbed attention over them under ``dsa_attend``). Both
+programs use the absorbed form there, each query's own 2048 entries where
+the context holds tens of thousands: decode scores a row's index keys in
+place (:func:`~marlin_tpu.ops.dsa.index_scores_paged`) and reads the chosen
+entries out of the slab; a prefill chunk scores on the MXU
+(:func:`~marlin_tpu.ops.dsa.index_scores_chunk`), never gathers or
+up-projects the whole context, and holds the gathered entries of
+:data:`_DSA_TILE` queries at a time. A chunk wholly below ``index_topk``
+keeps the flash path over the context's first ``index_topk`` entries. The
+family's expert layers pick group-limited (``n_group``, ``topk_group``:
+:func:`~marlin_tpu.models.moe._picks`) and its leading layers are dense
+(``first_k_dense_replace``). A configuration without ``index_topk`` has no
+indexer, no second array and traces to the programs it traced to before.
 """
 
 from __future__ import annotations
@@ -207,7 +239,7 @@ import numpy as np
 
 from ..ops.sparse_attention import SparseSpec
 
-__all__ = ["RopeSpec", "LatentSpec", "SsmSpec", "DeltaSpec", "KdaSpec",
+__all__ = ["RopeSpec", "IndexerSpec", "LatentSpec", "SsmSpec", "DeltaSpec", "KdaSpec",
            "ConvSpec", "LightningSpec", "SparseSpec", "Multipliers",
            "LayerSpec", "ModelSpec", "init_params", "state_slot_copy",
            "init_layer_params", "init_kv_pages", "window_ring_pages",
@@ -215,6 +247,9 @@ __all__ = ["RopeSpec", "LatentSpec", "SsmSpec", "DeltaSpec", "KdaSpec",
            "require_int_heads"]
 
 _MASKED = -1e30  # as ops/paged_attention.py: exp() underflows to exactly 0
+#: queries whose gathered entries stand in memory at once in a prefill chunk
+#: of a layer with an indexer (x topk x entry_width x 2 B: 84 MB at 32)
+_DSA_TILE = 32
 
 
 def require_int_heads(heads, what: str) -> None:
@@ -270,12 +305,30 @@ class RopeSpec:
 
 
 @dataclasses.dataclass(frozen=True)
+class IndexerSpec:
+    """The sizes of a latent layer's lightning indexer
+    (:mod:`~marlin_tpu.ops.dsa`; the ``deepseek_v32`` family's ``index_*``
+    keys): ``heads`` index queries of ``head_dim`` a token against ONE index
+    key of ``head_dim`` a token (the layer's second page-indexed array), the
+    first ``rope_dim`` columns of both rotated (rotate-half), ``topk`` tokens
+    a query attends; ``eps`` is the index key's LayerNorm's."""
+
+    heads: int
+    head_dim: int
+    topk: int
+    rope_dim: int
+    eps: float = 1e-6
+
+
+@dataclasses.dataclass(frozen=True)
 class LatentSpec:
     """The sizes of a latent-attention layer and its softmax scale:
     ``softmax_scale`` multiplies every score (``(nope_dim + rope_dim)^-1/2``
     times the square of YaRN's ``m``), and query ``i`` is scaled once more by
     ``tau(i) = 1 + scaling_beta * ln(1 + floor(i / scaling_original_max))``
-    (1 everywhere where ``scaling_beta`` is 0)."""
+    (1 everywhere where ``scaling_beta`` is 0). With an ``indexer`` a query
+    attends the ``indexer.topk`` tokens its index scores rank first and no
+    other (all of them where fewer exist)."""
 
     q_rank: int
     kv_rank: int
@@ -285,6 +338,7 @@ class LatentSpec:
     softmax_scale: float
     scaling_beta: float = 0.0
     scaling_original_max: int = 0
+    indexer: IndexerSpec | None = None
 
     @property
     def entry_dim(self) -> int:
@@ -544,8 +598,7 @@ _LAYER_TYPES_KEYS = (
     "shared_expert_intermediate_size", "num_experts", "num_experts_per_tok",
     "vocab_size")
 _LATENT_KEYS = (
-    "num_hidden_layers", "num_attention_heads", "rope_parameters",
-    "qk_nope_head_dim", "qk_rope_head_dim", "q_lora_rank", "v_head_dim",
+    "num_hidden_layers", "num_attention_heads", "qk_nope_head_dim", "qk_rope_head_dim", "q_lora_rank", "v_head_dim",
     "n_routed_experts", "moe_intermediate_size", "hidden_size",
     "num_key_value_heads", "intermediate_size", "num_experts_per_tok",
     "vocab_size")
@@ -658,6 +711,11 @@ class ModelSpec:
     tied_head: bool = False
     #: added to the sum the picks' weights are renormalised by
     renorm_eps: float = 0.0
+    #: group-limited routing: the experts in ``n_group`` groups of which the
+    #: ``topk_group`` best (by the sum of a group's two largest biased
+    #: scores) stay eligible; (1, 1): every expert is
+    n_group: int = 1
+    topk_group: int = 1
 
     @classmethod
     def from_config(cls, cfg: dict, experts_total: int | None = None,
@@ -681,7 +739,9 @@ class ModelSpec:
         (:meth:`_from_solar_open2_config`). A configuration that lacks keys
         its family needs raises a ``ValueError`` that names them."""
         if "kv_lora_rank" in cfg:
-            _require(cfg, _LATENT_KEYS, "latent-attention (kv_lora_rank)")
+            _require(cfg, _LATENT_KEYS + (
+                ("rope_parameters",) if "rope_theta" not in cfg
+                else ("rope_scaling",)), "latent-attention (kv_lora_rank)")
             return cls._from_latent_config(cfg, experts_total, first_expert)
         if "mamba_d_ssm" in cfg:
             _require(cfg, _FALCON_H1_KEYS, "falcon_h1 (mamba_d_ssm)")
@@ -759,11 +819,12 @@ class ModelSpec:
         (``n_routed_experts`` held here, ``n_shared_experts`` x
         ``moe_intermediate_size`` shared, sigmoid scoring), YaRN on the
         rotary dimensions with ``mscale`` / ``mscale_all_dim``, and
-        ``llama_4_scaling_beta`` for the position-dependent query scale."""
-        if (int(cfg.get("n_group", 1)), int(cfg.get("topk_group", 1))) \
-                != (1, 1):
-            raise ValueError("group-limited routing (n_group / topk_group "
-                             "> 1) is not built")
+        ``llama_4_scaling_beta`` for the position-dependent query scale. The
+        rotary embedding's keys are ``rope_parameters`` or, where a file has
+        the family's published pair instead, ``rope_scaling`` (its ``type``)
+        with ``rope_theta``. ``n_group`` / ``topk_group``: group-limited
+        picks. ``index_topk`` (with ``index_n_heads``, ``index_head_dim``):
+        every layer has a lightning indexer (:class:`IndexerSpec`)."""
         if not cfg.get("norm_topk_prob", True):
             raise ValueError("picks that are not renormalised "
                              "(norm_topk_prob false) are not built")
@@ -773,7 +834,12 @@ class ModelSpec:
             LayerSpec("latent", int(cfg["num_attention_heads"]),
                       "dense" if i < dense_first else "moe")
             for i in range(n))
-        rp = cfg["rope_parameters"]
+        if "rope_parameters" in cfg:
+            rp = cfg["rope_parameters"]
+        else:
+            rp = {**(cfg.get("rope_scaling") or {}),
+                  "rope_theta": cfg["rope_theta"]}
+            rp["rope_type"] = rp.pop("type", "default")
         factor = float(rp.get("factor", 1.0))
         yarn = rp.get("rope_type", "default") == "yarn"
 
@@ -797,9 +863,18 @@ class ModelSpec:
             softmax_scale=(nope + rdim) ** -0.5 * m_all * m_all,
             scaling_beta=float(rp.get("llama_4_scaling_beta", 0.0)),
             scaling_original_max=int(
-                rp.get("original_max_position_embeddings", 0)))
+                rp.get("original_max_position_embeddings", 0)),
+            indexer=None if "index_topk" not in cfg else IndexerSpec(
+                heads=int(cfg["index_n_heads"]),
+                head_dim=int(cfg["index_head_dim"]),
+                topk=int(cfg["index_topk"]), rope_dim=rdim,
+                eps=float(cfg.get("index_norm_eps", 1e-6))))
         held = int(cfg["n_routed_experts"])
         total = _experts_total(held, experts_total, first_expert)
+        n_group = int(cfg.get("n_group", 1))
+        if total % n_group or not 0 < int(cfg.get("topk_group", 1)) <= n_group:
+            raise ValueError(f"{total} experts are not {n_group} whole "
+                             f"groups of which topk_group stay")
         width = int(cfg["moe_intermediate_size"])
         return cls(
             d_model=int(cfg["hidden_size"]), head_dim=nope + rdim,
@@ -815,7 +890,8 @@ class ModelSpec:
             norm_eps=float(cfg.get("rms_norm_eps", 1e-6)),
             param_dtype=str(cfg.get("param_dtype", "bfloat16")),
             compute_dtype=str(cfg.get("compute_dtype", "bfloat16")),
-            scoring="sigmoid", latent=latent)
+            scoring="sigmoid", latent=latent, n_group=n_group,
+            topk_group=int(cfg.get("topk_group", 1)))
 
     @classmethod
     def _from_falcon_h1_config(cls, cfg: dict):
@@ -1176,15 +1252,16 @@ class ModelSpec:
         """Cache values ONE page id of class ``kind`` holds over all layers
         (``full``: the global class, the full, the latent and the sparse
         layers; ``sliding``: the window class): K and V per KV head for a
-        full or a sliding layer, one latent entry for a latent one, K, V and
-        the compressed keys (``1 / stride`` of an entry a token) for a sparse
-        one."""
+        full or a sliding layer, one latent entry for a latent one (and its
+        index key, where the layer has an indexer), K, V and the compressed
+        keys (``1 / stride`` of an entry a token) for a sparse one."""
         kinds = ("sliding",) if kind == "sliding" else (
             "full", "latent", "sparse")
         kv = self.kv_heads * self.head_dim
+        ix = self.latent and self.latent.indexer
         return sum(
-            page_len * self.latent.entry_width if ly.attn == "latent"
-            else 2 * page_len * kv + page_len // self.sparse.stride * kv
+            page_len * (self.latent.entry_width + (ix.head_dim if ix else 0))
+            if ly.attn == "latent" else 2 * page_len * kv + page_len // self.sparse.stride * kv
             if ly.attn == "sparse" else 2 * page_len * kv
             for ly in self.layers if ly.attn in kinds)
 
@@ -1298,6 +1375,16 @@ def init_layer_params(spec: ModelSpec, ly: LayerSpec, key) -> dict:
             wkv_b=_normal(ks[3], (la.kv_rank, H * (la.nope_dim + la.v_dim)),
                           la.kv_rank ** -0.5, dt),
             wo=_normal(ks[4], (H * la.v_dim, d), (H * la.v_dim) ** -0.5, dt))
+        if la.indexer is not None:   # N(0, 1/fan_in); the norm gain 1, bias 0
+            ix = la.indexer
+            kx = jax.random.split(jax.random.fold_in(key, 1), 3)
+            lp.update(
+                ix_wq_b=_normal(kx[0], (la.q_rank, ix.heads * ix.head_dim),
+                                la.q_rank ** -0.5, dt),
+                ix_wk=_normal(kx[1], (d, ix.head_dim), s, dt),
+                ix_w=_normal(kx[2], (d, ix.heads), s, dt),
+                ix_k_gain=jnp.ones((ix.head_dim,), jnp.float32),
+                ix_k_bias=jnp.zeros((ix.head_dim,), jnp.float32))
     else:
         lp.update(wq=_normal(ks[0], (d, hq), s, dt),
                   wk=_normal(ks[1], (d, hk), s, dt),
@@ -1539,7 +1626,10 @@ def init_kv_pages(spec: ModelSpec, num_pages: int, window_pages: int,
     THIRD page-indexed array after K and V: its compressed keys ``(num_pages,
     page_len / stride, kv_heads * head_dim)``, entry ``e`` of page ``p`` the
     mean of the ``2 * stride`` keys that END at token ``p * page_len + (e +
-    1) * stride - 1`` (:mod:`~marlin_tpu.ops.sparse_attention`).
+    1) * stride - 1`` (:mod:`~marlin_tpu.ops.sparse_attention`). A latent
+    layer with an indexer has a SECOND page-indexed array after its slab:
+    its index keys ``(num_pages, page_len, index_head_dim)``, one lane tile
+    a token (:mod:`~marlin_tpu.ops.dsa`).
     ``state_slots`` counts every slot of
     the arrays: the rows' and, after them, the pool's snapshot slots."""
     if num_pages < 2 or (spec.has_window and window_pages < 2):
@@ -1560,8 +1650,11 @@ def init_kv_pages(spec: ModelSpec, num_pages: int, window_pages: int,
                                  "conv": spec.conv,
                                  "lightning": spec.lightning}[ly.attn])
         if ly.attn == "latent":
+            ix = spec.latent.indexer
             return (jnp.zeros((num_pages, page_len,
-                               spec.latent.entry_width), dt),)
+                               spec.latent.entry_width), dt),) + (
+                () if ix is None else   # the index keys ride with the page
+                (jnp.zeros((num_pages, page_len, ix.head_dim), dt),))
         kv = tuple(
             jnp.zeros((window_pages if ly.attn == "sliding" else num_pages,
                        page_len, spec.kv_heads * spec.head_dim), dt)
@@ -1790,12 +1883,20 @@ def _latent_attention(spec: ModelSpec, ly: LayerSpec, lp: dict, x, positions,
     (T, H, rope), entry (T, entry_width), scale (T,) float32, wkv_b
     (kv_rank, H, nope + v))``, which stores ``entry`` where the program keeps
     its cache and returns the heads' outputs (T, H, v): every score is
-    ``scale[i] * (q_nope . k_nope + q_pe . k_pe)``."""
+    ``scale[i] * (q_nope . k_nope + q_pe . k_pe)``. A layer with an indexer
+    hands ``attend`` one argument more, under the ``dsa_index`` scope: the
+    index queries (T, J, D), the token's index key (T, D) (what the second
+    array keeps) and the heads' weights (T, J) float32
+    (:func:`_index_operands`)."""
     la, cd = spec.latent, jnp.dtype(spec.compute_dtype)
     T, H = x.shape[0], ly.q_heads
     h = _rmsnorm(x, lp["ln1"], spec.norm_eps).astype(cd)
     c_q = _rmsnorm(_mm(h, lp["wq_a"], jnp.float32), lp["q_norm"],
                    spec.norm_eps).astype(cd)
+    index = ()
+    if la.indexer is not None:
+        with jax.named_scope("dsa_index"):
+            index = (_index_operands(spec, lp, h, c_q, positions),)
     q = _mm(c_q, lp["wq_b"]).reshape(T, H, la.nope_dim + la.rope_dim)
     q_pe = _rope(q[..., la.nope_dim:], positions, spec.rope_full)
     kv = _mm(h, lp["wkv_a"], jnp.float32)
@@ -1806,9 +1907,84 @@ def _latent_attention(spec: ModelSpec, ly: LayerSpec, lp: dict, x, positions,
         axis=-1).astype(cd)
     o = attend(q[..., :la.nope_dim], q_pe, entry,
                la.query_scale(positions),
-               lp["wkv_b"].reshape(la.kv_rank, H, la.nope_dim + la.v_dim))
+               lp["wkv_b"].reshape(la.kv_rank, H, la.nope_dim + la.v_dim),
+               *index)
     return x + _mm(o.reshape(T, H * la.v_dim).astype(cd), lp["wo"],
                    jnp.float32)
+
+
+def _index_operands(spec: ModelSpec, lp: dict, h, c_q, positions):
+    """A token's side of the lightning indexer (:mod:`~marlin_tpu.ops.dsa`):
+    ``q^I`` (T, J, D) from the query latent ``c_q``, ``k^I`` (T, D) =
+    LayerNorm(``h`` W_k) (gain and bias, float32), both with their first
+    ``rope_dim`` columns rotated by the layer's frequencies in the
+    ROTATE-HALF layout whatever the main attention's is, in the compute
+    dtype; ``w`` (T, J) float32 = ``h`` W_w x J^-1/2 x D^-1/2."""
+    ix, cd = spec.latent.indexer, h.dtype
+    T = h.shape[0]
+    rope = dataclasses.replace(spec.rope_full, interleave=False)
+    qi = _rope(_mm(c_q, lp["ix_wq_b"]).reshape(T, ix.heads, ix.head_dim),
+               positions, rope)
+    k = _mm(h, lp["ix_wk"], jnp.float32)
+    mean = jnp.mean(k, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(k - mean), axis=-1, keepdims=True)
+    k = ((k - mean) * jax.lax.rsqrt(var + ix.eps) * lp["ix_k_gain"]
+         + lp["ix_k_bias"])
+    ki = _rope(k[:, None, :], positions, rope)[:, 0].astype(cd)
+    w = _mm(h, lp["ix_w"], jnp.float32) * (ix.heads * ix.head_dim) ** -0.5
+    return qi, ki, w
+
+
+def _attend_selected_tokens(q, ctx, scores, n_valid, la: LatentSpec,
+                            tile: int, entry_of=None, live=None):
+    """The selection and the attention of rows that hold their index scores:
+    ``q`` (R, H, entry_width) absorbed and scaled, ``scores`` (R, L) float32
+    over positions 0..L-1 of which row ``r``'s first ``n_valid[r]`` count.
+    ``ctx`` is what a list of positions reads entries from: the row's context
+    (L, entry_width) in position order, or with ``entry_of`` (positions (R,
+    k) -> flat rows of ``ctx``) a slab's rows. A ``tile`` of rows at a time:
+    under ``dsa_select`` the k-th score, the mask and the tile's lists; under
+    ``dsa_attend`` the tile's gathered entries (``tile x topk x
+    entry_width``: never the whole chunk's) and one softmax over each row's
+    own. A tile none of whose rows is ``live`` (a short chunk's padding) is
+    not computed and reads zeros. Returns the attended latents (R, H,
+    kv_rank) and, a row, the entries it attended (R,) int32: the lists'
+    own lengths, 0 in a tile that was not computed."""
+    from ..ops import dsa
+
+    R, L = scores.shape
+    pad = -L % 128
+    if pad:   # the prefix counts go by whole lane tiles
+        scores = jnp.pad(scores, ((0, 0), (0, pad)),
+                         constant_values=-jnp.inf)
+    k = min(la.indexer.topk, L + pad)
+    tile = min(tile, R)
+    if R % tile:
+        raise ValueError(f"{R} rows are not whole tiles of {tile}")
+
+    def one_tile(args):
+        qt, st, nt, at, lt = args
+
+        def attended():
+            with jax.named_scope("dsa_select"):
+                idx, count = dsa.select_tokens(st, nt, k)
+            with jax.named_scope("dsa_attend"):
+                rows = idx if entry_of is None else entry_of(idx, at)
+                return dsa.attend_list(qt, dsa.gather_entries(ctx, rows),
+                                       count, la.kv_rank), count
+
+        if live is None:
+            return attended()
+        return jax.lax.cond(
+            jnp.any(lt), attended,
+            lambda: (jnp.zeros((tile, q.shape[1], la.kv_rank), q.dtype),
+                     jnp.zeros((tile,), jnp.int32)))
+
+    tiles = lambda a: a.reshape(R // tile, tile, *a.shape[1:])  # noqa: E731
+    out, count = jax.lax.map(one_tile, (
+        tiles(q), tiles(scores), tiles(n_valid), tiles(jnp.arange(R)),
+        tiles(jnp.ones((R,), bool) if live is None else live)))
+    return out.reshape(R, *out.shape[2:]), count.reshape(R)
 
 
 def _parallel_mixers(spec: ModelSpec, ly: LayerSpec, lp: dict, x, positions,
@@ -2017,7 +2193,8 @@ def _ffn_half(spec: ModelSpec, ly: LayerSpec, lp: dict, x, valid):
             lp["moe"], h, valid, top_k=spec.top_k,
             first_expert=spec.first_expert, routed_scale=spec.routed_scale,
             compute_dtype=cd, scoring=spec.scoring,
-            renorm_eps=spec.renorm_eps)
+            renorm_eps=spec.renorm_eps, n_group=spec.n_group,
+            topk_group=spec.topk_group)
     return x + out, counts
 
 
@@ -2409,6 +2586,7 @@ def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
     x = _embed(spec, params, chunk)
     new_kv, new_state, counts = {}, {}, jnp.zeros((3,), jnp.int32)
     walked = []  # a sparse layer's (blocks its tiles met, their tokens took)
+    scored = []  # an indexer's (queries that selected, pairs they scored)
     for i, ly in enumerate(spec.layers):
         name = f"l{i}"
 
@@ -2525,16 +2703,59 @@ def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
             walked.append(met)
             return o
 
-        def attend_latent(q_nope, q_pe, entry, scale, wkv_b, name=name):
-            new_kv[name] = (entry,)
-            (ce,) = ctx[name]
+        def attend_latent(q_nope, q_pe, entry, scale, wkv_b, index=None,
+                          name=name):
+            la = spec.latent
+            new_kv[name] = (entry,) if index is None else (entry, index[1])
+            ce, *ck = ctx[name]
             ce = jax.lax.dynamic_update_slice(ce, entry.astype(ce.dtype),
                                               (chunk_start, 0))
-            if C % 128 == 0:   # the flash kernel's rows come in lane tiles
-                return _attend_latent_flash(q_nope, q_pe, ce, wkv_b, scale,
-                                            chunk_start, spec.latent)
-            return _attend_latent_blocks(q_nope, q_pe, ce, wkv_b, scale,
-                                         q_pos, spec.latent, block=C)
+
+            def every(ce):
+                if C % 128 == 0:   # the flash kernel's rows: lane tiles
+                    return _attend_latent_flash(q_nope, q_pe, ce, wkv_b,
+                                                scale, chunk_start, la)
+                return _attend_latent_blocks(q_nope, q_pe, ce, wkv_b, scale,
+                                             q_pos, la, block=C)
+
+            if index is None:
+                return every(ce)
+            # a layer with an indexer: a chunk wholly below `topk` attends
+            # every position it can see (all of them lie in the context's
+            # first `topk` entries); any other scores the row's index keys,
+            # takes each query's `topk` tokens and gathers THOSE entries,
+            # absorbed as decode is: the whole context is neither
+            # up-projected nor met
+            from ..ops import dsa
+
+            qi, ki, w = index
+            ck = jax.lax.dynamic_update_slice(
+                ck[0], ki.astype(ck[0].dtype), (chunk_start, 0))
+            topk = la.indexer.topk
+
+            def selected():
+                with jax.named_scope("dsa_index"):
+                    if C % 128 == 0 and ck.shape[0] % 512 == 0:
+                        scores = dsa.index_scores_chunk(
+                            qi, w, ck, chunk_start,
+                            jnp.clip(length - chunk_start, 0, C))
+                    else:
+                        scores = dsa.index_scores(qi, w, ck, q_pos)
+                ot, _ = _attend_selected_tokens(
+                    _absorbed_query(q_nope, q_pe, scale, wkv_b, la), ce,
+                    scores, q_pos + 1, la, _DSA_TILE, live=valid)
+                return jnp.einsum(
+                    "thc,chv->thv", ot,
+                    wkv_b[..., la.nope_dim:].astype(ot.dtype),
+                    preferred_element_type=jnp.float32).astype(ce.dtype)
+
+            scored.append(jnp.where(
+                chunk_start + C <= topk, 0,
+                jnp.stack([jnp.sum(valid), jnp.sum(jnp.where(
+                    valid, q_pos + 1, 0))])).astype(jnp.int32))
+            return jax.lax.cond(
+                chunk_start + C <= topk,
+                lambda: every(ce[:max(topk, C)]).astype(ce.dtype), selected)
 
         def attend(q, k, v, name=name, ly=ly):
             new_kv[name] = (k, v)
@@ -2596,6 +2817,9 @@ def _lm_prefill_paged_spec_jit(params, pages, gtable, wtable, chunk,
                             _row_key(seed, 0))
     if walked:  # after the expert layers' three
         counts = jnp.concatenate([counts, sum(walked).astype(jnp.int32)])
+    if scored:  # likewise: queries of ONE layer (all alike), pairs of all
+        counts = jnp.concatenate([counts, jnp.stack([
+            scored[0][0], sum(sc[1] for sc in scored)])])
     return new_pages, first, counts, logits
 
 
@@ -2719,6 +2943,7 @@ def _lm_decode_paged_spec_jit(params, pages, gtables, wtables, positions,
                                 lower // page_len, lower)
     x = _embed(spec, params, cur_tokens)
     new_pages, counts = {}, jnp.zeros((3,), jnp.int32)
+    read = []  # a layer with an indexer: (entries attended, held, rows past)
     for i, ly in enumerate(spec.layers):
         name = f"l{i}"
         tables, pids, first_page, low = per_class.get(ly.attn, one_table)
@@ -2812,17 +3037,42 @@ def _lm_decode_paged_spec_jit(params, pages, gtables, wtables, positions,
                 q, pk, pv, gtables, idx, taken.sum(axis=-1), lengths,
                 sp.block)
 
-        def attend_latent(q_nope, q_pe, entry, scale, wkv_b, name=name,
-                          tables=tables, pids=pids):
-            (slab,) = pages[name]
+        def attend_latent(q_nope, q_pe, entry, scale, wkv_b, index=None,
+                          name=name, tables=tables, pids=pids):
+            # the entry and, of a layer with an indexer, the index key
+            slabs = pages[name]
+            news = (entry,) if index is None else (entry, index[1])
             for b in range(B):  # as _scatter_kv_entries: in-place updates
-                slab = jax.lax.dynamic_update_slice(
-                    slab, entry[b].astype(slab.dtype)[None, None],
-                    (pids[b], off[b], 0))
-            new_pages[name] = (slab,)
+                slabs = tuple(jax.lax.dynamic_update_slice(
+                    t, new[b].astype(t.dtype)[None, None],
+                    (pids[b], off[b], 0)) for t, new in zip(slabs, news))
+            slab = slabs[0]
+            new_pages[name] = slabs
             la = spec.latent
             q = _absorbed_query(q_nope, q_pe, scale, wkv_b, la)
-            if kernel == "pallas":
+            if index is not None:
+                # each row scores its own index keys in place, takes its
+                # `topk` tokens and reads THOSE entries out of the slab
+                from ..ops import dsa
+
+                qi, _, w = index
+                with jax.named_scope("dsa_index"):
+                    scores = (dsa.index_scores_paged if kernel == "pallas"
+                              else dsa.index_scores_gather)(
+                                  qi, w, slabs[1], tables, lengths)
+
+                def rows_of(idx, at):   # positions -> rows of the flat slab
+                    return (tables[at[:, None], idx // page_len] * page_len
+                            + idx % page_len)
+
+                ot, took = _attend_selected_tokens(
+                    q, slab.reshape(-1, slab.shape[-1]), scores, lengths, la,
+                    B, rows_of)
+                read.append(jnp.stack([
+                    jnp.sum(jnp.where(live, took, 0)),
+                    jnp.sum(jnp.where(live, lengths, 0)),
+                    jnp.sum(live & (lengths > la.indexer.topk))]))
+            elif kernel == "pallas":
                 ot = paged_decode_attention_latent(q, slab, tables, lengths,
                                                    value_dim=la.kv_rank)
             else:
@@ -2839,6 +3089,10 @@ def _lm_decode_paged_spec_jit(params, pages, gtables, wtables, positions,
                               "conv": mix_conv,
                               "lightning": mix_lightning}.get(ly.attn, mix))
         counts = counts + c
+    if read:  # entries summed over the layers, the rows those of ONE layer
+        counts = jnp.concatenate([counts, jnp.stack([
+            sum(r[0] for r in read), sum(r[1] for r in read),
+            read[0][2]]).astype(jnp.int32)])
     logits = _head_logits(spec, params, x)
     nxt = _pick_token_rows(temperature, top_p, top_k, logits, seeds,
                            steps_done)
@@ -2872,7 +3126,10 @@ def decode_paged(params, pages, tables, positions, cur_tokens, steps_done,
     for a model with state-space mixers, ``state slots (B,)`` after them; a
     row whose global table starts at the dummy page is a dummy row, is
     routed to no expert and names the dummy state slot 0. Returns ``(pages, next_tokens, counts, logits)`` as
-    :func:`prefill_paged`."""
+    :func:`prefill_paged`; of a model with an indexer ``counts`` holds,
+    after the expert layers' three, what the live rows' layers read: the
+    entries their lists attended and the tokens their contexts hold (both
+    summed over rows and layers) and the rows past ``index_topk``."""
     args, static = _decode_args(params, pages, tables, positions, cur_tokens,
                                 steps_done, seeds, temperature, top_p, top_k,
                                 spec, page_len, kernel, prev_tokens,

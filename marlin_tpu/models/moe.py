@@ -277,15 +277,33 @@ def _grouped_matmul(xs, w, sizes, out_dtype, tiling=None):
     return out[:xs.shape[0]]
 
 
-def _picks(logits, mp: dict, top_k: int, scoring: str, renorm_eps: float):
+def _picks(logits, mp: dict, top_k: int, scoring: str, renorm_eps: float,
+           n_group: int = 1, topk_group: int = 1):
     """The router's picks from its float32 outputs ``logits`` (T, E):
     ``(weights (T, k), experts (T, k))`` as :func:`moe_experts_ffn`
-    describes them, the weights renormalised over the picks."""
+    describes them, the weights renormalised over the picks. With
+    ``n_group`` > 1 (sigmoid scoring) the picks are group-limited: the
+    experts lie in ``n_group`` groups of consecutive ones, a group scores the
+    sum of its TWO largest biased scores, and only the ``topk_group`` best
+    groups' experts can be picked."""
+    if n_group > 1 and scoring != "sigmoid":
+        raise ValueError("group-limited picks are the sigmoid scoring's")
     if scoring == "softmax":
         topv, topi = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
     elif scoring == "sigmoid":
         scores = jax.nn.sigmoid(logits)
-        _, topi = jax.lax.top_k(scores + mp["e_bias"], top_k)
+        choice = scores + mp["e_bias"]
+        if n_group > 1:
+            T, E = choice.shape
+            best2, _ = jax.lax.top_k(choice.reshape(T, n_group, E // n_group),
+                                     2)
+            _, kept = jax.lax.top_k(jnp.sum(best2, axis=-1), topk_group)
+            stays = jnp.any(kept[:, :, None] == jnp.arange(n_group)[None,
+                                                                   None, :],
+                            axis=1)                               # (T, groups)
+            choice = jnp.where(jnp.repeat(stays, E // n_group, axis=1),
+                               choice, -jnp.inf)
+        _, topi = jax.lax.top_k(choice, top_k)
         topv = jnp.take_along_axis(scores, topi, axis=-1)
     else:
         raise ValueError(f"unknown expert scoring {scoring!r}")
@@ -295,7 +313,8 @@ def _picks(logits, mp: dict, top_k: int, scoring: str, renorm_eps: float):
 
 def moe_experts_ffn(mp: dict, h, valid, *, top_k: int, first_expert: int = 0,
                     routed_scale: float = 1.0, compute_dtype=None,
-                    scoring: str = "softmax", renorm_eps: float = 0.0):
+                    scoring: str = "softmax", renorm_eps: float = 0.0,
+                    n_group: int = 1, topk_group: int = 1):
     """The dropless expert layer of the serving path, for a ``(T, d)`` batch
     of tokens — a prefill chunk or a decode bucket's rows alike, so both
     route identically: float32 softmax router over ALL the model's experts
@@ -317,7 +336,9 @@ def moe_experts_ffn(mp: dict, h, valid, *, top_k: int, first_expert: int = 0,
     the picks are the ``top_k`` largest of score + ``mp["e_bias"]`` (E,), a
     per-expert bias that only selects; the weights are the picks' own scores
     renormalised over themselves (``renorm_eps`` is added to their sum
-    first, where a family's implementation does).
+    first, where a family's implementation does). ``n_group`` /
+    ``topk_group`` (static) limit the picks to the best groups
+    (:func:`_picks`); (1, 1) is no limit.
 
     The router reads ``h`` as it comes (float32 from the caller's norm: a
     rounded input flips near-ties among the picks); the experts' matmuls run
@@ -331,7 +352,8 @@ def moe_experts_ffn(mp: dict, h, valid, *, top_k: int, first_expert: int = 0,
     held = mp["e_gate"].shape[0]
     logits = jnp.matmul(h.astype(jnp.float32),
                         mp["router"].astype(jnp.float32), precision="highest")
-    topv, topi = _picks(logits, mp, top_k, scoring, renorm_eps)    # (T, k)
+    topv, topi = _picks(logits, mp, top_k, scoring, renorm_eps, n_group,
+                        topk_group)                                # (T, k)
     local = ((topi >= first_expert) & (topi < first_expert + held)
              & valid[:, None])
     # one row per assignment, sorted by held expert; what is not held here

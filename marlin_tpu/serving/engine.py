@@ -2625,28 +2625,42 @@ class ServeEngine:
         return (int(np.where(sparse, np.minimum(held, sp.topk), held).sum())
                 * each, int(held.sum()) * each, int(sparse.sum()))
 
-    @staticmethod
-    def _moe_counts(span, counts) -> None:
+    def _moe_counts(self, span, counts) -> None:
         """A ModelSpec's programs return, after the tokens, what their
         expert layers counted (summed over layers) and, a prefill chunk of a
         model with sparse layers, after those the blocks its tiles of
         queries met and the blocks their tokens took
-        (``hybrid._attend_sparse_chunk``): set on the span in which the
-        result landed — the dispatch's own span has closed by then, and a
-        span's fields cannot be set afterwards. Read only while a trace is
-        taken; the arrays have landed with the tokens."""
+        (``hybrid._attend_sparse_chunk``). A model with a lightning indexer
+        returns after the experts' three: a prefill chunk the queries that
+        selected and the (query, key) pairs they scored (``dsa_queries``,
+        ``dsa_pairs_scored``); a decode call the entries its live rows'
+        lists attended, the tokens their contexts hold (both summed over
+        rows and layers) and the rows past ``index_topk``
+        (``dsa_tokens_attended``, ``dsa_tokens_held``, ``dsa_rows``). Set on
+        the span in which the result landed — the dispatch's own span has
+        closed by then, and a span's fields cannot be set afterwards. Read
+        only while a trace is taken; the arrays have landed with the
+        tokens."""
         if counts is not None and span.is_enabled():
             # analyze: ignore[host-sync] — a few ints that rode back with
             # the tokens the caller has just synced on
-            assigned, local, touched, *walked = (
+            assigned, local, touched, *more = (
                 int(c) for c in np.asarray(counts))
             span.set_metadata(moe_assignments=assigned,
                               moe_local_assignments=local,
                               moe_experts_touched=touched)
-            if walked:
-                met, taken = walked
+            if more and self._spec.sparse is not None:
+                met, taken = more
                 span.set_metadata(sparse_blocks_met=met,
                                   sparse_blocks_taken=taken)
+            elif len(more) == 2:   # an indexer's prefill chunk
+                queries, pairs = more
+                span.set_metadata(dsa_queries=queries,
+                                  dsa_pairs_scored=pairs)
+            elif more:             # an indexer's decode call
+                attended, held, rows = more
+                span.set_metadata(dsa_tokens_attended=attended,
+                                  dsa_tokens_held=held, dsa_rows=rows)
 
     def _leave_paged(self, pool, pools, launch: _Launch) -> None:
         """Free the slots of the rows whose step budget ends with ``launch``,

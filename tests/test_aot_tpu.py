@@ -1419,3 +1419,33 @@ def test_solaropen2_programs_compile_for_v5e_at_the_cells_sizes(one_chip):
         assert m.alias_size_in_bytes >= kv + state
         assert m.temp_size_in_bytes < min(kv, state) // 2
         assert m.peak_memory_in_bytes < 16.9e9
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_index_score_kernels_mosaic_compile_at_the_deepseekv32_cells_shapes(
+        one_chip, dtype):
+    """``serve.deepseekv32-longctx32``'s index scores: a prefill chunk's 1024
+    queries x 64 index heads of 128 against the row's 67,584 index keys (tiles
+    of 16 queries x 512 keys: one matmul a tile, the ReLU, the head weights
+    and the sum over a query's heads on the tile in VMEM), and a decode
+    call's 32 rows walking their own 264 pages of 256 keys out of the
+    index-key slab, which stays in HBM (float32: the f32 check's)."""
+    from marlin_tpu.ops.dsa import (_dsa_index_chunk_call,
+                                    _dsa_index_paged_call)
+
+    def st(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    T, J, D, L, B, W, page = 1024, 64, 128, 67584, 32, 264, 256
+    c = _dsa_index_chunk_call.trace(
+        st((T, J, D), dtype), st((T, J), jnp.float32), st((L, D), dtype),
+        st((2,)), tq=16, tk=512, interpret=False).lower().compile()
+    assert "tpu_custom_call" in c.as_text()
+    assert c.memory_analysis().temp_size_in_bytes < T * L * 4 // 8
+    p = _dsa_index_paged_call.trace(
+        st((B, J, D), dtype), st((B, J), jnp.float32),
+        st((2464, page, D), dtype), st((B, W)), st((B,)),
+        interpret=False).lower().compile()
+    assert "tpu_custom_call" in p.as_text()
+    assert p.memory_analysis().temp_size_in_bytes < B * W * page * 4

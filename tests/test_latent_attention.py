@@ -690,5 +690,10 @@ def test_the_spec_of_the_benchmarks_configuration():
             spec.has_window) \
         == (4096, 2048, 2048, 128, 32, 0, 4, 1.0, "sigmoid", 32768, False)
     assert kv_page_bytes({}, spec, 256) == 6 * 256 * 384 * 2
-    with pytest.raises(ValueError, match="group-limited"):
-        hybrid.ModelSpec.from_config(tiny_cfg(n_group=2))
+    # group-limited routing is built since PR 56 (tests/test_moe.py,
+    # tests/test_dsa.py); groups that do not divide the experts are refused
+    grouped = hybrid.ModelSpec.from_config(tiny_cfg(n_group=2, topk_group=1))
+    assert (grouped.n_group, grouped.topk_group) == (2, 1)
+    assert (spec.n_group, spec.topk_group) == (1, 1)
+    with pytest.raises(ValueError, match="whole groups"):
+        hybrid.ModelSpec.from_config(tiny_cfg(n_group=3))

@@ -244,3 +244,110 @@ def test_moe_offload_structure_guard(mesh):
                          moe_every=2)
     with pytest.raises(ValueError, match="uniform layer structure"):
         lm_loss(p, toks, mesh, heads=2, remat=True, offload_residuals=True)
+
+
+# group-limited picks (the deepseek_v32 family's routing) ----------------------
+
+
+def _group_limited_by_hand(scores, bias, n_group, topk_group, top_k):
+    """A plain loop: a group scores the sum of its TWO largest biased scores;
+    the best ``topk_group`` groups stay; the ``top_k`` largest biased scores
+    among their experts are the picks; the weights are the picks' own
+    UNBIASED scores renormalised."""
+    T, E = scores.shape
+    per = E // n_group
+    picks, weights = [], []
+    for t in range(T):
+        c = scores[t] + bias
+        gs = [np.sort(c[g * per:(g + 1) * per])[-2:].sum()
+              for g in range(n_group)]
+        stay = sorted(range(n_group), key=lambda g: (-gs[g], g))[:topk_group]
+        ok = [e for e in range(E) if e // per in stay]
+        top = sorted(ok, key=lambda e: (-c[e], e))[:top_k]
+        picks.append(top)
+        weights.append(scores[t, top] / scores[t, top].sum())
+    return np.asarray(weights), np.asarray(picks)
+
+
+def test_group_limited_picks_are_the_plain_loop():
+    from marlin_tpu.models.moe import _picks
+
+    rng = np.random.default_rng(0)
+    logits = jnp.asarray(rng.standard_normal((40, 32)), jnp.float32)
+    # a bias large enough to move picks: it selects and never weighs
+    mp = {"e_bias": jnp.asarray(0.3 * rng.standard_normal(32), jnp.float32)}
+    w, i = _picks(logits, mp, 4, "sigmoid", 0.0, n_group=8, topk_group=3)
+    sc = np.asarray(jax.nn.sigmoid(logits))
+    want_w, want_i = _group_limited_by_hand(sc, np.asarray(mp["e_bias"]), 8,
+                                            3, 4)
+    np.testing.assert_array_equal(np.asarray(i), want_i)
+    np.testing.assert_allclose(np.asarray(w), want_w, atol=1e-6)
+    # the sum of TWO, not the max: some token's kept groups differ
+    c = sc + np.asarray(mp["e_bias"])
+    by_max = np.argsort(-c.reshape(40, 8, 4).max(-1), axis=-1)[:, :3]
+    by_two = np.argsort(-np.sort(c.reshape(40, 8, 4), -1)[..., -2:].sum(-1),
+                        axis=-1)[:, :3]
+    assert any(set(a) != set(b) for a, b in zip(by_max, by_two))
+    # the bias selects: without it other experts are picked
+    _, unbiased = _picks(logits, {"e_bias": jnp.zeros(32)}, 4, "sigmoid",
+                         0.0, n_group=8, topk_group=3)
+    assert (np.asarray(unbiased) != np.asarray(i)).any()
+
+
+@pytest.mark.parametrize("scoring", ["softmax", "sigmoid"])
+def test_one_group_of_which_one_stays_is_todays_picks_bit_for_bit(scoring):
+    from marlin_tpu.models.moe import _picks
+
+    rng = np.random.default_rng(1)
+    logits = jnp.asarray(rng.standard_normal((24, 16)), jnp.float32)
+    mp = {"e_bias": jnp.asarray(0.05 * rng.standard_normal(16), jnp.float32)}
+    a = jax.jit(lambda x: _picks(x, mp, 4, scoring, 0.0))(logits)
+    b = jax.jit(lambda x: _picks(x, mp, 4, scoring, 0.0, n_group=1,
+                                 topk_group=1))(logits)
+    for x, y in zip(a, b):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    low = [str(jax.jit(lambda x, kw=kw: _picks(x, mp, 4, scoring, 0.0, **kw))
+               .lower(logits).as_text())
+           for kw in ({}, {"n_group": 1, "topk_group": 1})]
+    assert low[0] == low[1]
+
+
+def test_the_sixteen_shares_of_a_group_limited_layer_add_up():
+    """The guide's share test at a small size: what each of 16 chips computes
+    for its own experts of a group-limited layer (64 experts, 8 groups of
+    which 4 stay, 8 picks), the shared expert counted once, is the uncut
+    layer."""
+    from marlin_tpu.models.moe import moe_experts_ffn
+
+    E, d, f, T, shares = 64, 32, 16, 24, 16
+    ks = jax.random.split(jax.random.key(2), 9)
+    nrm = lambda k, shape, s: jax.random.normal(k, shape, jnp.float32) * s  # noqa: E731
+    mp = {"router": nrm(ks[0], (d, E), d ** -0.5),
+          "e_gate": nrm(ks[1], (E, d, f), d ** -0.5),
+          "e_up": nrm(ks[2], (E, d, f), d ** -0.5),
+          "e_down": nrm(ks[3], (E, f, d), f ** -0.5),
+          "s_gate": nrm(ks[4], (d, f), d ** -0.5),
+          "s_up": nrm(ks[5], (d, f), d ** -0.5),
+          "s_down": nrm(ks[6], (f, d), f ** -0.5),
+          "e_bias": nrm(ks[7], (E,), 0.05)}
+    h = nrm(ks[8], (T, d), 1.0)
+    valid = jnp.ones((T,), bool)
+    kw = dict(top_k=8, routed_scale=2.5, scoring="sigmoid", n_group=8,
+              topk_group=4)
+    held = E // shares
+
+    def share(first):
+        cut = dict(mp)
+        for k in ("e_gate", "e_up", "e_down"):
+            cut[k] = mp[k][first:first + held]
+        return cut
+
+    whole, counts = moe_experts_ffn(mp, h, valid, **kw)
+    outs = [moe_experts_ffn(share(k * held), h, valid, first_expert=k * held,
+                            **kw) for k in range(shares)]
+    shared_only, _ = moe_experts_ffn(share(0), h, jnp.zeros((T,), bool), **kw)
+    total = sum(np.asarray(o, np.float64) for o, _ in outs) \
+        - (shares - 1) * np.asarray(shared_only, np.float64)
+    np.testing.assert_allclose(total, np.asarray(whole), atol=2e-5)
+    assert int(counts[1]) == T * 8
+    assert sum(int(c[1]) for _, c in outs) == T * 8

@@ -360,7 +360,7 @@ def _norm_after_the_rotation():
 
 
 def _picks_with(flaw: str):
-    def flawed(logits, mp, top_k, scoring, renorm_eps):
+    def flawed(logits, mp, top_k, scoring, renorm_eps, n_group, topk_group):
         scores = jax.nn.sigmoid(logits)
         biased = scores + mp["e_bias"]
         _, topi = jax.lax.top_k(biased, top_k)

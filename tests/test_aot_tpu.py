@@ -1449,3 +1449,150 @@ def test_index_score_kernels_mosaic_compile_at_the_deepseekv32_cells_shapes(
         interpret=False).lower().compile()
     assert "tpu_custom_call" in p.as_text()
     assert p.memory_analysis().temp_size_in_bytes < B * W * page * 4
+
+
+@pytest.mark.parametrize("precision", [None, "highest"],
+                         ids=["default", "highest"])
+@pytest.mark.parametrize("L", [67584, 68608], ids=["decode", "prefill"])
+def test_select_kernel_mosaic_compiles_at_the_deepseekv32_cells_shapes(
+        one_chip, L, precision):
+    """``serve.deepseekv32-longctx32``'s selection, one tile: 32 rows' index
+    scores over the decode program's 264 pages of 256 keys and over the
+    prefill program's context (268 pages: its last piece of 2048 positions
+    lies past the row in part), ``index_topk`` 2048, as ONE kernel whose
+    scores never come back to HBM; under a default matmul precision of
+    "highest" too (the f32 check's: the prefix counts' product names its
+    own)."""
+    import contextlib
+
+    from marlin_tpu.ops import dsa
+
+    def st(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    assert dsa.select_kernel_supported(32, L, 2048)
+    # inside the VMEM a call has without asking: beside a call that names a
+    # limit XLA keeps less of the program's own arrays in VMEM (PR 57: the
+    # decode program's gathered entries left it, 0.82 -> 1.00 ms a layer)
+    assert dsa._select_vmem_bytes(L, 2048) <= dsa._SCOPED_VMEM
+    with (jax.default_matmul_precision(precision) if precision
+          else contextlib.nullcontext()):
+        c = dsa._dsa_select_call.trace(
+            st((32, L), jnp.float32), st((32,)), k=2048,
+            interpret=False).lower().compile()
+    text = c.as_text()
+    assert text.count("tpu_custom_call") == 1 and " while(" not in text
+    assert c.memory_analysis().temp_size_in_bytes < 32 * L * 4 // 8
+
+
+def _deepseekv32_cell(one_chip, layers):
+    """The cell's configuration cut to ``layers`` layers: ``(spec, engine
+    block, abstract params, abstract pages)``."""
+    import json
+    import os
+
+    from benchmarks.drivers import serve_deepseekv32 as driver
+    from marlin_tpu.models import hybrid
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmarks", "configs",
+                           "deepseek-v32-ep16-l5.json")) as f:
+        cfg = json.load(f)
+    spec = driver.model_spec(dict(cfg, num_hidden_layers=layers))
+
+    def sds(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            jnp.shape(x), x.dtype, sharding=one_chip), tree)
+
+    eng = cfg["engine"]
+    return (spec, eng,
+            sds(jax.eval_shape(
+                lambda: hybrid.init_params(spec, jax.random.key(0)))),
+            sds(jax.eval_shape(lambda: hybrid.init_kv_pages(
+                spec, eng["num_pages"], 0, eng["page_len"]))))
+
+
+def _paged_programs(one_chip, spec, eng, params, pages):
+    """The traced ``(prefill, decode)`` programs of a spec cell at its
+    engine's rows, page, table and chunk."""
+    from marlin_tpu.models import hybrid
+
+    def st(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    B, page_len = eng["max_batch"], eng["page_len"]
+    W = sum(eng["buckets"][-1]) // page_len
+    with mt.config_context(pallas_interpret=False):
+        return (
+            hybrid._lm_prefill_paged_spec_jit.trace(
+                params, pages, st((W + 1,)), st((0,)),
+                st((eng["prefill_chunk"],)), st(()), st(()),
+                st((), jnp.uint32), st((), jnp.float32), st((), jnp.float32),
+                st(()), spec=spec, page_len=page_len).lower(),
+            hybrid._lm_decode_paged_spec_jit.trace(
+                params, pages, st((B, W)), st((B, 0)), st((B,)), st((B,)),
+                st((B,)), st((B,), jnp.uint32), st((B,), jnp.float32),
+                st((B,), jnp.float32), st((B,)), spec=spec,
+                page_len=page_len, kernel="pallas").lower())
+
+
+def test_deepseekv32_programs_hold_one_selection_kernel_a_layer(one_chip):
+    """Both paged programs of the ``deepseek_v32`` family at the cell's
+    widths, rows, page, table and chunk, two layers (the dense one and one
+    on experts), compiled whole for a v5e: under the ``dsa_select`` scope
+    each holds ONE custom call a layer, named ``dsa_select``, and no while
+    loop (until PR 57: the radix search's sixteen counting passes, a loop of
+    XLA operations out of HBM, and a conditional around the ties' ranks)."""
+    layers = 2
+    compiled = [p.compile() for p in _paged_programs(
+        one_chip, *_deepseekv32_cell(one_chip, layers))]
+    for c in compiled:
+        scoped = [ln for ln in c.as_text().split("\n")
+                  if "/dsa_select/" in ln]
+        kernels = [ln for ln in scoped if " custom-call(" in ln]
+        assert len(kernels) == layers
+        assert all(ln.lstrip().startswith("%dsa_select") for ln in kernels)
+        assert not [ln for ln in scoped
+                    if " while(" in ln or " conditional(" in ln]
+        assert c.memory_analysis().peak_memory_in_bytes < 16.9e9
+
+
+def test_mistral4_programs_do_not_see_the_selection_kernel(one_chip,
+                                                           monkeypatch):
+    """``serve.mistral4-docqa32`` shares ``attend_latent`` and the latent
+    kernels with the DeepSeek-V3.2 cell but has no indexer: its two programs
+    lower to the same text whether the selection may take its kernel or not
+    (the parent's text: sha1 9135909138... / 9e52a4d662... from the parent's
+    path, PERF.md section 6 PR 57), and name no selection."""
+    import json
+    import os
+
+    from benchmarks.drivers import serve_mistral4 as driver
+    from marlin_tpu.models import hybrid
+    from marlin_tpu.ops import dsa
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmarks", "configs",
+                           "mistral-small4-ep4-l6.json")) as f:
+        cfg = json.load(f)
+    spec, eng = driver.model_spec(cfg), cfg["engine"]
+    assert spec.latent.indexer is None
+
+    def sds(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            jnp.shape(x), x.dtype, sharding=one_chip), tree)
+
+    params = sds(jax.eval_shape(
+        lambda: hybrid.init_params(spec, jax.random.key(0))))
+    pages = sds(jax.eval_shape(lambda: hybrid.init_kv_pages(
+        spec, eng["num_pages"], 0, eng["page_len"])))
+    texts = []
+    for kernel in (True, False):   # (one line lowers both: a kernel's text
+        if not kernel:             # holds the lines it was reached from)
+            monkeypatch.setattr(dsa, "select_kernel_supported",
+                                lambda *a: False)
+        jax.clear_caches()
+        texts.append([p.as_text() for p in _paged_programs(
+            one_chip, spec, eng, params, pages)])
+    assert texts[0] == texts[1]
+    assert not any("dsa_select" in text for text in texts[0])

@@ -180,23 +180,57 @@ def test_from_config_names_the_missing_rope_keys():
 # the selection ---------------------------------------------------------------
 
 
-@pytest.mark.parametrize("k", [1, 16, 40, 128])
-def test_the_selection_is_top_k_with_ties_to_the_lower_position(k):
+@pytest.mark.parametrize("k, R, L, kernel", [
+    (1, 12, 512, False), (16, 12, 512, False), (40, 12, 512, False),
+    (128, 12, 512, False),       # 12 rows are not whole groups of 8
+    (128, 8, 512, True), (128, 32, 1408, True),
+    (256, 8, 2048, True), (256, 32, 4224, True),
+    (2048, 8, 4480, True), (2048, 32, 8192, True), (2048, 8, 2048, True),
+    (128, 8, 8192, True), (256, 16, 17408, True),   # a last piece in part
+    (256, 20, 1024, False),
+    (120, 8, 512, False),        # a list that is not whole lane tiles
+    (128, 8, 128 * 16411, False)])   # a group's blocks past the VMEM asked for
+def test_the_selection_is_top_k_with_ties_to_the_lower_position(k, R, L,
+                                                                kernel):
     """Constructed ties (a seventh of the scores one value, a row of zeros, a
-    row of negative zeros) and every kind of row length: the list is
-    ``lax.top_k``'s set, ascending, and the places past the count name 0."""
-    rng = np.random.default_rng(k)
-    R, L = 12, 512
+    row of negative zeros beside positive ones, whole lane tiles of one
+    value, ``-inf`` inside the row's length) and every kind of row length (0,
+    1, ``k - 1``, ``k``, ``k + 1``, L): the list is ``lax.top_k``'s set,
+    ascending, and the places past the count name 0. Where the sizes allow
+    (:func:`dsa.select_kernel_supported`) the list comes from the ONE kernel
+    (the interpreter here) and equals XLA's form to the bit; where they do
+    not, XLA's form runs."""
+    assert dsa.select_kernel_supported(R, L, k) == kernel
+    if L > 1 << 20:     # only the choice is asked of a row this long
+        text = str(jax.make_jaxpr(lambda s, n: dsa.select_tokens(s, n, k))(
+            jax.ShapeDtypeStruct((R, L), jnp.float32),
+            jax.ShapeDtypeStruct((R,), jnp.int32)))
+        assert "pallas_call" not in text
+        return
+    rng = np.random.default_rng(k + R + L)
     s = rng.standard_normal((R, L)).astype(np.float32)
     s[:, ::7] = 0.5
     s[3] = 0.0
     s[4, :100] = -0.0
+    s[4, 1:100:2] = 0.0
     s[5, 200:] = s[5, 199]
-    n_valid = np.array([1, 5, 40, 41, 100, 300, 512, 512, 39, 200, 511, 64],
-                       np.int32)
+    s[6, 128:384] = 3.0          # two whole lane tiles of the largest value
+    s[7, 5:L // 2] = -np.inf     # fewer finite scores than the list is long
+    lengths = [0, 1, k - 1, k, k + 1, L, L, L, 5, 39, L - 1, 64]
+    n_valid = np.clip(np.array([lengths[r % 12] for r in range(R)]), 0,
+                      L).astype(np.int32)
     sm = np.where(np.arange(L)[None] < n_valid[:, None], s, -np.inf)
-    idx, count = dsa.select_tokens(jnp.asarray(sm), jnp.asarray(n_valid), k)
-    _, want = jax.lax.top_k(jnp.asarray(sm), k)
+    select = jax.jit(lambda s, n: dsa.select_tokens(s, n, k))
+    assert ("pallas_call" in str(jax.make_jaxpr(select)(sm, n_valid))
+            ) == kernel
+    idx, count = select(jnp.asarray(sm), jnp.asarray(n_valid))
+    mask, mcount = dsa.selection_mask(jnp.asarray(sm), jnp.asarray(n_valid),
+                                      k)
+    np.testing.assert_array_equal(idx, dsa.compact(mask, mcount, k))
+    np.testing.assert_array_equal(count, mcount)
+    # (a -0.0 counts as the +0.0 beside it: the sort of `lax.top_k` on a
+    # CPU ranks it lower, so it is shown the sum with +0.0)
+    _, want = jax.lax.top_k(jnp.asarray(sm + np.float32(0.0)), k)
     for r in range(R):
         c = min(k, n_valid[r])
         assert count[r] == c

@@ -19,6 +19,18 @@ host's dispatch is small beside it):
   T rows at once, for one tile of ``--tile`` of them (what the prefill
   program runs, a tile at a time) and for the 32 decode rows;
   ``select.top_k``: ``lax.top_k`` of the same scores (what it replaces);
+- ``select.kernel``: :func:`~marlin_tpu.ops.dsa.select_tokens` as the ONE
+  kernel the programs run (PR 57: the k-th score, the mask and the list with
+  a group of 8 rows' scores resident in VMEM), milliseconds a TILE of
+  ``--tile`` queries (the chunk's T rows in one call, a grid step a group,
+  over its tiles: a call of one tile is shorter than the host's dispatch)
+  and a decode call's 32 rows (eight calls' rows in one), with
+  ``equals_top_k`` (every row's list ``lax.top_k``'s set) and
+  ``equals_xla`` (the first tile's lists XLA's form's, to the bit): to be
+  read beside ``select.mask`` + ``select.compact`` of a tile, and beside
+  ``select.xla``: XLA's form in a loop over the chunk's tiles, as a program
+  runs it (XLA keeps a tile's scores in VMEM there, which a call of
+  ``select.mask`` alone cannot show);
 - ``select.compact``, ``attend.gather``, ``attend.list``: one tile of
   ``--tile`` queries: the list from the mask, XLA's gather of its entries
   (``attend.gather.u32``: the same out of a 32-bit view of the context, its
@@ -40,7 +52,7 @@ import numpy as np
 
 CELL = dict(T=1024, L=67584, J=64, D=128, k=2048, E=640, H=128, B=32,
             page_len=256, value_dim=512)
-TINY = dict(T=32, L=512, J=4, D=16, k=32, E=128, H=4, B=4, page_len=16,
+TINY = dict(T=32, L=512, J=4, D=16, k=128, E=128, H=4, B=8, page_len=16,
             value_dim=64)
 PEAK_FLOPS, PEAK_BYTES = 197e12, 819e9
 
@@ -100,6 +112,10 @@ def main(argv=None) -> int:
     def compact(m, count):
         return dsa.compact(m, count, k)
 
+    def select(scores, n_valid):
+        assert dsa.select_kernel_supported(*scores.shape, k)
+        return dsa.select_tokens(scores, n_valid, k, interpret=interpret)
+
     def attend(q, entries, count):
         return dsa.attend_list(q, entries, count, c["value_dim"])
 
@@ -129,6 +145,10 @@ def main(argv=None) -> int:
                                               sds((B,), i32))),
                 ("select.compact", compact, (sds((tile, L), jnp.bool_),
                                              sds((tile,), i32))),
+                ("select.kernel", select, (sds((tile, L), jnp.float32),
+                                           sds((tile,), i32))),
+                ("select.kernel.decode", select, (sds((B, L), jnp.float32),
+                                                  sds((B,), i32))),
                 ("attend.gather", dsa.gather_entries,
                  (s["ctx"], sds((tile, k), i32))),
                 ("attend.list", attend, (s["q"], sds((tile, k, E), cd),
@@ -185,12 +205,52 @@ def main(argv=None) -> int:
     m, count = jmask(scores, n_valid)
     jcompact = jax.jit(compact)
     idx = jcompact(m[:tile], count[:tile])
-    _, want = jax.lax.top_k(scores[:tile], k)
-    same = all(sorted(a) == sorted(b) for a, b in zip(
-        np.asarray(idx).tolist(), np.asarray(want).tolist()))
+
+    def sets_equal(lists, counts, sc):
+        _, want = jax.lax.top_k(sc, k)
+        return all(sorted(a[:c]) == sorted(b[:c]) and not any(a[c:])
+                   for a, b, c in zip(np.asarray(lists).tolist(),
+                                      np.asarray(want).tolist(),
+                                      np.asarray(counts).tolist()))
+
     print(json.dumps({"part": "select.compact", "tile": tile,
                       "ms": _time_call(jcompact, (m[:tile], count[:tile])),
-                      "equals_top_k": same}), flush=True)
+                      "equals_top_k": sets_equal(idx, count[:tile],
+                                                 scores[:tile])}), flush=True)
+    # the kernel: one call over the chunk's rows is T / tile tiles of the
+    # program's lax.map, a grid step a group of 8 rows either way
+    jselect = jax.jit(select)
+    got, gcount = jselect(scores, n_valid)
+    print(json.dumps({
+        "part": "select.kernel", "rows": "tile", "tile": tile,
+        "ms": _time_call(jselect, (scores, n_valid)) / (T // tile),
+        "equals_top_k": sets_equal(got, gcount, scores),
+        "equals_xla": bool(jnp.array_equal(got[:tile], idx)
+                           & jnp.array_equal(gcount, count))}), flush=True)
+    # XLA's form the way a PROGRAM runs it: a loop over the chunk's tiles,
+    # where XLA keeps a tile's 8.65 MB of scores in VMEM over the sixteen
+    # passes (0.33 ms a tile in the parent's prefill program, PERF.md
+    # section 6 PR 57); `select.mask` above reads its tile out of HBM
+    def in_a_loop(scores, n_valid):
+        def one(a):
+            m, count = dsa.selection_mask(*a, k)
+            return dsa.compact(m, count, k)
+        return jax.lax.map(one, (scores.reshape(T // tile, tile, L),
+                                 n_valid.reshape(T // tile, tile)))
+
+    jloop = jax.jit(in_a_loop)
+    print(json.dumps({
+        "part": "select.xla", "rows": "tile, in a loop", "tile": tile,
+        "ms": _time_call(jloop, (scores, n_valid)) / (T // tile),
+        "equals_kernel": bool(jnp.array_equal(
+            jloop(scores, n_valid).reshape(T, k), got))}), flush=True)
+    eight = jnp.tile(dscores, (8, 1)), jnp.tile(lengths, 8)
+    got, gcount = jselect(*eight)
+    print(json.dumps({
+        "part": "select.kernel", "rows": "decode",
+        "ms": _time_call(jselect, eight) / 8,
+        "equals_top_k": sets_equal(got[:B], gcount[:B], dscores)}),
+        flush=True)
     jgather = jax.jit(dsa.gather_entries)
     entries = jgather(ctx, idx)
     print(json.dumps({
